@@ -84,9 +84,6 @@ def demo():
 
 
 def main(argv=None):
-    from . import default_to_cpu
-
-    default_to_cpu()
     p = argparse.ArgumentParser()
     p.add_argument("--model", help="pretrained model file (BigDL format)")
     p.add_argument("--folder", help="image folder (class-per-subdir)")

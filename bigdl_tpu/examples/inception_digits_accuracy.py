@@ -38,10 +38,6 @@ def main(max_epoch_n: int = 12, target: float = 0.95,
          batch_size: int = 64) -> float:
     # 1500 % 64 = 28: every epoch ends in a masked partial batch, same
     # every-record guarantee the ResNet proof exercises
-    from . import default_to_cpu
-
-    default_to_cpu()
-
     from bigdl_tpu.models.inception import InceptionV1NoAuxClassifier
 
     from ._distributed_proof import run_distributed_proof

@@ -15,8 +15,9 @@ the TPU rebuild's equivalents sustain device-feeding rates at scale:
      metrics ("get weights average" vs "computing time average").
 
 Pass criterion: the full host-side chain sustains ≥ 3000 img/s — above
-the 2192 img/s a v5e chip consumes (BENCH_TPU_MEASURED_r03) — so the
-input pipeline cannot be the scaling bottleneck.
+the 2192 img/s one v5e chip consumed on ResNet-50 in the 2026-07-30
+chip window (docs/PERF.md; older than today's code) — so the input
+pipeline cannot be the scaling bottleneck.
 
 Run (CPU; the infeed path is host-side by definition):
 
@@ -34,8 +35,6 @@ import os
 import time
 
 import numpy as np
-
-from . import default_to_cpu
 
 
 def generate(folder: str, n: int, hw: int, shards: int = 16,
@@ -194,7 +193,6 @@ def drive(folder: str, crop: int, batch: int, iters: int = 8,
 
 
 def main():
-    default_to_cpu()
     p = argparse.ArgumentParser()
     p.add_argument("--folder", default="/tmp/infeed_shards")
     p.add_argument("--n", type=int, default=50000)

@@ -47,10 +47,6 @@ def digits_as_mnist():
 
 
 def main(max_epoch_n: int = 60, target: float = 0.98) -> float:
-    from . import default_to_cpu
-
-    default_to_cpu()
-
     from bigdl_tpu import nn
     from bigdl_tpu.dataset import array
     from bigdl_tpu.models.lenet import LeNet5

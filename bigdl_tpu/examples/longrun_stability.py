@@ -24,8 +24,6 @@ import time
 
 import numpy as np
 
-from . import default_to_cpu
-
 
 def _rss_mb() -> float:
     try:
@@ -69,7 +67,6 @@ class _Telemetry:
 
 
 def main():
-    default_to_cpu()
     p = argparse.ArgumentParser()
     p.add_argument("--minutes", type=float, default=120.0)
     p.add_argument("--mode", default="multi_axis",

@@ -48,10 +48,6 @@ def make_dataset(n: int, seed: int):
 
 def main(max_epoch_n: int = 25, target: float = 0.95,
          cell: str = "lstm") -> float:
-    from . import default_to_cpu
-
-    default_to_cpu()
-
     from bigdl_tpu import nn
     from bigdl_tpu.dataset import array
     from bigdl_tpu.models.rnn import LSTMClassifier

@@ -79,9 +79,6 @@ def multi_label_lr(n=256, epochs=60):
 
 
 def main():
-    from . import default_to_cpu
-
-    default_to_cpu()
     acc1 = classifier_lenet()
     acc2 = logistic_regression()
     mse = multi_label_lr()

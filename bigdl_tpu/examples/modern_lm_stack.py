@@ -25,8 +25,6 @@ from __future__ import annotations
 import argparse
 import tempfile
 
-from . import default_to_cpu
-
 
 def main(argv=None):
     parser = argparse.ArgumentParser()
@@ -50,7 +48,6 @@ def main(argv=None):
                      "reach the iteration-10 checkpoint the resume step "
                      "restores from)")
 
-    default_to_cpu()
     import jax
     import numpy as np
     from jax.sharding import Mesh

@@ -59,10 +59,6 @@ def digits_upscaled(factor: int, n_train: int = 1500):
 
 def main(max_epoch_n: int = 30, depth: int = 20, target: float = 0.97,
          batch_size: int = 64) -> float:
-    from . import default_to_cpu
-
-    default_to_cpu()
-
     from bigdl_tpu.models.resnet import ResNetCifar
 
     from ._distributed_proof import run_distributed_proof

@@ -58,9 +58,6 @@ def load_graph(path: str, inputs, outputs):
 
 
 def main(argv=None):
-    from . import default_to_cpu
-
-    default_to_cpu()
     p = argparse.ArgumentParser()
     p.add_argument("--load", help="frozen .pb to load instead of the demo")
     p.add_argument("--inputs", default="input")

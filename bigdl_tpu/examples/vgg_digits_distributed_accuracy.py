@@ -25,10 +25,6 @@ DEFAULT_TARGET = 0.97
 
 def main(max_epoch_n: int = 8, target: float = DEFAULT_TARGET,
          batch_size: int = 64) -> float:
-    from . import default_to_cpu
-
-    default_to_cpu()
-
     from bigdl_tpu.models.vgg import VggForCifar10
 
     from ._distributed_proof import run_distributed_proof

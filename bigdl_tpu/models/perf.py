@@ -146,9 +146,9 @@ def main(argv=None):
         import os
 
         os.environ["bigdl.conv.impl"] = args.conv_impl
-    from ..utils.engine import Engine
+    from ..utils.compile_cache import ensure_compile_cache
 
-    Engine.honor_jax_platforms_env()
+    ensure_compile_cache()
     performance(args.model, args.batchSize, args.iteration, args.inputdata,
                 distributed=args.distributed, dtype=args.dtype)
 

@@ -299,9 +299,9 @@ def main(argv=None):
                      "parallelism (expert parallelism rides the data "
                      "axis), not --tensor-parallel/--pipeline-parallel")
 
-    from ..utils.engine import Engine as _Engine
+    from ..utils.compile_cache import ensure_compile_cache
 
-    _Engine.honor_jax_platforms_env()
+    ensure_compile_cache()
 
     # per-model defaults from the reference Train configs
     defaults = {

@@ -3,14 +3,18 @@ the TPU build's counterpart of the reference's BigDL-core JNI layer
 (SURVEY §2.1): CRC32C, bf16 wire codec with compressed-domain add, and
 the multithreaded image batcher.
 
-The .so is built by ``make -C native`` (g++ is in the image).  If it is
-missing, the loader builds it once on first import; if that fails (no
-toolchain), every entry point falls back to a numpy implementation with
-identical semantics — the library is an accelerator, never a hard dep.
+The .so is built by ``make -C native`` (g++ is in the image), which
+stamps the sha256 of the source into it.  The loader builds it on first
+use when it is missing OR was built from other source (a stale .so left
+in the tree is rebuilt, never loaded).  If the build fails (no
+toolchain) a warning names the error and every entry point runs a numpy
+implementation with identical semantics — the library is an
+accelerator, never a hard dep; ``available()`` says which one runs.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,20 +26,38 @@ log = logging.getLogger(__name__)
 
 _SO_PATH = os.path.join(os.path.dirname(__file__), "libbigdl_tpu_native.so")
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_SRC = os.path.join(_SRC_DIR, "bigdl_tpu_native.cc")
 
 
 def _build() -> bool:
     try:
-        subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                       capture_output=True, timeout=120)
+        subprocess.run(["make", "-B", "-C", _SRC_DIR], check=True,
+                       capture_output=True, text=True, timeout=120)
         return os.path.exists(_SO_PATH)
-    except Exception as e:  # toolchain absent / build error
-        log.debug("native build failed: %s", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        log.warning("native runtime build failed, running the numpy "
+                    "implementations: %s %s", e,
+                    (getattr(e, "stderr", "") or "")[-500:])
         return False
 
 
+def _matches_source() -> bool:
+    """Whether the .so on disk carries the stamp of the source next to
+    it.  An installed tree ships the library without the source; there
+    is nothing to compare it with, and it is loaded as packaged."""
+    if not os.path.exists(_SO_PATH):
+        return False
+    if not os.path.exists(_SRC):
+        return True
+    with open(_SRC, "rb") as f:
+        tag = b"btpu-source-sha256:" + hashlib.sha256(
+            f.read()).hexdigest().encode()
+    with open(_SO_PATH, "rb") as f:
+        return tag in f.read()
+
+
 def _load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_SO_PATH) and not _build():
+    if not _matches_source() and not _build():
         return None
     try:
         lib = ctypes.CDLL(_SO_PATH)

@@ -58,7 +58,9 @@ class MultiHeadAttention(TensorModule):
         plus ``sparse_globals`` anchor blocks (Longformer-style);
         ``"strided"`` = own block + every ``sparse_stride``-th block.
         Masks are cached per (T, S); off-TPU the identical math runs
-        densely with the mask applied elementwise.
+        densely with the mask applied elementwise.  On a TPU the block
+        must be a multiple of 128 or span the whole sequence — a
+        smaller block raises (ops/block_sparse.py ``_kernel_path``).
     """
 
     def __init__(self, embed_dim: int, num_heads: int,

@@ -321,6 +321,18 @@ class Recurrent(AbstractModule):
         # (N, T, ...) → (T, N, ...) for scan
         pre_t = jnp.moveaxis(pre, 1, 0)
 
+        # inside a shard_map that checks varying axes the carry must
+        # enter the scan with the type it leaves with: the zero state
+        # starts unvarying, the step's output varies like the inputs
+        # and the weights
+        vma = frozenset().union(*(
+            jax.typeof(a).vma
+            for a in jax.tree_util.tree_leaves((pre_t, cp))))
+        if vma:
+            hidden0 = jax.tree_util.tree_map(
+                lambda h: jax.lax.pcast(h, tuple(vma), to="varying"),
+                hidden0)
+
         def step(hidden, p_t):
             out, new_hidden = cell.cell_apply(cp, p_t, hidden)
             return new_hidden, out

@@ -3,9 +3,10 @@
 XLA fuses most of this framework automatically (SURVEY §7 architecture
 stance); these kernels cover the cases where hand-tiling pays:
 attention's O(T²) score matrix (never materialized — online softmax in
-VMEM) and single-pass LayerNorm.  Everything degrades gracefully: on
-non-TPU backends the public wrappers fall back to reference jnp
-implementations, so tests and CPU development need no TPU.
+VMEM) and single-pass LayerNorm.  On non-TPU backends the public
+wrappers compute reference jnp implementations, so tests and CPU
+development need no TPU; on a TPU there is no second path — a kernel
+compiles or its error propagates.
 """
 from .block_sparse import (BlockMask, block_sparse_attention,
                            block_sparse_matmul, magnitude_block_mask,

@@ -1,82 +1,14 @@
-"""Shared Pallas availability/gating for the ops package."""
+"""Shared Pallas gating for the ops package."""
 from __future__ import annotations
 
-import logging
-
 import jax
-
-log = logging.getLogger("bigdl_tpu")
-
-try:
-    from jax.experimental import pallas as pl  # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    if not hasattr(pltpu, "CompilerParams"):
-        # jax 0.4.x names it TPUCompilerParams (same kwargs); alias the
-        # modern name the kernels use
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
 
 def use_kernel(interpret: bool) -> bool:
-    """Kernel path on TPU or when explicitly interpreting; jnp fallback
-    elsewhere (CPU tests exercise kernels with interpret=True)."""
-    if not HAS_PALLAS:
-        return False
-    if interpret:
-        return True
-    return jax.default_backend() == "tpu"
-
-
-class KernelProbe:
-    """First-dispatch compile health gate for a Pallas kernel family —
-    the ``conv3x3_pallas`` pattern, generalized so every kernel module
-    gets the same loud degradation instead of reinventing it.
-
-    ``probe_fn`` compiles (not runs) the kernel on a tiny
-    representative shape; a Mosaic/compile failure disables the kernel
-    for the process with ONE structured warning naming the error, and
-    every later dispatch silently takes the module's fallback.  The
-    error is retained for the bench schema (``reason()`` — the dead
-    conv kernel hid behind an opaque leg error for 4 releases; these
-    never will)."""
-
-    def __init__(self, name: str, probe_fn, fallback: str):
-        self.name = name
-        self._probe_fn = probe_fn
-        self._fallback = fallback
-        self.checked = False
-        self.ok = False
-        self.error = None
-
-    def healthy(self, interpret: bool) -> bool:
-        if interpret:
-            return True  # interpret mode is the CPU test path, not Mosaic
-        if not self.checked:
-            self.checked = True
-            try:
-                self._probe_fn()
-                self.ok = True
-            except Exception as e:  # MosaicError etc. — backend-specific
-                self.ok = False
-                self.error = f"{type(e).__name__}: {e}"[:300]
-                log.warning(
-                    "pallas %s kernel disabled: first-dispatch probe "
-                    "failed with %s — every dispatch falls back to %s "
-                    "(bench records the reason as attn_kernel_fallback)",
-                    self.name, self.error, self._fallback)
-        return self.ok
-
-    def reason(self):
-        """The error that disabled the kernel this process, or None."""
-        return self.error
-
-    def reset(self):
-        """Testing hook: forget the cached verdict."""
-        self.checked = False
-        self.ok = False
-        self.error = None
+    """Kernel path on a TPU backend or when explicitly interpreting; the
+    jnp reference on every other backend (CPU tests exercise the kernels
+    with ``interpret=True``).  On a TPU there is no second path: a
+    kernel compiles or its error propagates."""
+    return interpret or jax.default_backend() == "tpu"

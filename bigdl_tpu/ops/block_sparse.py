@@ -32,11 +32,11 @@ next to the dense equivalent; drivers feed them to
 work and the win lands in ``bigdl_perf_sparse_flops_skipped`` instead
 of reading as an MFU regression.
 
-Fallbacks ride the ``use_kernel``/interpret discipline: off-TPU (or on
-non-blockable shapes) both ops compute the identical math densely with
-the mask applied elementwise — same function, no skip.  A Mosaic
-compile failure at first dispatch disables the kernels loudly
-(``blocksparse_fallback_reason`` → bench ``attn_kernel_fallback``).
+Off a TPU backend (without ``interpret``) both ops compute the
+identical math densely with the mask applied elementwise — same
+function, no skip.  On a TPU there is no dense fallback: a block size
+Mosaic cannot tile (see :func:`_kernel_path`) raises, and a compile
+error propagates.
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ._support import KernelProbe, pl, pltpu, use_kernel
+from ._support import pl, pltpu, use_kernel
 from .flash_attention import (_BIG_LSE, _LANES, _accum_dkv_tile,
                               _accum_dq_tile, _dot, _finish_softmax_tile,
                               _init_softmax_scratch, _online_softmax_tile,
@@ -57,8 +57,7 @@ from .flash_attention import (_BIG_LSE, _LANES, _accum_dkv_tile,
 
 __all__ = ["BlockMask", "block_sparse_attention", "block_sparse_matmul",
            "sliding_window_mask", "strided_mask", "magnitude_block_mask",
-           "attention_work", "matmul_work", "pick_block_divisor",
-           "blocksparse_fallback_reason"]
+           "attention_work", "matmul_work", "pick_block_divisor"]
 
 
 # --------------------------------------------------------------------------
@@ -550,6 +549,33 @@ def _bs_attn_bwd_rule(mask, causal, sm_scale, interpret, res, g):
 _bs_attn.defvjp(_bs_attn_fwd_rule, _bs_attn_bwd_rule)
 
 
+def _kernel_path(interpret: bool, *blocks, rows: int = 8) -> bool:
+    """Whether a call takes the Pallas kernels, for ``(block, full_dim)``
+    pairs (and the matmul's row count).
+
+    Mosaic tiles the minor dimension on 128 lanes: the lse/delta rows
+    are ``(1, 1, block)`` tiles of a ``(BH, 1, T)`` array and the
+    matmul's activation tile is ``(bm, block)``, so on a TPU a block is
+    a 128-multiple or spans its whole dimension — anything else RAISES
+    here rather than reaching the lowering or, worse, the dense path.
+    The interpreter (the CPU tests) also takes 8-aligned sub-128
+    blocks; other sizes there, and every call off-TPU without
+    ``interpret``, run the masked dense reference."""
+    if not use_kernel(interpret):
+        return False
+    tiles = rows % 8 == 0 and all(
+        b % 128 == 0
+        or (b % 8 == 0 and (b == n or (interpret and b < 128)))
+        for b, n in blocks)
+    if tiles or interpret:
+        return tiles
+    raise ValueError(
+        "block-sparse kernels on a TPU need blocks that are a multiple "
+        "of 128 or span their whole dimension (8-aligned), and a row "
+        f"count that is a multiple of 8; got (block, dim) = {blocks}, "
+        f"rows = {rows}")
+
+
 def _bs_attention_reference(q, k, v, mask: BlockMask, causal: bool,
                             sm_scale: float):
     """Dense fallback with the IDENTICAL function: scores masked
@@ -585,10 +611,9 @@ def block_sparse_attention(q, k, v, block_mask, causal: bool = False,
     additionally applies the element-level causal mask inside
     diagonal-crossing blocks and prunes above-diagonal blocks from the
     sweep (an all-ones causal mask therefore runs exactly the flash
-    kernel's schedule).  Off-TPU (without ``interpret``), on
-    non-divisible shapes, or after a failed first-dispatch compile
-    probe, the identical math runs densely with the mask applied
-    elementwise."""
+    kernel's schedule).  Off-TPU (without ``interpret``) the identical
+    math runs densely with the mask applied elementwise; on a TPU a
+    block size Mosaic cannot tile raises (:func:`_kernel_path`)."""
     B, H, T, D = q.shape
     S = k.shape[2]
     if not isinstance(block_mask, BlockMask):
@@ -607,12 +632,8 @@ def block_sparse_attention(q, k, v, block_mask, causal: bool = False,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
 
-    def blockable(b):  # the flash kernel's alignment contract
-        return b % 128 == 0 or (b < 128 and b % 8 == 0)
-
-    if use_kernel(interpret) and blockable(block_mask.block_q) \
-            and blockable(block_mask.block_k) \
-            and _PROBE.healthy(interpret):
+    if _kernel_path(interpret, (block_mask.block_q, T),
+                    (block_mask.block_k, S)):
         return _bs_attn(q, k, v, block_mask, causal, float(sm_scale),
                         interpret)
     return _bs_attention_reference(q, k, v, block_mask, causal,
@@ -731,44 +752,9 @@ def block_sparse_matmul(x, w, block_mask, interpret: bool = False):
     lead = x.shape[:-1]
     x2 = x.reshape(-1, K)
 
-    def blockable(b):
-        return b % 128 == 0 or (b < 128 and b % 8 == 0)
-
-    if use_kernel(interpret) and blockable(block_mask.block_q) \
-            and blockable(block_mask.block_k) \
-            and x2.shape[0] % 8 == 0 and _PROBE.healthy(interpret):
+    if _kernel_path(interpret, (block_mask.block_q, K),
+                    (block_mask.block_k, N), rows=x2.shape[0]):
         y = _bs_mm(x2, w, block_mask, interpret)
     else:
         y = x2 @ (w * jnp.asarray(block_mask.elementwise(), w.dtype))
     return y.reshape(*lead, N)
-
-
-# --------------------------------------------------------------------------
-# First-dispatch compile probe (satellite of the conv3x3 pattern)
-# --------------------------------------------------------------------------
-
-def _probe_compile():
-    """Compile (not run) the sparse fwd+bwd attention and the sparse
-    matmul on tiny representative shapes."""
-    x = jnp.zeros((1, 1, 128, 32), jnp.float32)
-    mask = sliding_window_mask(2, 2, window=1, causal=True,
-                               block_q=64, block_k=64)
-
-    def f(q, k, v):
-        return jnp.sum(_bs_attn(q, k, v, mask, True, 0.25, False) ** 2)
-
-    jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(x, x, x).compile()
-    xm = jnp.zeros((8, 128), jnp.float32)
-    wm = jnp.zeros((128, 128), jnp.float32)
-    mm = BlockMask(np.ones((2, 2), bool), 64, 64)
-    jax.jit(lambda a, b: _bs_mm(a, b, mm, False)).lower(xm, wm).compile()
-
-
-_PROBE = KernelProbe("block_sparse", _probe_compile,
-                     "the masked dense path")
-
-
-def blocksparse_fallback_reason():
-    """The error that disabled the block-sparse kernels this process,
-    or None — bench.py folds it into ``attn_kernel_fallback``."""
-    return _PROBE.error

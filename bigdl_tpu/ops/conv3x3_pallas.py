@@ -38,7 +38,6 @@ already ideal.  Falls back to ``conv_gemm`` off-TPU.
 from __future__ import annotations
 
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +45,6 @@ from jax import lax
 
 from ._support import pl, pltpu, use_kernel
 from .conv_gemm import conv2d_gemm_nhwc
-
-log = logging.getLogger("bigdl_tpu")
 
 
 def _pick_th(h: int, target: int = 16) -> int:
@@ -149,55 +146,6 @@ def _bwd_rule(interpret, res, g):
 _conv3x3.defvjp(_fwd_rule, _bwd_rule)
 
 
-# --------------------------------------------------------------------------
-# graceful degradation: probe the kernel ONCE at first dispatch and fall
-# back to conv_gemm when Mosaic cannot compile it (the dead path used to
-# surface only as `resnet50_pallas_error: MosaicError` in the bench while
-# the headline silently rode XLA convs)
-# --------------------------------------------------------------------------
-
-_PROBE = {"checked": False, "ok": False, "error": None}
-
-
-def _probe_compile():
-    """Compile (not run) the kernel on a tiny representative shape —
-    Mosaic/compile errors surface here, before any real dispatch."""
-    x = jnp.zeros((1, 8, 8, 8), jnp.float32)
-    w = jnp.zeros((3, 3, 8, 8), jnp.float32)
-    jax.jit(functools.partial(_conv3x3, interpret=False)).lower(
-        x, w).compile()
-
-
-def _kernel_healthy(interpret: bool) -> bool:
-    """First-dispatch health gate for the real (non-interpret) kernel.
-    A Mosaic/compile failure disables the kernel for the process with
-    ONE structured warning naming the error; every later 3x3 dispatch
-    silently takes the ``conv_gemm`` fallback."""
-    if interpret:
-        return True  # interpret mode is the CPU test path, not Mosaic
-    if not _PROBE["checked"]:
-        _PROBE["checked"] = True
-        try:
-            _probe_compile()
-            _PROBE["ok"] = True
-        except Exception as e:  # MosaicError etc. — backend-specific
-            _PROBE["ok"] = False
-            _PROBE["error"] = f"{type(e).__name__}: {e}"[:300]
-            log.warning(
-                "pallas conv3x3 kernel disabled: first-dispatch probe "
-                "failed with %s — every 3x3 dispatch falls back to "
-                "conv_gemm (bench records the reason as "
-                "resnet50_conv_fallback)", _PROBE["error"])
-    return _PROBE["ok"]
-
-
-def pallas_fallback_reason():
-    """The error that disabled the kernel this process, or None —
-    bench.py records it as the ``resnet50_conv_fallback`` schema
-    field."""
-    return _PROBE["error"]
-
-
 def conv3x3_s1_same(x, w, interpret: bool = False):
     """3×3 stride-1 SAME NHWC conv via the Pallas slab kernel.
 
@@ -205,12 +153,9 @@ def conv3x3_s1_same(x, w, interpret: bool = False):
       x: [B, H, W, C];  w: [3, 3, C, O] (HWIO).
     Returns [B, H, W, O] in x.dtype (f32 accumulation).
     Off-TPU (without ``interpret``) delegates to ``conv2d_gemm_nhwc``;
-    on TPU a kernel that fails its first-dispatch compile probe
-    (Mosaic errors) degrades to the same fallback with one structured
-    warning instead of killing the step (see
-    :func:`pallas_fallback_reason`).
+    on a TPU the kernel compiles or the Mosaic error propagates.
     """
     assert w.shape[:2] == (3, 3), "conv3x3_s1_same is the 3×3 kernel"
-    if use_kernel(interpret) and _kernel_healthy(interpret):
+    if use_kernel(interpret):
         return _conv3x3(x, w, interpret)
     return conv2d_gemm_nhwc(x, w, stride=(1, 1), padding=(1, 1))

@@ -27,9 +27,11 @@ Design (pallas_guide.md patterns):
   accumulation; probability tiles are cast back to the input dtype
   before the PV/dV/dK products — elementwise math stays f32.
 
-The public ``flash_attention`` falls back to a jnp reference on
-non-TPU backends (or with ``interpret=True`` runs the kernels in the
-Pallas interpreter — used by tests).
+The public ``flash_attention`` computes the jnp reference on non-TPU
+backends (or with ``interpret=True`` runs the kernels in the Pallas
+interpreter — used by tests).  On a TPU the kernels compile or the
+error propagates; only a sequence length the kernels cannot tile
+(neither a 128-multiple nor one 8-aligned block) takes the reference.
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._support import KernelProbe, pl, pltpu, use_kernel
+from ._support import pl, pltpu, use_kernel
 
 _LANES = 128  # VMEM scratch lane width (TPU-friendly minor dim)
 _BIG_LSE = 1e30  # lse sentinel for fully-masked rows: exp(s - BIG) == 0
@@ -397,10 +399,14 @@ def _pick_block(n: int, d: int = 64) -> int:
     T<=1024 keep the 512 target).  Past the ridge the win comes from
     grid overhead: fewer, longer-running programs amortize
     prologue/epilogue and revisit the accumulators fewer times.  1024
-    is the VMEM ceiling — the f32 score tile is 1024²·4 B = 4 MB,
-    which still double-buffers in the ~16 MB VMEM; 2048² (16 MB) does
-    not fit.  Measured (v5e, r3): 512² runs the T=1024 grad 2.1×
-    faster than 128²; short sequences use one whole block."""
+    is the largest block Mosaic has been shown to take: fwd, dKdV and
+    dQ at 1024², D=128, bf16 compile on a v5e under Mosaic's default
+    VMEM limit and agree with the dense reference (``chip_smoke.py``,
+    PR 21, jax 0.9.0) — the f32 score tile is 1024²·4 B = 4 MB; 2048²
+    (16 MB) has never been tried.  Measured (v5e, r3): 512² runs the
+    T=1024 grad 2.1× faster than 128²; short sequences use one whole
+    block.  The timings quoted here date from 2026-07/08 and are older
+    than this code."""
     target = 512 if (n <= 1024 and d >= 128) else 1024
     if n <= target:
         return n
@@ -442,36 +448,6 @@ def _flash_bwd_rule(causal, sm_scale, interpret, block_q, block_k, res,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-# --------------------------------------------------------------------------
-# graceful degradation (satellite of the conv3x3 probe): compile the
-# kernel ONCE at first dispatch; a Mosaic failure disables it with one
-# structured warning and the bench records ``attn_kernel_fallback``
-# instead of silently riding the dense reference path
-# --------------------------------------------------------------------------
-
-def _probe_compile():
-    """Compile (not run) fwd+bwd on a tiny representative shape —
-    Mosaic/compile errors surface here, before any real dispatch."""
-    x = jnp.zeros((1, 1, 128, 32), jnp.float32)
-
-    def f(q, k, v):
-        out = _flash(q, k, v, True, 0.25, False, None, None)
-        return jnp.sum(out ** 2)
-
-    jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(x, x, x).compile()
-
-
-_PROBE = KernelProbe("flash_attention", _probe_compile,
-                     "the dense XLA reference")
-
-
-def attention_fallback_reason():
-    """The error that disabled the flash kernels this process, or None
-    — bench.py folds it into the ``attn_kernel_fallback`` schema
-    field."""
-    return _PROBE.error
-
-
 def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     interpret: bool = False,
@@ -482,8 +458,9 @@ def flash_attention(q, k, v, causal: bool = False,
     Uses the Pallas kernels on TPU (or under ``interpret=True``); plain
     XLA attention elsewhere.  The kernel path takes sequence lengths
     that are 128-multiples, or short 8-aligned sequences that fit one
-    block; anything else falls back (callers pad — the data layer's
-    fixed-length contract already guarantees static shapes).
+    block; any other length takes the dense reference (callers pad —
+    the data layer's fixed-length contract already guarantees static
+    shapes).
     ``block_q``/``block_k`` override the measured default (1024-target;
     see ``_pick_block``) — exposed for the on-hardware tuning sweeps.
     """
@@ -494,8 +471,7 @@ def flash_attention(q, k, v, causal: bool = False,
     def blockable(n):  # one whole block (8-aligned) or a 128-multiple
         return (n % 128 == 0) or (n < 128 and n % 8 == 0)
 
-    if use_kernel(interpret) and blockable(T) and blockable(S) \
-            and _PROBE.healthy(interpret):
+    if use_kernel(interpret) and blockable(T) and blockable(S):
         return _flash(q, k, v, causal, sm_scale, interpret,
                       block_q, block_k)
     return _attention_reference(q, k, v, causal, sm_scale)

@@ -17,12 +17,11 @@ from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..dataset.sample import MiniBatch, SampleToMiniBatch
 from .validation import ValidationMethod, ValidationResult
-
-from ..utils.jax_compat import shard_map
 
 from ._sharding_utils import data_mesh as _data_mesh, pad_batch, round_up
 
@@ -63,9 +62,11 @@ def _cached_eval_fwd(model, mesh: Optional[Mesh]):
             pspec = param_specs(model, None)
         else:
             pspec = P()
+        # unchecked like every training step the plan engine builds:
+        # a model the trainer accepts must also validate
         fwd = jax.jit(shard_map(fwd_local, mesh=mesh,
                                 in_specs=(pspec, P(), P("data")),
-                                out_specs=P("data")))
+                                out_specs=P("data"), check_vma=False))
     else:
         fwd = jax.jit(fwd_local)
     cache[mesh] = fwd

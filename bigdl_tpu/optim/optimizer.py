@@ -116,8 +116,10 @@ class Optimizer:
         self._sync_resume = None
         self._sync_force_average = False
         # how the last profiled iteration's phase split was measured:
-        # "trace" (jax.profiler device events) or None (not profiled)
+        # "trace" (jax.profiler device events) or None (not profiled),
+        # and that split (profiling.PhaseSplit, device seconds)
         self.phase_source = None
+        self.phase_split = None
         # online-training slices (train_more / the continuous-learning
         # loop) call optimize() every few steps — rebuilding the plan
         # engine each call would re-trace the jitted step and bill the
@@ -1479,6 +1481,7 @@ class Optimizer:
                     c_s, agg_s = trace_split
                     compute_ratio = c_s / max(c_s + agg_s, 1e-12)
                     self.phase_source = "trace"
+                    self.phase_split = trace_split
                 if compute_ratio is not None:
                     self.metrics.add("computing time average",
                                      train_time * compute_ratio)

@@ -14,10 +14,13 @@ device lines).
 from __future__ import annotations
 
 import glob
+import logging
 import os
 import shutil
 import tempfile
 from typing import Callable, NamedTuple, Optional
+
+log = logging.getLogger("bigdl_tpu")
 
 
 class PhaseSplit(NamedTuple):
@@ -80,64 +83,15 @@ def _device_lines(profile_data):
             if dev_plane and "xla ops" in line.name.lower():
                 yield line
             elif line.name.startswith("tf_XLA"):
-                # CPU PJRT executor threads: tf_XLAPjRtCpuClient/... on
-                # newer runtimes; tf_XLAEigen/... + tf_XLATfrtCpuClient/...
-                # on jax 0.4.x — same per-op event stream either way
+                # CPU PJRT executor threads (tf_XLAPjRtCpuClient/...)
                 yield line
-
-
-def _load_profile(path: str):
-    """Parse an xplane.pb into the (planes → lines → named events with
-    duration_ns) shape ``_device_lines`` walks.  jax>=0.5 ships
-    ``jax.profiler.ProfileData``; older runtimes fall back to the raw
-    XSpace proto (tensorflow's tsl copy), adapted to the same surface."""
-    try:
-        from jax.profiler import ProfileData
-
-        return ProfileData.from_file(path)
-    except ImportError:
-        pass
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    class _Ev:
-        __slots__ = ("name", "duration_ns")
-
-        def __init__(self, name, duration_ns):
-            self.name = name
-            self.duration_ns = duration_ns
-
-    class _Line:
-        __slots__ = ("name", "events")
-
-        def __init__(self, line, meta):
-            self.name = line.name
-            self.events = [
-                _Ev(meta[e.metadata_id].name, e.duration_ps / 1e3)
-                for e in line.events if e.metadata_id in meta]
-
-    class _Plane:
-        __slots__ = ("name", "lines")
-
-        def __init__(self, plane):
-            meta = dict(plane.event_metadata)
-            self.name = plane.name
-            self.lines = [_Line(l, meta) for l in plane.lines]
-
-    class _Space:
-        __slots__ = ("planes",)
-
-        def __init__(self, space):
-            self.planes = [_Plane(p) for p in space.planes]
-
-    space = xplane_pb2.XSpace()
-    with open(path, "rb") as f:
-        space.ParseFromString(f.read())
-    return _Space(space)
 
 
 def split_from_xplane(path: str) -> PhaseSplit:
     """Sum (compute_seconds, collective_seconds) over a trace file."""
-    pd = _load_profile(path)
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(path)
     compute_ns = 0
     collective_ns = 0
     for line in _device_lines(pd):
@@ -157,7 +111,8 @@ def trace_phase_split(run: Callable[[], None]) -> Optional[PhaseSplit]:
 
     ``run`` ALWAYS executes exactly once, and its exceptions propagate —
     the driver's failure-retry loop depends on seeing training errors.
-    Only the profiling machinery itself is allowed to fail silently.
+    A failure of the profiling machinery itself costs the split, not
+    the step: it is logged with its traceback and None is returned.
 
     The temp trace directory is removed on EVERY path — trace-start
     failure, a raising ``run``, an unparsable trace — via the
@@ -171,8 +126,9 @@ def trace_phase_split(run: Callable[[], None]) -> Optional[PhaseSplit]:
             ctx = jax.profiler.trace(tmp)
             ctx.__enter__()
             started = True
-        except Exception:  # backend without trace support: just run
-            pass
+        except Exception:
+            log.warning("profiler trace did not start — step runs "
+                        "untraced", exc_info=True)
         try:
             run()
         finally:
@@ -180,6 +136,8 @@ def trace_phase_split(run: Callable[[], None]) -> Optional[PhaseSplit]:
                 try:
                     ctx.__exit__(None, None, None)
                 except Exception:
+                    log.warning("profiler trace did not stop cleanly",
+                                exc_info=True)
                     started = False
         if not started:
             return None
@@ -187,12 +145,16 @@ def trace_phase_split(run: Callable[[], None]) -> Optional[PhaseSplit]:
             files = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
                               recursive=True)
             if not files:
+                log.warning("profiler wrote no xplane under %s", tmp)
                 return None
-            compute_s, collective_s = split_from_xplane(files[0])
-            if compute_s <= 0.0:
-                return None
-            return compute_s, collective_s
-        except Exception:  # unparsable trace — fall back
+            split = split_from_xplane(files[0])
+        except Exception:
+            log.warning("profiler trace could not be parsed",
+                        exc_info=True)
             return None
+        if split.compute_s <= 0.0:
+            log.warning("profiler trace has no device compute events")
+            return None
+        return split
     finally:
         shutil.rmtree(tmp, ignore_errors=True)

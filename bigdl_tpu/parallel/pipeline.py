@@ -45,10 +45,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 
 # per-iteration bookkeeping/timing/state attributes that legitimately

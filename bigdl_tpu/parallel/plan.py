@@ -52,10 +52,8 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 log = logging.getLogger("bigdl_tpu")
 
@@ -923,9 +921,13 @@ class CompiledPlanStep:
 
     def init_state(self, sync_resume=None):
         """Fresh device-placed (params, slots, buffers) from the live
-        model/optimizer — device_put COPIES, so the donating step can
-        never eat the model's own arrays (the retry loop re-enters
-        here after a restore).
+        model/optimizer — each leaf is COPIED first: ``device_put`` of
+        an array that already sits where it is asked to go returns the
+        same buffer, and a replicated put aliases the source as its
+        first shard (``may_alias=False`` does not stop that), so
+        without the copy the donating step eats the model's own arrays
+        and a failed attempt leaves the model unusable (the retry loop
+        re-enters here, with or without a checkpoint to restore).
 
         Relaxed-synchrony leaves (``sync="periodic(k)"/"stale(s)"``)
         are stacked with a leading ``[n_data]`` replica dim sharded
@@ -942,7 +944,8 @@ class CompiledPlanStep:
         host = self._host_params()
         put = lambda tree, specs: jax.tree_util.tree_map(
             lambda a, s: jax.device_put(
-                jnp.asarray(a), NamedSharding(self.mesh, s)), tree, specs)
+                jnp.array(a, copy=True), NamedSharding(self.mesh, s)),
+            tree, specs)
         slots_host = _resume_slots(self.optim,
                                    self.optim.init_state(host))
         if self.relaxed:

@@ -204,9 +204,8 @@ def make_ring_attention_sharded(mesh, axis_name: str = "seq",
     sharded over ``axis_name`` and each device runs the ring/Ulysses
     local program.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from ..utils.jax_compat import shard_map
 
     spec = P(None, None, axis_name, None)
     fn = ring_attention if strategy == "ring" else ulysses_attention

@@ -27,10 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 log = logging.getLogger("bigdl_tpu")
 

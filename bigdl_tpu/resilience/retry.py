@@ -23,6 +23,8 @@ import random
 import time
 from typing import Callable, Optional, Sequence, Tuple, Type
 
+import jax
+
 log = logging.getLogger("bigdl_tpu")
 
 
@@ -38,9 +40,28 @@ class LossSpikeError(RuntimeError):
 
 # Errors that will reproduce identically on a replay from the same
 # checkpoint — retrying them burns the budget without new information.
+# TypeError is what jax raises while TRACING a mistyped program (a scan
+# carry whose type changes, a dtype mismatch): the retrace is identical.
 DEFAULT_FATAL_TYPES: Tuple[Type[BaseException], ...] = (
     FatalTrainingError, MemoryError, NotImplementedError, SyntaxError,
+    TypeError,
 )
+
+# XLA status codes of a program the device or compiler refuses the same
+# way every time: out of device memory, or rejected at compile
+_FATAL_XLA_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                     "UNIMPLEMENTED")
+
+
+def _reproduces_on_replay(exc: BaseException) -> bool:
+    """Device out-of-memory, or a program XLA / Mosaic / the Pallas
+    lowering refuses to compile — recompiling it five times with
+    backoff only delays (and can bury) the report."""
+    msg = str(exc)
+    if "Mosaic" in msg or "Pallas" in msg:
+        return True
+    return isinstance(exc, jax.errors.JaxRuntimeError) and (
+        msg.startswith(_FATAL_XLA_STATUS) or "compil" in msg.lower())
 
 
 def classify_error(exc: BaseException,
@@ -49,12 +70,14 @@ def classify_error(exc: BaseException,
     """``"fatal"`` or ``"retryable"``.
 
     Control-flow exceptions (KeyboardInterrupt/SystemExit) are fatal —
-    the user asked to stop.  Beyond the explicit fatal list everything
-    defaults to retryable, preserving the reference loop's semantics
-    (it retried any Exception)."""
+    the user asked to stop — and so are compile and out-of-memory
+    errors, which fail identically on every replay.  Beyond those and
+    the explicit fatal list everything defaults to retryable,
+    preserving the reference loop's semantics (it retried any
+    Exception)."""
     if isinstance(exc, (KeyboardInterrupt, SystemExit, GeneratorExit)):
         return "fatal"
-    if isinstance(exc, tuple(fatal_types)):
+    if isinstance(exc, tuple(fatal_types)) or _reproduces_on_replay(exc):
         return "fatal"
     return "retryable"
 

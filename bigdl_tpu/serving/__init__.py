@@ -52,9 +52,9 @@ verified checkpoints) applied to an in-process request path:
   crc-verified handoff blobs), and :class:`Autoscaler` scales each
   pool independently on the router's aggregated telemetry (p99, shed
   rate, queue depth, KV occupancy) with hysteresis, cooldowns, and
-  drain-before-retire.  :mod:`.compile_cache` persists XLA
-  executables (``bigdl.serving.compileCache``) so cold autoscaled
-  replicas skip per-bucket compiles.
+  drain-before-retire.  Every ``InferenceServer.start`` turns on the
+  persistent compile cache (:mod:`bigdl_tpu.utils.compile_cache`) so
+  cold autoscaled replicas skip per-bucket compiles.
 
 Deterministic serving fault injectors (fail-next-N steps, injected
 step latency, poisoned params, replica kill/partition) live with the
@@ -63,7 +63,6 @@ training injectors in :mod:`bigdl_tpu.resilience.faults`.
 from .autoscale import AutoscalePolicy, Autoscaler
 from .batcher import MicroBatcher
 from .breaker import CircuitBreaker
-from .compile_cache import set_compile_cache_dir
 from .fleet import FleetQuorumError, ReplicaAgent, ServingFleet
 from .health import FleetHealthMonitor, ReplicaHealthPolicy
 from .kvpool import KVPagePool, PageLease, PoolExhausted
@@ -87,6 +86,6 @@ __all__ = [
     "ReplicaTraceSink",
     "RequestTracer", "ServeFuture", "ServeResult",
     "ServingFleet", "ServingMetrics", "SparseFetchClient", "Status",
-    "load_verified_params", "set_compile_cache_dir",
+    "load_verified_params",
     "trace_attribution", "trace_coverage",
 ]

@@ -16,8 +16,8 @@ Control discipline (what keeps it from flapping):
   ``sustain`` (``idle_sustain``) consecutive evaluations before any
   action; one noisy sample scales nothing.
 * **Cooldown** — after any action the pool holds for ``cooldown_s``;
-  a new replica needs time to warm (the persisted compile cache —
-  ``bigdl.serving.compileCache`` — shrinks exactly this window) before
+  a new replica needs time to warm (the persistent compile cache,
+  ``utils/compile_cache.py``, shrinks exactly this window) before
   its effect is measurable.
 * **Bounds** — ``min_replicas``/``max_replicas`` clamp every pool.
 * **Drain-before-retire** — scale-down rides the graceful-preemption

@@ -737,15 +737,11 @@ class ServingFleet:
         out = {"flops_total": total, "wall_s": wall, "chips": chips,
                "model_flops_per_sec_per_chip": rate, "mfu": None}
         if rate > 0:
-            try:
-                from ..telemetry.device_info import current_device_spec
+            from ..telemetry.device_info import current_device_spec
 
-                spec = current_device_spec()
-                if spec.peak_flops_per_sec:
-                    out["mfu"] = rate / spec.peak_flops_per_sec
-                    out["nominal_device"] = spec.nominal
-            except Exception:
-                pass
+            spec = current_device_spec()
+            out["mfu"] = rate / spec.peak_flops_per_sec
+            out["nominal_device"] = spec.nominal
         return out
 
     #: router registry families folded into the fleet view — the ones

@@ -366,15 +366,11 @@ class ServingMetrics:
         out = {"flops_total": total, "wall_s": wall,
                "model_flops_per_sec": rate, "mfu": None}
         if rate > 0:
-            try:
-                from ..telemetry.device_info import current_device_spec
+            from ..telemetry.device_info import current_device_spec
 
-                spec = current_device_spec()
-                if spec.peak_flops_per_sec:
-                    out["mfu"] = rate / spec.peak_flops_per_sec
-                    out["nominal_device"] = spec.nominal
-            except Exception:
-                pass
+            spec = current_device_spec()
+            out["mfu"] = rate / spec.peak_flops_per_sec
+            out["nominal_device"] = spec.nominal
         return out
 
     def tenants(self) -> dict:

@@ -230,12 +230,11 @@ class InferenceServer:
         if self._started:
             raise RuntimeError("server already started")
         from ..optim.evaluator import _cached_eval_fwd
-        from .compile_cache import maybe_set_compile_cache_dir
+        from ..utils.compile_cache import ensure_compile_cache
 
-        # persisted compile cache (bigdl.serving.compileCache): a cold
-        # autoscaled replica loads per-bucket executables instead of
-        # recompiling them — best-effort, never fails a start
-        maybe_set_compile_cache_dir()
+        # a cold autoscaled replica loads per-bucket executables from
+        # the persistent compile cache instead of recompiling them
+        ensure_compile_cache()
         self.model.evaluate()
         self._fwd = _cached_eval_fwd(self.model, self.mesh)
         # on_request flips readiness the instant the signal lands (the

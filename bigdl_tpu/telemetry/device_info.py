@@ -106,12 +106,20 @@ def device_spec(device_kind: str) -> Optional[DeviceSpec]:
 
 def current_device_spec(device=None) -> DeviceSpec:
     """Spec for a live jax device (default: ``jax.devices()[0]``).
-    Unknown accelerators degrade to the nominal CPU row rather than
-    None — the accountant always has *a* denominator, flagged
-    ``nominal`` when it is not a measured-peak claim."""
+    The CPU backend gets the nominal row; an accelerator whose
+    ``device_kind`` is not in :data:`DEVICE_SPECS` RAISES — a made-up
+    denominator under a real chip's MFU is worse than no number, so
+    add the row (with its source) instead."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "") or str(device)
-    return device_spec(kind) or CPU_SPEC
+    spec = device_spec(kind)
+    if spec is None or (spec.nominal
+                        and getattr(device, "platform", "cpu") != "cpu"):
+        raise LookupError(
+            f"no DEVICE_SPECS row for accelerator device_kind={kind!r} "
+            f"(platform {getattr(device, 'platform', '?')!r}): add its "
+            "per-chip peaks to telemetry/device_info.py")
+    return spec

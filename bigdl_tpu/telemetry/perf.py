@@ -216,12 +216,7 @@ class PerfAccountant:
     @property
     def spec(self) -> DeviceSpec:
         if self._spec is None:
-            try:
-                self._spec = current_device_spec()
-            except Exception:  # backend not up: nominal denominator
-                from .device_info import CPU_SPEC
-
-                self._spec = CPU_SPEC
+            self._spec = current_device_spec()
         return self._spec
 
     # -- program analysis ------------------------------------------------

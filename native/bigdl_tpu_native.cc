@@ -298,4 +298,14 @@ int64_t btpu_parse_records(const uint8_t* buf, int64_t n, int64_t* offsets,
 
 int btpu_num_threads() { return pool().size(); }
 
+// The Makefile stamps the sha256 of this file into the library; the
+// python loader greps the .so for the tag and rebuilds on a mismatch,
+// so a library built from other source is never loaded.
+#ifndef BTPU_SOURCE_SHA256
+#define BTPU_SOURCE_SHA256 "unstamped"
+#endif
+const char* btpu_source_tag() {
+  return "btpu-source-sha256:" BTPU_SOURCE_SHA256;
+}
+
 }  // extern "C"

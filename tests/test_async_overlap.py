@@ -624,20 +624,3 @@ def test_bench_ledger_carries_goodput_fields(tmp_path):
     # schema-stable: the fields exist even when unmeasured
     rec2 = bench.ledger_record({"tpu": False})
     assert rec2["goodput_productive_fraction"] is None
-
-
-def test_bench_no_probe_flag_and_probe_cache():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    # --no-probe: no subprocess, immediate CPU verdict
-    up, info, note, secs = bench._probe_backend(probe=False)
-    assert up is False and secs == 0.0 and "skip" in note
-    # the verdict is cached for the run — a later probe=True call must
-    # NOT launch the 300s probe path
-    up2, _, note2, _ = bench._probe_backend(probe=True)
-    assert up2 is False and note2 == note

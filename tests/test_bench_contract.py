@@ -1,8 +1,7 @@
-"""bench.py contract guards — the round driver runs bench.py on real
-hardware and records its ONE JSON line; a broken bench means no
-recorded numbers, so the cheap pieces are unit-tested here (the full
-worker is exercised by the driver itself)."""
-import json
+"""bench.py contract guards — the device battery prints ONE JSON line
+from the backend it finds and never falls back; the cheap pieces and
+every host-side leg's measurement function are unit-tested here at
+small sizes."""
 import subprocess
 import sys
 
@@ -54,109 +53,31 @@ def test_bench_model_runs_and_counts_steps():
     assert abs(c2.flops - c1.flops) / c1.flops < 0.2
 
 
-def test_newest_tpu_measurement_found():
-    bench = _bench()
-    got = bench._newest_tpu_measurement()
-    assert got is not None
-    data, src = got
-    assert data["tpu"] is True
-    assert "measured_at" in data or src  # stamped or mtime-dated
-
-
-def test_fallback_merges_persisted_tpu_numbers(tmp_path):
-    """With the probe resolving to CPU and the CPU pass timed out, the
-    emitted line must still CARRY the persisted chip numbers, stamped
-    stale (VERDICT r3: the judged artifact carries TPU truth)."""
+def test_device_battery_refuses_to_fall_back():
+    """No accelerator and no ``JAX_PLATFORMS=cpu``: the device battery
+    exits non-zero and prints no JSON line — no CPU worker steps in."""
     import os
 
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu", "BENCH_PROBE_TIMEOUT": "30",
-                "BENCH_CPU_TIMEOUT": "3",
-                # the serving/elastic/integrity/telemetry legs are
-                # unit-tested in-process (test_*_measurements_contract);
-                # skip their slow subprocesses here
-                "BENCH_SERVING_TIMEOUT": "0",
-                "BENCH_FLEET_TIMEOUT": "0",
-                "BENCH_DISAGG_TIMEOUT": "0",
-                "BENCH_ELASTIC_TIMEOUT": "0",
-                "BENCH_INTEGRITY_TIMEOUT": "0",
-                "BENCH_TELEMETRY_TIMEOUT": "0",
-                "BENCH_SHARDING_TIMEOUT": "0",
-                "BENCH_DLRM_TIMEOUT": "0",
-                "BENCH_SYNC_TIMEOUT": "0",
-                "BENCH_SLO_TIMEOUT": "0",
-                "BENCH_LOOP_TIMEOUT": "0",
-                "BENCH_BLOCKSPARSE_TIMEOUT": "0",
-                "BENCH_EMBED_TIMEOUT": "0",
-                "BENCH_TENANT_TIMEOUT": "0",
-                "BENCH_INCIDENT_TIMEOUT": "0"})
-    # --no-ledger: a test invocation must not append to the repo's
-    # judged PERF_LEDGER.jsonl trajectory
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--no-ledger"],
-        capture_output=True, text=True, timeout=300, cwd=".", env=env)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    out = subprocess.run([sys.executable, "bench.py"], capture_output=True,
+                         text=True, timeout=240, cwd=".", env=env)
+    assert out.returncode != 0, out.stdout
+    assert "no accelerator" in out.stderr
+    assert not [l for l in out.stdout.splitlines() if l.startswith("{")]
+
+
+def test_bench_import_touches_no_backend():
+    """Importing bench.py (what every leg's parent process does) must
+    not initialise a jax backend: one process per chip."""
+    code = ("import importlib.util, jax;"
+            "s = importlib.util.spec_from_file_location('b', 'bench.py');"
+            "m = importlib.util.module_from_spec(s); s.loader.exec_module(m);"
+            "import bigdl_tpu;"
+            "from jax._src import xla_bridge;"
+            "assert not xla_bridge._backends, xla_bridge._backends")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=".")
     assert out.returncode == 0, out.stderr
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, f"no JSON line:\n{out.stdout}\n{out.stderr}"
-    result = json.loads(lines[-1])
-    assert result["tpu"] is True          # the numbers are chip numbers
-    assert result["stale"] is True        # ...honestly stamped
-    assert result["tpu_live"] is False
-    assert result["value"] > 0
-    assert "measured_at" in result
-    assert "live_probe" in result
-
-
-def test_probe_mode_emits_json():
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--probe"], capture_output=True,
-        text=True, timeout=240, cwd=".",
-        env={"PATH": "/usr/bin:/bin:/usr/local/bin",
-             "JAX_PLATFORMS": "cpu"},
-    )
-    assert out.returncode == 0, out.stderr
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, f"no JSON in probe output:\n{out.stdout}\n{out.stderr}"
-    line = lines[-1]
-    info = json.loads(line)
-    assert info["platform"] == "cpu"
-    assert info["n_devices"] >= 1
-
-
-def test_salvage_partial_merges_with_provenance(monkeypatch, tmp_path):
-    """A worker killed mid-run leaves a section checkpoint; the
-    orchestrator must promote it to a live measurement, carrying
-    earlier-window fields only with explicit provenance."""
-    bench = _bench()
-    partial = {
-        "tpu": True, "device": "TPU v5 lite0", "value": 2200.0,
-        "metric": "ResNet-50 train throughput (bf16)",
-        "unit": "images/sec/chip",
-        "resnet50_bf16_images_per_sec_per_chip": 2200.0,
-        "partial": True, "sections_done": ["resnet50_bf16_sweep@300s"],
-        "measured_at": "2026-07-31T09:00:00Z",
-    }
-    previous = {
-        "tpu": True, "value": 2192.34, "measured_at": "2026-07-30T06:09:44Z",
-        "transformerlm_mfu": 0.6169, "stale": True, "tpu_live": False,
-        "note": "old-emit bookkeeping that must not leak",
-    }
-    (tmp_path / "BENCH_TPU_WORKER_PARTIAL.json").write_text(
-        json.dumps(partial))
-    (tmp_path / "BENCH_TPU_MEASURED_old.json").write_text(
-        json.dumps(previous))
-    monkeypatch.setattr(bench, "_here", lambda: str(tmp_path))
-    out = bench._salvage_partial({"tpu_bench_error": "timeout after 2700s"})
-    assert out is not None
-    assert out["value"] == 2200.0                      # live field wins
-    assert out["measured_at"] == "2026-07-31T09:00:00Z"
-    assert out["partial"] is True
-    assert out["tpu_bench_error"] == "timeout after 2700s"
-    assert out["transformerlm_mfu"] == 0.6169          # carried...
-    carried = out["carried_fields"]                    # ...with provenance
-    assert "transformerlm_mfu" in carried["keys"]
-    assert carried["measured_at"] == "2026-07-30T06:09:44Z"
-    assert "note" not in out and "stale" not in out    # bookkeeping dropped
 
 
 def test_serving_measurements_contract():
@@ -237,7 +158,7 @@ def test_disagg_measurements_contract():
         phase_s=0.5, low_rps=2.0, high_rps=8.0, users=8,
         max_new=4, long_prompt=4, long_new=12, t_max=32,
         page_size=4, eval_interval_s=0.2, cooldown_s=0.4,
-        deadline_s=20.0, cold_start=False, layers=1)
+        deadline_s=20.0, layers=1)
     # paged-vs-static at equal arena bytes: >= 2x concurrent long
     # decodes, every stream exactly the unpaged reference, no leaks
     c = out["concurrency"]
@@ -486,22 +407,19 @@ def test_blocksparse_measurements_contract():
     for row in out["density_sweep"]:
         assert abs(row["executed_fraction"] - row["density"]) \
             <= 0.10 * row["density"]
-    # the 50% magnitude mask halves the executed work exactly — the
-    # deterministic basis the sentinel guards when TPU is unreachable
+    # the 50% magnitude mask halves the executed work exactly — a
+    # deterministic count
     assert out["work_reduction_x"] == 2.0
     assert out["sparse_flops_skipped"] > 0
     assert out["sparse_flops_gauge"] == out["sparse_flops_skipped"]
     assert out["accountant_payload_has_skip"] is True
-    # kernels healthy on the interpret path: the must-be-null field
-    assert out["attn_kernel_fallback"] is None
     assert out["speedup_basis"] == "interpret_work_reduction"
     # and the record flattens into the schema-stable ledger fields
     rec = bench.ledger_record({"blocksparse": {
         "speedup_x": out["speedup_x"]}})
     assert rec["blocksparse_speedup_x"] == out["speedup_x"]
     assert rec["blocksparse_t4096_mfu"] is None
-    assert rec["attn_kernel_fallback"] is None
-    # a TPU worker record's wall ratio takes precedence over the leg
+    # the device battery's wall ratio takes precedence over the leg
     rec2 = bench.ledger_record({
         "transformerlm_blocksparse_T4096_speedup_x": 1.7,
         "transformerlm_blocksparse_T4096_mfu": 0.56,
@@ -712,11 +630,3 @@ def test_incident_measurements_contract():
     assert rec["incident_overhead_pct"] == out["overhead_pct"]
     for key in bench.LEDGER_FIELDS:
         assert key in rec
-
-
-def test_salvage_partial_requires_headline(monkeypatch, tmp_path):
-    bench = _bench()
-    (tmp_path / "BENCH_TPU_WORKER_PARTIAL.json").write_text(
-        json.dumps({"tpu": True, "device": "TPU v5 lite0"}))
-    monkeypatch.setattr(bench, "_here", lambda: str(tmp_path))
-    assert bench._salvage_partial({}) is None

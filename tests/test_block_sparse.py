@@ -303,29 +303,3 @@ class TestAccountantCorrection:
         entry = pa.payload()["programs"]["p"]
         assert entry["flops"] == 20.0
         assert "sparse_flops_skipped" not in entry
-
-
-class TestKernelProbe:
-    def test_fallback_reasons_none_on_cpu(self):
-        """On the CPU test topology the probes never run (use_kernel is
-        False off-TPU without interpret) — the fallback reasons stay
-        None and the bench field stays null (the sentinel's must-be-
-        null invariant)."""
-        from bigdl_tpu.ops.block_sparse import blocksparse_fallback_reason
-        from bigdl_tpu.ops.flash_attention import attention_fallback_reason
-
-        assert attention_fallback_reason() is None
-        assert blocksparse_fallback_reason() is None
-
-    def test_probe_disables_on_compile_failure(self):
-        from bigdl_tpu.ops._support import KernelProbe
-
-        boom = KernelProbe("boom", lambda: (_ for _ in ()).throw(
-            RuntimeError("Mosaic says no")), "the fallback")
-        assert boom.healthy(interpret=True)     # interpret never probes
-        assert boom.healthy(interpret=False) is False
-        assert "Mosaic says no" in boom.reason()
-        # verdict is cached: one probe, one warning
-        assert boom.healthy(interpret=False) is False
-        boom.reset()
-        assert boom.reason() is None

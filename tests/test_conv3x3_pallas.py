@@ -7,10 +7,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from bigdl_tpu.ops._support import HAS_PALLAS
 from bigdl_tpu.ops.conv3x3_pallas import conv3x3_s1_same
-
-pytestmark = pytest.mark.skipif(not HAS_PALLAS, reason="no pallas")
 
 R = np.random.RandomState(5)
 
@@ -84,57 +81,3 @@ def test_twin_pallas_impl_matches_xla():
     a = np.asarray(forward(params, x, training=False, impl="xla"))
     b = np.asarray(forward(params, x, training=False, impl="pallas"))
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# graceful degradation (ISSUE 10 satellite): a Mosaic-dead kernel falls
-# back to conv_gemm at first dispatch with ONE structured warning, and
-# the reason is queryable for the bench's schema field
-# ---------------------------------------------------------------------------
-
-def test_mosaic_failure_falls_back_with_one_warning(monkeypatch, caplog):
-    import logging
-
-    from bigdl_tpu.ops import conv3x3_pallas as mod
-
-    monkeypatch.setattr(mod, "_PROBE",
-                        {"checked": False, "ok": False, "error": None})
-
-    def broken_probe():
-        raise RuntimeError("Mosaic failed to compile: unsupported op")
-
-    monkeypatch.setattr(mod, "_probe_compile", broken_probe)
-    monkeypatch.setattr(mod, "use_kernel", lambda interpret: True)
-    x = jnp.asarray(R.randn(1, 8, 8, 8), jnp.float32)
-    w = jnp.asarray(R.randn(3, 3, 8, 8) * 0.1, jnp.float32)
-    with caplog.at_level(logging.WARNING, logger="bigdl_tpu"):
-        y1 = mod.conv3x3_s1_same(x, w)   # first dispatch: probe + warn
-        y2 = mod.conv3x3_s1_same(x, w)   # later dispatches: silent
-    warnings = [r for r in caplog.records
-                if "pallas conv3x3 kernel disabled" in r.message]
-    assert len(warnings) == 1, [r.message for r in caplog.records]
-    assert "RuntimeError" in warnings[0].message
-    # the reason the bench records as resnet50_conv_fallback
-    assert mod.pallas_fallback_reason().startswith("RuntimeError")
-    # and the math silently rode the gemm fallback, exactly
-    for y in (y1, y2):
-        np.testing.assert_allclose(np.asarray(y), np.asarray(_ref(x, w)),
-                                   rtol=1e-4, atol=1e-4)
-
-
-def test_probe_success_keeps_kernel(monkeypatch):
-    from bigdl_tpu.ops import conv3x3_pallas as mod
-
-    monkeypatch.setattr(mod, "_PROBE",
-                        {"checked": False, "ok": False, "error": None})
-    monkeypatch.setattr(mod, "_probe_compile", lambda: None)
-    assert mod._kernel_healthy(False) is True
-    assert mod.pallas_fallback_reason() is None
-    # interpret mode (the CPU test path) never consults the probe
-    def exploding_probe():
-        raise AssertionError("probe must not run for interpret mode")
-
-    monkeypatch.setattr(mod, "_probe_compile", exploding_probe)
-    monkeypatch.setattr(mod, "_PROBE",
-                        {"checked": False, "ok": False, "error": None})
-    assert mod._kernel_healthy(True) is True

@@ -1,5 +1,5 @@
 """Disaggregated serving + autoscaling specs (serving/pools.py,
-router.py, autoscale.py, compile_cache.py): prefill and decode route
+router.py, autoscale.py): prefill and decode route
 to their own role pools with the KV handoff riding crc-verified blobs
 between them, a decode replica killed mid-stream retries on a
 survivor within the remaining deadline budget with its pages freed,
@@ -336,44 +336,3 @@ def test_autoscaler_no_flap_under_alternating_noise():
         assert flips <= 1
     finally:
         fl.stop(15)
-
-
-# ---------------------------------------------------------------------------
-# persisted compile cache
-# ---------------------------------------------------------------------------
-
-def test_compile_cache_property_wires_jax_config(tmp_path,
-                                                 monkeypatch):
-    import jax
-
-    from bigdl_tpu.serving import compile_cache
-
-    cache_dir = tmp_path / "xla-cache"
-    monkeypatch.setenv("BIGDL_SERVING_COMPILECACHE", str(cache_dir))
-    # reset module state so the property is re-read
-    monkeypatch.setitem(compile_cache._STATE, "dir", None)
-    prior = jax.config.jax_compilation_cache_dir
-    try:
-        model = _model()
-        srv = InferenceServer(model, max_batch=4).start()
-        try:
-            assert jax.config.jax_compilation_cache_dir == \
-                str(cache_dir)
-            assert cache_dir.is_dir()
-            assert compile_cache.compile_cache_dir() == str(cache_dir)
-        finally:
-            srv.stop(10)
-        # idempotent: a second wire-in is a no-op, never an error
-        assert compile_cache.maybe_set_compile_cache_dir() == \
-            str(cache_dir)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
-        compile_cache._STATE["dir"] = None
-
-
-def test_compile_cache_absent_property_is_noop(monkeypatch):
-    from bigdl_tpu.serving import compile_cache
-
-    monkeypatch.delenv("BIGDL_SERVING_COMPILECACHE", raising=False)
-    monkeypatch.setitem(compile_cache._STATE, "dir", None)
-    assert compile_cache.maybe_set_compile_cache_dir() is None

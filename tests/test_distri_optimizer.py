@@ -98,7 +98,7 @@ def test_allreduce_parameter_semantics():
     reduce-scatter of per-shard grads + all-gather reproduces psum."""
     from functools import partial
 
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -121,7 +121,7 @@ def test_allreduce_parameter_semantics():
 def test_bf16_compression_close():
     """bf16 wire format ≈ fp32 within bf16 tolerance (reference fp16
     codec round-trip spec)."""
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -282,7 +282,7 @@ def test_trace_phase_split_classifies_collectives():
     import jax.numpy as jnp
     from jax import lax
 
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from bigdl_tpu.optim.profiling import trace_phase_split

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import enable_x64
-except ImportError:  # jax 0.4.x keeps it under experimental
-    from jax.experimental import enable_x64
+from jax import enable_x64
 
 from bigdl_tpu import nn
 from bigdl_tpu.utils.table import T, Table

@@ -81,30 +81,48 @@ def test_bf16_batchnorm_buffers_stay_f32():
         assert leaf.dtype == jnp.float32
 
 
-def test_local_step_donates_buffers():
-    """The jitted step must consume its param/slot inputs (VERDICT r2
-    weak #1): the model's pre-training arrays are deleted after step 1."""
-    ds = array(xor_samples(n=32))
-    model = xor_model()
-    before = jax.tree_util.tree_leaves(model.param_tree())
-    opt = LocalOptimizer(model, ds, nn.ClassNLLCriterion(), batch_size=32)
-    opt.set_optim_method(Adam(learning_rate=0.01))
-    opt.set_end_when(max_iteration(2))
-    opt.optimize()
-    assert any(getattr(a, "is_deleted", lambda: False)() for a in before), \
-        "no input buffer was donated by the local train step"
+def _assert_step_donates_and_model_survives(opt, model, before, x, y):
+    """The jitted step consumes the param/slot buffers it is HANDED
+    (VERDICT r2 weak #1) — and those are the engine's own copies: the
+    model's pre-training arrays stay alive, so a failed attempt leaves
+    the model usable."""
+    assert not any(a.is_deleted() for a in before), \
+        "the train step donated the model's own arrays"
+    engine = opt._engine_cache[1]
+    params, slots, buffers = engine.init_state()
+    handed = jax.tree_util.tree_leaves((params, slots))
+    engine.step(params, slots, buffers, 0.01, x, y)
+    assert all(a.is_deleted() for a in handed), \
+        "the train step did not donate its input buffers"
     # and the model's post-training params are live + usable
     _ = model.forward(np.zeros((1, 2), np.float32))
 
 
-def test_distri_step_donates_buffers():
-    Engine.init()
-    ds = array(xor_samples(n=64))
+def test_local_step_donates_buffers():
+    samples = xor_samples(n=32)
     model = xor_model()
     before = jax.tree_util.tree_leaves(model.param_tree())
-    opt = DistriOptimizer(model, ds, nn.ClassNLLCriterion(), batch_size=64)
+    opt = LocalOptimizer(model, array(samples), nn.ClassNLLCriterion(),
+                         batch_size=32)
+    opt.set_optim_method(Adam(learning_rate=0.01))
     opt.set_end_when(max_iteration(2))
+    opt.reuse_compiled_engine = True
     opt.optimize()
-    assert any(getattr(a, "is_deleted", lambda: False)() for a in before), \
-        "no input buffer was donated by the distributed train step"
-    _ = model.forward(np.zeros((1, 2), np.float32))
+    _assert_step_donates_and_model_survives(
+        opt, model, before, np.stack([s.feature for s in samples]),
+        np.stack([s.label for s in samples]))
+
+
+def test_distri_step_donates_buffers():
+    Engine.init()
+    samples = xor_samples(n=64)
+    model = xor_model()
+    before = jax.tree_util.tree_leaves(model.param_tree())
+    opt = DistriOptimizer(model, array(samples), nn.ClassNLLCriterion(),
+                          batch_size=64)
+    opt.set_end_when(max_iteration(2))
+    opt.reuse_compiled_engine = True
+    opt.optimize()
+    _assert_step_donates_and_model_survives(
+        opt, model, before, np.stack([s.feature for s in samples]),
+        np.stack([s.label for s in samples]))

@@ -83,7 +83,7 @@ def test_top2_expert_parallel_matches_dense():
     """The all_to_all dispatch computes the same top-2 function as the
     dense path — the [E, C] buffer shapes are routing-order-independent
     so the existing wire needs no change."""
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from bigdl_tpu.parallel.spmd import param_specs
 
@@ -194,7 +194,7 @@ def test_expert_parallel_matches_dense():
     from bigdl_tpu.parallel.spmd import param_specs
 
     pspecs = param_specs(moe, "model")
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     def local(pp, xx):
         out, _ = moe.apply_fn(pp, moe.buffer_tree(), xx, False, None)

@@ -130,3 +130,25 @@ def test_simple_rnn_trains():
     tgt = jnp.asarray(np.stack([s.label for s in samples[:16]]))
     final = crit.forward(out, tgt)
     assert final < np.log(V), f"LM loss {final} not below chance {np.log(V)}"
+
+
+def test_recurrent_scans_inside_a_checked_shard_map():
+    """jax 0.9 types a scan carry by the mesh axes it varies over: the
+    zero initial state starts unvarying while the step's output varies
+    like the batch-sharded input, so Recurrent must cast the carry
+    before the scan (LSTM's tuple state included)."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    x = jnp.asarray(np.random.RandomState(0).randn(4, 5, 4), jnp.float32)
+    for cell in (RnnCell(4, 6), LSTM(4, 6), GRU(4, 6)):
+        m = Recurrent(cell)
+        params, buffers = m.param_tree(), m.buffer_tree()
+        fwd = lambda p, x: m.apply_fn(p, buffers, x, False, None)[0]
+        sharded = jax.jit(shard_map(
+            fwd, mesh=mesh, in_specs=(P(), P("data")),
+            out_specs=P("data")))(params, x)   # check_vma defaults True
+        np.testing.assert_allclose(np.asarray(sharded),
+                                   np.asarray(fwd(params, x)), atol=1e-5)

@@ -351,6 +351,49 @@ def test_injected_exception_retries_and_converges(tmp_path):
         (loss_injected, loss_clean)
 
 
+def test_compile_and_oom_errors_are_fatal_and_surface_first(tmp_path):
+    """A program the compiler refuses, or one that does not fit device
+    memory, fails identically on every replay: the retry loop must
+    raise it at once — not recompile it five times with backoff, and
+    not bury it under a later error — and leave the model usable."""
+    def xla(msg):
+        return jax.errors.JaxRuntimeError(msg)
+
+    for exc in (xla("RESOURCE_EXHAUSTED: XLA:TPU compile permanent "
+                    "error. Ran out of memory in memory space hbm."),
+                xla("RESOURCE_EXHAUSTED: Error allocating device buffer"),
+                xla("INTERNAL: Mosaic failed to compile TPU kernel: x"),
+                xla("INVALID_ARGUMENT: bad shape"),
+                ValueError("The Pallas TPU lowering currently requires "
+                           "that the last two dimensions ..."),
+                TypeError("scan body function carry input and carry "
+                          "output must have equal types")):
+        assert classify_error(exc) == "fatal", exc
+    for exc in (RuntimeError("injected failure"), OSError("disk"),
+                xla("UNAVAILABLE: socket closed"),
+                LossSpikeError("diverged")):
+        assert classify_error(exc) == "retryable", exc
+
+    model = xor_model()
+    oom = faults.ExceptionTransformer(
+        fail_at=300, exc=lambda: xla("RESOURCE_EXHAUSTED: out of HBM"))
+    ds = array(xor_samples()) >> oom >> SampleToMiniBatch(64)
+    opt = LocalOptimizer(model, ds, nn.ClassNLLCriterion(), batch_size=64)
+    opt.set_optim_method(SGD(learning_rate=1.0))
+    opt.set_end_when(max_epoch(150))
+    opt.set_checkpoint(str(tmp_path / "ck"), several_iteration(1))
+    sleeps = []
+    opt.set_retry_policy(RetryPolicy(max_retries=5, backoff_base=0.01,
+                                     sleep=sleeps.append))
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED"):
+        opt.optimize()
+    assert oom.fired and opt.rollbacks == 0 and sleeps == []
+    # steps ran and donated before the failure; the model's own arrays
+    # were never the donated ones
+    _ = model.forward(np.zeros((1, 2), np.float32))
+
+
 # ---------------------------------------------------------------------------
 # preemption: checkpoint at the step boundary, exit clean, resume
 # ---------------------------------------------------------------------------

@@ -102,7 +102,7 @@ def test_mha_ring_inside_shard_map_matches_dense():
 
     from functools import partial
 
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     params = ring_layer.param_tree()
 

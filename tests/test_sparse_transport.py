@@ -233,7 +233,7 @@ def test_dlrm_sparse_matches_dense_loss_trajectory():
 
 def test_sharded_embedding_exchange_matches_local_gather():
     from bigdl_tpu.nn.embedding import ShardedEmbedding
-    from bigdl_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     RNG().set_seed(4)
     emb = ShardedEmbedding(64, 8, axis_name="data")

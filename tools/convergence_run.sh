@@ -5,9 +5,7 @@
 # other segment is kill -9'd at a random point mid-training, and the
 # next segment must resume from the last committed Orbax step (the
 # example logs `resumed_from` into LONGRUN_CONVERGENCE.jsonl).  Runs
-# until TARGET_MIN minutes of wall clock have elapsed.  Respects the
-# battery's /tmp/battery3/WINDOW_OPEN pause flag both here (between
-# segments) and inside the example (per-iteration).
+# until TARGET_MIN minutes of wall clock have elapsed.
 #
 #   TARGET_MIN=75 bash tools/convergence_run.sh
 set -u
@@ -17,7 +15,6 @@ SEG_ITERS=${SEG_ITERS:-150}
 CKPT=${CKPT:-}   # empty: the example picks dialect-specific defaults
 LOG=${LOG:-}
 EXTRA_FLAGS=${EXTRA_FLAGS:-}   # e.g. --llama
-FLAG=/tmp/battery3/WINDOW_OPEN
 export JAX_PLATFORMS=cpu
 export XLA_FLAGS=--xla_force_host_platform_device_count=8
 
@@ -25,7 +22,6 @@ start=$(date +%s)
 seg=0
 kills=0
 while [ $(( $(date +%s) - start )) -lt $(( TARGET_MIN * 60 )) ]; do
-    while [ -e "$FLAG" ]; do sleep 30; done   # yield to the TPU window
     seg=$((seg + 1))
     python -m bigdl_tpu.examples.convergence_docs_corpus \
         --iters "$SEG_ITERS" \
