@@ -38,8 +38,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, Iterator, Optional
+
+from ..telemetry.tracer import default_tracer
 
 __all__ = ["DevicePrefetcher", "InlineFeed", "make_feed"]
 
@@ -70,13 +71,14 @@ class InlineFeed:
                  transform: Optional[Callable] = None):
         self._it = data_iter
         self._transform = transform
+        self._clock = default_tracer().clock
 
     def get(self):
-        t0 = time.perf_counter()
+        t0 = self._clock()
         batch = next(self._it)
         item = ((batch, *self._transform(batch)) if self._transform
                 else (batch,))
-        return item, time.perf_counter() - t0
+        return item, self._clock() - t0
 
     def reset(self, data_iter: Iterator, epoch_size=None,
               start_records: int = 0):
@@ -115,6 +117,7 @@ class DevicePrefetcher:
                  name: str = "bigdl-infeed"):
         self.depth = max(1, int(depth))
         self._transform = transform
+        self._tracer = default_tracer()
         self._q: queue.Queue = queue.Queue(maxsize=self.depth)
         self._stop = threading.Event()
         self._lock = threading.Lock()
@@ -145,17 +148,15 @@ class DevicePrefetcher:
                 if budget is not None and fetched >= budget:
                     break  # epoch budget met: park until reset
                 try:
-                    batch = next(it)
+                    # next() + transform: the producer's own work
+                    with self._tracer.span("feed.produce", "other"):
+                        batch = next(it)
+                        item = ((batch, *self._transform(batch))
+                                if self._transform else (batch,))
                 except BaseException as e:  # noqa: BLE001 — re-raised
                     # in get() on the training thread (StopIteration
                     # included: a finite iterator ending early surfaces
                     # exactly where a synchronous next() would have)
-                    self._put(_Failure(e))
-                    break
-                try:
-                    item = ((batch, *self._transform(batch))
-                            if self._transform else (batch,))
-                except BaseException as e:  # noqa: BLE001
                     self._put(_Failure(e))
                     break
                 size = getattr(batch, "size", None)
@@ -172,13 +173,21 @@ class DevicePrefetcher:
 
     def _put(self, item) -> bool:
         """Bounded put that stays responsive to close(): returns False
-        when the feed was closed while waiting for queue room."""
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
+        when the feed was closed while waiting for queue room.  A wait
+        on a FULL queue is the ``feed.blocked`` span: the proof that
+        the driver never lacked data, not a cost."""
+        try:
+            self._q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with self._tracer.span("feed.blocked", "idle"):
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     # -- consumer --------------------------------------------------------
@@ -187,7 +196,8 @@ class DevicePrefetcher:
         when the buffer was actually empty — the honest ``data_stall``
         figure.  Re-raises any producer-side exception here, on the
         training thread."""
-        t0 = time.perf_counter()
+        clock = self._tracer.clock
+        t0 = clock()
         try:
             item = self._q.get_nowait()
             stall = 0.0
@@ -196,7 +206,7 @@ class DevicePrefetcher:
                    "infeed get() served from a non-empty buffer")
         except queue.Empty:
             item = self._q.get()
-            stall = time.perf_counter() - t0
+            stall = clock() - t0
             self.misses += 1
             _count("bigdl_infeed_buffer_misses_total",
                    "infeed get() blocked on an empty buffer "

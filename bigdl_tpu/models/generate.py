@@ -398,6 +398,11 @@ def make_generate(model, max_len: Optional[int] = None,
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, T_max, kv_int8=_kv_int8(kv_dtype))
 
+    # device scopes (``jax.named_scope``): metadata on the HLO
+    # operations only — ``generate.cast_params`` / ``.prefill`` /
+    # ``.decode_step`` / ``.sample`` name what a device trace shows as
+    # fusion.NNN; the computation is the same with or without them
+    @jax.named_scope("generate.sample")
     def _sample(logits, temperature, top_k, top_p, key):
         greedy = jnp.argmax(logits, axis=-1)
         if top_k:
@@ -423,7 +428,8 @@ def make_generate(model, max_len: Optional[int] = None,
     @partial(jax.jit, static_argnums=(2, 5))
     def _run(p, prompt, max_new, key, temperature, top_k, top_p,
              eos, pad):
-        pc = _cast_floats(p, compute_dtype) if compute_dtype else p
+        with jax.named_scope("generate.cast_params"):
+            pc = _cast_floats(p, compute_dtype) if compute_dtype else p
         B, T0 = prompt.shape
         if T0 + max_new > T_max:
             raise ValueError(
@@ -431,9 +437,11 @@ def make_generate(model, max_len: Optional[int] = None,
         dt = (compute_dtype
               or jax.tree_util.tree_leaves(pc)[0].dtype)
 
-        h, caches = prefill(pc, prompt, dt)
+        with jax.named_scope("generate.prefill"):
+            h, caches = prefill(pc, prompt, dt)
+            logits = logits_last(pc, h)
         key, sub = jax.random.split(key)
-        nxt = (_sample(logits_last(pc, h), temperature, top_k, top_p,
+        nxt = (_sample(logits, temperature, top_k, top_p,
                        sub) + 1)  # 1-based ids
         # eos==0 disables early stop (ids are 1-based, 0 never matches).
         # Static shapes throughout: finished rows keep decoding but
@@ -447,11 +455,12 @@ def make_generate(model, max_len: Optional[int] = None,
 
         def one_token(carry, _):
             caches, ids, pos, key, done = carry
-            tok = lax.dynamic_slice(ids, (0, pos), (B, 1))
-            h, new_caches = decode_token(pc, tok, caches, pos)
+            with jax.named_scope("generate.decode_step"):
+                tok = lax.dynamic_slice(ids, (0, pos), (B, 1))
+                h, new_caches = decode_token(pc, tok, caches, pos)
+                logits = logits_last(pc, h)
             key, sub = jax.random.split(key)
-            nxt = (_sample(logits_last(pc, h), temperature, top_k,
-                           top_p, sub) + 1)
+            nxt = (_sample(logits, temperature, top_k, top_p, sub) + 1)
             nxt = jnp.where(done, pad, nxt)
             done = done | ((nxt == eos) & (eos > 0))
             ids = lax.dynamic_update_slice(

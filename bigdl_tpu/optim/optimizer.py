@@ -641,7 +641,7 @@ class Optimizer:
 
     def _tm_step(self, state, train_time: float, data_time: float,
                  records: int, compiled: bool = False,
-                 phase_split=None, skipped: bool = False):
+                 phase_split=None, skipped: bool = False, span=None):
         """One driver iteration for the telemetry spine: data-wait +
         step time into the registry histograms and goodput ledger,
         categorized spans into the tracer (``compiled=True`` marks the
@@ -656,7 +656,7 @@ class Optimizer:
             tm.on_data_wait(data_time, step=step)
         tm.on_step(train_time, records=records, step=step,
                    compiled=compiled, phase_split=phase_split,
-                   skipped=skipped)
+                   skipped=skipped, span=span)
 
     def _tm_finish(self, state):
         """End of a training loop: drop the host's snapshot file when a
@@ -1250,13 +1250,25 @@ class Optimizer:
             log.debug("plan param-bytes accounting failed", exc_info=True)
 
     def _plan_loop(self, mesh) -> AbstractModule:
+        """One entry of ``optimize()`` (one retry attempt), under the
+        ``train.optimize`` span of the process tracer."""
+        from ..telemetry.tracer import default_tracer
+
+        tr = default_tracer()
+        with tr.span("train.optimize", "other") as span:
+            return self._plan_attempt(mesh, tr, span)
+
+    def _plan_attempt(self, mesh, tr, optimize_span) -> AbstractModule:
         from ._sharding_utils import maskable, pad_batch, round_up
         from .optim_method import OptimMethod  # noqa: F401 (doc link)
 
+        clock = tr.clock  # the one clock of every span site below
         self._tm_attempt_begin()
         model, optim = self.model, self.optim_method
         model.training()
         engine = self._plan_engine(mesh)
+        optimize_span.set(engine_cache_hit=bool(
+            getattr(self, "_engine_cache_hit", False)))
         # relaxed synchrony (parallel/plan.py "Synchrony"): restore
         # the exact per-replica stacks for bitwise resume — unless a
         # membership change forced an averaging round, in which case
@@ -1300,7 +1312,7 @@ class Optimizer:
         # batch (the restored order makes the skipped prefix identical)
         records_this_epoch = self._consume_resume_cursor(data_iter,
                                                          epoch_size)
-        wall_start = time.time()
+        wall_start = clock()
 
         profile_interval = int(get_property(
             "bigdl.metrics.profileInterval", 10))
@@ -1321,236 +1333,262 @@ class Optimizer:
         warm_reentry = not first_step
         try:
             while not self.end_when(state):
-                state["epoch_finished"] = False
-                self._elastic_step_start(state)
-                item, stall_time = feed.get()
-                if warm_reentry:
-                    stall_time = 0.0
-                    warm_reentry = False
-                batch, x, y = item
-                n_records = batch.size()
-                mask_kw = {}
-                if n_records % pad_multiple != 0:
-                    # trailing partial batch: pad whole records to the
-                    # mesh multiple and train the real ones via the
-                    # per-record weight mask — every record of an epoch
-                    # trains exactly once at static shape, on EVERY
-                    # mesh shape (reference DataSet.scala:255-288)
-                    if not maskable(y, n_records):
-                        raise ValueError(
-                            "training got a trailing partial batch of "
-                            f"{n_records} records but the targets are "
-                            "not record-leading arrays for pad-and-"
-                            "mask; size the dataset to a multiple of "
-                            f"{pad_multiple}")
-                    x, y, w = pad_batch(x, y, n_records,
-                                        round_up(n_records, pad_multiple))
-                    mask_kw = {"w": w, "total_w": float(n_records)}
-                if n_seq > 1:
-                    bad = [a.shape for a in jax.tree_util.tree_leaves(x)
-                           if getattr(a, "ndim", 0) > 1
-                           and a.shape[1] % n_seq != 0]
-                    if bad:
-                        raise ValueError(
-                            f"sequence dim of inputs {bad} must be "
-                            f"divisible by the mesh's seq-axis size "
-                            f"{n_seq}; pad sequences to a multiple")
-                h2d_time = 0.0
-                if multi_device:
-                    # pre-place the batch at the step's input sharding
-                    # (h2d attributed separately from the data stall)
-                    t_h2d0 = time.time()
-                    x = engine.place_batch(x)
-                    y = engine.place_batch(y)
-                    if mask_kw:
-                        mask_kw["w"] = engine.place_batch(mask_kw["w"])
-                    h2d_time = time.time() - t_h2d0
-                    if self.telemetry is not None and h2d_time > 0:
-                        self.telemetry.on_host_to_device(
-                            h2d_time, step=state["neval"])
-                infeed_time = stall_time + h2d_time
+                with tr.span("train.iteration", "step",
+                             step=state["neval"]) as it_span:
+                    state["epoch_finished"] = False
+                    self._elastic_step_start(state)
+                    with tr.span("train.data_wait", "data_wait") as sp:
+                        item, stall_time = feed.get()
+                        sp.set(hit=stall_time == 0.0)
+                    if warm_reentry:
+                        stall_time = 0.0
+                        warm_reentry = False
+                    batch, x, y = item
+                    n_records = batch.size()
+                    mask_kw = {}
+                    if n_records % pad_multiple != 0:
+                        # trailing partial batch: pad whole records to the
+                        # mesh multiple and train the real ones via the
+                        # per-record weight mask — every record of an epoch
+                        # trains exactly once at static shape, on EVERY
+                        # mesh shape (reference DataSet.scala:255-288)
+                        if not maskable(y, n_records):
+                            raise ValueError(
+                                "training got a trailing partial batch of "
+                                f"{n_records} records but the targets are "
+                                "not record-leading arrays for pad-and-"
+                                "mask; size the dataset to a multiple of "
+                                f"{pad_multiple}")
+                        x, y, w = pad_batch(x, y, n_records,
+                                            round_up(n_records, pad_multiple))
+                        mask_kw = {"w": w, "total_w": float(n_records)}
+                    if n_seq > 1:
+                        bad = [a.shape for a in jax.tree_util.tree_leaves(x)
+                               if getattr(a, "ndim", 0) > 1
+                               and a.shape[1] % n_seq != 0]
+                        if bad:
+                            raise ValueError(
+                                f"sequence dim of inputs {bad} must be "
+                                f"divisible by the mesh's seq-axis size "
+                                f"{n_seq}; pad sequences to a multiple")
+                    h2d_time = 0.0
+                    if multi_device:
+                        # pre-place the batch at the step's input sharding
+                        # (h2d attributed separately from the data stall)
+                        t_h2d0 = clock()
+                        with tr.span("train.place_batch",
+                                     "host_to_device"):
+                            x = engine.place_batch(x)
+                            y = engine.place_batch(y)
+                            if mask_kw:
+                                mask_kw["w"] = engine.place_batch(
+                                    mask_kw["w"])
+                        h2d_time = clock() - t_h2d0
+                        if self.telemetry is not None and h2d_time > 0:
+                            self.telemetry.on_host_to_device(
+                                h2d_time, step=state["neval"])
+                    infeed_time = stall_time + h2d_time
 
-                # profile past the compile iteration so timings are
-                # warm; single-device meshes skip (nothing to split)
-                profiled = (multi_device and profile_interval > 0
-                            and state["neval"] > 1
-                            and state["neval"] % profile_interval == 0
-                            and not mask_kw)
+                    # profile past the compile iteration so timings are
+                    # warm; single-device meshes skip (nothing to split)
+                    profiled = (multi_device and profile_interval > 0
+                                and state["neval"] > 1
+                                and state["neval"] % profile_interval == 0
+                                and not mask_kw)
 
-                # relaxed synchrony: advance the step-phase counters
-                # and fire this iteration's averaging flags (host-side
-                # — the flags are traced args, so the program never
-                # recompiles; an elastic relax-before-evict verdict
-                # widens the effective period here)
-                sync_kw = {}
-                if engine.has_relaxed:
-                    vals = [0] * engine.n_flags
-                    if sync_phases is not None:
-                        relax_f = (getattr(self.elastic,
-                                           "sync_relax_factor",
-                                           lambda: 1.0)()
-                                   if self.elastic is not None else 1.0)
-                        for gi, cad in enumerate(
-                                engine.periodic_cadences):
-                            sync_phases[gi] += 1
-                            eff = max(1, int(round(cad * relax_f)))
-                            if sync_phases[gi] >= eff:
-                                vals[gi] = 1
-                                sync_phases[gi] = 0
-                        state["sync_phase"] = list(sync_phases)
-                    sync_kw = {"sync_flags": np.asarray(vals, np.int32),
-                               "sync_state": sync_state}
-
-                lr = optim.get_current_lr()
-                t0 = time.time()
-                if first_step and not mask_kw \
-                        and self.telemetry is not None:
-                    # XLA cost-model accounting for the exact program
-                    # about to compile (inside the first step's timed
-                    # window, ledgered as COMPILE; the constant key
-                    # never consumes the checkpointed stream).  Wire
-                    # bytes come from the PLAN now — tensor-parallel
-                    # and FSDP traffic is counted per leaf, not assumed
-                    # to be a data-parallel ring.
-                    analyze_extra = ()
+                    # relaxed synchrony: advance the step-phase counters
+                    # and fire this iteration's averaging flags (host-side
+                    # — the flags are traced args, so the program never
+                    # recompiles; an elastic relax-before-evict verdict
+                    # widens the effective period here)
+                    sync_kw = {}
                     if engine.has_relaxed:
-                        analyze_extra = (
-                            jnp.zeros((engine.n_flags,), jnp.int32),
-                            sync_state)
-                    self._tm_analyze(
-                        engine.jitted_for(x, y, False), params, slots,
-                        buffers, jnp.float32(lr), jax.random.PRNGKey(0),
-                        x, y, *analyze_extra,
-                        collective_bytes=engine.collective_bytes,
-                        sparse_bytes_saved=engine.sparse_bytes_saved,
-                        sync_bytes_saved=engine.sync_bytes_saved)
+                        vals = [0] * engine.n_flags
+                        if sync_phases is not None:
+                            relax_f = (getattr(self.elastic,
+                                               "sync_relax_factor",
+                                               lambda: 1.0)()
+                                       if self.elastic is not None else 1.0)
+                            for gi, cad in enumerate(
+                                    engine.periodic_cadences):
+                                sync_phases[gi] += 1
+                                eff = max(1, int(round(cad * relax_f)))
+                                if sync_phases[gi] >= eff:
+                                    vals[gi] = 1
+                                    sync_phases[gi] = 0
+                            state["sync_phase"] = list(sync_phases)
+                        sync_kw = {"sync_flags": np.asarray(vals, np.int32),
+                                   "sync_state": sync_state}
 
-                def dispatch():
-                    return engine.step(params, slots, buffers, lr, x, y,
-                                       rng=next_jax_key(), **sync_kw,
-                                       **mask_kw)
+                    lr = optim.get_current_lr()
+                    t0 = clock()
+                    if first_step and not mask_kw \
+                            and self.telemetry is not None:
+                        # XLA cost-model accounting for the exact program
+                        # about to compile (inside the first step's timed
+                        # window, ledgered as COMPILE; the constant key
+                        # never consumes the checkpointed stream).  Wire
+                        # bytes come from the PLAN now — tensor-parallel
+                        # and FSDP traffic is counted per leaf, not assumed
+                        # to be a data-parallel ring.
+                        analyze_extra = ()
+                        if engine.has_relaxed:
+                            analyze_extra = (
+                                jnp.zeros((engine.n_flags,), jnp.int32),
+                                sync_state)
+                        self._tm_analyze(
+                            engine.jitted_for(x, y, False), params, slots,
+                            buffers, jnp.float32(lr), jax.random.PRNGKey(0),
+                            x, y, *analyze_extra,
+                            collective_bytes=engine.collective_bytes,
+                            sparse_bytes_saved=engine.sparse_bytes_saved,
+                            sync_bytes_saved=engine.sync_bytes_saved)
 
-                trace_split = None
-                if profiled:
-                    # phase split measured from the profiler trace of
-                    # THIS step's execution: collective vs compute
-                    # device time (reference Metrics.scala:103-121).
-                    # The loss fetch (execution barrier) happens inside
-                    # the trace so device events are captured.
-                    from .profiling import trace_phase_split
+                    def dispatch():
+                        # enqueue only: the step runs behind the return
+                        with tr.span("train.dispatch",
+                                     "compile" if first_step
+                                     else "dispatch",
+                                     compiled=first_step):
+                            return engine.step(
+                                params, slots, buffers, lr, x, y,
+                                rng=next_jax_key(), **sync_kw, **mask_kw)
 
-                    step_out = []
+                    def fetch_loss(out):
+                        # the device wait; the feed's producer keeps
+                        # prefetching meanwhile
+                        with tr.span("train.loss_fetch", "device_wait"):
+                            return float(out[0])
 
-                    def run_traced():
-                        tr = time.time()
-                        out = dispatch()
-                        loss_v = float(out[0])
-                        step_out.append((out, loss_v, time.time() - tr))
-                    trace_split = trace_phase_split(run_traced)
-                    out, loss, train_time = step_out[0]
-                else:
-                    out = self._elastic_dispatch(dispatch, state)
-                    loss = float(out[0])  # device sync; the feed's
-                    #                       producer keeps prefetching
-                    train_time = time.time() - t0
-                _, params, slots, buffers, step_ok, gnorm = out[:6]
-                if engine.has_relaxed:
-                    sync_state = out[6]
-                skipped = not bool(step_ok)
-                self._tm_step(state, train_time, stall_time, n_records,
-                              compiled=first_step,
-                              phase_split=trace_split, skipped=skipped)
-                first_step = False
-                self._check_loss_anomaly(loss, skipped)
-                self._health_step(state, loss, train_time)
-                params = self._maybe_corrupt_params(state, params)
-                self._record_fingerprint(state, loss, float(gnorm),
-                                         (x, y), lambda: params,
-                                         skipped=skipped)
-                self._integrity_step(state, lambda: params)
+                    trace_split = None
+                    if profiled:
+                        # phase split measured from the profiler trace of
+                        # THIS step's execution: collective vs compute
+                        # device time (reference Metrics.scala:103-121).
+                        # The loss fetch (execution barrier) happens inside
+                        # the trace so device events are captured.
+                        from .profiling import trace_phase_split
 
-                records_this_epoch += n_records
-                state["records_this_epoch"] = records_this_epoch
-                state["loss"] = loss
-                # metric-name contract (reference
-                # DistriOptimizer.scala:146-151): profiled iterations
-                # pin the compute/aggregate split from the trace; in
-                # between, the last measured ratio attributes the fused
-                # step's wall time
-                if profiled and trace_split is not None:
-                    c_s, agg_s = trace_split
-                    compute_ratio = c_s / max(c_s + agg_s, 1e-12)
-                    self.phase_source = "trace"
-                    self.phase_split = trace_split
-                if compute_ratio is not None:
-                    self.metrics.add("computing time average",
-                                     train_time * compute_ratio)
-                    self.metrics.add("aggregate gradient time",
-                                     train_time * (1.0 - compute_ratio))
-                else:
-                    self.metrics.add("computing time average",
-                                     train_time)
-                    self.metrics.add("aggregate gradient time", 0.0)
-                self.metrics.add("get weights average", infeed_time)
-                self.metrics.add("data fetch time", stall_time)
-                log.info(
-                    "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
-                    "Train %d in %.4f seconds. Throughput is %.1f "
-                    "records/second. Loss is %.5f.",
-                    state["epoch"], records_this_epoch, epoch_size,
-                    state["neval"], time.time() - wall_start, n_records,
-                    train_time + infeed_time,
-                    n_records / max(train_time + infeed_time, 1e-9),
-                    loss)
+                        step_out = []
 
-                if self.train_summary is not None:
-                    self.train_summary.add_scalar("Loss", loss,
-                                                  state["neval"])
-                    self.train_summary.add_scalar(
-                        "Throughput",
-                        n_records / max(train_time + infeed_time, 1e-9),
-                        state["neval"])
-                    if "LearningRate" in getattr(self.train_summary,
-                                                 "triggers", {}):
-                        self.train_summary.add_scalar(
-                            "LearningRate", lr, state["neval"])
-                    if self.gradient_guard:
-                        self.train_summary.add_scalar(
-                            "SkippedSteps", float(self.skipped_steps),
-                            state["neval"])
+                        def run_traced():
+                            t_run = clock()
+                            out = dispatch()
+                            loss_v = fetch_loss(out)
+                            step_out.append((out, loss_v,
+                                             clock() - t_run))
+                        trace_split = trace_phase_split(run_traced)
+                        out, loss, train_time = step_out[0]
+                    else:
+                        out = self._elastic_dispatch(dispatch, state)
+                        loss = fetch_loss(out)
+                        train_time = clock() - t0
+                    # everything from here to the end of the iteration is
+                    # host work the device does not wait for — unless it
+                    # has nothing queued
+                    with tr.span("train.bookkeeping", "other"):
+                        _, params, slots, buffers, step_ok, gnorm = out[:6]
+                        if engine.has_relaxed:
+                            sync_state = out[6]
+                        skipped = not bool(step_ok)
+                        self._tm_step(state, train_time, stall_time, n_records,
+                                      compiled=first_step,
+                                      phase_split=trace_split, skipped=skipped,
+                                      span=it_span)
+                        first_step = False
+                        self._check_loss_anomaly(loss, skipped)
+                        self._health_step(state, loss, train_time)
+                        params = self._maybe_corrupt_params(state, params)
+                        self._record_fingerprint(state, loss, float(gnorm),
+                                                 (x, y), lambda: params,
+                                                 skipped=skipped)
+                        self._integrity_step(state, lambda: params)
 
-                state["neval"] += 1
-                optim.state = state
+                        records_this_epoch += n_records
+                        state["records_this_epoch"] = records_this_epoch
+                        state["loss"] = loss
+                        # metric-name contract (reference
+                        # DistriOptimizer.scala:146-151): profiled iterations
+                        # pin the compute/aggregate split from the trace; in
+                        # between, the last measured ratio attributes the fused
+                        # step's wall time
+                        if profiled and trace_split is not None:
+                            c_s, agg_s = trace_split
+                            compute_ratio = c_s / max(c_s + agg_s, 1e-12)
+                            self.phase_source = "trace"
+                            self.phase_split = trace_split
+                        if compute_ratio is not None:
+                            self.metrics.add("computing time average",
+                                             train_time * compute_ratio)
+                            self.metrics.add("aggregate gradient time",
+                                             train_time * (1.0 - compute_ratio))
+                        else:
+                            self.metrics.add("computing time average",
+                                             train_time)
+                            self.metrics.add("aggregate gradient time", 0.0)
+                        self.metrics.add("get weights average", infeed_time)
+                        self.metrics.add("data fetch time", stall_time)
+                        log.info(
+                            "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
+                            "Train %d in %.4f seconds. Throughput is %.1f "
+                            "records/second. Loss is %.5f.",
+                            state["epoch"], records_this_epoch, epoch_size,
+                            state["neval"], clock() - wall_start, n_records,
+                            train_time + infeed_time,
+                            n_records / max(train_time + infeed_time, 1e-9),
+                            loss)
 
-                if records_this_epoch >= epoch_size:
-                    state["epoch"] += 1
-                    state["epoch_finished"] = True
-                    records_this_epoch = 0
-                    state["records_this_epoch"] = 0
-                    # the producer met its epoch budget and is parked —
-                    # the shuffle cannot race a fetch; reset re-arms
-                    # the same producer thread on the fresh iterator
-                    self.dataset.shuffle()
-                    data_iter = self.dataset.data(train=True)
-                    feed.reset(data_iter, epoch_size, 0)
+                        if self.train_summary is not None:
+                            self.train_summary.add_scalar("Loss", loss,
+                                                          state["neval"])
+                            self.train_summary.add_scalar(
+                                "Throughput",
+                                n_records / max(train_time + infeed_time, 1e-9),
+                                state["neval"])
+                            if "LearningRate" in getattr(self.train_summary,
+                                                         "triggers", {}):
+                                self.train_summary.add_scalar(
+                                    "LearningRate", lr, state["neval"])
+                            if self.gradient_guard:
+                                self.train_summary.add_scalar(
+                                    "SkippedSteps", float(self.skipped_steps),
+                                    state["neval"])
 
-                # evaluate each trigger exactly once per iteration
-                # (stateful user triggers must not see a second call)
-                do_validate = self._should(self.validation_trigger, state)
-                do_checkpoint = self._should(self.checkpoint_trigger,
-                                             state)
-                if do_validate:
-                    self._plan_validate(engine, state, params, buffers,
-                                        eval_cache)
-                if do_checkpoint or self._preempted():
-                    self._plan_checkpoint(engine, state, params, slots,
-                                          buffers, sync_state)
-                if self._preempted():
-                    self._drain_checkpoints()
-                    log.warning("preemption requested — checkpointed at "
-                                "iteration %d; exiting resumable",
-                                state["neval"] - 1)
-                    break
+                        state["neval"] += 1
+                        optim.state = state
+
+                        if records_this_epoch >= epoch_size:
+                            state["epoch"] += 1
+                            state["epoch_finished"] = True
+                            records_this_epoch = 0
+                            state["records_this_epoch"] = 0
+                            # the producer met its epoch budget and is parked —
+                            # the shuffle cannot race a fetch; reset re-arms
+                            # the same producer thread on the fresh iterator
+                            self.dataset.shuffle()
+                            data_iter = self.dataset.data(train=True)
+                            feed.reset(data_iter, epoch_size, 0)
+
+                        # evaluate each trigger exactly once per iteration
+                        # (stateful user triggers must not see a second call)
+                        do_validate = self._should(self.validation_trigger, state)
+                        do_checkpoint = self._should(self.checkpoint_trigger,
+                                                     state)
+                        if do_validate:
+                            with tr.span("train.validation", "other"):
+                                self._plan_validate(engine, state, params,
+                                                    buffers, eval_cache)
+                        if do_checkpoint or self._preempted():
+                            with tr.span("train.checkpoint", "checkpoint"):
+                                self._plan_checkpoint(engine, state, params,
+                                                      slots, buffers,
+                                                      sync_state)
+                        if self._preempted():
+                            self._drain_checkpoints()
+                            log.warning("preemption requested — checkpointed at "
+                                        "iteration %d; exiting resumable",
+                                        state["neval"] - 1)
+                            break
         finally:
             feed.close()
 

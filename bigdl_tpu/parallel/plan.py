@@ -938,6 +938,12 @@ class CompiledPlanStep:
         absent or shape-mismatched (an elastic shrink changed n_data),
         every replica seeds from the model's averaged params — the
         forced averaging round a membership change demands."""
+        from ..telemetry.tracer import default_tracer
+
+        with default_tracer().span("plan.init_state", "state_sync"):
+            return self._init_state(sync_resume)
+
+    def _init_state(self, sync_resume):
         from ..optim.optimizer import _resume_slots
 
         resume = sync_resume or {}
@@ -1084,6 +1090,12 @@ class CompiledPlanStep:
         out_specs make every output a global array; relaxed-synchrony
         replica stacks collapse to their mean, the local-SGD final
         model)."""
+        from ..telemetry.tracer import default_tracer
+
+        with default_tracer().span("plan.sync_to_model", "state_sync"):
+            self._sync_to_model(params, slots, buffers)
+
+    def _sync_to_model(self, params, slots, buffers):
         if self.kind == "packed":
             from .pipeline import unpack_params
 

@@ -1154,13 +1154,14 @@ class SimulatedHost:
         self.deaths = 0
         self._acked = -1
         # every fake member carries its own telemetry bundle (private
-        # registry — fake hosts must not pollute the process default)
-        # and publishes payloads like a real host would, so a
-        # single-process simulation exercises the leader's merge path
-        from ..telemetry import MetricsRegistry, Telemetry
+        # registry and tracer — fake hosts stand for other processes
+        # and must not pollute this one's defaults) and publishes
+        # payloads like a real host would, so a single-process
+        # simulation exercises the leader's merge path
+        from ..telemetry import MetricsRegistry, Telemetry, Tracer
 
         self.telemetry = Telemetry(registry=MetricsRegistry(),
-                                   host=str(host))
+                                   tracer=Tracer(), host=str(host))
         self._tm_publish_every = 5
         self._tm_last: Optional[float] = None
         self._stop = threading.Event()

@@ -87,7 +87,7 @@ class ReplicaTraceSink:
                  publisher: Optional[BackgroundPublisher] = None,
                  capacity: int = 4096, max_traces: int = 512,
                  eager_publish: bool = True,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.perf_counter):
         self.host = str(host)
         self.transport = transport
         #: eager: publish the fragment the moment the request resolves

@@ -36,9 +36,9 @@ batch + rollback — see :mod:`.swap`).
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import threading
-import time
 from typing import Any, Optional
 
 import jax
@@ -49,6 +49,7 @@ from ..resilience import faults as _faults
 from ..resilience.guards import tree_finite
 from ..resilience.preemption import PreemptionHandler
 from ..resilience.retry import RetryPolicy
+from ..telemetry.tracer import default_tracer, profiler_session_live
 from .batcher import MicroBatcher
 from .breaker import OPEN, PROBE, REJECT, CircuitBreaker
 from .metrics import ServingMetrics
@@ -178,8 +179,22 @@ class InferenceServer:
         #: batch formation, compiled-step execution, KV-page gathers
         #: and swap/canary windows record as children of the request's
         #: remote span and publish as trace fragments over the fleet
-        #: KV transport.  None = zero tracing overhead.
+        #: KV transport.  None = nothing is published.
         self.trace_sink = trace_sink
+        #: the process tracer: the worker's ``serve.*`` spans and every
+        #: request's queue / batch-wait / execute records land in its
+        #: ring, sink or no sink.  Its clock is the server's one clock
+        #: (submission stamps, deadlines, spans).
+        self._tracer = default_tracer()
+        self._clock = self._tracer.clock
+        self._request_ids = itertools.count(1)
+        self._batch_ids = itertools.count(1)
+        self._idle_span = None  # the worker's open ``serve.idle``
+        #: static batches whose per-request ring records are still owed
+        #: (see ``_flush_owed``)
+        self._owed: list = []
+        self._idle_since = 0.0
+        self._idle_in_session = False
         if role != "both" and kv_pool is None:
             raise ValueError(
                 f"role {role!r} requires a kv_pool (the prefill/"
@@ -320,7 +335,8 @@ class InferenceServer:
 
     # ------------------------------------------------------------ admission
     def _admit(self, req: Request) -> ServeFuture:
-        now = time.monotonic()
+        now = self._clock()
+        req.request_id = next(self._request_ids)
         if not self._started or self._draining or self._should_drain():
             self._resolve(req, ServeResult(
                 Status.UNAVAILABLE,
@@ -376,9 +392,12 @@ class InferenceServer:
 
     def _trace(self, req: Request, name: str, category: str,
                start: float, duration: float, **args):
-        """Record one request-phase span for a traced request (no-op
-        without a sink or context — the untraced hot path pays one
-        None check)."""
+        """Record one request-phase span: into the process tracer's
+        ring always (retroactive — the interval is only known once it
+        is over), and into the fleet sink for a request that carries a
+        trace context."""
+        self._tracer.record(name, category, start, duration,
+                            request_id=req.request_id, **args)
         if self.trace_sink is not None and req.trace is not None:
             self.trace_sink.record(req.trace, name, category, start,
                                    duration, **args)
@@ -399,7 +418,7 @@ class InferenceServer:
             raise ValueError(
                 f"feature shape {feature.shape} does not match this "
                 f"server's pinned shape {self._feature_shape}")
-        now = time.monotonic()
+        now = self._clock()
         deadline = self._deadline(deadline_s, now)
         fast = self._fast_fail_expired(deadline, now)
         if fast is not None:
@@ -425,7 +444,7 @@ class InferenceServer:
                              f"{prompt.shape}")
         if max_new < 1:
             raise ValueError(f"max_new must be >= 1, got {max_new}")
-        now = time.monotonic()
+        now = self._clock()
         deadline = self._deadline(deadline_s, now)
         fast = self._fast_fail_expired(deadline, now)
         if fast is not None:
@@ -456,7 +475,7 @@ class InferenceServer:
         if prompt.ndim != 1:
             raise ValueError(f"prompt_ids must be 1-D, got shape "
                              f"{prompt.shape}")
-        now = time.monotonic()
+        now = self._clock()
         deadline = self._deadline(deadline_s, now)
         fast = self._fast_fail_expired(deadline, now)
         if fast is not None:
@@ -481,7 +500,7 @@ class InferenceServer:
         self._require_pool("submit_decode")
         if max_new < 1:
             raise ValueError(f"max_new must be >= 1, got {max_new}")
-        now = time.monotonic()
+        now = self._clock()
         deadline = self._deadline(deadline_s, now)
         fast = self._fast_fail_expired(deadline, now)
         if fast is not None:
@@ -522,14 +541,14 @@ class InferenceServer:
         """
         if (params is None) == (path is None):
             raise ValueError("pass exactly one of params/path")
-        t_swap = time.monotonic()
+        t_swap = self._clock()
 
         def note_swap(outcome: str):
             # traced requests overlapping this window see it as a
             # swap_window span in their stitched timeline
             if self.trace_sink is not None:
                 self.trace_sink.record_swap_window(
-                    t_swap, time.monotonic() - t_swap, outcome)
+                    t_swap, self._clock() - t_swap, outcome)
 
         try:
             if path is not None:
@@ -599,7 +618,7 @@ class InferenceServer:
         return tenant if tenant is not None else self.model_name
 
     def _resolve(self, req: Request, result: ServeResult):
-        now = time.monotonic()
+        now = self._clock()
         result.latency_s = now - req.submitted_at
         self.metrics.record(result.status, result.latency_s,
                             result.queued_s,
@@ -621,19 +640,47 @@ class InferenceServer:
         """Block briefly for the first request, then coalesce whatever
         arrives inside the batch window (continuous micro-batching:
         the window bounds added latency, the ladder bounds compiles)."""
-        first = self._queue.get(timeout=self._poll_s)
+        # a busy worker finds the next request waiting: no idle stretch
+        first = self._queue.get_nowait() if self._idle_span is None \
+            else None
         if first is None:
-            return []
-        batch = [first]
-        window_end = time.monotonic() + self._batch_window_s
-        while len(batch) < limit:
-            remaining = window_end - time.monotonic()
-            nxt = self._queue.get_nowait() if remaining <= 0 else \
-                self._queue.get(timeout=remaining)
-            if nxt is None:
-                break
-            batch.append(nxt)
+            if self._idle_span is None:
+                # ``serve.idle``: no request in hand.  One span covers a
+                # run of empty polls; it is renewed once a second, and
+                # at the first poll after a profiler session started or
+                # ended (a span that was open before the session is not
+                # in its xplane, and the quiet stretch at its start
+                # would go unexplained).
+                self._idle_span = self._tracer.span("serve.idle", "idle")
+                self._idle_since = self._clock()
+                self._idle_in_session = profiler_session_live()
+            first = self._queue.get(timeout=self._poll_s)
+            if first is not None \
+                    or self._clock() - self._idle_since >= 1.0 \
+                    or profiler_session_live() != self._idle_in_session:
+                self._close_idle()
+            if first is None:
+                self._flush_owed()
+                return []
+        with self._tracer.span("serve.gather", "batch") as sp:
+            first.dequeued_at = self._clock()
+            batch = [first]
+            window_end = first.dequeued_at + self._batch_window_s
+            while len(batch) < limit:
+                remaining = window_end - self._clock()
+                nxt = self._queue.get_nowait() if remaining <= 0 else \
+                    self._queue.get(timeout=remaining)
+                if nxt is None:
+                    break
+                nxt.dequeued_at = self._clock()
+                batch.append(nxt)
+            sp.set(n=len(batch))
         return batch
+
+    def _close_idle(self):
+        span, self._idle_span = self._idle_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
 
     def _run(self):
         try:
@@ -648,7 +695,7 @@ class InferenceServer:
                 if not batch:
                     continue
                 # expired-in-queue requests resolve typed, pre-device
-                now = time.monotonic()
+                now = self._clock()
                 live = []
                 for r in batch:
                     if r.expired(now):
@@ -677,6 +724,8 @@ class InferenceServer:
         finally:
             # hard stop (or a worker crash — nothing may hang): every
             # queued request resolves
+            self._close_idle()
+            self._flush_owed()
             leftover = self._queue.drain_all()
             for r in leftover:
                 self._resolve(r, ServeResult(
@@ -707,38 +756,55 @@ class InferenceServer:
         if kind in ("prefill", "decode") or (
                 kind == "generate" and self.kv_pool is not None):
             return self._run_paged_group(kind, reqs)
-        t_batch = time.monotonic()
+        tr = self._tracer
+        batch_id = next(self._batch_ids)
+        with tr.span("serve.batch", "batch", batch_id=batch_id,
+                     kind=kind, rows=len(reqs)) as batch_span:
+            self._run_batch(kind, reqs, batch_id, batch_span)
+
+    def _run_batch(self, kind: str, reqs: list, batch_id: int,
+                   batch_span):
+        """One static-shape batch on the worker thread, in four spans:
+        ``serve.batch_form`` (stack, pad, host→device),
+        ``serve.dispatch`` (the call into the compiled program
+        returning — enqueue only), ``serve.fetch`` (device wait +
+        device→host) and ``serve.resolve`` (slice, count, futures).
+        Each request gets three retroactive records sharing its
+        ``request_id`` and this ``batch_id``: ``admission_queue``
+        (submitted → dequeued), ``batch_wait`` (dequeued → this batch
+        began) and ``execute:<kind>`` (this batch began → done) —
+        owed here, written by ``_flush_owed``."""
+        tr = self._tracer
+        t_batch = self._clock()
         queued = [t_batch - r.submitted_at for r in reqs]
-        for r, q in zip(reqs, queued):
-            self._trace(r, "admission_queue", "queue", r.submitted_at,
-                        q)
         with self._model_lock:
             params, buffers = self._params, self._buffers
         try:
             _faults.check_serving_fault(self.name)
             if kind == "classify":
-                x, bucket = self.batcher.coalesce(
-                    [r.payload for r in reqs])
-                xj = jnp.asarray(x)
-                t_exec = time.monotonic()
-                self._account_bucket_cost(bucket, params, buffers, xj)
-                out = self._fwd(params, buffers, xj)
+                with tr.span("serve.batch_form", "batch"):
+                    new_sig = self.batcher.bucket_for(len(reqs)) \
+                        not in self.batcher.buckets_dispatched
+                    x, bucket = self.batcher.coalesce(
+                        [r.payload for r in reqs])
+                    xj = jnp.asarray(x)
+                with tr.span("serve.dispatch",
+                             "compile" if new_sig else "dispatch",
+                             compiled=new_sig):
+                    self._account_bucket_cost(bucket, params, buffers,
+                                              xj)
+                    out = self._fwd(params, buffers, xj)
                 # host transfer doubles as the execution barrier —
                 # device-side failures surface here, inside the try
-                out_np = jax.tree_util.tree_map(np.asarray, out)
+                with tr.span("serve.fetch", "device_wait"):
+                    self._flush_owed()
+                    out_np = jax.tree_util.tree_map(np.asarray, out)
                 with self._model_lock:
                     self._canary_x = xj  # freshest known-good canary
             else:
-                t_exec = time.monotonic()
                 out_np, bucket = self._run_generate(params, reqs)
-            t_done = time.monotonic()
-            for r in reqs:
-                self._trace(r, "batch_form", "batch", t_batch,
-                            t_exec - t_batch, batch=len(reqs),
-                            bucket=bucket)
-                self._trace(r, f"execute:{kind}", "execute", t_exec,
-                            t_done - t_exec, bucket=bucket,
-                            batch=len(reqs))
+            batch_span.set(bucket=bucket)
+            t_done = self._clock()
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
@@ -748,17 +814,85 @@ class InferenceServer:
             log.warning("serving step failed (%s, %s): %s",
                         "fatal" if fatal else "retryable",
                         self.breaker.state, err)
+            self._owe_records(kind, reqs, t_batch, None, batch_id, None)
             for r, q in zip(reqs, queued):
                 self._resolve(r, ServeResult(
                     Status.INTERNAL_ERROR, error=err, queued_s=q))
             return
-        self.breaker.record_success()
-        self.metrics.record_batch(len(reqs), bucket)
-        for i, (r, q) in enumerate(zip(reqs, queued)):
-            self._resolve(r, ServeResult(
-                Status.OK, output=jax.tree_util.tree_map(
-                    lambda a: a[i], out_np),
-                queued_s=q, bucket=bucket))
+        with tr.span("serve.resolve", "other"):
+            self.breaker.record_success()
+            self.metrics.record_batch(len(reqs), bucket)
+            self._owe_records(kind, reqs, t_batch, t_done, batch_id,
+                              bucket)
+            for i, (r, q) in enumerate(zip(reqs, queued)):
+                self._resolve(r, ServeResult(
+                    Status.OK, output=jax.tree_util.tree_map(
+                        lambda a: a[i], out_np),
+                    queued_s=q, bucket=bucket))
+
+    @staticmethod
+    def _request_records(req: Request, kind: str, t_batch: float,
+                         t_done: Optional[float], batch_id: int,
+                         bucket: Optional[int], rows: int):
+        """The retroactive records of one request of a static batch:
+        its wait in the admission queue until the worker took it, its
+        wait in the worker's hands until the batch began (the gather
+        window, and any group of the same gather that ran first) — the
+        two sum to ``ServeResult.queued_s`` — and, for a batch that
+        ran, ``execute:<kind>`` from there to done."""
+        taken = min(max(req.dequeued_at, req.submitted_at), t_batch)
+        yield ("admission_queue", "queue", req.submitted_at,
+               taken - req.submitted_at, {"batch_id": batch_id})
+        yield ("batch_wait", "batch", taken, t_batch - taken,
+               {"batch_id": batch_id})
+        if t_done is not None:
+            yield (f"execute:{kind}", "execute", t_batch,
+                   t_done - t_batch,
+                   {"batch_id": batch_id, "bucket": bucket,
+                    "batch": rows})
+
+    def _owe_records(self, kind: str, reqs: list, t_batch: float,
+                     t_done: Optional[float], batch_id: int,
+                     bucket: Optional[int]):
+        """Per-request records of a batch that is about to resolve.
+        A request that carries a fleet trace context gets them into the
+        sink now (its fragment is published when it resolves).  The
+        ring's are OWED: writing three records a request between one
+        batch's fetch and the next one's dispatch is host time the chip
+        waits out and every queued request pays (0.15 ms a batch of 12
+        on the v5e host — enough, summed over a busy stretch, to move
+        which batch an arrival joins).  ``_flush_owed`` writes them
+        once the device is busy again."""
+        if self.trace_sink is not None:
+            for r in reqs:
+                if r.trace is not None:
+                    for name, cat, start, dur, args in \
+                            self._request_records(r, kind, t_batch,
+                                                  t_done, batch_id,
+                                                  bucket, len(reqs)):
+                        self.trace_sink.record(r.trace, name, cat,
+                                               start, dur, **args)
+        if self._tracer.enabled:
+            self._owed.append((kind, reqs, t_batch, t_done, batch_id,
+                               bucket))
+
+    def _flush_owed(self):
+        """Write the owed per-request ring records (worker thread
+        only).  Called where the worker has nothing better to do: at
+        the start of ``serve.fetch`` (the next program is already
+        running), on an empty poll, and when the worker exits — so the
+        ring is at most one batch or one poll behind."""
+        if not self._owed:
+            return
+        owed, self._owed = self._owed, []
+        record = self._tracer.record
+        for kind, reqs, t_batch, t_done, batch_id, bucket in owed:
+            for r in reqs:
+                for name, cat, start, dur, args in self._request_records(
+                        r, kind, t_batch, t_done, batch_id, bucket,
+                        len(reqs)):
+                    record(name, cat, start, dur,
+                           request_id=r.request_id, **args)
 
     def _account_bucket_cost(self, bucket: int, params, buffers, xj):
         """Per-bucket FLOP accounting: one XLA cost-model lowering the
@@ -848,7 +982,7 @@ class InferenceServer:
 
         live = []
         for req in reqs:
-            now = time.monotonic()
+            now = self._clock()
             queued_s = now - req.submitted_at
             self._trace(req, "admission_queue", "queue",
                         req.submitted_at, queued_s)
@@ -858,10 +992,10 @@ class InferenceServer:
                     max_new, eos_id, pad_id = req.opts
                     eos, pad = map(int, _eos_pad(self.model, eos_id,
                                                  pad_id))
-                    t_g = time.monotonic()
+                    t_g = self._clock()
                     seq = self._import_handoff(decoder, req.payload)
                     self._trace(req, "kv_import", "kv_gather", t_g,
-                                time.monotonic() - t_g,
+                                self._clock() - t_g,
                                 pages=len(seq.lease.pages))
                     # the first token rode the handoff: this dispatch
                     # owes the remaining max_new - 1
@@ -870,24 +1004,24 @@ class InferenceServer:
                         "target": max_new - 1, "eos": eos, "pad": pad,
                         "queued_s": queued_s,
                         "done": eos > 0 and seq.last == eos,
-                        "t_decode": time.monotonic(), "steps": 0,
+                        "t_decode": self._clock(), "steps": 0,
                     }
                     live.append(entry)
                 else:
-                    t0 = time.monotonic()
+                    t0 = self._clock()
                     seq = decoder.start(params, req.payload)
-                    prefill_s = time.monotonic() - t0
+                    prefill_s = self._clock() - t0
                     self.metrics.record_phase("prefill", prefill_s,
                                               tenant=self._tenant_of(req))
                     self.metrics.record_ttft(
-                        time.monotonic() - req.submitted_at,
+                        self._clock() - req.submitted_at,
                         tenant=self._tenant_of(req))
                     self._trace(req, "prefill", "prefill", t0,
                                 prefill_s,
                                 prompt_len=int(req.payload.shape[0]),
                                 pages=len(seq.lease.pages))
                     if req.kind == "prefill":
-                        t_g = time.monotonic()
+                        t_g = self._clock()
                         k_pages, v_pages = pool.read_pages(
                             seq.lease.pages)
                         extras = None
@@ -904,7 +1038,7 @@ class InferenceServer:
                             k_pages, v_pages, seq.last, seq.pos,
                             pool.page_size, extras=extras)
                         self._trace(req, "kv_export", "kv_gather", t_g,
-                                    time.monotonic() - t_g,
+                                    self._clock() - t_g,
                                     pages=len(seq.lease.pages),
                                     blob_bytes=len(blob))
                         seq.release()
@@ -923,7 +1057,7 @@ class InferenceServer:
                             "eos": eos, "pad": pad,
                             "queued_s": queued_s,
                             "done": eos > 0 and seq.last == eos,
-                            "t_decode": time.monotonic(), "steps": 0,
+                            "t_decode": self._clock(), "steps": 0,
                         })
             except PoolExhausted as e:
                 # admission control, not failure: shed typed (the
@@ -942,7 +1076,7 @@ class InferenceServer:
         def finish(entry):
             seq, req = entry["seq"], entry["req"]
             seq.release()
-            decode_s = time.monotonic() - entry["t_decode"]
+            decode_s = self._clock() - entry["t_decode"]
             self.metrics.record_phase("decode", decode_s,
                                       tenant=self._tenant_of(req))
             if entry["steps"]:
@@ -960,7 +1094,7 @@ class InferenceServer:
 
         def abort(entry, result: ServeResult):
             entry["seq"].release()
-            decode_s = time.monotonic() - entry["t_decode"]
+            decode_s = self._clock() - entry["t_decode"]
             self._trace(entry["req"], "decode", "decode",
                         entry["t_decode"], decode_s,
                         steps=entry["steps"], aborted=True)
@@ -992,7 +1126,7 @@ class InferenceServer:
                         * (entry["target"] - len(entry["toks"])))
                     nxt.append(entry)
                     continue
-                if req.expired(time.monotonic()):
+                if req.expired(self._clock()):
                     abort(entry, ServeResult(
                         Status.DEADLINE_EXCEEDED,
                         error="deadline expired mid-decode"))
@@ -1026,19 +1160,32 @@ class InferenceServer:
         generation traffic can't recompile per batch count either)."""
         from ..models.generate import cached_generate
 
+        tr = self._tracer
         max_new, eos_id, pad_id = reqs[0].opts
-        prompts = np.stack([r.payload for r in reqs])
-        n = prompts.shape[0]
-        bucket = self.batcher.bucket_for(n)
-        if n < bucket:
-            prompts = np.concatenate(
-                [prompts, np.repeat(prompts[-1:], bucket - n, axis=0)],
-                axis=0)
-        self.batcher.buckets_dispatched.add(
-            ("gen", bucket, prompts.shape[1], max_new))
-        gen = cached_generate(self.model,
-                              compute_dtype=self.generate_dtype)
-        ids = gen(params, prompts, max_new, eos_id=eos_id,
-                  pad_id=pad_id)
-        out = np.asarray(ids)[:, prompts.shape[1]:]  # generated tail
+        with tr.span("serve.batch_form", "batch"):
+            prompts = np.stack([r.payload for r in reqs])
+            n = prompts.shape[0]
+            bucket = self.batcher.bucket_for(n)
+            if n < bucket:
+                prompts = np.concatenate(
+                    [prompts,
+                     np.repeat(prompts[-1:], bucket - n, axis=0)],
+                    axis=0)
+            sig = ("gen", bucket, prompts.shape[1], max_new)
+            new_sig = sig not in self.batcher.buckets_dispatched
+            self.batcher.buckets_dispatched.add(sig)
+            prompts_j = jnp.asarray(prompts, jnp.int32)
+        # the call returning: eos/pad lookup, argument conversion and
+        # the enqueue of ONE compiled program (a build on a new
+        # signature); the decode itself runs behind it
+        with tr.span("serve.dispatch",
+                     "compile" if new_sig else "dispatch",
+                     compiled=new_sig):
+            gen = cached_generate(self.model,
+                                  compute_dtype=self.generate_dtype)
+            ids = gen(params, prompts_j, max_new, eos_id=eos_id,
+                      pad_id=pad_id)
+        with tr.span("serve.fetch", "device_wait"):
+            self._flush_owed()
+            out = np.asarray(ids)[:, prompts.shape[1]:]  # generated tail
         return out, bucket

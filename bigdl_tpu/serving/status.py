@@ -118,13 +118,17 @@ class Request:
     payload: Any
     future: ServeFuture
     submitted_at: float
-    #: absolute monotonic deadline, or None
+    #: absolute deadline on the clock of ``submitted_at``, or None
     deadline: Optional[float] = None
     #: generate-path options (max_new, eos_id, pad_id)
     opts: tuple = field(default_factory=tuple)
     #: distributed-trace context (telemetry.trace_context.TraceContext)
     #: propagated from the router, or None when untraced
     trace: Optional[object] = None
+    #: server-local id every span of this request carries
+    request_id: int = 0
+    #: when the worker took it off the queue (the tracer's clock)
+    dequeued_at: float = 0.0
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now >= self.deadline

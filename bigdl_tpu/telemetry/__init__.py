@@ -55,7 +55,8 @@ from .slog import configure_logging, get_logger
 from .timeseries import MetricRecorder
 from .trace_context import (REQUEST_CATEGORIES, TRACE_KV_PREFIX,
                             TailSampler, TraceContext)
-from .tracer import CATEGORIES, STEP_CATEGORIES, Span, Tracer
+from .tracer import (CATEGORIES, STEP_CATEGORIES, Span, Tracer,
+                     default_tracer, reset_default_tracer)
 
 __all__ = [
     "Alert", "BackgroundPublisher", "CATEGORIES",
@@ -71,14 +72,14 @@ __all__ = [
     "classify_roofline", "collect_snapshots", "configure_logging",
     "default_buckets", "default_journal", "default_loop_rules",
     "default_registry",
-    "default_serving_rules", "default_training_rules", "device_spec",
+    "default_serving_rules", "default_tracer", "default_training_rules", "device_spec",
     "get_logger", "ingest_deadman_rule",
     "merge_alerts", "merge_cluster", "merge_incidents",
     "merge_metrics",
     "merge_timeline", "peak_flops_per_sec",
     "publish_snapshot", "read_snapshot_dir", "record_change",
     "reset_default_journal", "reset_default_registry",
-    "write_snapshot",
+    "reset_default_tracer", "write_snapshot",
 ]
 
 #: log-spaced bounds sized for step/phase durations (100µs … ~100s)
@@ -89,12 +90,17 @@ class Telemetry:
     """The bundle the training/serving drivers speak to.
 
     Without arguments it adopts the process-wide default registry (so
-    the resilience layer's counters land in the same snapshot), a
-    fresh tracer and a fresh goodput ledger.  ``trace_every`` sets the
-    tracing cadence: spans are recorded for every Nth step (1 = every
-    step, the default; 0 disables span recording while keeping
-    metrics + goodput).  ``snapshot_dir`` makes :meth:`write_snapshot`
-    drop ``<host>.json`` payloads for ``tools/run_report.py``.
+    the resilience layer's counters land in the same snapshot), the
+    process-wide default tracer (the one the driver loop, the plan
+    engine, the prefetcher and the server record their in-place spans
+    into — :func:`default_tracer`) and a fresh goodput ledger.  The
+    hooks below feed histograms and the ledger; the spans themselves
+    are opened where the work happens, not here.  ``trace_every``
+    thins what the hooks still attach to those spans — the cost-model
+    args and the profiled compute/collective children of every Nth
+    step (1 = every step, the default; 0 = none).  ``snapshot_dir``
+    makes :meth:`write_snapshot` drop ``<host>.json`` payloads for
+    ``tools/run_report.py``.
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
@@ -106,7 +112,7 @@ class Telemetry:
                  perf: Optional[PerfAccountant] = None):
         self.registry = registry if registry is not None \
             else default_registry()
-        self.tracer = tracer or Tracer()
+        self.tracer = tracer if tracer is not None else default_tracer()
         self.ledger = ledger or GoodputLedger()
         # XLA cost-model work accounting (telemetry/perf.py): built on
         # the same registry so the mfu family lands in one snapshot
@@ -117,6 +123,7 @@ class Telemetry:
         self.trace_every = max(0, int(trace_every))
         self.incarnation = 0
         self._steps_seen = 0
+        self._recovery_t0: Optional[float] = None  # tracer clock
         #: optional online SLO engine (telemetry/slo.py) — a
         #: TrainingHealthMonitor built over this bundle registers
         #: itself here so payload() publishes the active-alert view
@@ -179,44 +186,39 @@ class Telemetry:
         self.ledger.start()
 
     def on_data_wait(self, seconds: float, step: Optional[int] = None):
-        """Host time spent waiting on the input pipeline."""
+        """Host time spent waiting on the input pipeline (the span is
+        ``train.data_wait``, opened around the wait itself)."""
         seconds = max(0.0, float(seconds))
         self.data_wait_seconds.observe(seconds)
         self.ledger.add("data_stall", seconds)
-        if self._trace_due():
-            end = self.tracer.clock()
-            self.tracer.record("data_wait", "data_wait", end - seconds,
-                               seconds, step=step)
 
     def on_host_to_device(self, seconds: float,
                           step: Optional[int] = None):
         """Host→device placement (infeed sharding) — ledgered as part
-        of the data stall, traced under its own category."""
+        of the data stall (the span is ``train.place_batch``)."""
         seconds = max(0.0, float(seconds))
         self.h2d_seconds.observe(seconds)
         self.ledger.add("data_stall", seconds)
-        if self._trace_due():
-            end = self.tracer.clock()
-            self.tracer.record("host_to_device", "host_to_device",
-                               end - seconds, seconds, step=step)
 
     def on_step(self, seconds: float, records: int = 0,
                 step: Optional[int] = None, compiled: bool = False,
-                phase_split=None, skipped: bool = False):
+                phase_split=None, skipped: bool = False, span=None):
         """One compiled-step dispatch completed.  ``compiled=True``
         classifies it as compile time (the first step of every fresh
-        program); ``phase_split`` is the optional
-        :class:`~bigdl_tpu.optim.profiling.PhaseSplit` attributing the
-        step's device time to compute vs collective children."""
+        program).  ``span`` is the caller's live ``train.iteration``
+        span: the cost model's static FLOPs/bytes/intensity ride on it
+        as args, and ``phase_split`` (the optional
+        :class:`~bigdl_tpu.optim.profiling.PhaseSplit` of a profiled
+        step) becomes its compute / collective children, laid from the
+        span's own start — estimates of device time, not clock truths."""
         seconds = max(0.0, float(seconds))
         if self.ledger.in_recovery:
             # the window closes where this step BEGAN — the step's own
             # seconds are attributed below, not as recovery
             rec = self.ledger.recovery_end(exclude=seconds)
-            if rec and self.trace_every > 0:
-                end = self.tracer.clock() - seconds
-                self.tracer.record("recovery", "recovery", end - rec,
-                                   rec)
+            t0, self._recovery_t0 = self._recovery_t0, None
+            if rec and t0 is not None and self.trace_every > 0:
+                self.tracer.record("recovery", "recovery", t0, rec)
         self.ledger.add("compile" if compiled else "productive", seconds)
         self.steps.inc()
         if records:
@@ -226,34 +228,25 @@ class Telemetry:
         (self.compile_seconds if compiled
          else self.step_seconds).observe(seconds)
         self.perf.on_step(seconds, compiled=compiled)
-        if self._trace_due():
-            end = self.tracer.clock()
-            # static FLOPs/bytes/intensity from the cost model ride on
-            # EVERY step span — Perfetto traces carry the work
-            # attribution even when the xplane profiler never ran
-            parent = self.tracer.record(
-                "compile" if compiled else "step",
-                "compile" if compiled else "step",
-                end - seconds, seconds, step=step,
-                **self.perf.span_args())
-            if phase_split is not None and parent is not None:
+        if isinstance(span, Span) and self._trace_due():  # not the
+            # disabled tracer's null span
+            span.set(**self.perf.span_args())
+            if phase_split is not None:
                 compute_s, collective_s = phase_split
-                self.tracer.record("compute", "compute", parent.start,
-                                   compute_s, parent=parent, step=step)
+                self.tracer.record("compute", "compute", span.start,
+                                   compute_s, parent=span, step=step)
                 self.tracer.record("collective", "collective",
-                                   parent.start + compute_s,
-                                   collective_s, parent=parent,
+                                   span.start + compute_s,
+                                   collective_s, parent=span,
                                    step=step)
         self._steps_seen += 1
 
     def on_checkpoint(self, seconds: float, step: Optional[int] = None):
+        """Checkpoint snapshot/write seconds on the critical path (the
+        span is ``train.checkpoint``, opened around the call)."""
         seconds = max(0.0, float(seconds))
         self.checkpoint_seconds.observe(seconds)
         self.ledger.add("checkpoint", seconds)
-        if self.trace_every > 0:
-            end = self.tracer.clock()
-            self.tracer.record("checkpoint", "checkpoint",
-                               end - seconds, seconds, step=step)
 
     def on_checkpoint_blocked(self, seconds: float,
                               step: Optional[int] = None):
@@ -267,16 +260,13 @@ class Telemetry:
             return
         self.checkpoint_blocked_seconds.observe(seconds)
         self.ledger.add("checkpoint", seconds)
-        if self.trace_every > 0:
-            end = self.tracer.clock()
-            self.tracer.record("checkpoint_blocked", "checkpoint",
-                               end - seconds, seconds, step=step)
 
     def on_recovery_begin(self):
         """A fault was detected (retry rollback, membership change):
         wall clock is recovery until the next completed step."""
         if not self.ledger.in_recovery:
             self.recoveries.inc()
+            self._recovery_t0 = self.tracer.clock()
         self.ledger.recovery_begin()
 
     # -- export ----------------------------------------------------------

@@ -12,6 +12,23 @@ optim/profiling.py), ``checkpoint``, ``recovery``.  The buffer is a
 ring: a week-long run keeps the most recent ``capacity`` spans instead
 of growing without bound.
 
+One tracer serves the process: :func:`default_tracer` (beside
+``default_registry``) is what the plan driver loop, the plan engine,
+the prefetcher, the serving worker and ``generate`` speak to directly
+— no ``set_telemetry``, no sink.  Its ring is a flight recorder: on by
+default, bounded, dumped with :meth:`Tracer.export_json` without
+foresight.  ``Tracer.enabled = False`` is the one switch; a disabled
+``span()`` takes no lock and allocates nothing.
+
+**The profiler bridge.**  A live :meth:`Tracer.span` also enters
+``jax.profiler.TraceAnnotation("bigdl." + name, **ids)``.  With no
+profiler session that is one flag check; inside one
+(``jax.profiler.trace`` / ``start_trace``) the span lands on its
+thread's line of ``/host:CPU`` in the xplane, on the clock of the
+``XLA Ops`` lines — joined to the device by construction.  ``jax`` is
+never imported here: a process that has not imported it has no
+session to join.
+
 Spans nest two ways:
 
 * :meth:`Tracer.span` — a context manager pushing onto a thread-local
@@ -26,8 +43,10 @@ Spans nest two ways:
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -35,14 +54,20 @@ from typing import Callable, Dict, List, Optional
 
 from .trace_context import REQUEST_CATEGORIES
 
-__all__ = ["CATEGORIES", "STEP_CATEGORIES", "Span", "Tracer"]
+__all__ = ["CATEGORIES", "PROGRAM_SPANS", "STEP_CATEGORIES", "Span",
+           "Tracer", "default_tracer", "profiler_session_live",
+           "reset_default_tracer"]
 
 #: the training-side vocabulary — everything the goodput ledger can
 #: attribute a second of wall clock to, plus the profiled split of
-#: on-device time
+#: on-device time, plus the three things a host thread does around a
+#: compiled program: ``dispatch`` (enqueue it), ``device_wait`` (block
+#: on its result) and ``state_sync`` (move whole state trees between
+#: module and device at the edges of ``optimize()``)
 STEP_CATEGORIES = (
     "step", "data_wait", "host_to_device", "compile", "compute",
     "collective", "checkpoint", "recovery", "idle", "other",
+    "dispatch", "device_wait", "state_sync",
 )
 
 #: the closed vocabulary of span categories: the training table above
@@ -52,9 +77,46 @@ STEP_CATEGORIES = (
 CATEGORIES = STEP_CATEGORIES + REQUEST_CATEGORIES
 
 
+#: the program's in-place spans — stable API (no file names, no line
+#: numbers; docs/observability.md has where each is emitted and what it
+#: bounds): name -> category.  A ``*.dispatch`` that builds a fresh
+#: program is categorized ``compile`` instead.  A lint
+#: (tests/test_program_spans.py) holds every literal span name under
+#: these prefixes to this table.
+PROGRAM_SPANS = {
+    "train.optimize": "other",
+    "plan.init_state": "state_sync",
+    "plan.sync_to_model": "state_sync",
+    "train.iteration": "step",
+    "train.data_wait": "data_wait",
+    "train.place_batch": "host_to_device",
+    "train.dispatch": "dispatch",
+    "train.loss_fetch": "device_wait",
+    "train.bookkeeping": "other",
+    "train.validation": "other",
+    "train.checkpoint": "checkpoint",
+    "feed.produce": "other",
+    "feed.blocked": "idle",
+    "serve.idle": "idle",
+    "serve.gather": "batch",
+    "serve.batch": "batch",
+    "serve.batch_form": "batch",
+    "serve.dispatch": "dispatch",
+    "serve.fetch": "device_wait",
+    "serve.resolve": "other",
+}
+
+
+def profiler_session_live() -> bool:
+    """Whether a profiler session is recording right now.  A span
+    opened before it started is not in its xplane; a long-lived one
+    (the serving worker's ``serve.idle``) asks, and renews itself."""
+    return _annotation_class() is not None
+
+
 class Span:
     __slots__ = ("id", "name", "category", "start", "end", "tid",
-                 "parent_id", "args")
+                 "parent_id", "args", "_annotation")
 
     def __init__(self, id: int, name: str, category: str, start: float,
                  tid: int, parent_id: Optional[int],
@@ -67,10 +129,22 @@ class Span:
         self.tid = tid
         self.parent_id = parent_id
         self.args = args
+        self._annotation = None  # the live profiler twin, if any
 
     @property
     def duration(self) -> float:
         return (self.end - self.start) if self.end is not None else 0.0
+
+    def set(self, **args):
+        """Attach what is only known once the span is open (``hit``,
+        ``compiled``, ``n``): into the ring's args and, inside a
+        profiler session, onto the xplane event's stats."""
+        if self.args is None:
+            self.args = args
+        else:
+            self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
 
     def __repr__(self):
         return (f"Span({self.name!r}, cat={self.category!r}, "
@@ -106,6 +180,40 @@ class _SpanCtx:
         return False
 
 
+class _NullSpan:
+    """What a disabled tracer hands out: one shared object that is its
+    own context manager and swallows :meth:`Span.set`."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def set(self, **args):
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+#: ``jax.profiler.TraceAnnotation`` once jax is in the process
+_TraceAnnotation = None
+
+
+def _annotation_class():
+    """The annotation class while a profiler session is live, else
+    None.  Never imports jax: without it there is no session."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation if _TraceAnnotation.is_enabled() else None
+
+
 class Tracer:
     def __init__(self, capacity: int = 8192,
                  clock: Callable[[], float] = time.perf_counter,
@@ -116,7 +224,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._done: deque = deque(maxlen=self.capacity)
         self._local = threading.local()
-        self._next_id = 0
+        self._ids = itertools.count(1)  # next() is atomic under the GIL
         self.dropped = 0  # spans evicted from the ring
 
     # -- internals ------------------------------------------------------
@@ -126,38 +234,38 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def _alloc_id(self) -> int:
-        with self._lock:
-            self._next_id += 1
-            return self._next_id
-
     def _finish(self, span: Span):
+        ann, span._annotation = span._annotation, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
         with self._lock:
             if len(self._done) == self._done.maxlen:
                 self.dropped += 1
             self._done.append(span)
 
     # -- recording ------------------------------------------------------
-    def span(self, name: str, category: str = "other",
-             **args) -> _SpanCtx:
+    def span(self, name: str, category: str = "other", **ids):
         """Open a nested span: ``with tracer.span("step", "step") as s``.
         Children opened on the same thread while it is open are linked
-        to it."""
+        to it.  ``ids`` (``step``, ``batch_id``, ``request_id``,
+        ``bucket``) ride as args in the ring and as event stats in a
+        profiler session."""
+        if not self.enabled:
+            return _NULL_SPAN
         _check_category(category)
         stack = self._stack()
-        parent = stack[-1] if stack else None
-        s = Span(self._alloc_id(), str(name), category, self._clock(),
+        s = Span(next(self._ids), str(name), category, 0.0,
                  threading.get_ident(),
-                 parent.id if parent else None, args or None)
-        if self.enabled:
-            stack.append(s)
-        else:
-            s.end = s.start  # disabled: a zero-width tombstone, not kept
+                 stack[-1].id if stack else None, ids or None)
+        stack.append(s)
+        ann = _annotation_class()
+        if ann is not None:
+            s._annotation = ann("bigdl." + s.name, **ids)
+            s._annotation.__enter__()
+        s.start = self._clock()
         return _SpanCtx(self, s)
 
     def _close(self, span: Span):
-        if not self.enabled and span.end is not None:
-            return
         now = self._clock()
         stack = self._stack()
         # close abandoned children first (an exception can unwind past
@@ -186,13 +294,12 @@ class Tracer:
             start = min(max(start, parent.start), parent.end)
             end = min(max(end, start), parent.end)
         tid = threading.get_ident()
-        # one lock round trip (id alloc + ring append) — retroactive
-        # records run on serving hot paths
+        s = Span(next(self._ids), str(name), category, start, tid,
+                 parent.id if parent else None, args or None)
+        s.end = end
+        # one lock round trip — retroactive records run on serving hot
+        # paths
         with self._lock:
-            self._next_id += 1
-            s = Span(self._next_id, str(name), category, start, tid,
-                     parent.id if parent else None, args or None)
-            s.end = end
             if len(self._done) == self._done.maxlen:
                 self.dropped += 1
             self._done.append(s)
@@ -221,10 +328,11 @@ class Tracer:
         return [s.to_dict() for s in spans]
 
     def category_totals(self) -> Dict[str, float]:
-        """Seconds per category, summed over completed spans.  ``step``
-        spans count their SELF time (step minus attributed children),
-        so a step with profiled compute/collective children does not
-        double-report."""
+        """Seconds per category, summed over completed spans.  A span
+        counts its SELF time (its duration minus its direct children),
+        so nested program spans — a ``train.iteration`` over its
+        phases, a ``serve.batch`` over its four, a step over its
+        profiled compute/collective children — never double-report."""
         spans = self.spans()
         child_sum: Dict[int, float] = {}
         for s in spans:
@@ -233,9 +341,7 @@ class Tracer:
                                           + s.duration)
         out: Dict[str, float] = {}
         for s in spans:
-            dur = s.duration
-            if s.category == "step":
-                dur = max(0.0, dur - child_sum.get(s.id, 0.0))
+            dur = max(0.0, s.duration - child_sum.get(s.id, 0.0))
             out[s.category] = out.get(s.category, 0.0) + dur
         return out
 
@@ -265,6 +371,31 @@ class Tracer:
 
 
 _CATEGORY_SET = frozenset(CATEGORIES)
+
+_default: Optional[Tracer] = None
+_default_lock = threading.Lock()
+
+
+def default_tracer() -> Tracer:
+    """The process-wide tracer.  The plan driver loop, the plan engine,
+    the prefetcher, the serving worker and ``generate`` record into it
+    unconditionally; a Telemetry facade built without an explicit
+    tracer adopts it, so one export carries the whole process."""
+    global _default
+    if _default is None:
+        with _default_lock:
+            if _default is None:
+                _default = Tracer()
+    return _default
+
+
+def reset_default_tracer() -> Tracer:
+    """Swap in a fresh default tracer (tests isolate with this).
+    Emitters look the tracer up at every entry, never at import."""
+    global _default
+    with _default_lock:
+        _default = Tracer()
+        return _default
 
 
 def _check_category(category: str):

@@ -49,6 +49,15 @@ def pytest_unconfigure(config):
 
 
 @pytest.fixture(autouse=True)
+def _fresh_default_tracer():
+    """Each test reads only its own spans in the process-wide ring."""
+    from bigdl_tpu.telemetry import reset_default_tracer
+
+    reset_default_tracer()
+    yield
+
+
+@pytest.fixture(autouse=True)
 def _seed_rng():
     """Deterministic host RNG per test (reference tests fix seeds per spec)."""
     from bigdl_tpu.utils.rng import RNG

@@ -528,8 +528,10 @@ def test_longrun_memory_object_counts_plateau():
 
     def pump(n0, n):
         for i in range(n0, n0 + n):
-            tm.on_data_wait(1e-4, step=i)
-            tm.on_step(1e-3, records=4, step=i)
+            with tm.tracer.span("train.iteration", "step", step=i) as it:
+                with tm.tracer.span("train.data_wait", "data_wait"):
+                    tm.on_data_wait(1e-4, step=i)
+                tm.on_step(1e-3, records=4, step=i, span=it)
             ctx.step_log.append((0, i, float(i), 1e-3))
             ctx.vote_log.append((i, 1e-4))
 

@@ -277,9 +277,14 @@ def test_telemetry_facade_hooks_and_summary_export(tmp_path):
     tm = Telemetry(registry=MetricsRegistry(), host="hostA",
                    snapshot_dir=str(tmp_path / "snaps"))
     tm.on_attempt_begin()
-    tm.on_step(0.5, records=32, step=1, compiled=True)
+    # the driver opens the spans where the work happens and hands the
+    # hooks the live iteration span; the hooks place none of their own
+    with tm.tracer.span("train.iteration", "step", step=1) as it:
+        tm.on_step(0.5, records=32, step=1, compiled=True, span=it)
     tm.on_data_wait(0.01, step=2)
-    tm.on_step(0.1, records=32, step=2, phase_split=(0.06, 0.03))
+    with tm.tracer.span("train.iteration", "step", step=2) as it:
+        tm.on_step(0.1, records=32, step=2, phase_split=(0.06, 0.03),
+                   span=it)
     tm.on_checkpoint(0.02, step=2)
     tm.on_recovery_begin()
     time.sleep(0.02)  # a real (wall) recovery window...
@@ -289,9 +294,20 @@ def test_telemetry_facade_hooks_and_summary_export(tmp_path):
     assert tm.records.value == 96
     assert tm.step_seconds.count == 2  # the compile step lands apart
     assert tm.compile_seconds.count == 1
-    cats = {s.category for s in tm.tracer.spans()}
-    assert {"compile", "step", "data_wait", "compute", "collective",
-            "checkpoint", "recovery"} <= cats
+    spans = tm.tracer.spans()
+    assert {s.category for s in spans} == {"step", "compute",
+                                           "collective", "recovery"}
+    # the profiled split hangs under the span it was handed, laid from
+    # that span's own start — not at the hook's "now"
+    step2 = next(s for s in spans if (s.args or {}).get("step") == 2
+                 and s.category == "step")
+    kids = [s for s in spans if s.parent_id == step2.id]
+    assert [k.name for k in kids] == ["compute", "collective"]
+    assert kids[0].start == step2.start
+    # the recovery window starts where the fault was noted
+    rec = next(s for s in spans if s.category == "recovery")
+    assert 0.015 <= rec.duration <= 5.0
+    assert rec.start >= step2.end
 
     summary = TelemetrySummary(str(tmp_path), "app")
     tm.to_summary(summary, step=3)
@@ -437,7 +453,11 @@ def test_local_optimizer_feeds_telemetry(tmp_path):
     # the tracer exported a parseable trace with step spans
     trace = json.loads(json.dumps(tm.tracer.to_chrome_trace()))
     names = [e["name"] for e in trace["traceEvents"]]
-    assert names.count("step") == 5 and "checkpoint" in names
+    assert names.count("train.iteration") == 6
+    assert "train.checkpoint" in names
+    compiled = [e["args"]["compiled"] for e in trace["traceEvents"]
+                if e["name"] == "train.dispatch"]
+    assert compiled == [True] + [False] * 5
     # the end-of-run snapshot landed for tools/run_report.py
     assert "local" in read_snapshot_dir(str(tmp_path / "snaps"))
 
