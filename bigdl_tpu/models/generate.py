@@ -31,9 +31,15 @@ Sampling: ``temperature=0`` → greedy argmax; ``temperature>0`` →
 categorical over ``logits/temperature`` (optionally within ``top_k``
 and/or the ``top_p`` nucleus) and REQUIRES an explicit ``rng`` key — a
 silent fixed-seed default would return the identical "sample" every
-call.  ``eos_id`` stops a row (sampling) or finishes a beam (beam
-search) early at static shapes, emitting ``pad_id`` from then on —
-hf.generate's convention.  Beam decode: :func:`make_beam_search`.
+call.  The sampler is chosen on the HOST, at the call, from those
+numbers: the compiled program holds only what was asked for (greedy:
+one ``argmax``, no key consumed; the sort / cumsum / scatter of the
+nucleus only when ``0 < top_p < 1``), while the VALUES of
+``temperature`` and ``top_p`` stay traced, so a new temperature
+compiles nothing.  ``eos_id`` stops a row (sampling) or finishes a
+beam (beam search) early at static shapes, emitting ``pad_id`` from
+then on — hf.generate's convention.  Beam decode:
+:func:`make_beam_search`.
 """
 from __future__ import annotations
 
@@ -388,8 +394,11 @@ def make_generate(model, max_len: Optional[int] = None,
     ``params`` is ``model.param_tree()`` (1-based token ids, like the
     training path).  ``max_len`` bounds prompt+generated (default: the
     model's positional table length).  One compiled program per
-    (prompt_shape, max_new, top_k); the decode loop itself is a scan —
-    no per-token dispatch.
+    (prompt_shape, max_new, top_k, greedy, nucleus), where ``greedy =
+    not temperature > 0`` and ``nucleus = 0 < top_p < 1`` are read on
+    the host from the call's own numbers (a greedy call ignores
+    ``top_k`` / ``top_p``: one program); the decode loop itself is a
+    scan — no per-token dispatch.
     """
     from ..optim.optimizer import _cast_floats
 
@@ -403,31 +412,36 @@ def make_generate(model, max_len: Optional[int] = None,
     # ``.decode_step`` / ``.sample`` name what a device trace shows as
     # fusion.NNN; the computation is the same with or without them
     @jax.named_scope("generate.sample")
-    def _sample(logits, temperature, top_k, top_p, key):
-        greedy = jnp.argmax(logits, axis=-1)
+    def _sample(logits, temperature, top_k, top_p, key, greedy, nucleus):
+        # ``top_k``, ``greedy`` and ``nucleus`` are Python values: the
+        # program holds only the arm the call asked for
+        if greedy:
+            return jnp.argmax(logits, axis=-1)
         if top_k:
             kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
             logits = jnp.where(logits < kth, -jnp.inf, logits)
-        # nucleus: drop tokens outside the smallest set whose prob mass
-        # reaches top_p (computed at the sampling temperature)
         scaled = logits / jnp.maximum(temperature, 1e-6)
-        probs = jax.nn.softmax(scaled, axis=-1)
-        order = jnp.argsort(-probs, axis=-1)
-        csum = jnp.cumsum(jnp.take_along_axis(probs, order, -1), axis=-1)
-        # keep ranks whose PRECEDING mass < top_p (always keeps rank 0)
-        keep_sorted = jnp.concatenate(
-            [jnp.zeros_like(csum[:, :1]), csum[:, :-1]], axis=-1) < top_p
-        keep = jnp.zeros_like(keep_sorted).at[
-            jnp.arange(logits.shape[0])[:, None], order].set(keep_sorted)
-        nucleus = jnp.where(keep, scaled, -jnp.inf)
-        use_nucleus = (top_p > 0) & (top_p < 1)
-        sampled = jax.random.categorical(
-            key, jnp.where(use_nucleus, nucleus, scaled), axis=-1)
-        return jnp.where(temperature > 0, sampled, greedy)
+        if nucleus:
+            # drop tokens outside the smallest set whose prob mass
+            # reaches top_p (computed at the sampling temperature)
+            probs = jax.nn.softmax(scaled, axis=-1)
+            order = jnp.argsort(-probs, axis=-1)
+            csum = jnp.cumsum(jnp.take_along_axis(probs, order, -1),
+                              axis=-1)
+            # keep ranks whose PRECEDING mass < top_p (always keeps
+            # rank 0)
+            keep_sorted = jnp.concatenate(
+                [jnp.zeros_like(csum[:, :1]), csum[:, :-1]],
+                axis=-1) < top_p
+            keep = jnp.zeros_like(keep_sorted).at[
+                jnp.arange(logits.shape[0])[:, None], order].set(
+                    keep_sorted)
+            scaled = jnp.where(keep, scaled, -jnp.inf)
+        return jax.random.categorical(key, scaled, axis=-1)
 
-    @partial(jax.jit, static_argnums=(2, 5))
+    @partial(jax.jit, static_argnums=(2, 5, 9, 10))
     def _run(p, prompt, max_new, key, temperature, top_k, top_p,
-             eos, pad):
+             eos, pad, greedy, nucleus):
         with jax.named_scope("generate.cast_params"):
             pc = _cast_floats(p, compute_dtype) if compute_dtype else p
         B, T0 = prompt.shape
@@ -437,12 +451,19 @@ def make_generate(model, max_len: Optional[int] = None,
         dt = (compute_dtype
               or jax.tree_util.tree_leaves(pc)[0].dtype)
 
+        def next_token(logits, key):
+            """(key, 1-based ids): a sampled step consumes one split of
+            the key, a greedy step none."""
+            sub = None
+            if not greedy:
+                key, sub = jax.random.split(key)
+            return key, _sample(logits, temperature, top_k, top_p, sub,
+                                greedy, nucleus) + 1
+
         with jax.named_scope("generate.prefill"):
             h, caches = prefill(pc, prompt, dt)
             logits = logits_last(pc, h)
-        key, sub = jax.random.split(key)
-        nxt = (_sample(logits, temperature, top_k, top_p,
-                       sub) + 1)  # 1-based ids
+        key, nxt = next_token(logits, key)
         # eos==0 disables early stop (ids are 1-based, 0 never matches).
         # Static shapes throughout: finished rows keep decoding but
         # emit `pad` (the hf.generate convention) — the work is bounded
@@ -459,8 +480,7 @@ def make_generate(model, max_len: Optional[int] = None,
                 tok = lax.dynamic_slice(ids, (0, pos), (B, 1))
                 h, new_caches = decode_token(pc, tok, caches, pos)
                 logits = logits_last(pc, h)
-            key, sub = jax.random.split(key)
-            nxt = (_sample(logits, temperature, top_k, top_p, sub) + 1)
+            key, nxt = next_token(logits, key)
             nxt = jnp.where(done, pad, nxt)
             done = done | ((nxt == eos) & (eos > 0))
             ids = lax.dynamic_update_slice(
@@ -484,9 +504,15 @@ def make_generate(model, max_len: Optional[int] = None,
                 "the identical sample every call")
         key = rng if rng is not None else jax.random.PRNGKey(0)
         eos, pad = _eos_pad(model, eos_id, pad_id)
+        # the sampler is chosen HERE, from the call's own numbers; their
+        # values stay traced, so a new temperature compiles nothing.  A
+        # greedy call ignores top_k / top_p and shares one program
+        greedy = not temperature > 0
+        nucleus = bool(not greedy and 0 < top_p < 1)
         return _run(params, jnp.asarray(prompt_ids, jnp.int32),
                     int(max_new), key, jnp.float32(temperature),
-                    int(top_k), jnp.float32(top_p), eos, pad)
+                    0 if greedy else int(top_k), jnp.float32(top_p),
+                    eos, pad, greedy, nucleus)
 
     return generate
 

@@ -296,7 +296,9 @@ class TransformerLM(Container):
         prefill + ``lax.scan`` decode at static shapes.  ``temperature=0``
         is greedy (pinned against the dense forward by teacher forcing);
         ``>0`` samples, optionally within ``top_k`` and/or the ``top_p``
-        nucleus.  ``eos_id`` stops a row early (it keeps emitting
+        nucleus; the compiled program holds only the sampler the call
+        asked for, and a new ``temperature`` or ``top_p`` value compiles
+        nothing.  ``eos_id`` stops a row early (it keeps emitting
         ``pad_id``, default the eos itself — hf.generate's convention,
         at static shapes).  The compiled generator is cached per
         (max_len, compute_dtype)."""

@@ -3,6 +3,9 @@ loop is pinned against the full dense forward by teacher forcing —
 every greedily decoded token must equal the argmax of the model's
 full-sequence output at the previous position.  Beyond reference
 parity (the reference predates autoregressive LMs, SURVEY §5.7)."""
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,6 +123,88 @@ def test_top_p_nucleus_restricts_support():
         prompt, max_new=5, rng=jax.random.PRNGKey(3), temperature=1.0,
         top_p=1.0))
     assert open_p.min() >= 1 and open_p.max() <= VOCAB
+
+
+# -- the sampler is chosen on the host: one arm per compiled program -------
+
+def _jitted_run(gen):
+    """The jitted ``_run`` inside a ``make_generate`` closure."""
+    return next(c.cell_contents for c in gen.__closure__
+                if hasattr(c.cell_contents, "lower"))
+
+
+_SORT, _SCATTER = "stablehlo.sort", "stablehlo.scatter"
+
+
+# (top_k, greedy, nucleus) as generate() hands them to _run
+@pytest.mark.parametrize("top_k,greedy,nucleus,absent,present", [
+    (0, True, False,
+     (_SORT, _SCATTER, "cumsum", "threefry", "random_bits"), ()),
+    (0, False, False, (_SORT, _SCATTER, "cumsum"), ("threefry",)),
+    (5, False, False, (_SCATTER, "cumsum"), (_SORT, "threefry")),
+    (0, False, True, (), (_SORT, _SCATTER, "cumsum", "threefry")),
+], ids=["greedy", "temperature", "top_k", "nucleus"])
+def test_program_holds_only_the_sampler_asked_for(
+        top_k, greedy, nucleus, absent, present):
+    model = _model()
+    low = _jitted_run(make_generate(model)).lower(
+        model.param_tree(), jnp.ones((2, 5), jnp.int32), 4,
+        jax.random.PRNGKey(0), jnp.float32(0.8), top_k, jnp.float32(0.9),
+        jnp.int32(0), jnp.int32(0), greedy, nucleus)
+    text = low.as_text()
+    for word in absent:
+        assert word not in text, word
+    for word in present:
+        assert word in text, word
+    # the argmax keeps the scope the device trace is read by
+    assert "generate.sample" in low.as_text(debug_info=True)
+
+
+def test_new_temperature_reuses_the_compiled_program():
+    """temperature and top_p VALUES are traced: only their class
+    (greedy or not, nucleus or not) is part of the program's key."""
+    model = _model()
+    gen = make_generate(model)
+    run = _jitted_run(gen)
+    prompt = np.ones((2, 4), np.int32)
+    kw = dict(max_new=3, rng=jax.random.PRNGKey(1), top_k=5)
+    before = run._cache_size()
+    gen(model.param_tree(), prompt, temperature=0.7, top_p=0.9, **kw)
+    gen(model.param_tree(), prompt, temperature=1.3, top_p=0.8, **kw)
+    assert run._cache_size() == before + 1
+    # greedy calls share ONE program whatever top_k / top_p they carry
+    gen(model.param_tree(), prompt, max_new=3)
+    gen(model.param_tree(), prompt, max_new=3, top_k=5, top_p=0.9)
+    assert run._cache_size() == before + 2
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("temperature_only", dict(temperature=0.8)),
+    ("top_k_only", dict(temperature=0.8, top_k=5)),
+    ("top_p_0", dict(temperature=0.8, top_p=0.0)),
+    ("top_p_1", dict(temperature=0.8, top_p=1.0)),
+    ("top_p_1.5", dict(temperature=0.8, top_p=1.5)),
+    ("top_p_0.9", dict(temperature=0.8, top_p=0.9)),
+    ("temperature_1.3_top_k", dict(temperature=1.3, top_k=5)),
+])
+def test_sampled_ids_are_bitwise_the_parent_commits(case, kw):
+    """tests/fixtures/generate_pr23_ids.json: what the commit before
+    the static sampler (PR 24: every arm computed, chosen by
+    ``jnp.where``) gave for the same model, prompt and key.  top_p
+    outside (0, 1) takes the no-nucleus arm; 0.9 the nucleus arm."""
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "generate_pr23_ids.json")) as f:
+        want = json.load(f)
+    model = _model()
+    prompt = np.random.RandomState(0).randint(1, 24, (2, 5)).astype(
+        np.int32)
+    got = np.asarray(make_generate(model)(
+        model.param_tree(), prompt, max_new=7, rng=jax.random.PRNGKey(3),
+        **kw))
+    np.testing.assert_array_equal(got, np.asarray(want[case]))
+    if case.startswith("top_p_") and case != "top_p_0.9":
+        assert want[case] == want["temperature_only"]
+    assert want["top_p_0.9"] != want["temperature_only"]
 
 
 @pytest.mark.slow  # ~7s; the EOS variant below runs the same

@@ -615,7 +615,11 @@ def test_generate_ids_are_bitwise_the_parent_commits(case):
     np.testing.assert_array_equal(got, np.asarray(want))
 
 
-def test_generate_scopes_name_the_lowered_operations():
+@pytest.mark.parametrize("temperature,top_p,greedy,nucleus", [
+    (0.0, 1.0, True, False), (0.8, 0.9, False, True)],
+    ids=["greedy", "nucleus"])
+def test_generate_scopes_name_the_lowered_operations(
+        temperature, top_p, greedy, nucleus):
     from bigdl_tpu.models.generate import make_generate
 
     model = _tiny_lm()
@@ -624,8 +628,9 @@ def test_generate_scopes_name_the_lowered_operations():
                if hasattr(c.cell_contents, "lower"))
     prompt = jnp.ones((2, 5), jnp.int32)
     text = run.lower(model.param_tree(), prompt, 4, jax.random.PRNGKey(0),
-                     jnp.float32(0.0), 0, jnp.float32(1.0),
-                     jnp.int32(0), jnp.int32(0)).as_text(debug_info=True)
+                     jnp.float32(temperature), 0, jnp.float32(top_p),
+                     jnp.int32(0), jnp.int32(0), greedy,
+                     nucleus).as_text(debug_info=True)
     for scope in ("generate.cast_params", "generate.prefill",
                   "generate.decode_step", "generate.sample"):
         assert scope in text, scope
