@@ -13,7 +13,11 @@ a token or a kernel call is taken to cost.  Conventions:
 
 ``dims(config)`` maps either dialect's published keys (GPT-2's ``n_*``,
 the llama family's ``hidden_size`` ...) to one small dict, so every
-function below serves every configuration file.
+function below serves both configurations the benchmark has.  It is not
+made to guess a third dialect: a new architecture (a mixer, a router, a
+latent projection) brings its arithmetic — its own ``dims``, step bytes
+and kernel operations — in a new file beside this one, which its own
+readers import; this file and the readers that use it stay as they are.
 """
 from __future__ import annotations
 

@@ -1,71 +1,95 @@
 """The system under test, built from a configuration file.
 
-The only place the benchmark touches the program's classes: the model
-constructor (``program.kwargs`` of the configuration), the relabelling
-between the references' flat parameter names and the module tree
-(``program.layout``), and the seeded weights handed over.  The weights
-are the BENCHMARK's (``reference.common.make_params``): the program is
-given them, the reference makes its own from the same seed.
+The only place the benchmark touches the program's model, and it knows
+no architecture: the configuration's own ``program`` object names the
+constructor (``class``, ``"package.module:Name"``), its ``kwargs``, and
+``params`` — the relabelling between the reference's flat parameter
+names and the program's parameter tree:
+
+    "params": {
+      "depth":  "num_hidden_layers",
+      "top":    {"embed": ["0", "weight"], "norm": ["L+1", "weight"], ...},
+      "first_layer": 1,
+      "kinds":  "block",
+      "layers": {"block": {"attn.wq": ["1", "wq"], ...}}
+    }
+
+``depth`` names the configuration's key that holds the number of
+layers.  ``top`` maps each top-level name to its path in the tree;
+``layers`` does the same for each KIND of layer, below that layer's own
+child, which is child ``first_layer + i`` for layer ``i``.  ``kinds``
+says which kind each layer is: one name for all, or a list that is
+repeated down the stack (a whole list or one period of it).  A path
+element ``L`` or ``L+n`` counts from the number of layers, so one table
+serves every depth.  The weights are the BENCHMARK's
+(``reference.common.make_params``): the program is given them, the
+reference makes its own from the same seed.
 """
 from __future__ import annotations
 
 import importlib
+import importlib.util
+import os
+import re
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+_REL = re.compile(r"^L(?:\+(\d+))?$")
 
 
-def reference_for(cfg: dict):
-    return importlib.import_module(f"benchmark.reference.{cfg['reference']}")
+def reference_for(cfg: dict, overlay: str | None = None):
+    """``reference/<name>.py`` — beside the manifest first (a test's or
+    a later PR's own file), then in this directory, as ``run.find``
+    looks for a driver or a reader."""
+    name = cfg["reference"]
+    path = overlay and os.path.join(os.path.abspath(overlay), "benchmark",
+                                    "reference", name + ".py")
+    if not path or path.startswith(HERE + os.sep) or not os.path.exists(path):
+        return importlib.import_module(f"benchmark.reference.{name}")
+    # under the package's own name, so that its relative imports
+    # (``from .common import mm``) find the benchmark's; loaded once
+    modname = f"benchmark.reference.overlay_{name}"
+    if getattr(sys.modules.get(modname), "__file__", None) != path:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]
 
 
-# flat reference name -> path in one TransformerBlock's param tree
-_BLOCK = {
-    "gpt2": {"ln_1.g": ("0", "weight"), "ln_1.b": ("0", "bias"),
-             "attn.wq": ("1", "wq"), "attn.bq": ("1", "bq"),
-             "attn.wk": ("1", "wk"), "attn.bk": ("1", "bk"),
-             "attn.wv": ("1", "wv"), "attn.bv": ("1", "bv"),
-             "attn.wo": ("1", "wo"), "attn.bo": ("1", "bo"),
-             "ln_2.g": ("2", "weight"), "ln_2.b": ("2", "bias"),
-             "mlp.w_fc": ("3", "weight"), "mlp.b_fc": ("3", "bias"),
-             "mlp.w_proj": ("4", "weight"), "mlp.b_proj": ("4", "bias")},
-    "llama": {"input_norm": ("0", "weight"),
-              "attn.wq": ("1", "wq"), "attn.wk": ("1", "wk"),
-              "attn.wv": ("1", "wv"), "attn.wo": ("1", "wo"),
-              "post_norm": ("2", "weight"), "mlp.gate": ("3", "weight"),
-              "mlp.up": ("4", "weight"), "mlp.down": ("5", "weight")},
-}
-
-
-def _top(layout: str, n_layers: int) -> dict:
-    """flat top-level name -> path in the TransformerLM param tree
-    (children keyed by index: 0 embedding, 1..L blocks, L+1 final norm,
-    L+2 head; GPT-2's position table is the model's own ``pos``)."""
-    nf, hd = str(n_layers + 1), str(n_layers + 2)
-    if layout == "gpt2":
-        return {"wte": ("0", "weight"), "wpe": ("pos",),
-                "ln_f.g": (nf, "weight"), "ln_f.b": (nf, "bias"),
-                "lm_head": (hd, "weight")}
-    return {"embed": ("0", "weight"), "norm": (nf, "weight"),
-            "lm_head": (hd, "weight")}
+def _element(el: str, n_layers: int) -> str:
+    m = _REL.match(el)
+    return str(n_layers + int(m.group(1) or 0)) if m else el
 
 
 def paths(cfg: dict) -> dict:
     """Every flat reference name -> its path in the program's tree."""
-    layout = cfg["program"]["layout"]
-    n = reference_for(cfg).n_layers(cfg)
-    out = dict(_top(layout, n))
+    table = cfg["program"]["params"]
+    n = int(cfg[table["depth"]])
+    kinds = table["kinds"]
+    if isinstance(kinds, str):
+        kinds = [kinds]
+    out = {name: tuple(_element(el, n) for el in path)
+           for name, path in table["top"].items()}
     for i in range(n):
-        for name, sub in _BLOCK[layout].items():
-            out[f"h.{i}.{name}"] = (str(i + 1),) + sub
+        child = str(table["first_layer"] + i)
+        for name, sub in table["layers"][kinds[i % len(kinds)]].items():
+            out[f"h.{i}.{name}"] = (child,) + tuple(sub)
     return out
 
 
-def to_tree(cfg: dict, flat: dict) -> dict:
+def _nest(table: dict, flat: dict) -> dict:
     tree: dict = {}
-    for name, path in paths(cfg).items():
+    for name, path in table.items():
         node = tree
         for key in path[:-1]:
             node = node.setdefault(key, {})
         node[path[-1]] = flat[name]
     return tree
+
+
+def to_tree(cfg: dict, flat: dict) -> dict:
+    return _nest(paths(cfg), flat)
 
 
 def from_tree(cfg: dict, tree: dict) -> dict:
@@ -78,8 +102,15 @@ def from_tree(cfg: dict, tree: dict) -> dict:
     return flat
 
 
-def build_model(cfg: dict, seed: int, clock=None):
-    """The program's model, holding the benchmark's seeded weights.
+def model_class(cfg: dict):
+    module, _, name = cfg["program"]["class"].partition(":")
+    return getattr(importlib.import_module(module), name)
+
+
+def build_model(cfg: dict, seed: int, clock=None, ref=None):
+    """The program's model, holding the benchmark's seeded weights;
+    ``ref`` is the configuration's reference where the caller has found
+    it already (``reference_for`` with the manifest's directory).
 
     The constructor draws its own initial weights first (the program's
     behaviour; they are dropped leaf by leaf as ours go in).  The tree
@@ -87,12 +118,10 @@ def build_model(cfg: dict, seed: int, clock=None):
     know, or the other way round, is an error, not a default."""
     import jax
 
-    from bigdl_tpu.models.transformer import TransformerLM
-
     from .reference import common
 
-    ref = reference_for(cfg)
-    model = TransformerLM(**cfg["program"]["kwargs"])
+    ref = ref or reference_for(cfg)
+    model = model_class(cfg)(**cfg["program"]["kwargs"])
     own = model.param_tree()
     if clock is not None:
         jax.block_until_ready(own)
@@ -105,12 +134,16 @@ def build_model(cfg: dict, seed: int, clock=None):
     del own
     flat = common.make_params(ref.param_specs(cfg), ref.n_layers(cfg),
                               cfg["initializer_range"], seed)
-    tree = to_tree(cfg, flat)
+    table = paths(cfg)
+    tree = _nest({k: p for k, p in table.items() if k in flat}, flat)
     ours = jax.tree_util.tree_map(lambda a: tuple(a.shape), tree)
-    if ours != shapes:
+    if ours != shapes or set(table) != set(flat):
         raise ValueError("the configuration's reference and the "
                          "program's model disagree on the parameter "
-                         f"tree: program {shapes} vs reference {ours}")
+                         f"tree: program {shapes} vs reference {ours}; "
+                         "names only in program.params "
+                         f"{sorted(set(table) - set(flat))}, only in the "
+                         f"reference {sorted(set(flat) - set(table))}")
     model.set_param_tree(tree)
     if clock is not None:
         jax.block_until_ready(tree)

@@ -12,6 +12,7 @@ each position of the same prompts and tokens it reads the gap of the
 token that the fp8 reference puts first."""
 from __future__ import annotations
 
+import inspect
 from functools import partial
 
 import jax
@@ -23,14 +24,18 @@ from . import common
 
 @partial(jax.jit, static_argnums=(0, 3, 4))
 def _logits(ref_cfg, params, ids, head_from: int, mode: str):
-    """Logits [B, n, V] of the positions from ``head_from`` on."""
+    """Logits [B, n, V] of the positions from ``head_from`` on.  A
+    reference whose layers are of several kinds names a ``layer``
+    argument in its ``block`` and is told which layer this is."""
     ref, cfg_items = ref_cfg
     cfg = dict(cfg_items)
+    indexed = "layer" in inspect.signature(ref.block).parameters
     h = ref.embed(params, ids, cfg)
     for i in range(ref.n_layers(cfg)):
         lp = {k.split(".", 2)[2]: v for k, v in params.items()
               if k.startswith(f"h.{i}.")}
-        h = ref.block(lp, h, cfg, mode)
+        h = (ref.block(lp, h, cfg, mode, layer=i) if indexed
+             else ref.block(lp, h, cfg, mode))
     return ref.head(params, h[:, head_from:], cfg, mode)
 
 

@@ -14,7 +14,9 @@ configurations, mixes or metrics.
 A run makes its weights and inputs from ``--seed``, warms every shape
 the cell dispatches (set-up), measures for ``--seconds``, checks what
 the timed path produced against the plain reference, and prints ONE
-JSON object as the last line of stdout.  ``--trace 0`` reports the
+JSON object as the last line of stdout; its last key, ``checks``, holds
+every number compared beside its limit, and the same are the last lines
+of stderr.  ``--trace 0`` reports the
 cell's end-to-end metrics with the profiler off; ``--trace 1`` reports
 its per-layer metrics from a profiled stretch of the same window.
 Without a TPU (or with fewer chips than the cell asks for) it exits 2
@@ -40,23 +42,26 @@ def say(msg: str) -> None:
 
 class Checks:
     """Every number compared, printed beside its limit; ``ok`` is the
-    conjunction."""
+    conjunction.  ``key`` is the short name the number goes by in the
+    result line's ``checks`` and in the last lines of standard error."""
 
     def __init__(self):
         self.ok = True
         self.rows = []
 
-    def le(self, name: str, value: float, limit: float, why: str = ""):
+    def le(self, key: str, name: str, value: float, limit: float,
+           why: str = ""):
         good = bool(value == value and value <= limit)  # NaN fails
         self.ok &= good
-        self.rows.append((name, value, limit, good))
+        self.rows.append((key, float(value), float(limit), good))
         say(f"[check] {name}: {value:.6g} (limit <= {limit:.6g}) "
             f"{'ok' if good else 'FAILED'}{' — ' + why if why else ''}")
         return good
 
-    def true(self, name: str, cond: bool, detail: str = ""):
+    def true(self, key: str, name: str, cond: bool, detail: str = ""):
+        """A condition: reported as 0 (held) or 1 (broken), limit 0."""
         self.ok &= bool(cond)
-        self.rows.append((name, float(bool(cond)), 1.0, bool(cond)))
+        self.rows.append((key, float(not cond), 0.0, bool(cond)))
         say(f"[check] {name}: {'ok' if cond else 'FAILED'}"
             f"{' — ' + detail if detail else ''}")
         return bool(cond)
@@ -207,9 +212,11 @@ def main(argv=None) -> int:
                          "CPU backend; NOT a chip run, never a measurement")
     ap.add_argument("--control", type=int, choices=(0, 1), default=0,
                     help="for setting limits, never in a benchmark run: "
-                         "also put the reference at the next lower "
-                         "precision (fp8 e4m3) in the program's place and "
-                         "print what the comparison reads there")
+                         "the reference at the next lower precision (fp8 "
+                         "e4m3) stands in the program's place and ITS "
+                         "readings go through the comparison, so the run "
+                         "has to end with correct false; the program's "
+                         "own readings are printed beside them")
     args = ap.parse_args(argv)
 
     if ROOT not in sys.path:
@@ -264,8 +271,9 @@ def main(argv=None) -> int:
     ctx = Context(cell=cell, config=config, traffic=traffic, seed=args.seed,
                   seconds=float(args.seconds), trace=bool(args.trace),
                   chips=int(cell["chips"]), devices=devices[:cell["chips"]],
-                  root=ROOT, t_process=T_PROCESS, clock=clock, say=say,
-                  checks=Checks(), compiles=CompileCounter(jax),
+                  root=ROOT, overlay=overlay, t_process=T_PROCESS,
+                  clock=clock, say=say, checks=Checks(),
+                  compiles=CompileCounter(jax),
                   peaks=peaks, counts=counts, rehearsal=args.rehearse_cpu,
                   control=bool(args.control),
                   trace_dir=os.path.join(ROOT, ".bench_trace"))
@@ -312,7 +320,14 @@ def main(argv=None) -> int:
             "idle_gaps": ctx.trace_summary["idle_gaps"][:10]}
     if args.rehearse_cpu:
         result["rehearsal"] = True
+    # every number compared beside its limit: last in the result line,
+    # and as the last lines of standard error
+    result["checks"] = {key: {"value": value, "limit": limit}
+                        for key, value, limit, _ in ctx.checks.rows}
     print(json.dumps(result), flush=True)
+    for key, value, limit, good in ctx.checks.rows:
+        print(f"[check] {key}: {value:.6g} (limit <= {limit:.6g}) "
+              f"{'ok' if good else 'FAILED'}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -17,7 +17,8 @@ if ROOT not in sys.path:
 RENAME = {"gpt2m_train_1chip": "tiny_train_1chip",
           "gpt2m_train_dp4": "tiny_train_dp4",
           "mistral7b_serve_decode": "tiny_serve_open",
-          "mistral7b_serve_prefill": "tiny_serve_closed"}
+          "mistral7b_serve_prefill": "tiny_serve_closed",
+          "mistral7b_serve_decode_sat": "tiny_serve_sat"}
 
 
 def tiny_manifest(dst: str) -> dict:

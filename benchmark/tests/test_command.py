@@ -9,17 +9,19 @@ import pytest
 
 from conftest import ROOT, run_command
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 DEVICE = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
 @pytest.mark.parametrize("cell,e2e", [
     ("tiny_train_1chip", {"train_tokens_per_s_per_chip", "setup_s"}),
     ("tiny_train_dp4", {"train_tokens_per_s_per_chip", "setup_s"}),
-    ("tiny_serve_open", {"serve_tokens_per_s", "serve_latency_p50_s",
-                         "serve_latency_p95_s", "setup_s"}),
+    ("tiny_serve_open", {"serve_latency_p50_s", "serve_latency_p95_s",
+                         "setup_s"}),   # below the knee: judged on tails
     ("tiny_serve_closed", {"serve_tokens_per_s", "serve_latency_p50_s",
                            "serve_latency_p95_s", "setup_s"}),
+    ("tiny_serve_sat", {"serve_tokens_per_s", "serve_latency_p50_s",
+                        "serve_latency_p95_s", "setup_s"}),
 ])
 def test_end_to_end_line(overlay, cell, e2e):
     rc, obj, log = run_command(overlay, cell)
@@ -33,12 +35,19 @@ def test_end_to_end_line(overlay, cell, e2e):
     assert set(obj["metrics"]) == e2e
     for m in obj["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
+    # every number compared beside its limit, as the line's last key
+    assert list(obj)[-1] == "checks" and len(obj["checks"]) >= 5
+    for c in obj["checks"].values():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
 
 
 @pytest.mark.parametrize("cell,some", [
     ("tiny_train_1chip", {"train_step_p50_ms", "train_step_tail_ratio"}),
     ("tiny_serve_open", {"serve_queue_pct", "serve_batch_fill_pct",
-                         "serve_generator_late_p95_ms"}),
+                         "serve_generator_late_p95_ms",
+                         "serve_unresolved_at_stop"}),
+    ("tiny_serve_sat", {"serve_queue_pct", "serve_batch_fill_pct",
+                        "serve_batch_form_ms"}),
 ])
 def test_traced_line_reports_per_layer_metrics(overlay, cell, some):
     """On the CPU there is no chip plane to reduce, so only the readers
@@ -83,3 +92,28 @@ def test_no_accelerator_no_result(overlay):
         timeout=300)
     assert p.returncode != 0
     assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
+
+
+def test_sweep_tool_reports_the_spread_of_repeated_windows(overlay, tmp_path):
+    """``tools/sweep_open.py``: one warmed server, every window offered
+    ``--repeat`` times on each ``--schedule-seeds``; the last lines give
+    each schedule's run-to-run spread of p50 and p95."""
+    out = str(tmp_path / "sweep.json")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark/tools/sweep_open.py"),
+         "--manifest", os.path.join(overlay, "BENCHMARK.json"),
+         "--rehearse-cpu", "--workload", "tiny_serve_open", "--rates", "40",
+         "--seconds", "0.5", "--schedule-seeds", "7,8", "--repeat", "3",
+         "--out", out],
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=600)
+    assert p.returncode == 0, p.stdout + p.stderr[-3000:]
+    with open(out) as f:
+        rows = json.load(f)
+    assert [r["schedule_seed"] for r in rows[:6]] == [7, 7, 7, 8, 8, 8]
+    assert all(r["failed"] == 0 and r["p95_s"] >= r["p50_s"] > 0
+               for r in rows[:6])
+    assert [(r["schedule_seed"], r["runs"]) for r in rows[6:]] == [(7, 3),
+                                                                   (8, 3)]
+    assert all(r["p95_s_spread"] >= 0 and len(r["backlog_at_end"]) == 3
+               for r in rows[6:])

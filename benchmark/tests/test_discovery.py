@@ -1,10 +1,27 @@
-"""A configuration, a traffic mix and a per-layer reader dropped into
+"""A configuration, a traffic mix, a per-layer reader — and a whole new
+architecture: class, parameter table and plain reference — dropped into
 the directories are found by name: one new entry each in BENCHMARK.json,
-no edit to ``run.py`` or to any file that was there."""
+no edit to ``run.py``, ``program.py`` or any file that was there."""
+import hashlib
 import json
 import os
+import shutil
 
-from conftest import run_command
+import pytest
+
+from conftest import HERE, ROOT, run_command
+
+
+def _snapshot(top: str) -> dict:
+    """relative path -> digest of every file under ``top``."""
+    out = {}
+    for d, _, files in os.walk(top):
+        for name in files:
+            path = os.path.join(d, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, top)] = hashlib.sha1(
+                    f.read()).hexdigest()
+    return out
 
 
 def test_new_config_mix_and_reader_are_found(overlay):
@@ -54,3 +71,92 @@ def test_new_config_mix_and_reader_are_found(overlay):
     assert obj["metrics"]["serve_requests_ok"]["unit"] == "requests"
     # the old metrics that list the old cells only are not reported here
     assert "serve_queue_pct" not in obj["metrics"]
+
+
+def _add_newarch(overlay):
+    """Lay the new architecture's own files over the overlay — nothing
+    that was there is edited — and add its cell to the manifest."""
+    shutil.copytree(os.path.join(HERE, "newarch"), overlay, dirs_exist_ok=True)
+    path = os.path.join(overlay, "BENCHMARK.json")
+    with open(path) as f:
+        m = json.load(f)
+    m["configs"].append({"name": "tiny-rmsgelu", "source": "fixture",
+                         "file": "benchmark/configs/tiny-rmsgelu.json",
+                         "reduced": [], "why": "fixture"})
+    m["workloads"].append({"name": "tiny_newarch_cell",
+                           "config": "tiny-rmsgelu", "traffic": "tiny_closed",
+                           "chips": 1, "why": "fixture"})
+    for metric in m["end_to_end"]:
+        if metric["name"].startswith("serve_"):
+            metric["workloads"].append("tiny_newarch_cell")
+    with open(path, "w") as f:
+        json.dump(m, f)
+    with open(os.path.join(overlay, "benchmark/configs/tiny-rmsgelu.json")) as f:
+        return json.load(f)
+
+
+def test_new_architecture_is_found(overlay):
+    """A block neither old table described (RMSNorm gains, bias-free
+    rotary attention, a two-matrix GELU MLP, no position table): its
+    configuration carries the class and the parameter table, its plain
+    reference lies beside the manifest, and the cell runs ``correct``."""
+    before = _snapshot(overlay)
+    _add_newarch(overlay)
+    assert {k: v for k, v in _snapshot(overlay).items()
+            if k in before and k != "BENCHMARK.json"} == {
+        k: v for k, v in before.items() if k != "BENCHMARK.json"}
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmark", "reference", "rmsgelu.py"))
+    rc, obj, log = run_command(overlay, "tiny_newarch_cell", trace=0)
+    assert rc == 0 and obj["correct"], log
+    assert obj["failed"] == 0 and obj["attempted"] > 0
+    assert obj["metrics"]["serve_tokens_per_s"]["value"] > 0
+
+
+@pytest.mark.parametrize("fault", ["left_out", "unknown_to_the_model",
+                                   "unknown_to_the_reference"])
+def test_a_wrong_parameter_table_is_an_error(overlay, fault):
+    """Strict both ways: a parameter the table leaves out, or one it
+    names that the model (or the reference) lacks, fails in
+    ``build_model`` with both trees in the message."""
+    from benchmark import program
+
+    cfg = _add_newarch(overlay)
+    block = cfg["program"]["params"]["layers"]["block"]
+    if fault == "left_out":
+        del block["attn.wo"]
+    elif fault == "unknown_to_the_model":
+        block["mlp.b_fc"] = ["3", "no_such_leaf"]
+    else:
+        block["attn.bq"] = ["1", "bq"]
+    with pytest.raises(ValueError) as err:
+        program.build_model(cfg, 3000000023,
+                            ref=program.reference_for(cfg, overlay))
+    text = str(err.value)
+    assert "program {" in text and "vs reference {" in text
+    if fault == "left_out":
+        assert "only in the reference ['h.0.attn.wo'" in text
+    if fault == "unknown_to_the_reference":
+        assert "only in program.params ['h.0.attn.bq'" in text
+
+
+def test_layers_of_several_kinds():
+    """``kinds`` as a period: layer ``i`` takes the table of kind
+    ``kinds[i % len]``; ``L`` and ``L+n`` count from the depth the
+    table's ``depth`` key names."""
+    from benchmark import program
+
+    cfg = {"num_hidden_layers": 5,
+           "program": {"params": {
+               "depth": "num_hidden_layers",
+               "top": {"embed": ["0", "weight"], "norm": ["L+1", "weight"],
+                       "last": ["L"]},
+               "first_layer": 1, "kinds": ["mix", "mix", "attn"],
+               "layers": {"mix": {"in_proj": ["0", "w"]},
+                          "attn": {"wq": ["1", "wq"], "wk": ["1", "wk"]}}}}}
+    got = program.paths(cfg)
+    assert got["norm"] == ("6", "weight") and got["last"] == ("5",)
+    assert [k for k in got if k.startswith("h.")] == [
+        "h.0.in_proj", "h.1.in_proj", "h.2.wq", "h.2.wk", "h.3.in_proj",
+        "h.4.in_proj"]
+    assert got["h.2.wk"] == ("3", "1", "wk") and got["h.4.in_proj"] == ("5", "0", "w")

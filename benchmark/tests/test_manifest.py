@@ -5,7 +5,7 @@ import json
 import os
 import re
 
-from conftest import ROOT
+from conftest import HERE, RENAME, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -89,12 +89,51 @@ def test_every_named_file_is_there():
         assert set(c["reduced"]) == set(cfg["reduced"])     # every cut, with its reason
         assert "assumed" in cfg and "deployment" in cfg
         assert os.path.exists(os.path.join(bench, "reference", cfg["reference"] + ".py"))
+    from benchmark import run
+
     for w in m["workloads"]:
-        with open(os.path.join(bench, "traffic", w["traffic"] + ".json")) as f:
-            mix = json.load(f)
-        assert os.path.exists(os.path.join(bench, "drivers", mix["driver"] + ".py"))
+        mix = run.load_json(run.find("traffic", w["traffic"], ".json", ROOT))
+        assert hasattr(run.load_py("drivers", mix["driver"], ROOT), "run")
         assert "limits" in mix
+        assert w["name"] in RENAME, "every real cell has a toy twin"
     for x in m["per_layer"]:
         assert os.path.exists(os.path.join(bench, "readers", x["name"] + ".py")), x["name"]
     open_mix = json.load(open(os.path.join(bench, "traffic", "open_p128_n96.json")))
     assert abs(open_mix["rate_rps"] - 0.8 * open_mix["knee_rps"]) < 1e-9  # stored, from the sweep
+
+
+def test_parameter_paths_are_the_recorded_ones():
+    """The tables in the configuration files give, entry for entry and
+    in the same order, the maps ``program.py`` held in code up to PR 25:
+    weights, programs and compile-cache keys are the parent's."""
+    from benchmark import program
+
+    with open(os.path.join(HERE, "recorded", "paths_pr25.json")) as f:
+        recorded = json.load(f)
+    m = manifest()
+    assert {c["name"] for c in m["configs"]} == set(recorded)
+    for c in m["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["program"]["class"].count(":") == 1
+        got = program.paths(cfg)
+        assert [(k, list(v)) for k, v in got.items()] == list(
+            recorded[c["name"]].items()), c["name"]
+
+
+def test_the_saturated_cell_and_its_twin():
+    """``mistral7b_serve_decode_sat`` is the decode cell's shapes and
+    limits under a closed loop of four batches' worth of callers; its
+    toy twin keeps that ratio."""
+    bench = os.path.join(ROOT, "benchmark", "traffic")
+    sat = json.load(open(os.path.join(bench, "closed64_p128_n96.json")))
+    dec = json.load(open(os.path.join(bench, "open_p128_n96.json")))
+    for key in ("prompt_len", "max_new", "max_batch", "generate_dtype",
+                "check_requests", "reference_rows", "limits"):
+        assert sat[key] == dec[key], key
+    assert sat["driver"] == "serve_closed"
+    assert sat["clients"] == 4 * sat["max_batch"] == 64
+    twin = json.load(open(os.path.join(
+        HERE, "tiny", "benchmark", "traffic", "tiny_closed_sat.json")))
+    assert twin["driver"] == "serve_closed"
+    assert twin["clients"] == 4 * twin["max_batch"]
