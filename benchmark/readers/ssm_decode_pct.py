@@ -1,0 +1,17 @@
+"""Share of chip 0's busy time spent on the one-token update of the
+recurrent state: self time of operations whose HLO ``op_name`` lies
+under the ``mixer.ssm_step`` scope (``jax.named_scope`` in
+``nn/mamba.py``: the conv tail's shift and the state's decay, update and
+read-out, in every decode step of every hybrid layer) over the busy
+seconds of the traced window — read as ``decode_sample_pct`` is, in the
+one pass ``_h1_scopes`` makes for this reader and ``ssd_scan_roofline``.
+A program without that scope (no such block) gives nothing to read."""
+from benchmark.readers import _h1_scopes
+
+
+def read(ctx):
+    scoped = (_h1_scopes.scope_seconds(ctx) or {}).get("mixer.ssm_step")
+    summary = getattr(ctx, "trace_summary", None)
+    if not scoped or not summary or summary["busy_s"] <= 0:
+        return None
+    return 100.0 * scoped / summary["busy_s"]

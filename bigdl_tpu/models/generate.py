@@ -1,4 +1,6 @@
-"""Autoregressive generation for TransformerLM — KV-cache decode.
+"""Autoregressive generation for TransformerLM and HybridMambaLM —
+KV-cache decode, with a recurrent state beside the K/V where a block
+has one.
 
 The reference predates autoregressive LMs entirely (its sequence story
 is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
@@ -9,6 +11,13 @@ decode steps at static shapes, with the caches (``[B, Hkv, T_max,
 Dh]`` — the KV head count, smaller than the query's under GQA)
 updated in place via ``lax.dynamic_update_slice``.  No Python-level
 loop over tokens, no recompilation per length.
+
+A hybrid block (``nn.HybridMambaBlock``) keeps, beside its K/V, the
+Mamba-2 mixer's SSM state ``[B, heads, head, N]`` (float32) and conv
+tail ``[B, d_conv - 1, channels]`` in the same per-layer cache dict:
+prefill runs the chunked scan and hands the state after the last
+prompt token to the decode scan, which advances it one token a step.
+The paged path keeps K/V pages only and refuses such a block.
 
 Built from the model's OWN parameter tree and modules (the
 parallel/pipeline.py pattern): LN/MLP sublayers run through their
@@ -52,6 +61,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..nn.mamba import scaled as _scaled
+
 # compiled generators per model instance (weak: dies with the model),
 # keyed by build config.  NOT stored on the module itself — a jitted
 # closure attribute would break the pickle-based checkpoint verbs.
@@ -59,11 +70,12 @@ _GEN_CACHE = weakref.WeakKeyDictionary()
 
 
 def _check_model(model):
+    from .hybrid_mamba import HybridMambaLM
     from .transformer import TransformerLM
 
-    if not isinstance(model, TransformerLM):
+    if not isinstance(model, (TransformerLM, HybridMambaLM)):
         raise TypeError(
-            f"generation supports TransformerLM (got "
+            f"generation supports TransformerLM and HybridMambaLM (got "
             f"{type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
@@ -71,6 +83,24 @@ def _check_model(model):
     # single-shard attention as a dense one (pinned against a dense twin
     # built from the same params in tests/test_generate.py)
     return 1, len(model.modules) - 3
+
+
+def _is_hybrid(block) -> bool:
+    """A block that carries a recurrent state beside its K/V
+    (``nn.HybridMambaBlock``)."""
+    return getattr(block, "kind", None) == "hybrid_mamba"
+
+
+def _refuse_recurrent(model, first, count, what: str):
+    """The paged path keeps K/V pages only: a block with a recurrent
+    state has nowhere to put it there, so it is refused, not decoded
+    without its state."""
+    if any(_is_hybrid(b) for b in model.modules[first:first + count]):
+        raise TypeError(
+            f"{what} pages K/V only and {type(model).__name__}'s blocks "
+            f"carry a recurrent state (SSM state and conv tail) beside "
+            f"it: decode this model through generate() / "
+            f"submit_generate(), whose static cache holds both")
 
 
 def _check_len(model, max_len):
@@ -204,12 +234,15 @@ def _ffn_sublayer(block, bp, h):
     if kind == "moe":
         ffn = _moe_ffn_nodrop(block.modules[3], bp["3"], ln2)
     elif kind == "swiglu":
+        # a hybrid block's two muP constants; (1, 1) multiplies nothing
+        gm, dm = getattr(block, "mlp_multipliers", (1.0, 1.0))
         g, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
                                          None)
         u, _ = block.modules[4].apply_fn(bp["4"], {}, ln2, False,
                                          None)
         ffn, _ = block.modules[5].apply_fn(
-            bp["5"], {}, jax.nn.silu(g) * u, False, None)
+            bp["5"], {}, jax.nn.silu(_scaled(g, gm)) * u, False, None)
+        ffn = _scaled(ffn, dm)
     else:
         mid, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
                                            None)
@@ -218,6 +251,49 @@ def _ffn_sublayer(block, bp, h):
                                            None)
         ffn = out
     return h + ffn
+
+
+def _cache_init(block, B, T_max, dt, kv_int8=False):
+    """One layer's state for ``B`` rows, a dict: K and V ``[B, Hkv,
+    T_max, Dh]`` (int8 with ``k_scale`` / ``v_scale`` beside them under
+    ``kv_int8``) and, for a hybrid block, the mixer's ``ssm`` state and
+    ``conv`` tail beside those."""
+    mha = block.modules[1]
+    Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
+    kv = (B, Hkv, T_max, mha.head_dim)
+    if kv_int8:
+        cache = {"k": jnp.zeros(kv, jnp.int8),
+                 "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
+                 "v": jnp.zeros(kv, jnp.int8),
+                 "v_scale": jnp.zeros(kv[:3] + (1,), jnp.float32)}
+    else:
+        cache = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
+    if _is_hybrid(block):
+        cache.update(block.mixer.state_init(B, dt))
+    return cache
+
+
+def cache_footprint(model, batch: int, compute_dtype=None,
+                    max_len: Optional[int] = None,
+                    kv_dtype: Optional[str] = None) -> dict:
+    """Bytes of state one generate call of ``batch`` rows holds on the
+    device, from shapes alone: ``kv_cache_bytes`` (the static K/V of
+    every layer) and ``recurrent_state_bytes`` (SSM state and conv tail;
+    zero for a model without them)."""
+    first, count = _check_model(model)
+    T_max = _check_len(model, max_len)
+    dt = jnp.dtype(compute_dtype or jax.tree_util.tree_leaves(
+        model.param_tree())[0].dtype)
+    out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0}
+    for block in model.modules[first:first + count]:
+        shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
+                                        T_max, dt, _kv_int8(kv_dtype)))
+        for name, a in shapes.items():
+            kind = ("kv_cache_bytes"
+                    if name in ("k", "v", "k_scale", "v_scale")
+                    else "recurrent_state_bytes")
+            out[kind] += a.size * a.dtype.itemsize
+    return out
 
 
 def _decode_machinery(model, first, count, T_max, kv_int8=False):
@@ -265,48 +341,35 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
         q_ = jnp.round(x.astype(jnp.float32) / s_).astype(jnp.int8)
         return q_, s_
 
-    def _cache_init(B, dt):
-        if kv_int8:
-            return (jnp.zeros((B, Hkv, T_max, Dh), jnp.int8),
-                    jnp.zeros((B, Hkv, T_max, 1), jnp.float32),
-                    jnp.zeros((B, Hkv, T_max, Dh), jnp.int8),
-                    jnp.zeros((B, Hkv, T_max, 1), jnp.float32))
-        return (jnp.zeros((B, Hkv, T_max, Dh), dt),
-                jnp.zeros((B, Hkv, T_max, Dh), dt))
-
     def _cache_write(cache, k, v, pos):
-        if kv_int8:
-            kq, ks, vq, vs = cache
-            qk, sk = _quant(k)
-            qv, sv = _quant(v)
-            return (lax.dynamic_update_slice(kq, qk, (0, 0, pos, 0)),
-                    lax.dynamic_update_slice(ks, sk, (0, 0, pos, 0)),
-                    lax.dynamic_update_slice(vq, qv, (0, 0, pos, 0)),
-                    lax.dynamic_update_slice(vs, sv, (0, 0, pos, 0)))
-        kc, vc = cache
-        return (lax.dynamic_update_slice(kc, k, (0, 0, pos, 0)),
-                lax.dynamic_update_slice(vc, v, (0, 0, pos, 0)))
+        new = dict(cache)
+        for name, x in (("k", k), ("v", v)):
+            if kv_int8:
+                x, scale = _quant(x)
+                new[name + "_scale"] = lax.dynamic_update_slice(
+                    cache[name + "_scale"], scale, (0, 0, pos, 0))
+            new[name] = lax.dynamic_update_slice(cache[name], x,
+                                                 (0, 0, pos, 0))
+        return new
 
     def _cache_kv(cache, dt):
         """(k, v) dense views of the cache — for int8 the convert+
         scale is elementwise and fuses into the attention dot's
         operand read (the int8 bytes are what HBM streams)."""
         if kv_int8:
-            kq, ks, vq, vs = cache
-            return kq.astype(dt) * ks.astype(dt), \
-                vq.astype(dt) * vs.astype(dt)
-        return cache
+            return (cache["k"].astype(dt) * cache["k_scale"].astype(dt),
+                    cache["v"].astype(dt) * cache["v_scale"].astype(dt))
+        return cache["k"], cache["v"]
 
-    def _block_step(block, bp, h, cache, pos):
-        """One block on Tq tokens (prefill: Tq=T0 at pos 0; decode:
-        Tq=1) against the cache pytree; returns (h, cache)."""
+    def _attention(block, ap, ln1, cache, pos):
+        """Cached attention of one block on Tq tokens at ``pos``;
+        returns (the output projection's result, cache)."""
         mha = block.modules[1]
-        B = h.shape[0]
-        ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
-        ap = bp["1"]
+        B = ln1.shape[0]
         q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
         v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
+        k = _scaled(k, getattr(mha, "key_multiplier", 1.0))
         if use_rope:
             # rotate at ABSOLUTE positions; the cache stores rotated
             # keys (the standard KV-cache convention for RoPE)
@@ -336,11 +399,33 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
         else:
             o = _attend(q, *_cache_kv(cache, q.dtype), pos)
         o = o.transpose(0, 2, 1, 3).reshape(B, o.shape[2], H * Dh)
-        h = h + _proj(o, ap, "wo", "bo", mha.with_bias)
-        return _ffn_sublayer(block, bp, h), cache
+        return _proj(o, ap, "wo", "bo", mha.with_bias), cache
+
+    def _block_step(block, bp, h, cache, pos):
+        """One block on Tq tokens (prefill: Tq=T0 at pos 0; decode:
+        Tq=1) against its cache; returns (h, cache).  A hybrid block's
+        mixer reads the same normed input as its attention: prefill
+        runs the chunked scan from an empty state and keeps the state
+        after the last prompt token, a decode step advances it."""
+        ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
+        if not _is_hybrid(block):
+            a, cache = _attention(block, bp["1"], ln1, cache, pos)
+            return _ffn_sublayer(block, bp, h + a), cache
+        with jax.named_scope("mixer.attention"):
+            a, cache = _attention(
+                block, bp["1"],
+                _scaled(ln1, block.attention_in_multiplier), cache, pos)
+        mixer = block.mixer
+        if isinstance(pos, int) and pos == 0:
+            m, state = mixer.sequence(bp["6"], ln1)
+        else:
+            m, state = mixer.step(bp["6"], ln1, cache)
+        return (_ffn_sublayer(block, bp, block.mix(h, a, m)),
+                {**cache, **state})
 
     def _embed_at(pc, tok, pos, Tq):
         h, _ = embed.apply_fn(pc["0"], {}, tok, False, None)
+        h = _scaled(h, getattr(model, "embedding_multiplier", 1.0))
         if use_rope:  # positions live in the per-layer q/k rotation
             return h
         return h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq)
@@ -352,7 +437,7 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
         h = _embed_at(pc, prompt, 0, T0)
         caches = []
         for bi, block in enumerate(blocks):
-            cache = _cache_init(B, dt)
+            cache = _cache_init(block, B, T_max, dt, kv_int8)
             h, cache = _block_step(block, pc[str(first + bi)], h,
                                    cache, 0)
             caches.append(cache)
@@ -375,6 +460,7 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
         h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
         h, _ = head.apply_fn(pc[str(first + count + 1)], {}, h, False,
                              None)
+        h = _scaled(h, getattr(model, "lm_head_multiplier", 1.0))
         return h[:, 0, :].astype(jnp.float32)
 
     return prefill, decode_token, logits_last
@@ -903,6 +989,7 @@ class PagedDecoder:
             raise ValueError(f"page_window must be >= 1 pages, got "
                              f"{page_window}")
         first, count = _check_model(model)
+        _refuse_recurrent(model, first, count, "PagedDecoder (KVPagePool)")
         mha0 = model.modules[first].modules[1]
         Hkv = getattr(mha0, "num_kv_heads", mha0.num_heads)
         if (pool.layers, pool.num_kv_heads, pool.head_dim) != \

@@ -273,7 +273,7 @@ class TransformerLM(Container):
     def grad_tree(self):
         tree = super().grad_tree()
         if not getattr(self, 'use_rope', False):
-            tree["pos"] = self.grads["pos"]
+            tree["pos"] = self._grad("pos")
         return tree
 
     def set_grad_tree(self, tree):

@@ -67,6 +67,7 @@ from .criterion import (
     TimeDistributedCriterion,
 )
 from .attention import MultiHeadAttention
+from .mamba import HybridMambaBlock, Mamba2Mixer
 from .recurrent import (
     BiRecurrent, Cell, ConvLSTMPeephole, GRU, LSTM, LSTMPeephole, Recurrent,
     RnnCell, TimeDistributed,

@@ -72,15 +72,22 @@ class MultiHeadAttention(TensorModule):
                  sparse_pattern: str = "sliding",
                  sparse_window: int = 2, sparse_globals: int = 1,
                  sparse_stride: int = 4,
-                 sparse_block: "int | None" = None):
+                 sparse_block: "int | None" = None,
+                 head_dim: "int | None" = None,
+                 key_multiplier: float = 1.0):
         super().__init__()
-        assert embed_dim % num_heads == 0, "embed_dim % num_heads != 0"
+        assert head_dim or embed_dim % num_heads == 0, \
+            "embed_dim % num_heads != 0"
         if seq_strategy not in SEQ_STRATEGIES:
             raise ValueError(f"seq_strategy {seq_strategy!r} not in "
                              f"{SEQ_STRATEGIES}")
         self.embed_dim = embed_dim
         self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        # heads need not tile the width: ``head_dim`` given, q and the
+        # output projection are [heads * head_dim] wide
+        self.head_dim = int(head_dim or embed_dim // num_heads)
+        # a constant scale on the keys (a muP multiplier); 1 = none
+        self.key_multiplier = float(key_multiplier)
         self.causal = causal
         self.with_bias = with_bias
         self.seq_strategy = seq_strategy
@@ -117,11 +124,13 @@ class MultiHeadAttention(TensorModule):
         w_init = self._init_methods.get("weight", (Xavier(), None))[0]
         b_init = self._init_methods.get("bias", (Zeros(), None))[0]
         E = self.embed_dim
+        qd = self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
-        for name, rows in (("wq", E), ("wk", kv), ("wv", kv), ("wo", E)):
-            self._register_param(name, w_init.init((rows, E), IN_OUT))
+        for name, shape in (("wq", (qd, E)), ("wk", (kv, E)),
+                            ("wv", (kv, E)), ("wo", (E, qd))):
+            self._register_param(name, w_init.init(shape, IN_OUT))
         if self.with_bias:
-            for name, n in (("bq", E), ("bk", kv), ("bv", kv), ("bo", E)):
+            for name, n in (("bq", qd), ("bk", kv), ("bv", kv), ("bo", E)):
                 self._register_param(name, b_init.init((n,), ONE_D))
         return self
 
@@ -188,6 +197,8 @@ class MultiHeadAttention(TensorModule):
         q = self._split(proj(x, params["wq"], "bq"))
         k = self._split(proj(x, params["wk"], "bk"), self.num_kv_heads)
         v = self._split(proj(x, params["wv"], "bv"), self.num_kv_heads)
+        if getattr(self, "key_multiplier", 1.0) != 1.0:
+            k = k * self.key_multiplier
         if self.rope:
             pos = jnp.arange(q.shape[2])
             q = rope_rotate(q, pos, self.rope_theta)

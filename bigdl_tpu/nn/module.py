@@ -55,6 +55,19 @@ def to_array(x):
     return x
 
 
+def hold_floats(tree, dtype):
+    """``tree`` with every floating leaf in ``dtype`` — one cast per
+    leaf that is not there yet, none for one that is, so the tree and
+    its copy never both exist whole.  ``dtype`` None: ``tree`` itself."""
+    if dtype is None:
+        return tree
+    dtype = jnp.dtype(dtype)
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) and a.dtype != dtype
+        else a, tree)
+
+
 class AbstractModule:
     """Base layer.  Subclasses define ``_build()`` (register params) and
     ``_apply(params, buffers, input, training, rng) -> (output, new_buffers)``.
@@ -99,7 +112,20 @@ class AbstractModule:
         self.params = dict(tree)
 
     def grad_tree(self):
+        """The gradient buffers, made on first use: a module owns none
+        until something asks (this, ``zero_grad_parameters``, a
+        backward) — a model that is only served, or trained through a
+        plan engine, never pays for them."""
+        for name in self.params:
+            self._grad(name)
         return dict(self.grads)
+
+    def _grad(self, name: str) -> jax.Array:
+        """One gradient buffer, zeros like its parameter when first
+        asked for."""
+        if name not in self.grads:
+            self.grads[name] = jnp.zeros_like(self.params[name])
+        return self.grads[name]
 
     def set_grad_tree(self, tree):
         self.grads = dict(tree)
@@ -112,7 +138,9 @@ class AbstractModule:
 
     def _register_param(self, name: str, value: jax.Array):
         self.params[name] = value
-        self.grads[name] = jnp.zeros_like(value)
+        # a buffer from an earlier draw is stale; the next one that is
+        # asked for is zeros like the new value (``grad_tree``)
+        self.grads.pop(name, None)
 
     def _register_buffer(self, name: str, value: jax.Array):
         self.buffers[name] = value

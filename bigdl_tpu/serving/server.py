@@ -212,6 +212,8 @@ class InferenceServer:
         self.policy = policy or RetryPolicy.from_properties(
             prefix="bigdl.serving")
         self.generate_dtype = generate_dtype
+        #: bucket -> cache_footprint of one generate call (span args)
+        self._gen_footprints = {}
         self._queue = _BoundedQueue(max_queue)
         self._batch_window_s = float(batch_window_s)
         self._default_deadline_s = default_deadline_s
@@ -1158,7 +1160,7 @@ class InferenceServer:
         max_new): prompts stack along the batch dim and pad up to the
         bucket by repeating the last row (same ladder as classify, so
         generation traffic can't recompile per batch count either)."""
-        from ..models.generate import cached_generate
+        from ..models.generate import cache_footprint, cached_generate
 
         tr = self._tracer
         max_new, eos_id, pad_id = reqs[0].opts
@@ -1178,9 +1180,15 @@ class InferenceServer:
         # the call returning: eos/pad lookup, argument conversion and
         # the enqueue of ONE compiled program (a build on a new
         # signature); the decode itself runs behind it
+        # what the bucket holds on the device beside the weights, from
+        # shapes (zero recurrent bytes for a model without the state)
+        holds = self._gen_footprints.get(bucket)
+        if holds is None:
+            holds = self._gen_footprints[bucket] = cache_footprint(
+                self.model, bucket, compute_dtype=self.generate_dtype)
         with tr.span("serve.dispatch",
                      "compile" if new_sig else "dispatch",
-                     compiled=new_sig):
+                     compiled=new_sig, **holds):
             gen = cached_generate(self.model,
                                   compute_dtype=self.generate_dtype)
             ids = gen(params, prompts_j, max_new, eos_id=eos_id,

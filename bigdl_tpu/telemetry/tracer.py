@@ -54,7 +54,8 @@ from typing import Callable, Dict, List, Optional
 
 from .trace_context import REQUEST_CATEGORIES
 
-__all__ = ["CATEGORIES", "PROGRAM_SPANS", "STEP_CATEGORIES", "Span",
+__all__ = ["CATEGORIES", "DEVICE_SCOPES", "PROGRAM_SPANS",
+           "STEP_CATEGORIES", "Span",
            "Tracer", "default_tracer", "profiler_session_live",
            "reset_default_tracer"]
 
@@ -105,6 +106,22 @@ PROGRAM_SPANS = {
     "serve.fetch": "device_wait",
     "serve.resolve": "other",
 }
+
+
+#: the device scopes (``jax.named_scope``: metadata on the HLO
+#: operations, read from a device trace's ``op_name``) — stable API like
+#: the spans above.  ``generate.*`` name the stretches of one compiled
+#: generate call (``models/generate.py``); ``mixer.*`` the parts of a
+#: hybrid block inside ``generate.prefill`` / ``generate.decode_step``
+#: (``nn/mamba.py``): ``mixer.ssd_scan`` is the chunked scan of a whole
+#: sequence, ``mixer.ssm_step`` the one-token update of conv tail and
+#: state, ``mixer.attention`` the attention branch beside the mixer.
+DEVICE_SCOPES = (
+    "generate.cast_params", "generate.prefill", "generate.decode_step",
+    "generate.sample",
+    "mixer.in_proj", "mixer.conv", "mixer.ssd_scan", "mixer.ssm_step",
+    "mixer.gate_norm", "mixer.out_proj", "mixer.attention",
+)
 
 
 def profiler_session_live() -> bool:

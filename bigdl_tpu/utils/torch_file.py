@@ -334,8 +334,9 @@ def _module_to_table(module):
     if isinstance(module, nn.Linear):
         t["weight"] = _np(p.get("weight"))
         t["bias"] = _np(p.get("bias"))
-        t["gradWeight"] = _np(module.grads.get("weight"))
-        t["gradBias"] = _np(module.grads.get("bias"))
+        grads = module.grad_tree()
+        t["gradWeight"] = _np(grads.get("weight"))
+        t["gradBias"] = _np(grads.get("bias"))
         return "nn.Linear", t
     if isinstance(module, nn.SpatialConvolution):
         t["nInputPlane"] = float(module.n_input_plane)

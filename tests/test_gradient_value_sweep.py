@@ -288,6 +288,15 @@ MODULE_CASES = {
     "MulConstant": (lambda: nn.MulConstant(2.5), lambda: X, {}),
     "MultiHeadAttention": (lambda: nn.MultiHeadAttention(8, 2),
                            lambda: X8, {}),
+    "Mamba2Mixer": (lambda: nn.Mamba2Mixer(8, n_heads=2, head_dim=4,
+                                           d_state=4, n_groups=2,
+                                           chunk_size=2),
+                    lambda: X8, {}),
+    "HybridMambaBlock": (lambda: nn.HybridMambaBlock(
+        8, num_heads=2, num_kv_heads=1, head_dim=4, mlp_dim=12,
+        mamba_heads=2, mamba_head_dim=4, mamba_d_state=4, mamba_groups=2,
+        mamba_chunk=2, key_multiplier=0.5, ssm_out_multiplier=0.7,
+        mlp_multipliers=(0.8, 0.6)), lambda: X8, {}),
     "Narrow": (lambda: nn.Narrow(2, 2, 3), lambda: X, {}),
     "NarrowTable": (lambda: nn.NarrowTable(1, 2),
                     lambda: T(X, X2, XP), {}),

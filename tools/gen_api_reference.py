@@ -90,7 +90,37 @@ def main():
             "`every_epoch()`, `every_iteration()`, `several_iteration(n)`,",
             "`max_epoch(n)`, `max_iteration(n)`, `min_loss(x)`,",
             "`max_score(x)`, `and_(..)`, `or_(..)` —",
-            "see `bigdl_tpu.optim.trigger`.", ""]
+            "see `bigdl_tpu.optim.trigger`.", "",
+            "## Module-wide behaviour", "",
+            "- **Gradient buffers appear on first use.** A module owns no",
+            "  gradient buffer when it is built: `grad_tree()`,",
+            "  `parameters()`, `zero_grad_parameters()` and `backward()`",
+            "  make the zeros they need, so a model that is only served, or",
+            "  trained through an optimizer's compiled step, never pays",
+            "  for them.",
+            "- **`param_dtype`** (`models.hybrid_mamba.HybridMambaLM`): the",
+            "  dtype the model HOLDS its floating parameters in.  The",
+            "  constructor draws in it, `set_param_tree` casts each",
+            "  incoming leaf to it (a leaf already there is kept as it",
+            "  is), and a generator whose compute dtype equals it casts",
+            "  nothing inside the call.  `A_log`, `dt_bias`, `D` and the",
+            "  SSM state compute in float32 whatever the held dtype.",
+            "- **`HybridMambaLM`** is a `Container` with `TransformerLM`'s",
+            "  child layout (`0` embedding, `1..L` `HybridMambaBlock`s,",
+            "  `L+1` RMSNorm, `L+2` head); `generate()` /",
+            "  `InferenceServer.submit_generate` decode it through a K/V",
+            "  cache and a recurrent state per layer; the paged path",
+            "  (`PagedDecoder`) refuses it.",
+            "- **`nn.initialization.device_draw()`**: inside this context",
+            "  the random initialisers (`RandomUniform`, `RandomNormal`,",
+            "  `Xavier`, `MsraFiller`) draw with `jax.random` on the",
+            "  device (its own bit generator, `impl=\"rbg\"`), seeded from",
+            "  the host generator's stream — the same",
+            "  distributions, other numbers.  `HybridMambaLM` builds and",
+            "  resets under it (2.4 B weights from the host's Mersenne",
+            "  Twister cost most of a minute of every start); every other",
+            "  model keeps the host stream and its historical numbers.",
+            ""]
 
     out_path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "docs", "api-reference.md")
