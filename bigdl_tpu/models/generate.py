@@ -7,10 +7,15 @@ is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
 extension: one jitted program containing a **batched prefill** (the
 whole prompt in one causal pass that fills the per-layer KV caches —
 MXU-sized matmuls, not a token loop) followed by a ``lax.scan`` over
-decode steps at static shapes, with the caches (``[B, Hkv, T_max,
+decode steps at static shapes, with the caches (``[B, Hkv, T_cache,
 Dh]`` — the KV head count, smaller than the query's under GQA)
 updated in place via ``lax.dynamic_update_slice``.  No Python-level
-loop over tokens, no recompilation per length.
+loop over tokens, no recompilation per length.  ``T_cache`` is what
+THIS program can use — prompt + ``max_new``, both static, rounded up
+to a multiple of 128 and never past ``max_len`` (:func:`_cache_len`):
+a decode step reads its whole cache, so a cache as long as the model's
+positional table would make every step pay for positions no call of
+this program can ever write.
 
 A hybrid block (``nn.HybridMambaBlock``) keeps, beside its K/V, the
 Mamba-2 mixer's SSM state ``[B, heads, head, N]`` (float32) and conv
@@ -253,14 +258,29 @@ def _ffn_sublayer(block, bp, h):
     return h + ffn
 
 
-def _cache_init(block, B, T_max, dt, kv_int8=False):
+def _cache_len(T_max, T0, max_new):
+    """Positions the static K/V cache of one generate program holds:
+    ``T0 + max_new`` rounded up to a multiple of 128 (the cache's
+    length is the lane axis of the decode step's score tile), never
+    past ``T_max``.  Both lengths are static in the program, so no
+    position beyond them is ever written or attended; ``T_max`` still
+    bounds what a caller may ask for."""
+    if T0 + max_new > T_max:
+        raise ValueError(
+            f"prompt {T0} + max_new {max_new} exceeds max_len {T_max}")
+    return min(T_max, -(-(T0 + max_new) // 128) * 128)
+
+
+def _cache_init(block, B, T_cache, dt, kv_int8=False):
     """One layer's state for ``B`` rows, a dict: K and V ``[B, Hkv,
-    T_max, Dh]`` (int8 with ``k_scale`` / ``v_scale`` beside them under
-    ``kv_int8``) and, for a hybrid block, the mixer's ``ssm`` state and
-    ``conv`` tail beside those."""
+    T_cache, Dh]`` (int8 with ``k_scale`` / ``v_scale`` beside them
+    under ``kv_int8``) and, for a hybrid block, the mixer's ``ssm``
+    state and ``conv`` tail beside those.  ``T_cache`` is the calling
+    program's :func:`_cache_len`, not the model's ``max_len``: every
+    reader of the cache takes its length from its shape."""
     mha = block.modules[1]
     Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
-    kv = (B, Hkv, T_max, mha.head_dim)
+    kv = (B, Hkv, T_cache, mha.head_dim)
     if kv_int8:
         cache = {"k": jnp.zeros(kv, jnp.int8),
                  "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
@@ -273,21 +293,27 @@ def _cache_init(block, B, T_max, dt, kv_int8=False):
     return cache
 
 
-def cache_footprint(model, batch: int, compute_dtype=None,
-                    max_len: Optional[int] = None,
+def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
+                    compute_dtype=None, max_len: Optional[int] = None,
                     kv_dtype: Optional[str] = None) -> dict:
-    """Bytes of state one generate call of ``batch`` rows holds on the
-    device, from shapes alone: ``kv_cache_bytes`` (the static K/V of
-    every layer) and ``recurrent_state_bytes`` (SSM state and conv tail;
-    zero for a model without them)."""
+    """State one generate call of ``batch`` rows, ``prompt_len`` prompt
+    tokens and ``max_new`` answer tokens holds on the device, from
+    shapes alone: ``kv_cache_positions`` (how long that program's
+    static cache is, :func:`_cache_len`, against the model's
+    ``max_len``), ``kv_cache_bytes`` (the K/V of every layer at that
+    length: what is allocated, and what every decode step reads) and
+    ``recurrent_state_bytes`` (SSM state and conv tail; zero for a
+    model without them)."""
     first, count = _check_model(model)
-    T_max = _check_len(model, max_len)
+    T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
+                         int(max_new))
     dt = jnp.dtype(compute_dtype or jax.tree_util.tree_leaves(
         model.param_tree())[0].dtype)
-    out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0}
+    out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
+           "kv_cache_positions": T_cache}
     for block in model.modules[first:first + count]:
         shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
-                                        T_max, dt, _kv_int8(kv_dtype)))
+                                        T_cache, dt, _kv_int8(kv_dtype)))
         for name, a in shapes.items():
             kind = ("kv_cache_bytes"
                     if name in ("k", "v", "k_scale", "v_scale")
@@ -296,11 +322,12 @@ def cache_footprint(model, batch: int, compute_dtype=None,
     return out
 
 
-def _decode_machinery(model, first, count, T_max, kv_int8=False):
+def _decode_machinery(model, first, count, kv_int8=False):
     """The cached-attention forward shared by the sampling decoder and
     beam search — built once per generator from the model structure.
     Every function takes the (already cast) param tree ``pc``
-    explicitly.
+    explicitly; ``prefill`` is told how long a cache to allocate, and
+    everything after it takes that length from the cache's shape.
 
     ``kv_int8`` stores the caches as int8 with a float32 scale per
     (batch, head, position) — absmax rounding over the head dim.
@@ -386,8 +413,8 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
             # bit-exact even under kv_int8
             # prefill: causal attention over the PROMPT only — cache
             # slots past the prompt are outside the causal horizon
-            # anyway, so scoring the whole [T_max] cache (the _attend
-            # path) wastes T_max/T0 of the work and materializes the
+            # anyway, so scoring the whole [T_cache] cache (the _attend
+            # path) wastes T_cache/T0 of the work and materializes the
             # full score tile.  The flash kernels make this
             # O(T0·block) memory on TPU; off-TPU (and at non-blockable
             # T0) flash_attention falls back to the same dense causal
@@ -430,14 +457,14 @@ def _decode_machinery(model, first, count, T_max, kv_int8=False):
             return h
         return h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq)
 
-    def prefill(pc, prompt, dt):
+    def prefill(pc, prompt, dt, T_cache):
         """The whole prompt in one causal pass; returns (h [B,T0,D],
-        caches) with positions [0, T0) filled."""
+        caches) of ``T_cache`` positions with [0, T0) filled."""
         B, T0 = prompt.shape
         h = _embed_at(pc, prompt, 0, T0)
         caches = []
         for bi, block in enumerate(blocks):
-            cache = _cache_init(block, B, T_max, dt, kv_int8)
+            cache = _cache_init(block, B, T_cache, dt, kv_int8)
             h, cache = _block_step(block, pc[str(first + bi)], h,
                                    cache, 0)
             caches.append(cache)
@@ -479,7 +506,10 @@ def make_generate(model, max_len: Optional[int] = None,
 
     ``params`` is ``model.param_tree()`` (1-based token ids, like the
     training path).  ``max_len`` bounds prompt+generated (default: the
-    model's positional table length).  One compiled program per
+    model's positional table length); it does NOT set the length of
+    the K/V cache, which each program allocates for its own prompt +
+    ``max_new`` (:func:`_cache_len`), so a short call on a long-context
+    model reads a short cache at every step.  One compiled program per
     (prompt_shape, max_new, top_k, greedy, nucleus), where ``greedy =
     not temperature > 0`` and ``nucleus = 0 < top_p < 1`` are read on
     the host from the call's own numbers (a greedy call ignores
@@ -491,7 +521,7 @@ def make_generate(model, max_len: Optional[int] = None,
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
-        model, first, count, T_max, kv_int8=_kv_int8(kv_dtype))
+        model, first, count, kv_int8=_kv_int8(kv_dtype))
 
     # device scopes (``jax.named_scope``): metadata on the HLO
     # operations only — ``generate.cast_params`` / ``.prefill`` /
@@ -531,9 +561,7 @@ def make_generate(model, max_len: Optional[int] = None,
         with jax.named_scope("generate.cast_params"):
             pc = _cast_floats(p, compute_dtype) if compute_dtype else p
         B, T0 = prompt.shape
-        if T0 + max_new > T_max:
-            raise ValueError(
-                f"prompt {T0} + max_new {max_new} exceeds max_len {T_max}")
+        T_cache = _cache_len(T_max, T0, max_new)
         dt = (compute_dtype
               or jax.tree_util.tree_leaves(pc)[0].dtype)
 
@@ -547,7 +575,7 @@ def make_generate(model, max_len: Optional[int] = None,
                                 greedy, nucleus) + 1
 
         with jax.named_scope("generate.prefill"):
-            h, caches = prefill(pc, prompt, dt)
+            h, caches = prefill(pc, prompt, dt, T_cache)
             logits = logits_last(pc, h)
         key, nxt = next_token(logits, key)
         # eos==0 disables early stop (ids are 1-based, 0 never matches).
@@ -630,19 +658,17 @@ def make_beam_search(model, max_len: Optional[int] = None,
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
-        model, first, count, T_max, kv_int8=_kv_int8(kv_dtype))
+        model, first, count, kv_int8=_kv_int8(kv_dtype))
 
     @partial(jax.jit, static_argnums=(2, 3))
     def _run(p, prompt, max_new, kk, eos, pad):
         pc = _cast_floats(p, compute_dtype) if compute_dtype else p
         B, T0 = prompt.shape
-        if T0 + max_new > T_max:
-            raise ValueError(
-                f"prompt {T0} + max_new {max_new} exceeds max_len {T_max}")
+        T_cache = _cache_len(T_max, T0, max_new)
         dt = (compute_dtype
               or jax.tree_util.tree_leaves(pc)[0].dtype)
 
-        h, caches = prefill(pc, prompt, dt)
+        h, caches = prefill(pc, prompt, dt, T_cache)
         logp0 = jax.nn.log_softmax(logits_last(pc, h), axis=-1)  # [B, V]
         V = logp0.shape[-1]
         # the first expansion has only V candidates: surplus beams
@@ -1154,14 +1180,14 @@ def capacity_bind_report(model, params, ids):
 
     slot = _BIND_CACHE.setdefault(model, {})
     if T not in slot:
-        prefill, _, _ = _decode_machinery(model, first, count, T)
+        prefill, _, _ = _decode_machinery(model, first, count)
 
         @jax.jit
         def _replay(p, toks):
             _BIND_TLS.capture = []
             try:
                 dt = jax.tree_util.tree_leaves(p)[0].dtype
-                prefill(p, toks, dt)
+                prefill(p, toks, dt, T)
                 fracs = list(_BIND_TLS.capture)
             finally:
                 _BIND_TLS.capture = None

@@ -1,11 +1,14 @@
 """Paged KV-cache arena — pages instead of whole static buckets.
 
 The unpaged decode path (`models/generate.py`) gives every in-flight
-generate request a dense ``[B, Hkv, T_max, Dh]`` cache: a request that
-will emit 40 tokens still pins ``T_max`` positions of HBM for its
-whole lifetime, so the number of concurrent long decodes is bounded by
-the *worst-case* window, not the *actual* one.  A :class:`KVPagePool`
-preallocates ONE arena of fixed-size pages::
+generate batch a dense ``[B, Hkv, T_cache, Dh]`` cache as long as its
+prompt plus its ``max_new`` (rounded up to a multiple of 128, at most
+``max_len``): a request that stops at an early eos after 40 tokens
+still pins every position it MIGHT have filled for its whole lifetime
+— ``T_max`` of them when callers ask for the whole window — so the
+number of concurrent long decodes is bounded by the *worst-case*
+window, not the *actual* one.  A :class:`KVPagePool` preallocates ONE
+arena of fixed-size pages::
 
     arena_k / arena_v : [num_pages, layers, Hkv, page_size, Dh]
 

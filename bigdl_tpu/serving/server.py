@@ -159,8 +159,8 @@ class InferenceServer:
         #: paged KV arena (``serving.kvpool.KVPagePool``): when set,
         #: generation serves through the paged decode path — each
         #: request holds pages for the positions it actually fills
-        #: instead of a whole static T_max bucket, and pool exhaustion
-        #: sheds typed OVERLOADED
+        #: instead of a static cache of prompt + max_new positions,
+        #: and pool exhaustion sheds typed OVERLOADED
         self.kv_pool = kv_pool
         #: page-granular block mask for long paged decodes (the BLaST
         #: sparsity story on the serving path): attend only the first
@@ -212,7 +212,8 @@ class InferenceServer:
         self.policy = policy or RetryPolicy.from_properties(
             prefix="bigdl.serving")
         self.generate_dtype = generate_dtype
-        #: bucket -> cache_footprint of one generate call (span args)
+        #: (bucket, prompt length, max_new) -> cache_footprint of that
+        #: generate program (span args)
         self._gen_footprints = {}
         self._queue = _BoundedQueue(max_queue)
         self._batch_window_s = float(batch_window_s)
@@ -1177,15 +1178,18 @@ class InferenceServer:
             new_sig = sig not in self.batcher.buckets_dispatched
             self.batcher.buckets_dispatched.add(sig)
             prompts_j = jnp.asarray(prompts, jnp.int32)
+        # what this program holds on the device beside the weights, from
+        # shapes: its cache is as long as prompt + max_new need, not as
+        # the model's max_len (zero recurrent bytes for a model without
+        # the state)
+        holds = self._gen_footprints.get(sig[1:])
+        if holds is None:
+            holds = self._gen_footprints[sig[1:]] = cache_footprint(
+                self.model, bucket, prompts.shape[1], max_new,
+                compute_dtype=self.generate_dtype)
         # the call returning: eos/pad lookup, argument conversion and
         # the enqueue of ONE compiled program (a build on a new
         # signature); the decode itself runs behind it
-        # what the bucket holds on the device beside the weights, from
-        # shapes (zero recurrent bytes for a model without the state)
-        holds = self._gen_footprints.get(bucket)
-        if holds is None:
-            holds = self._gen_footprints[bucket] = cache_footprint(
-                self.model, bucket, compute_dtype=self.generate_dtype)
         with tr.span("serve.dispatch",
                      "compile" if new_sig else "dispatch",
                      compiled=new_sig, **holds):

@@ -468,3 +468,164 @@ def test_capacity_bind_report_matches_brute_force():
     for k, v in want.items():
         np.testing.assert_allclose(rep[k], v, atol=1e-6)
     assert rep["overall"] > 0.0  # capacity 0.51 must bind somewhere
+
+
+# -- the K/V cache is as long as the program can use, not as max_len -------
+# (PR 28) a generate program is compiled per (prompt shape, max_new), so it
+# allocates round_up(T0 + max_new, 128) positions, at most max_len
+
+LONG = 640   # a positional table far above what the calls below use
+
+
+def _long_model(kind):
+    RNG().set_seed(4)
+    kw = (dict(num_heads=4, num_kv_heads=2, rope=True, norm="rms",
+               mlp="swiglu") if kind == "gqa" else dict(num_heads=HEADS))
+    return TransformerLM(VOCAB, embed_dim=EMBED, mlp_dim=MLP,
+                         num_layers=LAYERS, max_len=LONG, **kw)
+
+
+def _fixture(name):
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           name)) as f:
+        return json.load(f)
+
+
+def _short_prompt():
+    return np.random.RandomState(0).randint(
+        1, VOCAB + 1, (2, 5)).astype(np.int32)
+
+
+def _long_table_cases():
+    """name -> ids of a call that uses 12 positions of a 640-position
+    model.  tests/fixtures/generate_pr27_long_table_ids.json holds what
+    the commit before PR 28 (a cache of all 640 positions) gave."""
+    from bigdl_tpu.models.generate import make_beam_search
+
+    prompt = _short_prompt()
+    dense, gqa = _long_model("dense"), _long_model("gqa")
+    pd, pg = dense.param_tree(), gqa.param_tree()
+    return {
+        "dense": lambda: make_generate(dense)(pd, prompt, 7),
+        "gqa": lambda: make_generate(gqa)(pg, prompt, 7),
+        "gqa_bf16": lambda: make_generate(
+            gqa, compute_dtype=jnp.bfloat16)(pg, prompt, 7),
+        "sampled": lambda: make_generate(gqa)(
+            pg, prompt, 7, rng=jax.random.PRNGKey(3), temperature=0.8,
+            top_k=5, top_p=0.9),
+        "int8": lambda: make_generate(dense, kv_dtype="int8")(
+            pd, prompt, 7),
+        "gqa_int8": lambda: make_generate(gqa, kv_dtype="int8")(
+            pg, prompt, 7),
+        "beam": lambda: make_beam_search(dense)(
+            pd, prompt, 7, num_beams=3)[0],
+        "gqa_beam": lambda: make_beam_search(gqa)(
+            pg, prompt, 7, num_beams=3)[0],
+    }
+
+
+@pytest.mark.parametrize("case", ["dense", "gqa", "gqa_bf16", "sampled",
+                                  "int8", "gqa_int8", "beam", "gqa_beam"])
+def test_short_cache_gives_the_ids_of_the_whole_cache(case):
+    want = _fixture("generate_pr27_long_table_ids.json")[case]
+    got = np.asarray(_long_table_cases()[case]())
+    np.testing.assert_array_equal(got, np.asarray(want))
+
+
+@pytest.mark.parametrize("kind", ["dense", "gqa"])
+def test_ids_do_not_depend_on_the_positional_table(kind):
+    """max_len far above T0 + max_new, max_len = round_up(T0 + max_new,
+    128) and the teacher-forced dense forward agree; for the dense toy
+    model the 24-position fixture's greedy ids come out of a
+    640-position table holding the same weights."""
+    model = _long_model(kind)
+    p = model.param_tree()
+    prompt = _short_prompt()
+    far = np.asarray(make_generate(model)(p, prompt, 7))
+    near = np.asarray(make_generate(model, max_len=128)(p, prompt, 7))
+    np.testing.assert_array_equal(far, near)
+    _teacher_force_check(model, far, prompt_len=5)
+    if kind == "dense":
+        small = _model().param_tree()
+        p = {**small, "pos": p["pos"].at[:TMAX].set(small["pos"])}
+        np.testing.assert_array_equal(
+            np.asarray(make_generate(model)(p, prompt, 7)),
+            np.asarray(_fixture("generate_pr23_ids.json")["greedy"]))
+
+
+def _lowered(model, run, t0, max_new, *rest):
+    return run.lower(model.param_tree(), jnp.ones((2, t0), jnp.int32),
+                     max_new, *rest).as_text()
+
+
+@pytest.mark.parametrize("kind,t0,max_new,positions", [
+    ("dense", 5, 7, 128), ("gqa", 5, 7, 128), ("gqa", 100, 29, 256),
+    ("gqa", 600, 8, LONG), ("gqa", 630, 10, LONG)])
+def test_lowered_program_holds_no_cache_of_max_len(kind, t0, max_new,
+                                                   positions):
+    """The StableHLO of ``_run``: every K/V tensor ``[B, Hkv, T, Dh]``
+    has T = the program's own cache length, for the sampling decoder
+    and for beam search."""
+    import re
+
+    from bigdl_tpu.models.generate import make_beam_search
+
+    model = _long_model(kind)
+    dh = EMBED // (4 if kind == "gqa" else HEADS)
+    texts = [
+        _lowered(model, _jitted_run(make_generate(model)), t0, max_new,
+                 jax.random.PRNGKey(0), jnp.float32(0), 0, jnp.float32(1),
+                 jnp.int32(0), jnp.int32(0), True, False),
+        _lowered(model, _jitted_run(make_beam_search(model)), t0, max_new,
+                 3, jnp.int32(0), jnp.int32(0))]
+    for text, rows in zip(texts, (2, 6)):
+        lengths = {int(t) for t in re.findall(
+            rf"tensor<{rows}x2x(\d+)x{dh}xf32>", text)}
+        assert positions in lengths, lengths
+        assert not lengths - {positions, t0, 1}, lengths
+        if positions != LONG and kind == "gqa":    # no positional table
+            assert f"x{LONG}x" not in text
+
+
+@pytest.mark.parametrize("max_len,t0,max_new", [
+    (TMAX, 17, 7), (200, 150, 50), (256, 200, 56)])
+def test_a_call_that_fills_max_len_runs_and_one_past_it_raises(
+        max_len, t0, max_new):
+    from bigdl_tpu.models.generate import make_beam_search
+
+    RNG().set_seed(4)
+    model = TransformerLM(VOCAB, embed_dim=EMBED, num_heads=HEADS,
+                          mlp_dim=MLP, num_layers=1, max_len=max_len)
+    p = model.param_tree()
+    prompt = np.random.RandomState(2).randint(
+        1, VOCAB + 1, (1, t0)).astype(np.int32)
+    ids = np.asarray(make_generate(model)(p, prompt, max_new))
+    assert ids.shape == (1, max_len)
+    _teacher_force_check(model, ids, prompt_len=t0)
+    beam, _ = make_beam_search(model)(p, prompt, max_new, num_beams=1)
+    np.testing.assert_array_equal(np.asarray(beam), ids)
+    for make in (make_generate, make_beam_search):
+        with pytest.raises(ValueError, match=f"exceeds max_len {max_len}"):
+            make(model)(p, prompt, max_new + 1)
+
+
+@pytest.mark.parametrize("t0,max_new,max_len,want", [
+    (5, 7, LONG, 128), (128, 96, 2560, 256), (256, 128, 2560, 384),
+    (2048, 8, 2560, 2176), (2500, 60, 2560, 2560), (17, 7, TMAX, TMAX),
+    (100, 28, LONG, 128), (100, 29, LONG, 256)])
+def test_cache_footprint_counts_the_allocated_cache(t0, max_new, max_len,
+                                                    want):
+    from bigdl_tpu.models.generate import cache_footprint
+
+    RNG().set_seed(4)
+    model = TransformerLM(VOCAB, embed_dim=EMBED, num_heads=4,
+                          num_kv_heads=2, rope=True, mlp_dim=MLP,
+                          num_layers=LAYERS, max_len=max_len)
+    got = cache_footprint(model, 3, t0, max_new)
+    assert got == {
+        "kv_cache_positions": want, "recurrent_state_bytes": 0,
+        "kv_cache_bytes": LAYERS * 2 * 3 * 2 * want * (EMBED // 4) * 4}
+    q8 = cache_footprint(model, 3, t0, max_new, kv_dtype="int8")
+    assert q8["kv_cache_bytes"] == LAYERS * 2 * 3 * 2 * want * (4 + 4)
+    with pytest.raises(ValueError, match="exceeds max_len"):
+        cache_footprint(model, 3, t0, max_len - t0 + 1)

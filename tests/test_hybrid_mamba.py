@@ -415,22 +415,60 @@ def test_dispatch_span_says_what_the_bucket_holds():
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.telemetry import default_tracer
 
-    model = _model(_flat_params())
-    got = cache_footprint(model, 4, compute_dtype=jnp.float32)
-    kv = LAYERS * 2 * 4 * 2 * 48 * 8 * 4           # K, V [4, 2, 48, 8] f32
+    flat = _flat_params()
+    model = _model(flat)
     rec = LAYERS * 4 * (4 * 16 * 16 * 4 + 3 * 128 * 4)   # ssm + conv tail
-    assert got == {"kv_cache_bytes": kv, "recurrent_state_bytes": rec}
+
+    def kv(positions):                 # K, V [4, 2, positions, 8] f32
+        return LAYERS * 2 * 4 * 2 * positions * 8 * 4
+
+    # 11 + 3 positions of this model's 48: the whole table is the cache
+    got = cache_footprint(model, 4, 11, 3, compute_dtype=jnp.float32)
+    assert got == {"kv_cache_bytes": kv(48), "recurrent_state_bytes": rec,
+                   "kv_cache_positions": 48}
+    # the same call on a long table holds 128 positions, not 640, and
+    # the recurrent state does not care
+    long = _model(flat, max_len=640)
+    got = cache_footprint(long, 4, 11, 3, compute_dtype=jnp.float32)
+    assert got == {"kv_cache_bytes": kv(128), "recurrent_state_bytes": rec,
+                   "kv_cache_positions": 128}
     dense = TransformerLM(23, embed_dim=16, num_heads=2, mlp_dim=32,
                           num_layers=2, max_len=24)
-    assert cache_footprint(dense, 2)["recurrent_state_bytes"] == 0
+    assert cache_footprint(dense, 2, 5, 7)["recurrent_state_bytes"] == 0
     tracer = default_tracer()
     tracer.enabled = True
-    before = len(tracer.spans())
-    _serve(model, _prompts(4, 11, seed=6), max_new=3, max_batch=4)
-    spans = [s for s in tracer.spans()[before:] if s.name == "serve.dispatch"]
-    assert spans and all(s.args["recurrent_state_bytes"] > 0 for s in spans)
-    full = [s for s in spans if s.args["kv_cache_bytes"] == kv]
-    assert full and full[0].args["recurrent_state_bytes"] == rec
+    for served, positions in ((model, 48), (long, 128)):
+        before = len(tracer.spans())
+        _serve(served, _prompts(4, 11, seed=6), max_new=3, max_batch=4)
+        spans = [s for s in tracer.spans()[before:]
+                 if s.name == "serve.dispatch"]
+        assert spans and all(
+            s.args["recurrent_state_bytes"] > 0
+            and s.args["kv_cache_positions"] == positions for s in spans)
+        full = [s for s in spans if s.args["kv_cache_bytes"] == kv(positions)]
+        assert full and full[0].args["recurrent_state_bytes"] == rec
+
+
+def test_a_long_table_generates_the_ids_of_a_short_one():
+    """(PR 28) the hybrid block's K/V cache is as long as prompt +
+    max_new need (128 of 640 here; the 48-position twin holds 48): the
+    tokens are the same, from ``generate``, from the server and under
+    the int8 cache, and they are the reference's."""
+    flat = _flat_params()
+    short, long = _model(flat), _model(flat, max_len=640)
+    prompts = _prompts(3, 19)
+    want = np.asarray(short.generate(prompts, max_new=12))
+    got = np.asarray(long.generate(prompts, max_new=12))
+    np.testing.assert_array_equal(got, want)
+    assert _served_gap(flat, got)[:, 18:].max() < 1e-4
+    out, _ = _serve(long, prompts, max_new=12, max_batch=4)
+    np.testing.assert_array_equal(out, want[:, 19:])
+    q8 = [np.asarray(make_generate(m, kv_dtype="int8")(
+        m.param_tree(), prompts, 12)) for m in (short, long)]
+    np.testing.assert_array_equal(q8[1], q8[0])
+    np.testing.assert_array_equal(q8[1][:, :20], want[:, :20])
+    text = _lowered_run(long, make_generate(long)).as_text()
+    assert "x128x8xf32>" in text and "x640x" not in text
 
 
 # -- the constructor draws on the device ---------------------------------
