@@ -13,7 +13,8 @@ the ceiling is XLA's conv lowering, not the framework.
 (ops/conv_gemm.py) to test whether reformulating conv as MXU-shaped
 matmuls beats the native lowering end-to-end.
 
-Run on hardware via models/resnet_mfu_lab.py.
+The lab that ran it on hardware left in PR 29; its verdicts are in
+docs/PERF.md, and ``tests/test_conv_gemm.py`` keeps it running.
 """
 from __future__ import annotations
 

@@ -389,8 +389,8 @@ def _pick_block(n: int, d: int = 64) -> int:
     bf16) from HBM while doing 4·bq·S·D MXU FLOPs → arithmetic
     intensity = bq FLOP/byte.  v5e ridge point = 197 TFLOP/s ÷
     ~820 GB/s ≈ 240 FLOP/byte, so bq ≥ 256 already keeps the sweep
-    compute-bound — but the measured on-chip matrix (r4, v5e, MFU_LAB
-    flash rows) shows throughput keeps climbing past the ridge:
+    compute-bound — but the measured on-chip matrix (r4, v5e,
+    docs/PERF.md) shows throughput keeps climbing past the ridge:
     block=1024 beats 512 at every swept point but one, fwd and fwd+bwd
     (T=8192 D=128 fwd+bwd 62.5 vs 40.7 TFLOP/s; T=4096 D=64 27.5 vs
     17.9; the exception is T=1024 D=128, where 512 edges 1024 by ~2%

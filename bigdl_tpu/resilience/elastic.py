@@ -107,8 +107,7 @@ class KVTransport:
 
 
 class InMemoryKV(KVTransport):
-    """Dict-backed transport for single-process simulations (tests,
-    the ``bench.py --elastic`` leg)."""
+    """Dict-backed transport for single-process simulations (tests)."""
 
     def __init__(self):
         self._lock = threading.Lock()

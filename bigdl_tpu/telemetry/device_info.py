@@ -1,11 +1,7 @@
-"""Device capability table — the ONE copy of per-chip peaks.
-
-``bench.py`` carried the bf16 peak-FLOP/s table and
-``models/resnet_mfu_lab.py`` reached into it through a lazy
-file-path import; every future consumer (the PerfAccountant's MFU
-and roofline math, serving goodput-per-chip) would have grown the
-same cross-import.  The table lives here now; ``bench.py`` keeps a
-compat shim.
+"""Device capability table — the package's one copy of per-chip peaks
+(the PerfAccountant's MFU and roofline math, serving goodput-per-chip).
+The benchmark keeps its own v5e row in ``benchmark/peaks.json``;
+``tests/test_tree.py::test_one_v5e_peak`` holds the two equal.
 
 Numbers are public spec-sheet figures per **chip**:
 
@@ -59,7 +55,7 @@ class DeviceSpec(NamedTuple):
 
 
 # substring-matched against jax's device_kind (lowercased), first hit
-# wins — mirrors the original bench.py table order
+# wins
 DEVICE_SPECS = (
     DeviceSpec("v6e", 918e12, 32 * GiB, 1640e9, 900e9),
     DeviceSpec("trillium", 918e12, 32 * GiB, 1640e9, 900e9),
@@ -77,15 +73,15 @@ DEVICE_SPECS = (
 #: on the CPU backend without pretending to measure the host
 CPU_SPEC = DeviceSpec("cpu", 100e9, None, 20e9, None, nominal=True)
 
-#: (kind substring, bf16 peak FLOP/s) — the shape bench.py always had
+#: (kind substring, bf16 peak FLOP/s)
 PEAK_FLOPS_TABLE = tuple(
     (s.kind, s.peak_flops_per_sec) for s in DEVICE_SPECS)
 
 
 def peak_flops_per_sec(device_kind: str) -> Optional[float]:
     """bf16 peak FLOP/s per chip for a jax ``device_kind`` string, or
-    None when unknown (the bench.py contract: a CPU/unknown device has
-    no honest peak and reports no MFU)."""
+    None when unknown (a CPU/unknown device has no honest peak and
+    reports no MFU)."""
     spec = device_spec(device_kind)
     return None if spec is None or spec.nominal \
         else spec.peak_flops_per_sec

@@ -2,10 +2,9 @@
 multi-window burn-rate alerting, anomaly rules, structured alerts.
 
 The observability spine records everything but — before this module —
-evaluated nothing online: regressions were caught offline at bench
-time (``tools/perf_sentinel.py``) and the control loops acted on
-hand-coded raw thresholds.  The :class:`SloEngine` is the online twin
-of the offline sentinel: it evaluates a declarative rule set over the
+evaluated nothing online: regressions were caught offline, if at
+all, and the control loops acted on hand-coded raw thresholds.  The
+:class:`SloEngine` evaluates a declarative rule set over the
 windows a :class:`~.timeseries.MetricRecorder` holds and emits
 structured firing/resolved :class:`Alert` events the control planes
 act on — the autoscaler consumes verdicts as its breach signal, the

@@ -1,6 +1,6 @@
 """Where XLA's persistent compile cache lives — one rule, applied by
 every entry point (``models/train.py``, ``models/perf.py``,
-``bench.py``, ``chip_smoke.py``, ``InferenceServer.start``).
+``benchmark/run.py``, ``chip_smoke.py``, ``InferenceServer.start``).
 
 The directory is part of the cache key's world: a path that moves never
 hits.  So the place is chosen from OUTSIDE the program when

@@ -546,83 +546,3 @@ def test_longrun_memory_object_counts_plateau():
     assert len(ctx.step_log) == ctx.step_log.maxlen
     assert len(tm.tracer.spans()) == tm.tracer.capacity
     ctx.close()
-
-
-# ---------------------------------------------------------------------------
-# sentinel: goodput-family direction + absolute floors
-# ---------------------------------------------------------------------------
-
-def _sentinel():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_sentinel", os.path.join(os.path.dirname(__file__), "..",
-                                      "tools", "perf_sentinel.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _gp_record(**over):
-    rec = {"backend": "cpu", "goodput_productive_fraction": 0.96,
-           "goodput_accounted_fraction": 1.0,
-           "goodput_checkpoint_fraction": 0.0003,
-           "data_stall_s": 0.2, "checkpoint_blocked_s": 0.001}
-    rec.update(over)
-    return rec
-
-
-def test_sentinel_goodput_direction_aware():
-    ps = _sentinel()
-    base = ps.make_baseline(_gp_record())
-    # improvements never fail: fraction up, stall down
-    ok = ps.compare(_gp_record(goodput_productive_fraction=0.99,
-                               data_stall_s=0.01), base)
-    assert ok["status"] == "pass"
-    # productive fraction dropping past tolerance fails
-    bad = ps.compare(_gp_record(goodput_productive_fraction=0.60), base)
-    assert bad["status"] == "fail"
-    assert any(c["metric"] == "goodput_productive_fraction"
-               and c["status"] == "fail" for c in bad["checks"])
-    # a vanished goodput metric is a regression
-    gone = _gp_record()
-    del gone["data_stall_s"]
-    assert ps.compare(gone, base)["status"] == "fail"
-
-
-def test_sentinel_absolute_floor_absorbs_jitter_near_zero():
-    """checkpoint_blocked_s baseline ~0: millisecond jitter must pass
-    (the old pure-relative rule read any nonzero as an infinite
-    regression), while a real half-second stall still fails."""
-    ps = _sentinel()
-    base = ps.make_baseline(_gp_record(checkpoint_blocked_s=0.0))
-    assert ps.compare(_gp_record(checkpoint_blocked_s=0.02),
-                      base)["status"] == "pass"
-    res = ps.compare(_gp_record(checkpoint_blocked_s=0.6), base)
-    assert res["status"] == "fail"
-    assert any(c["metric"] == "checkpoint_blocked_s"
-               and c["status"] == "fail" for c in res["checks"])
-
-
-def test_bench_ledger_carries_goodput_fields(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench.ledger_record({
-        "tpu": False, "metric": "m", "value": 1.0,
-        "telemetry": {"overhead_pct": 1.0,
-                      "goodput_productive_fraction": 0.97,
-                      "goodput_accounted_fraction": 1.0,
-                      "goodput_checkpoint_fraction": 0.0002,
-                      "data_stall_s": 0.1,
-                      "checkpoint_blocked_s": 0.001}})
-    assert rec["goodput_productive_fraction"] == 0.97
-    assert rec["data_stall_s"] == 0.1
-    assert rec["checkpoint_blocked_s"] == 0.001
-    # schema-stable: the fields exist even when unmeasured
-    rec2 = bench.ledger_record({"tpu": False})
-    assert rec2["goodput_productive_fraction"] is None

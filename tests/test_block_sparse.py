@@ -38,14 +38,17 @@ def _full(T, block):
 class TestFullMaskParity:
     """All-ones mask == flash == dense, fwd + grads."""
 
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_three_way(self, causal):
-        q, k, v = _qkv()
-        ref = _attention_reference(q, k, v, causal,
-                                   1 / np.sqrt(q.shape[-1]))
-        fl = flash_attention(q, k, v, causal=causal, interpret=True)
-        bs = block_sparse_attention(q, k, v, _full(128, 32),
-                                    causal=causal, interpret=True)
+    @pytest.mark.parametrize("causal,T,block,sm", [
+        (False, 128, 32, 1.0), (True, 128, 32, 1.0), (True, 256, 64, 0.5)])
+    def test_forward_three_way(self, causal, T, block, sm):
+        q, k, v = _qkv(T=T)
+        sm /= np.sqrt(q.shape[-1])
+        ref = _attention_reference(q, k, v, causal, sm)
+        fl = flash_attention(q, k, v, causal=causal, sm_scale=sm,
+                             interpret=True)
+        bs = block_sparse_attention(q, k, v, _full(T, block),
+                                    causal=causal, sm_scale=sm,
+                                    interpret=True)
         # bitwise-class vs flash: identical shared tile machinery,
         # identical block visit order
         np.testing.assert_allclose(np.asarray(bs), np.asarray(fl),

@@ -12,6 +12,7 @@ import subprocess
 import sys
 
 import jax
+import pytest
 
 from bigdl_tpu.utils import compile_cache
 
@@ -56,6 +57,18 @@ def test_default_invocation_refuses_a_cpu(tmp_path):
     assert out.returncode not in (0, None)
     assert out.stdout.strip() == ""       # no result, no JSON line
     assert "no TPU" in out.stderr
+
+
+@pytest.mark.parametrize("module",
+                         ["bigdl_tpu", "chip_smoke", "benchmark.run"])
+def test_import_touches_no_backend(module):
+    """One process per chip: an importer can still hand it to a child."""
+    code = (f"import jax, {module};"
+            "from jax._src import xla_bridge;"
+            "assert not xla_bridge._backends, xla_bridge._backends")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=REPO)
+    assert out.returncode == 0, out.stderr
 
 
 def test_cache_helper_leaves_jax_alone_when_placed_from_outside(

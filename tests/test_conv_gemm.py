@@ -142,8 +142,7 @@ def _walk(m):
 @pytest.mark.slow
 def test_jax_twin_forward_and_step():
     """The independent plain-JAX twin runs: forward shapes, one train
-    step, finite loss (perf numbers are measured on hardware by
-    models/resnet_mfu_lab.py)."""
+    step, finite loss (its hardware numbers are docs/PERF.md's)."""
     from bigdl_tpu.models.resnet_jax_twin import (forward, init_params,
                                                   make_train_step)
 

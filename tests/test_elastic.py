@@ -8,6 +8,7 @@ descending.  No spec ever waits on a dead collective: every wait is
 bounded by a watchdog deadline, heartbeat timeout, or rendezvous
 timeout.
 """
+import contextlib
 import os
 import time
 
@@ -408,7 +409,10 @@ def _regression_samples(n=256, seed=0):
     return [Sample(x[i], y[i]) for i in range(n)]
 
 
-def test_elastic_chaos_end_to_end(tmp_path):
+@pytest.mark.parametrize("steps,hang_at,slow,die_at,back_at",
+                         [(56, 8, 3.0, 26, 38), (20, None, None, 6, 14)])
+def test_elastic_chaos_end_to_end(tmp_path, steps, hang_at, slow, die_at,
+                                  back_at):
     """The acceptance spec: a simulated 4-host cluster (FileKV — the
     file/dir transport carries the real protocol), one coordinator per
     fake host, driven through
@@ -435,9 +439,10 @@ def test_elastic_chaos_end_to_end(tmp_path):
     sims = [
         SimulatedHost("host1", kv, heartbeat_timeout=0.3),
         SimulatedHost("host2", kv, heartbeat_timeout=0.3,
-                      die_at_leader_step=26, rejoin_at_leader_step=38),
+                      die_at_leader_step=die_at,
+                      rejoin_at_leader_step=back_at),
         SimulatedHost("host3", kv, heartbeat_timeout=0.3,
-                      step_time=3.0, readmit_at_leader_step=38),
+                      step_time=slow, readmit_at_leader_step=back_at),
     ]
     summary = ElasticSummary(str(tmp_path / "logs"), "chaos")
     ts = TrainSummary(str(tmp_path / "logs"), "chaos")
@@ -452,7 +457,7 @@ def test_elastic_chaos_end_to_end(tmp_path):
     opt = DistriOptimizer(model, array(_regression_samples()),
                           nn.MSECriterion(), batch_size=64)
     opt.set_optim_method(SGD(learning_rate=0.3))
-    opt.set_end_when(max_iteration(56))
+    opt.set_end_when(max_iteration(steps))
     opt.set_checkpoint(str(tmp_path / "ckpt"), several_iteration(1))
     opt.set_retry_policy(RetryPolicy(max_retries=20, backoff_base=0.01,
                                      backoff_max=0.05))
@@ -462,7 +467,9 @@ def test_elastic_chaos_end_to_end(tmp_path):
 
     # pace the driver to ~50ms/step (delay_host on the real host) so
     # heartbeat staleness and sustained-skew windows are meaningful
-    with faults.hang_collective("host0", at_step=8, seconds=30) as hang, \
+    hang_cm = faults.hang_collective("host0", at_step=hang_at, seconds=30) \
+        if hang_at else contextlib.nullcontext({"fired": 0})
+    with hang_cm as hang, \
          faults.delay_host("host0", 0.05, at_step=1) as pace:
         for s in sims:
             s.start()
@@ -473,15 +480,15 @@ def test_elastic_chaos_end_to_end(tmp_path):
                 s.stop()
     elapsed = time.monotonic() - t_start
     assert elapsed < 120, f"chaos run must stay bounded, took {elapsed:.0f}s"
-    assert hang["fired"] == 1
+    assert hang["fired"] == bool(hang_at)
     assert pace["fired"] > 10
 
     # --- membership story ------------------------------------------------
     c = ctx.counters()
-    assert c["incarnation_changes"] >= 3, c     # evict + death + regrow
-    assert c["watchdog_trips"] >= 1, c
-    assert c["evictions"] >= 1, c
-    assert "host3" in c["evicted_hosts"], c
+    assert c["incarnation_changes"] >= (3 if slow else 1), c
+    assert c["watchdog_trips"] >= bool(hang_at), c
+    assert c["evictions"] >= bool(slow), c
+    assert ("host3" in c["evicted_hosts"]) == bool(slow), c
     assert "host2" not in c["evicted_hosts"], \
         "a dead host is the death path's business, not an eviction"
     assert c["recoveries_s"] and max(c["recoveries_s"]) < 30, c
@@ -493,13 +500,14 @@ def test_elastic_chaos_end_to_end(tmp_path):
     # --- ElasticSummary reports the acceptance counters ------------------
     incs = summary.read_scalar("Incarnation")
     assert len({v for _, v in incs}) >= 2        # >= 1 incarnation change
-    assert [v for _, v in summary.read_scalar("Evictions")][-1] >= 1
-    assert [v for _, v in summary.read_scalar("WatchdogTrips")][-1] >= 1
+    if slow:    # the small case has no hang and no straggler
+        for tag in ("Evictions", "WatchdogTrips"):
+            assert [v for _, v in summary.read_scalar(tag)][-1] >= 1
+        assert summary.read_scalar("StragglerSkew")
     assert summary.read_scalar("RecoverySeconds")
-    assert summary.read_scalar("StragglerSkew")
 
     # --- the training contract -------------------------------------------
-    assert opt.optim_method.state["neval"] - 1 == 56, "run must complete"
+    assert opt.optim_method.state["neval"] - 1 == steps, "run must complete"
     losses = ts.read_scalar("Loss")
     first = np.mean([v for _, v in losses[:3]])
     last = np.mean([v for _, v in losses[-3:]])

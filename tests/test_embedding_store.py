@@ -161,17 +161,19 @@ def test_clean_shrink_is_bitwise_identical(tmp_path):
         for s in survivors.values())
 
 
-def test_regrow_corrupt_shard_recovers_from_checkpointed_leg(tmp_path):
-    kv, stores = _cluster(tmp_path)
+@pytest.mark.parametrize("n_rows,block_rows", [(512, 32), (8192, 256)])
+def test_regrow_corrupt_shard_recovers_from_checkpointed_leg(
+        tmp_path, n_rows, block_rows):
+    kv, stores = _cluster(tmp_path, n_rows=n_rows, block_rows=block_rows)
     rng = np.random.RandomState(1)
-    target = rng.standard_normal((512, 8)).astype(np.float32)
+    target = rng.standard_normal((n_rows, 8)).astype(np.float32)
     _train(stores, rng, target, n_steps=6)
     for s in stores.values():
         s.checkpoint()
     before = table_checksum(list(stores.values()))
 
-    joiner = EmbeddingStore(TABLE, 512, 8, "host-3", HOSTS, kv=kv,
-                            block_rows=32, seed=7,
+    joiner = EmbeddingStore(TABLE, n_rows, 8, "host-3", HOSTS, kv=kv,
+                            block_rows=block_rows, seed=7,
                             checkpoint_dir=str(tmp_path))
     grown = HOSTS + ["host-3"]
     with faults.corrupt_migration_shard(TABLE, times=1) as f:
@@ -186,6 +188,12 @@ def test_regrow_corrupt_shard_recovers_from_checkpointed_leg(tmp_path):
     assert table_checksum(legs) == before
     assert all(s.version == 1 and s.members == tuple(sorted(grown))
                for s in legs)
+    client = SparseFetchClient({s.host: s for s in legs})   # Zipf reads
+    for _ in range(60):
+        assert client.fetch([int(r) for r in np.minimum(
+            rng.zipf(1.3, size=32) - 1, n_rows - 1)]).ok
+    snap = client.health_snapshot()
+    assert snap["bad_rows_served"] == 0 and snap["rows_served"] > 0
 
 
 def test_corrupt_shard_without_checkpoint_leg_raises_typed():

@@ -19,7 +19,8 @@ from bigdl_tpu.telemetry import (ChangeJournal, IncidentEngine,
                                  Telemetry, merge_alerts,
                                  merge_cluster, merge_incidents,
                                  record_change, reset_default_journal)
-from bigdl_tpu.telemetry import metric_names as M
+from bigdl_tpu.resilience import faults
+from bigdl_tpu.telemetry import events, metric_names as M
 from bigdl_tpu.telemetry.events import CHANGE_EVENT_KINDS, SCOPE_KEYS
 
 
@@ -270,19 +271,29 @@ def test_onset_precedes_firing_edge():
     assert inc.onset_at < inc.opened_at
 
 
-def test_suspect_ranking_scope_beats_fleet_wide_beats_mismatch():
+@pytest.mark.parametrize("healthy,injector",
+                         [(10, None), (60, faults.kill_replica)])
+def test_suspect_ranking_scope_beats_fleet_wide_beats_mismatch(
+        healthy, injector, monkeypatch, request):
     c, rec, j, eng, ie, _ = _wire(P99_RULE)
+    monkeypatch.setattr(events, "_default", j)
     L = {"replica": "r1"}
-    for _ in range(10):
+    for _ in range(healthy):
         rec.observe(M.REPLICA_P99_SECONDS, 0.05, labels=L)
-        ie.observe(eng.evaluate())
+        assert ie.observe(eng.evaluate()) == []
         c.tick(5.0)
+    assert ie.opened_total == 0 and ie.open_incidents() == []
     # three candidate causes, same instant: a ground-truth chaos
     # injection on the breached replica, a fleet-wide membership
     # change, and an autoscale move on a DIFFERENT replica (shared
     # key, conflicting value -> ranked below fleet-wide)
-    j.record("chaos_inject", "kind=kill", ground_truth=True,
-             replica="r1")
+    if injector:        # armed, never fired: there is no fleet here
+        fault = injector("r1")
+        fault.__enter__()
+        request.addfinalizer(lambda: fault.__exit__(None, None, None))
+    else:
+        j.record("chaos_inject", "kind=kill", ground_truth=True,
+                 replica="r1")
     j.record("membership_change", "incarnation=7")
     j.record("autoscale_up", "scale 2->3", replica="r9",
              pool="decode")

@@ -444,7 +444,8 @@ def test_snapshot_and_run_report_carry_tenant_view(tmp_path, capsys):
 # + poisoned deploy
 # ---------------------------------------------------------------------------
 
-def test_e2e_two_tenant_fleet_isolates_noisy_neighbor():
+@pytest.mark.parametrize("n,n_floods", [(60, 4), (30, 2)])
+def test_e2e_two_tenant_fleet_isolates_noisy_neighbor(n, n_floods):
     DEADLINE = 5.0
     fl = multi_fleet(n=2, capacity=16, pump_interval_s=0.05,
                      heartbeat_timeout=0.3,
@@ -467,7 +468,7 @@ def test_e2e_two_tenant_fleet_isolates_noisy_neighbor():
             return lats
 
         # tenant-B solo baseline
-        solo = beta_closed_loop(60)
+        solo = beta_closed_loop(n)
         solo_lat = sorted(l for _, l, _ in solo)
         solo_p99 = solo_lat[int(0.99 * (len(solo_lat) - 1))]
 
@@ -488,7 +489,7 @@ def test_e2e_two_tenant_fleet_isolates_noisy_neighbor():
                 time.sleep(0.001)
 
         floods = [threading.Thread(target=alpha_flood, args=(s,))
-                  for s in range(4)]
+                  for s in range(n_floods)]
         for th in floods:
             th.start()
         try:
@@ -506,7 +507,7 @@ def test_e2e_two_tenant_fleet_isolates_noisy_neighbor():
                         and time.monotonic() < deadline:
                     time.sleep(0.01)
             assert "alpha-r0" not in fl.router.members
-            contended = beta_closed_loop(60)
+            contended = beta_closed_loop(n)
         finally:
             stop.set()
             for th in floods:
@@ -538,11 +539,13 @@ def test_e2e_two_tenant_fleet_isolates_noisy_neighbor():
         # tenant B shed ZERO requests and its p99 stayed bounded
         tenants = fl.router.metrics.tenants()
         assert tenants["beta"]["shed_total"] == 0
+        assert tenants["beta"]["total"] >= 2 * n
         con_lat = sorted(l for _, l, _ in contended)
         con_p99 = con_lat[int(0.99 * (len(con_lat) - 1))]
         # isolation bar: <= 1.25x the solo baseline (+50ms grace for
         # shared-CPU scheduler noise at millisecond latencies)
-        assert con_p99 <= 1.25 * solo_p99 + 0.05, \
+        # at the full size only: of 30 latencies the p99 is the worst
+        assert n < 60 or con_p99 <= 1.25 * solo_p99 + 0.05, \
             f"tenant-B p99 {con_p99:.4f}s vs solo {solo_p99:.4f}s"
 
         # the flood DID make tenant A shed typed through its quota —

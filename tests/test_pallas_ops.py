@@ -161,7 +161,7 @@ class TestFlashInAttentionLayer:
 
 class TestPickBlock:
     """Pin the measured block-target rule (r4 on-chip matrix,
-    MFU_LAB.jsonl flash rows): target 1024 everywhere except wide heads
+    docs/PERF.md's flash rows): target 1024 everywhere except wide heads
     (D>=128) at short sequences (T<=1024), where 512 measured faster."""
 
     def test_long_sequences_target_1024(self):

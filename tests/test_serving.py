@@ -163,21 +163,26 @@ def test_expired_budget_fast_fails_generate_path():
         srv.stop(timeout=10)
 
 
-def test_queue_full_sheds_with_typed_overloaded():
-    srv = InferenceServer(small_model(), max_batch=4, max_queue=4)
+@pytest.mark.parametrize("max_batch,max_queue,n", [(4, 4, 40), (8, 16, 48)])
+def test_queue_full_sheds_with_typed_overloaded(max_batch, max_queue, n):
+    srv = InferenceServer(small_model(), max_batch=max_batch,
+                          max_queue=max_queue)
     srv.start()
     try:
         rng = np.random.RandomState(0)
         with faults.serving_step_latency(0.25, times=4):
-            futs = [srv.submit(feat(rng)) for _ in range(40)]
+            futs = [srv.submit(feat(rng)) for _ in range(n)]
             res = [f.result(timeout=60) for f in futs]
         by = Counter(r.status for r in res)
         assert by[Status.OVERLOADED] > 0       # shed, not queued forever
         assert by[Status.OK] > 0               # admitted ones served
-        assert by[Status.OK] + by[Status.OVERLOADED] == 40
+        assert by[Status.OK] + by[Status.OVERLOADED] == n
         snap = srv.metrics.snapshot()
         assert snap["shed"] == by[Status.OVERLOADED]  # counted, not silent
-        assert snap["shed_rate"] == pytest.approx(by[Status.OVERLOADED] / 40)
+        assert snap["shed_rate"] == pytest.approx(by[Status.OVERLOADED] / n)
+        assert snap["total"] == n == snap["served_ok"] + snap["shed"] \
+            + snap["deadline_exceeded"] + snap["internal_error"]
+        assert srv.drain(timeout=30)
     finally:
         srv.stop(timeout=10)
 

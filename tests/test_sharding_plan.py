@@ -308,7 +308,8 @@ def test_composed_2x2x2_matches_single_device_loss_trajectory():
 # FSDP: params beyond one device's budget, measured ~1/N per device
 # ---------------------------------------------------------------------------
 
-def test_fsdp_trains_model_exceeding_one_device_budget():
+@pytest.mark.parametrize("steps", [3, 4])
+def test_fsdp_trains_model_exceeding_one_device_budget(steps):
     from bigdl_tpu.telemetry import MetricsRegistry, Telemetry
 
     def build():
@@ -325,14 +326,17 @@ def test_fsdp_trains_model_exceeding_one_device_budget():
     def drive(fsdp_min_bytes):
         model = build()
         tm = Telemetry(registry=MetricsRegistry())
+        rec = _LossLog()
         opt = DistriOptimizer(model, array(samples),
                               nn.ClassNLLCriterion(), batch_size=64)
-        opt.set_optim_method(SGD(learning_rate=0.2))
-        opt.set_end_when(max_iteration(3))
+        opt.set_optim_method(SGD(learning_rate=0.1))
+        opt.set_end_when(max_iteration(steps))
         opt.set_telemetry(tm)
+        opt.set_train_summary(rec)
         if fsdp_min_bytes:
             opt.set_fsdp(fsdp_min_bytes)
         opt.optimize()
+        assert rec.losses[-1] < rec.losses[0]
         snap = tm.registry.snapshot()["metrics"]
         per_dev = snap["bigdl_plan_param_bytes_per_device"]["series"][0][
             "value"]
@@ -348,6 +352,7 @@ def test_fsdp_trains_model_exceeding_one_device_budget():
     assert total > budget
     assert per_dev < budget
     assert per_dev == pytest.approx(total / n, rel=0.35)
+    assert 0.10 <= per_dev / total <= 0.25
 
     # replicated control: every device holds the whole tree...
     model_dp, per_dev_dp, total_dp = drive(None)

@@ -179,7 +179,11 @@ def test_sparse_matches_dense_exactly_lookup(overflow):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_dlrm_sparse_matches_dense_loss_trajectory():
+@pytest.mark.parametrize("table_sizes,dim,batch,shard_min,win", [
+    ((1024, 256, 64), 8, 64, 16 * 1024, 3),
+    ((2048, 512, 128), 16, 128, 64 * 1024, 5)])
+def test_dlrm_sparse_matches_dense_loss_trajectory(table_sizes, dim, batch,
+                                                   shard_min, win):
     """The satellite spec: same seed, same Zipf batches — the DLRM
     with row-sharded big tables + sparse-transport small tables tracks
     the replicate-everything dense-all-reduce run within the
@@ -188,17 +192,15 @@ def test_dlrm_sparse_matches_dense_loss_trajectory():
     publishes."""
     from bigdl_tpu.telemetry import MetricsRegistry, Telemetry
 
-    table_sizes = (1024, 256, 64)
-
     def drive(plan):
         set_global_seed(11)
-        model = DLRM(dense_dim=4, table_sizes=table_sizes, embed_dim=8,
-                     shard_min_bytes=16 * 1024)
-        ds = ZipfClickstream(256, table_sizes, dense_dim=4)
+        model = DLRM(dense_dim=4, table_sizes=table_sizes, embed_dim=dim,
+                     shard_min_bytes=shard_min)
+        ds = ZipfClickstream(4 * batch, table_sizes, dense_dim=4)
         tm = Telemetry(registry=MetricsRegistry())
         rec = _LossLog()
         opt = DistriOptimizer(model, ds, nn.BCECriterion(),
-                              batch_size=64)
+                              batch_size=batch)
         opt.set_optim_method(SGD(learning_rate=0.5))
         opt.set_end_when(max_iteration(6))
         opt.set_telemetry(tm)
@@ -216,14 +218,14 @@ def test_dlrm_sparse_matches_dense_loss_trajectory():
                 gauge("bigdl_perf_sparse_bytes_saved"), model)
 
     sparse_losses, sparse_bytes, saved, model = drive(None)
-    assert model.sharded_tables == [0]  # 1024x8 f32 = 32 KiB >= 16 KiB
+    assert model.sharded_tables == [0]  # the first table alone is over
     dense_losses, dense_bytes, _, _ = drive(Plan([Rule(".*", P())]))
     assert len(sparse_losses) == len(dense_losses) == 6
     np.testing.assert_allclose(sparse_losses, dense_losses, rtol=2e-3,
                                atol=2e-4)
     # the wire win the transport exists for, on the judged gauge
     assert sparse_bytes is not None and dense_bytes is not None
-    assert sparse_bytes < dense_bytes / 3
+    assert sparse_bytes < dense_bytes / win
     assert saved and saved > 0
 
 
@@ -376,7 +378,7 @@ def test_host_death_repartitions_sharded_rows(tmp_path):
     from bigdl_tpu.resilience import (CollectiveWatchdog, ElasticContext,
                                       ElasticCoordinator, InMemoryKV,
                                       RetryPolicy, SimulatedHost,
-                                      StepTimeEstimator)
+                                      StepTimeEstimator, faults)
     from bigdl_tpu.resilience.integrity import checksum_tree
 
     kv = InMemoryKV()
@@ -417,7 +419,9 @@ def test_host_death_repartitions_sharded_rows(tmp_path):
     for s in sims:
         s.start()
     try:
-        opt.optimize()
+        # paced, or a fast host ends the run inside the heartbeat window
+        with faults.delay_host("host0", 0.05, at_step=1):
+            opt.optimize()
     finally:
         for s in sims:
             s.stop()

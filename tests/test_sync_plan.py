@@ -266,8 +266,9 @@ def _reg_model():
     return nn.Sequential(nn.Linear(8, 16), nn.Tanh(), nn.Linear(16, 1))
 
 
-def test_periodic_loss_matches_lockstep_rtol():
-    """periodic(4) local SGD tracks the lockstep trajectory within
+@pytest.mark.parametrize("period,steps,batch", [(4, 8, 256), (8, 6, 128)])
+def test_periodic_loss_matches_lockstep_rtol(period, steps, batch):
+    """periodic(k) local SGD tracks the lockstep trajectory within
     rtol 2e-3 on the 8-dev forced-host mesh, while the plan-derived
     collective-bytes gauge reports the AMORTIZED wire and the new
     sync-saved gauge publishes."""
@@ -281,9 +282,9 @@ def test_periodic_loss_matches_lockstep_rtol():
         tm = Telemetry(registry=MetricsRegistry())
         rec = _LossLog()
         opt = DistriOptimizer(model, array(samples), nn.MSECriterion(),
-                              batch_size=256)
+                              batch_size=batch)
         opt.set_optim_method(SGD(learning_rate=0.01))
-        opt.set_end_when(max_iteration(8))
+        opt.set_end_when(max_iteration(steps))
         opt.set_telemetry(tm)
         opt.set_train_summary(rec)
         if plan is not None:
@@ -299,15 +300,17 @@ def test_periodic_loss_matches_lockstep_rtol():
                 gauge("bigdl_perf_sync_bytes_saved"))
 
     got, rel_bytes, saved = run(
-        Plan([Rule(".*", P(), sync="periodic(4)")]))
+        Plan([Rule(".*", P(), sync=f"periodic({period})")]))
     want, lock_bytes, lock_saved = run(None)
-    assert len(got) == len(want) == 8
+    assert len(got) == len(want) == steps
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
-    assert got[-1] < got[0]  # and the trajectory descends
-    # the amortized accounting: periodic(4) reports ~1/4 of lockstep
+    if batch == 256:    # four noisier batches an epoch do not, in six
+        assert got[-1] < got[0]  # and the trajectory descends
+    # the amortized accounting: periodic(k) reports ~1/k of lockstep
     # (the 1-element bias is a scalar rule — it stays lockstep and
-    # contributes its full ring to both, hence the 3% slack)
-    assert rel_bytes == pytest.approx(lock_bytes / 4, rel=0.03)
+    # contributes its full ring to both: (160 + k) / 161 of 1/k)
+    assert rel_bytes == pytest.approx(lock_bytes / period,
+                                      rel=0.01 * period)
     assert saved == pytest.approx(lock_bytes - rel_bytes)
     assert lock_saved is None  # lockstep never publishes the gauge
 

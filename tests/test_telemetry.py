@@ -424,7 +424,8 @@ def _regression_samples(n=256):
     return [Sample(x[i], y[i]) for i in range(n)]
 
 
-def test_local_optimizer_feeds_telemetry(tmp_path):
+@pytest.mark.parametrize("steps,batch,every", [(6, 64, 3), (120, 512, 30)])
+def test_local_optimizer_feeds_telemetry(tmp_path, steps, batch, every):
     from bigdl_tpu import nn
     from bigdl_tpu.dataset import array
     from bigdl_tpu.optim import SGD, max_iteration, several_iteration
@@ -433,18 +434,18 @@ def test_local_optimizer_feeds_telemetry(tmp_path):
     tm = Telemetry(registry=MetricsRegistry(), host="local",
                    snapshot_dir=str(tmp_path / "snaps"))
     model = nn.Sequential(nn.Linear(4, 8), nn.Tanh(), nn.Linear(8, 1))
-    opt = LocalOptimizer(model, array(_regression_samples()),
-                         nn.MSECriterion(), batch_size=64)
+    opt = LocalOptimizer(model, array(_regression_samples(4 * batch)),
+                         nn.MSECriterion(), batch_size=batch)
     opt.set_optim_method(SGD(learning_rate=0.2))
-    opt.set_end_when(max_iteration(6))
-    opt.set_checkpoint(str(tmp_path / "ckpt"), several_iteration(3))
+    opt.set_end_when(max_iteration(steps))
+    opt.set_checkpoint(str(tmp_path / "ckpt"), several_iteration(every))
     opt.set_telemetry(tm)
     opt.optimize()
 
-    assert tm.steps.value == 6
-    assert tm.records.value == 6 * 64
+    assert tm.steps.value == steps
+    assert tm.records.value == steps * batch
     assert tm.compile_seconds.count == 1    # first step = XLA build
-    assert tm.step_seconds.count == 5
+    assert tm.step_seconds.count == steps - 1
     assert tm.checkpoint_seconds.count >= 1
     gp = tm.ledger.snapshot()
     assert gp["seconds"]["productive"] > 0
@@ -453,11 +454,11 @@ def test_local_optimizer_feeds_telemetry(tmp_path):
     # the tracer exported a parseable trace with step spans
     trace = json.loads(json.dumps(tm.tracer.to_chrome_trace()))
     names = [e["name"] for e in trace["traceEvents"]]
-    assert names.count("train.iteration") == 6
+    assert names.count("train.iteration") == steps
     assert "train.checkpoint" in names
     compiled = [e["args"]["compiled"] for e in trace["traceEvents"]
                 if e["name"] == "train.dispatch"]
-    assert compiled == [True] + [False] * 5
+    assert compiled == [True] + [False] * (steps - 1)
     # the end-of-run snapshot landed for tools/run_report.py
     assert "local" in read_snapshot_dir(str(tmp_path / "snaps"))
 

@@ -5,7 +5,7 @@ changed.
 Input: a JSON artifact carrying incidents in any of the shapes the
 stack produces —
 
-* a bench artifact (``INCIDENT_r01.json``) with an ``incidents`` list;
+* an artifact with an ``incidents`` list;
 * a merged cluster view (``merge_cluster`` output) whose ``incidents``
   key holds the ``merge_incidents`` fold;
 * a single ``Telemetry.payload()`` / ``IncidentEngine.snapshot()``
@@ -18,7 +18,7 @@ ranked suspect list the blame engine produced.  ``--json`` prints the
 normalized report instead (machine parity with the rendered view).
 
 Usage:
-    python tools/incident_report.py INCIDENT_r01.json
+    python tools/incident_report.py incidents.json
     python tools/incident_report.py cluster.json --json
 """
 import argparse
@@ -50,7 +50,7 @@ def load_incidents(path: str) -> list:
         if isinstance(snap.get(key), list):
             out.extend(snap[key])
     # bench artifact: per-scenario records each carrying an incident
-    # (a name -> record dict from INCIDENT_r01.json; tolerate a list)
+    # (a name -> record dict; tolerate a list)
     scenarios = data.get("scenarios") or {}
     if isinstance(scenarios, dict):
         scenarios = [dict(sc, name=name)

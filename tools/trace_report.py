@@ -2,9 +2,9 @@
 """Critical-path analysis over stitched distributed request traces.
 
 Input: a directory of stitched Chrome-trace JSONs (one per kept trace
-— what ``ServingFleet.stitch_trace`` / the bench trace leg writes), or
-a single chaos artifact carrying ``{"traces": {trace_id: <trace>}}``
-(TRACE_r01.json).  For every trace it computes, via
+— what ``ServingFleet.stitch_trace`` writes), or a single artifact
+carrying ``{"traces": {trace_id: <trace>}}``.  For every trace it
+computes, via
 ``bigdl_tpu.serving.request_trace.trace_attribution``:
 
 * wall-clock coverage (span union / request wall, hedge losers
@@ -19,7 +19,7 @@ dominant phase + replica are named.
 
 Usage:
     python tools/trace_report.py <trace_dir | artifact.json> [--json]
-    python tools/trace_report.py TRACE_r01.json --top 5
+    python tools/trace_report.py traces.json --top 5
 """
 import argparse
 import json
