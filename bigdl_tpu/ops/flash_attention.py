@@ -4,25 +4,39 @@ kernels (the hot op the reference era lacked; replaces materializing the
 VMEM).
 
 Design (pallas_guide.md patterns):
-- forward: grid = (batch*heads, T/block_q, S/block_k); each program owns
-  one (q tile, k tile) pair.  K/V blocks are *streamed* from HBM by the
-  BlockSpec index_map — VMEM holds only one (block_k, d) K and V tile at
-  a time, so sequence length is bounded by HBM, not VMEM.  Online
-  softmax carries m (running row max), l (running denominator), acc
-  (unnormalized output) in VMEM scratch across the innermost k grid
-  dimension; the output AND the row log-sum-exp (the backward's softmax
-  statistic) are written once on the final k step.
+- two tile sizes.  The GRID tile (``_pick_block``: 1024 where it fits)
+  is what a program owns and what the BlockSpec index maps stream from
+  HBM — VMEM holds one (block_k, d) K and V tile at a time, so sequence
+  length is bounded by HBM, not VMEM.  Inside it every kernel walks
+  SUB-TILES (``_pick_sub_tile``): the score tile that exists at one
+  time is (sub_q, sub_k), and the running statistics of one sub-tile
+  row are carried from sub-tile to sub-tile as values.  Grid steps are
+  bought with the grid tile; skipped and unmasked work with the
+  sub-tile.  The walk is unrolled — at most (grid tile / sub-tile)²
+  bodies, whatever T: on the v5e a rolled ``lax.fori_loop`` over the
+  same sub-tiles ran 1.5-3.3x slower (PERF.md §6 "PR 30").
+- forward: grid = (batch*heads, T/block_q, S/block_k).  For each q
+  sub-tile the k sub-tiles up to the diagonal fold into (m, l, acc);
+  with one k grid tile the output and the row log-sum-exp (the
+  backward's softmax statistic) are written straight from the carry,
+  with several the carry rests in VMEM scratch between grid steps.
 - backward: two Pallas kernels (FlashAttention-2 schedule).  Both
   recompute the probability tile from (q, k, lse) on the fly — no (T, S)
-  array ever exists.  Scores are computed TRANSPOSED, (block_k rows ×
-  block_q lanes), so the per-q-row lse/delta vectors broadcast along the
+  array ever exists.  Scores are computed TRANSPOSED, (sub_k rows ×
+  sub_q lanes), so the per-q-row lse/delta vectors broadcast along the
   sublane dimension without any in-kernel transpose:
-    * dKdV kernel: grid (BH, S/block_k, T/block_q), dk/dv accumulate in
-      VMEM scratch over the inner q sweep;
-    * dQ kernel: grid (BH, T/block_q, S/block_k), dq accumulates over
-      the inner k sweep.
-- causal: blocks strictly above the diagonal are skipped via ``pl.when``
-  in all three kernels (no wasted MXU work).
+    * dKdV kernel: grid (BH, S/block_k, T/block_q); for each k sub-tile
+      dk/dv accumulate over the q sub-tiles from the diagonal down;
+    * dQ kernel: grid (BH, T/block_q, S/block_k); for each q sub-tile
+      dq accumulates over the k sub-tiles up to the diagonal.
+- causal: the walks stop at the diagonal (``_visible_k`` /
+  ``_visible_q``; ``causal_schedule`` counts what they give): a
+  sub-tile wholly above it is never touched, one wholly under it takes
+  no mask at all, and only the sub-tiles the diagonal crosses build
+  ``_tile_causal_mask``.  Where the diagonal crosses a grid tile is
+  static per tile offset (``_tile_offsets``): ``pl.when`` picks that
+  offset's schedule, the unmasked one for a grid tile wholly under the
+  diagonal, and none for one above it.
 - matmuls run in the input dtype (bf16 stays bf16 on the MXU) with f32
   accumulation; probability tiles are cast back to the input dtype
   before the PV/dV/dK products — elementwise math stays f32.
@@ -66,9 +80,12 @@ def _dot(a, b, dims):
 
 # --------------------------------------------------------------------------
 # Shared tile machinery — ONE implementation of the online-softmax
-# (m, l, acc) accumulate and the FlashAttention-2 backward tile, used by
-# both the dense-grid flash kernels below and the block-sparse kernels
-# (ops/block_sparse.py), so the two can never drift numerically.
+# (m, l, acc) update and the FlashAttention-2 backward tile, as functions
+# of VALUES.  The dense-grid flash kernels below carry those values
+# from sub-tile to sub-tile; the block-sparse kernels
+# (ops/block_sparse.py) keep them in VMEM scratch between grid steps
+# through the thin ``*_tile`` wrappers — so the two can never drift
+# numerically.
 # --------------------------------------------------------------------------
 
 def _tile_causal_mask(q_start, k_start, block_q: int, block_k: int,
@@ -86,18 +103,16 @@ def _tile_causal_mask(q_start, k_start, block_q: int, block_k: int,
     return q_pos >= k_pos
 
 
-def _init_softmax_scratch(m_scr, l_scr, acc_scr):
-    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+def _softmax_init(rows: int, d: int):
+    """(m, l, acc) of a row block that has seen no key yet."""
+    return (jnp.full((rows, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, d), jnp.float32))
 
 
-def _online_softmax_tile(s, v, m_scr, l_scr, acc_scr):
+def _softmax_update(s, v, m, l, acc):
     """Fold one (bq, bk) f32 score tile into the running (max, denom,
     unnormalized output) statistics — the online-softmax accumulate."""
-    m = m_scr[...][:, :1]                             # (bq, 1)
-    l = l_scr[...][:, :1]
-    acc = acc_scr[...]
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     # guard fully-masked rows: exp(-inf - -inf) would be nan
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -105,22 +120,18 @@ def _online_softmax_tile(s, v, m_scr, l_scr, acc_scr):
     scale = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
     l_new = l * scale + jnp.sum(p, axis=-1, keepdims=True)
     acc_new = acc * scale + _dot(p.astype(v.dtype), v, ((1,), (0,)))
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-    acc_scr[...] = acc_new
+    return m_new, l_new, acc_new
 
 
-def _finish_softmax_tile(o_ref, lse_ref, m_scr, l_scr, acc_scr):
-    """Normalize the accumulated output and emit the row log-sum-exp
-    (the backward's softmax statistic); fully-masked rows (l == 0)
-    produce exactly zero output and the ``_BIG_LSE`` sentinel."""
-    m = m_scr[...][:, :1]
-    l = l_scr[...][:, :1]
-    o_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    # lse as a ROW (1, bq): broadcast along sublanes in the backward
+def _softmax_finish(m, l, acc):
+    """Normalized output and the row log-sum-exp (the backward's softmax
+    statistic) as a ROW (1, bq): it broadcasts along sublanes in the
+    backward.  Fully-masked rows (l == 0) give exactly zero output and
+    the ``_BIG_LSE`` sentinel."""
+    out = acc / jnp.maximum(l, 1e-30)
     lse = jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)),
                     _BIG_LSE)
-    lse_ref[0] = lse[:, 0][None, :]
+    return out, lse[:, 0][None, :]
 
 
 def _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask):
@@ -136,72 +147,241 @@ def _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask):
     return pt, dst
 
 
+def _dkv_tile(q, do, k, v, lse, delta, sm_scale, st_mask):
+    """One tile's (dk, dv) contribution, (bk, d) f32 each."""
+    pt, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
+    dv = _dot(pt.astype(v.dtype), do, ((1,), (0,)))
+    dk = _dot(dst.astype(q.dtype), q, ((1,), (0,))) * sm_scale
+    return dk, dv
+
+
+def _dq_tile(q, do, k, v, lse, delta, sm_scale, st_mask):
+    """One tile's dq contribution, (bq, d) f32: ds @ k contracts the bk
+    (sublane) dim — no transpose."""
+    _, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
+    return _dot(dst.astype(k.dtype), k, ((0,), (0,))) * sm_scale
+
+
+# the same arithmetic over VMEM scratch, for kernels whose accumulators
+# live across grid steps (ops/block_sparse.py; the dense kernels' carry
+# between grid tiles)
+
+def _init_softmax_scratch(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _online_softmax_tile(s, v, m_scr, l_scr, acc_scr):
+    m, l, acc = _softmax_update(s, v, m_scr[...][:, :1], l_scr[...][:, :1],
+                                acc_scr[...])
+    m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+    acc_scr[...] = acc
+
+
+def _finish_softmax_tile(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    out, lse = _softmax_finish(m_scr[...][:, :1], l_scr[...][:, :1],
+                               acc_scr[...])
+    o_ref[0] = out.astype(o_ref.dtype)
+    lse_ref[0] = lse
+
+
 def _accum_dkv_tile(q, do, k, v, lse, delta, sm_scale, st_mask,
                     dk_scr, dv_scr):
-    pt, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
-    dv_scr[...] += _dot(pt.astype(v.dtype), do, ((1,), (0,)))  # (bk, d)
-    dk_scr[...] += _dot(dst.astype(q.dtype), q, ((1,), (0,))) * sm_scale
+    dk, dv = _dkv_tile(q, do, k, v, lse, delta, sm_scale, st_mask)
+    dv_scr[...] += dv
+    dk_scr[...] += dk
 
 
 def _accum_dq_tile(q, do, k, v, lse, delta, sm_scale, st_mask, dq_scr):
-    pt, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
-    # dq += ds @ k — contract the bk (sublane) dim: no transpose
-    dq_scr[...] += _dot(dst.astype(k.dtype), k, ((0,), (0,))) * sm_scale
+    dq_scr[...] += _dq_tile(q, do, k, v, lse, delta, sm_scale, st_mask)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                num_k_blocks: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+# --------------------------------------------------------------------------
+# The causal schedule — ONE rule (a key position is seen by the query
+# positions at or after it), in the two closed forms the kernels' walks
+# need.  Everything here is a Python int: positions are RELATIVE to the
+# grid tile's first key, and ``offset`` = the tile's first query minus
+# its first key takes one of a few static values (0 where the grid tiles
+# are square), so a kernel holds one unrolled schedule per value and the
+# grid indices only choose among them.
+# --------------------------------------------------------------------------
 
-    @pl.when(ki == 0)
-    def _init():
-        _init_softmax_scratch(m_scr, l_scr, acc_scr)
-
-    def compute():
-        q = q_ref[0]                                      # (block_q, d)
-        k = k_ref[0]                                      # (block_k, d)
-        v = v_ref[0]
-        s = _dot(q, k, (((1,), (1,)))) * sm_scale         # (bq, bk) f32
-        if causal:
-            s = jnp.where(_tile_causal_mask(qi * block_q, ki * block_k,
-                                            block_q, block_k),
-                          s, -jnp.inf)
-        _online_softmax_tile(s, v, m_scr, l_scr, acc_scr)
-
-    if causal:
-        # key blocks strictly above the diagonal contribute nothing
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(ki == num_k_blocks - 1)
-    def _finish():
-        _finish_softmax_tile(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+def _clip(x: int, hi: int) -> int:
+    return min(max(x, 0), hi)
 
 
-def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int,
-               block_k: int, interpret: bool):
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    bq = min(block_q, T)
-    bk = min(block_k, S)
+def _visible_k(q0: int, sub_q: int, sub_k: int, n: int):
+    """For the query rows [q0, q0 + sub_q), of a grid tile's ``n`` key
+    sub-tiles: ``(full, computed)`` — sub-tiles [0, full) lie wholly
+    under the diagonal, [full, computed) are crossed by it, the rest
+    wholly above it."""
+    span = n * sub_k
+    return (_clip(q0 + 1, span) // sub_k,
+            _clip(q0 + sub_q - 1 + sub_k, span) // sub_k)
+
+
+def _visible_q(k0: int, sub_k: int, q_base: int, sub_q: int, n: int):
+    """For the key rows [k0, k0 + sub_k), of a grid tile's ``n`` query
+    sub-tiles (the first at ``q_base``): ``(first, full)`` — sub-tiles
+    [first, full) are crossed by the diagonal, [full, n) lie wholly
+    under it, those before ``first`` wholly above it."""
+    span = n * sub_q
+    return (_clip(k0 - q_base, span) // sub_q,
+            _clip(k0 + sub_k - 1 - q_base + sub_q - 1, span) // sub_q)
+
+
+def _tile_offsets(T: int, S: int, block_q: int, block_k: int,
+                  every: bool = False):
+    """The values ``qi * block_q - ki * block_k`` takes on the grid
+    tiles the diagonal crosses.  A tile whose offset is >= block_k - 1
+    lies wholly under the diagonal; one at <= -block_q wholly above it:
+    its schedule is empty, and only ``every`` lists it (for a kernel
+    that must still write its zeros)."""
+    seen = {qi * block_q - ki * block_k
+            for qi in range(T // block_q) for ki in range(S // block_k)}
+    return tuple(sorted(o for o in seen if o < block_k - 1
+                        and (every or o > -block_q)))
+
+
+def causal_schedule(T: int, S: int, grid_tile, sub_tile,
+                    causal: bool = True) -> dict:
+    """Sub-tiles per head that one kernel computes, masks (of the
+    computed: those the diagonal crosses) and skips, for (q, k) tile
+    pairs ``grid_tile`` and ``sub_tile`` (an int means square) — counted
+    with the bounds the kernels' walks run to."""
+    bq, bk = (grid_tile,) * 2 if isinstance(grid_tile, int) else grid_tile
+    sq, sk = (sub_tile,) * 2 if isinstance(sub_tile, int) else sub_tile
+    total = (T // sq) * (S // sk)
+    computed = masked = 0
+    for qi in range(T // bq):
+        for ki in range(S // bk):
+            # a non-causal tile is a tile wholly under the diagonal
+            offset = qi * bq - ki * bk if causal else bk
+            for i in range(bq // sq):
+                full, comp = _visible_k(offset + i * sq, sq, sk, bk // sk)
+                computed += comp
+                masked += comp - full
+    return {"computed": computed, "masked": masked,
+            "skipped": total - computed}
+
+
+def _record_schedule(kernel: str, T, S, D, tiles, causal):
+    """One ``flash.schedule`` event in the process tracer's ring per
+    traced kernel build: the schedule is static, so it is recorded where
+    it is made."""
+    from ..telemetry.tracer import default_tracer
+
+    bq, bk, sq, sk = tiles
+    tr = default_tracer()
+    tr.record("flash.schedule", "compile", tr.clock(), 0.0, kernel=kernel,
+              T=T, S=S, head_dim=D, grid_tile=[bq, bk], sub_tile=[sq, sk],
+              **causal_schedule(T, S, (bq, bk), (sq, sk), causal))
+
+
+def _sub(i: int, size: int) -> slice:
+    return slice(i * size, (i + 1) * size)
+
+
+def _at_static_offset(body, offset, offsets, block_k: int):
+    """Run ``body`` with this grid tile's offset as a Python int: one
+    ``pl.when`` branch per offset the diagonal crosses a tile at, one
+    (``body(block_k)``: nothing masked) for the tiles wholly under it;
+    a tile wholly above it matches none and is skipped."""
+    if isinstance(offset, int):         # a grid of one tile; non-causal
+        return body(offset)
+    for static in offsets:
+        pl.when(offset == static)(functools.partial(body, static))
+    pl.when(offset >= block_k - 1)(functools.partial(body, block_k))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                sm_scale: float, causal: bool, block_q: int, block_k: int,
+                sub_q: int, sub_k: int, num_q_blocks: int,
+                num_k_blocks: int, offsets):
+    qi = pl.program_id(1) if num_q_blocks > 1 else 0
+    ki = pl.program_id(2) if num_k_blocks > 1 else 0
+    n_k = block_k // sub_k
+    d = q_ref.shape[-1]
+    # with one k grid tile the carry never leaves the kernel's values
+    one_pass = num_k_blocks == 1
+    if not one_pass:
+        m_scr, l_scr, acc_scr = scratch
+
+        @pl.when(ki == 0)
+        def _init():
+            _init_softmax_scratch(m_scr, l_scr, acc_scr)
+
+    def q_sub_tile(i: int, offset: int):
+        rows = _sub(i, sub_q)
+        q = q_ref[0, rows, :]                             # (sub_q, d)
+        q0 = offset + i * sub_q
+        full, computed = _visible_k(q0, sub_q, sub_k, n_k)
+        carry = (_softmax_init(sub_q, d) if one_pass else
+                 (m_scr[rows, :1], l_scr[rows, :1], acc_scr[rows, :]))
+        for j in range(computed):
+            cols = _sub(j, sub_k)
+            s = _dot(q, k_ref[0, cols, :], ((1,), (1,))) * sm_scale
+            if j >= full:       # the diagonal crosses this sub-tile
+                s = jnp.where(_tile_causal_mask(q0, j * sub_k, sub_q, sub_k),
+                              s, -jnp.inf)
+            carry = _softmax_update(s, v_ref[0, cols, :], *carry)
+        if one_pass:
+            out, lse = _softmax_finish(*carry)
+            o_ref[0, rows, :] = out.astype(o_ref.dtype)
+            lse_ref[0, :, rows] = lse
+        else:
+            m, l, acc = carry
+            m_scr[rows, :] = jnp.broadcast_to(m, (sub_q, _LANES))
+            l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, _LANES))
+            acc_scr[rows, :] = acc
+
+    def tile(offset: int):
+        for i in range(block_q // sub_q):
+            q_sub_tile(i, offset)
+
+    # a non-causal tile is a tile wholly under the diagonal
+    offset = qi * block_q - ki * block_k if causal else block_k
+    _at_static_offset(tile, offset, offsets, block_k)
+
+    if not one_pass:
+        @pl.when(ki == num_k_blocks - 1)
+        def _finish():
+            _finish_softmax_tile(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _tiles(T: int, S: int, D: int, block_q, block_k, sub_tile):
+    """(block_q, block_k, sub_q, sub_k): the grid tile and the sub-tile
+    of each axis — chosen from the shape where the caller gives None."""
+    bq = min(block_q or _pick_block(T, D), T)
+    bk = min(block_k or _pick_block(S, D), S)
     assert T % bq == 0 and S % bk == 0, (
         f"seq lens ({T}, {S}) must divide block sizes ({bq}, {bk}); "
         "pad sequences to a block multiple")
+    sub = sub_tile or _pick_sub_tile(T, S, D)
+    sq, sk = (sub, sub) if isinstance(sub, int) else sub
+    return bq, bk, _fit_sub_tile(sq, bq), _fit_sub_tile(sk, bk)
+
+
+def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q, block_k,
+               sub_tile, interpret: bool):
+    B, H, T, D = q.shape
+    S = k.shape[2]
+    tiles = bq, bk, sq, sk = _tiles(T, S, D, block_q, block_k, sub_tile)
+    _record_schedule("fwd", T, S, D, tiles, causal)
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * H, S, D)
     vr = v.reshape(B * H, S, D)
 
-    nk = S // bk
+    nq, nk = T // bq, S // bk
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                               block_q=bq, block_k=bk, num_k_blocks=nk)
+                               block_q=bq, block_k=bk, sub_q=sq, sub_k=sk,
+                               num_q_blocks=nq, num_k_blocks=nk,
+                               offsets=_tile_offsets(T, S, bq, bk))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, T // bq, nk),
+        grid=(B * H, nq, nk),
         # bh/q-block programs are independent ("parallel" lets Mosaic
         # pipeline across them); the k sweep carries the online-softmax
         # accumulator and must stay sequential ("arbitrary")
@@ -225,7 +405,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int,
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running row max
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running denominator
             pltpu.VMEM((bq, D), jnp.float32),        # unnormalized output
@@ -236,77 +416,123 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int,
 
 
 def _dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *,
+                dk_ref, dv_ref, *scratch,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                num_q_blocks: int):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+                sub_q: int, sub_k: int, num_q_blocks: int,
+                num_k_blocks: int, offsets):
+    ki = pl.program_id(1) if num_k_blocks > 1 else 0
+    qi = pl.program_id(2) if num_q_blocks > 1 else 0
+    n_q = block_q // sub_q
+    d = k_ref.shape[-1]
+    one_pass = num_q_blocks == 1
+    if not one_pass:
+        dk_scr, dv_scr = scratch
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+        @pl.when(qi == 0)
+        def _init():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def compute():
-        # transposed scores: (bk rows, bq lanes) — lse/delta broadcast
-        # along sublanes with no in-kernel transpose
-        st_mask = _tile_causal_mask(qi * block_q, ki * block_k,
-                                    block_q, block_k,
-                                    transposed=True) if causal else None
-        _accum_dkv_tile(q_ref[0], do_ref[0], k_ref[0], v_ref[0],
-                        lse_ref[0], delta_ref[0], sm_scale, st_mask,
-                        dk_scr, dv_scr)
+    def k_sub_tile(j: int, offset: int):
+        rows = _sub(j, sub_k)
+        k = k_ref[0, rows, :]
+        v = v_ref[0, rows, :]
+        first, full = _visible_q(j * sub_k, sub_k, offset, sub_q, n_q)
+        if one_pass:
+            dk = dv = jnp.zeros((sub_k, d), jnp.float32)
+        else:
+            dk, dv = dk_scr[rows, :], dv_scr[rows, :]
+        for i in range(first, n_q):
+            cols = _sub(i, sub_q)
+            # transposed scores: (sub_k rows, sub_q lanes) — lse/delta
+            # broadcast along sublanes with no in-kernel transpose
+            st_mask = _tile_causal_mask(
+                offset + i * sub_q, j * sub_k, sub_q, sub_k,
+                transposed=True) if i < full else None
+            ddk, ddv = _dkv_tile(q_ref[0, cols, :], do_ref[0, cols, :], k, v,
+                                 lse_ref[0, :, cols], delta_ref[0, :, cols],
+                                 sm_scale, st_mask)
+            dk, dv = dk + ddk, dv + ddv
+        if one_pass:
+            dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+        else:
+            dk_scr[rows, :], dv_scr[rows, :] = dk, dv
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            compute()
-    else:
-        compute()
+    def tile(offset: int):
+        for j in range(block_k // sub_k):
+            k_sub_tile(j, offset)
 
-    @pl.when(qi == num_q_blocks - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+    # a non-causal tile is a tile wholly under the diagonal
+    offset = qi * block_q - ki * block_k if causal else block_k
+    _at_static_offset(tile, offset, offsets, block_k)
+
+    if not one_pass:
+        @pl.when(qi == num_q_blocks - 1)
+        def _finish():
+            dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *,
+               dq_ref, *scratch,
                sm_scale: float, causal: bool, block_q: int, block_k: int,
-               num_k_blocks: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+               sub_q: int, sub_k: int, num_q_blocks: int,
+               num_k_blocks: int, offsets):
+    qi = pl.program_id(1) if num_q_blocks > 1 else 0
+    ki = pl.program_id(2) if num_k_blocks > 1 else 0
+    n_k = block_k // sub_k
+    d = q_ref.shape[-1]
+    one_pass = num_k_blocks == 1
+    if not one_pass:
+        dq_scr, = scratch
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+        @pl.when(ki == 0)
+        def _init():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def compute():
-        st_mask = _tile_causal_mask(qi * block_q, ki * block_k,
-                                    block_q, block_k,
-                                    transposed=True) if causal else None
-        _accum_dq_tile(q_ref[0], do_ref[0], k_ref[0], v_ref[0],
-                       lse_ref[0], delta_ref[0], sm_scale, st_mask,
-                       dq_scr)
+    def q_sub_tile(i: int, offset: int):
+        rows = _sub(i, sub_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, :, rows]
+        delta = delta_ref[0, :, rows]
+        q0 = offset + i * sub_q
+        full, computed = _visible_k(q0, sub_q, sub_k, n_k)
+        dq = (jnp.zeros((sub_q, d), jnp.float32) if one_pass
+              else dq_scr[rows, :])
+        for j in range(computed):
+            cols = _sub(j, sub_k)
+            st_mask = _tile_causal_mask(
+                q0, j * sub_k, sub_q, sub_k,
+                transposed=True) if j >= full else None
+            dq = dq + _dq_tile(q, do, k_ref[0, cols, :], v_ref[0, cols, :],
+                               lse, delta, sm_scale, st_mask)
+        if one_pass:
+            dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        else:
+            dq_scr[rows, :] = dq
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    def tile(offset: int):
+        for i in range(block_q // sub_q):
+            q_sub_tile(i, offset)
 
-    @pl.when(ki == num_k_blocks - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+    # a non-causal tile is a tile wholly under the diagonal
+    offset = qi * block_q - ki * block_k if causal else block_k
+    _at_static_offset(tile, offset, offsets, block_k)
+
+    if not one_pass:
+        @pl.when(ki == num_k_blocks - 1)
+        def _finish():
+            dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
-               block_q: int, block_k: int, interpret: bool):
+               block_q, block_k, sub_tile, interpret: bool):
     B, H, T, D = q.shape
     S = k.shape[2]
-    bq = min(block_q, T)
-    bk = min(block_k, S)
+    tiles = bq, bk, sq, sk = _tiles(T, S, D, block_q, block_k, sub_tile)
+    _record_schedule("bwd", T, S, D, tiles, causal)
     BH = B * H
     qr = q.reshape(BH, T, D)
     kr = k.reshape(BH, S, D)
@@ -317,6 +543,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
                     axis=-1).reshape(BH, 1, T)
 
     nq, nk = T // bq, S // bk
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
+                  sub_q=sq, sub_k=sk, num_q_blocks=nq, num_k_blocks=nk)
     row_specs = [
         pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0),
                      memory_space=pltpu.VMEM),   # q
@@ -339,9 +567,11 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
             lambda bh, kj, ij, _m=spec.index_map: _m(bh, ij, kj),
             memory_space=pltpu.VMEM)
 
+    # with one q grid tile the dKdV kernel writes straight from its
+    # values, so it must visit the k tiles no query sees too (S > T)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, block_k=bk, num_q_blocks=nq),
+        functools.partial(_dkv_kernel, **static, offsets=_tile_offsets(
+            T, S, bq, bk, every=nq == 1)),
         grid=(BH, nk, nq),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -356,7 +586,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nq == 1 else [
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
@@ -365,8 +595,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 
     # --- dQ: grid over q blocks, sweep k blocks innermost -------------
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, block_k=bk, num_k_blocks=nk),
+        functools.partial(_dq_kernel, **static,
+                          offsets=_tile_offsets(T, S, bq, bk)),
         grid=(BH, nq, nk),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -374,7 +604,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[] if nk == 1 else [
+            pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
     )(qr, gr, kr, vr, lse, delta)
 
@@ -383,30 +614,12 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 
 
 def _pick_block(n: int, d: int = 64) -> int:
-    """Largest 128-aligned block <= a measured target dividing n.
-
-    Roofline: per q-block the kernel streams the whole K/V (4·S·D bytes
-    bf16) from HBM while doing 4·bq·S·D MXU FLOPs → arithmetic
-    intensity = bq FLOP/byte.  v5e ridge point = 197 TFLOP/s ÷
-    ~820 GB/s ≈ 240 FLOP/byte, so bq ≥ 256 already keeps the sweep
-    compute-bound — but the measured on-chip matrix (r4, v5e,
-    docs/PERF.md) shows throughput keeps climbing past the ridge:
-    block=1024 beats 512 at every swept point but one, fwd and fwd+bwd
-    (T=8192 D=128 fwd+bwd 62.5 vs 40.7 TFLOP/s; T=4096 D=64 27.5 vs
-    17.9; the exception is T=1024 D=128, where 512 edges 1024 by ~2%
-    fwd+bwd and ~30% fwd — the whole-sequence block leaves too few
-    programs to hide the pipeline at the short length, so wide heads at
-    T<=1024 keep the 512 target).  Past the ridge the win comes from
-    grid overhead: fewer, longer-running programs amortize
-    prologue/epilogue and revisit the accumulators fewer times.  1024
-    is the largest block Mosaic has been shown to take: fwd, dKdV and
-    dQ at 1024², D=128, bf16 compile on a v5e under Mosaic's default
-    VMEM limit and agree with the dense reference (``chip_smoke.py``,
-    PR 21, jax 0.9.0) — the f32 score tile is 1024²·4 B = 4 MB; 2048²
-    (16 MB) has never been tried.  Measured (v5e, r3): 512² runs the
-    T=1024 grad 2.1× faster than 128²; short sequences use one whole
-    block.  The timings quoted here date from 2026-07/08 and are older
-    than this code."""
+    """The GRID tile of one axis: the largest 128-aligned divisor of n
+    up to 1024 (512 for wide heads at n <= 1024).  It buys grid steps:
+    fewer, longer programs amortize prologue, K/V refetch and the
+    accumulators' trip through scratch.  What is skipped and what goes
+    unmasked is the sub-tile's business (``_pick_sub_tile``; PERF.md §6
+    "PR 30" has both sweeps; the 2026-07 matrix is superseded)."""
     target = 512 if (n <= 1024 and d >= 128) else 1024
     if n <= target:
         return n
@@ -418,31 +631,47 @@ def _pick_block(n: int, d: int = 64) -> int:
     return 128
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, interpret, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, causal, sm_scale,
-                        block_q or _pick_block(q.shape[2], q.shape[3]),
-                        block_k or _pick_block(k.shape[2], k.shape[3]),
-                        interpret)
+def _pick_sub_tile(T: int, S: int, d: int) -> int:
+    """The SUB-TILE the kernels walk inside a grid tile, from the shape
+    alone.  It buys skipped work (causal sub-tiles above the diagonal
+    are never touched) against the per-sub-tile cost of the carry.  A
+    sequence of one grid tile is walked in 512s; one of several grid
+    tiles already skips by grid tile, and is walked grid tile by grid
+    tile — there a sub-tile measured slower (PERF.md §6 "PR 30")."""
+    del d
+    return 512 if max(T, S) <= 1024 else 1024
+
+
+def _fit_sub_tile(sub: int, block: int) -> int:
+    """The largest 128-aligned halving of ``sub`` that divides the grid
+    tile; a tile under 128 is its own sub-tile."""
+    if block <= sub or block < 128:
+        return block
+    while sub > 128 and block % sub:
+        sub //= 2
+    return sub
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, interpret, block_q, block_k,
+           sub_tile=None):
+    out, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
+                        sub_tile, interpret)
     return out
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, interpret, block_q,
-                    block_k):
-    out, lse = _flash_fwd(q, k, v, causal, sm_scale,
-                          block_q or _pick_block(q.shape[2], q.shape[3]),
-                          block_k or _pick_block(k.shape[2], k.shape[3]),
-                          interpret)
+                    block_k, sub_tile):
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
+                          sub_tile, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, interpret, block_q, block_k, res,
-                    g):
+def _flash_bwd_rule(causal, sm_scale, interpret, block_q, block_k,
+                    sub_tile, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, causal, sm_scale,
-                      block_q or _pick_block(q.shape[2], q.shape[3]),
-                      block_k or _pick_block(k.shape[2], k.shape[3]),
-                      interpret)
+    return _flash_bwd(q, k, v, o, lse, g, causal, sm_scale, block_q,
+                      block_k, sub_tile, interpret)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -452,7 +681,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     interpret: bool = False,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    sub_tile: Optional[int] = None):
     """Attention over (B, H, T, D) tensors without materializing scores.
 
     Uses the Pallas kernels on TPU (or under ``interpret=True``); plain
@@ -461,8 +691,10 @@ def flash_attention(q, k, v, causal: bool = False,
     block; any other length takes the dense reference (callers pad —
     the data layer's fixed-length contract already guarantees static
     shapes).
-    ``block_q``/``block_k`` override the measured default (1024-target;
-    see ``_pick_block``) — exposed for the on-hardware tuning sweeps.
+    ``block_q``/``block_k`` override the grid tile and ``sub_tile`` the
+    sub-tile walked inside it; None means chosen from the shape
+    (``_pick_block``, ``_pick_sub_tile``) — exposed for the on-hardware
+    tuning sweeps.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -473,5 +705,5 @@ def flash_attention(q, k, v, causal: bool = False,
 
     if use_kernel(interpret) and blockable(T) and blockable(S):
         return _flash(q, k, v, causal, sm_scale, interpret,
-                      block_q, block_k)
+                      block_q, block_k, sub_tile)
     return _attention_reference(q, k, v, causal, sm_scale)

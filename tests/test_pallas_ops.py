@@ -97,6 +97,179 @@ class TestFlashAttention:
         assert out.shape == q.shape
 
 
+def _out_and_grads(fn, q, k, v):
+    """(out, dq, dk, dv), the loss a sum of squares."""
+    def loss(a, b, c):
+        out = fn(a, b, c)
+        return jnp.sum(out ** 2), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+def _dense_pair(fn_flash, fn_ref, q, k, v):
+    return _out_and_grads(fn_flash, q, k, v), _out_and_grads(fn_ref, q, k, v)
+
+
+class TestFlashSubTiles:
+    """PR 30: inside a grid tile the three kernels walk sub-tiles up to
+    the diagonal, and mask only those it crosses."""
+
+    def _qkv(self, T, S, D, seed):
+        rng = np.random.RandomState(seed)
+        return (jnp.asarray(rng.randn(1, 1, T, D).astype(np.float32) * 0.5),
+                jnp.asarray(rng.randn(1, 1, S, D).astype(np.float32) * 0.5),
+                jnp.asarray(rng.randn(1, 1, S, D).astype(np.float32) * 0.5))
+
+    def _check(self, T, S, head, causal, seed, **tiles):
+        q, k, v = self._qkv(T, S, head, seed)
+        got, ref = _dense_pair(
+            lambda a, b, c: flash_attention(a, b, c, causal=causal,
+                                            interpret=True, **tiles),
+            lambda a, b, c: _attention_reference(
+                a, b, c, causal, 1 / np.sqrt(head)), q, k, v)
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                                   rtol=1e-4, atol=1e-5)
+        for a, b in zip(got[1:], ref[1:]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4)
+
+    @pytest.mark.parametrize("head", [64, 128])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("grid,sub", [(256, 128), (512, 128),
+                                          (512, 256)])
+    def test_forward_and_gradients_match_dense(self, grid, sub, causal,
+                                               head):
+        # one grid tile: nothing goes through scratch
+        self._check(grid, grid, head, causal, seed=grid + sub + head,
+                    block_q=grid, block_k=grid, sub_tile=sub)
+
+    @pytest.mark.parametrize("head", [64, 128])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("T,S", [(512, 512), (512, 256), (768, 512)])
+    def test_several_grid_tiles(self, T, S, causal, head):
+        # grid tile 256, sub-tile 128: the carry rests in scratch between
+        # grid steps and ``pl.when`` picks each tile's schedule by its
+        # offset from the diagonal; T > S is causal cross-attention
+        self._check(T, S, head, causal, seed=T + S + head, block_q=256,
+                    block_k=256, sub_tile=128)
+
+    @pytest.mark.parametrize("sub", [128, (256, 128), (128, 256)])
+    def test_one_grid_tile_static_schedule(self, sub):
+        # T = S = one grid tile: no grid index chooses a schedule,
+        # nothing goes through scratch
+        q, k, v = self._qkv(512, 512, 64, seed=11)
+        got, ref = _dense_pair(
+            lambda a, b, c: flash_attention(a, b, c, causal=True,
+                                            interpret=True, sub_tile=sub),
+            lambda a, b, c: _attention_reference(a, b, c, True, 0.125),
+            q, k, v)
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4)
+
+    @pytest.mark.parametrize("block_k", [512, 256])
+    def test_kernels_skip_and_do_not_compute_then_mask(self, block_k):
+        """Causal with S > T: no query may see the K/V rows at
+        positions >= T.  They hold NaN: a kernel that skips their
+        sub-tiles never reads them; one that computes and then masks
+        gives NaN through 0 x NaN in the PV product.  block_k 512: the
+        sub-tile walk stops short inside one grid tile; 256: the second
+        k grid tile is skipped whole."""
+        T, S = 256, 512
+        q, k, v = self._qkv(T, S, 64, seed=12)
+        poison = jnp.arange(S)[None, None, :, None] >= T
+        kp = jnp.where(poison, jnp.nan, k)
+        vp = jnp.where(poison, jnp.nan, v)
+
+        def flash(a, b, c):
+            return flash_attention(a, b, c, causal=True, interpret=True,
+                                   block_q=256, block_k=block_k,
+                                   sub_tile=128)
+
+        out, dq, dk, dv = _out_and_grads(flash, q, kp, vp)
+        ref = _out_and_grads(
+            lambda a, b, c: _attention_reference(a, b, c, True, 0.125),
+            q, k[:, :, :T], v[:, :, :T])
+        for a, b in zip((out, dq, dk[:, :, :T], dv[:, :, :T]), ref):
+            assert np.isfinite(np.asarray(a)).all()
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4)
+        # the keys nobody sees get exactly zero gradient
+        assert not np.asarray(dk[:, :, T:]).any()
+        assert not np.asarray(dv[:, :, T:]).any()
+
+    def test_schedule_event_in_the_tracers_ring(self):
+        from bigdl_tpu.telemetry import default_tracer
+
+        q, k, v = self._qkv(512, 512, 64, seed=13)
+        jax.grad(lambda a: jnp.sum(flash_attention(
+            a, k, v, causal=True, interpret=True, block_q=512,
+            block_k=512, sub_tile=128)))(q)
+        events = [s for s in default_tracer().spans()
+                  if s.name == "flash.schedule"]
+        assert [e.args["kernel"] for e in events] == ["fwd", "bwd"]
+        for e in events:
+            assert e.category == "compile" and e.duration == 0.0
+            assert e.args == {
+                "kernel": e.args["kernel"], "T": 512, "S": 512,
+                "head_dim": 64, "grid_tile": [512, 512],
+                "sub_tile": [128, 128], "computed": 10, "masked": 4,
+                "skipped": 6}
+
+
+class TestCausalSchedule:
+    """``causal_schedule`` counts with the bounds the kernels' loops run
+    to (``_visible_k`` for the forward and dQ, ``_visible_q`` for
+    dKdV)."""
+
+    @pytest.mark.parametrize("T,grid,sub,want", [
+        (1024, 1024, 256, (10, 4, 6)),     # the training cells, of 16
+        (1024, 1024, 128, (36, 8, 28)),
+        (2048, 1024, 256, (36, 8, 28)),    # the prefill cell, of 64
+        (1024, 1024, 1024, (1, 1, 0)),     # the parent's schedule
+        (128, 128, 256, (1, 1, 0)),        # the decode cells' prompts
+    ])
+    def test_closed_form(self, T, grid, sub, want):
+        from bigdl_tpu.ops.flash_attention import _tiles, causal_schedule
+
+        bq, bk, sq, sk = _tiles(T, T, 64, grid, grid, sub)
+        got = causal_schedule(T, T, (bq, bk), (sq, sk), True)
+        assert (got["computed"], got["masked"], got["skipped"]) == want
+        # n sub-tiles a side: n(n+1)/2 on or under the diagonal, n on it
+        n = T // sq
+        assert want[:2] == (n * (n + 1) // 2, n)
+        assert causal_schedule(T, T, (bq, bk), (sq, sk), False) == {
+            "computed": n * n, "masked": 0, "skipped": 0}
+
+    @pytest.mark.parametrize("T,S,bq,bk,sq,sk", [
+        (1024, 1024, 512, 512, 128, 128),
+        (1024, 512, 512, 256, 256, 128),    # T > S
+        (512, 1024, 256, 512, 128, 256),    # S > T
+        (768, 768, 384, 768, 128, 128),
+        (1024, 1024, 1024, 512, 512, 128),
+    ])
+    def test_against_every_position(self, T, S, bq, bk, sq, sk):
+        from bigdl_tpu.ops.flash_attention import (_visible_q,
+                                                   causal_schedule)
+
+        see = np.arange(T)[:, None] >= np.arange(S)[None, :]
+        tiles = see.reshape(T // sq, sq, S // sk, sk)
+        some = tiles.any(axis=(1, 3))
+        every = tiles.all(axis=(1, 3))
+        got = causal_schedule(T, S, (bq, bk), (sq, sk), True)
+        assert got == {"computed": int(some.sum()),
+                       "masked": int((some & ~every).sum()),
+                       "skipped": int((~some).sum())}
+        # the dKdV kernel's bounds, per key sub-tile, give the same sets
+        for j in range(S // sk):
+            first, full = _visible_q(j * sk, sk, 0, sq, T // sq)
+            assert list(some[:, j]) == [i >= first
+                                        for i in range(T // sq)]
+            assert list(every[:, j]) == [i >= full for i in range(T // sq)]
+
+
 class TestFusedLayerNorm:
     def test_uneven_rows_use_divisor_blocks(self):
         from bigdl_tpu.ops.layer_norm import _ln_fwd
@@ -188,3 +361,35 @@ class TestPickBlock:
 
         # 1536 = 1024 + 512: largest pow2-halved divisor <= target
         assert _pick_block(1536, 64) == 512
+
+
+class TestPickSubTile:
+    """The sub-tile comes from the shape alone (``_pick_sub_tile``,
+    fitted to the grid tile of each axis); ``block_q`` / ``block_k`` /
+    ``sub_tile`` only override it for sweeps."""
+
+    @pytest.mark.parametrize("T,S,d,want", [
+        (1024, 1024, 64, (1024, 1024, 512, 512)),   # gpt2m training cells
+        (2048, 2048, 128, (1024, 1024, 1024, 1024)),  # mistral prefill:
+        (4096, 4096, 64, (1024, 1024, 1024, 1024)),   # grid tile by grid tile
+        (1024, 1024, 128, (512, 512, 512, 512)),    # wide heads: grid 512
+        (128, 128, 128, (128, 128, 128, 128)),      # decode cells' prompts:
+        (256, 256, 128, (256, 256, 256, 256)),      # one sub-tile, or less
+        (64, 64, 32, (64, 64, 64, 64)),
+        (640, 640, 64, (640, 640, 128, 128)),       # 512 and 256 do not fit
+        (1536, 512, 64, (512, 512, 512, 512)),
+    ])
+    def test_tiles_from_the_shape(self, T, S, d, want):
+        from bigdl_tpu.ops.flash_attention import _tiles
+
+        assert _tiles(T, S, d, None, None, None) == want
+
+    def test_overrides_are_fitted_to_the_grid_tile(self):
+        from bigdl_tpu.ops.flash_attention import _tiles
+
+        assert _tiles(1024, 1024, 64, 512, 512, 256) == (512, 512, 256, 256)
+        assert _tiles(1024, 1024, 64, None, None, (256, 128)) == (
+            1024, 1024, 256, 128)
+        # a sub-tile wider than the grid tile is the grid tile
+        assert _tiles(1024, 1024, 64, 256, 256, 512) == (256, 256, 256, 256)
+

@@ -3,8 +3,9 @@
 ``jax.jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs the
 Pallas→Mosaic lowering with no chip: it catches the block-shape and
 layout refusals (the 8x128 tiling rule) in seconds.  It does NOT compile
-— Mosaic's own limits (VMEM) are only met on the chip, which
-``chip_smoke.py`` covers.  Shapes here are the smoke's: the flagship
+— Mosaic's own limits (VMEM) are met by the compiles for a described
+v5e in ``tests/test_tpu_compile.py`` (the flash kernels) and on the
+chip, which ``chip_smoke.py`` covers.  Shapes here are the smoke's: the flagship
 TransformerLM's per-chip attention (B16 H8 T1024 D128 bf16) and its
 LayerNorm rows (16384 x 1024), plus the long-context attention shapes.
 
