@@ -210,12 +210,16 @@ class Harness:
                             for i in pick]).astype(np.int64) - 1
         # free the program's state before the reference makes its own
         # weights: the generator cache keeps the model alive, so the
-        # arrays are deleted, not just dropped
+        # arrays are deleted, not just dropped.  Gradient buffers: those
+        # that exist (``grad_tree()`` would MAKE a model's worth of
+        # zeros to hand over — a served model owns none until asked)
         import jax
 
-        for tree in (self.model.param_tree(), self.model.grad_tree()):
-            for leaf in jax.tree_util.tree_leaves(tree):
-                leaf.delete()
+        held = [self.model.param_tree()] + [
+            getattr(m, "grads", {}) for m in self.model.modules_iter()]
+        for leaf in jax.tree_util.tree_leaves(held):
+            leaf.delete()
+        del held
         self.model = self.server = None
         gc.collect()
         t0 = time.perf_counter()

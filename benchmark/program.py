@@ -22,7 +22,7 @@ says which kind each layer is: one name for all, or a list that is
 repeated down the stack (a whole list or one period of it).  A path
 element ``L`` or ``L+n`` counts from the number of layers, so one table
 serves every depth.  The weights are the BENCHMARK's
-(``reference.common.make_params``): the program is given them, the
+(``reference.common.seeded_leaf``): the program is given them, the
 reference makes its own from the same seed.
 """
 from __future__ import annotations
@@ -113,7 +113,11 @@ def build_model(cfg: dict, seed: int, clock=None, ref=None):
     it already (``reference_for`` with the manifest's directory).
 
     The constructor draws its own initial weights first (the program's
-    behaviour; they are dropped leaf by leaf as ours go in).  The tree
+    behaviour; they are dropped before ours go in).  Ours go in leaf by
+    leaf: seeded in float32, cast to the dtype the constructor's own
+    tree holds that leaf in, the float32 array dropped before the next
+    is made — set-up costs the bytes the configuration states plus ONE
+    float32 leaf, never a float32 copy of the model.  The tree
     structures must match exactly — a parameter the reference does not
     know, or the other way round, is an error, not a default."""
     import jax
@@ -127,25 +131,33 @@ def build_model(cfg: dict, seed: int, clock=None, ref=None):
         jax.block_until_ready(own)
         clock.mark("model constructor (draws its own weights)")
     shapes = jax.tree_util.tree_map(lambda a: tuple(a.shape), own)
+    dtypes = jax.tree_util.tree_map(lambda a: a.dtype, own)
     # let go of the constructor's arrays before ours are made, so that
     # set-up never holds two copies of the weights
     model.set_param_tree(jax.tree_util.tree_map(
         lambda a: jax.numpy.zeros((0,), a.dtype), own))
     del own
-    flat = common.make_params(ref.param_specs(cfg), ref.n_layers(cfg),
-                              cfg["initializer_range"], seed)
+    specs = common.flat_specs(ref.param_specs(cfg), ref.n_layers(cfg))
     table = paths(cfg)
-    tree = _nest({k: p for k, p in table.items() if k in flat}, flat)
-    ours = jax.tree_util.tree_map(lambda a: tuple(a.shape), tree)
-    if ours != shapes or set(table) != set(flat):
+    known = {k: p for k, p in table.items() if k in specs}
+    ours = _nest(known, {k: tuple(shape) for k, (shape, _) in specs.items()})
+    if ours != shapes or set(table) != set(specs):
         raise ValueError("the configuration's reference and the "
                          "program's model disagree on the parameter "
                          f"tree: program {shapes} vs reference {ours}; "
                          "names only in program.params "
-                         f"{sorted(set(table) - set(flat))}, only in the "
-                         f"reference {sorted(set(flat) - set(table))}")
-    model.set_param_tree(tree)
+                         f"{sorted(set(table) - set(specs))}, only in the "
+                         f"reference {sorted(set(specs) - set(table))}")
+    held = from_tree(cfg, dtypes)
+    flat = {}
+    for name, (shape, kind) in specs.items():
+        leaf = common.seeded_leaf(seed, name, shape, kind,
+                                  cfg["initializer_range"])
+        # wait for the cast before the float32 array goes: the next
+        # leaf's program must not be given memory this one still holds
+        flat[name] = jax.block_until_ready(leaf.astype(held[name]))
+        del leaf
+    model.set_param_tree(_nest(table, flat))
     if clock is not None:
-        jax.block_until_ready(tree)
-        clock.mark("seeded weights, one jitted call")
+        clock.mark("seeded weights, leaf by leaf in the dtype held")
     return model

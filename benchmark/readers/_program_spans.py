@@ -201,17 +201,24 @@ def scope_seconds(ctx, scope: str):
     """Self seconds, inside the traced window, of chip 0's operations
     whose ``op_name`` lies under ``scope``; None where the trace holds
     no program span, no device event, or no event that names a scope at
-    all (then the share would read 0 for the wrong reason)."""
+    all (then the share would read 0 for the wrong reason).  The pass
+    over the events is made once a run and kept beside the spans: every
+    scope is a sum over its rows."""
     spans = load(ctx)
     if not spans or not spans["chip_events"] or not spans["window"]:
         return None
-    lo, hi = spans["window"]
-    events = [e for e in spans["chip_events"] if lo <= e[1] < hi]
-    if not any(e[3]["scope"] for e in events):
+    if "scope_self_ns" not in spans:
+        lo, hi = spans["window"]
+        events = [e for e in spans["chip_events"] if lo <= e[1] < hi]
+        spans["scope_self_ns"] = [
+            (ev[3]["scope"] + "/", self_ns)
+            for ev, self_ns, _ in trace_reduce.self_times(events)
+        ] if any(e[3]["scope"] for e in events) else None
+    rows = spans["scope_self_ns"]
+    if rows is None:
         return None
     mark = scope + "/"
-    return sum(self_ns for ev, self_ns, _ in trace_reduce.self_times(events)
-               if mark in ev[3]["scope"] + "/") / 1e9
+    return sum(self_ns for path, self_ns in rows if mark in path) / 1e9
 
 
 def handoffs(line: Line, done: str, enqueued: str) -> list:

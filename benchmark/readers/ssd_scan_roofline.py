@@ -4,13 +4,13 @@ over the traced self time of the operations under the ``mixer.ssd_scan``
 scope — one call a layer and executed batch, every batch a full bucket.
 A reading over 100 % is a wrong count, not a fast scan."""
 from benchmark import counts_falcon_h1
-from benchmark.readers import _h1_scopes
+from benchmark.readers import _program_spans
 from benchmark.readers._common import main_module
 
 
 def read(ctx):
     mod = main_module(getattr(ctx, "trace_summary", None))
-    seconds = (_h1_scopes.scope_seconds(ctx) or {}).get("mixer.ssd_scan")
+    seconds = _program_spans.scope_seconds(ctx, "mixer.ssd_scan")
     if mod is None or ctx.peaks is None or not seconds:
         return None
     sh = ctx.run["shapes"]

@@ -18,56 +18,63 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _leaf(key, name: str, shape, kind: str, std: float):
+@partial(jax.jit, static_argnums=(2, 3, 4))
+def _seeded(seed_pair, crc, shape, kind: str, std: float):
     if kind == "ones":
         return jnp.ones(shape, jnp.float32)
     if kind == "zeros":
         return jnp.zeros(shape, jnp.float32)
-    k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-    return std * jax.random.normal(k, shape, jnp.float32)
-
-
-def layer_names(specs: dict, n_layers: int, stacked: bool):
-    """Flat parameter names in a fixed order: the top-level leaves, then
-    the block leaves (``h.<name>`` stacked, ``h.<i>.<name>`` otherwise)."""
-    names = list(specs["top"])
-    if stacked:
-        return names + [f"h.{n}" for n in specs["layer"]]
-    return names + [f"h.{i}.{n}" for i in range(n_layers)
-                    for n in specs["layer"]]
-
-
-@partial(jax.jit, static_argnums=(0, 1, 2, 4))
-def _make(spec_items, n_layers, std, seed_pair, stacked):
     lo, hi = seed_pair
     key = jax.random.fold_in(jax.random.PRNGKey(lo), hi)
-    top, layer = spec_items
-    out = {n: _leaf(key, n, shape, kind, std) for n, shape, kind in top}
-    for n, shape, kind in layer:
-        per = [_leaf(key, f"h.{i}.{n}", shape, kind, std)
-               for i in range(n_layers)]
-        if stacked:
-            out[f"h.{n}"] = jnp.stack(per)
-        else:
-            out.update({f"h.{i}.{n}": a for i, a in enumerate(per)})
+    return std * jax.random.normal(jax.random.fold_in(key, crc), shape,
+                                   jnp.float32)
+
+
+def seeded_leaf(seed: int, name: str, shape, kind: str, std: float):
+    """ONE parameter, float32, on the device.  Its values depend on the
+    seed and its own name only (``h.<i>.<name>`` for layer ``i``), so a
+    caller that can hold one leaf at a time — ``program.build_model``
+    casting each to the dtype the model holds it in, ``serve_check``
+    going layer by layer — reads the numbers ``make_params`` gives.  One
+    program serves every leaf of a shape and kind: the name goes in as
+    a number."""
+    seed = int(seed)
+    pair = (np.uint32(seed & 0x7FFFFFFF), np.uint32(seed >> 31))
+    crc = np.uint32(zlib.crc32(name.encode()) & 0x7FFFFFFF)
+    return _seeded(pair, crc, tuple(shape), kind, float(std))
+
+
+def flat_specs(specs: dict, n_layers: int) -> dict:
+    """{flat name: (shape, kind)}: the top-level leaves, then layer by
+    layer ``h.<i>.<name>``."""
+    out = dict(specs["top"])
+    for i in range(n_layers):
+        out.update({f"h.{i}.{n}": sk for n, sk in specs["layer"].items()})
     return out
 
 
 def make_params(specs: dict, n_layers: int, std: float, seed: int,
                 stacked: bool = False) -> dict:
-    """Every parameter, on the device, in ONE jitted call from the seed.
-    A leaf's values depend on the seed and its own name only, so the
-    stacked form (the reference scans over it) and the per-layer form
-    (handed to the program) hold the same numbers."""
-    items = tuple(tuple((n, tuple(s), k) for n, (s, k) in specs[g].items())
-                  for g in ("top", "layer"))
-    seed = int(seed)
-    pair = (jnp.uint32(seed & 0x7FFFFFFF), jnp.uint32(seed >> 31))
-    return _make(items, int(n_layers), float(std), pair, bool(stacked))
+    """Every parameter, float32, on the device, leaf by leaf from the
+    seed (``seeded_leaf``): the stacked form (the reference scans over
+    it) and the per-layer form hold the same numbers.  For a caller
+    that wants the whole set at once; one that cannot afford 4 bytes a
+    parameter walks ``flat_specs`` itself."""
+    if not stacked:
+        return {name: seeded_leaf(seed, name, shape, kind, std) for name,
+                (shape, kind) in flat_specs(specs, n_layers).items()}
+    out = {n: seeded_leaf(seed, n, shape, kind, std)
+           for n, (shape, kind) in specs["top"].items()}
+    for n, (shape, kind) in specs["layer"].items():
+        out[f"h.{n}"] = jnp.stack([
+            seeded_leaf(seed, f"h.{i}.{n}", shape, kind, std)
+            for i in range(n_layers)])
+    return out
 
 
 def hashable(cfg: dict):

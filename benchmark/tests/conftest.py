@@ -14,26 +14,51 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-RENAME = {"gpt2m_train_1chip": "tiny_train_1chip",
-          "gpt2m_train_dp4": "tiny_train_dp4",
-          "mistral7b_serve_decode": "tiny_serve_open",
-          "mistral7b_serve_prefill": "tiny_serve_closed",
-          "mistral7b_serve_decode_sat": "tiny_serve_sat"}
+TEMPLATE = "BENCHMARK.template.json"
 
 
-def tiny_manifest(dst: str) -> dict:
-    """Copy the toy configurations and mixes to ``dst`` and write a
+def twin_dirs(tests_dir: str = HERE) -> dict:
+    """directory -> its ``BENCHMARK.template.json``: toy ``configs`` and
+    ``workloads``, each workload naming under ``twin_of`` the real cell
+    it is the toy twin of.  A listing, so a PR that adds a cell brings
+    its twin as a directory of files and edits nothing here."""
+    out = {}
+    for name in sorted(os.listdir(tests_dir)):
+        path = os.path.join(tests_dir, name, TEMPLATE)
+        if os.path.exists(path):
+            with open(path) as f:
+                out[name] = json.load(f)
+    return out
+
+
+def twins(tests_dir: str = HERE) -> dict:
+    """real cell -> (directory, toy workload entry) of every twin."""
+    return {w["twin_of"]: (d, w)
+            for d, part in twin_dirs(tests_dir).items()
+            for w in part["workloads"] if "twin_of" in w}
+
+
+def tiny_manifest(dst: str, extra=()) -> dict:
+    """Copy the toy configurations and mixes of ``tiny/`` (and of the
+    ``extra`` twin directories, laid over it) to ``dst`` and write a
     manifest there that pairs them with the REAL metric lists; a real
-    cell with no toy twin (one a later PR adds) is left out."""
-    shutil.copytree(os.path.join(HERE, "tiny"), dst, dirs_exist_ok=True)
+    cell with no toy twin among them is left out."""
+    parts = twin_dirs()
+    tiny, rename = dict(parts["tiny"], configs=[], workloads=[]), {}
+    for d in ("tiny",) + tuple(extra):
+        shutil.copytree(os.path.join(HERE, d), dst, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns(TEMPLATE))
+        tiny["configs"] += parts[d]["configs"]
+        for w in parts[d]["workloads"]:
+            w = dict(w)
+            rename[w.pop("twin_of")] = w["name"]
+            tiny["workloads"].append(w)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
-    with open(os.path.join(dst, "BENCHMARK.template.json")) as f:
-        tiny = json.load(f)
     for group in ("end_to_end", "per_layer"):
         tiny[group] = [
-            dict(m, workloads=[RENAME[w] for w in m["workloads"]
-                               if w in RENAME])
+            dict(m, workloads=[rename[w] for w in m["workloads"]
+                               if w in rename])
             if "workloads" in m else dict(m) for m in real[group]]
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
         json.dump(tiny, f, indent=1)

@@ -95,11 +95,72 @@ def _add_newarch(overlay):
         return json.load(f)
 
 
-def test_new_architecture_is_found(overlay):
+def _as_a_later_prs_cell(tmp_path) -> str:
+    """A copy of the checkout's benchmark to which a later PR ADDS the
+    new architecture as a real cell — configuration, reference, mix,
+    manifest entries — and its toy twin as a directory of files under
+    ``benchmark/tests/``; no file that was there is edited."""
+    import pytest
+
+    import test_manifest
+
+    root = str(tmp_path / "checkout")
+    shutil.copytree(os.path.join(ROOT, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    before = _snapshot(root)
+    bench, new = os.path.join(root, "benchmark"), os.path.join(HERE, "newarch")
+    twin = os.path.join(bench, "tests", "rmsgelu_twin")
+    shutil.copytree(new, twin)
+    shutil.copy(os.path.join(new, "benchmark/reference/rmsgelu.py"),
+                os.path.join(bench, "reference"))
+    with open(os.path.join(new, "benchmark/configs/tiny-rmsgelu.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="rmsgelu-1b", reduced={}, assumed={}, deployment="fixture")
+    with open(os.path.join(bench, "configs", "rmsgelu-1b.json"), "w") as f:
+        json.dump(cfg, f)
+    shutil.copy(os.path.join(bench, "traffic", "closed64_p128_n96.json"),
+                os.path.join(bench, "traffic", "closed64_rmsgelu.json"))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        m = json.load(f)
+    m["configs"].append({"name": "rmsgelu-1b", "source": cfg["source"],
+                         "file": "benchmark/configs/rmsgelu-1b.json",
+                         "reduced": [], "why": "fixture"})
+    m["workloads"].append({"name": "rmsgelu_serve_sat", "config": "rmsgelu-1b",
+                           "traffic": "closed64_rmsgelu", "chips": 1,
+                           "why": "fixture"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    with pytest.raises(AssertionError, match="toy twin"):   # not yet
+        test_manifest.named_files_are_there(root)
+    with open(os.path.join(twin, "BENCHMARK.template.json"), "w") as f:
+        json.dump({"configs": [{"name": "tiny-rmsgelu", "source": "fixture",
+                                "file": "benchmark/configs/tiny-rmsgelu.json",
+                                "reduced": [], "why": "fixture"}],
+                   "workloads": [{"name": "tiny_rmsgelu_sat",
+                                  "config": "tiny-rmsgelu",
+                                  "traffic": "tiny_closed_sat", "chips": 1,
+                                  "why": "fixture",
+                                  "twin_of": "rmsgelu_serve_sat"}]}, f)
+    after = _snapshot(root)
+    assert {k: after[k] for k in before if k != "BENCHMARK.json"} == {
+        k: v for k, v in before.items() if k != "BENCHMARK.json"}
+    return root
+
+
+def test_new_architecture_is_found(overlay, tmp_path):
     """A block neither old table described (RMSNorm gains, bias-free
     rotary attention, a two-matrix GELU MLP, no position table): its
     configuration carries the class and the parameter table, its plain
-    reference lies beside the manifest, and the cell runs ``correct``."""
+    reference lies beside the manifest, and the cell runs ``correct``.
+    Brought as a real cell with its toy twin as files, it passes
+    ``test_manifest.py`` with no edit to a file that was there."""
+    import test_manifest
+
+    root = _as_a_later_prs_cell(tmp_path)
+    test_manifest.named_files_are_there(root)
+    test_manifest.paths_are_the_recorded_ones(root)
     before = _snapshot(overlay)
     _add_newarch(overlay)
     assert {k: v for k, v in _snapshot(overlay).items()

@@ -5,42 +5,29 @@ multiplier keys equal to the published lists, and ``counts_falcon_h1``
 against hand arithmetic."""
 import json
 import os
-import shutil
 
 import pytest
 
-from conftest import HERE, ROOT, run_command
+from conftest import ROOT, run_command, tiny_manifest
 from test_broken_path import run_main
 
 REAL_CELL, CELL = "falconh1_serve_decode_sat", "tiny_falconh1_sat"
 
 
 @pytest.fixture()
-def h1_overlay(overlay):
+def h1_overlay(tmp_path):
     """The toy overlay with the hybrid block's own files laid over it
-    and its cell in the manifest under every metric the real cell
-    lists."""
-    shutil.copytree(os.path.join(HERE, "falconh1"), overlay,
-                    dirs_exist_ok=True)
-    path = os.path.join(overlay, "BENCHMARK.json")
-    with open(path) as f:
-        m = json.load(f)
+    and its cell in the manifest under every metric the real cell lists
+    (``falconh1/BENCHMARK.template.json`` names it as that cell's twin)."""
+    dst = str(tmp_path / "overlay")
+    m = tiny_manifest(dst, extra=("falconh1",))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
-    m["configs"].append({"name": "tiny-falcon-h1", "source": "fixture",
-                         "file": "benchmark/configs/tiny-falcon-h1.json",
-                         "reduced": [], "why": "fixture"})
-    m["workloads"].append({"name": CELL, "config": "tiny-falcon-h1",
-                           "traffic": "tiny_closed_h1", "chips": 1,
-                           "why": "fixture"})
     for group in ("end_to_end", "per_layer"):
         for metric, ours in zip(real[group], m[group]):
-            assert metric["name"] == ours["name"]
-            if REAL_CELL in metric.get("workloads", ()):
-                ours["workloads"].append(CELL)
-    with open(path, "w") as f:
-        json.dump(m, f)
-    return overlay
+            assert (CELL in ours.get("workloads", ())) == (
+                REAL_CELL in metric.get("workloads", ())), metric["name"]
+    return dst
 
 
 def _config(name="benchmark/configs/falcon-h1-34b-l4v4.json"):
@@ -219,18 +206,34 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
         assert reader.read(Ctx()) is None, name
 
 
+def _scope_seconds_pr30(spans, scope):
+    """``_program_spans.scope_seconds`` as it was up to PR 30: one pass
+    through ``self_times`` for every scope asked."""
+    from benchmark import trace_reduce
+
+    lo, hi = spans["window"]
+    events = [e for e in spans["chip_events"] if lo <= e[1] < hi]
+    if not any(e[3]["scope"] for e in events):
+        return None
+    mark = scope + "/"
+    return sum(self_ns for ev, self_ns, _ in trace_reduce.self_times(events)
+               if mark in ev[3]["scope"] + "/") / 1e9
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_the_one_pass_over_two_scopes_is_the_harness_reading(seed):
-    """``_h1_scopes`` nests events as ``trace_reduce.self_times`` does:
-    on a line of nested, abutting and overlapping (asynchronous) events
-    under a ``while`` it reads, for each scope, the nanoseconds
-    ``_program_spans.scope_seconds`` reads — and the two readers take
-    their numbers from it."""
+def test_the_one_cached_pass_reads_every_scope_as_a_pass_of_its_own(seed):
+    """``_program_spans.scope_seconds`` walks the events once a run and
+    sums each scope from the rows it kept: on a line of nested, abutting
+    and overlapping (asynchronous) events under a ``while`` whose text
+    is kilobytes long it reads, for each scope, the nanoseconds a pass
+    of its own read up to PR 30 — and the two readers take their numbers
+    from it."""
     import random
     import types
 
-    from benchmark.readers import (_h1_scopes, _program_spans,
-                                   ssd_scan_roofline, ssm_decode_pct)
+    from benchmark import trace_reduce
+    from benchmark.readers import (_program_spans, ssd_scan_roofline,
+                                   ssm_decode_pct)
 
     rnd = random.Random(seed)
     paths = ["jit(_run)/generate.decode_step/mixer.ssm_step/mul",
@@ -238,6 +241,7 @@ def test_the_one_pass_over_two_scopes_is_the_harness_reading(seed):
              "jit(_run)/generate.prefill/mixer.ssd_scan/dot_general",
              "jit(_run)/generate.decode_step/mixer.ssm_step_other/mul",
              "jit(_run)/generate.decode_step/dot_general", ""]
+    operands = ", ".join(f"bf16[64,4,2560,{i}]" for i in range(400))
     events, t = [], 1000
     for b in range(3):
         for _ in range(5):      # prefill: flat, one async pair overlapping
@@ -255,7 +259,7 @@ def test_the_one_pass_over_two_scopes_is_the_harness_reading(seed):
                 inner.append([f"%copy-done.{len(inner)} = f32[8] copy-done(%s)",
                               t + d // 2, d, {"scope": rnd.choice(paths)}])
             t += d + rnd.randint(0, 5)
-        events.append([f"%while.{b} = (s32[], f32[8]) while(%t), body=%b",
+        events.append([f"%while.{b} = (s32[], {operands}) while(%t), body=%b",
                        start, t - start, {"scope": "jit(_run)/while"}])
         events += inner
         t += 100
@@ -270,16 +274,25 @@ def test_the_one_pass_over_two_scopes_is_the_harness_reading(seed):
         config=_config())
     from benchmark import counts
     ctx.counts = counts
-    fast = _h1_scopes.scope_seconds(ctx)
-    assert set(fast) == set(_h1_scopes.SCOPES)
-    for scope in _h1_scopes.SCOPES:
-        assert fast[scope] == _program_spans.scope_seconds(ctx, scope) > 0
+    for scope in ("mixer.ssm_step", "mixer.ssd_scan", "generate.decode_step",
+                  "generate.sample"):
+        want = _scope_seconds_pr30(
+            {"chip_events": events, "window": (lo, hi)}, scope)
+        assert _program_spans.scope_seconds(ctx, scope) == want
+        assert (want > 0) == (scope != "generate.sample")
+    assert len(spans["scope_self_ns"]) == sum(lo <= e[1] < hi for e in events)
     assert ssm_decode_pct.read(ctx) == pytest.approx(
-        100.0 * fast["mixer.ssm_step"] / 1e-3)
+        100.0 * _program_spans.scope_seconds(ctx, "mixer.ssm_step") / 1e-3)
     assert ssd_scan_roofline.read(ctx) > 0
-    # no event names a scope at all -> nothing to read, as the harness has it
+    # the memoised parsers say what the bare ones say
+    for ev in events:
+        assert trace_reduce.base_name(ev[0]) == \
+            trace_reduce.base_name.__wrapped__(ev[0])
+        assert trace_reduce.split_hlo(ev[0]) == \
+            trace_reduce.split_hlo.__wrapped__(ev[0])
+    # no event names a scope at all -> nothing to read
     bare = types.SimpleNamespace(_program_spans={
         "chip_events": [[e[0], e[1], e[2], {"scope": ""}] for e in events],
         "window": (lo, hi)})
-    assert _h1_scopes.scope_seconds(bare) is None
     assert _program_spans.scope_seconds(bare, "mixer.ssm_step") is None
+    assert _program_spans.scope_seconds(bare, "mixer.ssd_scan") is None

@@ -5,7 +5,7 @@ import json
 import os
 import re
 
-from conftest import HERE, RENAME, ROOT
+from conftest import HERE, ROOT, twins
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -14,8 +14,8 @@ WIDTHS = {"hidden_size", "intermediate_size", "n_embd", "n_inner",
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def manifest(root=ROOT):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
@@ -79,11 +79,13 @@ def test_metrics():
         assert any(cell in x.get("workloads", cell_names) for x in m["per_layer"])
 
 
-def test_every_named_file_is_there():
-    m = manifest()
-    bench = os.path.join(ROOT, "benchmark")
+def named_files_are_there(root=ROOT):
+    """``root``: the checkout (``test_discovery`` hands a copy to which
+    a later PR's files were added)."""
+    m = manifest(root)
+    bench = os.path.join(root, "benchmark")
     for c in m["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert set(c["reduced"]) == set(cfg["reduced"])     # every cut, with its reason
@@ -91,34 +93,53 @@ def test_every_named_file_is_there():
         assert os.path.exists(os.path.join(bench, "reference", cfg["reference"] + ".py"))
     from benchmark import run
 
+    tests = os.path.join(bench, "tests")
+    twin = twins(tests)
     for w in m["workloads"]:
-        mix = run.load_json(run.find("traffic", w["traffic"], ".json", ROOT))
-        assert hasattr(run.load_py("drivers", mix["driver"], ROOT), "run")
+        mix = run.load_json(run.find("traffic", w["traffic"], ".json", root))
+        assert hasattr(run.load_py("drivers", mix["driver"], root), "run")
         assert "limits" in mix
-        assert w["name"] in RENAME, "every real cell has a toy twin"
+        assert w["name"] in twin, "every real cell has a toy twin"
+        d, toy = twin[w["name"]]    # its files: its own, or tiny/'s below it
+        for kind, key in (("configs", "config"), ("traffic", "traffic")):
+            assert any(os.path.exists(os.path.join(
+                tests, base, "benchmark", kind, toy[key] + ".json"))
+                for base in (d, "tiny")), (w["name"], toy[key])
     for x in m["per_layer"]:
         assert os.path.exists(os.path.join(bench, "readers", x["name"] + ".py")), x["name"]
-    open_mix = json.load(open(os.path.join(bench, "traffic", "open_p128_n96.json")))
+
+
+def test_every_named_file_is_there():
+    named_files_are_there()
+    open_mix = json.load(open(os.path.join(
+        ROOT, "benchmark", "traffic", "open_p128_n96.json")))
     assert abs(open_mix["rate_rps"] - 0.8 * open_mix["knee_rps"]) < 1e-9  # stored, from the sweep
 
 
-def test_parameter_paths_are_the_recorded_ones():
-    """The tables in the configuration files give, entry for entry and
-    in the same order, the maps ``program.py`` held in code up to PR 25:
-    weights, programs and compile-cache keys are the parent's."""
+def paths_are_the_recorded_ones(root=ROOT):
     from benchmark import program
 
     with open(os.path.join(HERE, "recorded", "paths_pr25.json")) as f:
         recorded = json.load(f)
-    m = manifest()
-    assert {c["name"] for c in m["configs"]} == set(recorded)
+    m = manifest(root)
+    assert set(recorded) <= {c["name"] for c in m["configs"]}
     for c in m["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["program"]["class"].count(":") == 1
-        got = program.paths(cfg)
-        assert [(k, list(v)) for k, v in got.items()] == list(
-            recorded[c["name"]].items()), c["name"]
+        if c["name"] in recorded:
+            got = program.paths(cfg)
+            assert [(k, list(v)) for k, v in got.items()] == list(
+                recorded[c["name"]].items()), c["name"]
+
+
+def test_parameter_paths_are_the_recorded_ones():
+    """The tables in the configuration files of the two configurations
+    PR 25 had give, entry for entry and in the same order, the maps
+    ``program.py`` held in code up to then: weights, programs and
+    compile-cache keys are that parent's.  A configuration added since
+    has no recorded map and only has to name its class."""
+    paths_are_the_recorded_ones()
 
 
 def test_the_saturated_cell_and_its_twin():

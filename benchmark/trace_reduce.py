@@ -30,6 +30,7 @@ import glob
 import os
 import re
 from collections import defaultdict
+from functools import lru_cache
 
 COLLECTIVE = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
               "collective-permute", "send", "recv")
@@ -120,6 +121,12 @@ def _subtract(a, b):
     return out
 
 
+# The three parsers below are memoised on the event's name: a traced
+# window holds some hundred thousand events of a few thousand distinct
+# instructions, and the ``while`` of a decode scan carries every weight
+# and cache as an operand — kilobytes of text that ``self_times`` asks
+# about once for each of its children.
+@lru_cache(maxsize=1 << 16)
 def split_hlo(text: str):
     """``%fusion.35 = (shapes) fusion(...)`` -> (``fusion.35``, the rest).
     A name that is no instruction text comes back whole."""
@@ -127,12 +134,14 @@ def split_hlo(text: str):
     return (m.group(1), m.group(2)) if m else (text, "")
 
 
+@lru_cache(maxsize=1 << 16)
 def signature(body: str, limit: int = 48) -> str:
     """A short, stable tag of an operation's shapes from its HLO text."""
     body = re.sub(r"\{[^{}]*\}", "", body)       # layouts
     return re.sub(r"[^A-Za-z0-9]+", "_", body)[:limit].strip("_")
 
 
+@lru_cache(maxsize=1 << 16)
 def base_name(name: str) -> str:
     """What an event does: the opcode of its instruction text
     (``%psum.3006 = f32[..] all-reduce(...)`` -> ``all-reduce``), else its
