@@ -1,6 +1,7 @@
-"""Autoregressive generation for TransformerLM and HybridMambaLM —
-KV-cache decode, with a recurrent state beside the K/V where a block
-has one.
+"""Autoregressive generation for TransformerLM, HybridMambaLM and
+ParallelMoELM — KV-cache decode, with a recurrent state beside the K/V
+where a block has one, and a cache of each layer's own length where
+layers differ in what they see.
 
 The reference predates autoregressive LMs entirely (its sequence story
 is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
@@ -23,6 +24,17 @@ tail ``[B, d_conv - 1, channels]`` in the same per-layer cache dict:
 prefill runs the chunked scan and hands the state after the last
 prompt token to the decode scan, which advances it one token a step.
 The paged path keeps K/V pages only and refuses such a block.
+
+A block may see a sliding WINDOW (``MultiHeadAttention.window``): its
+K/V cache is then ``min(T_cache, window)`` positions long, written at
+``pos mod`` that length once it is full, and read with each slot's
+absolute position and the lower bound ``k_pos > q_pos - window``; a
+block without one keeps ``T_cache`` positions.  Rotation (rotate-half,
+interleaved, or none) and window are read per block.  A parallel block
+(``models/parallel_moe.py``) runs attention and its expert layer on ONE
+normed input; its cache also carries ``moe_counts`` ``[B, held]``, the
+assignments each held expert took from each row, which a generate call
+returns beside the tokens on request (``return_stats=True``).
 
 Built from the model's OWN parameter tree and modules (the
 parallel/pipeline.py pattern): LN/MLP sublayers run through their
@@ -76,12 +88,13 @@ _GEN_CACHE = weakref.WeakKeyDictionary()
 
 def _check_model(model):
     from .hybrid_mamba import HybridMambaLM
+    from .parallel_moe import ParallelMoELM
     from .transformer import TransformerLM
 
-    if not isinstance(model, (TransformerLM, HybridMambaLM)):
+    if not isinstance(model, (TransformerLM, HybridMambaLM, ParallelMoELM)):
         raise TypeError(
-            f"generation supports TransformerLM and HybridMambaLM (got "
-            f"{type(model).__name__})")
+            f"generation supports TransformerLM, HybridMambaLM and "
+            f"ParallelMoELM (got {type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
     # so a ring/Ulysses-trained model decodes through the same cached
@@ -96,10 +109,32 @@ def _is_hybrid(block) -> bool:
     return getattr(block, "kind", None) == "hybrid_mamba"
 
 
+def _is_parallel(block) -> bool:
+    """A block whose attention and expert layer read one normed input
+    (``models.parallel_moe.ParallelMoEBlock``)."""
+    return getattr(block, "kind", None) == "parallel_moe"
+
+
+def _window_of(block):
+    """The block's sliding window in positions, or None."""
+    return getattr(block.modules[1], "window", None)
+
+
 def _refuse_recurrent(model, first, count, what: str):
     """The paged path keeps K/V pages only: a block with a recurrent
     state has nowhere to put it there, so it is refused, not decoded
-    without its state."""
+    without its state.  Its pages are all of one length and its block
+    step is the sequential one, so a block with a window or a parallel
+    expert layer is refused too, not decoded as another model."""
+    blocks = model.modules[first:first + count]
+    if any(_is_parallel(b) or _window_of(b) for b in blocks):
+        raise TypeError(
+            f"{what} keeps pages of ONE length for every layer and runs "
+            f"the sequential block: {type(model).__name__}'s layers "
+            f"differ in what they see (a window beside full attention) "
+            f"and run attention and experts on one norm.  Decode this "
+            f"model through generate() / submit_generate(), whose "
+            f"static cache is sized per layer")
     if any(_is_hybrid(b) for b in model.modules[first:first + count]):
         raise TypeError(
             f"{what} pages K/V only and {type(model).__name__}'s blocks "
@@ -156,20 +191,19 @@ _BIND_TLS = threading.local()
 
 
 def _moe_ffn_nodrop(moe, params, x):
-    """Capacity-free top-k dispatch for decode: gather each token's
-    chosen experts' weights and apply their MLPs, mixed by the (top-1
-    raw / top-k renormalized) gates.  [B, Tq, D] -> [B, Tq, D].
-    (Prefill materializes [N, D, H] gathered weights per choice — fine
-    for decode windows; very long prompts on tiny-HBM chips may prefer
-    the training dispatch.)"""
+    """Capacity-free top-k advance of a ``MoEFFN`` for decode, through
+    the dropless dispatch of ``parallel/moe.py``: assignments sorted by
+    expert, one grouped product per projection (each expert's weights
+    read at most once a call, none gathered per token), mixed by the
+    (top-1 raw / top-k renormalized) gates.  [B, Tq, D] -> [B, Tq, D]."""
+    from ..parallel.moe import (dropless_apply, grouped_matmul,
+                                route_top_k, row_experts)
+
     B, Tq, D = x.shape
     K = getattr(moe, "top_k", 1)
     x2 = x.reshape(B * Tq, D)
-    logits = jnp.dot(x2, params["router_w"].T) + params["router_b"]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gk, idxk = jax.lax.top_k(probs, K)                  # [N, K]
-    if K > 1:
-        gk = gk / jnp.sum(gk, axis=-1, keepdims=True)
+    gk, idxk = route_top_k(x2, params["router_w"], params["router_b"], K,
+                           "softmax", renormalize=K > 1)
     if getattr(_BIND_TLS, "capture", None) is not None:
         # the training dispatch's keep rule, via the module's own
         # shared helper so the two can never drift (capacity from THIS
@@ -182,20 +216,20 @@ def _moe_ffn_nodrop(moe, params, x):
             _, keep, counts = moe.keep_mask(oh, counts)
             kept = kept + jnp.sum(keep.astype(jnp.float32))
         _BIND_TLS.capture.append(1.0 - kept / (B * Tq * K))
-    y = 0.0
-    for c in range(K):
-        idx = idxk[:, c]
-        wi, bi = params["wi"][idx], params["bi"][idx]  # [N, D, H], [N, H]
-        wo, bo = params["wo"][idx], params["bo"][idx]  # [N, H, D], [N, D]
-        h = jax.nn.gelu(jnp.einsum("nd,ndh->nh", x2, wi.astype(x.dtype))
-                        + bi.astype(x.dtype))
-        yc = jnp.einsum("nh,nhd->nd", h, wo.astype(x.dtype)) + bo.astype(
-            x.dtype)
-        y = y + gk[:, c, None].astype(x.dtype) * yc
+
+    def gelu_experts(xs, sizes):
+        e = row_experts(sizes, xs.shape[0])
+        h = jax.nn.gelu(grouped_matmul(xs, params["wi"], sizes)
+                        + params["bi"][e].astype(xs.dtype))
+        return (grouped_matmul(h, params["wo"], sizes)
+                + params["bo"][e].astype(xs.dtype))
+
+    y, _ = dropless_apply(x2, idxk, gk, (0, moe.n_experts), gelu_experts)
     return y.reshape(B, Tq, D)
 
 
-def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None):
+def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None,
+                window=None):
     """Causal attention of Tq queries (absolute positions
     pos..pos+Tq-1) against a dense ``[B, Hkv, Tm, Dh]`` cache view.
     GQA contracts the query groups against the UN-repeated cache — a
@@ -206,13 +240,18 @@ def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None):
     drift numerically.  ``k_pos`` [Tm] gives each cache slot's
     ABSOLUTE position when the view is not contiguous from 0 — the
     page-window path gathers only the live pages, so slot index and
-    position diverge."""
+    position diverge.  ``window`` adds the sliding window's far edge,
+    ``k_pos > q_pos - window``, and masks a slot that holds no position
+    yet (``k_pos < 0``: a ring that is not full)."""
     Tq, Tm = q.shape[2], k_cache.shape[2]
     scale = 1.0 / jnp.sqrt(jnp.float32(Dh)).astype(q.dtype)
     qpos = pos + jnp.arange(Tq)
     if k_pos is None:
         k_pos = jnp.arange(Tm)
     mask = k_pos[None, :] <= qpos[:, None]            # [Tq, Tm]
+    if window is not None:
+        mask = (mask & (k_pos[None, :] > qpos[:, None] - window)
+                & (k_pos[None, :] >= 0))
     if Hkv == H:
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache) * scale
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
@@ -277,10 +316,13 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
     under ``kv_int8``) and, for a hybrid block, the mixer's ``ssm``
     state and ``conv`` tail beside those.  ``T_cache`` is the calling
     program's :func:`_cache_len`, not the model's ``max_len``: every
-    reader of the cache takes its length from its shape."""
+    reader of the cache takes its length from its shape.  A block with
+    a sliding window keeps ``min(T_cache, window)`` positions (a ring);
+    a parallel block adds ``moe_counts`` ``[B, held]`` int32."""
     mha = block.modules[1]
     Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
-    kv = (B, Hkv, T_cache, mha.head_dim)
+    kv = (B, Hkv, min(T_cache, _window_of(block) or T_cache),
+          mha.head_dim)
     if kv_int8:
         cache = {"k": jnp.zeros(kv, jnp.int8),
                  "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
@@ -290,6 +332,8 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
         cache = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
     if _is_hybrid(block):
         cache.update(block.mixer.state_init(B, dt))
+    if _is_parallel(block):
+        cache["moe_counts"] = jnp.zeros((B, block.moe.held[1]), jnp.int32)
     return cache
 
 
@@ -303,7 +347,9 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     ``max_len``), ``kv_cache_bytes`` (the K/V of every layer at that
     length: what is allocated, and what every decode step reads) and
     ``recurrent_state_bytes`` (SSM state and conv tail; zero for a
-    model without them)."""
+    model without them).  Where some layer has a sliding window, K/V
+    is also given by KIND of layer: ``kv_cache_bytes_window`` (layers
+    that keep ``min(positions, window)``) and ``kv_cache_bytes_full``."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -311,14 +357,21 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
         model.param_tree())[0].dtype)
     out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
            "kv_cache_positions": T_cache}
-    for block in model.modules[first:first + count]:
+    blocks = model.modules[first:first + count]
+    by_kind = {"kv_cache_bytes_window": 0, "kv_cache_bytes_full": 0}
+    for block in blocks:
         shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
                                         T_cache, dt, _kv_int8(kv_dtype)))
         for name, a in shapes.items():
-            kind = ("kv_cache_bytes"
-                    if name in ("k", "v", "k_scale", "v_scale")
-                    else "recurrent_state_bytes")
-            out[kind] += a.size * a.dtype.itemsize
+            nbytes = a.size * a.dtype.itemsize
+            if name in ("k", "v", "k_scale", "v_scale"):
+                out["kv_cache_bytes"] += nbytes
+                by_kind["kv_cache_bytes_window" if _window_of(block)
+                        else "kv_cache_bytes_full"] += nbytes
+            elif name != "moe_counts":      # a counter, not a state
+                out["recurrent_state_bytes"] += nbytes
+    if any(_window_of(b) for b in blocks):
+        out.update(by_kind)
     return out
 
 
@@ -344,7 +397,15 @@ def _decode_machinery(model, first, count, kv_int8=False):
     H, Dh = mha0.num_heads, mha0.head_dim
     Hkv = getattr(mha0, "num_kv_heads", H)   # GQA: smaller KV caches
     use_rope = getattr(model, "use_rope", False)
-    rope_theta = getattr(mha0, "rope_theta", 10000.0)
+    tied = getattr(model, "tied_head", False)
+
+    def _rope_of(mha):
+        """(kind, theta) of ONE block's rotation — "half",
+        "interleaved", or None for a layer without positions; a model
+        that rotates (``use_rope``) and whose layers do not say how
+        rotates by halves."""
+        kind = getattr(mha, "rope_kind", "half") if use_rope else None
+        return kind, getattr(mha, "rope_theta", 10000.0)
 
     def _split(x, B, h=H):
         return x.reshape(B, -1, h, Dh).transpose(0, 2, 1, 3)
@@ -357,8 +418,15 @@ def _decode_machinery(model, first, count, kv_int8=False):
             return kv
         return jnp.repeat(kv, H // Hkv, axis=1)
 
-    def _attend(q, k_cache, v_cache, pos):
-        return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh)
+    def _attend(q, k_cache, v_cache, pos, window=None):
+        if window is None:
+            return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh)
+        # a ring: slot s holds the latest position <= pos that is s
+        # mod the ring's length (negative: none yet)
+        ring = k_cache.shape[2]
+        k_pos = pos - (pos - jnp.arange(ring)) % ring
+        return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh,
+                           k_pos=k_pos, window=window)
 
     def _quant(x):
         """absmax int8 over the head dim: x ≈ q * s, q int8,
@@ -368,15 +436,30 @@ def _decode_machinery(model, first, count, kv_int8=False):
         q_ = jnp.round(x.astype(jnp.float32) / s_).astype(jnp.int8)
         return q_, s_
 
-    def _cache_write(cache, k, v, pos):
+    def _ring_put(arr, x, pos):
+        """``x`` [B, Hkv, Tq, ·] at positions pos.. into ``arr``, whose
+        time axis may be SHORTER than the positions the program spans
+        (a sliding layer's ring): a token goes to slot ``pos mod`` the
+        ring's length; of a prompt longer than the ring the last ring's
+        worth is kept, each position at its slot."""
+        ring, Tq = arr.shape[2], x.shape[2]
+        if isinstance(pos, int):                # prefill, from 0
+            if Tq <= ring:
+                return lax.dynamic_update_slice(arr, x, (0, 0, pos, 0))
+            return jnp.roll(x[:, :, Tq - ring:], (Tq - ring) % ring, axis=2)
+        return lax.dynamic_update_slice(arr, x, (0, 0, pos % ring, 0))
+
+    def _cache_write(cache, k, v, pos, ringed=False):
+        put = _ring_put if ringed else (
+            lambda arr, x, pos: lax.dynamic_update_slice(arr, x,
+                                                         (0, 0, pos, 0)))
         new = dict(cache)
         for name, x in (("k", k), ("v", v)):
             if kv_int8:
                 x, scale = _quant(x)
-                new[name + "_scale"] = lax.dynamic_update_slice(
-                    cache[name + "_scale"], scale, (0, 0, pos, 0))
-            new[name] = lax.dynamic_update_slice(cache[name], x,
-                                                 (0, 0, pos, 0))
+                new[name + "_scale"] = put(cache[name + "_scale"], scale,
+                                           pos)
+            new[name] = put(cache[name], x, pos)
         return new
 
     def _cache_kv(cache, dt):
@@ -397,15 +480,22 @@ def _decode_machinery(model, first, count, kv_int8=False):
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
         v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
         k = _scaled(k, getattr(mha, "key_multiplier", 1.0))
-        if use_rope:
+        rope, rope_theta = _rope_of(mha)
+        if rope:
             # rotate at ABSOLUTE positions; the cache stores rotated
             # keys (the standard KV-cache convention for RoPE)
             from ..nn.attention import rope_rotate
 
             qpos = pos + jnp.arange(q.shape[2])
-            q = rope_rotate(q, qpos, rope_theta)
-            k = rope_rotate(k, qpos, rope_theta)
-        cache = _cache_write(cache, k, v, pos)
+            il = rope == "interleaved"
+            q = rope_rotate(q, qpos, rope_theta, interleaved=il)
+            k = rope_rotate(k, qpos, rope_theta, interleaved=il)
+        window = _window_of(block)
+        # a sliding layer's cache as long as its window is a ring; a
+        # shorter one holds every position of this program, all of them
+        # inside the window: a plain cache
+        ringed = bool(window) and cache["k"].shape[2] == window
+        cache = _cache_write(cache, k, v, pos, ringed)
         if isinstance(pos, int) and pos == 0:
             # the whole prefill (ANY prompt length — a 1-token prompt
             # rides flash_attention's dense fallback) attends the
@@ -422,9 +512,11 @@ def _decode_machinery(model, first, count, kv_int8=False):
             # teacher-forcing oracle either way.
             from ..ops.flash_attention import flash_attention
 
-            o = flash_attention(q, _rep(k), _rep(v), causal=True)
+            o = flash_attention(q, _rep(k), _rep(v), causal=True,
+                                window=window)
         else:
-            o = _attend(q, *_cache_kv(cache, q.dtype), pos)
+            o = _attend(q, *_cache_kv(cache, q.dtype), pos,
+                        window if ringed else None)
         o = o.transpose(0, 2, 1, 3).reshape(B, o.shape[2], H * Dh)
         return _proj(o, ap, "wo", "bo", mha.with_bias), cache
 
@@ -435,6 +527,15 @@ def _decode_machinery(model, first, count, kv_int8=False):
         runs the chunked scan from an empty state and keeps the state
         after the last prompt token, a decode step advances it."""
         ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
+        if _is_parallel(block):
+            # attention and the expert layer read the SAME normed input
+            with jax.named_scope("block.attention"):
+                a, cache = _attention(block, bp["1"], ln1, cache, pos)
+            B, Tq, D = ln1.shape
+            m, counts = block.moe.routed(bp["2"], ln1.reshape(B * Tq, D),
+                                         batch=B)
+            return (h + a + m.reshape(B, Tq, D),
+                    {**cache, "moe_counts": cache["moe_counts"] + counts})
         if not _is_hybrid(block):
             a, cache = _attention(block, bp["1"], ln1, cache, pos)
             return _ffn_sublayer(block, bp, h + a), cache
@@ -485,9 +586,11 @@ def _decode_machinery(model, first, count, kv_int8=False):
         """Head on the LAST position of h only -> [B, V] f32."""
         h = h[:, -1:, :]
         h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
-        h, _ = head.apply_fn(pc[str(first + count + 1)], {}, h, False,
-                             None)
+        # a tied head owns no leaf: it is handed the embedding's
+        h, _ = head.apply_fn(pc["0" if tied else str(first + count + 1)],
+                             {}, h, False, None)
         h = _scaled(h, getattr(model, "lm_head_multiplier", 1.0))
+        h = _scaled(h, getattr(model, "logit_scale", 1.0))
         return h[:, 0, :].astype(jnp.float32)
 
     return prefill, decode_token, logits_last
@@ -514,7 +617,11 @@ def make_generate(model, max_len: Optional[int] = None,
     not temperature > 0`` and ``nucleus = 0 < top_p < 1`` are read on
     the host from the call's own numbers (a greedy call ignores
     ``top_k`` / ``top_p``: one program); the decode loop itself is a
-    scan — no per-token dispatch.
+    scan — no per-token dispatch.  ``return_stats=True`` returns
+    ``(ids, stats)``: for a model with parallel expert blocks ``stats``
+    holds ``moe_counts`` ``[layers, held]`` int32, the assignments each
+    held expert took in the call (fetched with the tokens); for any
+    other model it is empty.
     """
     from ..optim.optimizer import _cast_floats
 
@@ -522,6 +629,8 @@ def make_generate(model, max_len: Optional[int] = None,
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
+    counted = any(_is_parallel(b)
+                  for b in model.modules[first:first + count])
 
     # device scopes (``jax.named_scope``): metadata on the HLO
     # operations only — ``generate.cast_params`` / ``.prefill`` /
@@ -605,12 +714,17 @@ def make_generate(model, max_len: Optional[int] = None,
             (caches, ids, _, _, _), _ = lax.scan(
                 one_token, (caches, ids, T0, key, done), None,
                 length=max_new - 1)
-        return ids
+        if not counted:
+            return ids
+        # [layers, held]: the assignments each held expert took in this
+        # call, prefill and every decode step, all rows
+        return ids, jnp.stack([jnp.sum(c["moe_counts"], axis=0)
+                               for c in caches if "moe_counts" in c])
 
     def generate(params, prompt_ids, max_new: int, rng=None,
                  temperature: float = 0.0, top_k: int = 0,
                  top_p: float = 1.0, eos_id: Optional[int] = None,
-                 pad_id: Optional[int] = None):
+                 pad_id: Optional[int] = None, return_stats: bool = False):
         if temperature > 0 and rng is None:
             raise ValueError(
                 "temperature > 0 requires an explicit rng key "
@@ -623,10 +737,13 @@ def make_generate(model, max_len: Optional[int] = None,
         # greedy call ignores top_k / top_p and shares one program
         greedy = not temperature > 0
         nucleus = bool(not greedy and 0 < top_p < 1)
-        return _run(params, jnp.asarray(prompt_ids, jnp.int32),
-                    int(max_new), key, jnp.float32(temperature),
-                    0 if greedy else int(top_k), jnp.float32(top_p),
-                    eos, pad, greedy, nucleus)
+        out = _run(params, jnp.asarray(prompt_ids, jnp.int32),
+                   int(max_new), key, jnp.float32(temperature),
+                   0 if greedy else int(top_k), jnp.float32(top_p),
+                   eos, pad, greedy, nucleus)
+        ids, stats = (out[0], {"moe_counts": out[1]}) if counted \
+            else (out, {})
+        return (ids, stats) if return_stats else ids
 
     return generate
 

@@ -9,6 +9,8 @@ modules wrap it in the standard layer protocol.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ..parallel.ring_attention import (attention, blockwise_attention,
                                        ring_attention, ulysses_attention)
@@ -22,19 +24,50 @@ SEQ_STRATEGIES = ("dense", "flash", "block", "ring", "ulysses",
 SPARSE_PATTERNS = ("sliding", "strided")
 
 
-def rope_rotate(x, pos, theta: float = 10000.0):
-    """Rotary position embedding (HF Llama's rotate-half convention)
-    over ``x`` [B, H, T, D] at absolute positions ``pos`` [T]."""
+ROPE_KINDS = ("half", "interleaved")
+
+
+def rope_kind(rope):
+    """``True`` / ``"half"`` / ``"interleaved"`` / falsy -> the kind, or
+    None for a layer without positions."""
+    kind = "half" if rope is True else (rope or None)
+    if kind is not None and kind not in ROPE_KINDS:
+        raise ValueError(f"rope {rope!r} not in {ROPE_KINDS}, True or None")
+    return kind
+
+
+def rope_rotate(x, pos, theta: float = 10000.0, interleaved: bool = False):
+    """Rotary position embedding over ``x`` [B, H, T, D] at absolute
+    positions ``pos`` [T]: HF Llama's rotate-half convention (dim ``i``
+    pairs with ``i + D/2``), or with ``interleaved`` GPT-J's (dim ``2i``
+    pairs with ``2i + 1``) — the same rotation under a permutation of
+    the head's columns."""
     D = x.shape[-1]
     # like RMSNorm: float64 oracles keep their precision, low-precision
     # inputs still get at least float32 tables
     ct = jnp.promote_types(x.dtype, jnp.float32)
     inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=ct) / D))
     ang = pos.astype(ct)[:, None] * inv[None, :]            # [T, D/2]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)  # [T, D]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
-    rot = jnp.concatenate([-x2, x1], -1)
+    if interleaved:
+        cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)           # [T, D]
+        sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)
+        # the partner of an even lane is its right neighbour (negated),
+        # of an odd lane its left one: ONE product with a constant
+        # [D, D] matrix of 0 / +1 / -1 (exact in any dtype: one nonzero
+        # term a sum) — no [D/2, 2] reshape of the minor dimension, and
+        # none of the lane-shifted copies of x two ``roll``s cost (four
+        # arrays of q's size at a prompt of thousands of positions)
+        swap = np.zeros((D, D), np.float32)
+        even = np.arange(0, D, 2)
+        swap[even + 1, even] = -1.0          # rot[2i]   = -x[2i + 1]
+        swap[even, even + 1] = 1.0           # rot[2i+1] =  x[2i]
+        rot = jnp.einsum("...d,de->...e", x, jnp.asarray(swap, x.dtype),
+                         precision=lax.Precision.HIGHEST)
+    else:
+        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)  # [T, D]
+        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+        x1, x2 = x[..., :D // 2], x[..., D // 2:]
+        rot = jnp.concatenate([-x2, x1], -1)
     return (x * cos[None, None].astype(x.dtype)
             + rot * sin[None, None].astype(x.dtype))
 
@@ -74,7 +107,8 @@ class MultiHeadAttention(TensorModule):
                  sparse_stride: int = 4,
                  sparse_block: "int | None" = None,
                  head_dim: "int | None" = None,
-                 key_multiplier: float = 1.0):
+                 key_multiplier: float = 1.0,
+                 window: "int | None" = None):
         super().__init__()
         assert head_dim or embed_dim % num_heads == 0, \
             "embed_dim % num_heads != 0"
@@ -109,8 +143,18 @@ class MultiHeadAttention(TensorModule):
         self.sparse_stride = int(sparse_stride)
         self.sparse_block = sparse_block
         self._sparse_masks = {}   # (T, S) -> BlockMask (static, hashable)
-        self.rope = bool(rope)
+        # ``rope``: True / "half" (rotate-half), "interleaved" (GPT-J
+        # pairs), or falsy — a layer with no positions at all
+        self.rope_kind = rope_kind(rope)
+        self.rope = self.rope_kind is not None
         self.rope_theta = float(rope_theta)
+        # sliding window: query t sees keys t - window + 1 .. t
+        self.window = int(window) if window else None
+        if self.window and not (causal and seq_strategy in ("dense",
+                                                             "flash")):
+            raise ValueError(
+                "window composes with causal dense/flash attention only "
+                f"(got causal={causal}, seq_strategy={seq_strategy!r})")
         if self.rope and seq_strategy in ("ring", "ulysses"):
             # the rotation needs GLOBAL positions, which the module
             # cannot know inside a seq-sharded shard_map region
@@ -183,10 +227,16 @@ class MultiHeadAttention(TensorModule):
         if self.seq_strategy == "block":
             return blockwise_attention(q, k, v, block_size=self.block_size,
                                        causal=self.causal)
+        window = getattr(self, "window", None)
         if self.seq_strategy == "flash":
             from ..ops import flash_attention
 
-            return flash_attention(q, k, v, causal=self.causal)
+            return flash_attention(q, k, v, causal=self.causal,
+                                   window=window)
+        if window:
+            from ..ops.flash_attention import windowed_attention
+
+            return windowed_attention(q, k, v, window)
         return attention(q, k, v, causal=self.causal)
 
     def _apply(self, params, buffers, x, training, rng):
@@ -201,8 +251,9 @@ class MultiHeadAttention(TensorModule):
             k = k * self.key_multiplier
         if self.rope:
             pos = jnp.arange(q.shape[2])
-            q = rope_rotate(q, pos, self.rope_theta)
-            k = rope_rotate(k, pos, self.rope_theta)
+            il = getattr(self, "rope_kind", "half") == "interleaved"
+            q = rope_rotate(q, pos, self.rope_theta, interleaved=il)
+            k = rope_rotate(k, pos, self.rope_theta, interleaved=il)
         if self.num_kv_heads != self.num_heads:
             group = self.num_heads // self.num_kv_heads
             k = jnp.repeat(k, group, axis=1)

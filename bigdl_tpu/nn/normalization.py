@@ -243,22 +243,36 @@ class LayerNorm(TensorModule):
     normalises its local activations independently.
     """
 
-    def __init__(self, n_output: int, eps: float = 1e-5, affine: bool = True):
+    def __init__(self, n_output: int, eps: float = 1e-5, affine: bool = True,
+                 with_bias: bool = True):
         super().__init__()
         self.n_output = n_output
         self.eps = eps
         self.affine = affine
+        # ``with_bias=False``: a gain and no bias LEAF (the Cohere
+        # family's norm) — not a bias held at zero
+        self.with_bias = bool(with_bias)
         self.reset()
 
     def reset(self):
         if self.affine:
             w_init = self._init_methods.get("weight", (Ones(), None))[0]
-            b_init = self._init_methods.get("bias", (Zeros(), None))[0]
             self._register_param("weight", w_init.init((self.n_output,), ONE_D))
+        if self.affine and getattr(self, "with_bias", True):
+            b_init = self._init_methods.get("bias", (Zeros(), None))[0]
             self._register_param("bias", b_init.init((self.n_output,), ONE_D))
         return self
 
     def _apply(self, params, buffers, x, training, rng):
+        if self.affine and not getattr(self, "with_bias", True):
+            # statistics in at least float32 and the cast back BEFORE
+            # the gain, as RMSNorm does
+            ct = jnp.promote_types(x.dtype, jnp.float32)
+            xc = x.astype(ct)
+            xc = xc - xc.mean(axis=-1, keepdims=True)
+            var = (xc * xc).mean(axis=-1, keepdims=True)
+            y = (xc * lax.rsqrt(var + self.eps)).astype(x.dtype)
+            return y * params["weight"].astype(x.dtype), buffers
         if self.affine:
             # Pallas single-pass kernel on TPU, jnp fallback elsewhere
             from ..ops import fused_layer_norm

@@ -63,14 +63,35 @@ _LANES = 128  # VMEM scratch lane width (TPU-friendly minor dim)
 _BIG_LSE = 1e30  # lse sentinel for fully-masked rows: exp(s - BIG) == 0
 
 
-def _attention_reference(q, k, v, causal: bool, sm_scale: float):
+def _attention_reference(q, k, v, causal: bool, sm_scale: float,
+                         window: Optional[int] = None):
     """Numerics oracle + short-sequence fallback — delegates to the
     canonical dense attention (parallel/ring_attention.py:170),
-    pre-scaling q so a non-default sm_scale lands on the same path."""
+    pre-scaling q so a non-default sm_scale lands on the same path.
+    With a ``window`` the mask is written out here."""
+    if window is not None:
+        return windowed_attention(q, k, v, window, sm_scale)
     from ..parallel.ring_attention import attention as dense_attention
 
     d = q.shape[-1]
     return dense_attention(q * (sm_scale * math.sqrt(d)), k, v, causal)
+
+
+def windowed_attention(q, k, v, window: int,
+                       sm_scale: Optional[float] = None):
+    """Causal attention in which query ``t`` sees keys ``t - window + 1
+    .. t``, scores materialized: the oracle of ``flash_attention(...,
+    window=)`` and its path off the TPU.  Softmax in at least float32."""
+    T, S = q.shape[2], k.shape[2]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    # at least float32 (a float64 oracle keeps its precision)
+    ct = jnp.promote_types(q.dtype, jnp.float32)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(ct) * sm_scale
+    back = jnp.arange(T)[:, None] - jnp.arange(S)[None, :]
+    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 def _dot(a, b, dims):
@@ -101,6 +122,16 @@ def _tile_causal_mask(q_start, k_start, block_q: int, block_k: int,
         q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
         k_pos = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
     return q_pos >= k_pos
+
+
+def _tile_window_mask(q_start, k_start, block_q: int, block_k: int,
+                      window: int):
+    """Boolean mask of the keys at most ``window - 1`` positions behind
+    their query, for one (bq, bk) score tile at absolute offsets — the
+    sliding window's far edge (the near one is the causal mask)."""
+    q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k_pos = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return q_pos - k_pos < window
 
 
 def _softmax_init(rows: int, d: int):
@@ -232,25 +263,47 @@ def _visible_q(k0: int, sub_k: int, q_base: int, sub_q: int, n: int):
             _clip(k0 + sub_k - 1 - q_base + sub_q - 1, span) // sub_q)
 
 
+def _window_k(q0: int, sub_q: int, sub_k: int, n: int, window):
+    """For the query rows [q0, q0 + sub_q) under a sliding ``window``
+    (a query sees the keys at most ``window - 1`` behind it), of a grid
+    tile's ``n`` key sub-tiles: ``(first, inside)`` — sub-tiles before
+    ``first`` are wholly older than the window, [first, inside) are
+    crossed by its edge, from ``inside`` on every key is inside it.
+    ``window`` None: (0, 0), nothing skipped and nothing masked."""
+    if window is None:
+        return 0, 0
+    span = n * sub_k
+    return (_clip(q0 - window + 1, span) // sub_k,
+            _clip(q0 + sub_q - window + sub_k - 1, span) // sub_k)
+
+
 def _tile_offsets(T: int, S: int, block_q: int, block_k: int,
-                  every: bool = False):
+                  every: bool = False, window=None):
     """The values ``qi * block_q - ki * block_k`` takes on the grid
     tiles the diagonal crosses.  A tile whose offset is >= block_k - 1
     lies wholly under the diagonal; one at <= -block_q wholly above it:
     its schedule is empty, and only ``every`` lists it (for a kernel
-    that must still write its zeros)."""
+    that must still write its zeros).  With a ``window``, also the
+    offsets of the tiles under the diagonal that the window's edge
+    crosses: one past ``window + block_k - 2`` is wholly older than the
+    window (skipped), one up to ``window - block_q`` wholly inside it."""
     seen = {qi * block_q - ki * block_k
             for qi in range(T // block_q) for ki in range(S // block_k)}
-    return tuple(sorted(o for o in seen if o < block_k - 1
-                        and (every or o > -block_q)))
+    out = {o for o in seen if o < block_k - 1
+           and (every or o > -block_q)}
+    if window is not None:
+        out |= {o for o in seen if o >= block_k - 1
+                and window - block_q < o < window + block_k - 1}
+    return tuple(sorted(out))
 
 
 def causal_schedule(T: int, S: int, grid_tile, sub_tile,
-                    causal: bool = True) -> dict:
+                    causal: bool = True, window=None) -> dict:
     """Sub-tiles per head that one kernel computes, masks (of the
-    computed: those the diagonal crosses) and skips, for (q, k) tile
-    pairs ``grid_tile`` and ``sub_tile`` (an int means square) — counted
-    with the bounds the kernels' walks run to."""
+    computed: those the diagonal or the window's edge crosses) and
+    skips, for (q, k) tile pairs ``grid_tile`` and ``sub_tile`` (an int
+    means square) — counted with the bounds the kernels' walks run to.
+    A ``window`` of ``T`` or more is the causal schedule."""
     bq, bk = (grid_tile,) * 2 if isinstance(grid_tile, int) else grid_tile
     sq, sk = (sub_tile,) * 2 if isinstance(sub_tile, int) else sub_tile
     total = (T // sq) * (S // sk)
@@ -260,14 +313,17 @@ def causal_schedule(T: int, S: int, grid_tile, sub_tile,
             # a non-causal tile is a tile wholly under the diagonal
             offset = qi * bq - ki * bk if causal else bk
             for i in range(bq // sq):
-                full, comp = _visible_k(offset + i * sq, sq, sk, bk // sk)
-                computed += comp
-                masked += comp - full
+                q0 = offset + i * sq
+                full, comp = _visible_k(q0, sq, sk, bk // sk)
+                first, inside = _window_k(q0, sq, sk, bk // sk, window)
+                computed += max(comp - first, 0)
+                masked += sum(1 for j in range(first, comp)
+                              if j >= full or j < inside)
     return {"computed": computed, "masked": masked,
             "skipped": total - computed}
 
 
-def _record_schedule(kernel: str, T, S, D, tiles, causal):
+def _record_schedule(kernel: str, T, S, D, tiles, causal, window=None):
     """One ``flash.schedule`` event in the process tracer's ring per
     traced kernel build: the schedule is static, so it is recorded where
     it is made."""
@@ -275,31 +331,47 @@ def _record_schedule(kernel: str, T, S, D, tiles, causal):
 
     bq, bk, sq, sk = tiles
     tr = default_tracer()
+    extra = {} if window is None else {"window": window}
     tr.record("flash.schedule", "compile", tr.clock(), 0.0, kernel=kernel,
               T=T, S=S, head_dim=D, grid_tile=[bq, bk], sub_tile=[sq, sk],
-              **causal_schedule(T, S, (bq, bk), (sq, sk), causal))
+              **extra,
+              **causal_schedule(T, S, (bq, bk), (sq, sk), causal, window))
 
 
 def _sub(i: int, size: int) -> slice:
     return slice(i * size, (i + 1) * size)
 
 
-def _at_static_offset(body, offset, offsets, block_k: int):
+def _at_static_offset(body, offset, offsets, block_k: int, window=None,
+                      block_q: int = 0):
     """Run ``body`` with this grid tile's offset as a Python int: one
     ``pl.when`` branch per offset the diagonal crosses a tile at, one
     (``body(block_k)``: nothing masked) for the tiles wholly under it;
-    a tile wholly above it matches none and is skipped."""
-    if isinstance(offset, int):         # a grid of one tile; non-causal
-        return body(offset)
+    a tile wholly above it matches none and is skipped.  Under a
+    ``window`` (the forward kernel only) ``offsets`` also holds the
+    tiles its edge crosses, ``body`` takes the window as a second
+    argument — None in the unmasked branch, which then stops at the
+    last tile wholly inside the window — and a tile wholly older than
+    the window matches none."""
+    if window is None:
+        if isinstance(offset, int):     # a grid of one tile; non-causal
+            return body(offset)
+        for static in offsets:
+            pl.when(offset == static)(functools.partial(body, static))
+        pl.when(offset >= block_k - 1)(functools.partial(body, block_k))
+        return
+    if isinstance(offset, int):
+        return body(offset, window)
     for static in offsets:
-        pl.when(offset == static)(functools.partial(body, static))
-    pl.when(offset >= block_k - 1)(functools.partial(body, block_k))
+        pl.when(offset == static)(functools.partial(body, static, window))
+    pl.when((offset >= block_k - 1) & (offset <= window - block_q))(
+        functools.partial(body, block_k, None))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 sub_q: int, sub_k: int, num_q_blocks: int,
-                num_k_blocks: int, offsets):
+                num_k_blocks: int, offsets, window=None):
     qi = pl.program_id(1) if num_q_blocks > 1 else 0
     ki = pl.program_id(2) if num_k_blocks > 1 else 0
     n_k = block_k // sub_k
@@ -313,19 +385,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         def _init():
             _init_softmax_scratch(m_scr, l_scr, acc_scr)
 
-    def q_sub_tile(i: int, offset: int):
+    def q_sub_tile(i: int, offset: int, win):
         rows = _sub(i, sub_q)
         q = q_ref[0, rows, :]                             # (sub_q, d)
         q0 = offset + i * sub_q
         full, computed = _visible_k(q0, sub_q, sub_k, n_k)
+        # a sliding window: sub-tiles wholly older than it are never
+        # touched, those its edge crosses take its mask
+        first, inside = _window_k(q0, sub_q, sub_k, n_k, win)
         carry = (_softmax_init(sub_q, d) if one_pass else
                  (m_scr[rows, :1], l_scr[rows, :1], acc_scr[rows, :]))
-        for j in range(computed):
+        for j in range(first, computed):
             cols = _sub(j, sub_k)
             s = _dot(q, k_ref[0, cols, :], ((1,), (1,))) * sm_scale
             if j >= full:       # the diagonal crosses this sub-tile
                 s = jnp.where(_tile_causal_mask(q0, j * sub_k, sub_q, sub_k),
                               s, -jnp.inf)
+            if j < inside:      # the window's edge crosses it
+                s = jnp.where(_tile_window_mask(q0, j * sub_k, sub_q,
+                                                sub_k, win), s, -jnp.inf)
             carry = _softmax_update(s, v_ref[0, cols, :], *carry)
         if one_pass:
             out, lse = _softmax_finish(*carry)
@@ -337,13 +415,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             l_scr[rows, :] = jnp.broadcast_to(l, (sub_q, _LANES))
             acc_scr[rows, :] = acc
 
-    def tile(offset: int):
+    def tile(offset: int, win=None):
         for i in range(block_q // sub_q):
-            q_sub_tile(i, offset)
+            q_sub_tile(i, offset, win)
 
     # a non-causal tile is a tile wholly under the diagonal
     offset = qi * block_q - ki * block_k if causal else block_k
-    _at_static_offset(tile, offset, offsets, block_k)
+    _at_static_offset(tile, offset, offsets, block_k, window, block_q)
 
     if not one_pass:
         @pl.when(ki == num_k_blocks - 1)
@@ -365,11 +443,11 @@ def _tiles(T: int, S: int, D: int, block_q, block_k, sub_tile):
 
 
 def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q, block_k,
-               sub_tile, interpret: bool):
+               sub_tile, interpret: bool, window=None):
     B, H, T, D = q.shape
     S = k.shape[2]
     tiles = bq, bk, sq, sk = _tiles(T, S, D, block_q, block_k, sub_tile)
-    _record_schedule("fwd", T, S, D, tiles, causal)
+    _record_schedule("fwd", T, S, D, tiles, causal, window)
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * H, S, D)
     vr = v.reshape(B * H, S, D)
@@ -378,7 +456,10 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q, block_k,
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_q=bq, block_k=bk, sub_q=sq, sub_k=sk,
                                num_q_blocks=nq, num_k_blocks=nk,
-                               offsets=_tile_offsets(T, S, bq, bk))
+                               offsets=_tile_offsets(T, S, bq, bk,
+                                                     window=window),
+                               **({} if window is None
+                                  else {"window": window}))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -652,23 +733,29 @@ def _fit_sub_tile(sub: int, block: int) -> int:
     return sub
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, sm_scale, interpret, block_q, block_k,
-           sub_tile=None):
+           sub_tile=None, window=None):
     out, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                        sub_tile, interpret)
+                        sub_tile, interpret, window)
     return out
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, interpret, block_q,
-                    block_k, sub_tile):
+                    block_k, sub_tile, window):
     out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                          sub_tile, interpret)
+                          sub_tile, interpret, window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, interpret, block_q, block_k,
-                    sub_tile, res, g):
+                    sub_tile, window, res, g):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention(window=) has a forward kernel only: the "
+            "two backward kernels walk the causal schedule.  Train a "
+            "windowed layer with seq_strategy='dense'.")
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, g, causal, sm_scale, block_q,
                       block_k, sub_tile, interpret)
@@ -682,7 +769,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    sub_tile: Optional[int] = None):
+                    sub_tile: Optional[int] = None,
+                    window: Optional[int] = None):
     """Attention over (B, H, T, D) tensors without materializing scores.
 
     Uses the Pallas kernels on TPU (or under ``interpret=True``); plain
@@ -695,15 +783,28 @@ def flash_attention(q, k, v, causal: bool = False,
     sub-tile walked inside it; None means chosen from the shape
     (``_pick_block``, ``_pick_sub_tile``) — exposed for the on-hardware
     tuning sweeps.
+    ``window`` (causal only): query ``t`` sees keys ``t - window + 1 ..
+    t``.  The forward kernel's walk skips the sub-tiles wholly older
+    than the window and masks those its edge crosses; a window of ``T``
+    or more is the causal schedule, sub-tile for sub-tile.  Forward
+    only: differentiating the windowed kernel raises.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     T, S = q.shape[2], k.shape[2]
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal=True and a "
+                             "length of at least 1")
+        window = None if window >= T else int(window)
 
     def blockable(n):  # one whole block (8-aligned) or a 128-multiple
         return (n % 128 == 0) or (n < 128 and n % 8 == 0)
 
     if use_kernel(interpret) and blockable(T) and blockable(S):
+        if window is None:
+            return _flash(q, k, v, causal, sm_scale, interpret,
+                          block_q, block_k, sub_tile)
         return _flash(q, k, v, causal, sm_scale, interpret,
-                      block_q, block_k, sub_tile)
-    return _attention_reference(q, k, v, causal, sm_scale)
+                      block_q, block_k, sub_tile, window)
+    return _attention_reference(q, k, v, causal, sm_scale, window)

@@ -1,7 +1,7 @@
 from .all_reduce import AllReduceParameter, padded_size, shard_batch
 from .compressed import (CompressedTensor, FP16CompressedTensor,
                          FP16SplitsCompressedTensor)
-from .moe import MoEFFN, aux_loss_term, collect_aux_paths
+from .moe import DroplessMoE, MoEFFN, aux_loss_term, collect_aux_paths
 from .pipeline import (make_pipeline_eval_forward, make_pipeline_train_step,
                        pack_params, unpack_params)
 from .plan import (CompiledPlanStep, Plan, Rule, compile_step_with_plan,

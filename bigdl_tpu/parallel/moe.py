@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..nn.initialization import IN_OUT, ONE_D, Xavier, Zeros
+from ..nn.initialization import (IN_OUT, ONE_D, RandomNormal, Xavier,
+                                 Zeros)
 from ..nn.module import TensorModule
 
 
@@ -246,6 +247,317 @@ class MoEFFN(TensorModule):
         # gate; top-k: the renormalized per-choice gates), so the
         # weighted mixture falls out of one einsum
         y = jnp.einsum("nec,ecd->nd", comb, out_e)
+        return y.reshape(B, T, D), buffers
+
+
+# --------------------------------------------------------------------------
+# The dropless dispatch: top-k routing over ALL experts, the held
+# experts' assignments sorted by expert, one grouped matrix product per
+# projection, a weighted gather back.  Shared by ``DroplessMoE`` (gated
+# experts, a share of the experts) and by decode's capacity-free advance
+# of a ``MoEFFN`` (``models/generate.py::_moe_ffn_nodrop``).
+# --------------------------------------------------------------------------
+
+SCORINGS = ("softmax", "sigmoid")
+
+#: rows of the sorted buffer one dispatch may hold: a longer token list
+#: is routed at once and dispatched in equal pieces, so the
+#: worst-case buffers of a long prefill stay a few hundred megabytes
+MAX_DISPATCH_ROWS = 32768
+#: up to this many pieces are a Python loop, more a ``lax.map``
+MAX_UNROLLED_PIECES = 32
+
+
+def route_top_k(x2, router_w, router_b, top_k: int,
+                scoring: str = "softmax", renormalize: bool = True):
+    """Scores over ALL experts in float32 from ``x2`` [N, D] (whatever
+    dtype it has: the product accumulates in float32), the ``top_k``
+    largest, and their gates — the scores themselves, or divided by
+    their sum under ``renormalize``.  -> (gates [N, K] in at least
+    float32, idx [N, K])."""
+    with jax.named_scope("moe.route"):
+        # at least float32 (a float64 oracle keeps its precision)
+        ct = jnp.promote_types(x2.dtype, jnp.float32)
+        logits = jnp.dot(x2, router_w.T.astype(x2.dtype),
+                         preferred_element_type=ct)
+        if router_b is not None:
+            logits = logits + router_b.astype(ct)
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"scoring {scoring!r} not in {SCORINGS}")
+        gates, idx = lax.top_k(scores, top_k)
+        if renormalize:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, idx
+
+
+def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
+    """``xs`` [R, k] rows sorted by group, ``w`` [G, k, n]: row ``r`` of
+    group ``g`` is multiplied by ``w[g]``; rows past ``sum(group_sizes)``
+    come out UNDEFINED (the caller masks them).  ``impl``:
+
+    * ``"ragged"`` — ``jax.lax.ragged_dot``: plain XLA off the TPU
+      (differentiable by autodiff), the compiler's own grouped kernel in
+      tiles of 512 on it;
+    * ``"gmm"`` — the Pallas grouped matmul that ships with jax
+      (megablox), rows in tiles of 128: a decode step's handful of rows
+      an expert costs a quarter of the MXU work of a 512-row tile, and
+      each weight tile is still read once.  TPU only.
+
+    None picks by backend and size (``PERF.md`` §6 "PR 32" has the
+    sweep): ``gmm`` on a TPU for a buffer of at most 2048 rows whose
+    sizes its tiles divide, ``ragged`` everywhere else."""
+    R, k = xs.shape
+    n = w.shape[-1]
+    if impl is None:
+        impl = ("gmm" if jax.default_backend() == "tpu" and R <= 2048
+                and R % 128 == 0 and k % 512 == 0 and n % 512 == 0
+                else "ragged")
+    if impl == "ragged":
+        return lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes)
+    if impl != "gmm":
+        raise ValueError(f"grouped matmul {impl!r} not in (ragged, gmm)")
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    tk = 1024 if k % 1024 == 0 else 512
+    tn = 1024 if n % 1024 == 0 else 512
+    return gmm(xs, w.astype(xs.dtype), group_sizes,
+               preferred_element_type=xs.dtype, tiling=(128, tk, tn))
+
+
+def held_key(idx, held):
+    """``idx`` with each expert by its place among the ``held`` =
+    (first, count) ones, and ``count`` for every expert that is not."""
+    first, count = held
+    local = idx - first
+    return jnp.where((local >= 0) & (local < count), local, count)
+
+
+def dispatch_plan(idx, gates, held, rows: int):
+    """Where each assignment to a held expert goes in the sorted buffer.
+
+    ``idx`` / ``gates`` [N, K] from :func:`route_top_k`, ``held`` =
+    (first, count).  Returns ``tok`` [R] (the token of each buffer row),
+    ``valid`` [R], ``pos`` [N, K] (the buffer row of each assignment;
+    any row for one that is not held, its ``weight`` is 0), ``weight``
+    [N, K] f32 and ``sizes`` [count] int32 (rows per held expert).
+    ``R = N * min(K, count)``: top-k picks distinct experts, so no token
+    holds more than that — the buffer fits the worst imbalance and
+    nothing is ever dropped."""
+    count = held[1]
+    N, K = idx.shape
+    key = held_key(idx, held)
+    is_held = key < count
+    key = key.reshape(-1)                                     # [N*K]
+    order = jnp.argsort(key, stable=True)       # held first, by expert
+    sizes = jnp.sum(jax.nn.one_hot(key, count + 1, dtype=jnp.int32),
+                    axis=0)[:count]
+    inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32))
+    pos = jnp.minimum(inv, rows - 1).reshape(N, K)
+    order = order[:rows]
+    tok = (order // K).astype(jnp.int32)
+    valid = jnp.arange(rows) < jnp.sum(sizes)
+    weight = jnp.where(is_held, gates, 0.0)
+    return tok, valid, pos, weight, sizes
+
+
+def dropless_apply(x2, idx, gates, held, expert_fn):
+    """The held experts' part of the mixture for ``x2`` [N, D]:
+    ``sum_k weight[n, k] * expert_{idx[n, k]}(x2[n])`` over the choices
+    that name a held expert; what the others would add is left out.
+
+    ``expert_fn(xs, sizes) -> ys`` applies the experts to the sorted
+    rows (:func:`row_experts` gives each row's expert, for a per-expert
+    bias).  Returns (y [N, D], sizes [count] int32).  Static
+    shapes for the worst case, nothing dropped; a token list whose
+    buffer would pass ``MAX_DISPATCH_ROWS`` goes in equal pieces."""
+    N, K = idx.shape
+    per_tok = min(K, held[1])
+    if N * per_tok <= MAX_DISPATCH_ROWS or N == 1:
+        return _dropless_piece(x2, idx, gates, held, expert_fn)
+    pieces = -(-N * per_tok // MAX_DISPATCH_ROWS)
+    while N % pieces:
+        pieces += 1
+    n = N // pieces
+    if pieces <= MAX_UNROLLED_PIECES:
+        # unrolled, so that a generate program's device trace holds one
+        # ``while`` — its decode scan (as the chunked SSD scan does)
+        outs = [_dropless_piece(x2[lo:lo + n], idx[lo:lo + n],
+                                gates[lo:lo + n], held, expert_fn)
+                for lo in range(0, N, n)]
+        return (jnp.concatenate([y for y, _ in outs]),
+                sum(sizes for _, sizes in outs))
+    ys, sizes = lax.map(
+        lambda a: _dropless_piece(a[0], a[1], a[2], held, expert_fn),
+        (x2.reshape(pieces, n, -1), idx.reshape(pieces, n, K),
+         gates.reshape(pieces, n, K)))
+    return ys.reshape(N, -1), jnp.sum(sizes, axis=0)
+
+
+def _record_schedule(tokens: int, rows: int, held: int, k: int):
+    """One ``moe.schedule`` event in the process tracer's ring per
+    traced dispatch: its shapes are static, so they are recorded where
+    they are made (as ``flash.schedule`` is)."""
+    from ..telemetry.tracer import default_tracer
+
+    tr = default_tracer()
+    tr.record("moe.schedule", "compile", tr.clock(), 0.0, tokens=tokens,
+              rows=rows, held=held, k=k)
+
+
+def _dropless_piece(x2, idx, gates, held, expert_fn):
+    N, K = idx.shape
+    rows = N * min(K, held[1])
+    _record_schedule(N, rows, held[1], K)
+    with jax.named_scope("moe.dispatch"):
+        tok, valid, pos, weight, sizes = dispatch_plan(idx, gates, held,
+                                                       rows)
+        xs = jnp.take(x2, tok, axis=0)                        # [R, D]
+    with jax.named_scope("moe.expert_matmul"):
+        ys = expert_fn(xs, sizes)
+    with jax.named_scope("moe.combine"):
+        # rows past the last assignment are whatever the grouped
+        # product left there: zeroed before they are gathered
+        ys = jnp.where(valid[:, None], ys, 0)
+        picked = jnp.take(ys, pos.reshape(-1), axis=0).reshape(N, K, -1)
+        ct = jnp.promote_types(x2.dtype, jnp.float32)
+        y = jnp.einsum("nk,nkd->nd", weight.astype(ct), picked.astype(ct))
+    return y.astype(x2.dtype), sizes
+
+
+def row_experts(sizes, rows: int):
+    """The held expert of each of the sorted buffer's ``rows`` (the last
+    one for the rows past the last assignment)."""
+    return jnp.minimum(jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(rows),
+                                        side="right"), sizes.shape[0] - 1)
+
+
+def swiglu_experts(w_gate, w_up, w_down):
+    """``expert_fn`` of gated experts: ``down(silu(gate(x)) * up(x))``
+    with ``w_gate`` / ``w_up`` [E, D, F] and ``w_down`` [E, F, D] — three
+    grouped products and the gate."""
+    def fn(xs, sizes):
+        g = grouped_matmul(xs, w_gate, sizes)
+        u = grouped_matmul(xs, w_up, sizes)
+        return grouped_matmul(jax.nn.silu(g) * u, w_down, sizes)
+
+    return fn
+
+
+class DroplessMoE(TensorModule):
+    """A gated mixture-of-experts FFN over [batch, seq, embed] that
+    drops nothing and can hold a SHARE of its experts.
+
+    The router scores ALL ``n_experts`` (``scoring`` ``"softmax"`` or
+    ``"sigmoid"``, in float32), keeps the ``top_k`` largest and — under
+    ``renormalize`` — divides their scores by their sum.  ``held =
+    (first, count)`` says which experts' weights live here (default:
+    all): the layer computes the part of the mixture its own experts
+    give, and what absent experts would add is left out — the layer
+    expert parallelism needs, run without its exchange.  Experts are
+    SwiGLU MLPs ``embed -> hidden -> embed`` without biases, stored
+    ``w_gate`` / ``w_up`` [count, embed, hidden] and ``w_down`` [count,
+    hidden, embed], drawn ``normal(0, init_std)`` unless an init method
+    is set.  ``n_shared`` shared experts of the same shape see
+    every token; their MEAN is added (``shared_gate`` / ``shared_up``
+    [n_shared, embed, hidden], ``shared_down`` [n_shared, hidden,
+    embed]).
+
+    Dispatch (:func:`dropless_apply`): assignments to held experts
+    sorted by expert, one grouped matrix product per projection, a
+    weighted gather back; static shapes for the worst imbalance.
+    ``apply_fn`` is plain differentiable jax off the TPU.
+
+    ``routed(params, x2)`` returns the per-expert assignment counts
+    beside the result, for the decode scan's counters."""
+
+    def __init__(self, embed_dim: int, hidden_dim: int, n_experts: int,
+                 top_k: int = 2, scoring: str = "softmax",
+                 renormalize: bool = True, n_shared: int = 0,
+                 held: Optional[tuple] = None, init_std: float = 0.02):
+        super().__init__()
+        self.init_std = float(init_std)
+        if scoring not in SCORINGS:
+            raise ValueError(f"scoring {scoring!r} not in {SCORINGS}")
+        if not 1 <= top_k <= n_experts:
+            raise ValueError(
+                f"top_k must be in [1, n_experts={n_experts}], got {top_k}")
+        first, count = held if held is not None else (0, n_experts)
+        if not (0 <= first and count >= 1 and first + count <= n_experts):
+            raise ValueError(f"held={held} is no range of the "
+                             f"{n_experts} experts")
+        self.embed_dim, self.hidden_dim = embed_dim, hidden_dim
+        self.n_experts, self.top_k = n_experts, int(top_k)
+        self.scoring, self.renormalize = scoring, bool(renormalize)
+        self.n_shared = int(n_shared)
+        self.held = (int(first), int(count))
+        self.reset()
+
+    def reset(self):
+        D, F, (_, count) = self.embed_dim, self.hidden_dim, self.held
+        custom = self._init_methods.get("weight", (None, None))[0]
+
+        def stack(n, rows, cols):
+            if custom is None:      # one draw a leaf: no fan to respect
+                return RandomNormal(0.0, self.init_std).init((n, rows, cols))
+            return jnp.stack([jnp.asarray(custom.init((cols, rows),
+                                                      IN_OUT)).T
+                              for _ in range(n)])
+
+        self._register_param("router_w", (
+            custom or RandomNormal(0.0, self.init_std)).init(
+                (self.n_experts, D), IN_OUT))
+        self._register_param("w_gate", stack(count, D, F))
+        self._register_param("w_up", stack(count, D, F))
+        self._register_param("w_down", stack(count, F, D))
+        if self.n_shared:
+            self._register_param("shared_gate", stack(self.n_shared, D, F))
+            self._register_param("shared_up", stack(self.n_shared, D, F))
+            self._register_param("shared_down", stack(self.n_shared, F, D))
+        return self
+
+    def shared(self, params, x2):
+        """The mean of the shared experts on ``x2`` [N, D]: one plain
+        matmul a matrix (``w[s]`` is a contiguous slice of the stacked
+        leaf), summed in at least float32."""
+        with jax.named_scope("moe.shared"):
+            dt = x2.dtype
+            ct = jnp.promote_types(dt, jnp.float32)
+            y = 0.0
+            for s in range(self.n_shared):
+                g = jnp.dot(x2, params["shared_gate"][s].astype(dt))
+                u = jnp.dot(x2, params["shared_up"][s].astype(dt))
+                y = y + jnp.dot(jax.nn.silu(g) * u,
+                                params["shared_down"][s].astype(dt),
+                                preferred_element_type=ct)
+            return (y / self.n_shared).astype(dt)
+
+    def routed(self, params, x2, batch: Optional[int] = None):
+        """(the held experts' part plus the shared mean [N, D], the
+        assignments each held expert took: [count] int32, or [batch,
+        count] — by leading row of the ``batch`` the tokens came in)."""
+        gates, idx = route_top_k(x2, params["router_w"], None, self.top_k,
+                                 self.scoring, self.renormalize)
+        y, sizes = dropless_apply(
+            x2, idx, gates, self.held,
+            swiglu_experts(params["w_gate"], params["w_up"],
+                           params["w_down"]))
+        if self.n_shared:
+            y = y + self.shared(params, x2)
+        if batch is not None:
+            count = self.held[1]
+            sizes = jnp.sum(jax.nn.one_hot(
+                held_key(idx, self.held).reshape(batch, -1), count + 1,
+                dtype=jnp.int32), axis=1)[:, :count]
+        return y, sizes
+
+    def _apply(self, params, buffers, x, training, rng):
+        B, T, D = x.shape
+        y, _ = self.routed(params, x.reshape(B * T, D))
         return y.reshape(B, T, D), buffers
 
 

@@ -106,6 +106,21 @@ class _BoundedQueue:
             return items
 
 
+def moe_counters(counts, tokens_per_layer: int) -> dict:
+    """The ``serve.fetch`` arguments of a generate call that routed over
+    experts, from its ``[layers, held]`` assignment counts:
+    ``moe_assignments`` (total to the held experts), ``moe_tokens``
+    (tokens routed over, counted once a layer) and
+    ``moe_load_max_over_mean`` (a layer's busiest held expert over its
+    mean one, the worst layer)."""
+    counts = np.asarray(counts, np.int64)
+    mean = np.maximum(counts.mean(axis=1), 1e-9)
+    return {"moe_assignments": int(counts.sum()),
+            "moe_tokens": int(tokens_per_layer * counts.shape[0]),
+            "moe_load_max_over_mean": float((counts.max(axis=1)
+                                             / mean).max())}
+
+
 class InferenceServer:
     """See the module docstring for the full request lifecycle.
 
@@ -1195,9 +1210,15 @@ class InferenceServer:
                      compiled=new_sig, **holds):
             gen = cached_generate(self.model,
                                   compute_dtype=self.generate_dtype)
-            ids = gen(params, prompts_j, max_new, eos_id=eos_id,
-                      pad_id=pad_id)
-        with tr.span("serve.fetch", "device_wait"):
+            ids, stats = gen(params, prompts_j, max_new, eos_id=eos_id,
+                             pad_id=pad_id, return_stats=True)
+        with tr.span("serve.fetch", "device_wait") as fetch:
             self._flush_owed()
             out = np.asarray(ids)[:, prompts.shape[1]:]  # generated tail
+            if "moe_counts" in stats:
+                # [layers, held] assignments to the held experts in this
+                # call, fetched with the tokens
+                fetch.set(**moe_counters(
+                    np.asarray(stats["moe_counts"]),
+                    bucket * (prompts.shape[1] + max_new - 1)))
         return out, bucket

@@ -116,11 +116,19 @@ PROGRAM_SPANS = {
 #: (``nn/mamba.py``): ``mixer.ssd_scan`` is the chunked scan of a whole
 #: sequence, ``mixer.ssm_step`` the one-token update of conv tail and
 #: state, ``mixer.attention`` the attention branch beside the mixer.
+#: ``moe.*`` the parts of a dropless expert layer (``parallel/moe.py``):
+#: ``moe.route`` scores, top-k and gates, ``moe.dispatch`` the sort and
+#: the gather into the sorted buffer, ``moe.expert_matmul`` the grouped
+#: products and the gate, ``moe.combine`` the weighted gather back,
+#: ``moe.shared`` the shared experts; ``block.attention`` the attention
+#: branch of a parallel block (``models/parallel_moe.py``).
 DEVICE_SCOPES = (
     "generate.cast_params", "generate.prefill", "generate.decode_step",
     "generate.sample",
     "mixer.in_proj", "mixer.conv", "mixer.ssd_scan", "mixer.ssm_step",
     "mixer.gate_norm", "mixer.out_proj", "mixer.attention",
+    "moe.route", "moe.dispatch", "moe.expert_matmul", "moe.combine",
+    "moe.shared", "block.attention",
 )
 
 

@@ -23,6 +23,8 @@ import pytest
 from jax import enable_x64
 
 from bigdl_tpu import nn
+from bigdl_tpu.models.parallel_moe import ParallelMoEBlock
+from bigdl_tpu.parallel.moe import DroplessMoE
 from bigdl_tpu.utils.table import T, Table
 
 EPS = 1e-6
@@ -297,6 +299,18 @@ MODULE_CASES = {
         mamba_heads=2, mamba_head_dim=4, mamba_d_state=4, mamba_groups=2,
         mamba_chunk=2, key_multiplier=0.5, ssm_out_multiplier=0.7,
         mlp_multipliers=(0.8, 0.6)), lambda: X8, {}),
+    # the dropless expert layer (a share of the experts, sigmoid scores,
+    # a shared expert) and the parallel block around it: the selection
+    # is piecewise constant, so central differences see the gates',
+    # the experts' and the attention's derivatives
+    "DroplessMoE": (lambda: DroplessMoE(8, 12, 6, top_k=2,
+                                        scoring="sigmoid", n_shared=1,
+                                        held=(1, 4), init_std=0.3),
+                    lambda: X8, {}),
+    "ParallelMoEBlock": (lambda: ParallelMoEBlock(
+        8, num_heads=2, num_kv_heads=1, head_dim=4, expert_dim=12,
+        n_experts=6, top_k=2, attention="sliding", window=3, n_shared=1,
+        held=(0, 3), init_std=0.3), lambda: X8, {}),
     "Narrow": (lambda: nn.Narrow(2, 2, 3), lambda: X, {}),
     "NarrowTable": (lambda: nn.NarrowTable(1, 2),
                     lambda: T(X, X2, XP), {}),

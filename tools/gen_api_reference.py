@@ -111,6 +111,35 @@ def main():
             "  `InferenceServer.submit_generate` decode it through a K/V",
             "  cache and a recurrent state per layer; the paged path",
             "  (`PagedDecoder`) refuses it.",
+            "- **`models.parallel_moe.ParallelMoELM`** (PR 32; also",
+            "  `param_dtype` and the device draw): a `Container` of",
+            "  `ParallelMoEBlock`s — ONE bias-free `LayerNorm`",
+            "  (`with_bias=False`), then attention and a `DroplessMoE` on",
+            "  that same normed input, both summed into the residual —",
+            "  whose layers are `\"sliding\"` (window, interleaved RoPE:",
+            "  `MultiHeadAttention(rope=\"interleaved\", window=W)`) or",
+            "  `\"full\"` (no positions, `rope=None`) by `layer_switch` /",
+            "  `local_first`, and whose head (`TiedHead`, child `L+2`)",
+            "  owns no leaf and reads the embedding's.  `generate()` /",
+            "  `submit_generate` keep a cache per layer of that layer's own",
+            "  length (`min(T_cache, window)` for a sliding layer);",
+            "  `PagedDecoder` refuses it.",
+            "- **`parallel.moe.DroplessMoE`**: scores ALL `n_experts`",
+            "  (`softmax` or `sigmoid`, float32), keeps `top_k`, and",
+            "  computes the part of the mixture the experts it HOLDS give",
+            "  (`held=(first, count)`; default all) plus the mean of",
+            "  `n_shared` shared experts.  Nothing is dropped: assignments",
+            "  are sorted by expert into a buffer sized for the worst",
+            "  imbalance and go through one grouped matrix product per",
+            "  projection (`grouped_matmul`: `jax.lax.ragged_dot`, or the",
+            "  Pallas grouped matmul that ships with jax for a decode",
+            "  step's small buffer on a TPU).  `MoEFFN` keeps its capacity",
+            "  dispatch for training; decode advances it through the same",
+            "  dropless dispatch.",
+            "- **`ops.flash_attention(..., window=W)`**: the FORWARD kernel",
+            "  skips the sub-tiles wholly older than the window and masks",
+            "  those its edge crosses; a window of the sequence or more is",
+            "  the causal schedule; differentiating it raises.",
             "- **`nn.initialization.device_draw()`**: inside this context",
             "  the random initialisers (`RandomUniform`, `RandomNormal`,",
             "  `Xavier`, `MsraFiller`) draw with `jax.random` on the",
