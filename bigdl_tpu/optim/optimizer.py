@@ -25,7 +25,7 @@ from ..resilience.guards import LossSpikeDetector
 from ..resilience.preemption import PreemptionHandler
 from ..resilience.retry import LossSpikeError, RetryPolicy
 from ..utils.engine import get_property
-from ..utils.rng import next_jax_key
+from ..utils.rng import next_jax_key, peek_jax_key
 from .metrics import Metrics
 from .optim_method import SGD, OptimMethod
 from .trigger import Trigger
@@ -658,6 +658,15 @@ class Optimizer:
                    compiled=compiled, phase_split=phase_split,
                    skipped=skipped, span=span)
 
+    def _tm_commit(self, train_time: float, span):
+        """A step's loss has arrived: what the telemetry spine does at
+        the step's own boundary, while its ``train.iteration`` span is
+        live (a recovery window closes; the static work attributes go
+        onto the span).  The rest of the step's telling waits for
+        ``train.report`` (:meth:`_tm_step`)."""
+        if self.telemetry is not None:
+            self.telemetry.on_step_commit(train_time, span)
+
     def _tm_finish(self, state):
         """End of a training loop: drop the host's snapshot file when a
         snapshot directory is configured (tools/run_report.py input)."""
@@ -1223,14 +1232,18 @@ class Optimizer:
             sync_period=self.sync_period,
             sync_staleness=self.sync_staleness)
 
+    def _registry(self):
+        """The attached telemetry's registry, or the process's."""
+        from ..telemetry.registry import default_registry
+
+        return (self.telemetry.registry if self.telemetry is not None
+                else default_registry())
+
     def _publish_plan_metrics(self, engine, params):
         """Addressable-param-bytes gauges: the FSDP acceptance
         measurement (per-device bytes ~ total/N under an FSDP plan)
         and a live view of what the plan actually placed where."""
-        from ..telemetry.registry import default_registry
-
-        reg = (self.telemetry.registry if self.telemetry is not None
-               else default_registry())
+        reg = self._registry()
         try:
             by_dev = engine.param_bytes_by_device(params)
             total = float(sum(
@@ -1318,10 +1331,29 @@ class Optimizer:
             "bigdl.metrics.profileInterval", 10))
         compute_ratio = None   # last measured compute/total split
         eval_cache = {}        # lazily built validation forward
+
+        def seq_misfits(x):
+            if n_seq <= 1:
+                return []
+            return [a.shape for a in jax.tree_util.tree_leaves(x)
+                    if getattr(a, "ndim", 0) > 1
+                    and a.shape[1] % n_seq != 0]
+
+        def to_device(batch):
+            # the feed's producer puts a whole batch straight at the
+            # step's input sharding; one the driver has to pad or turn
+            # away first goes to the default device, as on one chip
+            if multi_device and batch.size() % pad_multiple == 0:
+                x, y = batch.get_input(), batch.get_target()
+                if not seq_misfits(x):
+                    return engine.place_batch(x), engine.place_batch(y)
+            return _device_batch(batch)
+
         # bounded prefetch-to-device infeed (dataset/prefetch.py):
         # batch N+1's host prep overlaps the compiled step on batch N;
         # data_time below is the REAL empty-buffer stall only
-        feed = self._make_feed(data_iter, epoch_size, records_this_epoch)
+        feed = self._make_feed(data_iter, epoch_size, records_this_epoch,
+                               transform=to_device)
         # first dispatch = XLA build (telemetry) — unless the engine
         # came out of the train_more cache, in which case there is no
         # build to attribute (goodput would book it as compile)
@@ -1331,21 +1363,31 @@ class Optimizer:
         # slice boundary, not an empty-buffer stall — a real infeed
         # stall would keep showing on the following iterations
         warm_reentry = not first_step
-        try:
-            while not self.end_when(state):
-                with tr.span("train.iteration", "step",
-                             step=state["neval"]) as it_span:
-                    state["epoch_finished"] = False
-                    self._elastic_step_start(state)
+        staged_steps = self._registry().counter(
+            "bigdl_train_steps_staged_total",
+            "train steps whose every input was ready before the "
+            "previous step's loss arrived").labels()
+
+        def stage_step():
+            """Everything of a step that waits for no loss: its batch
+            from the feed, padded, masked, checked and placed, as the
+            arrays the compiled call takes, the call for them and the
+            key the step will draw.  Run while the step before is on
+            the device; what goes wrong here is kept and raised where
+            that step would have begun."""
+            nonlocal warm_reentry
+            st = _StagedStep()
+            try:
+                with tr.span("train.stage", "other"):
                     with tr.span("train.data_wait", "data_wait") as sp:
-                        item, stall_time = feed.get()
-                        sp.set(hit=stall_time == 0.0)
+                        item, st.stall_time = feed.get()
+                        sp.set(hit=st.stall_time == 0.0)
                     if warm_reentry:
-                        stall_time = 0.0
+                        st.stall_time = 0.0
                         warm_reentry = False
                     batch, x, y = item
-                    n_records = batch.size()
-                    mask_kw = {}
+                    n_records = st.n_records = batch.size()
+                    w = None
                     if n_records % pad_multiple != 0:
                         # trailing partial batch: pad whole records to the
                         # mesh multiple and train the real ones via the
@@ -1361,40 +1403,125 @@ class Optimizer:
                                 f"{pad_multiple}")
                         x, y, w = pad_batch(x, y, n_records,
                                             round_up(n_records, pad_multiple))
-                        mask_kw = {"w": w, "total_w": float(n_records)}
-                    if n_seq > 1:
-                        bad = [a.shape for a in jax.tree_util.tree_leaves(x)
-                               if getattr(a, "ndim", 0) > 1
-                               and a.shape[1] % n_seq != 0]
-                        if bad:
-                            raise ValueError(
-                                f"sequence dim of inputs {bad} must be "
-                                f"divisible by the mesh's seq-axis size "
-                                f"{n_seq}; pad sequences to a multiple")
-                    h2d_time = 0.0
+                        st.total_w = float(n_records)
+                    bad = seq_misfits(x)
+                    if bad:
+                        raise ValueError(
+                            f"sequence dim of inputs {bad} must be "
+                            f"divisible by the mesh's seq-axis size "
+                            f"{n_seq}; pad sequences to a multiple")
                     if multi_device:
-                        # pre-place the batch at the step's input sharding
-                        # (h2d attributed separately from the data stall)
+                        # at the step's input sharding (h2d attributed
+                        # separately from the data stall); what the feed
+                        # has placed already comes back as it is
                         t_h2d0 = clock()
-                        with tr.span("train.place_batch",
-                                     "host_to_device"):
+                        with tr.span("train.place_batch", "host_to_device"):
                             x = engine.place_batch(x)
                             y = engine.place_batch(y)
-                            if mask_kw:
-                                mask_kw["w"] = engine.place_batch(
-                                    mask_kw["w"])
-                        h2d_time = clock() - t_h2d0
-                        if self.telemetry is not None and h2d_time > 0:
-                            self.telemetry.on_host_to_device(
-                                h2d_time, step=state["neval"])
-                    infeed_time = stall_time + h2d_time
+                            if w is not None:
+                                w = engine.place_batch(w)
+                        st.h2d_time = clock() - t_h2d0
+                    st.call, st.x, st.y, st.w = engine.stage(x, y, w)
+                    st.key = peek_jax_key()
+            except Exception as e:  # noqa: BLE001 — raised by the loop
+                st.error = e
+            return st
+
+        def report():
+            """What only tells of a step — telemetry, health, metrics,
+            the log line, the summary — with the step's own values.
+            Owed from its loss until the next step is on the device,
+            and paid before the loop returns or raises."""
+            nonlocal owed, compute_ratio
+            r, owed = owed, None
+            if r is None:
+                return
+            with tr.span("train.report", "other", step=r["neval"]):
+                st, at = r["staged"], {"neval": r["neval"]}
+                if r["was_staged"]:
+                    staged_steps.inc()
+                if self.telemetry is not None and st.h2d_time > 0:
+                    self.telemetry.on_host_to_device(st.h2d_time,
+                                                     step=r["neval"])
+                train_time, loss = r["train_time"], r["loss"]
+                self._tm_step(at, train_time, st.stall_time, st.n_records,
+                              compiled=r["compiled"],
+                              phase_split=r["trace_split"],
+                              skipped=r["skipped"], span=r["span"])
+                self._health_step(at, loss, train_time)
+                # metric-name contract (reference
+                # DistriOptimizer.scala:146-151): profiled iterations
+                # pin the compute/aggregate split from the trace; in
+                # between, the last measured ratio attributes the fused
+                # step's wall time
+                if r["trace_split"] is not None:
+                    c_s, agg_s = r["trace_split"]
+                    compute_ratio = c_s / max(c_s + agg_s, 1e-12)
+                    self.phase_source = "trace"
+                    self.phase_split = r["trace_split"]
+                if compute_ratio is not None:
+                    self.metrics.add("computing time average",
+                                     train_time * compute_ratio)
+                    self.metrics.add("aggregate gradient time",
+                                     train_time * (1.0 - compute_ratio))
+                else:
+                    self.metrics.add("computing time average", train_time)
+                    self.metrics.add("aggregate gradient time", 0.0)
+                infeed_time = st.stall_time + st.h2d_time
+                self.metrics.add("get weights average", infeed_time)
+                self.metrics.add("data fetch time", st.stall_time)
+                rate = st.n_records / max(train_time + infeed_time, 1e-9)
+                log.info(
+                    "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
+                    "Train %d in %.4f seconds. Throughput is %.1f "
+                    "records/second. Loss is %.5f.",
+                    r["epoch"], r["records"], epoch_size, r["neval"],
+                    r["wall"], st.n_records, train_time + infeed_time,
+                    rate, loss)
+                if self.train_summary is not None:
+                    self.train_summary.add_scalar("Loss", loss, r["neval"])
+                    self.train_summary.add_scalar("Throughput", rate,
+                                                  r["neval"])
+                    if "LearningRate" in getattr(self.train_summary,
+                                                 "triggers", {}):
+                        self.train_summary.add_scalar(
+                            "LearningRate", r["lr"], r["neval"])
+                    if self.gradient_guard:
+                        self.train_summary.add_scalar(
+                            "SkippedSteps", float(r["skipped_steps"]),
+                            r["neval"])
+
+        # One order for every plan: loss n -> commit, triggers -> enqueue
+        # n+1.  Between the loss and the enqueue stands only what can
+        # stop training, reads or replaces the step's device state, or
+        # changes an argument of the next step; step n+1's inputs are
+        # staged while step n runs, and step n is reported once n+1 is
+        # on the device.
+        staged = None   # step n+1's inputs, made while step n runs
+        owed = None     # step n's values, until report() has told them
+        spent = None    # step n's donated state trees, until n+1 is enqueued
+        try:
+            running = not self.end_when(state)
+            while running:
+                with tr.span("train.iteration", "step",
+                             step=state["neval"]) as it_span:
+                    state["epoch_finished"] = False
+                    self._elastic_step_start(state)
+                    was_staged = staged is not None
+                    st, staged = staged or stage_step(), None
+                    if st.error is not None:
+                        raise st.error
+                    n_records, x, y = st.n_records, st.x, st.y
+                    masked = st.w is not None
+                    mask_kw = ({"w": st.w, "total_w": st.total_w}
+                               if masked else {})
 
                     # profile past the compile iteration so timings are
                     # warm; single-device meshes skip (nothing to split)
                     profiled = (multi_device and profile_interval > 0
                                 and state["neval"] > 1
                                 and state["neval"] % profile_interval == 0
-                                and not mask_kw)
+                                and not masked)
 
                     # relaxed synchrony: advance the step-phase counters
                     # and fire this iteration's averaging flags (host-side
@@ -1422,7 +1549,7 @@ class Optimizer:
 
                     lr = optim.get_current_lr()
                     t0 = clock()
-                    if first_step and not mask_kw \
+                    if first_step and not masked \
                             and self.telemetry is not None:
                         # XLA cost-model accounting for the exact program
                         # about to compile (inside the first step's timed
@@ -1437,7 +1564,7 @@ class Optimizer:
                                 jnp.zeros((engine.n_flags,), jnp.int32),
                                 sync_state)
                         self._tm_analyze(
-                            engine.jitted_for(x, y, False), params, slots,
+                            st.call, params, slots,
                             buffers, jnp.float32(lr), jax.random.PRNGKey(0),
                             x, y, *analyze_extra,
                             collective_bytes=engine.collective_bytes,
@@ -1449,16 +1576,33 @@ class Optimizer:
                         with tr.span("train.dispatch",
                                      "compile" if first_step
                                      else "dispatch",
-                                     compiled=first_step):
+                                     compiled=first_step,
+                                     staged=was_staged):
                             return engine.step(
                                 params, slots, buffers, lr, x, y,
-                                rng=next_jax_key(), **sync_kw, **mask_kw)
+                                rng=next_jax_key(st.key), call=st.call,
+                                **sync_kw, **mask_kw)
+
+                    def while_it_runs():
+                        # the device is busy with this step: tell of the
+                        # one before, make the next one's inputs.  An
+                        # epoch's last step stages nothing — the feed has
+                        # met its budget, the shuffle waits for the loss
+                        nonlocal staged, spent
+                        spent = None
+                        report()
+                        if records_this_epoch + n_records < epoch_size:
+                            staged = stage_step()
 
                     def fetch_loss(out):
-                        # the device wait; the feed's producer keeps
-                        # prefetching meanwhile
+                        # the device wait, and ONE fetch for all the
+                        # driver reads of the step: the three copies are
+                        # issued together, not each behind the wait for
+                        # the one before
                         with tr.span("train.loss_fetch", "device_wait"):
-                            return float(out[0])
+                            loss_v, ok, gn = jax.device_get(
+                                (out[0], out[4], out[5]))
+                            return float(loss_v), bool(ok), float(gn)
 
                     trace_split = None
                     if profiled:
@@ -1474,86 +1618,51 @@ class Optimizer:
                         def run_traced():
                             t_run = clock()
                             out = dispatch()
-                            loss_v = fetch_loss(out)
-                            step_out.append((out, loss_v,
+                            while_it_runs()
+                            step_out.append((out, fetch_loss(out),
                                              clock() - t_run))
                         trace_split = trace_phase_split(run_traced)
-                        out, loss, train_time = step_out[0]
+                        out, fetched, train_time = step_out[0]
                     else:
                         out = self._elastic_dispatch(dispatch, state)
-                        loss = fetch_loss(out)
+                        while_it_runs()
+                        fetched = fetch_loss(out)
                         train_time = clock() - t0
-                    # everything from here to the end of the iteration is
-                    # host work the device does not wait for — unless it
-                    # has nothing queued
+                    loss, step_ok, gnorm = fetched
+                    # commit and decide: the device has nothing queued
+                    # until the next iteration's dispatch, so only what
+                    # that dispatch depends on stands here
                     with tr.span("train.bookkeeping", "other"):
-                        _, params, slots, buffers, step_ok, gnorm = out[:6]
+                        # the trees this step was given are donated: to
+                        # let go of a thousand spent arrays takes
+                        # milliseconds, so it waits for the next enqueue
+                        spent = (params, slots, buffers)
+                        _, params, slots, buffers = out[:4]
                         if engine.has_relaxed:
                             sync_state = out[6]
-                        skipped = not bool(step_ok)
-                        self._tm_step(state, train_time, stall_time, n_records,
-                                      compiled=first_step,
-                                      phase_split=trace_split, skipped=skipped,
-                                      span=it_span)
+                        skipped = not step_ok
+                        records_this_epoch += n_records
+                        owed = {
+                            "staged": st, "was_staged": was_staged,
+                            "neval": state["neval"],
+                            "epoch": state["epoch"],
+                            "records": records_this_epoch, "loss": loss,
+                            "train_time": train_time, "lr": lr,
+                            "compiled": first_step, "skipped": skipped,
+                            "trace_split": trace_split, "span": it_span,
+                            "wall": clock() - wall_start,
+                            "skipped_steps": self.skipped_steps + skipped}
                         first_step = False
+                        self._tm_commit(train_time, it_span)
                         self._check_loss_anomaly(loss, skipped)
-                        self._health_step(state, loss, train_time)
                         params = self._maybe_corrupt_params(state, params)
-                        self._record_fingerprint(state, loss, float(gnorm),
+                        self._record_fingerprint(state, loss, gnorm,
                                                  (x, y), lambda: params,
                                                  skipped=skipped)
                         self._integrity_step(state, lambda: params)
 
-                        records_this_epoch += n_records
                         state["records_this_epoch"] = records_this_epoch
                         state["loss"] = loss
-                        # metric-name contract (reference
-                        # DistriOptimizer.scala:146-151): profiled iterations
-                        # pin the compute/aggregate split from the trace; in
-                        # between, the last measured ratio attributes the fused
-                        # step's wall time
-                        if profiled and trace_split is not None:
-                            c_s, agg_s = trace_split
-                            compute_ratio = c_s / max(c_s + agg_s, 1e-12)
-                            self.phase_source = "trace"
-                            self.phase_split = trace_split
-                        if compute_ratio is not None:
-                            self.metrics.add("computing time average",
-                                             train_time * compute_ratio)
-                            self.metrics.add("aggregate gradient time",
-                                             train_time * (1.0 - compute_ratio))
-                        else:
-                            self.metrics.add("computing time average",
-                                             train_time)
-                            self.metrics.add("aggregate gradient time", 0.0)
-                        self.metrics.add("get weights average", infeed_time)
-                        self.metrics.add("data fetch time", stall_time)
-                        log.info(
-                            "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
-                            "Train %d in %.4f seconds. Throughput is %.1f "
-                            "records/second. Loss is %.5f.",
-                            state["epoch"], records_this_epoch, epoch_size,
-                            state["neval"], clock() - wall_start, n_records,
-                            train_time + infeed_time,
-                            n_records / max(train_time + infeed_time, 1e-9),
-                            loss)
-
-                        if self.train_summary is not None:
-                            self.train_summary.add_scalar("Loss", loss,
-                                                          state["neval"])
-                            self.train_summary.add_scalar(
-                                "Throughput",
-                                n_records / max(train_time + infeed_time, 1e-9),
-                                state["neval"])
-                            if "LearningRate" in getattr(self.train_summary,
-                                                         "triggers", {}):
-                                self.train_summary.add_scalar(
-                                    "LearningRate", lr, state["neval"])
-                            if self.gradient_guard:
-                                self.train_summary.add_scalar(
-                                    "SkippedSteps", float(self.skipped_steps),
-                                    state["neval"])
-
                         state["neval"] += 1
                         optim.state = state
 
@@ -1588,9 +1697,17 @@ class Optimizer:
                             log.warning("preemption requested — checkpointed at "
                                         "iteration %d; exiting resumable",
                                         state["neval"] - 1)
-                            break
+                            running = False
+                    # the end trigger closes the iteration: it is handed
+                    # the table with the step just done in it
+                    running = running and not self.end_when(state)
+                    if not running:
+                        report()  # nothing follows to hide it behind
         finally:
-            feed.close()
+            try:
+                report()  # of the step an exception ended the loop at
+            finally:
+                feed.close()
 
         engine.sync_to_model(params, slots, buffers)
         model.evaluate()
@@ -1768,6 +1885,19 @@ def _restore_dtypes(tree, template):
     BatchNorm running stats f32 under a bf16 compute pass."""
     return jax.tree_util.tree_map(
         lambda a, t: jnp.asarray(a, jnp.result_type(t)), tree, template)
+
+
+class _StagedStep:
+    """One step's inputs, made ahead of its enqueue (``stage_step`` of
+    the plan driver): the placed arrays, the compiled call for them,
+    the peeked key — or the error that making them met."""
+
+    __slots__ = ("error", "n_records", "x", "y", "w", "total_w", "call",
+                 "key", "stall_time", "h2d_time")
+
+    def __init__(self):
+        self.error = self.w = self.total_w = None
+        self.stall_time = self.h2d_time = 0.0
 
 
 def _device_batch(batch: MiniBatch):

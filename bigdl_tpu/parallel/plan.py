@@ -45,6 +45,7 @@ spmd.py's model axis and pipeline.py's pipe axis):
 """
 from __future__ import annotations
 
+import functools
 import logging
 import re
 from typing import NamedTuple, Optional, Sequence, Tuple
@@ -903,7 +904,9 @@ class CompiledPlanStep:
     ``step(params, slots, buffers, lr, x, y, rng=None, w=None,
     total_w=None) -> (loss, params, slots, buffers, ok, gnorm)`` for
     ANY mesh; ``kind`` is ``"model"`` (params are the module tree) or
-    ``"packed"`` (the pipeline's stacked-block layout).  ``init_state``
+    ``"packed"`` (the pipeline's stacked-block layout).  ``stage(x, y,
+    w=None) -> (call, x, y, w)`` makes the stateless half of that call
+    ahead of time, for ``step(..., call=call)``.  ``init_state``
     device-places fresh trees per the plan, ``sync_to_model`` writes
     them back host-side, ``eval_forward`` builds the matching compiled
     validation forward."""
@@ -913,7 +916,7 @@ class CompiledPlanStep:
 
     # populated by compile_step_with_plan:
     #   kind, mesh, plan, model, optim, param_specs, slot_specs,
-    #   buffer_specs, input_spec, io_spec, pad_multiple, step,
+    #   buffer_specs, input_spec, io_spec, pad_multiple, step, stage,
     #   jitted_for, collective_bytes, sparse_bytes_saved,
     #   sync_bytes_saved, transport_table, sync_table, relaxed,
     #   periodic_cadences, stale_cadences, n_flags, has_relaxed,
@@ -1128,11 +1131,15 @@ class CompiledPlanStep:
 
     def place_batch(self, tree):
         """device_put a host batch pytree at the step's input sharding
-        (so dispatch never pays a surprise reshard)."""
+        (so dispatch never pays a surprise reshard).  A numpy leaf goes
+        from the host straight to its shards, never whole through the
+        default device; a leaf already placed so comes back as it is."""
         spec = self.io_spec(tree)
         return jax.tree_util.tree_map(
             lambda a, s: jax.device_put(
-                jnp.asarray(a), NamedSharding(self.mesh, s)), tree, spec)
+                a if isinstance(a, (np.ndarray, jax.Array))
+                else jnp.asarray(a), NamedSharding(self.mesh, s)),
+            tree, spec)
 
     def param_bytes_by_device(self, params) -> dict:
         """bytes of addressable param shards per device — the FSDP
@@ -1162,6 +1169,18 @@ def _warn_dropped_axes(model, mesh, seq_axis, model_axis):
             "only has %s — those layers will run replicated/degraded; "
             "pass a mesh with the axis or rebuild the model without it",
             missing, tuple(mesh.axis_names))
+
+
+def _stage(jitted_for, x, y, w=None):
+    """``CompiledPlanStep.stage``: the half of a step's call that needs
+    no state — the inputs as the arrays the compiled call takes, and the
+    call for their structure — so that ``step(..., call=...)`` converts
+    and looks up nothing between a loss and the next enqueue."""
+    x = jax.tree_util.tree_map(jnp.asarray, x)
+    y = jax.tree_util.tree_map(jnp.asarray, y)
+    if w is not None:
+        w = jnp.asarray(w, jnp.float32)
+    return jitted_for(x, y, w is not None), x, y, w
 
 
 def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
@@ -1715,13 +1734,16 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
     _shape_by_name = {nm: tuple(np.shape(leaf))
                       for nm, leaf in named_leaves(host_params)}
 
+    stage = functools.partial(_stage, _jitted_for)
+
     def step(params, slots, buffers, lr, x, y, rng=None, w=None,
-             total_w=None, sync_flags=None, sync_state=None):
-        x = jax.tree_util.tree_map(jnp.asarray, x)
-        y = jax.tree_util.tree_map(jnp.asarray, y)
+             total_w=None, sync_flags=None, sync_state=None, call=None):
+        if call is None:
+            call, x, y, w = stage(x, y, w)
         if rng is None:  # deterministic default (ad-hoc/test use)
             rng = jax.random.PRNGKey(0)
-        args = (params, slots, buffers, jnp.float32(lr), rng, x, y)
+        # host scalars: the compiled call moves them itself
+        args = (params, slots, buffers, np.float32(lr), rng, x, y)
         if has_relaxed:
             flags = (jnp.zeros((n_flags,), jnp.int32)
                      if sync_flags is None
@@ -1733,14 +1755,13 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
                     for nm in stale_cadences}
             args = args + (flags, pend)
         if w is not None:
-            args = args + (jnp.asarray(w, jnp.float32),
-                           jnp.float32(total_w))
-        return _jitted_for(x, y, w is not None)(*args)
+            args = args + (w, np.float32(total_w))
+        return call(*args)
 
     return CompiledPlanStep(
         kind="model", mesh=mesh, plan=plan, model=model, optim=optim,
         param_specs=pspecs, slot_specs=sslots, buffer_specs=bspecs,
-        input_spec=in_spec(2), io_spec=io_spec, step=step,
+        input_spec=in_spec(2), io_spec=io_spec, step=step, stage=stage,
         jitted_for=_jitted_for, pad_multiple=n_data,
         collective_bytes=plan.collective_bytes(host_params),
         sparse_bytes_saved=plan.sparse_bytes_saved(host_params),
@@ -1904,15 +1925,17 @@ def _compile_pipeline(model, criterion, optim, mesh, plan, d_ax, m_ax,
                 sharded, donate_argnums=(0, 1, 2) if donate else ())
         return _jitted[masked]
 
+    stage = functools.partial(_stage, _jitted_for)
+
     def step(packed, slots, buffers, lr, x, y, rng=None, w=None,
-             total_w=None):
-        args = (packed, slots, buffers, jnp.float32(lr),
-                rng if rng is not None else jax.random.PRNGKey(0),
-                jnp.asarray(x), jnp.asarray(y))
+             total_w=None, call=None):
+        if call is None:
+            call, x, y, w = stage(x, y, w)
+        args = (packed, slots, buffers, np.float32(lr),
+                rng if rng is not None else jax.random.PRNGKey(0), x, y)
         if w is not None:
-            args = args + (jnp.asarray(w, jnp.float32),
-                           jnp.float32(total_w))
-        return _jitted_for(x, y, w is not None)(*args)
+            args = args + (w, np.float32(total_w))
+        return call(*args)
 
     in_spec_fn = lambda ndim: P(*((d_ax,) + (None,) * (ndim - 1))) \
         if d_ax else P()
@@ -1922,7 +1945,7 @@ def _compile_pipeline(model, criterion, optim, mesh, plan, d_ax, m_ax,
     return CompiledPlanStep(
         kind="packed", mesh=mesh, plan=plan, model=model, optim=optim,
         param_specs=pspecs, slot_specs=sslots, buffer_specs=bspecs,
-        input_spec=in_batch, io_spec=io_spec, step=step,
+        input_spec=in_batch, io_spec=io_spec, step=step, stage=stage,
         jitted_for=_jitted_for, pad_multiple=n_data * M,
         collective_bytes=plan.collective_bytes(packed0),
         sparse_bytes_saved=0.0, sync_bytes_saved=0.0,

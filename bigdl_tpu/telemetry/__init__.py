@@ -200,25 +200,42 @@ class Telemetry:
         self.h2d_seconds.observe(seconds)
         self.ledger.add("data_stall", seconds)
 
+    def on_step_commit(self, seconds: float, span=None):
+        """A step's loss has arrived — the driver's commit, BEFORE the
+        next step is enqueued.  What has to happen at the step's own
+        boundary and while its ``train.iteration`` span is live: an
+        open recovery window closes where this step BEGAN (the step's
+        own ``seconds`` are attributed by :meth:`on_step`, not as
+        recovery), and the cost model's static FLOPs/bytes/intensity go
+        onto the live span, so a profiler session's event carries them.
+        Cheap: a flag read and a dict update.  Idempotent — a later
+        :meth:`on_step` for the same step finds both done."""
+        seconds = max(0.0, float(seconds))
+        if self.ledger.in_recovery:
+            rec = self.ledger.recovery_end(exclude=seconds)
+            t0, self._recovery_t0 = self._recovery_t0, None
+            if rec and t0 is not None and self.trace_every > 0:
+                self.tracer.record("recovery", "recovery", t0, rec)
+        if isinstance(span, Span) and span.end is None \
+                and self._trace_due():  # live, and not the disabled
+            # tracer's null span
+            span.set(**self.perf.span_args())
+
     def on_step(self, seconds: float, records: int = 0,
                 step: Optional[int] = None, compiled: bool = False,
                 phase_split=None, skipped: bool = False, span=None):
         """One compiled-step dispatch completed.  ``compiled=True``
         classifies it as compile time (the first step of every fresh
-        program).  ``span`` is the caller's live ``train.iteration``
-        span: the cost model's static FLOPs/bytes/intensity ride on it
-        as args, and ``phase_split`` (the optional
-        :class:`~bigdl_tpu.optim.profiling.PhaseSplit` of a profiled
-        step) becomes its compute / collective children, laid from the
-        span's own start — estimates of device time, not clock truths."""
+        program).  ``span`` is the caller's ``train.iteration`` span of
+        that step — live, or closed already where the driver reports a
+        step after the next one is enqueued and has called
+        :meth:`on_step_commit` while it was live: ``phase_split`` (the
+        optional :class:`~bigdl_tpu.optim.profiling.PhaseSplit` of a
+        profiled step) becomes its compute / collective children, laid
+        from the span's own start — estimates of device time, not clock
+        truths."""
         seconds = max(0.0, float(seconds))
-        if self.ledger.in_recovery:
-            # the window closes where this step BEGAN — the step's own
-            # seconds are attributed below, not as recovery
-            rec = self.ledger.recovery_end(exclude=seconds)
-            t0, self._recovery_t0 = self._recovery_t0, None
-            if rec and t0 is not None and self.trace_every > 0:
-                self.tracer.record("recovery", "recovery", t0, rec)
+        self.on_step_commit(seconds, span)
         self.ledger.add("compile" if compiled else "productive", seconds)
         self.steps.inc()
         if records:
@@ -228,17 +245,14 @@ class Telemetry:
         (self.compile_seconds if compiled
          else self.step_seconds).observe(seconds)
         self.perf.on_step(seconds, compiled=compiled)
-        if isinstance(span, Span) and self._trace_due():  # not the
-            # disabled tracer's null span
-            span.set(**self.perf.span_args())
-            if phase_split is not None:
-                compute_s, collective_s = phase_split
-                self.tracer.record("compute", "compute", span.start,
-                                   compute_s, parent=span, step=step)
-                self.tracer.record("collective", "collective",
-                                   span.start + compute_s,
-                                   collective_s, parent=span,
-                                   step=step)
+        if isinstance(span, Span) and phase_split is not None \
+                and self._trace_due():
+            compute_s, collective_s = phase_split
+            self.tracer.record("compute", "compute", span.start,
+                               compute_s, parent=span, step=step)
+            self.tracer.record("collective", "collective",
+                               span.start + compute_s,
+                               collective_s, parent=span, step=step)
         self._steps_seen += 1
 
     def on_checkpoint(self, seconds: float, step: Optional[int] = None):

@@ -24,6 +24,7 @@ __all__ = ["METRIC_FAMILY_NAMES"]
 
 # --- training spine (telemetry/__init__.py) ------------------------------
 TRAIN_STEPS_TOTAL = "bigdl_train_steps_total"
+TRAIN_STEPS_STAGED_TOTAL = "bigdl_train_steps_staged_total"
 TRAIN_RECORDS_TOTAL = "bigdl_train_records_total"
 TRAIN_STEP_SECONDS = "bigdl_train_step_seconds"
 TRAIN_COMPILE_SECONDS = "bigdl_train_compile_seconds"

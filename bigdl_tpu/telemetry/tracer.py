@@ -89,6 +89,7 @@ PROGRAM_SPANS = {
     "plan.init_state": "state_sync",
     "plan.sync_to_model": "state_sync",
     "train.iteration": "step",
+    "train.stage": "other",
     "train.data_wait": "data_wait",
     "train.place_batch": "host_to_device",
     "train.dispatch": "dispatch",
@@ -96,6 +97,7 @@ PROGRAM_SPANS = {
     "train.bookkeeping": "other",
     "train.validation": "other",
     "train.checkpoint": "checkpoint",
+    "train.report": "other",
     "feed.produce": "other",
     "feed.blocked": "idle",
     "serve.idle": "idle",

@@ -117,8 +117,29 @@ def np_stream(fallback: int) -> "np.random.RandomState":
     return np.random.RandomState(derive_seed(fallback))
 
 
-def next_jax_key():
-    """Derive a fresh jax PRNG key from the host generator."""
+def _draw_key_seed(rng: RandomGenerator) -> int:
+    return int(rng.random_int(0, 2**31 - 1))
+
+
+def peek_jax_key():
+    """``(seed, key)`` as the next :func:`next_jax_key` will draw them,
+    from a clone: the host stream does not move.  The training driver
+    builds a step's key ahead of its enqueue this way; the draw itself
+    stays where it was, so a checkpoint or a step that is never
+    enqueued sees the stream exactly as before."""
     import jax
 
-    return jax.random.PRNGKey(int(RNG().random_int(0, 2**31 - 1)))
+    seed = _draw_key_seed(RNG().clone())
+    return seed, jax.random.PRNGKey(seed)
+
+
+def next_jax_key(peeked=None):
+    """Derive a fresh jax PRNG key from the host generator.  ``peeked``
+    (:func:`peek_jax_key`) is handed back when the draw gives its seed
+    — whatever moved the stream in between, the key is the draw's."""
+    import jax
+
+    seed = _draw_key_seed(RNG())
+    if peeked is not None and peeked[0] == seed:
+        return peeked[1]
+    return jax.random.PRNGKey(seed)

@@ -377,3 +377,22 @@ def test_pytree_table_targets_pad_and_mask():
     w_l, _ = m_local.get_parameters()
     # 5e-4: psum_scatter vs local-sum f32 accumulation order over 3 steps
     np.testing.assert_allclose(np.asarray(w_d), np.asarray(w_l), atol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# the order of a step (ISSUE 35), on the 8-device data mesh: the same cases
+# as tests/test_local_optimizer.py, against the parent commit's account
+# ---------------------------------------------------------------------------
+
+import driver_order_scenarios as order  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def distri_order(tmp_path_factory):
+    return (order.scenarios("distri", str(tmp_path_factory.mktemp("order"))),
+            order.fixture()["distri"])
+
+
+@pytest.mark.parametrize("case", order.CASES)
+def test_distri_driver_order(distri_order, case):
+    getattr(order, "check_" + case)(*distri_order)

@@ -201,3 +201,24 @@ def test_optimizer_slots_survive_checkpoint(tmp_path):
     opt2.set_optim_method(om)
     opt2.set_end_when(max_iteration(12))
     opt2.optimize()  # no crash; moments carried forward
+
+
+# ---------------------------------------------------------------------------
+# the order of a step (ISSUE 35): step n+1 is staged while step n runs and
+# step n is reported once n+1 is enqueued — nothing a run computes or a
+# trigger sees may move.  The cases live in driver_order_scenarios.py,
+# the parent commit's account in fixtures/driver_order_pr35.json.
+# ---------------------------------------------------------------------------
+
+import driver_order_scenarios as order  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def local_order(tmp_path_factory):
+    return (order.scenarios("local", str(tmp_path_factory.mktemp("order"))),
+            order.fixture()["local"])
+
+
+@pytest.mark.parametrize("case", order.CASES)
+def test_local_driver_order(local_order, case):
+    getattr(order, "check_" + case)(*local_order)

@@ -255,6 +255,56 @@ def test_step_spans_carry_static_work_attributes():
     assert ev and ev[0]["args"]["flops"] > 0
 
 
+def test_work_attributes_are_set_while_the_step_span_is_live(monkeypatch):
+    """A step is reported after the NEXT one is enqueued, when its
+    ``train.iteration`` span has closed; the static work attributes
+    still go on at the step's commit — only a live span hands them to
+    a profiler session's event — and a recovery window closes there
+    too, not one dispatch later."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import Sample, array
+    from bigdl_tpu.optim import SGD
+    from bigdl_tpu.optim.optimizer import LocalOptimizer
+    from bigdl_tpu.telemetry import Telemetry
+    from bigdl_tpu.telemetry.tracer import Span
+
+    live = []
+    real_set = Span.set
+
+    def spy(self, **args):
+        if "flops" in args:
+            live.append((self.name, self.args["step"], self.end is None))
+        return real_set(self, **args)
+
+    monkeypatch.setattr(Span, "set", spy)
+    tm = Telemetry(registry=MetricsRegistry())
+    seen = []
+
+    def end(state):  # called after step n's commit, before enqueue n+1
+        seen.append((state["neval"] - 1, tm.ledger.in_recovery))
+        if state["neval"] - 1 == 2:
+            tm.on_recovery_begin()  # a fault noted after step 2
+        return state["neval"] > 5
+
+    rng = np.random.RandomState(0)
+    x = rng.rand(256, 8).astype(np.float32)
+    samples = [Sample(x[i], x[i, :1]) for i in range(len(x))]
+    opt = LocalOptimizer(nn.Sequential(nn.Linear(8, 1)), array(samples),
+                         nn.MSECriterion(), batch_size=32)
+    opt.set_optim_method(SGD(learning_rate=0.05))
+    opt.set_end_when(end)
+    opt.set_telemetry(tm)
+    opt.optimize()
+    # (a run's last step is reported inside its own iteration: twice)
+    assert sorted(set(live)) == [("train.iteration", n, True)
+                                 for n in range(1, 6)]
+    # open when step 3 began, closed by step 3's commit: the trigger
+    # that follows that commit already finds the window shut
+    assert seen == [(0, False), (1, False), (2, False), (3, False),
+                    (4, False), (5, False)]
+    assert tm.ledger.recovery_windows == 1 and not tm.ledger.in_recovery
+
+
 def test_distri_data_path_publishes_collective_bytes():
     from bigdl_tpu import nn
     from bigdl_tpu.dataset import Sample, array

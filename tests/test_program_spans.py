@@ -196,14 +196,23 @@ def test_plain_local_optimizer_records_its_span_tree():
     its = [s for s in spans if s.name == "train.iteration"]
     assert [s.args["step"] for s in its] == list(range(1, 9))
     assert all(s.parent_id == root[0].id and s.tid == driver for s in its)
+    # the order of an iteration (ISSUE 35): the dispatch comes first —
+    # its inputs were staged while the step before ran; behind it, while
+    # the device is busy, the report of the step before and the staging
+    # of the step after (its data wait inside); then the loss and the
+    # short commit.  An entry's first step stages itself, and the eighth
+    # batch is the epoch's last: nothing is staged beside it, and as the
+    # run ends there its own report closes its iteration
+    report, stage = "train.report", "train.stage"
+    tail = ["train.loss_fetch", "train.bookkeeping"]
+    want = ([[stage, "train.dispatch", stage] + tail]
+            + [["train.dispatch", report, stage] + tail] * 6
+            + [["train.dispatch", report] + tail + [report]])
     covered = total = 0.0
-    for it in its:
+    for it, names in zip(its, want):
         kids = sorted(_children(spans, it), key=lambda s: s.start)
-        assert [k.name for k in kids] == [
-            "train.data_wait", "train.dispatch", "train.loss_fetch",
-            "train.bookkeeping"]
-        # in place: inside the parent, one after another — the data
-        # wait of step n BEFORE the dispatch of step n
+        assert [k.name for k in kids] == names
+        # in place: inside the parent, one after another
         edges = [it.start]
         for k in kids:
             edges += [k.start, k.end]
@@ -211,9 +220,16 @@ def test_plain_local_optimizer_records_its_span_tree():
         assert edges == sorted(edges)
         covered += sum(k.duration for k in kids)
         total += it.duration
+        for k in kids:  # a staging holds its data wait, in place
+            if k.name == stage:
+                inner = _children(spans, k)
+                assert [c.name for c in inner] == ["train.data_wait"]
+                assert k.start <= inner[0].start <= inner[0].end <= k.end
+    # the span tree accounts for the driver's time: staging included
     assert covered >= 0.95 * total
     disp = [s for s in spans if s.name == "train.dispatch"]
     assert [s.args["compiled"] for s in disp] == [True] + [False] * 7
+    assert [s.args["staged"] for s in disp] == [False] + [True] * 7
     assert disp[0].category == "compile"
     assert {s.category for s in disp[1:]} == {"dispatch"}
     waits = [s for s in spans if s.name == "train.data_wait"]
@@ -222,6 +238,103 @@ def test_plain_local_optimizer_records_its_span_tree():
     for s in spans:
         assert s.name in PROGRAM_SPANS, s.name
         assert s.category in (PROGRAM_SPANS[s.name], "compile")
+
+
+def _order_optimizer(kind, steps):
+    """Four batches of 64 an epoch, on one device or the 8-device mesh."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import array
+    from bigdl_tpu.optim import SGD, max_iteration
+    from bigdl_tpu.optim.distri_optimizer import DistriOptimizer
+    from bigdl_tpu.optim.optimizer import LocalOptimizer
+
+    model = nn.Sequential(nn.Linear(4, 8), nn.Tanh(), nn.Linear(8, 1))
+    cls = LocalOptimizer if kind == "local" else DistriOptimizer
+    opt = cls(model, array(_regression()), nn.MSECriterion(), batch_size=64)
+    opt.set_optim_method(SGD(learning_rate=0.1))
+    opt.set_end_when(max_iteration(steps))
+    return opt
+
+
+@pytest.mark.parametrize("kind", ["local", "distri"])
+def test_between_a_loss_and_the_next_dispatch_stands_only_the_decision(kind):
+    """ISSUE 35: the feed, the placement and the reporting leave the
+    gap in which the device waits for the driver — read off the ring."""
+    from bigdl_tpu.optim import max_iteration
+    from bigdl_tpu.telemetry import default_registry
+
+    counter = "bigdl_train_steps_staged_total"
+    fam = default_registry().get(counter)
+    before = fam.labels().value if fam else 0.0
+    opt = _order_optimizer(kind, 7)
+    opt.reuse_compiled_engine = True
+    opt.optimize()
+    opt.set_end_when(max_iteration(10))
+    opt.optimize()  # a second entry: it begins its epoch again
+    spans = sorted((s for s in default_tracer().spans()
+                    if s.tid == threading.get_ident()),
+                   key=lambda s: s.start)
+    entries = [s for s in spans if s.name == "train.optimize"]
+    assert len(entries) == 2
+    kept_out = {"train.stage", "train.data_wait", "train.place_batch",
+                "train.report"}
+    gaps = 0
+    for entry in entries:
+        inside = [s for s in spans
+                  if entry.start <= s.start and s.end <= entry.end]
+        dispatches = [s for s in inside if s.name == "train.dispatch"]
+        for fetch in (s for s in inside if s.name == "train.loss_fetch"):
+            nxt = next((d for d in dispatches if d.start >= fetch.end), None)
+            if nxt is None:
+                continue  # the entry's last step
+            between = [s.name for s in inside
+                       if fetch.end <= s.start < nxt.start]
+            if nxt.args["staged"]:
+                assert not kept_out & set(between), between
+                gaps += 1
+            # what does stand there: the commit, and what it decides
+            assert "train.bookkeeping" in between
+    disp = [s for s in spans if s.name == "train.dispatch"]
+    # unstaged: an entry's first step (1, 8) and an epoch's first (5)
+    assert [s.args["staged"] for s in disp] == [
+        False, True, True, True, False, True, True, False, True, True]
+    assert gaps == 7
+    assert default_registry().get(counter).labels().value - before == 7
+    if kind == "distri":  # staged with the rest, not left in the gap
+        assert len([s for s in spans
+                    if s.name == "train.place_batch"]) >= 10
+    # every step is reported once, the last of an entry before it returns
+    reports = [s for s in spans if s.name == "train.report"]
+    assert [s.args["step"] for s in reports] == list(range(1, 11))
+    assert entries[0].start < reports[6].end <= entries[0].end
+
+
+@pytest.mark.parametrize("where", ["end", "validation"])
+def test_the_last_step_is_reported_when_a_trigger_raises(where):
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset import array
+    from bigdl_tpu.optim import Loss
+
+    class Boom(Exception):
+        pass
+
+    def trigger(state):
+        if state["neval"] > 3:  # with the third step's loss in the table
+            raise Boom
+        return False
+
+    opt = _local_optimizer(8)
+    if where == "end":
+        opt.set_end_when(trigger)
+    else:  # inside the commit, where the step's state is still its own
+        opt.set_validation(trigger, array(_regression(64)),
+                           [Loss(nn.MSECriterion())], batch_size=32)
+    with pytest.raises(Boom):
+        opt.optimize()
+    spans = default_tracer().spans()
+    assert [s.args["step"] for s in spans
+            if s.name == "train.report"] == [1, 2, 3]
+    assert len([s for s in spans if s.name == "train.dispatch"]) == 3
 
 
 def test_plan_engine_state_spans_bracket_the_loop():
