@@ -1,7 +1,8 @@
-"""Autoregressive generation for TransformerLM, HybridMambaLM and
-ParallelMoELM — KV-cache decode, with a recurrent state beside the K/V
-where a block has one, and a cache of each layer's own length where
-layers differ in what they see.
+"""Autoregressive generation for TransformerLM, HybridMambaLM,
+ParallelMoELM and LatentMoELM — KV-cache decode, with a recurrent state
+beside the K/V where a block has one, a cache of each layer's own
+length where layers differ in what they see, and a LATENT cache (no K
+or V by head) where a block's attention is latent.
 
 The reference predates autoregressive LMs entirely (its sequence story
 is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
@@ -35,6 +36,16 @@ interleaved, or none) and window are read per block.  A parallel block
 normed input; its cache also carries ``moe_counts`` ``[B, held]``, the
 assignments each held expert took from each row, which a generate call
 returns beside the tokens on request (``return_stats=True``).
+
+A block whose attention is LATENT (``nn.LatentAttention``;
+``models/latent_moe.py``) keeps ``ckv`` ``[B, T_cache, kv_rank]`` and
+``kr`` ``[B, T_cache, rope]`` — the normed latent and the one rotated
+key all heads share — and has TWO attention paths: prefill expands the
+prompt's latent to per-head K and V once and runs causal (flash)
+attention; a decode step absorbs ``wkv_b`` into the query and the
+output and attends on the latent itself, so that nothing with both a
+head and a cached-position axis exists but the scores.  What a block is
+made of is decided in one place (:func:`_block_kind`).
 
 Built from the model's OWN parameter tree and modules (the
 parallel/pipeline.py pattern): LN/MLP sublayers run through their
@@ -88,13 +99,15 @@ _GEN_CACHE = weakref.WeakKeyDictionary()
 
 def _check_model(model):
     from .hybrid_mamba import HybridMambaLM
+    from .latent_moe import LatentMoELM
     from .parallel_moe import ParallelMoELM
     from .transformer import TransformerLM
 
-    if not isinstance(model, (TransformerLM, HybridMambaLM, ParallelMoELM)):
+    if not isinstance(model, (TransformerLM, HybridMambaLM, ParallelMoELM,
+                              LatentMoELM)):
         raise TypeError(
-            f"generation supports TransformerLM, HybridMambaLM and "
-            f"ParallelMoELM (got {type(model).__name__})")
+            f"generation supports TransformerLM, HybridMambaLM, "
+            f"ParallelMoELM and LatentMoELM (got {type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
     # so a ring/Ulysses-trained model decodes through the same cached
@@ -103,16 +116,39 @@ def _check_model(model):
     return 1, len(model.modules) - 3
 
 
+def _block_kind(block) -> tuple:
+    """What a block is made of — decided HERE and nowhere else:
+    ``(form, attention, experts)``.
+
+    * ``form``: ``"hybrid"`` (``nn.HybridMambaBlock``: a recurrent state
+      beside its attention), ``"parallel"``
+      (``models.parallel_moe.ParallelMoEBlock``: attention and the
+      expert layer read one normed input) or ``"sequential"`` (attention,
+      then the FFN on a second norm);
+    * ``attention``: ``"latent"`` (``nn.LatentAttention``: the cache
+      holds the latent and the shared rotated key) or ``"kv"`` (per-head
+      K and V);
+    * ``experts``: the block's ``DroplessMoE`` (its cache carries
+      ``moe_counts``), or None."""
+    form = {"hybrid_mamba": "hybrid", "parallel_moe": "parallel"}.get(
+        getattr(block, "kind", None), "sequential")
+    attention = ("latent" if getattr(block.modules[1], "kind", None)
+                 == "latent" else "kv")
+    experts = (block.moe if form == "parallel"
+               or getattr(block, "ffn_kind", None) == "moe" else None)
+    return form, attention, experts
+
+
 def _is_hybrid(block) -> bool:
-    """A block that carries a recurrent state beside its K/V
-    (``nn.HybridMambaBlock``)."""
-    return getattr(block, "kind", None) == "hybrid_mamba"
+    return _block_kind(block)[0] == "hybrid"
 
 
 def _is_parallel(block) -> bool:
-    """A block whose attention and expert layer read one normed input
-    (``models.parallel_moe.ParallelMoEBlock``)."""
-    return getattr(block, "kind", None) == "parallel_moe"
+    return _block_kind(block)[0] == "parallel"
+
+
+def _is_latent(block) -> bool:
+    return _block_kind(block)[1] == "latent"
 
 
 def _window_of(block):
@@ -127,6 +163,7 @@ def _refuse_recurrent(model, first, count, what: str):
     step is the sequential one, so a block with a window or a parallel
     expert layer is refused too, not decoded as another model."""
     blocks = model.modules[first:first + count]
+    _refuse_latent(blocks, what, "K/V pages [Hkv, page, Dh]")
     if any(_is_parallel(b) or _window_of(b) for b in blocks):
         raise TypeError(
             f"{what} keeps pages of ONE length for every layer and runs "
@@ -141,6 +178,19 @@ def _refuse_recurrent(model, first, count, what: str):
             f"carry a recurrent state (SSM state and conv tail) beside "
             f"it: decode this model through generate() / "
             f"submit_generate(), whose static cache holds both")
+
+
+def _refuse_latent(blocks, what: str, holds: str):
+    """A latent block's cache is the latent and ONE rotated key a
+    position, no K or V by head: a store made for those has no place
+    for it."""
+    latent = [b.modules[1] for b in blocks if _is_latent(b)]
+    if latent:
+        raise TypeError(
+            f"{what} holds {holds} and {type(latent[0]).__name__} keeps "
+            f"no K or V by head — its cache is the latent and one rotated "
+            f"key a position: decode this model through generate() / "
+            f"submit_generate() with the default cache")
 
 
 def _check_len(model, max_len):
@@ -173,6 +223,17 @@ def _eos_pad(model, eos_id, pad_id):
     eos = int(eos_id or 0)
     pad = int(pad_id) if pad_id is not None else eos
     return jnp.int32(eos), jnp.int32(pad)
+
+
+def _cast_params(p, compute_dtype):
+    """The parameter tree a generator computes on: every floating leaf
+    in ``compute_dtype`` (None: as held) but the leaves that stay
+    float32 whatever the model computes in (``FLOAT32_LEAVES``: a
+    router's selection bias)."""
+    from ..nn.module import hold_floats
+    from ..parallel.moe import FLOAT32_LEAVES
+
+    return hold_floats(p, compute_dtype, keep=FLOAT32_LEAVES)
 
 
 def _proj(x, params, w, b, with_bias):
@@ -318,22 +379,30 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
     program's :func:`_cache_len`, not the model's ``max_len``: every
     reader of the cache takes its length from its shape.  A block with
     a sliding window keeps ``min(T_cache, window)`` positions (a ring);
-    a parallel block adds ``moe_counts`` ``[B, held]`` int32."""
+    a block with an expert layer adds ``moe_counts`` ``[B, held]``
+    int32.  A LATENT block keeps no K or V: ``ckv`` ``[B, T_cache,
+    kv_rank]`` (the normed latent) and ``kr`` ``[B, T_cache, rope]``
+    (the rotated key all heads share) — no leaf has a head axis."""
+    form, attention, experts = _block_kind(block)
     mha = block.modules[1]
-    Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
-    kv = (B, Hkv, min(T_cache, _window_of(block) or T_cache),
-          mha.head_dim)
-    if kv_int8:
-        cache = {"k": jnp.zeros(kv, jnp.int8),
-                 "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
-                 "v": jnp.zeros(kv, jnp.int8),
-                 "v_scale": jnp.zeros(kv[:3] + (1,), jnp.float32)}
+    if attention == "latent":
+        cache = {"ckv": jnp.zeros((B, T_cache, mha.kv_rank), dt),
+                 "kr": jnp.zeros((B, T_cache, mha.rope_dim), dt)}
     else:
-        cache = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
-    if _is_hybrid(block):
+        Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
+        kv = (B, Hkv, min(T_cache, _window_of(block) or T_cache),
+              mha.head_dim)
+        if kv_int8:
+            cache = {"k": jnp.zeros(kv, jnp.int8),
+                     "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
+                     "v": jnp.zeros(kv, jnp.int8),
+                     "v_scale": jnp.zeros(kv[:3] + (1,), jnp.float32)}
+        else:
+            cache = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
+    if form == "hybrid":
         cache.update(block.mixer.state_init(B, dt))
-    if _is_parallel(block):
-        cache["moe_counts"] = jnp.zeros((B, block.moe.held[1]), jnp.int32)
+    if experts is not None:
+        cache["moe_counts"] = jnp.zeros((B, experts.held[1]), jnp.int32)
     return cache
 
 
@@ -349,7 +418,10 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     ``recurrent_state_bytes`` (SSM state and conv tail; zero for a
     model without them).  Where some layer has a sliding window, K/V
     is also given by KIND of layer: ``kv_cache_bytes_window`` (layers
-    that keep ``min(positions, window)``) and ``kv_cache_bytes_full``."""
+    that keep ``min(positions, window)``) and ``kv_cache_bytes_full``.
+    A model with latent attention also gives ``latent_cache_bytes``
+    (the latent and the shared rotated key of every position; its
+    ``kv_cache_bytes`` is 0)."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -359,6 +431,8 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
            "kv_cache_positions": T_cache}
     blocks = model.modules[first:first + count]
     by_kind = {"kv_cache_bytes_window": 0, "kv_cache_bytes_full": 0}
+    if any(_is_latent(b) for b in blocks):
+        out["latent_cache_bytes"] = 0
     for block in blocks:
         shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
                                         T_cache, dt, _kv_int8(kv_dtype)))
@@ -368,6 +442,8 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                 out["kv_cache_bytes"] += nbytes
                 by_kind["kv_cache_bytes_window" if _window_of(block)
                         else "kv_cache_bytes_full"] += nbytes
+            elif name in ("ckv", "kr"):
+                out["latent_cache_bytes"] += nbytes
             elif name != "moe_counts":      # a counter, not a state
                 out["recurrent_state_bytes"] += nbytes
     if any(_window_of(b) for b in blocks):
@@ -393,8 +469,12 @@ def _decode_machinery(model, first, count, kv_int8=False):
     ln_f = model.modules[first + count]
     head = model.modules[first + count + 1]
     embed = model.modules[0]
+    if kv_int8:
+        _refuse_latent(blocks, 'kv_dtype="int8"',
+                       "K and V by head as int8 with a scale a head")
     mha0 = blocks[0].modules[1]
-    H, Dh = mha0.num_heads, mha0.head_dim
+    # per-head K/V geometry (a latent block has none and uses none)
+    H, Dh = mha0.num_heads, getattr(mha0, "head_dim", None)
     Hkv = getattr(mha0, "num_kv_heads", H)   # GQA: smaller KV caches
     use_rope = getattr(model, "use_rope", False)
     tied = getattr(model, "tied_head", False)
@@ -471,10 +551,73 @@ def _decode_machinery(model, first, count, kv_int8=False):
                     cache["v"].astype(dt) * cache["v_scale"].astype(dt))
         return cache["k"], cache["v"]
 
+    def _latent_attention(mla, ap, ln1, cache, pos):
+        """Latent attention of Tq tokens at ``pos`` against the cache of
+        ``ckv`` / ``kr``.  Prefill EXPANDS the prompt's latent to
+        per-head K and V once and runs causal (flash) attention at the
+        full head size.  A decode step never expands the cache: the
+        key half of ``wkv_b`` is absorbed into the query (``q_lat =
+        q_nope W_uk``), scores and the weighted sum are taken on the
+        latent itself, and the value half is applied to the ONE
+        resulting latent a head (``o = o_lat W_uv``) — algebraically the
+        same, ``kv_rank + rope`` numbers a cached position read instead
+        of ``heads * (qk + v)`` made.  The only array with both a head
+        and a cached-position axis is the scores."""
+        Tq = ln1.shape[1]
+        qpos = pos + jnp.arange(Tq)
+        with jax.named_scope("mla.q_proj"):
+            q_nope, q_rope = mla.queries(ap, ln1, qpos)
+        with jax.named_scope("mla.kv_latent"):
+            ckv, kr = mla.latent(ap, ln1, qpos)
+            cache = {**cache,
+                     "ckv": lax.dynamic_update_slice(
+                         cache["ckv"], ckv.astype(cache["ckv"].dtype),
+                         (0, pos, 0)),
+                     "kr": lax.dynamic_update_slice(
+                         cache["kr"], kr.astype(cache["kr"].dtype),
+                         (0, pos, 0))}
+        if isinstance(pos, int) and pos == 0:
+            with jax.named_scope("mla.expand"):
+                k, v = mla.expand(ap, ckv, kr)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            if v.shape[-1] == q.shape[-1]:
+                from ..ops.flash_attention import flash_attention
+
+                o = flash_attention(q, k, v, causal=True)
+            else:           # the kernels take one head size
+                from ..parallel.ring_attention import attention
+
+                o = attention(q, k, v, causal=True)
+        else:
+            w_uk, w_uv = mla.up_weights(ap)
+            dt = q_nope.dtype
+            with jax.named_scope("mla.absorb"):
+                q_lat = jnp.einsum("bhqn,hnc->bhqc", q_nope,
+                                   w_uk.astype(dt))
+            with jax.named_scope("mla.attend"):
+                c_all, r_all = cache["ckv"], cache["kr"]
+                ct = jnp.promote_types(dt, jnp.float32)
+                scores = (jnp.einsum("bhqc,bkc->bhqk", q_lat, c_all,
+                                     preferred_element_type=ct)
+                          + jnp.einsum("bhqr,bkr->bhqk", q_rope, r_all,
+                                       preferred_element_type=ct))
+                scores = scores / jnp.sqrt(jnp.asarray(mla.qk_dim, ct))
+                seen = jnp.arange(c_all.shape[1])[None, :] <= qpos[:, None]
+                scores = jnp.where(seen[None, None], scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                o_lat = jnp.einsum("bhqk,bkc->bhqc", probs.astype(dt),
+                                   c_all)
+            with jax.named_scope("mla.absorb"):
+                o = jnp.einsum("bhqc,hvc->bhqv", o_lat, w_uv.astype(dt))
+        with jax.named_scope("mla.out_proj"):
+            return mla.out_proj(ap, o), cache
+
     def _attention(block, ap, ln1, cache, pos):
         """Cached attention of one block on Tq tokens at ``pos``;
         returns (the output projection's result, cache)."""
         mha = block.modules[1]
+        if _is_latent(block):
+            return _latent_attention(mha, ap, ln1, cache, pos)
         B = ln1.shape[0]
         q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
@@ -527,7 +670,22 @@ def _decode_machinery(model, first, count, kv_int8=False):
         runs the chunked scan from an empty state and keeps the state
         after the last prompt token, a decode step advances it."""
         ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
-        if _is_parallel(block):
+        form, attention, experts = _block_kind(block)
+        if form == "sequential" and attention == "latent":
+            with jax.named_scope("block.attention"):
+                a, cache = _attention(block, bp["1"], ln1, cache, pos)
+            h = h + a
+            ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
+            if experts is None:
+                m, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
+                                                 None)
+                return h + m, cache
+            B, Tq, D = ln2.shape
+            m, counts = experts.routed(bp["3"], ln2.reshape(B * Tq, D),
+                                       batch=B)
+            return (h + m.reshape(B, Tq, D),
+                    {**cache, "moe_counts": cache["moe_counts"] + counts})
+        if form == "parallel":
             # attention and the expert layer read the SAME normed input
             with jax.named_scope("block.attention"):
                 a, cache = _attention(block, bp["1"], ln1, cache, pos)
@@ -536,7 +694,7 @@ def _decode_machinery(model, first, count, kv_int8=False):
                                          batch=B)
             return (h + a + m.reshape(B, Tq, D),
                     {**cache, "moe_counts": cache["moe_counts"] + counts})
-        if not _is_hybrid(block):
+        if form == "sequential":
             a, cache = _attention(block, bp["1"], ln1, cache, pos)
             return _ffn_sublayer(block, bp, h + a), cache
         with jax.named_scope("mixer.attention"):
@@ -618,18 +776,17 @@ def make_generate(model, max_len: Optional[int] = None,
     the host from the call's own numbers (a greedy call ignores
     ``top_k`` / ``top_p``: one program); the decode loop itself is a
     scan — no per-token dispatch.  ``return_stats=True`` returns
-    ``(ids, stats)``: for a model with parallel expert blocks ``stats``
-    holds ``moe_counts`` ``[layers, held]`` int32, the assignments each
-    held expert took in the call (fetched with the tokens); for any
-    other model it is empty.
+    ``(ids, stats)``: for a model with dropless expert layers ``stats``
+    holds ``moe_counts`` ``[expert layers, held]`` int32, the
+    assignments each held expert took in the call (fetched with the
+    tokens; a dense layer among them has no row); for any other model
+    it is empty.
     """
-    from ..optim.optimizer import _cast_floats
-
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
-    counted = any(_is_parallel(b)
+    counted = any(_block_kind(b)[2] is not None
                   for b in model.modules[first:first + count])
 
     # device scopes (``jax.named_scope``): metadata on the HLO
@@ -668,7 +825,7 @@ def make_generate(model, max_len: Optional[int] = None,
     def _run(p, prompt, max_new, key, temperature, top_k, top_p,
              eos, pad, greedy, nucleus):
         with jax.named_scope("generate.cast_params"):
-            pc = _cast_floats(p, compute_dtype) if compute_dtype else p
+            pc = _cast_params(p, compute_dtype)
         B, T0 = prompt.shape
         T_cache = _cache_len(T_max, T0, max_new)
         dt = (compute_dtype
@@ -770,8 +927,6 @@ def make_beam_search(model, max_len: Optional[int] = None,
     to hold every prefix it IS exhaustive search (the oracle test pins
     that, with and without eos).  Shares :func:`_decode_machinery` with
     the sampling decoder."""
-    from ..optim.optimizer import _cast_floats
-
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
@@ -779,7 +934,7 @@ def make_beam_search(model, max_len: Optional[int] = None,
 
     @partial(jax.jit, static_argnums=(2, 3))
     def _run(p, prompt, max_new, kk, eos, pad):
-        pc = _cast_floats(p, compute_dtype) if compute_dtype else p
+        pc = _cast_params(p, compute_dtype)
         B, T0 = prompt.shape
         T_cache = _cache_len(T_max, T0, max_new)
         dt = (compute_dtype
@@ -915,6 +1070,8 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
 
     def _qkv(block, ap, ln1, pos_ids):
         mha = block.modules[1]
+        if _is_latent(block):
+            return _latent_attention(mha, ap, ln1, cache, pos)
         B = ln1.shape[0]
         q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)

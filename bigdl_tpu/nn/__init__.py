@@ -66,7 +66,7 @@ from .criterion import (
     SmoothL1CriterionWithWeights, SoftMarginCriterion, SoftmaxWithCriterion,
     TimeDistributedCriterion,
 )
-from .attention import MultiHeadAttention
+from .attention import LatentAttention, MultiHeadAttention
 from .mamba import HybridMambaBlock, Mamba2Mixer
 from .recurrent import (
     BiRecurrent, Cell, ConvLSTMPeephole, GRU, LSTM, LSTMPeephole, Recurrent,

@@ -14,8 +14,9 @@ from jax import lax
 
 from ..parallel.ring_attention import (attention, blockwise_attention,
                                        ring_attention, ulysses_attention)
-from .initialization import IN_OUT, ONE_D, Xavier, Zeros
+from .initialization import IN_OUT, ONE_D, RandomNormal, Xavier, Zeros
 from .module import TensorModule
+from .normalization import rms_normed
 
 SEQ_STRATEGIES = ("dense", "flash", "block", "ring", "ulysses",
                   "blocksparse")
@@ -262,3 +263,134 @@ class MultiHeadAttention(TensorModule):
         B, H, T, D = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
         return proj(o, params["wo"], "bo"), buffers
+
+
+class LatentAttention(TensorModule):
+    """Multi-head LATENT attention (DeepSeek-V2's MLA; GLM-4.7-Flash's
+    ``glm4_moe_lite``) over [batch, seq, embed]: queries and keys/values
+    go through low-rank bottlenecks, and the rotated part of the key is
+    ONE vector a position shared by all heads.
+
+        c_q  = RMSNorm_q(x wq_a)                    [q_rank]
+        q    = c_q wq_b  -> [H, nope + rope] = q_nope | q_rope
+        c, k_r = x wkv_a -> [kv_rank] | [rope]
+        c_kv = RMSNorm_kv(c);  k_rope = RoPE(k_r);  q_rope = RoPE(q_rope)
+        k_nope | v = c_kv wkv_b -> [H, nope] | [H, v_dim]
+        scores = (q_nope k_nope + q_rope k_rope) / sqrt(nope + rope)
+
+    Seven leaves, all [out, in] but the two gains: ``wq_a``, ``q_norm``,
+    ``wq_b``, ``wkv_a``, ``kv_norm``, ``wkv_b`` (per head ``nope`` rows
+    of keys, then ``v_dim`` rows of values), ``wo``.  Rotation is by
+    halves over the ``rope`` dims at ``rope_theta``.
+
+    ``apply_fn`` is the EXPANDED full-sequence form (per-head K and V
+    made from the latent, then causal flash or dense attention) — the
+    training and prefill form, differentiable by autodiff.  What a
+    decode step keeps and reads is ``c_kv`` and the rotated ``k_rope``
+    (``kv_rank + rope`` numbers a position), with ``wkv_b`` absorbed
+    into the query and the output: ``models/generate.py``."""
+
+    kind = "latent"
+
+    def __init__(self, embed_dim: int, num_heads: int, q_rank: int,
+                 kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                 seq_strategy: str = "dense",
+                 init_std: "float | None" = None):
+        super().__init__()
+        # matrices drawn normal(0, init_std); None: Xavier, as the
+        # other attention draws (an init method set later wins)
+        self.init_std = init_std
+        if seq_strategy not in ("dense", "flash"):
+            raise ValueError(f"seq_strategy {seq_strategy!r} not in "
+                             "('dense', 'flash')")
+        if seq_strategy == "flash" and v_dim != nope_dim + rope_dim:
+            raise ValueError("the flash kernels take one head size: "
+                             f"v_dim {v_dim} != {nope_dim} + {rope_dim}")
+        if rope_dim % 2:
+            raise ValueError(f"rope_dim {rope_dim} is odd")
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.qk_dim = nope_dim + rope_dim
+        self.rope_theta, self.norm_eps = float(rope_theta), float(norm_eps)
+        self.seq_strategy = seq_strategy
+        self.reset()
+
+    def reset(self):
+        default = (Xavier() if self.init_std is None
+                   else RandomNormal(0.0, float(self.init_std)))
+        w_init = self._init_methods.get("weight", (default, None))[0]
+        E, H = self.embed_dim, self.num_heads
+        for name, shape in (
+                ("wq_a", (self.q_rank, E)),
+                ("wq_b", (H * self.qk_dim, self.q_rank)),
+                ("wkv_a", (self.kv_rank + self.rope_dim, E)),
+                ("wkv_b", (H * (self.nope_dim + self.v_dim), self.kv_rank)),
+                ("wo", (E, H * self.v_dim))):
+            self._register_param(name, w_init.init(shape, IN_OUT))
+        for name, n in (("q_norm", self.q_rank), ("kv_norm", self.kv_rank)):
+            self._register_param(name, jnp.ones((n,), jnp.float32))
+        return self
+
+    # -- the pieces the cached decoder shares with ``apply_fn`` ---------
+    def queries(self, params, x, pos):
+        """(q_nope [B, H, T, nope], q_rope [B, H, T, rope] rotated at
+        ``pos`` [T])."""
+        B, T, _ = x.shape
+        cq = rms_normed(jnp.dot(x, params["wq_a"].T), params["q_norm"],
+                        self.norm_eps)
+        q = jnp.dot(cq, params["wq_b"].T).reshape(
+            B, T, self.num_heads, self.qk_dim).transpose(0, 2, 1, 3)
+        return (q[..., :self.nope_dim],
+                rope_rotate(q[..., self.nope_dim:], pos, self.rope_theta))
+
+    def latent(self, params, x, pos):
+        """What the cache keeps of ``x`` at ``pos`` [T]: (c_kv [B, T,
+        kv_rank] normed, k_rope [B, T, rope] rotated)."""
+        ckr = jnp.dot(x, params["wkv_a"].T)
+        ckv = rms_normed(ckr[..., :self.kv_rank], params["kv_norm"],
+                         self.norm_eps)
+        kr = rope_rotate(ckr[:, None, :, self.kv_rank:], pos,
+                         self.rope_theta)[:, 0]
+        return ckv, kr
+
+    def up_weights(self, params):
+        """``wkv_b`` by head: (W_uk [H, nope, kv_rank], W_uv [H, v_dim,
+        kv_rank])."""
+        w = params["wkv_b"].reshape(self.num_heads,
+                                    self.nope_dim + self.v_dim, self.kv_rank)
+        return w[:, :self.nope_dim], w[:, self.nope_dim:]
+
+    def expand(self, params, ckv, kr):
+        """Per-head (k [B, H, T, nope + rope], v [B, H, T, v_dim]) from
+        the latent — the full-sequence form only."""
+        B, T, _ = ckv.shape
+        H = self.num_heads
+        kv = jnp.dot(ckv, params["wkv_b"].T).reshape(
+            B, T, H, self.nope_dim + self.v_dim).transpose(0, 2, 1, 3)
+        k = jnp.concatenate(
+            [kv[..., :self.nope_dim],
+             jnp.broadcast_to(kr[:, None], (B, H, T, self.rope_dim))], -1)
+        return k, kv[..., self.nope_dim:]
+
+    def attend_full(self, q_nope, q_rope, k, v):
+        """Causal attention of the whole sequence on per-head K and V."""
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        if self.seq_strategy == "flash":
+            from ..ops import flash_attention
+
+            return flash_attention(q, k, v, causal=True)
+        return attention(q, k, v, causal=True)
+
+    def out_proj(self, params, o):
+        B, H, T, D = o.shape
+        return jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, H * D),
+                       params["wo"].T)
+
+    def _apply(self, params, buffers, x, training, rng):
+        pos = jnp.arange(x.shape[1])
+        q_nope, q_rope = self.queries(params, x, pos)
+        k, v = self.expand(params, *self.latent(params, x, pos))
+        return self.out_proj(params, self.attend_full(q_nope, q_rope, k,
+                                                      v)), buffers

@@ -55,17 +55,24 @@ def to_array(x):
     return x
 
 
-def hold_floats(tree, dtype):
+def hold_floats(tree, dtype, keep=()):
     """``tree`` with every floating leaf in ``dtype`` — one cast per
     leaf that is not there yet, none for one that is, so the tree and
-    its copy never both exist whole.  ``dtype`` None: ``tree`` itself."""
+    its copy never both exist whole.  ``dtype`` None: ``tree`` itself.
+    A leaf whose own name (the last key of its path) is in ``keep``
+    stays as it is."""
     if dtype is None:
         return tree
     dtype = jnp.dtype(dtype)
-    return jax.tree_util.tree_map(
-        lambda a: a.astype(dtype)
-        if jnp.issubdtype(a.dtype, jnp.floating) and a.dtype != dtype
-        else a, tree)
+
+    def held(path, a):
+        if (not jnp.issubdtype(jnp.result_type(a), jnp.floating)
+                or a.dtype == dtype
+                or (keep and getattr(path[-1], "key", None) in keep)):
+            return a
+        return a.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(held, tree)
 
 
 class AbstractModule:

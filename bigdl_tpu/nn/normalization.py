@@ -284,6 +284,18 @@ class LayerNorm(TensorModule):
         return (x - mean) * lax.rsqrt(var + self.eps), buffers
 
 
+def rms_normed(x, weight, eps: float):
+    """``RMSNorm``'s arithmetic on a bare gain (a norm inside another
+    module, as latent attention's two): at-LEAST float32 statistics
+    (bf16 upcasts, f64 oracles keep their precision) — the HF convention
+    for low-precision inputs — cast back to ``x``'s dtype BEFORE the gain
+    multiplies."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    normed = (xf * lax.rsqrt(var + eps)).astype(x.dtype)
+    return normed * weight.astype(x.dtype)
+
+
 class RMSNorm(TensorModule):
     """Root-mean-square normalization over the last dimension (the
     Llama-family norm): ``x * rsqrt(mean(x²) + eps) * weight`` — no
@@ -309,12 +321,7 @@ class RMSNorm(TensorModule):
         return self
 
     def _apply(self, params, buffers, x, training, rng):
-        # at-LEAST float32 statistics (bf16 upcasts, f64 oracles keep
-        # their precision) — the HF convention for low-precision inputs
-        xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        normed = (xf * lax.rsqrt(var + self.eps)).astype(x.dtype)
-        return normed * params["weight"].astype(x.dtype), buffers
+        return rms_normed(x, params["weight"], self.eps), buffers
 
 
 class ImageNormalize(TensorModule):

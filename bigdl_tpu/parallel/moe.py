@@ -260,6 +260,11 @@ class MoEFFN(TensorModule):
 
 SCORINGS = ("softmax", "sigmoid")
 
+#: leaves that stay float32 where a model holds or computes in a lower
+#: precision (``hold_floats`` / the generator's cast leave them alone):
+#: the selection bias is compared with float32 scores
+FLOAT32_LEAVES = ("score_bias",)
+
 #: rows of the sorted buffer one dispatch may hold: a longer token list
 #: is routed at once and dispatched in equal pieces, so the
 #: worst-case buffers of a long prefill stay a few hundred megabytes
@@ -269,12 +274,17 @@ MAX_UNROLLED_PIECES = 32
 
 
 def route_top_k(x2, router_w, router_b, top_k: int,
-                scoring: str = "softmax", renormalize: bool = True):
+                scoring: str = "softmax", renormalize: bool = True,
+                select_bias=None, gate_scale: float = 1.0):
     """Scores over ALL experts in float32 from ``x2`` [N, D] (whatever
     dtype it has: the product accumulates in float32), the ``top_k``
     largest, and their gates — the scores themselves, or divided by
-    their sum under ``renormalize``.  -> (gates [N, K] in at least
-    float32, idx [N, K])."""
+    their sum under ``renormalize``.  ``select_bias`` [E] (the
+    ``noaux_tc`` router's correction) is added to the scores for the
+    SELECTION only: the gates are the unbiased scores of the chosen
+    experts, renormalised with the reference's ``1e-20`` in the sum.
+    ``gate_scale`` multiplies the gates last.  -> (gates [N, K] in at
+    least float32, idx [N, K])."""
     with jax.named_scope("moe.route"):
         # at least float32 (a float64 oracle keeps its precision)
         ct = jnp.promote_types(x2.dtype, jnp.float32)
@@ -288,9 +298,18 @@ def route_top_k(x2, router_w, router_b, top_k: int,
             scores = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"scoring {scoring!r} not in {SCORINGS}")
-        gates, idx = lax.top_k(scores, top_k)
-        if renormalize:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if select_bias is None:
+            gates, idx = lax.top_k(scores, top_k)
+            if renormalize:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        else:
+            _, idx = lax.top_k(scores + select_bias.astype(ct), top_k)
+            gates = jnp.take_along_axis(scores, idx, axis=-1)
+            if renormalize:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                                 + 1e-20)
+        if gate_scale != 1.0:
+            gates = gates * gate_scale
     return gates, idx
 
 
@@ -465,7 +484,11 @@ class DroplessMoE(TensorModule):
     is set.  ``n_shared`` shared experts of the same shape see
     every token; their MEAN is added (``shared_gate`` / ``shared_up``
     [n_shared, embed, hidden], ``shared_down`` [n_shared, hidden,
-    embed]).
+    embed]).  ``score_bias`` adds the leaf ``score_bias`` [n_experts],
+    float32 whatever dtype the others are held in
+    (:data:`FLOAT32_LEAVES`), zeros until given: it enters the selection
+    only (:func:`route_top_k`'s ``select_bias``); ``routed_scale``
+    multiplies the gates.
 
     Dispatch (:func:`dropless_apply`): assignments to held experts
     sorted by expert, one grouped matrix product per projection, a
@@ -478,9 +501,12 @@ class DroplessMoE(TensorModule):
     def __init__(self, embed_dim: int, hidden_dim: int, n_experts: int,
                  top_k: int = 2, scoring: str = "softmax",
                  renormalize: bool = True, n_shared: int = 0,
-                 held: Optional[tuple] = None, init_std: float = 0.02):
+                 held: Optional[tuple] = None, init_std: float = 0.02,
+                 score_bias: bool = False, routed_scale: float = 1.0):
         super().__init__()
         self.init_std = float(init_std)
+        self.score_bias = bool(score_bias)
+        self.routed_scale = float(routed_scale)
         if scoring not in SCORINGS:
             raise ValueError(f"scoring {scoring!r} not in {SCORINGS}")
         if not 1 <= top_k <= n_experts:
@@ -518,6 +544,9 @@ class DroplessMoE(TensorModule):
             self._register_param("shared_gate", stack(self.n_shared, D, F))
             self._register_param("shared_up", stack(self.n_shared, D, F))
             self._register_param("shared_down", stack(self.n_shared, F, D))
+        if self.score_bias:
+            self._register_param("score_bias",
+                                 jnp.zeros((self.n_experts,), jnp.float32))
         return self
 
     def shared(self, params, x2):
@@ -541,7 +570,9 @@ class DroplessMoE(TensorModule):
         assignments each held expert took: [count] int32, or [batch,
         count] — by leading row of the ``batch`` the tokens came in)."""
         gates, idx = route_top_k(x2, params["router_w"], None, self.top_k,
-                                 self.scoring, self.renormalize)
+                                 self.scoring, self.renormalize,
+                                 params.get("score_bias"),
+                                 self.routed_scale)
         y, sizes = dropless_apply(
             x2, idx, gates, self.held,
             swiglu_experts(params["w_gate"], params["w_up"],

@@ -108,7 +108,8 @@ class _BoundedQueue:
 
 def moe_counters(counts, tokens_per_layer: int) -> dict:
     """The ``serve.fetch`` arguments of a generate call that routed over
-    experts, from its ``[layers, held]`` assignment counts:
+    experts, from its ``[layers, held]`` assignment counts — a row for
+    each layer that HAS experts (a leading dense layer carries none):
     ``moe_assignments`` (total to the held experts), ``moe_tokens``
     (tokens routed over, counted once a layer) and
     ``moe_load_max_over_mean`` (a layer's busiest held expert over its

@@ -123,7 +123,16 @@ PROGRAM_SPANS = {
 #: the gather into the sorted buffer, ``moe.expert_matmul`` the grouped
 #: products and the gate, ``moe.combine`` the weighted gather back,
 #: ``moe.shared`` the shared experts; ``block.attention`` the attention
-#: branch of a parallel block (``models/parallel_moe.py``).
+#: branch of a parallel block (``models/parallel_moe.py``) or of a
+#: latent block (``models/latent_moe.py``).  ``mla.*`` the parts of
+#: latent attention inside ``block.attention``: ``mla.q_proj`` the
+#: query's two projections, norm and rotation, ``mla.kv_latent`` the
+#: latent, its norm, the rotated shared key and the cache write,
+#: ``mla.expand`` per-head K and V from the latent (prefill only),
+#: ``mla.absorb`` the absorbed products of a decode step (``q_nope ->
+#: q_lat`` and ``o_lat -> o``), ``mla.attend`` scores, softmax and the
+#: weighted sum over the cached latent, ``mla.out_proj`` the output
+#: projection.
 DEVICE_SCOPES = (
     "generate.cast_params", "generate.prefill", "generate.decode_step",
     "generate.sample",
@@ -131,6 +140,8 @@ DEVICE_SCOPES = (
     "mixer.gate_norm", "mixer.out_proj", "mixer.attention",
     "moe.route", "moe.dispatch", "moe.expert_matmul", "moe.combine",
     "moe.shared", "block.attention",
+    "mla.q_proj", "mla.kv_latent", "mla.expand", "mla.absorb",
+    "mla.attend", "mla.out_proj",
 )
 
 

@@ -23,6 +23,7 @@ import pytest
 from jax import enable_x64
 
 from bigdl_tpu import nn
+from bigdl_tpu.models.latent_moe import LatentMoEBlock
 from bigdl_tpu.models.parallel_moe import ParallelMoEBlock
 from bigdl_tpu.parallel.moe import DroplessMoE
 from bigdl_tpu.utils.table import T, Table
@@ -311,6 +312,18 @@ MODULE_CASES = {
         8, num_heads=2, num_kv_heads=1, head_dim=4, expert_dim=12,
         n_experts=6, top_k=2, attention="sliding", window=3, n_shared=1,
         held=(0, 3), init_std=0.3), lambda: X8, {}),
+    # latent attention (the expanded form: two low-rank paths, a shared
+    # rotated key) and the sequential block around it with the
+    # bias-corrected router (the bias chooses: its gradient is zero)
+    "LatentAttention": (lambda: nn.LatentAttention(
+        8, 2, q_rank=6, kv_rank=6, nope_dim=4, rope_dim=2, v_dim=6),
+        lambda: X8, {}),
+    "LatentMoEBlock": (lambda: LatentMoEBlock(
+        nn.LatentAttention(8, 2, q_rank=6, kv_rank=6, nope_dim=4,
+                           rope_dim=2, v_dim=6),
+        DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", n_shared=1,
+                    held=(0, 3), init_std=0.3, score_bias=True,
+                    routed_scale=1.8), 8, 1e-5), lambda: X8, {}),
     "Narrow": (lambda: nn.Narrow(2, 2, 3), lambda: X, {}),
     "NarrowTable": (lambda: nn.NarrowTable(1, 2),
                     lambda: T(X, X2, XP), {}),

@@ -53,6 +53,10 @@ def _fwd_bwd(grid, sub):
     pytest.param((1, 16, 2048, 64), _fwd_bwd(512, 128), 3, id="bwd_grid"),
     # the decode cells' prompt: one sub-tile or less
     pytest.param((16, 32, 128, 128), _fwd(None, None), 1, id="prompt"),
+    # glm47flash_serve_decode_sat: latent attention expanded to per-head
+    # K and V in prefill — 256 x 20 heads, prompt 128, head 256
+    pytest.param((256, 20, 128, 256), _fwd(None, None), 1,
+                 id="latent_prompt"),
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, shape, fn, calls):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)

@@ -124,6 +124,35 @@ def main():
             "  `submit_generate` keep a cache per layer of that layer's own",
             "  length (`min(T_cache, window)` for a sliding layer);",
             "  `PagedDecoder` refuses it.",
+            "- **`nn.LatentAttention`** (PR 36): multi-head LATENT attention",
+            "  (MLA) — `q` and K/V through low-rank bottlenecks (`q_rank`,",
+            "  `kv_rank`, each with an RMSNorm), the rotated part of the",
+            "  key (`rope_dim`) ONE vector a position shared by all heads,",
+            "  a query/key head of `nope_dim + rope_dim` and a value head",
+            "  of `v_dim`.  Seven leaves: `wq_a`, `q_norm`, `wq_b`,",
+            "  `wkv_a`, `kv_norm`, `wkv_b`, `wo`.  `apply_fn` is the",
+            "  EXPANDED full-sequence form (dense or flash causal",
+            "  attention), differentiable by autodiff.",
+            "- **`models.latent_moe.LatentMoELM`** (PR 36; `param_dtype` and",
+            "  the device draw): a `Container` of SEQUENTIAL pre-norm",
+            "  blocks (`LatentMoEBlock`: RMSNorm, `LatentAttention`,",
+            "  RMSNorm, FFN) whose FFN is a dense SwiGLU (`GatedFFN`) for",
+            "  the first `first_dense` layers and a `DroplessMoE` after",
+            "  them (`score_bias=True`, `routed_scale`), with an untied",
+            "  head that gives float32 logits (`LogitHead`).",
+            "  `generate()` / `submit_generate` keep the latent and the",
+            "  shared rotated key of every position (`ckv`, `kr`) and",
+            "  nothing by head: prefill expands them once, a decode step",
+            "  absorbs `wkv_b` into the query and the output.",
+            "  `PagedDecoder` and `kv_dtype=\"int8\"` refuse it by name.",
+            "- **`parallel.moe.route_top_k(..., select_bias=None,",
+            "  gate_scale=1.0)`** / **`DroplessMoE(score_bias=False,",
+            "  routed_scale=1.0)`**: the `noaux_tc` router — a per-expert",
+            "  bias (leaf `score_bias`, float32 whatever the model holds",
+            "  or computes in: `parallel.moe.FLOAT32_LEAVES`) added to the",
+            "  scores for the SELECTION only; the gates are the unbiased",
+            "  scores of the chosen, renormalised, times `routed_scale`.",
+            "  The defaults are the router as it was.",
             "- **`parallel.moe.DroplessMoE`**: scores ALL `n_experts`",
             "  (`softmax` or `sigmoid`, float32), keeps `top_k`, and",
             "  computes the part of the mixture the experts it HOLDS give",
