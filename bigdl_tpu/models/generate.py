@@ -39,13 +39,20 @@ returns beside the tokens on request (``return_stats=True``).
 
 A block whose attention is LATENT (``nn.LatentAttention``;
 ``models/latent_moe.py``) keeps ``ckv`` ``[B, T_cache, kv_rank]`` and
-``kr`` ``[B, T_cache, rope]`` — the normed latent and the one rotated
-key all heads share — and has TWO attention paths: prefill expands the
-prompt's latent to per-head K and V once and runs causal (flash)
-attention; a decode step absorbs ``wkv_b`` into the query and the
-output and attends on the latent itself, so that nothing with both a
-head and a cached-position axis exists but the scores.  What a block is
-made of is decided in one place (:func:`_block_kind`).
+``kr`` ``[B, rope, T_cache]`` — the normed latent and the one rotated
+key all heads share, the key with positions minor (``rope`` is half a
+lane tile) — and has TWO attention paths: prefill expands the prompt's
+latent to per-head K and V once and runs causal (flash) attention; a
+decode step absorbs ``wkv_b`` into the query and the output and attends
+on the latent itself, so that nothing with both a head and a
+cached-position axis exists but the scores.  That attend has two arms,
+chosen by shapes alone (``ops/latent_attend.py``, ``attend_plan``):
+where a layer's cache is large, on a TPU, ONE Pallas kernel walks the
+cache in blocks up to the block the step's position falls in — each
+block read once for scores, softmax and ``P c_kv``, nothing beyond it
+fetched; everywhere else the plain einsums read the whole static cache
+twice.  What a block is made of is decided in one place
+(:func:`_block_kind`).
 
 Built from the model's OWN parameter tree and modules (the
 parallel/pipeline.py pattern): LN/MLP sublayers run through their
@@ -381,13 +388,16 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
     a sliding window keeps ``min(T_cache, window)`` positions (a ring);
     a block with an expert layer adds ``moe_counts`` ``[B, held]``
     int32.  A LATENT block keeps no K or V: ``ckv`` ``[B, T_cache,
-    kv_rank]`` (the normed latent) and ``kr`` ``[B, T_cache, rope]``
-    (the rotated key all heads share) — no leaf has a head axis."""
+    kv_rank]`` (the normed latent) and ``kr`` ``[B, rope, T_cache]``
+    (the rotated key all heads share; positions minor, so that no
+    position's ``rope`` numbers are padded to a lane tile and the
+    attend's kernel reads what the leaf holds) — no leaf has a head
+    axis, and a position holds ``kv_rank + rope`` numbers."""
     form, attention, experts = _block_kind(block)
     mha = block.modules[1]
     if attention == "latent":
         cache = {"ckv": jnp.zeros((B, T_cache, mha.kv_rank), dt),
-                 "kr": jnp.zeros((B, T_cache, mha.rope_dim), dt)}
+                 "kr": jnp.zeros((B, mha.rope_dim, T_cache), dt)}
     else:
         Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
         kv = (B, Hkv, min(T_cache, _window_of(block) or T_cache),
@@ -420,8 +430,13 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     is also given by KIND of layer: ``kv_cache_bytes_window`` (layers
     that keep ``min(positions, window)``) and ``kv_cache_bytes_full``.
     A model with latent attention also gives ``latent_cache_bytes``
-    (the latent and the shared rotated key of every position; its
-    ``kv_cache_bytes`` is 0)."""
+    (the latent and the shared rotated key of every position, as
+    allocated; its ``kv_cache_bytes`` is 0) and which arm of the
+    absorbed attend this program's decode step compiled:
+    ``latent_attend`` (``"kernel"`` or ``"einsum"``) and
+    ``latent_attend_block`` (positions a block of the kernel's walk; 0
+    for the einsums) — ``ops.latent_attend.attend_plan``, the rule the
+    step itself reads."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -448,6 +463,14 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                 out["recurrent_state_bytes"] += nbytes
     if any(_window_of(b) for b in blocks):
         out.update(by_kind)
+    if "latent_cache_bytes" in out:
+        from ..ops.latent_attend import attend_plan
+
+        mla = next(b.modules[1] for b in blocks if _is_latent(b))
+        block = attend_plan(int(batch), T_cache, mla.kv_rank,
+                            mla.rope_dim, dt)
+        out.update(latent_attend="kernel" if block else "einsum",
+                   latent_attend_block=block)
     return out
 
 
@@ -574,8 +597,9 @@ def _decode_machinery(model, first, count, kv_int8=False):
                          cache["ckv"], ckv.astype(cache["ckv"].dtype),
                          (0, pos, 0)),
                      "kr": lax.dynamic_update_slice(
-                         cache["kr"], kr.astype(cache["kr"].dtype),
-                         (0, pos, 0))}
+                         cache["kr"],
+                         kr.astype(cache["kr"].dtype).transpose(0, 2, 1),
+                         (0, 0, pos))}
         if isinstance(pos, int) and pos == 0:
             with jax.named_scope("mla.expand"):
                 k, v = mla.expand(ap, ckv, kr)
@@ -595,18 +619,13 @@ def _decode_machinery(model, first, count, kv_int8=False):
                 q_lat = jnp.einsum("bhqn,hnc->bhqc", q_nope,
                                    w_uk.astype(dt))
             with jax.named_scope("mla.attend"):
-                c_all, r_all = cache["ckv"], cache["kr"]
-                ct = jnp.promote_types(dt, jnp.float32)
-                scores = (jnp.einsum("bhqc,bkc->bhqk", q_lat, c_all,
-                                     preferred_element_type=ct)
-                          + jnp.einsum("bhqr,bkr->bhqk", q_rope, r_all,
-                                       preferred_element_type=ct))
-                scores = scores / jnp.sqrt(jnp.asarray(mla.qk_dim, ct))
-                seen = jnp.arange(c_all.shape[1])[None, :] <= qpos[:, None]
-                scores = jnp.where(seen[None, None], scores, -jnp.inf)
-                probs = jax.nn.softmax(scores, axis=-1)
-                o_lat = jnp.einsum("bhqk,bkc->bhqc", probs.astype(dt),
-                                   c_all)
+                # one pass over the written part of the cache where the
+                # shapes say the kernel wins, the plain einsums over the
+                # whole of it otherwise (``ops.latent_attend.attend_plan``)
+                from ..ops.latent_attend import latent_attend
+
+                o_lat = latent_attend(q_lat, q_rope, cache["ckv"],
+                                      cache["kr"], pos, mla.qk_dim)
             with jax.named_scope("mla.absorb"):
                 o = jnp.einsum("bhqc,hvc->bhqv", o_lat, w_uv.astype(dt))
         with jax.named_scope("mla.out_proj"):
@@ -1070,8 +1089,6 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
 
     def _qkv(block, ap, ln1, pos_ids):
         mha = block.modules[1]
-        if _is_latent(block):
-            return _latent_attention(mha, ap, ln1, cache, pos)
         B = ln1.shape[0]
         q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)

@@ -171,15 +171,24 @@ def test_the_cache_holds_the_latent_and_one_rotated_key_a_position():
     _, caches = _decode_logits(model, ids, 19)
     heads, T_cache = CFG["num_attention_heads"], 64   # min(max_len, 128)
     for i, cache in enumerate(caches):
-        want = {"ckv": (2, T_cache, RANK), "kr": (2, T_cache, ROPE)}
+        # the shared key with positions MINOR: ``rope`` is half a lane
+        # tile, so [B, T, rope] would pad every position to twice its
+        # bytes where a kernel reads it (ops/latent_attend.py)
+        want = {"ckv": (2, T_cache, RANK), "kr": (2, ROPE, T_cache)}
         if i >= CFG["first_k_dense_replace"]:
             want["moe_counts"] = (2, CFG["n_routed_experts"])
         assert {k: v.shape for k, v in cache.items()} == want
         # no leaf has a head axis: nothing is kept by head
-        assert all(a.ndim <= 3 and heads not in a.shape[1:-1]
-                   for a in cache.values())
+        assert all(a.ndim <= 3 for a in cache.values())
+        assert heads not in cache["ckv"].shape[1:-1]
+        # (the toy's rotated key is as wide as the toy has heads)
+        assert cache["kr"].shape[1:] == (ROPE, T_cache)
     foot = G.cache_footprint(model, 2, 19, 5)
     assert foot["kv_cache_positions"] == T_cache
+    # what a position HOLDS is rank + rope numbers, whatever the layout;
+    # on the CPU a decode step attends by the plain einsums
+    assert (foot["latent_attend"], foot["latent_attend_block"]) == (
+        "einsum", 0)
     assert foot["latent_cache_bytes"] == LAYERS * 2 * T_cache * (RANK
                                                                  + ROPE) * 4
     assert foot["kv_cache_bytes"] == 0 and foot["recurrent_state_bytes"] == 0
@@ -360,6 +369,12 @@ def test_the_server_reports_the_latent_cache_and_the_expert_counters():
         LAYERS * 64 * (RANK + ROPE) * 4)
     assert bucket in (1, 2, 4)
     assert dispatch.args["kv_cache_bytes"] == 0
+    # every dispatched batch says which arm of the absorbed attend its
+    # program compiled (the einsums on the CPU) and the kernel's block
+    for s in spans:
+        if s.name == "serve.dispatch":
+            assert s.args["latent_attend"] == "einsum"
+            assert s.args["latent_attend_block"] == 0
 
 
 def test_scopes_and_counters_of_one_generate_call():

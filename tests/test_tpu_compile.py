@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops.flash_attention import _flash
+from bigdl_tpu.ops.latent_attend import BLOCK_POSITIONS, _latent_attend_kernel
 
 
 @pytest.fixture(scope="module")
@@ -62,3 +63,20 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, fn, calls):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(fn).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == calls
+
+
+@pytest.mark.parametrize("rows", [256, 8])
+def test_latent_attend_kernel_compiles_for_v5e(one_chip, rows):
+    """glm47flash_serve_decode_sat's decode step: 20 heads (not a
+    multiple of the sublane tile), a latent of 512 and a shared key of
+    64 with positions minor, a cache of 640 — the full bucket and the
+    smallest one the shape rule engages."""
+    def S(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda ql, qr, c, r, pos: _latent_attend_kernel(
+            ql, qr, c, r, pos, 256, BLOCK_POSITIONS, False)).lower(
+                S(rows, 20, 512), S(rows, 20, 64), S(rows, 640, 512),
+                S(rows, 64, 640), S(dt=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1

@@ -143,7 +143,11 @@ def main():
             "  `generate()` / `submit_generate` keep the latent and the",
             "  shared rotated key of every position (`ckv`, `kr`) and",
             "  nothing by head: prefill expands them once, a decode step",
-            "  absorbs `wkv_b` into the query and the output.",
+            "  absorbs `wkv_b` into the query and the output and attends",
+            "  through `ops.latent_attend.latent_attend` (PR 37: one",
+            "  Pallas kernel over the written part of the cache where a",
+            "  layer's cache is large, on a TPU; the plain einsums",
+            "  elsewhere — `attend_plan` decides by shapes).",
             "  `PagedDecoder` and `kv_dtype=\"int8\"` refuse it by name.",
             "- **`parallel.moe.route_top_k(..., select_bias=None,",
             "  gate_scale=1.0)`** / **`DroplessMoE(score_bias=False,",
