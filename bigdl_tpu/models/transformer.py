@@ -147,33 +147,38 @@ class TransformerBlock(Container):
         def sub(i):
             return jax.random.fold_in(rng, i) if rng is not None else None
 
+        # device scopes (``telemetry.tracer.DEVICE_SCOPES``; metadata
+        # only): the two residual branches, each with its norm and add
         nb = dict(buffers)
-        h, nb["0"] = self.modules[0].apply_fn(
-            params["0"], buffers["0"], x, training, sub(0))
-        h, nb["1"] = self.modules[1].apply_fn(
-            params["1"], buffers["1"], h, training, sub(1))
-        x = x + self._drop(h, sub(10), training)
-        h, nb["2"] = self.modules[2].apply_fn(
-            params["2"], buffers["2"], x, training, sub(2))
-        if getattr(self, "mlp_kind", None) == "swiglu":
-            # llama MLP: down(silu(gate(x)) * up(x))
-            g, nb["3"] = self.modules[3].apply_fn(
-                params["3"], buffers["3"], h, training, sub(3))
-            u, nb["4"] = self.modules[4].apply_fn(
-                params["4"], buffers["4"], h, training, sub(4))
-            h, nb["5"] = self.modules[5].apply_fn(
-                params["5"], buffers["5"], jax.nn.silu(g) * u,
-                training, sub(5))
-        else:
-            h, nb["3"] = self.modules[3].apply_fn(
-                params["3"], buffers["3"], h, training, sub(3))
-            if not self.is_moe:
-                # dense MLP: gelu between the column/row pair; the MoE
-                # FFN applies its own gelu between the expert matmuls
-                h = jax.nn.gelu(h)
-                h, nb["4"] = self.modules[4].apply_fn(
+        with jax.named_scope("block.attention"):
+            h, nb["0"] = self.modules[0].apply_fn(
+                params["0"], buffers["0"], x, training, sub(0))
+            h, nb["1"] = self.modules[1].apply_fn(
+                params["1"], buffers["1"], h, training, sub(1))
+            x = x + self._drop(h, sub(10), training)
+        with jax.named_scope("block.mlp"):
+            h, nb["2"] = self.modules[2].apply_fn(
+                params["2"], buffers["2"], x, training, sub(2))
+            if getattr(self, "mlp_kind", None) == "swiglu":
+                # llama MLP: down(silu(gate(x)) * up(x))
+                g, nb["3"] = self.modules[3].apply_fn(
+                    params["3"], buffers["3"], h, training, sub(3))
+                u, nb["4"] = self.modules[4].apply_fn(
                     params["4"], buffers["4"], h, training, sub(4))
-        return x + self._drop(h, sub(11), training), nb
+                h, nb["5"] = self.modules[5].apply_fn(
+                    params["5"], buffers["5"], jax.nn.silu(g) * u,
+                    training, sub(5))
+            else:
+                h, nb["3"] = self.modules[3].apply_fn(
+                    params["3"], buffers["3"], h, training, sub(3))
+                if not self.is_moe:
+                    # dense MLP: gelu between the column/row pair; the
+                    # MoE FFN applies its own gelu between the expert
+                    # matmuls
+                    h = jax.nn.gelu(h)
+                    h, nb["4"] = self.modules[4].apply_fn(
+                        params["4"], buffers["4"], h, training, sub(4))
+            return x + self._drop(h, sub(11), training), nb
 
 
 class TransformerLM(Container):
@@ -324,15 +329,17 @@ class TransformerLM(Container):
 
     def apply_fn(self, params, buffers, x, training, rng):
         embed = self.modules[0]
-        h, eb = embed.apply_fn(params["0"], buffers["0"], x, training,
-                               jax.random.fold_in(rng, 0)
-                               if rng is not None else None)
-        if not getattr(self, 'use_rope', False):  # rope positions live in the q/k rotation
-            h = h + self._positions(params["pos"], h.shape[1])
+        with jax.named_scope("lm.embed"):
+            h, eb = embed.apply_fn(params["0"], buffers["0"], x, training,
+                                   jax.random.fold_in(rng, 0)
+                                   if rng is not None else None)
+            if not getattr(self, 'use_rope', False):  # rope positions live in the q/k rotation
+                h = h + self._positions(params["pos"], h.shape[1])
         new_buffers = dict(buffers)
-        for i, m in enumerate(self.modules[1:], start=1):
+
+        def child(i, h):
+            m = self.modules[i]
             sub = jax.random.fold_in(rng, i) if rng is not None else None
-            apply = m.apply_fn
             if self.remat and isinstance(m, TransformerBlock):
                 # rematerialize each block's activations in the backward
                 # pass — HBM for FLOPs (jax.checkpoint; SURVEY north-star
@@ -341,12 +348,17 @@ class TransformerLM(Container):
                 apply = jax.checkpoint(
                     lambda p, b, h_, _m=m, _s=sub: _m.apply_fn(
                         p, b, h_, training, _s))
-                h, nb = apply(params[str(i)], buffers[str(i)], h)
-            else:
-                h, nb = apply(params[str(i)], buffers[str(i)], h, training,
-                              sub)
-            new_buffers[str(i)] = nb
+                return apply(params[str(i)], buffers[str(i)], h)
+            return m.apply_fn(params[str(i)], buffers[str(i)], h,
+                              training, sub)
+
+        head = len(self.modules) - 2  # the final norm, then the head
+        for i in range(1, head):
+            h, new_buffers[str(i)] = child(i, h)
+        with jax.named_scope("lm.head"):
+            for i in (head, head + 1):
+                h, new_buffers[str(i)] = child(i, h)
+            if self._output_mode != "logits":
+                h = jax.nn.log_softmax(h, axis=-1)
         new_buffers["0"] = eb
-        if self._output_mode == "logits":
-            return h, new_buffers
-        return jax.nn.log_softmax(h, axis=-1), new_buffers
+        return h, new_buffers

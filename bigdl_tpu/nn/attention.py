@@ -8,6 +8,7 @@ modules wrap it in the standard layer protocol.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -259,7 +260,11 @@ class MultiHeadAttention(TensorModule):
             group = self.num_heads // self.num_kv_heads
             k = jnp.repeat(k, group, axis=1)
             v = jnp.repeat(v, group, axis=1)
-        o = self._attend(q, k, v)
+        # device scope (``telemetry.tracer.DEVICE_SCOPES``): the
+        # attention itself, whichever arm; projections and rotation
+        # stay outside it
+        with jax.named_scope("attention.core"):
+            o = self._attend(q, k, v)
         B, H, T, D = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
         return proj(o, params["wo"], "bo"), buffers

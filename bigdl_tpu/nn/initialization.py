@@ -9,11 +9,14 @@ import contextlib
 import functools
 import math
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.metric_names import INIT_DRAW_SECONDS_TOTAL
+from ..telemetry.registry import default_registry
 from ..utils.rng import RNG
 
 _draw = threading.local()
@@ -53,16 +56,36 @@ def _device_key():
     return jax.random.key(int(RNG().random_int(0, 2**31 - 1)), impl="rbg")
 
 
-def _uniform(lo, hi, shape):
+def _drawn(normal: bool, a, b, shape):
+    """One leaf, drawn where ``device_draw`` says and BOOKED there: the
+    host seconds of the call, as a counter family of the default
+    registry labelled ``where``.  On the host that is the draw itself;
+    on the device it is what the host spends building and enqueuing the
+    draw — the compile or the cache load of a shape's program included,
+    which is most of it where nothing is cached — while the draw itself
+    runs behind it.  Two clock reads a leaf; no span per leaf in the
+    ring."""
+    t0 = time.perf_counter()
     if getattr(_draw, "on", False):
-        return _device_random(_device_key(), lo, hi, tuple(shape), False)
-    return jnp.asarray(RNG().uniform(lo, hi, shape), jnp.float32)
+        where = "device"
+        out = _device_random(_device_key(), a, b, tuple(shape), normal)
+    else:
+        where = "host"
+        gen = RNG().normal if normal else RNG().uniform
+        out = jnp.asarray(gen(a, b, shape), jnp.float32)
+    default_registry().counter(
+        INIT_DRAW_SECONDS_TOTAL,
+        "host seconds the initialisers spent drawing weights",
+        labels=("where",)).labels(where=where).inc(time.perf_counter() - t0)
+    return out
+
+
+def _uniform(lo, hi, shape):
+    return _drawn(False, lo, hi, shape)
 
 
 def _normal(mean, std, shape):
-    if getattr(_draw, "on", False):
-        return _device_random(_device_key(), mean, std, tuple(shape), True)
-    return jnp.asarray(RNG().normal(mean, std, shape), jnp.float32)
+    return _drawn(True, mean, std, shape)
 
 
 class VariableFormat:

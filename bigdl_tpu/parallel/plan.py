@@ -1540,16 +1540,20 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
         from ..optim.optimizer import _cast_floats, _restore_dtypes
 
         p_c, x_c = p, x
-        if compute_dtype is not None:
-            p_c = _cast_floats(p, compute_dtype)
-            x_c = _cast_floats(x, compute_dtype)
-        if has_fsdp:
-            p_c = _gather_fsdp(p_c)
-        out, nb = model.apply_fn(p_c, buf, x_c, training, rng)
-        if compute_dtype is not None:
-            if upcast_out:
+        with jax.named_scope("step.cast_params"):
+            if compute_dtype is not None:
+                p_c = _cast_floats(p, compute_dtype)
+                x_c = _cast_floats(x, compute_dtype)
+            if has_fsdp:
+                p_c = _gather_fsdp(p_c)
+        with jax.named_scope("step.forward"):
+            out, nb = model.apply_fn(p_c, buf, x_c, training, rng)
+            if compute_dtype is not None:
+                nb = _restore_dtypes(nb, buf)
+        if compute_dtype is not None and upcast_out:
+            # the criterion's input precision: booked with the loss
+            with jax.named_scope("step.loss"):
                 out = _cast_floats(out, jnp.float32)
-            nb = _restore_dtypes(nb, buf)
         return out, nb
 
     def _spec_for_path(path):
@@ -1606,87 +1610,112 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
             def loss_fn(p):
                 out, nb = _run_fwd(_unstack_params(p), buf, x, True,
                                    rng)
-                aux = aux_loss_term(nb, aux_paths) if aux_paths else 0.0
-                if masked:
-                    # trailing partial batch: per-record loss weighted
-                    # 1-real/0-pad over the GLOBAL real count — every
-                    # record of an epoch trains exactly once at static
-                    # shape (reference DataSet.scala:255-288)
-                    w, total_w = mask_args
-                    add_axis = lambda v: jax.tree_util.tree_map(
-                        lambda a: a[None], v)
-                    per = jax.vmap(
-                        lambda o, t: criterion._loss(add_axis(o),
-                                                     add_axis(t)))(out, y)
-                    return jnp.sum(per * w) / total_w + aux / n_data, nb
-                return criterion._loss(out, y) + aux, nb
+                with jax.named_scope("step.loss"):
+                    aux = (aux_loss_term(nb, aux_paths) if aux_paths
+                           else 0.0)
+                    if masked:
+                        # trailing partial batch: per-record loss
+                        # weighted 1-real/0-pad over the GLOBAL real
+                        # count — every record of an epoch trains
+                        # exactly once at static shape (reference
+                        # DataSet.scala:255-288)
+                        w, total_w = mask_args
+                        add_axis = lambda v: jax.tree_util.tree_map(
+                            lambda a: a[None], v)
+                        per = jax.vmap(
+                            lambda o, t: criterion._loss(
+                                add_axis(o), add_axis(t)))(out, y)
+                        return (jnp.sum(per * w) / total_w
+                                + aux / n_data), nb
+                    return criterion._loss(out, y) + aux, nb
 
+            # device scopes (``jax.named_scope``, metadata only;
+            # ``telemetry.tracer.DEVICE_SCOPES``): the backward needs
+            # none of its own — autodiff wraps the forward's path, so
+            # its operations read ``transpose(jvp(step.forward))/...``
             (loss, nb), grads = jax.value_and_grad(loss_fn,
                                                    has_aux=True)(params)
-            grads = jax.tree_util.tree_map(reduce_grad, grads, pspecs,
-                                           k_tree, sync_kind_tree)
-            if stale_cadences:
-                grads, new_pending = _stale_exchange(grads, pending,
-                                                     masked)
-            else:
-                new_pending = pending
-            if reg_paths:
-                # per-shard reg grads are exact — added AFTER the
-                # cross-shard reduction, never scaled by it
-                reg_g = jax.grad(
-                    lambda p: regularizer_loss(p, reg_paths))(params)
-                grads = jax.tree_util.tree_map(lambda g, r: g + r,
-                                               grads, reg_g)
-                reg = _reg_term(params)
-                loss = loss + (reg / n_data if masked else reg)
-            if needs_scale:  # reference setScaleW/setScaleB semantics
-                grads = jax.tree_util.tree_map(lambda g, s: g * s,
-                                               grads, scale_tree)
-            gn = _gnorm(grads) if with_gnorm else jnp.float32(0.0)
-            if masked:
-                if d_ax:
-                    loss = lax.psum(loss, d_ax)
-                if s_ax:
-                    loss = lax.pmean(loss, s_ax)
-                # padded rows would pollute batch statistics: keep the
-                # pre-step buffers for the trailing partial batch
-                nb = buf
-            elif batch_axes:
-                loss = lax.pmean(loss, batch_axes)
-                # sync running stats (BatchNorm) across batch shards
-                nb = jax.tree_util.tree_map(
-                    lambda b: (lax.pmean(b, batch_axes)
-                               if jnp.issubdtype(b.dtype, jnp.floating)
-                               else b),
-                    nb)
-            new_params, new_slots = optim.step(grads, params, slots, lr)
-            if guard:
-                # NaN/Inf anywhere skips the whole update; pmin over
-                # every axis makes all shards agree, so sharded slices
-                # stay consistent.  Relaxed leaves' grads are LOCAL on
-                # skip steps, but the pmin makes the skip decision
-                # uniform — shards never diverge on the guard.
-                ok_local = jnp.logical_and(tree_finite(grads),
-                                           jnp.isfinite(loss))
-                ok = (lax.pmin(ok_local.astype(jnp.int32), all_axes) > 0
-                      if all_axes else ok_local)
-                new_params = where_tree(ok, new_params, params)
-                new_slots = where_tree(ok, new_slots, slots)
-                nb = where_tree(ok, nb, buf)
+            with jax.named_scope("step.grad_reduce"):
+                grads = jax.tree_util.tree_map(reduce_grad, grads,
+                                               pspecs, k_tree,
+                                               sync_kind_tree)
                 if stale_cadences:
-                    new_pending = where_tree(ok, new_pending, pending)
-            else:
-                ok = jnp.bool_(True)
-            # the periodic averaging round: one lax.cond per cadence
-            # group on its traced flag — averaging a skipped step's
-            # (reverted) replicas is harmless and keeps the cadence,
-            # so the round runs on both guard phases
-            for _gi, _cadence in enumerate(periodic_cadences):
-                avg = _make_group_avg(group_param_masks[_cadence],
-                                      group_slot_masks[_cadence])
-                new_params, new_slots = lax.cond(
-                    sync_flags[_gi] > 0, avg, lambda o: o,
-                    (new_params, new_slots))
+                    grads, new_pending = _stale_exchange(grads, pending,
+                                                         masked)
+                else:
+                    new_pending = pending
+            # ONE name for every pass over the parameter tree after
+            # the reduce (regulariser, scales, norm, optimizer, guard,
+            # periodic averaging): on the chip the guard's select is
+            # the root of every optimizer fusion, so a name each books
+            # the whole stretch to the guard and 0 to the optimizer
+            with jax.named_scope("step.update"):
+                if reg_paths:
+                    # per-shard reg grads are exact — added AFTER the
+                    # cross-shard reduction, never scaled by it
+                    reg_g = jax.grad(
+                        lambda p: regularizer_loss(p, reg_paths))(params)
+                    grads = jax.tree_util.tree_map(lambda g, r: g + r,
+                                                   grads, reg_g)
+                    reg = _reg_term(params)
+                    loss = loss + (reg / n_data if masked else reg)
+                if needs_scale:  # reference setScaleW/setScaleB semantics
+                    grads = jax.tree_util.tree_map(lambda g, s: g * s,
+                                                   grads, scale_tree)
+                gn = _gnorm(grads) if with_gnorm else jnp.float32(0.0)
+            with jax.named_scope("step.grad_reduce"):
+                if masked:
+                    if d_ax:
+                        loss = lax.psum(loss, d_ax)
+                    if s_ax:
+                        loss = lax.pmean(loss, s_ax)
+                    # padded rows would pollute batch statistics: keep
+                    # the pre-step buffers for the trailing partial
+                    # batch
+                    nb = buf
+                elif batch_axes:
+                    loss = lax.pmean(loss, batch_axes)
+                    # sync running stats (BatchNorm) across batch shards
+                    nb = jax.tree_util.tree_map(
+                        lambda b: (lax.pmean(b, batch_axes)
+                                   if jnp.issubdtype(b.dtype,
+                                                     jnp.floating)
+                                   else b),
+                        nb)
+            with jax.named_scope("step.update"):
+                new_params, new_slots = optim.step(grads, params, slots,
+                                                   lr)
+                if guard:
+                    # NaN/Inf anywhere skips the whole update; pmin
+                    # over every axis makes all shards agree, so
+                    # sharded slices stay consistent.  Relaxed leaves'
+                    # grads are LOCAL on skip steps, but the pmin makes
+                    # the skip decision uniform — shards never diverge
+                    # on the guard.
+                    ok_local = jnp.logical_and(tree_finite(grads),
+                                               jnp.isfinite(loss))
+                    ok = (lax.pmin(ok_local.astype(jnp.int32),
+                                   all_axes) > 0
+                          if all_axes else ok_local)
+                    new_params = where_tree(ok, new_params, params)
+                    new_slots = where_tree(ok, new_slots, slots)
+                    nb = where_tree(ok, nb, buf)
+                    if stale_cadences:
+                        new_pending = where_tree(ok, new_pending,
+                                                 pending)
+                else:
+                    ok = jnp.bool_(True)
+                # the periodic averaging round: one lax.cond per
+                # cadence group on its traced flag — averaging a
+                # skipped step's (reverted) replicas is harmless and
+                # keeps the cadence, so the round runs on both guard
+                # phases
+                for _gi, _cadence in enumerate(periodic_cadences):
+                    avg = _make_group_avg(group_param_masks[_cadence],
+                                          group_slot_masks[_cadence])
+                    new_params, new_slots = lax.cond(
+                        sync_flags[_gi] > 0, avg, lambda o: o,
+                        (new_params, new_slots))
             if has_relaxed:
                 return (loss, new_params, new_slots, nb, ok, gn,
                         new_pending)

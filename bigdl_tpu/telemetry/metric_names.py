@@ -51,6 +51,13 @@ CHECKPOINT_ASYNC_WRITE_SECONDS_TOTAL = \
 INFEED_BUFFER_HITS_TOTAL = "bigdl_infeed_buffer_hits_total"
 INFEED_BUFFER_MISSES_TOTAL = "bigdl_infeed_buffer_misses_total"
 
+# --- set-up (nn/initialization.py) ----------------------------------------
+#: host seconds of the initialisers' weight draw, labeled {where}:
+#: host | device (``device_draw``: enqueue, with the draw programs'
+#: compile or cache load) — a constructor's share of a job's start,
+#: booked where the work happens
+INIT_DRAW_SECONDS_TOTAL = "bigdl_init_draw_seconds_total"
+
 # --- performance accounting (telemetry/perf.py, parallel/plan.py) --------
 PERF_FLOPS_PER_STEP = "bigdl_perf_flops_per_step"
 PERF_BYTES_PER_STEP = "bigdl_perf_bytes_per_step"

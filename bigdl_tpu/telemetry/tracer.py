@@ -133,6 +133,35 @@ PROGRAM_SPANS = {
 #: q_lat`` and ``o_lat -> o``), ``mla.attend`` scores, softmax and the
 #: weighted sum over the cached latent, ``mla.out_proj`` the output
 #: projection.
+#: ``step.*`` the parts of one compiled TRAINING step
+#: (``parallel/plan.py``'s local step; ``train.*`` stay host spans); the
+#: backward has no scope of its own: autodiff wraps the outermost
+#: component of the forward's path,
+#: ``transpose(jvp(step.forward))/block.mlp/...``:
+#: ``step.cast_params`` the cast of parameters and input to the compute
+#: dtype and the FSDP gather,
+#: ``step.forward`` the model's ``apply_fn`` (the model's scopes nest in it),
+#: ``step.loss`` the criterion and the auxiliary term (masked or not),
+#: ``step.grad_reduce`` the gradients' collectives over the mesh, the
+#: stale exchange, and the reduce of the loss and the buffers,
+#: ``step.update`` every pass over the parameter tree after the reduce:
+#: the regulariser's gradient and the gradient scales where a model has
+#: them, the global gradient norm, the optimizer method's step, the
+#: finiteness guard (check, ``pmin``, selects) and the periodic
+#: averaging round — ONE name, because the compiler fuses the
+#: optimizer's arithmetic under the guard's selects: a name each read 0
+#: for the optimizer and the whole stretch for the guard (v5e, PR 38).
+#: In a ``TransformerLM`` (``models/transformer.py``):
+#: ``lm.embed`` the token embedding and the learned positions,
+#: ``block.mlp`` a block's second norm, its FFN (dense, SwiGLU or
+#: experts) and the residual add — beside ``block.attention``, here the
+#: first norm, the attention module and its add,
+#: ``lm.head`` the final norm, the vocabulary head and its
+#: ``log_softmax`` where that runs,
+#: ``attention.core`` (``nn/attention.py``) the attention itself inside
+#: ``MultiHeadAttention`` — the flash kernels, forward and backward, or
+#: whichever arm ``seq_strategy`` picks; projections and rotation are
+#: outside it.
 DEVICE_SCOPES = (
     "generate.cast_params", "generate.prefill", "generate.decode_step",
     "generate.sample",
@@ -142,6 +171,9 @@ DEVICE_SCOPES = (
     "moe.shared", "block.attention",
     "mla.q_proj", "mla.kv_latent", "mla.expand", "mla.absorb",
     "mla.attend", "mla.out_proj",
+    "step.cast_params", "step.forward", "step.loss", "step.grad_reduce",
+    "step.update",
+    "lm.embed", "block.mlp", "lm.head", "attention.core",
 )
 
 
