@@ -20,15 +20,23 @@ Design (pallas_guide.md patterns):
   with one k grid tile the output and the row log-sum-exp (the
   backward's softmax statistic) are written straight from the carry,
   with several the carry rests in VMEM scratch between grid steps.
-- backward: two Pallas kernels (FlashAttention-2 schedule).  Both
-  recompute the probability tile from (q, k, lse) on the fly — no (T, S)
-  array ever exists.  Scores are computed TRANSPOSED, (sub_k rows ×
-  sub_q lanes), so the per-q-row lse/delta vectors broadcast along the
-  sublane dimension without any in-kernel transpose:
-    * dKdV kernel: grid (BH, S/block_k, T/block_q); for each k sub-tile
-      dk/dv accumulate over the q sub-tiles from the diagonal down;
-    * dQ kernel: grid (BH, T/block_q, S/block_k); for each q sub-tile
-      dq accumulates over the k sub-tiles up to the diagonal.
+- backward (FlashAttention-2 schedule): the probability tile is
+  recomputed from (q, k, lse) on the fly — no (T, S) array ever exists.
+  Scores are computed TRANSPOSED, (sub_k rows × sub_q lanes), so the
+  per-q-row lse/delta vectors broadcast along the sublane dimension
+  without any in-kernel transpose.  How many kernels is a SHAPE:
+    * the key axis is ONE grid tile (S <= the grid tile: training at
+      T 1024): one fused kernel, grid (BH, T/block_q, 1).  For each k
+      sub-tile it walks the q sub-tiles from the diagonal down; each
+      (pᵀ, dSᵀ) is computed once and gives its share of dv, dk and dq —
+      dk/dv are one k sub-tile's values, dq is held for the q grid tile
+      across the walk and written once;
+    * several key grid tiles: dq would have to accumulate across grid
+      steps that are not consecutive, so two kernels, each recomputing
+      the tile — dKdV, grid (BH, S/block_k, T/block_q): for each k
+      sub-tile dk/dv accumulate over the q sub-tiles from the diagonal
+      down; dQ, grid (BH, T/block_q, S/block_k): for each q sub-tile dq
+      accumulates over the k sub-tiles up to the diagonal.
 - causal: the walks stop at the diagonal (``_visible_k`` /
   ``_visible_q``; ``causal_schedule`` counts what they give): a
   sub-tile wholly above it is never touched, one wholly under it takes
@@ -191,6 +199,18 @@ def _dq_tile(q, do, k, v, lse, delta, sm_scale, st_mask):
     (sublane) dim — no transpose."""
     _, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
     return _dot(dst.astype(k.dtype), k, ((0,), (0,))) * sm_scale
+
+
+def _bwd_tile(q, do, k, v, lse, delta, sm_scale, st_mask):
+    """One tile's (dq, dk, dv) contributions from ONE pass over its
+    scores: what ``_dq_tile`` and ``_dkv_tile`` give, product for
+    product, without the second Sᵀ, ``exp`` and dPᵀ."""
+    pt, dst = _bwd_tile_terms(q, do, k, v, lse, delta, sm_scale, st_mask)
+    dst = dst.astype(q.dtype)
+    dv = _dot(pt.astype(v.dtype), do, ((1,), (0,)))
+    dk = _dot(dst, q, ((1,), (0,))) * sm_scale
+    dq = _dot(dst, k, ((0,), (0,))) * sm_scale
+    return dq, dk, dv
 
 
 # the same arithmetic over VMEM scratch, for kernels whose accumulators
@@ -429,15 +449,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             _finish_softmax_tile(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _tiles(T: int, S: int, D: int, block_q, block_k, sub_tile):
+def _tiles(T: int, S: int, D: int, block_q, block_k, sub_tile,
+           backward: bool = False):
     """(block_q, block_k, sub_q, sub_k): the grid tile and the sub-tile
-    of each axis — chosen from the shape where the caller gives None."""
+    of each axis — chosen from the shape where the caller gives None.
+    The ``backward`` of a key axis of one grid tile is the fused kernel,
+    which has a sub-tile of its own."""
     bq = min(block_q or _pick_block(T, D), T)
     bk = min(block_k or _pick_block(S, D), S)
     assert T % bq == 0 and S % bk == 0, (
         f"seq lens ({T}, {S}) must divide block sizes ({bq}, {bk}); "
         "pad sequences to a block multiple")
-    sub = sub_tile or _pick_sub_tile(T, S, D)
+    sub = sub_tile or _pick_sub_tile(T, S, D, backward and bk == S)
     sq, sk = (sub, sub) if isinstance(sub, int) else sub
     return bq, bk, _fit_sub_tile(sq, bq), _fit_sub_tile(sk, bk)
 
@@ -556,21 +579,19 @@ def _dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-               dq_ref, *scratch,
+               dq_ref, dq_scr,
                sm_scale: float, causal: bool, block_q: int, block_k: int,
                sub_q: int, sub_k: int, num_q_blocks: int,
                num_k_blocks: int, offsets):
+    # several k grid tiles (one takes the fused kernel): dq rests in
+    # scratch between them
     qi = pl.program_id(1) if num_q_blocks > 1 else 0
-    ki = pl.program_id(2) if num_k_blocks > 1 else 0
+    ki = pl.program_id(2)
     n_k = block_k // sub_k
-    d = q_ref.shape[-1]
-    one_pass = num_k_blocks == 1
-    if not one_pass:
-        dq_scr, = scratch
 
-        @pl.when(ki == 0)
-        def _init():
-            dq_scr[...] = jnp.zeros_like(dq_scr)
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def q_sub_tile(i: int, offset: int):
         rows = _sub(i, sub_q)
@@ -580,8 +601,7 @@ def _dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
         delta = delta_ref[0, :, rows]
         q0 = offset + i * sub_q
         full, computed = _visible_k(q0, sub_q, sub_k, n_k)
-        dq = (jnp.zeros((sub_q, d), jnp.float32) if one_pass
-              else dq_scr[rows, :])
+        dq = dq_scr[rows, :]
         for j in range(computed):
             cols = _sub(j, sub_k)
             st_mask = _tile_causal_mask(
@@ -589,10 +609,7 @@ def _dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
                 transposed=True) if j >= full else None
             dq = dq + _dq_tile(q, do, k_ref[0, cols, :], v_ref[0, cols, :],
                                lse, delta, sm_scale, st_mask)
-        if one_pass:
-            dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
-        else:
-            dq_scr[rows, :] = dq
+        dq_scr[rows, :] = dq
 
     def tile(offset: int):
         for i in range(block_q // sub_q):
@@ -602,18 +619,84 @@ def _dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
     offset = qi * block_q - ki * block_k if causal else block_k
     _at_static_offset(tile, offset, offsets, block_k)
 
+    @pl.when(ki == num_k_blocks - 1)
+    def _finish():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _bwd_fused_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, *scratch,
+                      sm_scale: float, causal: bool, block_q: int,
+                      block_k: int, sub_q: int, sub_k: int,
+                      num_q_blocks: int, offsets):
+    """The whole backward where the key axis is ONE grid tile: dKdV's
+    walk (k sub-tile outer, the visible q sub-tiles inner), and from
+    each tile's one (pᵀ, dSᵀ) also its share of dQ, which is held for
+    the q grid tile across the walk and written once."""
+    qi = pl.program_id(1) if num_q_blocks > 1 else 0
+    n_q = block_q // sub_q
+    d = k_ref.shape[-1]
+    one_pass = num_q_blocks == 1
     if not one_pass:
-        @pl.when(ki == num_k_blocks - 1)
+        dk_scr, dv_scr = scratch
+
+        @pl.when(qi == 0)
+        def _init():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def tile(offset: int):
+        dq = [jnp.zeros((sub_q, d), jnp.float32)] * n_q
+        for j in range(block_k // sub_k):
+            rows = _sub(j, sub_k)
+            k = k_ref[0, rows, :]
+            v = v_ref[0, rows, :]
+            first, full = _visible_q(j * sub_k, sub_k, offset, sub_q, n_q)
+            if one_pass:
+                dk = dv = jnp.zeros((sub_k, d), jnp.float32)
+            else:
+                dk, dv = dk_scr[rows, :], dv_scr[rows, :]
+            for i in range(first, n_q):
+                cols = _sub(i, sub_q)
+                st_mask = _tile_causal_mask(
+                    offset + i * sub_q, j * sub_k, sub_q, sub_k,
+                    transposed=True) if i < full else None
+                ddq, ddk, ddv = _bwd_tile(
+                    q_ref[0, cols, :], do_ref[0, cols, :], k, v,
+                    lse_ref[0, :, cols], delta_ref[0, :, cols], sm_scale,
+                    st_mask)
+                dq[i], dk, dv = dq[i] + ddq, dk + ddk, dv + ddv
+            if one_pass:
+                dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+                dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+            else:
+                dk_scr[rows, :], dv_scr[rows, :] = dk, dv
+        for i in range(n_q):
+            dq_ref[0, _sub(i, sub_q), :] = dq[i].astype(dq_ref.dtype)
+
+    # a non-causal tile is a tile wholly under the diagonal; with one k
+    # grid tile none lies above it, so every q grid tile writes its dq
+    offset = qi * block_q if causal else block_k
+    _at_static_offset(tile, offset, offsets, block_k)
+
+    if not one_pass:
+        @pl.when(qi == num_q_blocks - 1)
         def _finish():
-            dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+            dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
                block_q, block_k, sub_tile, interpret: bool):
     B, H, T, D = q.shape
     S = k.shape[2]
-    tiles = bq, bk, sq, sk = _tiles(T, S, D, block_q, block_k, sub_tile)
-    _record_schedule("bwd", T, S, D, tiles, causal)
+    tiles = bq, bk, sq, sk = _tiles(T, S, D, block_q, block_k, sub_tile,
+                                    backward=True)
+    nq, nk = T // bq, S // bk
+    # one k grid tile: nothing makes dq wait for a grid step that is not
+    # the next one, so one kernel forms all three from each score tile
+    fused = nk == 1
+    _record_schedule("bwd_fused" if fused else "bwd", T, S, D, tiles, causal)
     BH = B * H
     qr = q.reshape(BH, T, D)
     kr = k.reshape(BH, S, D)
@@ -623,9 +706,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(BH, 1, T)
 
-    nq, nk = T // bq, S // bk
     static = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
-                  sub_q=sq, sub_k=sk, num_q_blocks=nq, num_k_blocks=nk)
+                  sub_q=sq, sub_k=sk, num_q_blocks=nq)
     row_specs = [
         pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0),
                      memory_space=pltpu.VMEM),   # q
@@ -640,6 +722,32 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
         pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i),
                      memory_space=pltpu.VMEM),   # delta
     ]
+    dq_spec, dk_spec, dv_spec = row_specs[0], row_specs[2], row_specs[3]
+    dq_shape = jax.ShapeDtypeStruct((BH, T, D), q.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+                  jax.ShapeDtypeStruct((BH, S, D), v.dtype)]
+    # dk/dv of a k grid tile rest in scratch between its q grid tiles
+    dkv_scratch = [] if nq == 1 else [pltpu.VMEM((bk, D), jnp.float32),
+                                      pltpu.VMEM((bk, D), jnp.float32)]
+
+    if fused:
+        # grid (BH, nq, 1); the q sweep carries dk/dv: sequential
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, **static,
+                              offsets=_tile_offsets(T, S, bq, bk)),
+            grid=(BH, nq, nk),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            in_specs=row_specs,
+            out_specs=[dq_spec, dk_spec, dv_spec],
+            out_shape=[dq_shape] + dkv_shapes,
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+        )(qr, gr, kr, vr, lse, delta)
+        return (dq.reshape(B, H, T, D), dk.reshape(B, H, S, D),
+                dv.reshape(B, H, S, D))
+
+    static = dict(static, num_k_blocks=nk)
 
     # --- dK/dV: grid over k blocks, sweep q blocks innermost ----------
     def swap(spec):  # same tensors, but grid dims are (bh, ki, qi)
@@ -657,20 +765,9 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         in_specs=[swap(s) for s in row_specs],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda bh, j, i: (bh, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, D), lambda bh, j, i: (bh, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
-        ],
-        scratch_shapes=[] if nq == 1 else [
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
+        out_specs=[swap(dk_spec), swap(dv_spec)],
+        out_shape=dkv_shapes,
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
     )(qr, gr, kr, vr, lse, delta)
 
@@ -682,11 +779,9 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         in_specs=row_specs,
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=dq_spec,
+        out_shape=dq_shape,
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
     )(qr, gr, kr, vr, lse, delta)
 
@@ -712,15 +807,20 @@ def _pick_block(n: int, d: int = 64) -> int:
     return 128
 
 
-def _pick_sub_tile(T: int, S: int, d: int) -> int:
+def _pick_sub_tile(T: int, S: int, d: int, fused_bwd: bool = False) -> int:
     """The SUB-TILE the kernels walk inside a grid tile, from the shape
     alone.  It buys skipped work (causal sub-tiles above the diagonal
     are never touched) against the per-sub-tile cost of the carry.  A
-    sequence of one grid tile is walked in 512s; one of several grid
-    tiles already skips by grid tile, and is walked grid tile by grid
-    tile — there a sub-tile measured slower (PERF.md §6 "PR 30")."""
+    sequence of one grid tile is walked in 512s by the forward, whose
+    carry is rescaled at every sub-tile, and in 256s by the fused
+    backward, whose accumulators are only added to (PERF.md §6
+    "PR 39"); one of several grid tiles already skips by grid tile, and
+    is walked grid tile by grid tile — there a sub-tile measured slower
+    (PERF.md §6 "PR 30")."""
     del d
-    return 512 if max(T, S) <= 1024 else 1024
+    if max(T, S) > 1024:
+        return 1024
+    return 256 if fused_bwd else 512
 
 
 def _fit_sub_tile(sub: int, block: int) -> int:
@@ -754,7 +854,7 @@ def _flash_bwd_rule(causal, sm_scale, interpret, block_q, block_k,
     if window is not None:
         raise NotImplementedError(
             "flash_attention(window=) has a forward kernel only: the "
-            "two backward kernels walk the causal schedule.  Train a "
+            "backward kernels walk the causal schedule.  Train a "
             "windowed layer with seq_strategy='dense'.")
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, g, causal, sm_scale, block_q,

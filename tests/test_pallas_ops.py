@@ -138,20 +138,25 @@ class TestFlashSubTiles:
     @pytest.mark.parametrize("head", [64, 128])
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("grid,sub", [(256, 128), (512, 128),
-                                          (512, 256)])
+                                          (512, 256), (512, 512),
+                                          (1024, 512)])
     def test_forward_and_gradients_match_dense(self, grid, sub, causal,
                                                head):
-        # one grid tile: nothing goes through scratch
+        # one grid tile: nothing goes through scratch, and the backward
+        # is the fused kernel (1024 walked in 512s: the training cells)
         self._check(grid, grid, head, causal, seed=grid + sub + head,
                     block_q=grid, block_k=grid, sub_tile=sub)
 
     @pytest.mark.parametrize("head", [64, 128])
     @pytest.mark.parametrize("causal", [False, True])
-    @pytest.mark.parametrize("T,S", [(512, 512), (512, 256), (768, 512)])
+    @pytest.mark.parametrize("T,S", [(512, 512), (512, 256), (768, 512),
+                                     (768, 256), (256, 512)])
     def test_several_grid_tiles(self, T, S, causal, head):
         # grid tile 256, sub-tile 128: the carry rests in scratch between
         # grid steps and ``pl.when`` picks each tile's schedule by its
-        # offset from the diagonal; T > S is causal cross-attention
+        # offset from the diagonal; T > S is causal cross-attention.
+        # S = 256 is ONE key tile under two or three query tiles: the
+        # fused backward, dk/dv resting in scratch between them
         self._check(T, S, head, causal, seed=T + S + head, block_q=256,
                     block_k=256, sub_tile=128)
 
@@ -168,6 +173,76 @@ class TestFlashSubTiles:
         for a, b in zip(got, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-3, atol=1e-4)
+
+    @pytest.mark.parametrize("T,S,tiles,kernel", [
+        # the training cells' shape, tiles from the shape: 1024 in 512s
+        (1024, 1024, {}, "bwd_fused"),
+        (512, 512, {}, "bwd_fused"),
+        # S > T and T > S with one key grid tile
+        (256, 512, dict(block_q=256, block_k=512, sub_tile=128),
+         "bwd_fused"),
+        (1024, 512, dict(block_q=512, block_k=512, sub_tile=256),
+         "bwd_fused"),
+        # several key grid tiles keep the dKdV and dQ kernels
+        (512, 512, dict(block_q=256, block_k=256, sub_tile=128), "bwd"),
+        (256, 512, dict(block_q=256, block_k=256, sub_tile=128), "bwd"),
+    ])
+    def test_backward_is_chosen_by_the_key_grid_tiles(self, T, S, tiles,
+                                                      kernel):
+        """One kernel where the key axis is one grid tile, two where it
+        is several — the operands' shapes alone choose, and both answer
+        the dense gradients."""
+        from bigdl_tpu.telemetry import default_tracer
+
+        self._check(T, S, 64, True, seed=T + S, **tiles)
+        assert [s.args["kernel"] for s in default_tracer().spans()
+                if s.name == "flash.schedule"] == ["fwd", kernel]
+
+    def test_two_kernel_backward_over_many_heads(self):
+        # (1, 16, 2048, 64) at grid 512: 4 x 4 grid tiles a head
+        rng = np.random.RandomState(16)
+        q, k, v = (jnp.asarray(rng.randn(1, 16, 2048, 64).astype(np.float32)
+                               * 0.5) for _ in range(3))
+        got, ref = _dense_pair(
+            lambda a, b, c: flash_attention(a, b, c, causal=True,
+                                            interpret=True, block_q=512,
+                                            block_k=512),
+            lambda a, b, c: _attention_reference(a, b, c, True, 0.125),
+            q, k, v)
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4)
+
+    @pytest.mark.parametrize("shape,grid,want", [
+        ((8, 16, 1024, 64), None, "bwd_fused"),     # the training cells
+        ((1, 16, 2048, 64), 512, "bwd"),
+        ((1, 16, 2048, 64), None, "bwd"),           # 2 x 2 tiles of 1024
+        ((4, 8, 512, 128), None, "bwd_fused"),      # wide heads, one tile
+        ((4, 8, 1024, 128), None, "bwd"),           # ... grid 512: two
+    ])
+    def test_traced_backward_records_which_it_built(self, shape, grid,
+                                                    want):
+        """The counter: tracing ``_flash_bwd`` (nothing runs) records ONE
+        ``flash.schedule`` event that names the backward it built."""
+        from bigdl_tpu.ops.flash_attention import (_flash_bwd, _tiles,
+                                                   causal_schedule)
+        from bigdl_tpu.telemetry import default_tracer
+
+        B, H, T, D = shape
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)
+        jax.eval_shape(
+            lambda q, k, v, o, l, g: _flash_bwd(
+                q, k, v, o, l, g, True, 0.125, grid, grid, None, True),
+            x, x, x, x, lse, x)
+        event, = [s for s in default_tracer().spans()
+                  if s.name == "flash.schedule"]
+        bq, bk, sq, sk = _tiles(T, T, D, grid, grid, None, backward=True)
+        assert event.args == {
+            "kernel": want, "T": T, "S": T, "head_dim": D,
+            "grid_tile": [bq, bk], "sub_tile": [sq, sk],
+            **causal_schedule(T, T, (bq, bk), (sq, sk), True)}
+        assert (want == "bwd_fused") == (T == bk)
 
     @pytest.mark.parametrize("block_k", [512, 256])
     def test_kernels_skip_and_do_not_compute_then_mask(self, block_k):
@@ -209,7 +284,7 @@ class TestFlashSubTiles:
             block_k=512, sub_tile=128)))(q)
         events = [s for s in default_tracer().spans()
                   if s.name == "flash.schedule"]
-        assert [e.args["kernel"] for e in events] == ["fwd", "bwd"]
+        assert [e.args["kernel"] for e in events] == ["fwd", "bwd_fused"]
         for e in events:
             assert e.category == "compile" and e.duration == 0.0
             assert e.args == {
@@ -383,6 +458,24 @@ class TestPickSubTile:
         from bigdl_tpu.ops.flash_attention import _tiles
 
         assert _tiles(T, S, d, None, None, None) == want
+
+    @pytest.mark.parametrize("T,S,d,want", [
+        # one key grid tile, the fused kernel: walked in 256s
+        (1024, 1024, 64, (1024, 1024, 256, 256)),   # gpt2m training cells
+        (512, 512, 128, (512, 512, 256, 256)),
+        (2048, 1024, 64, (1024, 1024, 1024, 1024)),  # ... but not past 1024
+        (128, 128, 64, (128, 128, 128, 128)),
+        # several key grid tiles, dKdV and dQ: the forward's sub-tile
+        (1024, 1024, 128, (512, 512, 512, 512)),
+        (2048, 2048, 64, (1024, 1024, 1024, 1024)),
+    ])
+    def test_backward_tiles_from_the_shape(self, T, S, d, want):
+        from bigdl_tpu.ops.flash_attention import _tiles
+
+        assert _tiles(T, S, d, None, None, None, backward=True) == want
+        # an override is an override for every kernel
+        assert _tiles(T, S, d, None, None, 128, backward=True) == _tiles(
+            T, S, d, None, None, 128)
 
     def test_overrides_are_fitted_to_the_grid_tile(self):
         from bigdl_tpu.ops.flash_attention import _tiles

@@ -45,12 +45,19 @@ def _fwd_bwd(grid, sub):
 
 @pytest.mark.parametrize("shape,fn,calls", [
     # gpt2m_train_*: 8 x 16 heads, T 1024, head 64 — one grid tile
-    # walked in 512s, the carry never leaves the kernel's values
-    pytest.param((8, 16, 1024, 64), _fwd_bwd(None, None), 3, id="train"),
+    # walked in 512s, the carry never leaves the kernel's values; the
+    # backward is ONE kernel (one key grid tile): 2 calls a layer
+    pytest.param((8, 16, 1024, 64), _fwd_bwd(None, None), 2, id="train"),
+    # the fused backward at wide heads (one tile of 512) and with dk/dv
+    # resting in scratch between two query grid tiles
+    pytest.param((4, 8, 512, 128), _fwd_bwd(None, None), 2,
+                 id="train_head128"),
+    pytest.param((2, 20, 512, 256), _fwd_bwd(None, None), 2,
+                 id="train_head256"),
     # mistral7b_serve_prefill: 8 x 32 heads, T 2048, head 128 — 2 x 2
     # grid tiles, a schedule per tile offset, carry through scratch
     pytest.param((8, 32, 2048, 128), _fwd(None, None), 1, id="prefill"),
-    # the backward over several grid tiles AND sub-tiles
+    # the backward over several grid tiles AND sub-tiles: dKdV and dQ
     pytest.param((1, 16, 2048, 64), _fwd_bwd(512, 128), 3, id="bwd_grid"),
     # the decode cells' prompt: one sub-tile or less
     pytest.param((16, 32, 128, 128), _fwd(None, None), 1, id="prompt"),

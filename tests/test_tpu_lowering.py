@@ -36,18 +36,22 @@ def _grad_of_sum(fn):
                     argnums=(0, 1, 2))
 
 
-@pytest.mark.parametrize("shape", [
-    (16, 8, 1024, 128),   # the smoke's train step (block 512)
-    (4, 8, 4096, 128),    # long context (block 1024)
-    (2, 8, 8192, 128),
+@pytest.mark.parametrize("shape,calls", [
+    # several key grid tiles: forward, dK/dV and dQ
+    ((16, 8, 1024, 128), 3),   # the smoke's train step (block 512)
+    ((4, 8, 4096, 128), 3),    # long context (block 1024)
+    ((2, 8, 8192, 128), 3),
+    # one key grid tile: forward and the fused backward
+    ((8, 16, 1024, 64), 2),    # the training cells (block 1024)
+    ((16, 8, 512, 128), 2),    # wide heads at one tile of 512
+    ((2, 20, 512, 256), 2),    # latent attention's expanded heads
 ])
-def test_flash_fwd_and_bwd_lower_for_tpu(shape):
+def test_flash_fwd_and_bwd_lower_for_tpu(shape, calls):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     text = _lower_tpu(_grad_of_sum(
         lambda q, k, v: _flash(q, k, v, True, 0.088, False, None, None)),
         x, x, x)
-    # forward, dK/dV and dQ
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == calls
 
 
 def test_fused_layer_norm_lowers_for_tpu():

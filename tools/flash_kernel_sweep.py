@@ -1,8 +1,11 @@
 """The flash kernels alone, on the chip: ms a call and share of the
-roofline for the forward, dKdV and dQ kernels apart, over grid tile x
-sub-tile, at the two shapes the benchmark's cells run — [128, 1024, 64]
-forward + backward (gpt2m training) and [256, 2048, 128] forward
-(mistral prefill).  PERF.md §6 "PR 30" holds the table this printed.
+roofline for the forward and the backward kernels apart — the fused
+backward where the key axis is one grid tile (``bwd_ms``), dKdV and dQ
+where it is several or the checkout predates the fused kernel — over
+grid tile x sub-tile, at the two shapes the benchmark's cells run —
+[128, 1024, 64] forward + backward (gpt2m training) and [256, 2048,
+128] forward (mistral prefill).  PERF.md §6 "PR 30" and "PR 39" hold
+the tables this printed.
 
 Several applications are chained in ONE dispatch (each round's outputs
 feed the next round's inputs), and the kernels' seconds are read from
@@ -12,9 +15,9 @@ clock over the whole dispatch stands beside them as a check.
 
     python tools/flash_kernel_sweep.py --parent .bench_parent
 
-``--parent <checkout>`` also times that checkout's kernels (a tree
-whose ``_flash_fwd`` has no sub-tile).  Needs a TPU; through the chip
-tool only.  Writes ``chiprun_out/flash_kernel_sweep.json``.
+``--parent <checkout> ...`` also times those checkouts' kernels over
+the same tiles (trees from PR 30 on: the sub-tile is an argument).
+Needs a TPU; through the chip tool only.  Writes ``chiprun_out/flash_kernel_sweep.json``.
 """
 import argparse
 import importlib.util
@@ -33,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import counts, trace_reduce
-from bigdl_tpu.ops.flash_attention import _flash_bwd, _flash_fwd
 
 SHAPES = {  # name: (batch*heads, T, head_dim, with backward)
     "train_128x1024x64": (128, 1024, 64, True),
@@ -67,12 +69,13 @@ def _chain(fwd, bwd, rounds, with_bwd):
 
 
 def _kind(hlo_text):
-    """fwd / dkv / dq by the custom call's result: the forward also
-    returns the f32 row statistic, dKdV two tensors, dQ one."""
+    """fwd / bwd / dkv / dq by the custom call's result: the forward
+    also returns the f32 row statistic, the fused backward three
+    tensors, dKdV two, dQ one."""
     result = hlo_text.split("custom-call(")[0]
     if "f32[" in result:
         return "fwd"
-    return "dkv" if result.count("bf16[") == 2 else "dq"
+    return {3: "bwd", 2: "dkv", 1: "dq"}[result.count("bf16[")]
 
 
 def _kernel_seconds(trace_dir):
@@ -113,21 +116,26 @@ def measure(label, fwd, bwd, shape_name, rounds, peak):
     row["fwd_roofline_pct"] = (100 * need["fwd_flops"] / peak
                                / (row["fwd_ms"] / 1e3))
     if with_bwd:
+        # one fused kernel, or dKdV and dQ
+        bwd_ms = row.setdefault("bwd_ms", row.get("dkv_ms", 0.0)
+                                + row.get("dq_ms", 0.0))
         row["bwd_roofline_pct"] = (100 * need["bwd_flops"] / peak
-                                   / ((row["dkv_ms"] + row["dq_ms"]) / 1e3))
+                                   / (bwd_ms / 1e3))
         row["all_roofline_pct"] = (
             100 * (need["fwd_flops"] + need["bwd_flops"]) / peak
-            / ((row["fwd_ms"] + row["dkv_ms"] + row["dq_ms"]) / 1e3))
+            / ((row["fwd_ms"] + bwd_ms) / 1e3))
     print(json.dumps(row), flush=True)
     return row
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--parent", default=None)
+    ap.add_argument("--parent", nargs="*", default=[])
+    ap.add_argument("--shape", nargs="*", default=list(SHAPES),
+                    choices=list(SHAPES))
     ap.add_argument("--rounds", type=int, default=12)
     ap.add_argument("--grid", type=int, nargs="*", default=[512, 1024])
-    ap.add_argument("--sub", nargs="*", default=["128", "256", "512"],
+    ap.add_argument("--sub", nargs="*", default=["256", "512", "1024"],
                     help="sub-tiles: N, or QxK for a non-square one")
     ap.add_argument("--out", default="chiprun_out/flash_kernel_sweep.json")
     args = ap.parse_args()
@@ -141,32 +149,29 @@ def main():
     subs = [tuple(int(x) for x in s.split("x")) if "x" in s else int(s)
             for s in args.sub]
     rows = []
-    pa = _load_parent(args.parent) if args.parent else None
-    for shape_name, (bh, t, d, _) in SHAPES.items():
+    # the package's attribute of that name is the function
+    here = importlib.import_module("bigdl_tpu.ops.flash_attention")
+    trees = [(os.path.basename(os.path.normpath(c)) + " ", _load_parent(c))
+             for c in args.parent] + [("", here)]
+    for shape_name in args.shape:
+        bh, t, d, _ = SHAPES[shape_name]
         scale = 1.0 / d ** 0.5
-        if pa:
-            for grid in [None] + args.grid:
-                g_ = grid or pa._pick_block(t, d)
+        # a sub-tile over the grid tile is fitted down to it: the same row
+        variants = [(None, None)] + [
+            (g_, s) for g_ in args.grid for s in subs
+            if max(s if isinstance(s, tuple) else (s,)) <= g_]
+        for prefix, mod in trees:
+            for grid, sub in variants:
+                label = prefix + ("chosen from the shape" if grid is None
+                                  else "grid %d sub %s" % (grid, sub))
                 rows.append(measure(
-                    "parent grid %s%s" % (g_, "" if grid else " (its choice)"),
-                    lambda q, k, v, g_=g_: pa._flash_fwd(
-                        q, k, v, True, scale, g_, g_, False),
-                    lambda q, k, v, o, lse, g, g_=g_: pa._flash_bwd(
-                        q, k, v, o, lse, g, True, scale, g_, g_, False),
+                    label,
+                    lambda q, k, v: mod._flash_fwd(
+                        q, k, v, True, scale, grid, grid, sub, False),
+                    lambda q, k, v, o, lse, g: mod._flash_bwd(
+                        q, k, v, o, lse, g, True, scale, grid, grid, sub,
+                        False),
                     shape_name, args.rounds, peak))
-        variants = [(None, None)] + [(g_, s) for g_ in args.grid
-                                     for s in subs]
-        for grid, sub in variants:
-            label = ("chosen from the shape" if grid is None
-                     else "grid %d sub %s" % (grid, sub))
-            rows.append(measure(
-                label,
-                lambda q, k, v: _flash_fwd(
-                    q, k, v, True, scale, grid, grid, sub, False),
-                lambda q, k, v, o, lse, g: _flash_bwd(
-                    q, k, v, o, lse, g, True, scale, grid, grid, sub,
-                    False),
-                shape_name, args.rounds, peak))
     from bigdl_tpu.telemetry.tracer import default_tracer
     events = [s.to_dict() for s in default_tracer().spans()
               if s.name == "flash.schedule"]
