@@ -1,8 +1,9 @@
 """Autoregressive generation for TransformerLM, HybridMambaLM,
-ParallelMoELM and LatentMoELM — KV-cache decode, with a recurrent state
-beside the K/V where a block has one, a cache of each layer's own
-length where layers differ in what they see, and a LATENT cache (no K
-or V by head) where a block's attention is latent.
+ParallelMoELM and SequentialMoELM — KV-cache decode, with a recurrent
+state beside the K/V where a block has one, a cache of each layer's own
+length where layers differ in what they see, a LATENT cache (no K or V
+by head) where a block's attention is latent, and NO cache but a
+two-position tail where a block's operator is a short convolution.
 
 The reference predates autoregressive LMs entirely (its sequence story
 is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
@@ -51,7 +52,16 @@ where a layer's cache is large, on a TPU, ONE Pallas kernel walks the
 cache in blocks up to the block the step's position falls in — each
 block read once for scores, softmax and ``P c_kv``, nothing beyond it
 fetched; everywhere else the plain einsums read the whole static cache
-twice.  What a block is made of is decided in one place
+twice.
+
+A block whose operator is a gated SHORT CONVOLUTION
+(``nn.GatedShortConv``; ``models/latent_moe.py``'s ``ShortConvMoELM``)
+has no attention at all: its whole state is ``conv`` ``[B, kernel - 1,
+D]``, the last values of its gated input, whatever the context — no
+``k``, no ``v``, no position.  Prefill keeps the tail of the prompt, a
+decode step reads it, writes one output from ``kernel`` values and
+shifts.  The head geometry of such a model is its first attention
+layer's.  What a block is made of is decided in one place
 (:func:`_block_kind`).
 
 Built from the model's OWN parameter tree and modules (the
@@ -89,6 +99,7 @@ from __future__ import annotations
 
 import threading
 import weakref
+from contextlib import nullcontext
 from functools import partial
 from typing import Optional
 
@@ -106,15 +117,16 @@ _GEN_CACHE = weakref.WeakKeyDictionary()
 
 def _check_model(model):
     from .hybrid_mamba import HybridMambaLM
-    from .latent_moe import LatentMoELM
+    from .latent_moe import SequentialMoELM
     from .parallel_moe import ParallelMoELM
     from .transformer import TransformerLM
 
     if not isinstance(model, (TransformerLM, HybridMambaLM, ParallelMoELM,
-                              LatentMoELM)):
+                              SequentialMoELM)):
         raise TypeError(
             f"generation supports TransformerLM, HybridMambaLM, "
-            f"ParallelMoELM and LatentMoELM (got {type(model).__name__})")
+            f"ParallelMoELM and SequentialMoELM (LatentMoELM, "
+            f"ShortConvMoELM; got {type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
     # so a ring/Ulysses-trained model decodes through the same cached
@@ -125,25 +137,27 @@ def _check_model(model):
 
 def _block_kind(block) -> tuple:
     """What a block is made of — decided HERE and nowhere else:
-    ``(form, attention, experts)``.
+    ``(form, operator, experts)``.
 
     * ``form``: ``"hybrid"`` (``nn.HybridMambaBlock``: a recurrent state
       beside its attention), ``"parallel"``
       (``models.parallel_moe.ParallelMoEBlock``: attention and the
-      expert layer read one normed input) or ``"sequential"`` (attention,
-      then the FFN on a second norm);
-    * ``attention``: ``"latent"`` (``nn.LatentAttention``: the cache
-      holds the latent and the shared rotated key) or ``"kv"`` (per-head
-      K and V);
+      expert layer read one normed input) or ``"sequential"`` (the
+      operator, then the FFN on a second norm);
+    * ``operator``: ``"latent"`` (``nn.LatentAttention``: the cache
+      holds the latent and the shared rotated key), ``"conv"``
+      (``nn.GatedShortConv``: no attention; the cache holds the
+      convolution's tail and nothing else) or ``"kv"`` (per-head K and
+      V);
     * ``experts``: the block's ``DroplessMoE`` (its cache carries
       ``moe_counts``), or None."""
     form = {"hybrid_mamba": "hybrid", "parallel_moe": "parallel"}.get(
         getattr(block, "kind", None), "sequential")
-    attention = ("latent" if getattr(block.modules[1], "kind", None)
-                 == "latent" else "kv")
+    operator = {"latent": "latent", "short_conv": "conv"}.get(
+        getattr(block.modules[1], "kind", None), "kv")
     experts = (block.moe if form == "parallel"
                or getattr(block, "ffn_kind", None) == "moe" else None)
-    return form, attention, experts
+    return form, operator, experts
 
 
 def _is_hybrid(block) -> bool:
@@ -156,6 +170,24 @@ def _is_parallel(block) -> bool:
 
 def _is_latent(block) -> bool:
     return _block_kind(block)[1] == "latent"
+
+
+def _is_conv(block) -> bool:
+    return _block_kind(block)[1] == "conv"
+
+
+def _head_geometry(blocks) -> tuple:
+    """``(heads, K/V heads, head size)`` of the model's per-head K/V,
+    from the first block that keeps one: a block without attention has
+    no heads to ask for, and a latent block keeps nothing by head (its
+    head count is returned for a model of latent blocks alone, with no
+    head size — such a model uses none)."""
+    by_kind = {}
+    for b in blocks:
+        by_kind.setdefault(_block_kind(b)[1], b.modules[1])
+    mha = by_kind.get("kv", by_kind.get("latent"))
+    H = getattr(mha, "num_heads", None)
+    return H, getattr(mha, "num_kv_heads", H), getattr(mha, "head_dim", None)
 
 
 def _window_of(block):
@@ -171,6 +203,14 @@ def _refuse_recurrent(model, first, count, what: str):
     expert layer is refused too, not decoded as another model."""
     blocks = model.modules[first:first + count]
     _refuse_latent(blocks, what, "K/V pages [Hkv, page, Dh]")
+    conv = [b.modules[1] for b in blocks if _is_conv(b)]
+    if conv:
+        raise TypeError(
+            f"{what} pages K/V only and {type(conv[0]).__name__} keeps no "
+            f"K or V at all — its whole state is a convolution tail of "
+            f"{conv[0].kernel - 1} positions a row: decode this model "
+            f"through generate() / submit_generate(), whose static cache "
+            f"holds a tail where a layer has one")
     if any(_is_parallel(b) or _window_of(b) for b in blocks):
         raise TypeError(
             f"{what} keeps pages of ONE length for every layer and runs "
@@ -337,14 +377,16 @@ def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None,
 
 
 def _ffn_sublayer(block, bp, h):
-    """ln2 + the block's MLP (gelu / swiglu / capacity-free MoE) with
-    the residual add — the post-attention half of a block, shared by
-    the dense-cache and paged machineries."""
+    """ln2 + the block's MLP (gelu / swiglu / one gated module /
+    capacity-free MoE) with the residual add — the post-attention half
+    of a block, shared by the dense-cache and paged machineries."""
     ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
     kind = getattr(block, "mlp_kind",
                    "moe" if block.is_moe else "gelu")
     if kind == "moe":
         ffn = _moe_ffn_nodrop(block.modules[3], bp["3"], ln2)
+    elif kind == "gated":       # the whole SwiGLU is child 3 (GatedFFN)
+        ffn, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False, None)
     elif kind == "swiglu":
         # a hybrid block's two muP constants; (1, 1) multiplies nothing
         gm, dm = getattr(block, "mlp_multipliers", (1.0, 1.0))
@@ -392,10 +434,15 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
     (the rotated key all heads share; positions minor, so that no
     position's ``rope`` numbers are padded to a lane tile and the
     attend's kernel reads what the leaf holds) — no leaf has a head
-    axis, and a position holds ``kv_rank + rope`` numbers."""
-    form, attention, experts = _block_kind(block)
+    axis, and a position holds ``kv_rank + rope`` numbers.  A block
+    whose operator is a SHORT CONVOLUTION keeps ``conv`` ``[B, kernel -
+    1, D]`` and nothing else: no ``k``, no ``v``, under ``kv_int8``
+    too (the tail is not K/V and stays in ``dt``)."""
+    form, operator, experts = _block_kind(block)
     mha = block.modules[1]
-    if attention == "latent":
+    if operator == "conv":
+        cache = mha.state_init(B, dt)
+    elif operator == "latent":
         cache = {"ckv": jnp.zeros((B, T_cache, mha.kv_rank), dt),
                  "kr": jnp.zeros((B, mha.rope_dim, T_cache), dt)}
     else:
@@ -423,10 +470,11 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     tokens and ``max_new`` answer tokens holds on the device, from
     shapes alone: ``kv_cache_positions`` (how long that program's
     static cache is, :func:`_cache_len`, against the model's
-    ``max_len``), ``kv_cache_bytes`` (the K/V of every layer at that
-    length: what is allocated, and what every decode step reads) and
-    ``recurrent_state_bytes`` (SSM state and conv tail; zero for a
-    model without them).  Where some layer has a sliding window, K/V
+    ``max_len``), ``kv_cache_bytes`` (the K/V of every layer THAT KEEPS
+    ONE at that length: what is allocated, and what every decode step
+    reads) and ``recurrent_state_bytes`` (SSM state and conv tail — a
+    short-convolution layer's whole state; zero for a model without
+    them).  Where some layer has a sliding window, K/V
     is also given by KIND of layer: ``kv_cache_bytes_window`` (layers
     that keep ``min(positions, window)``) and ``kv_cache_bytes_full``.
     A model with latent attention also gives ``latent_cache_bytes``
@@ -487,7 +535,8 @@ def _decode_machinery(model, first, count, kv_int8=False):
     read per step buys throughput; the prompt's own prefill attention
     stays full-precision (only post-prefill decode steps read the
     quantized cache).  Lossy by construction — an approximation knob,
-    off by default."""
+    off by default.  It quantises K and V and nothing else: a layer
+    without attention keeps its convolution tail as it is."""
     blocks = model.modules[first:first + count]
     ln_f = model.modules[first + count]
     head = model.modules[first + count + 1]
@@ -495,10 +544,10 @@ def _decode_machinery(model, first, count, kv_int8=False):
     if kv_int8:
         _refuse_latent(blocks, 'kv_dtype="int8"',
                        "K and V by head as int8 with a scale a head")
-    mha0 = blocks[0].modules[1]
-    # per-head K/V geometry (a latent block has none and uses none)
-    H, Dh = mha0.num_heads, getattr(mha0, "head_dim", None)
-    Hkv = getattr(mha0, "num_kv_heads", H)   # GQA: smaller KV caches
+    # per-head K/V geometry, GQA's smaller K/V head count included (a
+    # latent block has none and uses none; a block without attention
+    # is passed over)
+    H, Hkv, Dh = _head_geometry(blocks)
     use_rope = getattr(model, "use_rope", False)
     tied = getattr(model, "tied_head", False)
 
@@ -642,6 +691,9 @@ def _decode_machinery(model, first, count, kv_int8=False):
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
         v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
         k = _scaled(k, getattr(mha, "key_multiplier", 1.0))
+        # per-head QK-norm where the module has it, BEFORE the rotation:
+        # the cache holds normed, rotated keys
+        q, k = mha.normed_heads(ap, q, k)
         rope, rope_theta = _rope_of(mha)
         if rope:
             # rotate at ABSOLUTE positions; the cache stores rotated
@@ -682,6 +734,20 @@ def _decode_machinery(model, first, count, kv_int8=False):
         o = o.transpose(0, 2, 1, 3).reshape(B, o.shape[2], H * Dh)
         return _proj(o, ap, "wo", "bo", mha.with_bias), cache
 
+    def _operator(block, operator, ap, ln1, cache, pos):
+        """The token-mixing operator of one sequential block on Tq
+        tokens at ``pos``: cached attention, or the short convolution
+        over its tail (prefill keeps the prompt's last values, a step
+        shifts one in)."""
+        if operator != "conv":
+            return _attention(block, ap, ln1, cache, pos)
+        conv = block.modules[1]
+        if isinstance(pos, int) and pos == 0:
+            a, state = conv.sequence(ap, ln1)
+        else:
+            a, state = conv.step(ap, ln1, cache)
+        return a, {**cache, **state}
+
     def _block_step(block, bp, h, cache, pos):
         """One block on Tq tokens (prefill: Tq=T0 at pos 0; decode:
         Tq=1) against its cache; returns (h, cache).  A hybrid block's
@@ -689,16 +755,18 @@ def _decode_machinery(model, first, count, kv_int8=False):
         runs the chunked scan from an empty state and keeps the state
         after the last prompt token, a decode step advances it."""
         ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
-        form, attention, experts = _block_kind(block)
-        if form == "sequential" and attention == "latent":
-            with jax.named_scope("block.attention"):
-                a, cache = _attention(block, bp["1"], ln1, cache, pos)
+        form, operator, experts = _block_kind(block)
+        if form == "sequential":
+            # ONE arm for every operator (per-head K/V, latent, short
+            # convolution) and every FFN; a block that names its
+            # operator's device scope gets it
+            with getattr(block, "operator_scope", nullcontext)():
+                a, cache = _operator(block, operator, bp["1"], ln1, cache,
+                                     pos)
             h = h + a
-            ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
             if experts is None:
-                m, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
-                                                 None)
-                return h + m, cache
+                return _ffn_sublayer(block, bp, h), cache
+            ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
             B, Tq, D = ln2.shape
             m, counts = experts.routed(bp["3"], ln2.reshape(B * Tq, D),
                                        batch=B)
@@ -713,9 +781,6 @@ def _decode_machinery(model, first, count, kv_int8=False):
                                          batch=B)
             return (h + a + m.reshape(B, Tq, D),
                     {**cache, "moe_counts": cache["moe_counts"] + counts})
-        if form == "sequential":
-            a, cache = _attention(block, bp["1"], ln1, cache, pos)
-            return _ffn_sublayer(block, bp, h + a), cache
         with jax.named_scope("mixer.attention"):
             a, cache = _attention(
                 block, bp["1"],
@@ -1093,6 +1158,7 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
         q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
         k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
         v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
+        q, k = mha.normed_heads(ap, q, k)
         if use_rope:
             from ..nn.attention import rope_rotate
 

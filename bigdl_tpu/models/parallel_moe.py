@@ -69,6 +69,42 @@ class TiedHead(TensorModule):
         return jnp.dot(x, params["weight"].T.astype(x.dtype)), buffers
 
 
+class TiedHeadTrees:
+    """Mixed into a ``Container`` whose LAST child is a head that owns
+    no leaf where ``self.tied_head`` holds: the head's (empty) entry is
+    left out of the parameter-shaped trees, so a tree names exactly the
+    leaves a checkpoint or a reference has, and is accepted back with
+    or without it."""
+
+    tied_head = True
+
+    def _leafed(self, tree):
+        if not self.tied_head:
+            return tree
+        return {k: v for k, v in tree.items()
+                if k != str(len(self.modules) - 1)}
+
+    def _with_head(self, tree):
+        if not self.tied_head:
+            return tree
+        return {**tree, str(len(self.modules) - 1): {}}
+
+    def param_tree(self):
+        return self._leafed(super().param_tree())
+
+    def grad_tree(self):
+        return self._leafed(super().grad_tree())
+
+    def gradient_scale_tree(self):
+        return self._leafed(super().gradient_scale_tree())
+
+    def set_grad_tree(self, tree):
+        super().set_grad_tree(self._with_head(tree))
+
+    def set_param_tree(self, tree):
+        super().set_param_tree(self._with_head(tree))
+
+
 class ParallelMoEBlock(Container):
     """``x + Attn(LN(x)) + MoE(LN(x))``.  Children, in the order the
     generation builder relies on: ``0`` the LayerNorm (no bias), ``1``
@@ -124,7 +160,7 @@ class ParallelMoEBlock(Container):
         return x + a + run(2, n), buffers
 
 
-class ParallelMoELM(Container):
+class ParallelMoELM(TiedHeadTrees, Container):
     """Decoder-only causal LM over 1-based token ids [batch, seq]."""
 
     def __init__(self, vocab_size: int, embed_dim: int, num_heads: int,
@@ -146,7 +182,6 @@ class ParallelMoELM(Container):
         self.embed_dim = embed_dim
         self.max_len = max_len
         self.use_rope = True            # no position table to add
-        self.tied_head = True
         self.logit_scale = float(logit_scale)
         self.param_dtype = (jnp.dtype(param_dtype).name if param_dtype
                             else None)
@@ -173,31 +208,8 @@ class ParallelMoELM(Container):
                               self.param_dtype))
             self.add(TiedHead())
 
-    # the head owns no leaf: its (empty) entry is left out of the
-    # parameter-shaped trees, so the tree names exactly the leaves a
-    # checkpoint or a reference has, and accepted back with or without
-    def _leafed(self, tree):
-        return {k: v for k, v in tree.items()
-                if k != str(len(self.modules) - 1)}
-
-    def _with_head(self, tree):
-        return {**tree, str(len(self.modules) - 1): {}}
-
-    def param_tree(self):
-        return self._leafed(super().param_tree())
-
-    def grad_tree(self):
-        return self._leafed(super().grad_tree())
-
-    def gradient_scale_tree(self):
-        return self._leafed(super().gradient_scale_tree())
-
-    def set_grad_tree(self, tree):
-        super().set_grad_tree(self._with_head(tree))
-
     def set_param_tree(self, tree):
-        super().set_param_tree(self._with_head(
-            hold_floats(tree, self.param_dtype)))
+        super().set_param_tree(hold_floats(tree, self.param_dtype))
 
     def reset(self):
         with device_draw():

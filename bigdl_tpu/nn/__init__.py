@@ -68,6 +68,7 @@ from .criterion import (
 )
 from .attention import LatentAttention, MultiHeadAttention
 from .mamba import HybridMambaBlock, Mamba2Mixer
+from .short_conv import GatedShortConv
 from .recurrent import (
     BiRecurrent, Cell, ConvLSTMPeephole, GRU, LSTM, LSTMPeephole, Recurrent,
     RnnCell, TimeDistributed,

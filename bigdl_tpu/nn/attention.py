@@ -110,7 +110,8 @@ class MultiHeadAttention(TensorModule):
                  sparse_block: "int | None" = None,
                  head_dim: "int | None" = None,
                  key_multiplier: float = 1.0,
-                 window: "int | None" = None):
+                 window: "int | None" = None,
+                 qk_norm: bool = False, norm_eps: float = 1e-6):
         super().__init__()
         assert head_dim or embed_dim % num_heads == 0, \
             "embed_dim % num_heads != 0"
@@ -124,6 +125,9 @@ class MultiHeadAttention(TensorModule):
         self.head_dim = int(head_dim or embed_dim // num_heads)
         # a constant scale on the keys (a muP multiplier); 1 = none
         self.key_multiplier = float(key_multiplier)
+        # RMSNorm over the head_dim numbers of EACH query and key head
+        # (one gain vector for all heads), before the rotation
+        self.qk_norm, self.norm_eps = bool(qk_norm), float(norm_eps)
         self.causal = causal
         self.with_bias = with_bias
         self.seq_strategy = seq_strategy
@@ -178,7 +182,20 @@ class MultiHeadAttention(TensorModule):
         if self.with_bias:
             for name, n in (("bq", qd), ("bk", kv), ("bv", kv), ("bo", E)):
                 self._register_param(name, b_init.init((n,), ONE_D))
+        if getattr(self, "qk_norm", False):
+            for name in ("q_norm", "k_norm"):
+                self._register_param(name, jnp.ones((self.head_dim,),
+                                                    jnp.float32))
         return self
+
+    def normed_heads(self, params, q, k):
+        """``q`` / ``k`` [B, heads, T, head_dim] through the per-head
+        RMSNorms (``qk_norm``); as they came without them.  Shared with
+        the cached decoder, which keeps the normed, rotated keys."""
+        if not getattr(self, "qk_norm", False):
+            return q, k
+        return (rms_normed(q, params["q_norm"], self.norm_eps),
+                rms_normed(k, params["k_norm"], self.norm_eps))
 
     def _split(self, x, heads=None):
         B, T, _ = x.shape
@@ -251,6 +268,7 @@ class MultiHeadAttention(TensorModule):
         v = self._split(proj(x, params["wv"], "bv"), self.num_kv_heads)
         if getattr(self, "key_multiplier", 1.0) != 1.0:
             k = k * self.key_multiplier
+        q, k = self.normed_heads(params, q, k)
         if self.rope:
             pos = jnp.arange(q.shape[2])
             il = getattr(self, "rope_kind", "half") == "interleaved"

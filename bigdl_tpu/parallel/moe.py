@@ -275,14 +275,16 @@ MAX_UNROLLED_PIECES = 32
 
 def route_top_k(x2, router_w, router_b, top_k: int,
                 scoring: str = "softmax", renormalize: bool = True,
-                select_bias=None, gate_scale: float = 1.0):
+                select_bias=None, gate_scale: float = 1.0,
+                renorm_eps: float = 1e-20):
     """Scores over ALL experts in float32 from ``x2`` [N, D] (whatever
     dtype it has: the product accumulates in float32), the ``top_k``
     largest, and their gates — the scores themselves, or divided by
     their sum under ``renormalize``.  ``select_bias`` [E] (the
     ``noaux_tc`` router's correction) is added to the scores for the
     SELECTION only: the gates are the unbiased scores of the chosen
-    experts, renormalised with the reference's ``1e-20`` in the sum.
+    experts, renormalised with ``renorm_eps`` in the sum (``noaux_tc``'s
+    ``1e-20``; ``lfm2_moe`` has ``1e-6``).
     ``gate_scale`` multiplies the gates last.  -> (gates [N, K] in at
     least float32, idx [N, K])."""
     with jax.named_scope("moe.route"):
@@ -307,7 +309,7 @@ def route_top_k(x2, router_w, router_b, top_k: int,
             gates = jnp.take_along_axis(scores, idx, axis=-1)
             if renormalize:
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + renorm_eps)
         if gate_scale != 1.0:
             gates = gates * gate_scale
     return gates, idx
@@ -487,7 +489,8 @@ class DroplessMoE(TensorModule):
     embed]).  ``score_bias`` adds the leaf ``score_bias`` [n_experts],
     float32 whatever dtype the others are held in
     (:data:`FLOAT32_LEAVES`), zeros until given: it enters the selection
-    only (:func:`route_top_k`'s ``select_bias``); ``routed_scale``
+    only (:func:`route_top_k`'s ``select_bias``) and the chosen scores
+    are renormalised with ``renorm_eps`` in the sum; ``routed_scale``
     multiplies the gates.
 
     Dispatch (:func:`dropless_apply`): assignments to held experts
@@ -502,8 +505,10 @@ class DroplessMoE(TensorModule):
                  top_k: int = 2, scoring: str = "softmax",
                  renormalize: bool = True, n_shared: int = 0,
                  held: Optional[tuple] = None, init_std: float = 0.02,
-                 score_bias: bool = False, routed_scale: float = 1.0):
+                 score_bias: bool = False, routed_scale: float = 1.0,
+                 renorm_eps: float = 1e-20):
         super().__init__()
+        self.renorm_eps = float(renorm_eps)
         self.init_std = float(init_std)
         self.score_bias = bool(score_bias)
         self.routed_scale = float(routed_scale)
@@ -569,10 +574,15 @@ class DroplessMoE(TensorModule):
         """(the held experts' part plus the shared mean [N, D], the
         assignments each held expert took: [count] int32, or [batch,
         count] — by leading row of the ``batch`` the tokens came in)."""
+        # a layer names ``renorm_eps`` only where it departs from the
+        # default: every other layer's call is the one it always made
+        eps = getattr(self, "renorm_eps", 1e-20)
         gates, idx = route_top_k(x2, params["router_w"], None, self.top_k,
                                  self.scoring, self.renormalize,
                                  params.get("score_bias"),
-                                 self.routed_scale)
+                                 self.routed_scale,
+                                 **({} if eps == 1e-20
+                                    else {"renorm_eps": eps}))
         y, sizes = dropless_apply(
             x2, idx, gates, self.held,
             swiglu_experts(params["w_gate"], params["w_up"],

@@ -133,6 +133,15 @@ PROGRAM_SPANS = {
 #: q_lat`` and ``o_lat -> o``), ``mla.attend`` scores, softmax and the
 #: weighted sum over the cached latent, ``mla.out_proj`` the output
 #: projection.
+#: ``block.conv`` the operator sublayer of a block WITHOUT attention
+#: (``models/latent_moe.py``'s sequential block over
+#: ``nn/short_conv.py``) — the twin of ``block.attention``, which the
+#: same model's attention layers carry: norm, operator and residual in
+#: the block's ``apply_fn``, the operator in a generate program — and
+#: inside it ``conv.in_proj`` the one product that gives ``B``, ``C``
+#: and ``u``, ``conv.short`` everything between the two products
+#: (``B * u``, the ``kernel``-tap sum over the tail, the tail's shift,
+#: ``C *``), ``conv.out_proj`` the output projection.
 #: ``step.*`` the parts of one compiled TRAINING step
 #: (``parallel/plan.py``'s local step; ``train.*`` stay host spans); the
 #: backward has no scope of its own: autodiff wraps the outermost
@@ -171,6 +180,7 @@ DEVICE_SCOPES = (
     "moe.shared", "block.attention",
     "mla.q_proj", "mla.kv_latent", "mla.expand", "mla.absorb",
     "mla.attend", "mla.out_proj",
+    "block.conv", "conv.in_proj", "conv.short", "conv.out_proj",
     "step.cast_params", "step.forward", "step.loss", "step.grad_reduce",
     "step.update",
     "lm.embed", "block.mlp", "lm.head", "attention.core",

@@ -324,6 +324,19 @@ MODULE_CASES = {
         DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", n_shared=1,
                     held=(0, 3), init_std=0.3, score_bias=True,
                     routed_scale=1.8), 8, 1e-5), lambda: X8, {}),
+    # the gated short convolution (no attention: three taps over the
+    # gated input), per-head QK-norm before the rotation, and the
+    # sequential block over each of them
+    "GatedShortConv": (lambda: nn.GatedShortConv(8, 3, init_std=0.3),
+                       lambda: X8, {}),
+    "MultiHeadAttentionQKNorm": (lambda: nn.MultiHeadAttention(
+        8, 2, causal=True, with_bias=False, num_kv_heads=1, head_dim=4,
+        rope=True, qk_norm=True, norm_eps=1e-5), lambda: X8, {}),
+    "ShortConvMoEBlock": (lambda: LatentMoEBlock(
+        nn.GatedShortConv(8, 3, init_std=0.3),
+        DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", init_std=0.3,
+                    score_bias=True, renorm_eps=1e-6), 8, 1e-5),
+        lambda: X8, {}),
     "Narrow": (lambda: nn.Narrow(2, 2, 3), lambda: X, {}),
     "NarrowTable": (lambda: nn.NarrowTable(1, 2),
                     lambda: T(X, X2, XP), {}),

@@ -133,13 +133,51 @@ def main():
             "  `wkv_a`, `kv_norm`, `wkv_b`, `wo`.  `apply_fn` is the",
             "  EXPANDED full-sequence form (dense or flash causal",
             "  attention), differentiable by autodiff.",
-            "- **`models.latent_moe.LatentMoELM`** (PR 36; `param_dtype` and",
-            "  the device draw): a `Container` of SEQUENTIAL pre-norm",
-            "  blocks (`LatentMoEBlock`: RMSNorm, `LatentAttention`,",
-            "  RMSNorm, FFN) whose FFN is a dense SwiGLU (`GatedFFN`) for",
-            "  the first `first_dense` layers and a `DroplessMoE` after",
-            "  them (`score_bias=True`, `routed_scale`), with an untied",
-            "  head that gives float32 logits (`LogitHead`).",
+            "- **`nn.GatedShortConv(embed_dim, kernel=3)`** (PR 40): the",
+            "  gated short convolution of `lfm2` / `lfm2_moe` — `(B, C, u) =",
+            "  split(w_in x)`, a depthwise causal convolution of `kernel`",
+            "  taps over `B * u` (`nn.mamba.causal_conv`), `w_out (C * .)`,",
+            "  no bias.  Leaves `w_in` `[3D, D]`, `conv` `[kernel, D]`,",
+            "  `w_out` `[D, D]`.  `apply_fn` is the whole sequence;",
+            "  `sequence` / `step` / `state_init` are `Mamba2Mixer`'s",
+            "  contract over the one state it has: `{\"conv\": [batch,",
+            "  kernel - 1, D]}`, whatever the context.  No K, no V.",
+            "- **`nn.MultiHeadAttention(qk_norm=False, norm_eps=1e-6)`**",
+            "  (PR 40): with `qk_norm`, leaves `q_norm` / `k_norm`",
+            "  `[head_dim]` and an RMSNorm over the numbers of EACH query",
+            "  and key head (one gain vector for all heads) before the",
+            "  rotation, in every `seq_strategy` (`normed_heads`, which the",
+            "  cached and the paged decoder call too: a cache holds normed,",
+            "  rotated keys).  Off by default: no leaf, no operation.",
+            "- **`models.latent_moe.SequentialMoELM(vocab_size, embed_dim,",
+            "  operators, ffns, tied_head=False, ...)`** (PR 40; `param_dtype`",
+            "  and the device draw): the `Container` of SEQUENTIAL pre-norm",
+            "  blocks (`SequentialMoEBlock`: RMSNorm, operator, RMSNorm, FFN;",
+            "  `LatentMoEBlock` is its older name) with ONE factory a layer",
+            "  for the operator (`LatentAttention`, `MultiHeadAttention` or",
+            "  `GatedShortConv`) and one for the FFN (`GatedFFN` or",
+            "  `DroplessMoE`), and a `LogitHead` that gives float32 logits",
+            "  from a matrix of its own or (`tied_head`: it owns no leaf,",
+            "  `parallel_moe.TiedHeadTrees` leaves its entry out of the",
+            "  trees) from the embedding's.  Two families are constructors",
+            "  of it and add no method:",
+            "- **`models.latent_moe.ShortConvMoELM`** (PR 40; `lfm2_moe`,",
+            "  LFM2-24B-A2B): `layer_types[i]` `\"conv\"` or",
+            "  `\"full_attention\"` (grouped-query, `qk_norm=True`, rotation",
+            "  by halves), a dense FFN in the first `first_dense` layers and",
+            "  experts after them (`score_bias=True`, `renorm_eps=1e-6`, no",
+            "  shared expert, `held` default all), a tied head.",
+            "  `generate()` / `submit_generate` keep K/V for the attention",
+            "  layers ONLY and a two-position tail for each conv layer",
+            "  (`cache_footprint`: `kv_cache_bytes` sums the layers that",
+            "  keep K/V, the tails are `recurrent_state_bytes`);",
+            "  `kv_dtype=\"int8\"` quantises those K/V and leaves the tails;",
+            "  `PagedDecoder` refuses the block by name.",
+            "- **`models.latent_moe.LatentMoELM`** (PR 36; the same",
+            "  container since PR 40, its tree and its programs unchanged):",
+            "  `LatentAttention` in every layer, a dense SwiGLU (`GatedFFN`)",
+            "  for the first `first_dense` layers and a `DroplessMoE` after",
+            "  them (`score_bias=True`, `routed_scale`), an untied head.",
             "  `generate()` / `submit_generate` keep the latent and the",
             "  shared rotated key of every position (`ckv`, `kr`) and",
             "  nothing by head: prefill expands them once, a decode step",
@@ -150,12 +188,14 @@ def main():
             "  elsewhere — `attend_plan` decides by shapes).",
             "  `PagedDecoder` and `kv_dtype=\"int8\"` refuse it by name.",
             "- **`parallel.moe.route_top_k(..., select_bias=None,",
-            "  gate_scale=1.0)`** / **`DroplessMoE(score_bias=False,",
-            "  routed_scale=1.0)`**: the `noaux_tc` router — a per-expert",
+            "  gate_scale=1.0, renorm_eps=1e-20)`** /",
+            "  **`DroplessMoE(score_bias=False, routed_scale=1.0,",
+            "  renorm_eps=1e-20)`**: the `noaux_tc` router — a per-expert",
             "  bias (leaf `score_bias`, float32 whatever the model holds",
             "  or computes in: `parallel.moe.FLOAT32_LEAVES`) added to the",
             "  scores for the SELECTION only; the gates are the unbiased",
-            "  scores of the chosen, renormalised, times `routed_scale`.",
+            "  scores of the chosen, renormalised (`renorm_eps` in the",
+            "  sum: `lfm2_moe` has 1e-6), times `routed_scale`.",
             "  The defaults are the router as it was.",
             "- **`parallel.moe.DroplessMoE`**: scores ALL `n_experts`",
             "  (`softmax` or `sigmoid`, float32), keeps `top_k`, and",
