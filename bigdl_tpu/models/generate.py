@@ -16,9 +16,21 @@ updated in place via ``lax.dynamic_update_slice``.  No Python-level
 loop over tokens, no recompilation per length.  ``T_cache`` is what
 THIS program can use — prompt + ``max_new``, both static, rounded up
 to a multiple of 128 and never past ``max_len`` (:func:`_cache_len`):
-a decode step reads its whole cache, so a cache as long as the model's
-positional table would make every step pay for positions no call of
-this program can ever write.
+a decode step of the plain form reads its whole cache, so a cache as
+long as the model's positional table would make every step pay for
+positions no call of this program can ever write.
+
+TWO decode attends are chosen by shapes alone, each by the
+``attend_plan`` of its op, with no argument, flag or model name: the
+attend on per-head K/V (``ops/gqa_attend.py``) and the absorbed attend
+on a latent cache (``ops/latent_attend.py``, below).  Where a layer's
+cache is large, on a TPU, ONE Pallas kernel walks it in blocks of 128
+positions up to the block the step's position falls in — each block
+read once for scores, softmax and the weighted sum, nothing beyond it
+fetched.  Everywhere else — small buckets, every other backend,
+``Tq > 1``, and for per-head K/V a ring or int8 storage — the plain
+einsums (:func:`_gqa_attend`, the one plain form of the per-head attend
+and the kernel's reference) read the whole static cache twice.
 
 A hybrid block (``nn.HybridMambaBlock``) keeps, beside its K/V, the
 Mamba-2 mixer's SSM state ``[B, heads, head, N]`` (float32) and conv
@@ -484,7 +496,10 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     ``latent_attend`` (``"kernel"`` or ``"einsum"``) and
     ``latent_attend_block`` (positions a block of the kernel's walk; 0
     for the einsums) — ``ops.latent_attend.attend_plan``, the rule the
-    step itself reads."""
+    step itself reads.  A model with per-head K/V gives the same of its
+    decode attend: ``kv_attend`` and ``kv_attend_block``, from
+    ``ops.gqa_attend.attend_plan`` (the layers that are no ring; a ring
+    keeps the einsums)."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -496,11 +511,14 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     by_kind = {"kv_cache_bytes_window": 0, "kv_cache_bytes_full": 0}
     if any(_is_latent(b) for b in blocks):
         out["latent_cache_bytes"] = 0
+    plain = None    # what a K/V layer that is no ring stores
     for block in blocks:
         shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
                                         T_cache, dt, _kv_int8(kv_dtype)))
         for name, a in shapes.items():
             nbytes = a.size * a.dtype.itemsize
+            if name == "k" and a.shape[2] != _window_of(block):
+                plain = a.dtype
             if name in ("k", "v", "k_scale", "v_scale"):
                 out["kv_cache_bytes"] += nbytes
                 by_kind["kv_cache_bytes_window" if _window_of(block)
@@ -519,6 +537,16 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                             mla.rope_dim, dt)
         out.update(latent_attend="kernel" if block else "einsum",
                    latent_attend_block=block)
+    if out["kv_cache_bytes"]:
+        from ..ops.gqa_attend import attend_plan
+
+        # every layer that is not a ring keeps T_cache positions and
+        # attends alike; a ring keeps the einsums whatever its size
+        _, Hkv, Dh = _head_geometry(blocks)
+        plan = 0 if plain is None else attend_plan(
+            int(batch), Hkv, T_cache, Dh, plain)
+        out.update(kv_attend="kernel" if plan else "einsum",
+                   kv_attend_block=plan)
     return out
 
 
@@ -571,14 +599,27 @@ def _decode_machinery(model, first, count, kv_int8=False):
         return jnp.repeat(kv, H // Hkv, axis=1)
 
     def _attend(q, k_cache, v_cache, pos, window=None):
-        if window is None:
-            return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh)
-        # a ring: slot s holds the latest position <= pos that is s
-        # mod the ring's length (negative: none yet)
-        ring = k_cache.shape[2]
-        k_pos = pos - (pos - jnp.arange(ring)) % ring
-        return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh,
-                           k_pos=k_pos, window=window)
+        from ..ops.gqa_attend import _gqa_attend_kernel, attend_plan
+
+        # one kernel pass over the written part of the cache where the
+        # shapes say it wins, ``_gqa_attend`` over the whole of it
+        # otherwise (a ring, int8 storage, ``Tq > 1``, a small cache,
+        # every backend but a TPU)
+        block = attend_plan(q.shape[0], Hkv, k_cache.shape[2], Dh,
+                            jnp.int8 if kv_int8 else k_cache.dtype,
+                            q.shape[2], window)
+        with jax.named_scope("attention.decode_attend"):
+            if block:
+                return _gqa_attend_kernel(q[:, :, 0], k_cache, v_cache, pos,
+                                          block, False)[:, :, None]
+            if window is None:
+                return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh)
+            # a ring: slot s holds the latest position <= pos that is s
+            # mod the ring's length (negative: none yet)
+            ring = k_cache.shape[2]
+            k_pos = pos - (pos - jnp.arange(ring)) % ring
+            return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh,
+                               k_pos=k_pos, window=window)
 
     def _quant(x):
         """absmax int8 over the head dim: x ≈ q * s, q int8,

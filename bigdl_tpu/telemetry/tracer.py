@@ -184,6 +184,7 @@ DEVICE_SCOPES = (
     "step.cast_params", "step.forward", "step.loss", "step.grad_reduce",
     "step.update",
     "lm.embed", "block.mlp", "lm.head", "attention.core",
+    "attention.decode_attend",
 )
 
 

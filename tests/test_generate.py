@@ -624,7 +624,8 @@ def test_cache_footprint_counts_the_allocated_cache(t0, max_new, max_len,
     got = cache_footprint(model, 3, t0, max_new)
     assert got == {
         "kv_cache_positions": want, "recurrent_state_bytes": 0,
-        "kv_cache_bytes": LAYERS * 2 * 3 * 2 * want * (EMBED // 4) * 4}
+        "kv_cache_bytes": LAYERS * 2 * 3 * 2 * want * (EMBED // 4) * 4,
+        "kv_attend": "einsum", "kv_attend_block": 0}    # the CPU's arm
     q8 = cache_footprint(model, 3, t0, max_new, kv_dtype="int8")
     assert q8["kv_cache_bytes"] == LAYERS * 2 * 3 * 2 * want * (4 + 4)
     with pytest.raises(ValueError, match="exceeds max_len"):

@@ -428,13 +428,15 @@ def test_dispatch_span_says_what_the_bucket_holds():
     # 11 + 3 positions of this model's 48: the whole table is the cache
     got = cache_footprint(model, 4, 11, 3, compute_dtype=jnp.float32)
     assert got == {"kv_cache_bytes": kv(48), "recurrent_state_bytes": rec,
-                   "kv_cache_positions": 48}
+                   "kv_cache_positions": 48,
+                   "kv_attend": "einsum", "kv_attend_block": 0}
     # the same call on a long table holds 128 positions, not 640, and
     # the recurrent state does not care
     long = _model(flat, max_len=640)
     got = cache_footprint(long, 4, 11, 3, compute_dtype=jnp.float32)
     assert got == {"kv_cache_bytes": kv(128), "recurrent_state_bytes": rec,
-                   "kv_cache_positions": 128}
+                   "kv_cache_positions": 128,
+                   "kv_attend": "einsum", "kv_attend_block": 0}
     dense = TransformerLM(23, embed_dim=16, num_heads=2, mlp_dim=32,
                           num_layers=2, max_len=24)
     assert cache_footprint(dense, 2, 5, 7)["recurrent_state_bytes"] == 0

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bigdl_tpu.ops import gqa_attend
 from bigdl_tpu.ops.flash_attention import _flash
 from bigdl_tpu.ops.latent_attend import BLOCK_POSITIONS, _latent_attend_kernel
 
@@ -87,3 +88,82 @@ def test_latent_attend_kernel_compiles_for_v5e(one_chip, rows):
                 S(rows, 20, 512), S(rows, 20, 64), S(rows, 640, 512),
                 S(rows, 64, 640), S(dt=jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("B,H,Hkv,Dh,T", [
+    # lfm2moe_serve_decode_sat: groups of 4 (a quarter of a sublane
+    # tile), a head of 64
+    pytest.param(256, 32, 8, 64, 384, id="lfm2_head64"),
+    # commandaplus_serve_decode_sat: groups of 16, a head of 128
+    pytest.param(128, 128, 8, 128, 256, id="commandaplus_head128"),
+    # falconh1_serve_decode_sat: groups of 5
+    pytest.param(64, 20, 4, 128, 384, id="falconh1_group5"),
+])
+def test_gqa_attend_kernel_compiles_for_v5e(one_chip, B, H, Hkv, Dh, T):
+    """The decode attend of the cells with per-head K/V.  An ARGUMENT of
+    ``[.., T, 64]`` lies positions-minor on the chip and the kernel
+    reads it row-major, so here it is copied once; a head of whole lane
+    tiles is not.  In the generate program the leaf is CARRIED row-major
+    and nothing is copied: the test below."""
+    def S(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, pos: gqa_attend._gqa_attend_kernel(
+            q, k, v, pos, gqa_attend.BLOCK_POSITIONS, False)).lower(
+                S(B, H, Dh), S(B, Hkv, T, Dh), S(B, Hkv, T, Dh),
+                S(dt=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    copied = compiled.memory_analysis().temp_size_in_bytes \
+        >= B * Hkv * T * Dh * 2
+    assert copied == (Dh == 64)
+
+
+def test_lfm2_decode_loop_carries_its_kv_row_major(one_chip, monkeypatch):
+    """What the LFM2 cell's gain rests on (PERF.md §6 "PR 41"): because
+    the kernel arm reads them, the compiled generate program carries
+    the ``[256, 8, 384, 64]`` K and V leaves ROW-MAJOR — a step's
+    one-position write is then 2048 rows and no scatter (0.03 ms a leaf
+    where the einsum arm's positions-minor leaf takes 0.43) — and puts
+    no copy of a leaf anywhere, the decode loop included.  The cell's
+    own batch, heads and cache at toy depth and width of everything
+    else (one conv layer, one attention layer with four small
+    experts), the TPU's branches as on the chip."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.models import generate as G
+    from bigdl_tpu.models.latent_moe import ShortConvMoELM
+
+    B, T0, new = 256, 128, 256
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "lfm2-24b-a2b-l5.json")) as f:
+        kw = json.load(f)["program"]["kwargs"]
+    model = ShortConvMoELM(**{
+        **kw, "layer_types": ["conv", "full_attention"], "vocab_size": 256,
+        "mlp_dim": 256, "n_experts": 4, "held": [0, 4], "top_k": 2,
+        "expert_dim": 128, "max_len": T0 + new})
+    Hkv, Dh = kw["num_kv_heads"], kw["head_dim"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert G.cache_footprint(model, B, T0, new)["kv_attend"] == "kernel"
+
+    def S(shape=(), dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    gen = G.make_generate(model, compute_dtype=jnp.bfloat16)
+    run = [c.cell_contents for c in gen.__closure__
+           if hasattr(c.cell_contents, "lower")][0]
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
+                                    model.param_tree())
+    text = run.lower(params, S((B, T0)), new, S((2,), jnp.uint32),
+                     S(dt=jnp.float32), 0, S(dt=jnp.float32), S(), S(),
+                     True, False).compile().as_text()
+    # every instruction that yields a whole leaf: its layout, its opcode
+    leaves = re.findall(
+        rf"= bf16\[{B},{Hkv},{T0 + new},{Dh}\]\{{([\d,]+)[^ ]* ([\w\-]+)\(",
+        text)
+    written = [op for _, op in leaves if op == "dynamic-update-slice"]
+    assert len(written) == 2                    # K and V, in the loop
+    assert {layout for layout, _ in leaves} == {"3,2,1,0"}
+    assert "copy" not in {op for _, op in leaves}

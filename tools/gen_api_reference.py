@@ -187,6 +187,20 @@ def main():
             "  layer's cache is large, on a TPU; the plain einsums",
             "  elsewhere — `attend_plan` decides by shapes).",
             "  `PagedDecoder` and `kv_dtype=\"int8\"` refuse it by name.",
+            "- **`ops.gqa_attend.attend_plan(B, Hkv, T, Dh, dtype, Tq=1,",
+            "  window=None)`** (PR 41): the arm of a decode step's attend",
+            "  on per-head K/V — one query a row against the un-repeated",
+            "  `[B, Hkv, T, Dh]` leaves, every model above but the latent",
+            "  one; the decode step (`generate._decode_machinery`) and",
+            "  `cache_footprint` read the one rule.  Where `attend_plan`",
+            "  says so (a TPU, `Tq == 1`, a cache contiguous from position",
+            "  0 and no ring, K/V stored floating, `T` a whole number of",
+            "  128-position blocks, a head of 64 or of whole lane tiles,",
+            "  and a layer's K + V of `KERNEL_MIN_CACHE_BYTES` or more) ONE",
+            "  Pallas kernel walks the cache up to the block `pos` falls",
+            "  in; elsewhere `generate._gqa_attend`, the plain einsums and",
+            "  the kernel's reference.  `cache_footprint` / `serve.dispatch`",
+            "  say which: `kv_attend`, `kv_attend_block`.",
             "- **`parallel.moe.route_top_k(..., select_bias=None,",
             "  gate_scale=1.0, renorm_eps=1e-20)`** /",
             "  **`DroplessMoE(score_bias=False, routed_scale=1.0,",
