@@ -76,6 +76,18 @@ shifts.  The head geometry of such a model is its first attention
 layer's.  What a block is made of is decided in one place
 (:func:`_block_kind`).
 
+A block whose RESIDUAL is a hyper-connection (``nn.HyperConnection``;
+``models/latent_moe.py``'s ``HyperLatentMoELM``) carries ``n`` streams a
+token: ``prefill`` and ``decode_token`` hand ``[B, Tq, n, D]`` from
+layer to layer, each sublayer reading the mixture and writing the
+result its maps give (``models.latent_moe.sublayer_input`` /
+``sublayer_result``: the sequential arm asks them, for the plain
+residual too), and ``logits_last`` sums the streams.  The caches are
+what the operator's are; beside ``moe_counts`` such a layer's cache
+carries ``mhc_err``, the call's largest distance of a residual map from
+doubly stochastic.  Beam search and the paged decoder refuse the block
+by name.
+
 Built from the model's OWN parameter tree and modules (the
 parallel/pipeline.py pattern): LN/MLP sublayers run through their
 module ``apply_fn``; attention re-derives the q/k/v/o projections from
@@ -111,6 +123,7 @@ from __future__ import annotations
 
 import threading
 import weakref
+from concurrent.futures import CancelledError
 from contextlib import nullcontext
 from functools import partial
 from typing import Optional
@@ -120,11 +133,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..nn.mamba import scaled as _scaled
+from .latent_moe import sublayer_input, sublayer_result
 
 # compiled generators per model instance (weak: dies with the model),
 # keyed by build config.  NOT stored on the module itself — a jitted
 # closure attribute would break the pickle-based checkpoint verbs.
 _GEN_CACHE = weakref.WeakKeyDictionary()
+# tracing and lowering a generate program run the model's Python: one
+# at a time, whichever thread asks (``make_generate.compile_ahead``)
+_LOWER_LOCK = threading.Lock()
 
 
 def _check_model(model):
@@ -138,7 +155,7 @@ def _check_model(model):
         raise TypeError(
             f"generation supports TransformerLM, HybridMambaLM, "
             f"ParallelMoELM and SequentialMoELM (LatentMoELM, "
-            f"ShortConvMoELM; got {type(model).__name__})")
+            f"ShortConvMoELM, HyperLatentMoELM; got {type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
     # so a ring/Ulysses-trained model decodes through the same cached
@@ -214,6 +231,8 @@ def _refuse_recurrent(model, first, count, what: str):
     step is the sequential one, so a block with a window or a parallel
     expert layer is refused too, not decoded as another model."""
     blocks = model.modules[first:first + count]
+    _refuse_streams(blocks, what,
+                    "carries one residual vector [B, 1, D] a token")
     _refuse_latent(blocks, what, "K/V pages [Hkv, page, Dh]")
     conv = [b.modules[1] for b in blocks if _is_conv(b)]
     if conv:
@@ -237,6 +256,20 @@ def _refuse_recurrent(model, first, count, what: str):
             f"carry a recurrent state (SSM state and conv tail) beside "
             f"it: decode this model through generate() / "
             f"submit_generate(), whose static cache holds both")
+
+
+def _refuse_streams(blocks, what: str, why: str):
+    """A hyper-connected block's state between layers is ``n`` streams a
+    token and its cache carries a counter with no batch axis: a decoder
+    built around one residual vector and batch-major cache leaves
+    refuses it, by name."""
+    hyper = [b for b in blocks if getattr(b, "streams", 0)]
+    if hyper:
+        raise TypeError(
+            f"{what} {why} and {type(hyper[0].hyper[0]).__name__} makes "
+            f"the residual of {type(hyper[0]).__name__} "
+            f"{hyper[0].streams} streams a token: decode this model "
+            f"through generate() / submit_generate()")
 
 
 def _refuse_latent(blocks, what: str, holds: str):
@@ -289,8 +322,7 @@ def _cast_params(p, compute_dtype):
     in ``compute_dtype`` (None: as held) but the leaves that stay
     float32 whatever the model computes in (``FLOAT32_LEAVES``: a
     router's selection bias)."""
-    from ..nn.module import hold_floats
-    from ..parallel.moe import FLOAT32_LEAVES
+    from ..nn.module import FLOAT32_LEAVES, hold_floats
 
     return hold_floats(p, compute_dtype, keep=FLOAT32_LEAVES)
 
@@ -388,11 +420,9 @@ def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None,
     return o.reshape(B, H, Tq, Dh)
 
 
-def _ffn_sublayer(block, bp, h):
-    """ln2 + the block's MLP (gelu / swiglu / one gated module /
-    capacity-free MoE) with the residual add — the post-attention half
-    of a block, shared by the dense-cache and paged machineries."""
-    ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
+def _ffn(block, bp, ln2):
+    """The block's MLP (gelu / swiglu / one gated module / capacity-free
+    MoE) on its normed input."""
     kind = getattr(block, "mlp_kind",
                    "moe" if block.is_moe else "gelu")
     if kind == "moe":
@@ -416,7 +446,15 @@ def _ffn_sublayer(block, bp, h):
                                            jax.nn.gelu(mid), False,
                                            None)
         ffn = out
-    return h + ffn
+    return ffn
+
+
+def _ffn_sublayer(block, bp, h):
+    """ln2 + :func:`_ffn` with the residual add — the post-attention
+    half of a block with the plain residual, shared by the dense-cache
+    and paged machineries."""
+    ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
+    return h + _ffn(block, bp, ln2)
 
 
 def _cache_len(T_max, T0, max_new):
@@ -472,6 +510,8 @@ def _cache_init(block, B, T_cache, dt, kv_int8=False):
         cache.update(block.mixer.state_init(B, dt))
     if experts is not None:
         cache["moe_counts"] = jnp.zeros((B, experts.held[1]), jnp.int32)
+    if getattr(block, "streams", 0):   # a counter too: one number a layer
+        cache["mhc_err"] = jnp.zeros((), jnp.float32)
     return cache
 
 
@@ -525,7 +565,7 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                         else "kv_cache_bytes_full"] += nbytes
             elif name in ("ckv", "kr"):
                 out["latent_cache_bytes"] += nbytes
-            elif name != "moe_counts":      # a counter, not a state
+            elif name not in ("moe_counts", "mhc_err"):   # counters
                 out["recurrent_state_bytes"] += nbytes
     if any(_window_of(b) for b in blocks):
         out.update(by_kind)
@@ -578,6 +618,8 @@ def _decode_machinery(model, first, count, kv_int8=False):
     H, Hkv, Dh = _head_geometry(blocks)
     use_rope = getattr(model, "use_rope", False)
     tied = getattr(model, "tied_head", False)
+    # streams of the state between layers: all blocks alike (0: plain)
+    n_streams = getattr(blocks[0], "streams", 0)
 
     def _rope_of(mha):
         """(kind, theta) of ONE block's rotation — "half",
@@ -693,15 +735,10 @@ def _decode_machinery(model, first, count, kv_int8=False):
         if isinstance(pos, int) and pos == 0:
             with jax.named_scope("mla.expand"):
                 k, v = mla.expand(ap, ckv, kr)
-            q = jnp.concatenate([q_nope, q_rope], -1)
-            if v.shape[-1] == q.shape[-1]:
-                from ..ops.flash_attention import flash_attention
-
-                o = flash_attention(q, k, v, causal=True)
-            else:           # the kernels take one head size
-                from ..parallel.ring_attention import attention
-
-                o = attention(q, k, v, causal=True)
+            # the flash kernels take one head size; a narrower value
+            # head goes the plain way, whole scores
+            o = mla.attend_full(q_nope, q_rope, k, v,
+                                flash=v.shape[-1] == mla.qk_dim)
         else:
             w_uk, w_uv = mla.up_weights(ap)
             dt = q_nope.dtype
@@ -715,7 +752,8 @@ def _decode_machinery(model, first, count, kv_int8=False):
                 from ..ops.latent_attend import latent_attend
 
                 o_lat = latent_attend(q_lat, q_rope, cache["ckv"],
-                                      cache["kr"], pos, mla.qk_dim)
+                                      cache["kr"], pos, mla.qk_dim,
+                                      scale_mult=mla.softmax_mult)
             with jax.named_scope("mla.absorb"):
                 o = jnp.einsum("bhqc,hvc->bhqv", o_lat, w_uv.astype(dt))
         with jax.named_scope("mla.out_proj"):
@@ -795,24 +833,36 @@ def _decode_machinery(model, first, count, kv_int8=False):
         mixer reads the same normed input as its attention: prefill
         runs the chunked scan from an empty state and keeps the state
         after the last prompt token, a decode step advances it."""
-        ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
         form, operator, experts = _block_kind(block)
         if form == "sequential":
             # ONE arm for every operator (per-head K/V, latent, short
-            # convolution) and every FFN; a block that names its
+            # convolution), every FFN and both residuals: the block says
+            # what a sublayer reads of the state and how its result goes
+            # back (``h + y``, or a hyper-connection's maps over the
+            # streams ``[B, Tq, n, D]``); a block that names its
             # operator's device scope gets it
+            ln1, co = sublayer_input(block, bp, 0, h)
             with getattr(block, "operator_scope", nullcontext)():
                 a, cache = _operator(block, operator, bp["1"], ln1, cache,
                                      pos)
-            h = h + a
+            h = sublayer_result(block, 0, h, a, co)
+            ln2, co2 = sublayer_input(block, bp, 1, h)
             if experts is None:
-                return _ffn_sublayer(block, bp, h), cache
-            ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
-            B, Tq, D = ln2.shape
-            m, counts = experts.routed(bp["3"], ln2.reshape(B * Tq, D),
-                                       batch=B)
-            return (h + m.reshape(B, Tq, D),
-                    {**cache, "moe_counts": cache["moe_counts"] + counts})
+                h = sublayer_result(block, 1, h, _ffn(block, bp, ln2), co2)
+            else:
+                B, Tq, D = ln2.shape
+                m, counts = experts.routed(bp["3"], ln2.reshape(B * Tq, D),
+                                           batch=B)
+                h = sublayer_result(block, 1, h, m.reshape(B, Tq, D), co2)
+                cache = {**cache,
+                         "moe_counts": cache["moe_counts"] + counts}
+            if co is not None:
+                # the counter of the call: how far from doubly stochastic
+                # the worst residual map of either sublayer was
+                cache = {**cache, "mhc_err": jnp.maximum(
+                    cache["mhc_err"], jnp.maximum(co.err, co2.err))}
+            return h, cache
+        ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
         if form == "parallel":
             # attention and the expert layer read the SAME normed input
             with jax.named_scope("block.attention"):
@@ -841,11 +891,17 @@ def _decode_machinery(model, first, count, kv_int8=False):
             return h
         return h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq)
 
+    def _state_of(h):
+        """The state the first layer takes: the embedding, or where the
+        blocks carry streams every stream the embedding."""
+        return blocks[0].hyper[0].replicate(h) if n_streams else h
+
     def prefill(pc, prompt, dt, T_cache):
-        """The whole prompt in one causal pass; returns (h [B,T0,D],
-        caches) of ``T_cache`` positions with [0, T0) filled."""
+        """The whole prompt in one causal pass; returns (h [B,T0,D] —
+        [B,T0,n,D] where the blocks carry ``n`` streams — and caches) of
+        ``T_cache`` positions with [0, T0) filled."""
         B, T0 = prompt.shape
-        h = _embed_at(pc, prompt, 0, T0)
+        h = _state_of(_embed_at(pc, prompt, 0, T0))
         caches = []
         for bi, block in enumerate(blocks):
             cache = _cache_init(block, B, T_cache, dt, kv_int8)
@@ -857,7 +913,7 @@ def _decode_machinery(model, first, count, kv_int8=False):
     def decode_token(pc, tok, caches, pos):
         """One token [B, 1] at absolute position ``pos``; returns
         (h [B,1,D], new_caches)."""
-        h = _embed_at(pc, tok, pos, 1)
+        h = _state_of(_embed_at(pc, tok, pos, 1))
         new_caches = []
         for bi, block in enumerate(blocks):
             h, cache = _block_step(block, pc[str(first + bi)], h,
@@ -866,8 +922,11 @@ def _decode_machinery(model, first, count, kv_int8=False):
         return h, new_caches
 
     def logits_last(pc, h):
-        """Head on the LAST position of h only -> [B, V] f32."""
-        h = h[:, -1:, :]
+        """Head on the LAST position of h only -> [B, V] f32; the
+        streams of a hyper-connected state are summed first."""
+        h = h[:, -1:]
+        if n_streams:
+            h = blocks[0].hyper[0].reduce(h)
         h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
         # a tied head owns no leaf: it is handed the embedding's
         h, _ = head.apply_fn(pc["0" if tied else str(first + count + 1)],
@@ -904,14 +963,25 @@ def make_generate(model, max_len: Optional[int] = None,
     ``(ids, stats)``: for a model with dropless expert layers ``stats``
     holds ``moe_counts`` ``[expert layers, held]`` int32, the
     assignments each held expert took in the call (fetched with the
-    tokens; a dense layer among them has no row); for any other model
-    it is empty.
+    tokens; a dense layer among them has no row); for a model whose
+    residual is a hyper-connection ``mhc_sinkhorn_err``, the largest
+    ``|rowsum - 1|`` or ``|colsum - 1|`` of a residual map the call
+    computed (a float32 scalar); for any other model it is empty.
+
+    ``generate.compile_ahead(params, batch, prompt_len, max_new,
+    executor)`` starts the compile of the GREEDY program of that shape
+    on ``executor`` (a ``concurrent.futures`` pool) and returns at once;
+    the first greedy call of the shape waits for it and runs what it
+    built.  A server that is going to need a whole ladder of batch sizes
+    compiles them beside each other so (``serving/server.py``): tracing
+    and lowering are Python and take their turn under one lock, XLA's
+    compile of one program runs while the next is lowered.
     """
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
-    counted = any(_block_kind(b)[2] is not None
+    counted = any(_block_kind(b)[2] is not None or getattr(b, "streams", 0)
                   for b in model.modules[first:first + count])
 
     # device scopes (``jax.named_scope``): metadata on the HLO
@@ -998,10 +1068,49 @@ def make_generate(model, max_len: Optional[int] = None,
                 length=max_new - 1)
         if not counted:
             return ids
-        # [layers, held]: the assignments each held expert took in this
-        # call, prefill and every decode step, all rows
-        return ids, jnp.stack([jnp.sum(c["moe_counts"], axis=0)
-                               for c in caches if "moe_counts" in c])
+        stats = {}
+        counts = [jnp.sum(c["moe_counts"], axis=0)
+                  for c in caches if "moe_counts" in c]
+        if counts:
+            # [layers, held]: the assignments each held expert took in
+            # this call, prefill and every decode step, all rows
+            stats["moe_counts"] = jnp.stack(counts)
+        errs = [c["mhc_err"] for c in caches if "mhc_err" in c]
+        if errs:
+            stats["mhc_sinkhorn_err"] = jnp.max(jnp.stack(errs))
+        return ids, stats
+
+    # greedy programs compiled ahead of their first call:
+    # (batch, prompt_len, max_new) -> Future of the executable
+    ahead = {}
+
+    def _compile(params, batch, prompt_len, max_new):
+        prompt = jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)
+        with _LOWER_LOCK:
+            lowered = _run.lower(
+                params, prompt, max_new, jax.random.PRNGKey(0),
+                jnp.float32(0.0), 0, jnp.float32(1.0), jnp.int32(0),
+                jnp.int32(0), True, False)
+        return lowered.compile()
+
+    def compile_ahead(params, batch: int, prompt_len: int, max_new: int,
+                      executor):
+        shape = (int(batch), int(prompt_len), int(max_new))
+        if shape not in ahead:
+            ahead[shape] = executor.submit(_compile, params, *shape)
+
+    def _compiled_ahead(shape):
+        """The executable ``compile_ahead`` built for ``shape``, waited
+        for; None where none was asked for or its pool was shut down
+        before its turn (the call then compiles by itself)."""
+        fut = ahead.get(shape)
+        if fut is None:
+            return None
+        try:
+            return fut.result()
+        except CancelledError:
+            del ahead[shape]
+            return None
 
     def generate(params, prompt_ids, max_new: int, rng=None,
                  temperature: float = 0.0, top_k: int = 0,
@@ -1019,14 +1128,32 @@ def make_generate(model, max_len: Optional[int] = None,
         # greedy call ignores top_k / top_p and shares one program
         greedy = not temperature > 0
         nucleus = bool(not greedy and 0 < top_p < 1)
-        out = _run(params, jnp.asarray(prompt_ids, jnp.int32),
-                   int(max_new), key, jnp.float32(temperature),
-                   0 if greedy else int(top_k), jnp.float32(top_p),
-                   eos, pad, greedy, nucleus)
-        ids, stats = (out[0], {"moe_counts": out[1]}) if counted \
-            else (out, {})
+        prompt = jnp.asarray(prompt_ids, jnp.int32)
+        shape = prompt.shape + (int(max_new),)
+        program = (_compiled_ahead(shape)
+                   if greedy and rng is None else None)
+        if program is not None:
+            try:
+                # the static arguments are part of the executable
+                out = program(params, prompt, key,
+                              jnp.float32(temperature), jnp.float32(top_p),
+                              eos, pad)
+            except TypeError:
+                # an executable serves the parameter tree it was
+                # lowered for: one swapped in at another dtype or
+                # placement goes the jitted way from here on
+                del ahead[shape]
+                program = None
+        if program is None:
+            out = _run(params, prompt, int(max_new), key,
+                       jnp.float32(temperature),
+                       0 if greedy else int(top_k), jnp.float32(top_p),
+                       eos, pad, greedy, nucleus)
+        ids, stats = out if counted else (out, {})
         return (ids, stats) if return_stats else ids
 
+    generate.compile_ahead = compile_ahead
+    generate.ahead = ahead
     return generate
 
 
@@ -1054,6 +1181,8 @@ def make_beam_search(model, max_len: Optional[int] = None,
     the sampling decoder."""
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
+    _refuse_streams(model.modules[first:first + count], "beam search",
+                    "gathers every cache leaf along the beam axis")
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
 

@@ -1,10 +1,22 @@
 """SequentialMoELM — a causal LM of SEQUENTIAL pre-norm blocks whose
 token-mixing OPERATOR is given per layer and whose FFN is a dense SwiGLU
 in the first layers and a mixture of experts after them.  RMSNorm
-throughout and no bias anywhere:
+throughout and no bias anywhere.  The block's RESIDUAL is one of two
+kinds.  Plain, one vector a token:
 
     h = x + Op_i(RMSNorm_1(x))
     y = h + FFN_i(RMSNorm_2(h))
+
+or a hyper-connection (``nn/hyper_connection.py``; ``hyper`` given):
+the state is ``n`` streams a token, ``X [n, C]``, and each sublayer
+``f`` (the operator, the FFN) has a module of its own that computes
+three maps from ``X`` and mixes the streams around it:
+
+    u  = sum_i H_pre[i] X[i];   y = f(RMSNorm(u))
+    X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y
+
+with the embedding repeated into the ``n`` streams before the first
+block and the streams summed before the final norm.
 
 ``Op_i`` is whatever module the layer's factory makes:
 :class:`~bigdl_tpu.nn.attention.LatentAttention` (MLA),
@@ -19,7 +31,7 @@ experts, a per-expert correction bias that enters the selection only
 (``held``), ``n_shared`` shared experts added.  The head gives float32
 logits: a matrix of its own, or (``tied_head``) the embedding's.
 
-Two published families are constructors of it, each building the
+Three published families are constructors of it, each building the
 operator and FFN lists from its configuration's own numbers:
 
 * :class:`LatentMoELM` — Zhipu's ``glm4_moe_lite`` (GLM-4.7-Flash;
@@ -29,12 +41,18 @@ operator and FFN lists from its configuration's own numbers:
   operator follows a published list (``layer_types``: ``"conv"`` or
   ``"full_attention"``), attention is grouped-query with RMSNorm over
   each query and key head, no shared expert, a tied head.
+* :class:`HyperLatentMoELM` — XingChen's ``xing4_0``
+  (Xing4.0-29B-A4B): ``glm4_moe_lite``'s layers — latent attention,
+  here with YaRN and a value head narrower than the query's, the same
+  router — around a residual of ``hc_mult`` streams mixed per token by
+  manifold-constrained hyper-connections, an untied head.
 
 A ``Container`` with ``TransformerLM``'s child layout — ``0`` the
 embedding, ``1..L`` the blocks (children ``0`` RMSNorm, ``1`` the
-operator, ``2`` RMSNorm, ``3`` the FFN), ``L+1`` the final RMSNorm,
-``L+2`` the head — so the generation builder, the server and the
-optimizers take it as they take the dense model.  What ``generate``
+operator, ``2`` RMSNorm, ``3`` the FFN and, where the residual is a
+hyper-connection, ``4`` the operator's and ``5`` the FFN's), ``L+1``
+the final RMSNorm, ``L+2`` the head — so the generation builder, the
+server and the optimizers take it as they take the dense model.  What ``generate``
 keeps a layer depends on its operator (``models/generate.py``): the
 latent ``c_kv`` and the rotated shared key of every position and nothing
 by head; per-head K and V; or, for a short convolution, the last
@@ -44,15 +62,19 @@ the selection bias stays float32.
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..nn.initialization import IN_OUT, RandomNormal, device_draw
-from ..nn.module import Container, TensorModule, hold_floats
-from ..parallel.moe import FLOAT32_LEAVES, DroplessMoE
+from ..nn.hyper_connection import HyperConnection
+from ..nn.initialization import (IN_OUT, RandomNormal, device_draw,
+                                 no_draw)
+from ..nn.module import (FLOAT32_LEAVES, Container, TensorModule,
+                         hold_floats)
+from ..parallel.moe import DroplessMoE
 from .parallel_moe import TiedHeadTrees
 
 
@@ -123,21 +145,71 @@ class LogitHead(TensorModule):
                        preferred_element_type=ct), buffers
 
 
+# -- a sequential block's residual, plain or hyper-connected ---------------
+def _sublayer_scope(block, i: int):
+    """The device scope of sublayer ``i``'s hyper-connection: the
+    block's own operator scope, or ``block.mlp``."""
+    return (block.operator_scope() if i == 0
+            else jax.named_scope("block.mlp"))
+
+
+def sublayer_input(block, params, i: int, state):
+    """What sublayer ``i`` (0 the operator, 1 the FFN) of a block with
+    children norm, operator, norm, FFN reads of ``state``, through the
+    sublayer's own norm (child ``2 i``): ``(normed input [..., embed],
+    coefficients)``.  A plain block — a :class:`SequentialMoEBlock`
+    without ``hyper``, an ``nn.TransformerBlock`` — reads the state
+    itself and has no coefficients (None); a hyper-connected one
+    (``block.hyper``: children ``4`` and ``5``) the mixture its maps
+    give."""
+    co = None
+    if getattr(block, "hyper", None):
+        hc = block.hyper[i]
+        with _sublayer_scope(block, i):
+            co = hc.coefficients(params[str(4 + i)], state)
+            state = hc.pre(co, state)
+    x, _ = block.modules[2 * i].apply_fn(params[str(2 * i)], {}, state,
+                                         False, None)
+    return x, co
+
+
+def sublayer_result(block, i: int, state, y, co):
+    """The state after sublayer ``i`` gave ``y``: ``state + y``, or the
+    hyper-connection's write to every stream."""
+    if co is None:
+        return state + y
+    with _sublayer_scope(block, i):
+        return block.hyper[i].post(co, state, y)
+
+
 class SequentialMoEBlock(Container):
     """``h = x + Op(norm_1 x); y = h + FFN(norm_2 h)``.  Children, in
     the order the generation builder relies on: ``0`` RMSNorm, ``1`` the
     operator (latent or grouped-query attention, or a gated short
     convolution), ``2`` RMSNorm, ``3`` the FFN (``ffn_kind``:
-    ``"dense"`` a :class:`GatedFFN`, ``"moe"`` a ``DroplessMoE``)."""
+    ``"dense"`` a :class:`GatedFFN`, ``"moe"`` a ``DroplessMoE``).
+
+    ``hyper`` (a zero-argument factory of a ``HyperConnection``) makes the
+    residual a hyper-connection: children ``4`` (the operator's) and
+    ``5`` (the FFN's) follow, ``x`` and ``y`` are ``[B, T, n, embed]``,
+    and each sublayer reads and writes the streams through its module's
+    maps (:func:`sublayer_input` / :func:`sublayer_result`, which
+    ``apply_fn`` and the generation builder both call, for the plain
+    residual too).  Without it the block is what it was, program and
+    parameter tree."""
 
     kind = "sequential_moe"
 
     def __init__(self, operator, ffn, embed_dim: int, norm_eps: float,
-                 param_dtype: Optional[str] = None):
-        super().__init__(
-            *(_held_in(m, param_dtype)
-              for m in (nn.RMSNorm(embed_dim, eps=norm_eps), operator,
-                        nn.RMSNorm(embed_dim, eps=norm_eps), ffn)))
+                 param_dtype: Optional[str] = None,
+                 hyper: Optional[Callable] = None):
+        children = [nn.RMSNorm(embed_dim, eps=norm_eps), operator,
+                    nn.RMSNorm(embed_dim, eps=norm_eps), ffn]
+        if hyper is not None:
+            children += [hyper(), hyper()]
+        super().__init__(*(_held_in(m, param_dtype) for m in children))
+        #: the two hyper-connections (operator's, FFN's), or None
+        self.hyper = tuple(self.modules[4:6]) if hyper is not None else None
         self.ffn_kind = "moe" if isinstance(ffn, DroplessMoE) else "dense"
         self.is_moe = self.ffn_kind == "moe"
         if not self.is_moe:
@@ -154,14 +226,22 @@ class SequentialMoEBlock(Container):
             return jax.named_scope("block.conv")
         return jax.named_scope("block.attention")
 
-    def apply_fn(self, params, buffers, x, training, rng):
-        def run(i, v):
-            return self.modules[i].apply_fn(params[str(i)], buffers[str(i)],
-                                            v, training, None)[0]
+    @property
+    def streams(self) -> int:
+        """Streams of the state the block carries: its
+        hyper-connections' ``n``, or 0 for the plain residual (one
+        vector a token)."""
+        return self.hyper[0].n_streams if self.hyper else 0
 
-        with self.operator_scope():
-            h = x + run(1, run(0, x))
-        return h + run(3, run(2, h)), buffers
+    def apply_fn(self, params, buffers, x, training, rng):
+        for i in (0, 1):        # the operator, then the FFN
+            n, co = sublayer_input(self, params, i, x)
+            with self.operator_scope() if i == 0 else nullcontext():
+                y, _ = self.modules[2 * i + 1].apply_fn(
+                    params[str(2 * i + 1)], buffers[str(2 * i + 1)], n,
+                    training, None)
+            x = sublayer_result(self, i, x, y, co)
+        return x, buffers
 
 
 #: the name the block had while latent attention was its only operator
@@ -174,13 +254,20 @@ class SequentialMoELM(TiedHeadTrees, Container):
     ``operators`` and ``ffns`` are one zero-argument FACTORY a layer
     each: a layer's modules are made inside the device draw and cast to
     ``param_dtype`` child by child, so neither a block nor the model is
-    ever whole in float32."""
+    ever whole in float32.  ``hyper`` (a factory of a
+    ``HyperConnection``) gives EVERY block a hyper-connected residual: the embedding is
+    repeated into the streams and they are summed before the final
+    norm.  ``draw_weights=False`` builds the model WITHOUT drawing: every
+    matrix zeros, for a caller that sets the weights next (a checkpoint,
+    seeded leaves) and should not pay the draw's programs first."""
 
     def __init__(self, vocab_size: int, embed_dim: int,
                  operators: Sequence[Callable], ffns: Sequence[Callable],
                  tied_head: bool = False, max_len: int = 2048,
                  norm_eps: float = 1e-5, output: str = "log_probs",
-                 init_std: float = 0.02, param_dtype: Optional[str] = None):
+                 init_std: float = 0.02, param_dtype: Optional[str] = None,
+                 hyper: Optional[Callable] = None,
+                 draw_weights: bool = True):
         if output not in ("log_probs", "logits"):
             raise ValueError(f"output {output!r} not in (log_probs, logits)")
         if len(operators) != len(ffns):
@@ -195,7 +282,7 @@ class SequentialMoELM(TiedHeadTrees, Container):
         self.tied_head = bool(tied_head)
         self.param_dtype = (jnp.dtype(param_dtype).name if param_dtype
                             else None)
-        with device_draw():
+        with device_draw(), (nullcontext() if draw_weights else no_draw()):
             embed = nn.LookupTable(vocab_size, embed_dim)
             embed.set_init_method(RandomNormal(0.0, init_std))
             embed.reset()
@@ -203,7 +290,7 @@ class SequentialMoELM(TiedHeadTrees, Container):
             for make_operator, make_ffn in zip(operators, ffns):
                 self.add(SequentialMoEBlock(make_operator(), make_ffn(),
                                             embed_dim, norm_eps,
-                                            self.param_dtype))
+                                            self.param_dtype, hyper))
             self.add(_held_in(nn.RMSNorm(embed_dim, eps=norm_eps),
                               self.param_dtype))
             self.add(_held_in(LogitHead(embed_dim, vocab_size, init_std,
@@ -240,12 +327,17 @@ class SequentialMoELM(TiedHeadTrees, Container):
 
     def apply_fn(self, params, buffers, x, training, rng):
         h, last = x, len(self.modules) - 1
+        hyper = self.modules[1].hyper
         for i, m in enumerate(self.modules):
             # a tied head owns no leaf: it is handed the embedding's
             tied = self.tied_head and i == last
+            if hyper and i == last - 1:     # before the final norm
+                h = hyper[0].reduce(h)
             h, _ = m.apply_fn(params["0" if tied else str(i)],
                               {} if tied else buffers[str(i)], h, training,
                               None)
+            if hyper and i == 0:            # after the embedding
+                h = hyper[0].replicate(h)
         if self._output_mode == "logits":
             return h, buffers
         return jax.nn.log_softmax(h, axis=-1), buffers
@@ -276,12 +368,16 @@ class LatentMoELM(SequentialMoELM):
                  max_len: int = 2048, rope_theta: float = 10000.0,
                  norm_eps: float = 1e-5, seq_strategy: str = "dense",
                  output: str = "log_probs", init_std: float = 0.02,
-                 param_dtype: Optional[str] = None):
+                 param_dtype: Optional[str] = None,
+                 rope_scaling: Optional[dict] = None,
+                 hyper: Optional[Callable] = None,
+                 draw_weights: bool = True):
         def attention():
             return nn.LatentAttention(
                 embed_dim, num_heads, q_rank, kv_rank, nope_dim, rope_dim,
                 v_dim, rope_theta=rope_theta, norm_eps=norm_eps,
-                seq_strategy=seq_strategy, init_std=init_std)
+                seq_strategy=seq_strategy, init_std=init_std,
+                rope_scaling=rope_scaling)
 
         def experts():
             return DroplessMoE(
@@ -297,7 +393,30 @@ class LatentMoELM(SequentialMoELM):
             _ffn_factories(num_layers, first_dense, embed_dim, mlp_dim,
                            init_std, experts),
             max_len=max_len, norm_eps=norm_eps, output=output,
-            init_std=init_std, param_dtype=param_dtype)
+            init_std=init_std, param_dtype=param_dtype, hyper=hyper,
+            draw_weights=draw_weights)
+
+
+class HyperLatentMoELM(LatentMoELM):
+    """``xing4_0``: :class:`LatentMoELM`'s layers (``rope_scaling``: the
+    configuration's YaRN object) around a residual of ``hc_mult``
+    streams — every sublayer wrapped in a hyper-connection whose
+    residual map takes ``hc_sinkhorn_iters`` Sinkhorn sweeps with
+    ``hc_eps`` in each sum and its logits clamped to ``h_res_clamp``;
+    the RMS over the streams uses the model's ``norm_eps``."""
+
+    def __init__(self, vocab_size: int, embed_dim: int, *args,
+                 hc_mult: int = 4, hc_sinkhorn_iters: int = 20,
+                 hc_eps: float = 1e-6, h_res_clamp=(-30.0, 30.0),
+                 norm_eps: float = 1e-5, init_std: float = 0.02, **kwargs):
+        def hyper():
+            return HyperConnection(
+                embed_dim, hc_mult, hc_sinkhorn_iters, hc_eps, norm_eps,
+                h_res_clamp, init_std)
+
+        super().__init__(vocab_size, embed_dim, *args, hyper=hyper,
+                         norm_eps=norm_eps, init_std=init_std, **kwargs)
+        self.hc_mult = int(hc_mult)
 
 
 #: ``layer_types`` of a ``lfm2_moe`` configuration

@@ -69,6 +69,7 @@ from .criterion import (
 from .attention import LatentAttention, MultiHeadAttention
 from .mamba import HybridMambaBlock, Mamba2Mixer
 from .short_conv import GatedShortConv
+from .hyper_connection import HyperConnection
 from .recurrent import (
     BiRecurrent, Cell, ConvLSTMPeephole, GRU, LSTM, LSTMPeephole, Recurrent,
     RnnCell, TimeDistributed,

@@ -38,17 +38,60 @@ def rope_kind(rope):
     return kind
 
 
-def rope_rotate(x, pos, theta: float = 10000.0, interleaved: bool = False):
+def yarn_rotation(rope_dim: int, theta: float, scaling: dict) -> tuple:
+    """YaRN as DeepSeek-V3's published modelling code computes it from a
+    configuration's ``rope_scaling`` (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``): ``(inv_freq [rope_dim / 2] float32,
+    what multiplies cos and sin, what multiplies the softmax scale)``.
+    Dimension pair ``i`` keeps its frequency ``f_i = theta^(-2i / d)``
+    below ``low``, takes ``f_i / factor`` above ``high`` and a linear
+    blend between; ``m(s) = 0.1 s ln(factor) + 1``."""
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling of type {kind!r}: only 'yarn' is "
+                         "built")
+    d, factor = int(rope_dim), float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def boundary(rotations: float) -> float:
+        return (d * np.log(orig / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(boundary(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(np.ceil(boundary(float(scaling.get("beta_slow", 1)))), d - 1)
+    if low == high:
+        high += 0.001                       # the published guard
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    inv_freq = (f / factor) * ramp + f * (1.0 - ramp)
+
+    def m(s) -> float:
+        return 0.1 * float(s) * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+    all_dim = scaling.get("mscale_all_dim", 0)
+    return (inv_freq.astype(np.float32),
+            float(m(scaling.get("mscale", 1)) / m(all_dim)),
+            float(m(all_dim) ** 2) if all_dim else 1.0)
+
+
+def rope_rotate(x, pos, theta: float = 10000.0, interleaved: bool = False,
+                inv_freq=None, mscale: float = 1.0):
     """Rotary position embedding over ``x`` [B, H, T, D] at absolute
     positions ``pos`` [T]: HF Llama's rotate-half convention (dim ``i``
     pairs with ``i + D/2``), or with ``interleaved`` GPT-J's (dim ``2i``
     pairs with ``2i + 1``) — the same rotation under a permutation of
-    the head's columns."""
+    the head's columns.  ``inv_freq`` [D/2] stands in for ``theta``'s
+    own frequencies where a scaling has blended them
+    (:func:`yarn_rotation`), and ``mscale`` multiplies cos and sin."""
     D = x.shape[-1]
     # like RMSNorm: float64 oracles keep their precision, low-precision
     # inputs still get at least float32 tables
     ct = jnp.promote_types(x.dtype, jnp.float32)
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=ct) / D))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=ct) / D))
+    else:
+        inv = jnp.asarray(inv_freq, ct)
     ang = pos.astype(ct)[:, None] * inv[None, :]            # [T, D/2]
     if interleaved:
         cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)           # [T, D]
@@ -70,6 +113,8 @@ def rope_rotate(x, pos, theta: float = 10000.0, interleaved: bool = False):
         sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
         x1, x2 = x[..., :D // 2], x[..., D // 2:]
         rot = jnp.concatenate([-x2, x1], -1)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     return (x * cos[None, None].astype(x.dtype)
             + rot * sin[None, None].astype(x.dtype))
 
@@ -319,7 +364,8 @@ class LatentAttention(TensorModule):
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
                  rope_theta: float = 10000.0, norm_eps: float = 1e-6,
                  seq_strategy: str = "dense",
-                 init_std: "float | None" = None):
+                 init_std: "float | None" = None,
+                 rope_scaling: "dict | None" = None):
         super().__init__()
         # matrices drawn normal(0, init_std); None: Xavier, as the
         # other attention draws (an init method set later wins)
@@ -338,7 +384,23 @@ class LatentAttention(TensorModule):
         self.qk_dim = nope_dim + rope_dim
         self.rope_theta, self.norm_eps = float(rope_theta), float(norm_eps)
         self.seq_strategy = seq_strategy
+        # no scaling: the rotation's own frequencies, cos and sin as
+        # they are, the scale 1 / sqrt(qk_dim)
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.inv_freq, self.rope_mscale, self.softmax_mult = (
+            yarn_rotation(rope_dim, self.rope_theta, self.rope_scaling)
+            if self.rope_scaling else (None, 1.0, 1.0))
         self.reset()
+
+    @property
+    def softmax_scale(self) -> float:
+        """What multiplies this layer's scores before the softmax."""
+        return self.softmax_mult / float(np.sqrt(self.qk_dim))
+
+    def rotate(self, x, pos):
+        """``x`` [B, H, T, rope] rotated at ``pos`` [T]."""
+        return rope_rotate(x, pos, self.rope_theta, inv_freq=self.inv_freq,
+                           mscale=self.rope_mscale)
 
     def reset(self):
         default = (Xavier() if self.init_std is None
@@ -366,7 +428,7 @@ class LatentAttention(TensorModule):
         q = jnp.dot(cq, params["wq_b"].T).reshape(
             B, T, self.num_heads, self.qk_dim).transpose(0, 2, 1, 3)
         return (q[..., :self.nope_dim],
-                rope_rotate(q[..., self.nope_dim:], pos, self.rope_theta))
+                self.rotate(q[..., self.nope_dim:], pos))
 
     def latent(self, params, x, pos):
         """What the cache keeps of ``x`` at ``pos`` [T]: (c_kv [B, T,
@@ -374,8 +436,7 @@ class LatentAttention(TensorModule):
         ckr = jnp.dot(x, params["wkv_a"].T)
         ckv = rms_normed(ckr[..., :self.kv_rank], params["kv_norm"],
                          self.norm_eps)
-        kr = rope_rotate(ckr[:, None, :, self.kv_rank:], pos,
-                         self.rope_theta)[:, 0]
+        kr = self.rotate(ckr[:, None, :, self.kv_rank:], pos)[:, 0]
         return ckv, kr
 
     def up_weights(self, params):
@@ -397,14 +458,28 @@ class LatentAttention(TensorModule):
              jnp.broadcast_to(kr[:, None], (B, H, T, self.rope_dim))], -1)
         return k, kv[..., self.nope_dim:]
 
-    def attend_full(self, q_nope, q_rope, k, v):
-        """Causal attention of the whole sequence on per-head K and V."""
+    def attend_full(self, q_nope, q_rope, k, v, flash=None):
+        """Causal attention of the whole sequence on per-head K and V at
+        ``softmax_scale``: the flash kernels where ``flash`` says so
+        (None: ``seq_strategy``), else plain attention with whole scores
+        — the only form for a value head narrower than the query's —
+        under the device scope ``mla.prefill_attend``."""
         q = jnp.concatenate([q_nope, q_rope], -1)
-        if self.seq_strategy == "flash":
+        scaled = self.softmax_mult != 1.0
+        if self.seq_strategy == "flash" if flash is None else flash:
             from ..ops import flash_attention
 
-            return flash_attention(q, k, v, causal=True)
-        return attention(q, k, v, causal=True)
+            return flash_attention(
+                q, k, v, causal=True,
+                sm_scale=self.softmax_scale if scaled else None)
+        # where this pass stands in for the flash kernels (a narrower
+        # value head) its scores are float32 as theirs are
+        narrow = v.shape[-1] != q.shape[-1]
+        with jax.named_scope("mla.prefill_attend"):
+            return attention(q, k, v, causal=True,
+                             scale=self.softmax_scale if scaled else None,
+                             score_dtype=jnp.promote_types(
+                                 q.dtype, jnp.float32) if narrow else None)
 
     def out_proj(self, params, o):
         B, H, T, D = o.shape

@@ -38,6 +38,21 @@ def device_draw():
         _draw.on = before
 
 
+@contextlib.contextmanager
+def no_draw():
+    """Inside this block the random initialisers draw NOTHING: a leaf
+    is allocated as zeros.  For a model built to be GIVEN its weights (a
+    checkpoint; a benchmark's seeded leaves): where nothing is cached a
+    model's fifteen shapes cost 40 s of small compilations for numbers
+    that are dropped unread (v5e, PR 42)."""
+    before = getattr(_draw, "skip", False)
+    _draw.skip = True
+    try:
+        yield
+    finally:
+        _draw.skip = before
+
+
 @functools.partial(jax.jit, static_argnames=("shape", "normal"))
 def _device_random(key, a, b, shape, normal):
     """One program a shape and kind (the bounds are arguments): drawn
@@ -65,6 +80,8 @@ def _drawn(normal: bool, a, b, shape):
     which is most of it where nothing is cached — while the draw itself
     runs behind it.  Two clock reads a leaf; no span per leaf in the
     ring."""
+    if getattr(_draw, "skip", False):       # ``no_draw``: nothing booked
+        return jnp.zeros(tuple(shape), jnp.float32)
     t0 = time.perf_counter()
     if getattr(_draw, "on", False):
         where = "device"

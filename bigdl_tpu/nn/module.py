@@ -55,6 +55,15 @@ def to_array(x):
     return x
 
 
+#: leaves that stay float32 where a model holds or computes in a lower
+#: precision (``hold_floats`` with ``keep=FLOAT32_LEAVES``; the
+#: generator's cast): a router's selection bias is compared with float32
+#: scores, and a hyper-connection's gates and biases enter float32
+#: sigmoids and ``exp``
+FLOAT32_LEAVES = ("score_bias", "alpha_pre", "alpha_post", "alpha_res",
+                  "b_pre", "b_post", "b_res")
+
+
 def hold_floats(tree, dtype, keep=()):
     """``tree`` with every floating leaf in ``dtype`` — one cast per
     leaf that is not there yet, none for one that is, so the tree and

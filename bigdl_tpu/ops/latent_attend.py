@@ -73,11 +73,15 @@ _BLOCK_BYTES = 2 << 20
 KERNEL_MIN_CACHE_BYTES = 4 << 20
 
 
-def latent_attend_reference(q_lat, q_rope, ckv, kr, pos, qk_dim: int):
+def latent_attend_reference(q_lat, q_rope, ckv, kr, pos, qk_dim: int,
+                            scale_mult: float = 1.0):
     """The plain form, any ``Tq``: ``q_lat [B, H, Tq, kv_rank]``,
     ``q_rope [B, H, Tq, rope]`` at positions ``pos .. pos + Tq - 1``
     against ``ckv [B, T, kv_rank]`` / ``kr [B, rope, T]`` ->
-    ``o_lat [B, H, Tq, kv_rank]``.  Two reads of the whole cache."""
+    ``o_lat [B, H, Tq, kv_rank]``.  Two reads of the whole cache.  The
+    scores are divided by ``sqrt(qk_dim)`` and, where a rotation scaling
+    asks for it (YaRN's ``mscale^2``: ``LatentAttention.softmax_mult``),
+    multiplied by ``scale_mult``."""
     dt = q_lat.dtype
     ct = jnp.promote_types(dt, jnp.float32)
     qpos = pos + jnp.arange(q_lat.shape[2])
@@ -86,6 +90,8 @@ def latent_attend_reference(q_lat, q_rope, ckv, kr, pos, qk_dim: int):
               + jnp.einsum("bhqr,brk->bhqk", q_rope, kr,
                            preferred_element_type=ct))
     scores = scores / jnp.sqrt(jnp.asarray(qk_dim, ct))
+    if scale_mult != 1.0:
+        scores = scores * jnp.asarray(scale_mult, ct)
     seen = jnp.arange(ckv.shape[1])[None, :] <= qpos[:, None]
     scores = jnp.where(seen[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -125,7 +131,8 @@ def attend_plan(B: int, T: int, kv_rank: int, rope: int, dtype,
 
 
 def _kernel(pos_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, qk_dim: int, block: int, n_blocks: int):
+            acc_scr, *, qk_dim: int, scale_mult: float, block: int,
+            n_blocks: int):
     step = pl.program_id(1)
     pos = pos_ref[0]
     # the walk ends at the block ``pos`` falls in, on the LAST grid step;
@@ -145,6 +152,8 @@ def _kernel(pos_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_scr, l_scr,
              + jnp.einsum("bhr,brk->bhk", qr_ref[...], r_ref[...],
                           preferred_element_type=jnp.float32))
         s = s / jnp.sqrt(jnp.float32(qk_dim))           # [rows, H, block]
+        if scale_mult != 1.0:
+            s = s * jnp.float32(scale_mult)
         if masked:
             at = t * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
             s = jnp.where(at <= pos, s, -jnp.inf)
@@ -170,7 +179,8 @@ def _kernel(pos_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_scr, l_scr,
 
 
 def _latent_attend_kernel(q_lat, q_rope, ckv, kr, pos, qk_dim: int,
-                          block: int, interpret: bool, rows=None):
+                          block: int, interpret: bool, rows=None,
+                          scale_mult: float = 1.0):
     """The kernel arm on ``q_lat [B, H, C]`` / ``q_rope [B, H, R]``;
     ``rows`` (batch rows a program; the sweep's lever) defaults to
     :func:`_rows_per_program`."""
@@ -201,7 +211,8 @@ def _latent_attend_kernel(q_lat, q_rope, ckv, kr, pos, qk_dim: int,
     def by_row(b, step, pos_ref):
         return (b, 0, 0)
 
-    kernel = functools.partial(_kernel, qk_dim=qk_dim, block=block,
+    kernel = functools.partial(_kernel, qk_dim=qk_dim,
+                               scale_mult=float(scale_mult), block=block,
                                n_blocks=n_blocks)
     return pl.pallas_call(
         kernel,
@@ -231,18 +242,21 @@ def _latent_attend_kernel(q_lat, q_rope, ckv, kr, pos, qk_dim: int,
 
 
 def latent_attend(q_lat, q_rope, ckv, kr, pos, qk_dim: int,
-                  interpret: bool = False):
+                  interpret: bool = False, scale_mult: float = 1.0):
     """``o_lat [B, H, Tq, kv_rank]``: attention of the absorbed queries
     ``q_lat [B, H, Tq, kv_rank]`` / ``q_rope [B, H, Tq, rope]`` at
     positions ``pos ..`` on the cached latent ``ckv [B, T, kv_rank]``
     and shared rotated key ``kr [B, rope, T]``, positions ``0 .. pos +
-    Tq - 1`` seen.  The kernel where :func:`attend_plan` says so, the
-    plain einsums otherwise."""
+    Tq - 1`` seen, the scores times ``scale_mult / sqrt(qk_dim)``.  The
+    kernel where :func:`attend_plan` says so, the plain einsums
+    otherwise."""
     B, H, Tq, C = q_lat.shape
     block = attend_plan(B, ckv.shape[1], C, kr.shape[1], ckv.dtype, Tq,
                         interpret)
     if not block:
-        return latent_attend_reference(q_lat, q_rope, ckv, kr, pos, qk_dim)
+        return latent_attend_reference(q_lat, q_rope, ckv, kr, pos, qk_dim,
+                                       scale_mult)
     o = _latent_attend_kernel(q_lat[:, :, 0], q_rope[:, :, 0], ckv, kr, pos,
-                              qk_dim, block, interpret)
+                              qk_dim, block, interpret,
+                              scale_mult=scale_mult)
     return o[:, :, None]

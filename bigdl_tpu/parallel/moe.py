@@ -53,7 +53,7 @@ from jax import lax
 
 from ..nn.initialization import (IN_OUT, ONE_D, RandomNormal, Xavier,
                                  Zeros)
-from ..nn.module import TensorModule
+from ..nn.module import FLOAT32_LEAVES, TensorModule  # noqa: F401
 
 
 class MoEFFN(TensorModule):
@@ -259,11 +259,6 @@ class MoEFFN(TensorModule):
 # --------------------------------------------------------------------------
 
 SCORINGS = ("softmax", "sigmoid")
-
-#: leaves that stay float32 where a model holds or computes in a lower
-#: precision (``hold_floats`` / the generator's cast leave them alone):
-#: the selection bias is compared with float32 scores
-FLOAT32_LEAVES = ("score_bias",)
 
 #: rows of the sorted buffer one dispatch may hold: a longer token list
 #: is routed at once and dispatched in equal pieces, so the

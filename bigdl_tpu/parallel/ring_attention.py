@@ -171,17 +171,26 @@ def ulysses_attention(q, k, v, axis_name: str = "seq",
     return heads_to_seq(of)
 
 
-def attention(q, k, v, causal: bool = False):
+def attention(q, k, v, causal: bool = False, scale=None,
+              score_dtype=None):
     """Dense reference attention (materialises [T, T]); oracle for tests
     and the fast path for short sequences where one matmul wins.
+    ``scale`` multiplies the scores (None: ``1 / sqrt(head size)``); the
+    value head may be narrower than the query's.  ``score_dtype`` holds
+    the scores in another dtype than the inputs' (float32: as the flash
+    kernels hold theirs — for a caller whose whole-score pass stands in
+    for one).
 
     Scores stay in the INPUT dtype (bf16 under mixed precision — an f32
     [B,H,T,T] tensor is pure HBM burn, measured 25% of the whole dense
     grad on a v5e); only the softmax normalisation accumulates f32,
     which preserves the max-subtracted exp's accuracy.
     """
-    scale = jnp.asarray(1.0 / np.sqrt(q.shape[-1]), q.dtype)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    sdt = q.dtype if score_dtype is None else score_dtype
+    scale = jnp.asarray(1.0 / np.sqrt(q.shape[-1]) if scale is None
+                        else scale, sdt)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=score_dtype) * scale
     if causal:
         Tq, Tk = s.shape[-2:]
         s = jnp.where(jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :],

@@ -36,9 +36,12 @@ batch + rollback — see :mod:`.swap`).
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import logging
+import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
 import jax
@@ -231,6 +234,9 @@ class InferenceServer:
         #: (bucket, prompt length, max_new) -> cache_footprint of that
         #: generate program (span args)
         self._gen_footprints = {}
+        #: threads that compile a generate ladder's programs beside
+        #: each other (``_compile_ladder``); made with the first one
+        self._compile_pool: Optional[ThreadPoolExecutor] = None
         self._queue = _BoundedQueue(max_queue)
         self._batch_window_s = float(batch_window_s)
         self._default_deadline_s = default_deadline_s
@@ -282,6 +288,7 @@ class InferenceServer:
         self._draining = False
         self._hard_stop = False
         self._drained.clear()
+        self._settle_heap()
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="bigdl-serving-worker")
         self._worker.start()
@@ -299,8 +306,28 @@ class InferenceServer:
         if self._preemption is not None:
             self._preemption.__exit__(None, None, None)
             self._preemption = None
+        if self._compile_pool is not None:
+            # what has not begun is dropped; a later call of that shape
+            # compiles by itself
+            self._compile_pool.shutdown(wait=False, cancel_futures=True)
+            self._compile_pool = None
+        gc.unfreeze()
         self._started = False
         return done
+
+    @staticmethod
+    def _settle_heap():
+        """What is alive now is here to stay while the server runs —
+        jax, the model, the programs compiled so far: 600 000 tracked
+        objects on a serving process — so it is moved out of the
+        collector's sight (``gc.freeze``; ``drain`` gives it back).  A
+        full collection then walks what the serving loop made since, a
+        few milliseconds, where it took 0.22 s with the GIL held: the
+        worker needs the GIL at every batch boundary, and a collection
+        that fell on one left the device idle that long (once a window
+        of the benchmark's closed loop, PERF.md section 6 "PR 42").
+        Called at ``start`` and after every batch that compiled."""
+        gc.freeze()
 
     def stop(self, timeout: Optional[float] = None) -> bool:
         """Hard shutdown: still-queued requests resolve CANCELLED (the
@@ -820,6 +847,8 @@ class InferenceServer:
                     out_np = jax.tree_util.tree_map(np.asarray, out)
                 with self._model_lock:
                     self._canary_x = xj  # freshest known-good canary
+                if new_sig:
+                    self._settle_heap()
             else:
                 out_np, bucket = self._run_generate(params, reqs)
             batch_span.set(bucket=bucket)
@@ -1172,6 +1201,26 @@ class InferenceServer:
             live = nxt
         self.metrics.set_kv_pool(pool.stats())
 
+    def _compile_ladder(self, gen, params, sig):
+        """The first batch of a (prompt length, ``max_new``) is the
+        first of its ladder: callers that fill this bucket will fill
+        the others, and each new one would stop the worker for a whole
+        compile.  All of them start compiling now, beside each other
+        on the compile pool — this batch's first, which ``gen`` then
+        waits for — so a ladder costs about its slowest program, not
+        their sum (cold start of an autoscaled replica; the warm-up of
+        a benchmark)."""
+        _, bucket, prompt_len, max_new = sig
+        ladder = self.batcher.ladder
+        if self._compile_pool is None:
+            self._compile_pool = ThreadPoolExecutor(
+                max_workers=max(1, min(len(ladder),
+                                       (os.cpu_count() or 2) - 1)),
+                thread_name_prefix="bigdl-serving-compile")
+        for b in [bucket] + [b for b in ladder if b != bucket]:
+            gen.compile_ahead(params, b, prompt_len, max_new,
+                              self._compile_pool)
+
     def _run_generate(self, params, reqs):
         """One compiled decode program per (bucket, prompt_len,
         max_new): prompts stack along the batch dim and pad up to the
@@ -1211,6 +1260,8 @@ class InferenceServer:
                      compiled=new_sig, **holds):
             gen = cached_generate(self.model,
                                   compute_dtype=self.generate_dtype)
+            if new_sig and self.mesh is None:
+                self._compile_ladder(gen, params, sig)
             ids, stats = gen(params, prompts_j, max_new, eos_id=eos_id,
                              pad_id=pad_id, return_stats=True)
         with tr.span("serve.fetch", "device_wait") as fetch:
@@ -1222,4 +1273,11 @@ class InferenceServer:
                 fetch.set(**moe_counters(
                     np.asarray(stats["moe_counts"]),
                     bucket * (prompts.shape[1] + max_new - 1)))
+            if "mhc_sinkhorn_err" in stats:
+                # how far from doubly stochastic the call's worst
+                # residual map was (a hyper-connected model)
+                fetch.set(mhc_sinkhorn_err=float(
+                    stats["mhc_sinkhorn_err"]))
+        if new_sig:
+            self._settle_heap()
         return out, bucket

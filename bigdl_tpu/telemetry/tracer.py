@@ -133,6 +133,17 @@ PROGRAM_SPANS = {
 #: q_lat`` and ``o_lat -> o``), ``mla.attend`` scores, softmax and the
 #: weighted sum over the cached latent, ``mla.out_proj`` the output
 #: projection.
+#: ``mla.prefill_attend`` the plain causal attention of a whole sequence
+#: on per-head K and V, whole scores (``nn/attention.py``): the prompt
+#: pass of a latent layer whose value head is narrower than its query's
+#: (the flash kernels take one head size).
+#: ``mhc.*`` the parts of ONE sublayer's hyper-connection
+#: (``nn/hyper_connection.py``), inside that sublayer's scope
+#: (``block.attention``, or ``block.mlp`` for the FFN): ``mhc.coeffs``
+#: the mean square over the streams, the one product with ``phi`` and
+#: the two sigmoids, ``mhc.sinkhorn`` clip, ``exp``, the sweeps and the
+#: error reading, ``mhc.pre`` the mixture a sublayer reads, ``mhc.post``
+#: the write of its result to every stream.
 #: ``block.conv`` the operator sublayer of a block WITHOUT attention
 #: (``models/latent_moe.py``'s sequential block over
 #: ``nn/short_conv.py``) — the twin of ``block.attention``, which the
@@ -179,7 +190,8 @@ DEVICE_SCOPES = (
     "moe.route", "moe.dispatch", "moe.expert_matmul", "moe.combine",
     "moe.shared", "block.attention",
     "mla.q_proj", "mla.kv_latent", "mla.expand", "mla.absorb",
-    "mla.attend", "mla.out_proj",
+    "mla.attend", "mla.out_proj", "mla.prefill_attend",
+    "mhc.coeffs", "mhc.sinkhorn", "mhc.pre", "mhc.post",
     "block.conv", "conv.in_proj", "conv.short", "conv.out_proj",
     "step.cast_params", "step.forward", "step.loss", "step.grad_reduce",
     "step.update",

@@ -184,6 +184,7 @@ X4 = R.randn(2, 3, 8, 8).astype(np.float32)        # NCHW images
 X134 = R.randn(3, 1, 4).astype(np.float32)
 X234 = R.randn(2, 3, 4).astype(np.float32)
 X8 = R.randn(2, 5, 8).astype(np.float32)
+X8S = R.randn(2, 5, 3, 8).astype(np.float32)        # (B, T, streams, C)
 X5D = R.randn(1, 2, 4, 6, 6).astype(np.float32)    # NCDHW
 XC = R.randn(2, 3, 3, 8, 8).astype(np.float32)     # (B, T, C, H, W)
 
@@ -194,6 +195,15 @@ _TREE = np.stack([np.array([[2, 3, -1], [0, 0, 1], [4, 5, 0],
 _XTREE = R.randn(2, 3, 4).astype(np.float32)
 
 # name -> (module factory, input factory, kwargs for check_module)
+def _moving(hc):
+    """A hyper-connection whose maps move with the token: gates of 1
+    (the module draws the papers' 0.01)."""
+    tree = hc.param_tree()
+    hc.set_param_tree({**tree, **{k: jnp.ones_like(tree[k]) for k in
+                                  ("alpha_pre", "alpha_post", "alpha_res")}})
+    return hc
+
+
 MODULE_CASES = {
     "Abs": (lambda: nn.Abs(), lambda: XP, {}),
     "Add": (lambda: nn.Add(6), lambda: X, {}),
@@ -337,6 +347,23 @@ MODULE_CASES = {
         DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", init_std=0.3,
                     score_bias=True, renorm_eps=1e-6), 8, 1e-5),
         lambda: X8, {}),
+    # a hyper-connected residual (three streams a token) around latent
+    # attention with YaRN and the expert layer: autodiff through the
+    # sigmoids, ``exp`` and the unrolled Sinkhorn sweeps of both
+    # sublayers' maps (``HyperConnection`` has no forward of its own: a
+    # block calls its three functions)
+    "HyperConnection": (lambda: LatentMoEBlock(
+        nn.LatentAttention(8, 2, q_rank=6, kv_rank=6, nope_dim=4,
+                           rope_dim=2, v_dim=4, rope_scaling={
+                               "type": "yarn", "factor": 4,
+                               "original_max_position_embeddings": 2,
+                               "mscale": 1, "mscale_all_dim": 1}),
+        DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", n_shared=1,
+                    held=(0, 3), init_std=0.3, score_bias=True,
+                    routed_scale=2.0), 8, 1e-6,
+        hyper=lambda: _moving(nn.HyperConnection(
+            8, 3, sinkhorn_iters=5, init_std=0.3))),
+        lambda: X8S, {}),
     "Narrow": (lambda: nn.Narrow(2, 2, 3), lambda: X, {}),
     "NarrowTable": (lambda: nn.NarrowTable(1, 2),
                     lambda: T(X, X2, XP), {}),

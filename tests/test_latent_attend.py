@@ -132,7 +132,7 @@ def _force_kernel(monkeypatch):
                         interpret=False: 32 if Tq == 1 else 0)
     monkeypatch.setattr(
         L, "_latent_attend_kernel",
-        lambda *a: real(*a[:-1], True))     # the last is ``interpret``
+        lambda *a, **kw: real(*a[:-1], True, **kw))   # last: ``interpret``
 
 
 def test_greedy_tokens_of_the_kernel_arm_are_the_einsum_arms(monkeypatch):

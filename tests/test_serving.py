@@ -6,6 +6,7 @@ fault injectors in resilience.faults, all on the CPU backend.
 """
 import os
 import signal
+import threading
 import time
 from collections import Counter
 
@@ -365,6 +366,120 @@ def test_generate_microbatch_matches_library_decode():
             srv.submit_generate(prompts[0], max_new=0)
     finally:
         srv.stop(timeout=10)
+
+
+def test_a_new_generate_shape_compiles_its_whole_ladder_ahead():
+    """The first batch of a (prompt length, max_new) starts the compile
+    of every bucket of the ladder on the server's pool — tracing under
+    one lock, XLA's compiles beside each other — and each bucket's
+    first batch runs what was built for it: the tokens are the library
+    decode's, whichever way the program was compiled."""
+    from bigdl_tpu.models.generate import cached_generate, make_generate
+    from bigdl_tpu.models.transformer import TransformerLM
+
+    lm = TransformerLM(61, embed_dim=16, num_heads=2, num_layers=1,
+                       max_len=32, output="logits")
+    srv = InferenceServer(lm, max_batch=4, batch_window_s=0.05).start()
+    try:
+        rng = np.random.RandomState(1)
+        first = rng.randint(1, 61, 6).astype(np.int32)
+        assert srv.submit_generate(first, max_new=4).result(180).ok
+        ahead = cached_generate(lm).ahead
+        assert set(ahead) == {(1, 6, 4), (2, 6, 4), (4, 6, 4)}
+        assert srv._compile_pool is not None
+        prompts = [rng.randint(1, 61, 6).astype(np.int32)
+                   for _ in range(4)]
+        res = [f.result(timeout=180) for f in
+               [srv.submit_generate(p, max_new=4) for p in prompts]]
+        assert all(r.ok for r in res)
+        assert all(f.done() and f.exception() is None
+                   for f in ahead.values())
+        np.testing.assert_array_equal(
+            np.stack([r.output for r in res]),
+            np.asarray(make_generate(lm)(lm.param_tree(),
+                                         np.stack(prompts), 4))[:, 6:])
+        # another max_new is another ladder
+        assert srv.submit_generate(first, max_new=3).result(180).ok
+        assert {(1, 6, 3), (2, 6, 3), (4, 6, 3)} <= set(ahead)
+    finally:
+        srv.stop(timeout=10)
+    assert srv._compile_pool is None
+
+
+def test_a_running_server_keeps_the_settled_heap_from_the_collector(
+        monkeypatch):
+    """``gc.freeze`` at ``start`` and after a batch that compiled — not
+    after a warm one — so a full collection inside the serving loop
+    walks what the loop made and not the process; ``stop`` gives the
+    heap back."""
+    import gc
+
+    from bigdl_tpu.models.transformer import TransformerLM
+
+    calls, real = [], InferenceServer._settle_heap
+    monkeypatch.setattr(InferenceServer, "_settle_heap",
+                        staticmethod(lambda: (calls.append(1), real())))
+    gc.unfreeze()
+    lm = TransformerLM(61, embed_dim=16, num_heads=2, num_layers=1,
+                       max_len=32, output="logits")
+    srv = InferenceServer(lm, max_batch=2, batch_window_s=0.05).start()
+    try:
+        assert len(calls) == 1
+        assert gc.get_freeze_count() > 10_000   # jax and the model
+        prompt = np.arange(1, 7).astype(np.int32)
+        assert srv.submit_generate(prompt, max_new=3).result(180).ok
+        assert len(calls) == 2                  # the batch compiled
+        assert srv.submit_generate(prompt, max_new=3).result(180).ok
+        assert len(calls) == 2                  # a warm batch
+    finally:
+        srv.stop(timeout=10)
+    assert gc.get_freeze_count() == 0
+
+
+def test_compile_ahead_is_the_program_the_call_would_build():
+    """``generate.compile_ahead``: the executable serves greedy calls of
+    its shape only; a sampled call, another shape and a pool shut down
+    before the compile began all go the jitted way, same tokens."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+
+    from bigdl_tpu.models.generate import make_generate
+    from bigdl_tpu.models.transformer import TransformerLM
+
+    lm = TransformerLM(61, embed_dim=16, num_heads=2, num_layers=1,
+                       max_len=32, output="logits")
+    p = lm.param_tree()
+    ids = np.random.RandomState(2).randint(1, 61, (2, 5)).astype(np.int32)
+    want = np.asarray(make_generate(lm)(p, ids, 4))
+    gen = make_generate(lm)
+    with ThreadPoolExecutor(1) as pool:
+        gen.compile_ahead(p, 2, 5, 4, pool)
+        gen.compile_ahead(p, 2, 5, 4, pool)     # asked twice, built once
+        assert list(gen.ahead) == [(2, 5, 4)]
+        np.testing.assert_array_equal(np.asarray(gen(p, ids, 4)), want)
+    assert gen.ahead[(2, 5, 4)].done()
+    np.testing.assert_array_equal(np.asarray(gen(p, ids[:1], 4)), want[:1])
+    assert gen(p, ids, 4, rng=jax.random.PRNGKey(0),
+               temperature=0.7).shape == want.shape
+    # a parameter tree of another dtype than the executable was lowered
+    # for (a swap): the jitted way, and the executable is let go
+    import jax.numpy as jnp
+
+    p16 = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), p)
+    assert np.asarray(gen(p16, ids, 4)).shape == want.shape
+    assert (2, 5, 4) not in gen.ahead
+    # a pool shut down before the compile's turn (a server stopped
+    # while its ladder was queued): the call compiles by itself
+    gate, pool = threading.Event(), ThreadPoolExecutor(1)
+    pool.submit(gate.wait)
+    gen2 = make_generate(lm)
+    gen2.compile_ahead(p, 2, 5, 4, pool)
+    pool.shutdown(wait=False, cancel_futures=True)
+    gate.set()
+    assert gen2.ahead[(2, 5, 4)].cancelled()
+    np.testing.assert_array_equal(np.asarray(gen2(p, ids, 4)), want)
+    assert not gen2.ahead
 
 
 # ---------------------------------------------------------------------------

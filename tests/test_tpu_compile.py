@@ -167,3 +167,77 @@ def test_lfm2_decode_loop_carries_its_kv_row_major(one_chip, monkeypatch):
     assert len(written) == 2                    # K and V, in the loop
     assert {layout for layout, _ in leaves} == {"3,2,1,0"}
     assert "copy" not in {op for _, op in leaves}
+
+
+@pytest.mark.parametrize("rows", [1, 16, 256, 256 * 128])
+def test_sinkhorn_kernel_compiles_at_the_serving_rows(one_chip, monkeypatch,
+                                                      rows):
+    """The residual map of a hyper-connected sublayer
+    (``ops/sinkhorn.py``) at the rows of the Xing4.0 cell: a decode
+    step of the smallest and the largest bucket, a bucket that is no
+    lane tile, and the 32 768 tokens of the largest bucket's prompt —
+    ONE Mosaic call each, twenty sweeps inside it."""
+    from bigdl_tpu.ops.sinkhorn import sinkhorn_map
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((4, 4, rows), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda x: sinkhorn_map(x, 20, 1e-6, -30.0, 30.0)).lower(
+        x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in text        # the sweeps are the kernel's loop
+
+
+def test_xing4_decode_step_names_its_hyper_connections(one_chip, monkeypatch):
+    """The compiled generate program of the Xing4.0 cell's shapes (its
+    batch, widths, heads and streams; toy depth, vocabulary and experts;
+    the TPU's branches as on the chip): every operation of a
+    hyper-connection carries, in its ``op_name``, the stretch, the
+    sublayer's scope and the part's — what ``benchmark/readers/
+    mhc_decode_pct.py`` and ``mla_decode_pct.py`` match — with the one
+    ``jit(...)`` of the function that is traced once for all sublayers
+    between them; the sweeps are ONE Mosaic call a sublayer, and the
+    prompt's attention is the plain one under its own scope."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.models import generate as G
+    from bigdl_tpu.models.latent_moe import HyperLatentMoELM
+
+    B, T0, new = 256, 128, 256
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "xing4.0-29b-a4b-l5e32v2.json")) as f:
+        kw = json.load(f)["program"]["kwargs"]
+    model = HyperLatentMoELM(**{
+        **kw, "num_layers": 2, "vocab_size": 256, "mlp_dim": 256,
+        "n_experts": 4, "held": [0, 4], "top_k": 2, "expert_dim": 128,
+        "max_len": T0 + new})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape=(), dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    gen = G.make_generate(model, compute_dtype=jnp.bfloat16)
+    run = [c.cell_contents for c in gen.__closure__
+           if hasattr(c.cell_contents, "lower")][0]
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
+                                    model.param_tree())
+    text = run.lower(params, S((B, T0)), new, S((2,), jnp.uint32),
+                     S(dt=jnp.float32), 0, S(dt=jnp.float32), S(), S(),
+                     True, False).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for stretch in ("generate.decode_step", "generate.prefill"):
+        for sub in ("block.attention", "block.mlp"):
+            for fn, part in (("_coefficients", "mhc.coeffs"),
+                             ("_coefficients", "mhc.sinkhorn"),
+                             ("_pre", "mhc.pre"), ("_post", "mhc.post")):
+                want = f"{stretch}/{sub}/jit({fn})/{part}/"
+                assert any(want in n for n in names), want
+    sweeps = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln
+              and "mhc.sinkhorn" in ln]
+    assert len(sweeps) == 2 * 2 * 2     # layers x sublayers x (prefill, step)
+    assert any("generate.prefill/block.attention/mla.prefill_attend/" in n
+               for n in names)
+    assert not any("generate.decode_step" in n and "mla.prefill_attend" in n
+                   for n in names)

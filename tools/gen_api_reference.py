@@ -206,7 +206,7 @@ def main():
             "  **`DroplessMoE(score_bias=False, routed_scale=1.0,",
             "  renorm_eps=1e-20)`**: the `noaux_tc` router — a per-expert",
             "  bias (leaf `score_bias`, float32 whatever the model holds",
-            "  or computes in: `parallel.moe.FLOAT32_LEAVES`) added to the",
+            "  or computes in: `nn.module.FLOAT32_LEAVES`) added to the",
             "  scores for the SELECTION only; the gates are the unbiased",
             "  scores of the chosen, renormalised (`renorm_eps` in the",
             "  sum: `lfm2_moe` has 1e-6), times `routed_scale`.",
