@@ -539,7 +539,13 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     step itself reads.  A model with per-head K/V gives the same of its
     decode attend: ``kv_attend`` and ``kv_attend_block``, from
     ``ops.gqa_attend.attend_plan`` (the layers that are no ring; a ring
-    keeps the einsums)."""
+    keeps the einsums).  A model with experts gives the arm and the
+    tile plan of the grouped products in its decode step (a buffer of
+    ``batch * min(top_k, held)`` rows): ``grouped`` (``"ragged"``,
+    ``"grouped_decode"`` or ``"gmm"``), ``grouped_tiles`` (``"<rows a
+    product>x<tk>x<tn>"`` of the gate and up products; empty for
+    ``ragged``) and ``grouped_tiles_down`` — ``parallel.moe.
+    grouped_plan``, the rule ``grouped_matmul`` itself reads."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -587,6 +593,22 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
             int(batch), Hkv, T_cache, Dh, plain)
         out.update(kv_attend="kernel" if plan else "einsum",
                    kv_attend_block=plan)
+    experts = next((e for e in (_block_kind(b)[2] for b in blocks)
+                    if e is not None), None)
+    if experts is not None:
+        from ..parallel.moe import grouped_plan
+
+        # a decode step's buffer: one token a row, its choices among
+        # the held experts
+        rows = int(batch) * min(experts.top_k, experts.held[1])
+        D, F = experts.embed_dim, experts.hidden_dim
+        impl, up = grouped_plan(rows, D, F, dt)
+        down = grouped_plan(rows, F, D, dt)[1]
+        # as text: these ride on ``serve.dispatch`` into a profiler
+        # session, whose event metadata is split at commas
+        out.update(grouped=impl,
+                   grouped_tiles="x".join(map(str, up or ())),
+                   grouped_tiles_down="x".join(map(str, down or ())))
     return out
 
 

@@ -44,6 +44,7 @@ criterion loss, so its gradient falls out of autodiff
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 import jax
@@ -310,6 +311,51 @@ def route_top_k(x2, router_w, router_b, top_k: int,
     return gates, idx
 
 
+GROUPED_IMPLS = ("ragged", "gmm", "grouped_decode")
+# the plans :func:`grouped_matmul` chose while this thread traces an
+# expert function, for the ``moe.schedule`` event of the dispatch
+_TRACING = threading.local()
+
+
+def _tiles_of(impl: str, k: int, n: int):
+    """``(rows, tk, tn)`` of one grid step of ``impl`` at depth ``k`` and
+    width ``n``; None for ``ragged`` (the compiler's own)."""
+    if impl == "grouped_decode":        # the whole matrix
+        from ..ops.grouped_decode import CHUNK_ROWS
+
+        return (CHUNK_ROWS, k, n)
+    if impl == "gmm":       # PR 32's: 1024 where that divides, else 512
+        return (128, 1024 if k % 1024 == 0 else 512,
+                1024 if n % 1024 == 0 else 512)
+    return None
+
+
+def grouped_plan(R: int, k: int, n: int, dtype) -> tuple:
+    """``(impl, tiles)`` of the grouped product ``[R, k] x [G, k, n]``
+    held in ``dtype`` — the ONE rule :func:`grouped_matmul` and
+    ``generate.cache_footprint`` read, from shapes and the backend
+    alone (``PERF.md`` §6 "PR 32" and "PR 43" have the sweeps):
+
+    * off a TPU, for a buffer of more than 2048 rows (a prefill piece)
+      or one that 128 rows, 512 deep and 512 wide do not divide:
+      ``("ragged", None)``;
+    * else ``("grouped_decode", (rows a product, k, n))`` — the kernel
+      of ``ops/grouped_decode.py``, an expert's whole matrix one tile —
+      where the matrix is within ``grouped_decode.WHOLE_BYTES`` (8 MB)
+      and the call within the kernel's VMEM;
+    * else ``("gmm", (128, tk, tn))``, megablox under PR 32's tiles: an
+      expert of 32 MB in 2 MB tiles reads 92 % of its bytes' time there,
+      and no way of cutting it read more."""
+    if not (jax.default_backend() == "tpu" and R <= 2048 and R % 128 == 0
+            and k % 512 == 0 and n % 512 == 0):
+        return "ragged", None
+    from ..ops.grouped_decode import fits
+
+    impl = ("grouped_decode" if fits(R, k, n, jnp.dtype(dtype).itemsize)
+            else "gmm")
+    return impl, _tiles_of(impl, k, n)
+
+
 def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
     """``xs`` [R, k] rows sorted by group, ``w`` [G, k, n]: row ``r`` of
     group ``g`` is multiplied by ``w[g]``; rows past ``sum(group_sizes)``
@@ -318,30 +364,39 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
     * ``"ragged"`` — ``jax.lax.ragged_dot``: plain XLA off the TPU
       (differentiable by autodiff), the compiler's own grouped kernel in
       tiles of 512 on it;
+    * ``"grouped_decode"`` — the repo's own Pallas kernel
+      (``ops/grouped_decode.py``) for a decode step's buffer: one grid
+      step a group, its whole matrix one contiguous tile of up to 8 MB,
+      so every hit expert's weights cross HBM ONCE however its handful
+      of rows lie in the buffer.  TPU only;
     * ``"gmm"`` — the Pallas grouped matmul that ships with jax
-      (megablox), rows in tiles of 128: a decode step's handful of rows
-      an expert costs a quarter of the MXU work of a 512-row tile, and
-      each weight tile is still read once.  TPU only.
+      (megablox), rows in tiles of 128: a group that straddles a row
+      tile is visited twice, and with more than one k tile its weights
+      are fetched twice.  What a shape without a ``grouped_decode`` plan
+      keeps.  TPU only.
 
-    None picks by backend and size (``PERF.md`` §6 "PR 32" has the
-    sweep): ``gmm`` on a TPU for a buffer of at most 2048 rows whose
-    sizes its tiles divide, ``ragged`` everywhere else."""
+    None picks by :func:`grouped_plan`."""
     R, k = xs.shape
     n = w.shape[-1]
     if impl is None:
-        impl = ("gmm" if jax.default_backend() == "tpu" and R <= 2048
-                and R % 128 == 0 and k % 512 == 0 and n % 512 == 0
-                else "ragged")
+        impl, tiles = grouped_plan(R, k, n, xs.dtype)
+    elif impl in GROUPED_IMPLS:
+        tiles = _tiles_of(impl, k, n)
+    else:
+        raise ValueError(f"grouped matmul {impl!r} not in {GROUPED_IMPLS}")
+    plans = getattr(_TRACING, "plans", None)
+    if plans is not None:
+        plans.append((impl, tiles, k // tiles[1] if tiles else 0))
     if impl == "ragged":
         return lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes)
-    if impl != "gmm":
-        raise ValueError(f"grouped matmul {impl!r} not in (ragged, gmm)")
+    if impl == "grouped_decode":
+        from ..ops.grouped_decode import grouped_decode
+
+        return grouped_decode(xs, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    tk = 1024 if k % 1024 == 0 else 512
-    tn = 1024 if n % 1024 == 0 else 512
     return gmm(xs, w.astype(xs.dtype), group_sizes,
-               preferred_element_type=xs.dtype, tiling=(128, tk, tn))
+               preferred_element_type=xs.dtype, tiling=tiles)
 
 
 def held_key(idx, held):
@@ -414,27 +469,38 @@ def dropless_apply(x2, idx, gates, held, expert_fn):
     return ys.reshape(N, -1), jnp.sum(sizes, axis=0)
 
 
-def _record_schedule(tokens: int, rows: int, held: int, k: int):
+def _record_schedule(tokens: int, rows: int, held: int, k: int, plans):
     """One ``moe.schedule`` event in the process tracer's ring per
     traced dispatch: its shapes are static, so they are recorded where
-    they are made (as ``flash.schedule`` is)."""
+    they are made (as ``flash.schedule`` is).  ``plans`` are the
+    ``(impl, tiles, k tiles)`` :func:`grouped_matmul` chose for the
+    expert function's products; the event names the first's arm
+    (``impl``), its ``tiles`` ``[rows, tk, tn]`` (none for ``ragged``:
+    the compiler's own) and the most ``k_tiles`` any of them walks (1:
+    every weight tile holds the whole depth)."""
     from ..telemetry.tracer import default_tracer
 
+    impl, tiles, _ = plans[0] if plans else (None, None, 0)
     tr = default_tracer()
     tr.record("moe.schedule", "compile", tr.clock(), 0.0, tokens=tokens,
-              rows=rows, held=held, k=k)
+              rows=rows, held=held, k=k, impl=impl, tiles=list(tiles or ()),
+              k_tiles=max((p[2] for p in plans), default=0))
 
 
 def _dropless_piece(x2, idx, gates, held, expert_fn):
     N, K = idx.shape
     rows = N * min(K, held[1])
-    _record_schedule(N, rows, held[1], K)
     with jax.named_scope("moe.dispatch"):
         tok, valid, pos, weight, sizes = dispatch_plan(idx, gates, held,
                                                        rows)
         xs = jnp.take(x2, tok, axis=0)                        # [R, D]
     with jax.named_scope("moe.expert_matmul"):
-        ys = expert_fn(xs, sizes)
+        _TRACING.plans = plans = []
+        try:
+            ys = expert_fn(xs, sizes)
+        finally:
+            _TRACING.plans = None
+    _record_schedule(N, rows, held[1], K, plans)
     with jax.named_scope("moe.combine"):
         # rows past the last assignment are whatever the grouped
         # product left there: zeroed before they are gathered

@@ -369,6 +369,9 @@ def test_cache_footprint_by_kind_of_layer():
     assert fp["kv_cache_bytes"] == (fp["kv_cache_bytes_window"]
                                     + fp["kv_cache_bytes_full"])
     assert fp["recurrent_state_bytes"] == 0     # the counters are no state
+    # the arm and plan of the step's grouped products (PR 43): the CPU's
+    assert (fp["grouped"], fp["grouped_tiles"],
+            fp["grouped_tiles_down"]) == ("ragged", "", "")
     # a context shorter than the window: every layer keeps all of it
     short = G.cache_footprint(_model(window=128), 3, 19, 11)
     assert short["kv_cache_bytes_window"] == 3 * per_pos * T
@@ -409,6 +412,10 @@ def test_scopes_counters_and_the_schedule_event():
     events = [s for s in default_tracer().spans() if s.name == "moe.schedule"]
     assert {(e.args["tokens"], e.args["rows"], e.args["held"], e.args["k"])
             for e in events} >= {(4 * 19, 4 * 19 * 4, 4, 4), (4, 16, 4, 4)}
+    # which arm and tile plan the grouped products compiled (PR 43): off
+    # the TPU ``ragged_dot``, whose tiles are the compiler's own
+    assert {(e.args["impl"], tuple(e.args["tiles"]), e.args["k_tiles"])
+            for e in events} == {("ragged", (), 0)}
     ids, stats = gen(model.param_tree(), prompts, 11, return_stats=True)
     counts = np.asarray(stats["moe_counts"])
     assert counts.shape == (LAYERS, 4) and counts.dtype == np.int32
@@ -450,6 +457,9 @@ def test_the_server_reports_the_counters_and_the_cache_by_kind():
     assert (dispatch.args["kv_cache_bytes_window"]
             + dispatch.args["kv_cache_bytes_full"]
             == dispatch.args["kv_cache_bytes"])
+    # every generate batch says which grouped product it compiled
+    assert (dispatch.args["grouped"], dispatch.args["grouped_tiles"],
+            dispatch.args["grouped_tiles_down"]) == ("ragged", "", "")
 
 
 # -- the paths it shares -------------------------------------------------

@@ -241,3 +241,62 @@ def test_xing4_decode_step_names_its_hyper_connections(one_chip, monkeypatch):
                for n in names)
     assert not any("generate.decode_step" in n and "mla.prefill_attend" in n
                    for n in names)
+
+
+@pytest.mark.parametrize("config,cls,up,down", [
+    ("lfm2-24b-a2b-l5", "ShortConvMoELM", "128x2048x1536",
+     "128x1536x2048"),
+    ("xing4.0-29b-a4b-l5e32v2", "HyperLatentMoELM", "128x3584x1024",
+     "128x1024x3584"),
+])
+def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
+        one_chip, monkeypatch, config, cls, up, down):
+    """The largest bucket of the LFM2 and Xing4.0 cells (256 rows, four
+    choices a token: a decode buffer of 1024 rows at the published
+    embed and expert widths; four experts, toy vocabulary and dense
+    FFN) compiles under the plan of ``parallel.moe.grouped_plan`` —
+    each expert's matrix ONE whole-depth tile of 6-7 MB, 22-31 MiB of
+    VMEM a call — and a decode step holds twelve Mosaic calls under
+    ``moe.expert_matmul`` (three a layer, four expert layers): what the
+    ``*_expert_matmul_roofline`` readers count a step's products by."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.models import generate as G
+    from bigdl_tpu.models import latent_moe
+
+    B, T0, new = 256, 128, 256
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", config + ".json")) as f:
+        kw = json.load(f)["program"]["kwargs"]
+    model = getattr(latent_moe, cls)(**{
+        **kw, "vocab_size": 256, "mlp_dim": 256, "n_experts": 4,
+        "held": [0, 4], "top_k": 4, "max_len": T0 + new})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    foot = G.cache_footprint(model, B, T0, new, compute_dtype=jnp.bfloat16)
+    assert (foot["grouped"], foot["grouped_tiles"],
+            foot["grouped_tiles_down"]) == ("grouped_decode", up, down)
+
+    def S(shape=(), dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    gen = G.make_generate(model, compute_dtype=jnp.bfloat16)
+    run = [c.cell_contents for c in gen.__closure__
+           if hasattr(c.cell_contents, "lower")][0]
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
+                                    model.param_tree())
+    text = run.lower(params, S((B, T0)), new, S((2,), jnp.uint32),
+                     S(dt=jnp.float32), 0, S(dt=jnp.float32), S(), S(),
+                     True, False).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "moe.expert_matmul" in line]
+    step = [c for c in calls if "generate.decode_step" in c]
+    assert len(step) == 12
+    assert {re.search(r"= bf16\[(\d+),(\d+)\]", c).groups()
+            for c in step} == {(str(4 * B), up.split("x")[2]),
+                               (str(4 * B), down.split("x")[2])}
+    # the prompt's 32 768 rows go in pieces of ``ragged_dot``: no Mosaic
+    # call under the scope outside the decode step
+    assert len(calls) == len(step)

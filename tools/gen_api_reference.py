@@ -218,9 +218,16 @@ def main():
             "  `n_shared` shared experts.  Nothing is dropped: assignments",
             "  are sorted by expert into a buffer sized for the worst",
             "  imbalance and go through one grouped matrix product per",
-            "  projection (`grouped_matmul`: `jax.lax.ragged_dot`, or the",
-            "  Pallas grouped matmul that ships with jax for a decode",
-            "  step's small buffer on a TPU).  `MoEFFN` keeps its capacity",
+            "  projection (`grouped_matmul`: `jax.lax.ragged_dot`; for a",
+            "  decode step's buffer of at most 2048 rows on a TPU the",
+            "  repo's own kernel, `ops.grouped_decode.grouped_decode` —",
+            "  one grid step an expert, its whole matrix ONE contiguous",
+            "  tile of up to 8 MB, so a hit expert's weights cross HBM",
+            "  once wherever its rows lie — and for a larger matrix the",
+            "  Pallas grouped matmul that ships with jax; `grouped_plan`",
+            "  is the one rule, from shapes alone, and `cache_footprint` /",
+            "  `serve.dispatch` say which: `grouped`, `grouped_tiles`,",
+            "  `grouped_tiles_down`).  `MoEFFN` keeps its capacity",
             "  dispatch for training; decode advances it through the same",
             "  dropless dispatch.",
             "- **`ops.flash_attention(..., window=W)`**: the FORWARD kernel",

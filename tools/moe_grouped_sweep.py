@@ -1,81 +1,285 @@
 #!/usr/bin/env python3
-"""The grouped matrix product of the dropless expert layer, alone, on
-the chip: ``jax.lax.ragged_dot`` (the compiler's grouped kernel, rows in
-tiles of 512) against the Pallas grouped matmul that ships with jax
-(megablox ``gmm``, rows in tiles of 128), at the two shapes the
-Command A+ cell dispatches — a decode step's buffer (1024 rows of which
-128 are real, 16 held experts) and one piece of a prefill (32768 rows
-of which 4096 are real).
+"""The grouped matrix products of the dropless expert layer, alone, on
+the chip, as a decode step issues them: ``--calls`` gated experts
+(``gate`` and ``up`` ``[R, d] x [G, d, f]``, ``down`` ``[R, f] x [G, f,
+d]``) back to back inside ONE jitted loop, each fed the one before it,
+at the shapes the four serving cells with experts dispatch — and with
+group sizes drawn as those cells route: UNEVEN (a multinomial over
+popularities from a Dirichlet, the busiest group at 2-3 x the mean, as
+``moe_load_max_over_mean`` reads), so that groups straddle the 128-row
+tiles of megablox's ``gmm``.  (PR 32's sweep gave every group ``real //
+held`` rows: every group started on a tile boundary, none straddled.)
 
-    python tools/moe_grouped_sweep.py [--d 4096 --f 4096 --held 16]
+    python tools/moe_grouped_sweep.py [--shapes lfm2,xing4,glm,commandaplus]
+        [--arms gmm,decode,ragged] [--chunk 128,64]
+        [--tiling today plan 128,k,today 64,k,plan]
+        [--d .. --f .. --held .. --rows .. --real ..]
 
-Chip only.  Times are host-clock means over a loop of calls that ends in
-``block_until_ready`` (the calls queue back to back, so the mean is the
-device time of one call plus its dispatch); the roofline share is the
-real rows' operations and the hit experts' bytes at whichever peak
-binds, over that time.  PERF.md §6 "PR 32" has the readings.
+Arms: ``gmm`` (the Pallas grouped matmul that ships with jax) under each
+``--tiling`` — ``today`` is what PR 32 shipped (``tm`` 128, ``tk`` and
+``tn`` 1024 where that divides, else 512), ``plan`` ISSUE 43's Arm A
+(one k tile and the widest column tile within 4 MB that divides ``n``:
+``[2048, 768]``, ``[3584, 512]``, ...), ``tm,tk,tn`` explicit (``tk`` a
+number or ``k`` for the whole depth, ``tn`` a number, ``today`` or
+``plan``); ``decode`` (the repo's own ``ops/grouped_decode.py``: one
+grid step a group, its whole matrix one tile) under each ``--chunk``
+(rows a product); ``ragged`` (``jax.lax.ragged_dot``).  An arm is
+skipped at a shape its tiles do not divide, ``decode`` where the matrix
+is over its 8 MB.  ``--d`` ... give one shape of your own in place of
+``--shapes``.
+
+Chip only.  A row of the table is one product (``gate``: the gate and
+up calls; ``down``) of one arm at one shape:
+
+* ``ms_per_call`` (``ms_min``, ``ms_max``): from the DEVICE TRACE of the
+  loop, the custom call's own events in the order a layer runs them;
+* the kernel's grid, counted on the host from the sizes: ``visits``
+  ((row tile, group) pairs of ``gmm``; groups hit), ``grid_steps``,
+  ``products`` (MXU passes of a weight tile) and, for ``gmm``,
+  ``read_over_needed`` — weight bytes the grid's block indices fetch
+  over the hit experts' bytes: a straddling group's second visit
+  re-fetches unless the block is the one held;
+* ``bytes_once_pct``: the hit experts' weights and the real rows in and
+  out ONCE at the chip's bandwidth (``benchmark/peaks.json``), over
+  ``ms_per_call``;
+* ``loop_ms_per_layer``: the host clock around the whole loop over its
+  layers — the three products with the sizes' bookkeeping and the gate
+  between them, as the program pays them — and
+  ``other_ops_ms_per_layer``, the traced self time of everything in the
+  loop that is no product (``gmm`` builds its visit lists from the sizes
+  with a dozen small operations a call);
+* ``gate_err_vs_ragged``: the arm's largest difference from
+  ``ragged_dot`` on the defined rows of one gate product (values of
+  order 1).
+
+PERF.md §6 "PR 43" has the readings (§6 "PR 32" the first sweep's).
 """
 import argparse
 import json
 import os
+import shutil
+import statistics
 import sys
+import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+# name -> (d, f, held, rows of the buffer, real rows): the decode step
+# of a full bucket in each cell with experts (PERF.md §4)
+SHAPES = {
+    "lfm2": (2048, 1536, 64, 1024, 1024),
+    "xing4": (3584, 1024, 32, 1024, 512),
+    "glm": (2048, 1536, 16, 1024, 256),
+    "commandaplus": (4096, 4096, 16, 1024, 128),
+}
+
+
+def draw_sizes(held: int, real: int, seed: int):
+    """Rows a group as a router deals them: popularities from a
+    Dirichlet(4) (a coefficient of variation of one half), ``real``
+    assignments drawn over them."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return rng.multinomial(real, rng.dirichlet([4.0] * held)).astype("int32")
+
+
+def gmm_grid(sizes, tiles, k: int, n: int):
+    """``gmm``'s grid ``(n tiles, visits, k tiles)`` for these sizes: a
+    visit is a (row tile, group) pair; a step copies its weight tile
+    unless it is the block the step before it held."""
+    tm, tk, tn = tiles
+    visits, at = [], 0
+    for g, s in enumerate(int(s) for s in sizes):
+        if s:
+            visits += [g] * (-(-(at + s) // tm) - at // tm)
+        at += s
+    tiles_k, tiles_n = k // tk, n // tn
+    fetched = len(visits) * tiles_k if tiles_k > 1 else len(set(visits))
+    return {"visits": len(visits),
+            "grid_steps": tiles_n * len(visits) * tiles_k,
+            "read_over_needed": fetched / (len(set(visits)) * tiles_k)}
+
+
+def decode_grid(sizes, chunk: int, align: int = 16):
+    """``grouped_decode``'s grid ``(groups that have rows,)``: a visit
+    and a grid step each, a product one chunk of a group's rows from
+    its offset rounded down to ``align``."""
+    products, hit, at = 0, 0, 0
+    for s in (int(s) for s in sizes):
+        if s:
+            hit += 1
+            products += -(-(at + s - at // align * align) // chunk)
+        at += s
+    return {"visits": hit, "grid_steps": hit, "products": products}
+
+
+def call_seconds(trace_dir, trace_reduce):
+    """([seconds of each Mosaic / ragged call, in the order they ran],
+    self seconds of every OTHER operation inside the loop) of chip 0."""
+    planes = trace_reduce.load(trace_dir)
+    chip = next(p for p in planes if trace_reduce._is_chip(p["name"]))
+    calls, other = [], 0.0
+    for ev, self_ns, in_loop in trace_reduce.self_times(
+            trace_reduce._line(chip, "XLA Ops")):
+        if "tpu_custom_call" in ev[0] or " ragged-dot(" in ev[0]:
+            calls.append((ev[1], ev[2] / 1e9))
+        elif in_loop:
+            other += self_ns / 1e9
+    return [sec for _, sec in sorted(calls)], other
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--d", type=int, default=4096)
-    ap.add_argument("--f", type=int, default=4096)
-    ap.add_argument("--held", type=int, default=16)
-    ap.add_argument("--calls", type=int, default=30)
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    for dim in ("d", "f", "held", "rows", "real"):
+        ap.add_argument("--" + dim, type=int, default=0)
+    ap.add_argument("--arms", default="gmm,decode")
+    ap.add_argument("--tiling", nargs="*", default=["today", "plan"],
+                    help="today | plan | tm,tk,tn with tk a number or k, "
+                    "tn a number, today or plan (gmm)")
+    ap.add_argument("--chunk", default="128", help="rows a product (decode)")
+    ap.add_argument("--calls", type=int, default=24,
+                    help="expert layers in one timed loop")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default="chiprun_out/moe_grouped_sweep.json")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    from benchmark import counts
-    from bigdl_tpu.parallel.moe import grouped_matmul
+    from benchmark import counts, trace_reduce
+    from bigdl_tpu.ops import grouped_decode as GD
+    from bigdl_tpu.parallel.moe import _tiles_of
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"needs a TPU, found {dev.platform!r}", file=sys.stderr)
         return 2
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmark", "peaks.json")) as fh:
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as fh:
         peaks = counts.peaks_for(dev.device_kind, json.load(fh))
-    key = jax.random.PRNGKey(0)
-    w = jax.random.normal(key, (args.held, args.d, args.f), jnp.bfloat16)
+    dt = jnp.bfloat16
+    shapes = ({"custom": (args.d, args.f, args.held, args.rows, args.real)}
+              if args.d else {s: SHAPES[s] for s in args.shapes.split(",")})
+    def variants(k, n, rows):
+        """[(label, tiles, product fn, grid fn)] for [rows, k] x
+        [G, k, n]; the product False for an arm that does not take it."""
+        tn_plan = min(n, (4 << 20) // (2 * k)) // 128 * 128
+        while n % tn_plan:
+            tn_plan -= 128
+        out = []
+        for arm in args.arms.split(","):
+            if arm == "ragged":
+                out.append(("ragged", None, lax.ragged_dot, None))
+            for t in args.tiling if arm == "gmm" else ():
+                today = _tiles_of("gmm", k, n)
+                named = {"k": k, "today": today[2], "plan": tn_plan}
+                tiles = (today if t == "today" else
+                         (today[0], k, tn_plan) if t == "plan" else
+                         tuple(named.get(w) or int(w) for w in t.split(",")))
+                ok = not (k % tiles[1] or n % tiles[2])
+                out.append((
+                    f"gmm {t}", tiles,
+                    ok and (lambda x, w, s, tiles=tiles: gmm(
+                        x, w, s, preferred_element_type=dt, tiling=tiles)),
+                    lambda sizes, tiles=tiles: gmm_grid(sizes, tiles, k, n)))
+            for c in (int(c) for c in args.chunk.split(",")
+                      if arm == "decode"):
+                out.append((
+                    f"decode chunk {c}", (c, k, n),
+                    GD.fits(rows, k, n, 2) and (
+                        lambda x, w, s, c=c: GD.grouped_decode(
+                            x, w, s, chunk=c)),
+                    lambda sizes, c=c: decode_grid(sizes, c)))
+        return out
+
     rows_out = []
-    for rows, real in ((1024, 128), (1024, 1024), (32768, 4096),
-                       (32768, 32768)):
-        x = jax.random.normal(key, (rows, args.d), jnp.bfloat16)
-        sizes = jnp.full((args.held,), real // args.held, jnp.int32)
-        flops = 2.0 * real * args.d * args.f
-        nbytes = 2.0 * (args.held * args.d * args.f
-                        + real * (args.d + args.f))
-        least, binds = counts.roofline_seconds(flops, nbytes, peaks)
-        for impl in ("ragged", "gmm"):
-            fn = jax.jit(lambda x, w, s, impl=impl:
-                         grouped_matmul(x, w, s, impl))
+    for name, (d, f, held, rows, real) in shapes.items():
+        sizes_np = draw_sizes(held, real, args.seed)
+        sizes = jnp.asarray(sizes_np)
+        hit = int((sizes_np > 0).sum())
+        ks = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+        x = jax.random.normal(ks[0], (rows, d), dt)
+        wg, wu = (jax.random.normal(kk, (held, d, f), dt) / d ** 0.5
+                  for kk in ks[1:3])
+        wd = jax.random.normal(ks[3], (held, f, d), dt) / f ** 0.5
+        for (label, tg, up, grid_g), (_, td, down, grid_d) in zip(
+                variants(d, f, rows), variants(f, d, rows)):
+            head = {"shape": name, "d": d, "f": f, "held": held,
+                    "rows": rows, "real_rows": real, "groups_hit": hit,
+                    "max_over_mean": float(sizes_np.max() * held / real),
+                    "arm": label}
+            if not (up and down):
+                rows_out.append(dict(
+                    head, skipped="the tiles do not divide the products, "
+                    "or the matrix is no one tile"))
+                print(json.dumps(rows_out[-1]), flush=True)
+                continue
+
+            @jax.jit
+            def loop(x, wg, wu, wd, sizes, up=up, down=down):
+                def layer(i, x):
+                    # the sizes are the step's own: nothing of their
+                    # bookkeeping is hoisted out of the loop
+                    s = sizes + (i < 0).astype(jnp.int32)
+                    h = jax.nn.silu(up(x, wg, s)) * up(x, wu, s)
+                    return down(h, wd, s)
+                return lax.fori_loop(0, args.calls, layer, x)
+
+            trace_dir = tempfile.mkdtemp(prefix="moe_sweep_")
             try:
-                jax.block_until_ready(fn(x, w, sizes))
-                t0 = time.perf_counter()
-                for _ in range(args.calls):     # queued back to back;
-                    out = fn(x, w, sizes)       # one result alive
-                jax.block_until_ready(out)
-                ms = 1e3 * (time.perf_counter() - t0) / args.calls
-                row = {"rows": rows, "real_rows": real, "impl": impl,
-                       "ms_per_call": ms, "binds": binds,
-                       "roofline_pct": 100.0 * least / (ms / 1e3)}
+                # the arm's gate product against the compiler's own, on
+                # the rows that are defined
+                head["gate_err_vs_ragged"] = float(jnp.max(jnp.abs(
+                    (up(x, wg, sizes) - lax.ragged_dot(x, wg, sizes))
+                    [:real].astype(jnp.float32))))
+                jax.block_until_ready(loop(x, wg, wu, wd, sizes))
+                walls = []
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(loop(x, wg, wu, wd, sizes))
+                    walls.append(time.perf_counter() - t0)
+                with jax.profiler.trace(trace_dir):
+                    jax.block_until_ready(loop(x, wg, wu, wd, sizes))
+                secs, other = call_seconds(trace_dir, trace_reduce)
             except Exception as e:  # noqa: BLE001 — a sweep reports
-                row = {"rows": rows, "real_rows": real, "impl": impl,
-                       "error": f"{type(e).__name__}: {str(e)[:200]}"}
-            rows_out.append(row)
-            print(json.dumps(row), flush=True)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/moe_grouped_sweep.json", "w") as fh:
-        json.dump(rows_out, fh, indent=1)
+                rows_out.append(dict(
+                    head, error=f"{type(e).__name__}: {str(e)[:300]}"))
+                print(json.dumps(rows_out[-1]), flush=True)
+                continue
+            finally:
+                shutil.rmtree(trace_dir, ignore_errors=True)
+            loop_ms = 1e3 * min(walls) / args.calls
+            # a layer runs gate, up, down in that order
+            whole = len(secs) == 3 * args.calls
+            for product, k, n, tiles, grid, calls in (
+                    ("gate", d, f, tg, grid_g,
+                     [c for i, c in enumerate(secs) if i % 3 < 2]),
+                    ("down", f, d, td, grid_d, secs[2::3])):
+                row = dict(head, product=product, k=k, n=n, tiles=tiles,
+                           loop_ms_per_layer=loop_ms,
+                           other_ops_ms_per_layer=1e3 * other / args.calls,
+                           calls_timed=len(calls) if whole else 0)
+                if whole:
+                    ms = 1e3 * statistics.median(calls)
+                    once = 2.0 * (hit * k * n + real * (k + n))
+                    row.update(
+                        ms_per_call=ms, ms_min=1e3 * min(calls),
+                        ms_max=1e3 * max(calls), bytes_once_pct=100.0 * once
+                        / peaks["hbm_bytes_per_s"] / (ms / 1e3))
+                if grid:
+                    row.update(grid(sizes_np))
+                    if whole:
+                        row["us_per_grid_step"] = 1e3 * ms / row["grid_steps"]
+                rows_out.append(row)
+                print(json.dumps(row), flush=True)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as fh:
+        json.dump({"device": dev.device_kind, "seed": args.seed,
+                   "rows": rows_out}, fh, indent=1)
     return 0
 
 
