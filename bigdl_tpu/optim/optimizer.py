@@ -21,6 +21,7 @@ import numpy as np
 
 from ..dataset.sample import MiniBatch, SampleToMiniBatch
 from ..nn.module import AbstractModule
+from ..parallel.plan import FSDP_MIN_BYTES
 from ..resilience.guards import LossSpikeDetector
 from ..resilience.preemption import PreemptionHandler
 from ..resilience.retry import LossSpikeError, RetryPolicy
@@ -87,12 +88,15 @@ class Optimizer:
         # unified sharding-plan engine (parallel/plan.py, ISSUE 8):
         # every mesh path compiles through ONE compile_step_with_plan
         # builder.  ``sharding_plan`` overrides the derived default
-        # rule set; ``fsdp_min_bytes`` arms the threshold FSDP rule
-        # (large replicated params shard over the data axis with
-        # gather-on-use).  bigdl.fsdp.minBytes sets the default.
+        # rule set; ``fsdp_min_bytes`` is the threshold of the FSDP
+        # rule (on a data axis of more than one device a large leaf's
+        # master, slots and update live on its data shard; only the
+        # compute copy is gathered) — 1 MiB unless
+        # bigdl.fsdp.minBytes says otherwise; 0 / None replicates.
         self.sharding_plan = None
         _fsdp = get_property("bigdl.fsdp.minBytes")
-        self.fsdp_min_bytes = int(_fsdp) if _fsdp else None
+        self.fsdp_min_bytes = (FSDP_MIN_BYTES if _fsdp is None
+                               else int(_fsdp or 0) or None)
         # sparse gradient transport row budget, as a fraction of a
         # table's rows (parallel/plan.py "Gradient transport";
         # bigdl.sparse.density property sets the default, 1/16) —
@@ -275,22 +279,29 @@ class Optimizer:
         """Install an explicit :class:`~bigdl_tpu.parallel.plan.Plan`
         (ordered regex rules mapping param-tree path names to
         PartitionSpecs).  ``None`` restores the derived default —
-        module introspection plus the FSDP threshold rule when
-        :meth:`set_fsdp` armed one.  The plan re-binds to the live mesh
+        module introspection plus the FSDP threshold rule at
+        :meth:`set_fsdp`'s threshold (an explicit plan carries its own
+        ``fsdp_min_bytes``).  The plan re-binds to the live mesh
         every attempt, so elastic shrink/regrow is one mesh+plan
         re-derivation."""
         self.sharding_plan = plan
         return self
 
-    def set_fsdp(self, min_bytes: Optional[int] = 1 << 20):
-        """Arm FSDP-style parameter sharding: any parameter of at least
-        ``min_bytes`` that the plan would otherwise replicate over the
-        ``data`` axis is sharded over it instead (largest divisible
-        dim), gathered on use inside the step, with the gradient
-        reduce-scatter riding the gather's AD transpose — parameters
-        whose full tree does not fit one chip train anyway.  ``None``
-        disables.  (``bigdl.fsdp.minBytes`` property sets the
-        default.)"""
+    def set_fsdp(self, min_bytes: Optional[int] = FSDP_MIN_BYTES):
+        """The threshold of FSDP-style parameter sharding, which is ON
+        by default at 1 MiB wherever the mesh's ``data`` axis has more
+        than one device: any dense, step-synchronous parameter of at
+        least ``min_bytes`` that the plan would otherwise replicate
+        over ``data`` is sharded over it instead (its minor dim
+        where the shard is whole lane tiles, else the largest dim that
+        divides) — master, optimizer slots and update on the shard; the
+        compute-dtype copy gathered on use inside the step; the
+        gradient upcast to the master dtype and reduce-scattered — so
+        each parameter is updated on ONE shard, and parameters whose
+        full tree does not fit one chip train anyway.  ``None`` (or 0)
+        replicates: every device keeps and updates the whole tree after
+        a gradient all-reduce.  (``bigdl.fsdp.minBytes`` property sets
+        the default; 0 replicates.)"""
         self.fsdp_min_bytes = int(min_bytes) if min_bytes else None
         return self
 
@@ -1259,6 +1270,11 @@ class Optimizer:
                 "bigdl_plan_param_bytes_total",
                 "logical parameter bytes of the model"
             ).set(total)
+            reg.gauge(
+                "bigdl_plan_update_sharded_bytes",
+                "parameter bytes whose optimizer update runs on one "
+                "data shard (FSDP leaves)"
+            ).set(float(engine.update_sharded_bytes))
         except Exception:  # accounting must never take down training
             log.debug("plan param-bytes accounting failed", exc_info=True)
 

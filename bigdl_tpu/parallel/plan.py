@@ -36,10 +36,13 @@ spmd.py's model axis and pipeline.py's pipe axis):
 
 * a leaf SHARDED over an axis divides out that axis' replicated-loss
   cotangent amplification (``/n_axis``); for the ``data`` axis the
-  AD transpose (all_gather -> psum_scatter for FSDP, all_to_all for
-  expert stacks) already summed the shards, so unmasked steps divide
-  by ``n_data`` and masked steps (loss pre-normalized by the global
-  real count) take the sum as-is;
+  shards arrive already summed — an FSDP leaf's whole cotangent is
+  upcast to the MASTER dtype and ``psum_scatter``ed by hand (the
+  backward of ``_gather_on_use``; never the gather's AD transpose,
+  which would sum in the compute dtype), expert stacks ride their
+  all_to_all's transpose — so unmasked steps divide by ``n_data`` and
+  masked steps (loss pre-normalized by the global real count) take
+  the sum as-is;
 * a leaf REPLICATED over an axis pmeans its copies (psum over ``data``
   on masked steps — the weighted local losses sum to the global mean).
 """
@@ -58,7 +61,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 log = logging.getLogger("bigdl_tpu")
 
-__all__ = ["Rule", "Plan", "TRANSPORTS", "SYNCS", "derive_plan",
+__all__ = ["Rule", "Plan", "TRANSPORTS", "SYNCS", "FSDP_MIN_BYTES",
+           "derive_plan",
            "named_leaves", "match_partition_rules",
            "compile_step_with_plan", "CompiledPlanStep", "spec_table"]
 
@@ -159,6 +163,15 @@ TRANSPORTS = ("dense", "sparse")
 #: else is rejected loudly at plan-construction time.
 SYNCS = ("step", "periodic(k)", "stale(s)")
 
+#: the FSDP threshold rule's default: on a mesh whose data axis has more
+#: than one device, a dense lockstep leaf of at least this many bytes
+#: that the plan would replicate over ``data`` lives on its data shard —
+#: master, optimizer slots, reduced gradient and update — and only its
+#: compute-dtype copy is ever whole (``Plan._maybe_auto_fsdp``).  What
+#: ``derive_plan`` / ``compile_step_with_plan`` / ``Optimizer.set_fsdp``
+#: apply unless told ``None`` / 0 ("replicate").
+FSDP_MIN_BYTES = 1 << 20
+
 _SYNC_RE = re.compile(r"^(?:step|periodic\((\d+)\)|stale\((\d+)\))$")
 
 #: (table name, mesh shape) pairs whose degrade-to-replica warning has
@@ -196,7 +209,10 @@ class Rule(NamedTuple):
 
     ``spec`` is the leaf's PartitionSpec.  ``fsdp=True`` marks the rule's
     leaves for data-axis parameter sharding with gather-on-use (the spec
-    then carries the data axis on the sharded weight dim); ``reason``
+    then carries the data axis on the sharded weight dim) — the layout
+    the threshold rule gives every large leaf by default, and the same
+    compiled path: compute-dtype gather, master-dtype reduce-scatter,
+    update on the shard; ``reason``
     documents where the rule came from (introspection kind, "fsdp",
     "user", "default").  ``transport`` picks the gradient wire for the
     rule's leaves (see :data:`TRANSPORTS`): ``"sparse"`` ships
@@ -253,7 +269,10 @@ class Plan:
     against a concrete mesh (axes the mesh lacks degrade to replication
     — with a structured warning, so a misconfigured mesh is diagnosable
     — and FSDP rules learn the data-axis size for divisibility).
-    """
+
+    An explicit ``Plan`` shards what its rules say and nothing else:
+    ``fsdp_min_bytes`` is ``None`` here (replicate); the default
+    :data:`FSDP_MIN_BYTES` is ``derive_plan``'s, the plan nobody wrote."""
 
     def __init__(self, rules: Sequence[Rule], *, mesh: Optional[Mesh] = None,
                  fsdp_min_bytes: Optional[int] = None,
@@ -269,8 +288,8 @@ class Plan:
             if r.transport == "sparse" and r.fsdp:
                 raise ValueError(
                     f"rule {r.pattern!r} combines transport='sparse' "
-                    "with fsdp=True — FSDP gradients already ride the "
-                    "gather's reduce-scatter transpose; sparse "
+                    "with fsdp=True — an FSDP leaf's gradient is "
+                    "reduce-scattered onto its data shard; sparse "
                     "transport applies to data-replicated tables only")
             kind, _ = _parse_sync(r.sync)  # rejects unknown values
             if kind != "step" and r.fsdp:
@@ -450,10 +469,15 @@ class Plan:
 
     def _maybe_auto_fsdp(self, spec: P, leaf) -> P:
         """FSDP threshold rule: a large leaf left replicated over the
-        data axis gets its largest divisible free dim sharded over it
-        (gather-on-use; the grad reduce-scatter rides the gather's AD
-        transpose)."""
-        if self.fsdp_min_bytes is None:
+        data axis gets a divisible free dim sharded over it
+        (compute-dtype gather on use, master-dtype gradient
+        reduce-scatter, update on the shard).  ``None`` / 0 bytes:
+        replicate.  Which dim is read off the shapes: the MINOR one
+        where its shard is whole 128-lane tiles — the TPU compiler then
+        scatters and gathers it as it lies, where a 4096-row major dim
+        is padded to 4224 rows and routed through permutes and copies
+        (PERF.md §6 "PR 44") — else the largest that divides."""
+        if not self.fsdp_min_bytes:
             return spec
         n_data = self._mesh_size(self.data_axis)
         if n_data <= 1 or self.data_axis in _spec_axes(spec):
@@ -463,14 +487,14 @@ class Plan:
         if nbytes < self.fsdp_min_bytes:
             return spec
         parts = list(spec) + [None] * (len(shape) - len(spec))
-        best = None
-        for dim, ext in enumerate(shape):
-            if parts[dim] is not None or ext % n_data != 0:
-                continue
-            if best is None or ext > shape[best]:
-                best = dim
-        if best is None:
+        free = [dim for dim, ext in enumerate(shape)
+                if parts[dim] is None and ext % n_data == 0]
+        if not free:
             return spec  # no divisible free dim — stays replicated
+        minor = len(shape) - 1
+        best = (minor if minor in free
+                and shape[minor] % (128 * n_data) == 0
+                else max(free, key=lambda dim: (shape[dim], -dim)))
         parts[best] = self.data_axis
         return P(*parts)
 
@@ -590,14 +614,16 @@ class Plan:
             leaf, local)
 
     # -- collective accounting -------------------------------------------
-    def collective_bytes(self, tree) -> float:
+    def collective_bytes(self, tree, compute_dtype=None) -> float:
         """Estimated collective wire bytes ONE training step moves for
         this plan's parameter/gradient traffic (what the telemetry
         ``bigdl_perf_collective_bytes`` gauge publishes).  Per leaf:
 
-        * FSDP leaf: ``2(n_d-1)/n_d x full bytes`` — the gather-on-use
-          plus its reduce-scatter transpose — plus the grad all-reduce
-          of the slice over any OTHER replicated axes;
+        * FSDP leaf: ``(n_d-1)/n_d x`` (the gathered leaf at
+          ``compute_dtype`` bytes — the gather-on-use — plus the same
+          at master bytes — the gradient's reduce-scatter, summed in
+          the master dtype), plus the grad all-reduce of the slice over
+          any OTHER replicated axes;
         * non-FSDP dense leaf: ``2(R-1)/R x local slice bytes`` where
           ``R`` is the product of the mesh axes the leaf is replicated
           over (the gradient pmean's reduce-scatter + all-gather pair);
@@ -622,6 +648,7 @@ class Plan:
         exactly the old hard-wired ``2(n-1)/n x param bytes`` ring
         estimate; on composed meshes and FSDP plans it is what the
         hard-wired formula lied about (CHANGES.md PR 6).
+        ``compute_dtype``: the step's (None: the masters' own).
         """
         if self.mesh is None:
             return 0.0
@@ -640,7 +667,13 @@ class Plan:
             local = nbytes / max(shard_n, 1)
             if entry.fsdp:
                 n_d = self._mesh_size(self.data_axis)
-                total += 2.0 * (n_d - 1) / n_d * nbytes
+                whole = local * n_d  # what one device gathers
+                cast = (compute_dtype is not None and jnp.issubdtype(
+                    leaf.dtype, jnp.floating))
+                gathered = (whole * jnp.dtype(compute_dtype).itemsize
+                            / jnp.dtype(leaf.dtype).itemsize
+                            if cast else whole)
+                total += (n_d - 1) / n_d * (gathered + whole)
                 r = 1
                 for a in axes:
                     if a not in sharded and a != self.data_axis:
@@ -798,7 +831,7 @@ def _sparse_param_names(module, prefix=()):
 def derive_plan(model, mesh: Mesh, *, model_axis: Optional[str] = "model",
                 pipe_axis: Optional[str] = None,
                 n_pipe: Optional[int] = None,
-                fsdp_min_bytes: Optional[int] = None,
+                fsdp_min_bytes: Optional[int] = FSDP_MIN_BYTES,
                 sparse_density: Optional[float] = None,
                 sync_period: Optional[int] = None,
                 sync_staleness: Optional[int] = None,
@@ -811,8 +844,10 @@ def derive_plan(model, mesh: Mesh, *, model_axis: Optional[str] = "model",
     ``pipe_axis`` prepends the packed block stack's rules (leading
     layer dim over ``pipe``, composed with per-block tensor-parallel
     specs).  ``extra_rules`` go FIRST — user regex rules override the
-    derived defaults.  ``fsdp_min_bytes`` arms the threshold FSDP rule
-    (see :meth:`Plan._maybe_auto_fsdp`).  Modules with
+    derived defaults.  ``fsdp_min_bytes`` is the threshold of the FSDP
+    rule (see :meth:`Plan._maybe_auto_fsdp`; :data:`FSDP_MIN_BYTES` by
+    default, ``None`` / 0 replicates; the pipeline layout never takes
+    it).  Modules with
     ``sparse_grads = True`` get their rules stamped
     ``transport="sparse"`` (docs/distributed.md "Gradient
     transport").
@@ -883,6 +918,12 @@ def derive_plan(model, mesh: Mesh, *, model_axis: Optional[str] = "model",
                               reason="introspection",
                               transport=transport, sync=sync))
     rules.append(Rule(".*", P(), reason="default"))
+    if pipe_axis and fsdp_min_bytes:
+        # the packed block stack is stage-sharded and never gathered on use
+        log.info("sharding plan: the pipeline layout keeps the "
+                 "replicated update — the FSDP threshold (%d bytes) "
+                 "is not applied", fsdp_min_bytes)
+        fsdp_min_bytes = None
     return Plan(rules, mesh=mesh, fsdp_min_bytes=fsdp_min_bytes,
                 sparse_density=sparse_density)
 
@@ -917,10 +958,11 @@ class CompiledPlanStep:
     # populated by compile_step_with_plan:
     #   kind, mesh, plan, model, optim, param_specs, slot_specs,
     #   buffer_specs, input_spec, io_spec, pad_multiple, step, stage,
-    #   jitted_for, collective_bytes, sparse_bytes_saved,
+    #   jitted_for, collective_bytes, update_sharded_bytes,
+    #   sparse_bytes_saved,
     #   sync_bytes_saved, transport_table, sync_table, relaxed,
     #   periodic_cadences, stale_cadences, n_flags, has_relaxed,
-    #   has_fsdp, n_data, n_seq
+    #   has_fsdp, fsdp_flags, n_data, n_seq
 
     def init_state(self, sync_resume=None):
         """Fresh device-placed (params, slots, buffers) from the live
@@ -1089,7 +1131,8 @@ class CompiledPlanStep:
 
     def sync_to_model(self, params, slots, buffers):
         """Write the device trees back into the module/optimizer
-        (device_get reassembles model-sharded and FSDP leaves — the
+        (whole trees on the host: FSDP leaves are gathered on the mesh
+        first, ``device_get`` reassembles model-sharded ones — the
         out_specs make every output a global array; relaxed-synchrony
         replica stacks collapse to their mean, the local-SGD final
         model)."""
@@ -1105,8 +1148,9 @@ class CompiledPlanStep:
             unpack_params(jax.device_get(params), self.model)
             self.optim._slots = jax.device_get(slots)
             return
-        host_p = jax.device_get(params)
-        host_s = jax.device_get(slots)
+        host_p = self._whole_on_host(params, self.fsdp_flags)
+        host_s = self._whole_on_host(
+            slots, _slot_tree_like(slots, self.fsdp_flags, False))
         if self.relaxed:
             host_p = self._unstack_host(host_p, self.relaxed)
             host_s = self._unstack_host(host_s,
@@ -1114,6 +1158,37 @@ class CompiledPlanStep:
         self.model.set_param_tree(host_p)
         self.model.set_buffer_tree(jax.device_get(buffers))
         self.optim._slots = host_s
+
+    #: bytes of gathered FSDP leaves one fetch may hold whole on a chip
+    _GATHER_BATCH_BYTES = 1 << 30
+
+    def _whole_on_host(self, tree, fsdp):
+        """Host copies of a device tree.  An FSDP leaf (``fsdp`` true)
+        is gathered over the mesh first (a jitted identity onto the
+        replicated sharding: a collective on the chips' own links), then
+        ONE copy is fetched, a bounded batch of leaves at a time so no
+        chip ever holds the whole tree; ``device_get`` of the shards
+        would assemble them on the host, every byte a second pass over
+        fresh memory.  Every other leaf comes back as ``device_get``
+        reassembles it."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        gather = [i for i, f in enumerate(
+            jax.tree_util.tree_leaves(fsdp)) if f]
+        if gather and getattr(self, "_gather_whole", None) is None:
+            self._gather_whole = jax.jit(
+                lambda a: a,
+                out_shardings=NamedSharding(self.mesh, P()))
+        while gather:
+            batch, size = [], 0
+            while gather and (not batch
+                              or size < self._GATHER_BATCH_BYTES):
+                batch.append(gather.pop())
+                size += leaves[batch[-1]].nbytes
+            for i, a in zip(batch, jax.device_get(
+                    [self._gather_whole(leaves[i]) for i in batch])):
+                leaves[i] = a
+        return jax.tree_util.tree_unflatten(treedef,
+                                            jax.device_get(leaves))
 
     def checkpoint_tree(self, params, slots, buffers):
         """(orbax tree, kind) for the sharded-checkpoint path."""
@@ -1190,7 +1265,7 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
                            guard: bool = True, with_gnorm: bool = True,
                            n_microbatch: Optional[int] = None,
                            remat: Optional[bool] = None,
-                           fsdp_min_bytes: Optional[int] = None,
+                           fsdp_min_bytes: Optional[int] = FSDP_MIN_BYTES,
                            sparse_density: Optional[float] = None,
                            sync_period: Optional[int] = None,
                            sync_staleness: Optional[int] = None,
@@ -1205,7 +1280,16 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
     ring, derived backward), everything else the flat SPMD layout; in
     BOTH cases the per-leaf partitioning, gradient reduction, guard and
     grad-norm come from the same :class:`Plan` machinery, so data /
-    seq / model / pipe axes and FSDP param sharding compose freely.
+    seq / model axes and FSDP param sharding compose freely.  FSDP is
+    the DEFAULT layout of a derived plan's large leaves wherever the
+    data axis has more than one device (``fsdp_min_bytes``,
+    :data:`FSDP_MIN_BYTES`; ``None`` / 0 replicates; an explicit
+    ``plan`` carries its own threshold): such a leaf's master, slots,
+    reduced gradient and update live on its data shard, its
+    compute-dtype copy is gathered before the forward, and its
+    cotangent is upcast to the master dtype and reduce-scattered.  The
+    pipeline layout keeps the replicated update (its stack is
+    stage-sharded and never gathered on use).
 
     ``guard`` adds the in-program NaN/Inf skip-select (``ok`` output);
     ``with_gnorm`` the cross-shard global gradient norm (the flight
@@ -1240,7 +1324,7 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
         return _compile_pipeline(model, criterion, optim, mesh, plan,
                                  d_ax, m_ax, p_ax, n_microbatch,
                                  compute_dtype, donate, guard, with_gnorm,
-                                 remat, fsdp_min_bytes)
+                                 remat)
 
     # ---------------- flat SPMD layout (data x seq x model) -------------
     # single-device fast path (the LocalOptimizer shape): an unbound
@@ -1368,19 +1452,44 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
     def _spec_has(spec, axis):
         return axis is not None and axis in _spec_axes(spec)
 
-    def _gather_fsdp(p):
-        """gather-on-use: reassemble FSDP-sharded leaves along their
-        data-axis dim (the AD transpose of this gather is the gradient
-        reduce-scatter — ZeRO-3's wire pattern for free)."""
-        def g(leaf, spec, f):
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+    def _gather_on_use(shard, dim, master_dtype):
+        """An FSDP leaf's compute copy: the master SHARD cast to the
+        compute dtype, then gathered along its data-axis dim — half the
+        master's bytes on the wire, and the whole master never exists.
+        Its backward is written out, not derived: the gather's AD
+        transpose would reduce-scatter the cotangent in the COMPUTE
+        dtype; the plan's one reduction rule sums over chips in the
+        master dtype, as the replicated leaves' ``pmean`` does."""
+        with jax.named_scope("step.cast_params"):
+            if compute_dtype is not None and jnp.issubdtype(
+                    master_dtype, jnp.floating):
+                shard = shard.astype(compute_dtype)
+            return lax.all_gather(shard, d_ax, axis=dim, tiled=True)
+
+    def _gather_fwd(shard, dim, master_dtype):
+        return _gather_on_use(shard, dim, master_dtype), None
+
+    def _gather_bwd(dim, master_dtype, _, g):
+        with jax.named_scope("step.grad_reduce"):
+            return (lax.psum_scatter(g.astype(master_dtype), d_ax,
+                                     scatter_dimension=dim, tiled=True),)
+
+    _gather_on_use.defvjp(_gather_fwd, _gather_bwd)
+
+    def _gather_fsdp(p, p_c):
+        """gather-on-use: the FSDP leaves of the compute tree ``p_c``
+        come from their master shards in ``p`` (ZeRO-3's wire pattern,
+        the reference's ``AllReduceParameter`` — SURVEY.md P3)."""
+        def g(shard, leaf, spec, f):
             if not f:
                 return leaf
             dim = next(i for i, part in enumerate(spec)
                        if part is not None and d_ax in
                        ((part,) if not isinstance(part, tuple) else part))
-            return lax.all_gather(leaf, d_ax, axis=dim, tiled=True)
+            return _gather_on_use(shard, dim, shard.dtype)
 
-        return jax.tree_util.tree_map(g, p, pspecs, fsdp_flags)
+        return jax.tree_util.tree_map(g, p, p_c, pspecs, fsdp_flags)
 
     def _sparse_allreduce(g, k, spec):
         """Sparse gradient transport over the data axis: ship each
@@ -1441,7 +1550,8 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
                 return g
             if d_ax:
                 if _spec_has(spec, d_ax):
-                    # FSDP (gather transpose), expert stacks and
+                    # FSDP (the master-dtype reduce-scatter of
+                    # _gather_on_use's backward), expert stacks and
                     # sharded embedding rows (all_to_all/exchange
                     # transposes) arrive pre-summed over data
                     if not masked:
@@ -1534,9 +1644,11 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
                       for s in jax.tree_util.tree_leaves(scale_tree))
 
     def _run_fwd(p, buf, x, training, rng):
-        """cast -> FSDP gather -> forward (gather moves compute-dtype
-        bytes; its vjp reduce-scatters the compute-dtype cotangent and
-        the cast's vjp upcasts to the f32 master grads)."""
+        """cast -> FSDP gather -> forward (the gather moves
+        compute-dtype bytes and names its own scopes: its backward is
+        the master-dtype reduce-scatter of ``step.grad_reduce``; the
+        other leaves' cast transposes to the upcast of their f32
+        master grads)."""
         from ..optim.optimizer import _cast_floats, _restore_dtypes
 
         p_c, x_c = p, x
@@ -1544,8 +1656,8 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
             if compute_dtype is not None:
                 p_c = _cast_floats(p, compute_dtype)
                 x_c = _cast_floats(x, compute_dtype)
-            if has_fsdp:
-                p_c = _gather_fsdp(p_c)
+        if has_fsdp:
+            p_c = _gather_fsdp(p, p_c)
         with jax.named_scope("step.forward"):
             out, nb = model.apply_fn(p_c, buf, x_c, training, rng)
             if compute_dtype is not None:
@@ -1562,17 +1674,22 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
             node = node[k]
         return node
 
-    # LOGGED loss psums model-sharded params' reg penalty over the model
-    # axis (each shard sees only its slice); per-slice reg GRADS are
-    # exact and ride a separate pass (spmd.py's rule, kept verbatim)
-    reg_sharded = [pr for pr in reg_paths
-                   if _spec_has(_spec_for_path(pr[0]), m_ax)]
-    reg_repl = [pr for pr in reg_paths if pr not in reg_sharded]
+    # LOGGED loss psums a sharded param's reg penalty over the axes that
+    # shard it (each shard sees only its slice: the model axis, and the
+    # data axis of an FSDP leaf); per-slice reg GRADS are exact and ride
+    # a separate pass (spmd.py's rule)
+    reg_by_axes = {}
+    for pr in reg_paths:
+        spec = _spec_for_path(pr[0])
+        reg_by_axes.setdefault(
+            tuple(a for a in (d_ax, m_ax) if _spec_has(spec, a)),
+            []).append(pr)
 
     def _reg_term(p):
-        term = regularizer_loss(p, reg_repl)
-        if reg_sharded:
-            term = term + lax.psum(regularizer_loss(p, reg_sharded), m_ax)
+        term = 0.0
+        for axes, paths in reg_by_axes.items():
+            part = regularizer_loss(p, paths)
+            term = term + (lax.psum(part, axes) if axes else part)
         return term
 
     def _gnorm(grads):
@@ -1792,15 +1909,24 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
         param_specs=pspecs, slot_specs=sslots, buffer_specs=bspecs,
         input_spec=in_spec(2), io_spec=io_spec, step=step, stage=stage,
         jitted_for=_jitted_for, pad_multiple=n_data,
-        collective_bytes=plan.collective_bytes(host_params),
+        collective_bytes=plan.collective_bytes(host_params,
+                                               compute_dtype),
+        # master bytes whose update runs on ONE data shard (the gauge
+        # bigdl_plan_update_sharded_bytes; ÷ ..._param_bytes_total is
+        # the engagement share) — the flags as compiled, so 0 on one
+        # device whatever a rule armed
+        update_sharded_bytes=float(sum(
+            a.nbytes for a, f in zip(
+                jax.tree_util.tree_leaves(host_params),
+                jax.tree_util.tree_leaves(fsdp_flags)) if f)),
         sparse_bytes_saved=plan.sparse_bytes_saved(host_params),
         sync_bytes_saved=plan.sync_bytes_saved(host_params),
         transport_table=transport_table, sync_table=sync_table,
         relaxed=relaxed, periodic_cadences=periodic_cadences,
         stale_cadences=stale_cadences, n_flags=n_flags,
         has_relaxed=has_relaxed,
-        has_fsdp=has_fsdp, n_data=n_data, n_seq=n_seq,
-        n_model=n_model, n_pipe=1, model_axis=m_ax, seq_axis=s_ax,
+        has_fsdp=has_fsdp, fsdp_flags=fsdp_flags, n_data=n_data,
+        n_seq=n_seq, n_model=n_model, n_pipe=1, model_axis=m_ax, seq_axis=s_ax,
         input_seq_dim=input_seq_dim)
 
 
@@ -1810,7 +1936,7 @@ def compile_step_with_plan(model, criterion, optim, mesh: Mesh,
 
 def _compile_pipeline(model, criterion, optim, mesh, plan, d_ax, m_ax,
                       p_ax, n_microbatch, compute_dtype, donate, guard,
-                      with_gnorm, remat, fsdp_min_bytes):
+                      with_gnorm, remat):
     """data x pipe [x model] composition: the GPipe schedule from
     pipeline.py's shared local forward, partitioned/reduced by the SAME
     Plan machinery as the flat layout."""
@@ -1833,11 +1959,6 @@ def _compile_pipeline(model, criterion, optim, mesh, plan, d_ax, m_ax,
             "scaleW/scaleB are not supported on the pipeline layout yet")
     if remat is None:
         remat = bool(getattr(model, "remat", False))
-    if fsdp_min_bytes:
-        raise NotImplementedError(
-            "FSDP param sharding does not compose with the pipeline "
-            "layout yet — stage-sharded layers already partition the "
-            "param tree; use a data x model mesh for FSDP")
     upcast_out = not getattr(criterion, "accepts_low_precision", False)
     local_fwd = _make_local_forward(model, first, count, S, M, p_ax,
                                     compute_dtype, remat)
@@ -1977,6 +2098,7 @@ def _compile_pipeline(model, criterion, optim, mesh, plan, d_ax, m_ax,
         input_spec=in_batch, io_spec=io_spec, step=step, stage=stage,
         jitted_for=_jitted_for, pad_multiple=n_data * M,
         collective_bytes=plan.collective_bytes(packed0),
+        update_sharded_bytes=0.0,
         sparse_bytes_saved=0.0, sync_bytes_saved=0.0,
         transport_table={}, sync_table={}, relaxed={},
         periodic_cadences=(), stale_cadences={}, n_flags=0,

@@ -74,6 +74,7 @@ PERF_HBM_PEAK_BYTES = "bigdl_perf_hbm_peak_bytes"
 PERF_HBM_LIMIT_BYTES = "bigdl_perf_hbm_limit_bytes"
 PLAN_PARAM_BYTES_PER_DEVICE = "bigdl_plan_param_bytes_per_device"
 PLAN_PARAM_BYTES_TOTAL = "bigdl_plan_param_bytes_total"
+PLAN_UPDATE_SHARDED_BYTES = "bigdl_plan_update_sharded_bytes"
 
 # --- serving (serving/metrics.py, router.py, autoscale.py) ---------------
 SERVING_REQUESTS_TOTAL = "bigdl_serving_requests_total"

@@ -333,14 +333,21 @@ def test_fsdp_trains_model_exceeding_one_device_budget(steps):
         opt.set_end_when(max_iteration(steps))
         opt.set_telemetry(tm)
         opt.set_train_summary(rec)
-        if fsdp_min_bytes:
-            opt.set_fsdp(fsdp_min_bytes)
+        # None: the replicated control (the 1 MiB default would shard
+        # the 512 x 512 layer)
+        opt.set_fsdp(fsdp_min_bytes)
         opt.optimize()
         assert rec.losses[-1] < rec.losses[0]
         snap = tm.registry.snapshot()["metrics"]
         per_dev = snap["bigdl_plan_param_bytes_per_device"]["series"][0][
             "value"]
         total = snap["bigdl_plan_param_bytes_total"]["series"][0]["value"]
+        # the engagement gauge: master bytes updated on ONE data shard —
+        # the two big weights when armed, nothing when replicated
+        sharded = snap["bigdl_plan_update_sharded_bytes"]["series"][0][
+            "value"]
+        assert sharded == ((512 * 256 + 512 * 512) * 4
+                           if fsdp_min_bytes else 0.0)
         return model, per_dev, total
 
     n = jax.device_count()
@@ -362,7 +369,7 @@ def test_fsdp_trains_model_exceeding_one_device_budget(steps):
     for a, b in zip(jax.tree_util.tree_leaves(model_fsdp.param_tree()),
                     jax.tree_util.tree_leaves(model_dp.param_tree())):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4)
+                                   atol=2e-6)
 
 
 def test_fsdp_specs_shard_large_leaves_only():
@@ -501,3 +508,388 @@ def test_seq_pipe_mesh_rejected():
     opt.set_end_when(max_iteration(1))
     with pytest.raises(ValueError, match="seq"):
         opt.optimize()
+
+
+# ---------------------------------------------------------------------------
+# the data-parallel step updates each parameter on ONE shard (ISSUE 44):
+# by default, on a data axis of more than one device, a dense lockstep
+# leaf of 1 MiB or more lives on its data shard — master, slots, reduced
+# gradient, update — and only its compute-dtype copy is ever whole
+# ---------------------------------------------------------------------------
+
+def _big_mlp():
+    """0/weight [1024, 512] and 2/weight [512, 1024] are 2 MiB of f32
+    each (over the 1 MiB default), every other leaf is small."""
+    RNG().set_seed(4)
+    return nn.Sequential(nn.Linear(512, 1024), nn.Tanh(),
+                         nn.Linear(1024, 512), nn.Tanh(),
+                         nn.Linear(512, 4), nn.LogSoftMax())
+
+
+def _big_lookup():
+    """0/weight [40000, 8] f32 = 1.28 MB: a table over the threshold."""
+    RNG().set_seed(2)
+    return nn.Sequential(nn.LookupTable(40000, 8), nn.Sum(dimension=2),
+                         nn.Linear(8, 4), nn.LogSoftMax())
+
+
+def _data_mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), ("data",))
+
+
+def _mlp_batch(n=16, seed=0):
+    rng = np.random.RandomState(seed)
+    return (rng.rand(n, 512).astype(np.float32),
+            (1 + rng.randint(0, 4, n)).astype(np.float32))
+
+
+def _assert_trees(got, want, **tol):
+    """Leaf by leaf: equal, or close where a tolerance is given."""
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        if tol:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _lowered_collectives(eng, x, y):
+    """[(op, operand dims, operand dtype)] of the lowered step's
+    collectives, from the StableHLO text."""
+    import re
+
+    x, y = jnp.asarray(x), jnp.asarray(y)
+    args = eng.init_state() + (np.float32(0.1), jax.random.PRNGKey(0), x, y)
+    if eng.has_relaxed:
+        args += (jnp.zeros((eng.n_flags,), jnp.int32),
+                 eng.init_sync_state())
+    text = eng.jitted_for(x, y, False).lower(*args).as_text()
+    out = []
+    for op in ("all_gather", "reduce_scatter", "all_reduce"):
+        # an op with a reduction body carries its type after the body
+        for m in re.finditer(r'"stablehlo\.%s"\(.*?: \(tensor<([^>]*)>\) -> '
+                             % op, text, re.S):
+            dims = m.group(1).split("x")
+            out.append((op, tuple(int(d) for d in dims[:-1]), dims[-1]))
+    return out
+
+
+@pytest.mark.parametrize("shape,n,want", [
+    # the minor dim where its shard is whole 128-lane tiles ...
+    ((1024, 4096), 4, P(None, "data")),
+    ((4096, 1024), 4, P(None, "data")),
+    ((50257, 1024), 4, P(None, "data")),
+    # ... else the largest dim that divides (the first of equals)
+    ((4096, 1000), 4, P("data", None)),
+    ((3, 3, 512, 512), 8, P(None, None, "data", None)),
+    ((50257, 1022), 4, P()),            # nothing divides: replicated
+])
+def test_threshold_rule_reads_the_dim_off_the_shapes(shape, n, want):
+    plan = Plan([Rule(".*", P())], mesh=_data_mesh(n),
+                fsdp_min_bytes=1 << 20)
+    got = plan.param_specs({"w": jax.ShapeDtypeStruct(shape, jnp.float32)})
+    assert got["w"] == want
+
+
+_SHARD_CASES = {
+    # name: (model, n devices, plan or None, engine kwargs,
+    #        {leaf: spec string it must carry},
+    #        the lowered collectives over whole / shard of big leaves)
+    "default": (
+        _big_mlp, 4, None, {},
+        {"0/weight": "(-, data)", "2/weight": "(-, data)",
+         "4/weight": "replicated", "0/bias": "replicated"},
+        [("all_gather", (512, 256), "bf16"),
+         ("all_gather", (1024, 128), "bf16"),
+         ("reduce_scatter", (512, 1024), "f32"),
+         ("reduce_scatter", (1024, 512), "f32")]),
+    "off": (
+        _big_mlp, 4, None, {"fsdp_min_bytes": None},
+        {"0/weight": "replicated", "2/weight": "replicated"},
+        [("all_reduce", (512, 1024), "f32"),
+         ("all_reduce", (1024, 512), "f32")]),
+    "under_threshold": (
+        _big_mlp, 4, None, {"fsdp_min_bytes": 4 << 20},
+        {"0/weight": "replicated", "2/weight": "replicated"},
+        [("all_reduce", (512, 1024), "f32"),
+         ("all_reduce", (1024, 512), "f32")]),
+    "one_device": (
+        _big_mlp, 1, None, {},
+        {"0/weight": "replicated", "2/weight": "replicated"}, []),
+    "sparse_table": (
+        _big_lookup, 4,
+        lambda: Plan([Rule(r"^0/weight$", P(), transport="sparse"),
+                      Rule(".*", P())], fsdp_min_bytes=1 << 20), {},
+        {"0/weight": "replicated"}, None),
+    "periodic": (
+        _big_mlp, 4,
+        lambda: Plan([Rule(r"^0/", P(), sync="periodic(4)"),
+                      Rule(".*", P())], fsdp_min_bytes=1 << 20), {},
+        {"0/weight": "replicated", "2/weight": "(-, data)"},
+        [("all_gather", (512, 256), "bf16"),
+         # the averaging round's pmean of the replica stack, in its cond
+         ("all_reduce", (1, 1024, 512), "f32"),
+         ("reduce_scatter", (512, 1024), "f32")]),
+    # the explicitly armed rule takes the same path: compute-dtype
+    # gather, MASTER-dtype scatter (never the gather's bf16 transpose)
+    "armed_rule": (
+        _big_mlp, 4,
+        lambda: Plan([Rule(r"^0/weight$", P(None, "data"), fsdp=True),
+                      Rule(".*", P())]), {},
+        {"0/weight": "(-, data)", "2/weight": "replicated"},
+        [("all_gather", (1024, 128), "bf16"),
+         ("all_reduce", (512, 1024), "f32"),
+         ("reduce_scatter", (1024, 512), "f32")]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHARD_CASES))
+def test_default_layout_updates_each_large_leaf_on_one_shard(case):
+    build, n, plan, kw, specs, want = _SHARD_CASES[case]
+    model = build()
+    if build is _big_lookup:
+        rng = np.random.RandomState(0)
+        x = (1 + rng.randint(0, 50, (16, 3))).astype(np.float32)
+        y = (1 + rng.randint(0, 4, 16)).astype(np.float32)
+    else:
+        x, y = _mlp_batch()
+    eng = compile_step_with_plan(
+        model, nn.ClassNLLCriterion(), SGD(), _data_mesh(n),
+        plan=plan() if plan else None, compute_dtype=jnp.bfloat16, **kw)
+    table = eng.plan.table(model.param_tree())
+    for leaf, spec in specs.items():
+        assert table[leaf].split(" | ")[0] == spec, (leaf, table[leaf])
+        assert ("[fsdp]" in table[leaf]) == ("data" in spec)
+    p, s, _ = eng.init_state()
+    for leaf, spec in specs.items():
+        if "data" not in spec:
+            continue
+        arr = dict(named_leaves(p))[leaf]
+        dim = spec.strip("()").split(", ").index("data")
+        assert arr.sharding.spec[dim] == "data"
+        assert arr.addressable_shards[0].data.shape[dim] \
+            == arr.shape[dim] // n
+    colls = _lowered_collectives(eng, x, y)
+    big = sorted(c for c in colls if int(np.prod(c[1] or (1,))) >= 1 << 17)
+    if want is None:
+        # the sparse table keeps its replica and its index+value wire
+        # (K = 40000 / 16 rows a shard): nothing gathers or scatters a
+        # whole table
+        assert sorted(c for c in colls if c[0] != "all_reduce") == [
+            ("all_gather", (2500,), "i32"),
+            ("all_gather", (2500, 8), "f32")], colls
+        assert eng.update_sharded_bytes == 0.0
+        return
+    assert big == sorted(want), colls
+    # (d) the accounting reads what was lowered: a gather's operand is
+    # one shard (the wire carries the n - 1 others), a scatter's the
+    # whole cotangent (the wire carries (n - 1) / n of it)
+    nbytes = lambda c: float(np.prod(c[1])) * (2 if c[2] == "bf16" else 4)
+    wire = sum(nbytes(c) * (n - 1 if c[0] == "all_gather" else (n - 1) / n)
+               for c in big if c[0] != "all_reduce")
+    leaves = named_leaves(model.param_tree())
+    assert eng.update_sharded_bytes == sum(
+        l.size * 4 for name, l in leaves if "[fsdp]" in table[name])
+    ring = 2.0 * (n - 1) / n
+    assert eng.collective_bytes == pytest.approx(wire + ring * sum(
+        l.size * 4 / (4 if "periodic" in table[name] else 1)
+        for name, l in leaves if "[fsdp]" not in table[name]))
+
+
+@pytest.mark.parametrize("case", ["plain", "masked", "nan"])
+def test_sharded_update_is_the_replicated_update(case):
+    """Three Adam steps, bf16 compute, on a data mesh of 4: the default
+    (sharded) layout against ``fsdp_min_bytes=None`` from the same seed.
+    The same local cotangent, upcast, summed over chips in f32, through
+    the same elementwise Adam: equal to the order of an f32 sum — on a
+    full batch, on a trailing batch whose last rows are padding, and on
+    a step whose gradient holds a NaN (every shard skips it)."""
+    from bigdl_tpu.optim import Adam
+
+    x, y = _mlp_batch()
+    w = total_w = None
+    if case == "masked":
+        w = np.array([1.0] * 10 + [0.0] * 6, np.float32)
+        total_w = 10.0
+    runs = {}
+    for name, kw in (("sharded", {}), ("replicated",
+                                       {"fsdp_min_bytes": None})):
+        model = _big_mlp()
+        eng = compile_step_with_plan(
+            model, nn.ClassNLLCriterion(), Adam(1e-3), _data_mesh(4),
+            compute_dtype=jnp.bfloat16, **kw)
+        assert eng.has_fsdp == (name == "sharded")
+        p, s, b = eng.init_state()
+        first = jax.device_get(p)
+        log = []
+        for i in range(3):
+            xi = x.copy()
+            poisoned = case == "nan" and i == 1
+            if poisoned:
+                xi[5, 7] = np.nan
+                before = jax.device_get((p, s))
+            loss, p, s, b, ok, gn = eng.step(p, s, b, 1e-3, xi, y, w=w,
+                                             total_w=total_w)
+            log.append((float(loss), float(gn), bool(ok)))
+            if poisoned:
+                # the skip left every shard of every leaf as it was
+                assert not bool(ok)
+                _assert_trees((p, s), before)
+        runs[name] = (log, jax.device_get((p, s)))
+        assert any(np.any(a != b_) for a, b_ in zip(
+            jax.tree_util.tree_leaves(runs[name][1][0]),
+            jax.tree_util.tree_leaves(first)))  # it trained
+        eng.sync_to_model(p, s, b)
+        # whole trees come back on the host, sharded or not
+        _assert_trees(model.param_tree(), runs[name][1][0])
+    (log_s, state_s), (log_r, state_r) = runs["sharded"], runs["replicated"]
+    for (l1, g1, ok1), (l2, g2, ok2) in zip(log_s, log_r):
+        assert ok1 == ok2
+        if ok1:
+            assert l1 == pytest.approx(l2, rel=1e-6)
+            # sums of 0.5 M squares, in 4 parts or in 1
+            assert g1 == pytest.approx(g2, rel=5e-4)
+    _assert_trees(state_s, state_r, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("n_after", [2, 1])
+def test_state_sharded_over_four_rebinds_to_a_smaller_mesh(n_after):
+    """What an elastic shrink does each attempt (``_plan_loop`` on the
+    live mesh): a new engine for the survivors' mesh takes its state
+    from the host trees the four-way-sharded run wrote back.  Parameters
+    and both Adam moments arrive identical, laid out for the new mesh,
+    and the next step is the replicated twin's."""
+    from bigdl_tpu.optim import Adam
+
+    x, y = _mlp_batch()
+    model, optim = _big_mlp(), Adam(1e-3)
+    crit = nn.ClassNLLCriterion()
+    eng4 = compile_step_with_plan(model, crit, optim, _data_mesh(4))
+    p, s, b = eng4.init_state()
+    for _ in range(2):
+        _, p, s, b, _, _ = eng4.step(p, s, b, 1e-3, x, y)
+    want = jax.device_get((p, s))
+    eng4.sync_to_model(p, s, b)
+
+    eng = compile_step_with_plan(model, crit, optim, _data_mesh(n_after))
+    assert eng.has_fsdp == (n_after > 1)
+    p2, s2, b2 = eng.init_state()
+    _assert_trees((p2, s2), want)
+    big = dict(named_leaves(p2))["0/weight"]
+    assert big.addressable_shards[0].data.shape == (1024, 512 // n_after)
+    m = dict(named_leaves(s2))["m/0/weight"]
+    assert m.addressable_shards[0].data.shape == (1024, 512 // n_after)
+
+    twin = compile_step_with_plan(model, crit, optim, _data_mesh(4),
+                                  fsdp_min_bytes=None)
+    loss, p2, s2, b2, _, _ = eng.step(p2, s2, b2, 1e-3, x, y)
+    loss_t, pt, st, _, _, _ = twin.step(*twin.init_state(), 1e-3, x, y)
+    assert float(loss) == pytest.approx(float(loss_t), rel=1e-6)
+    _assert_trees((p2, s2), (pt, st), rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("fmt", ["pickle", "orbax"])
+@pytest.mark.parametrize("written,read", [(1 << 20, None), (None, 1 << 20)])
+def test_checkpoint_crosses_the_sharded_and_replicated_layouts(
+        tmp_path, written, read, fmt):
+    """A checkpoint holds whole trees on the host: one written by the
+    sharded layout resumes replicated, and the other way round, onto the
+    trajectory of a run that was never interrupted."""
+    from bigdl_tpu.optim import Adam, several_iteration
+
+    xs, ys = _mlp_batch(64, seed=1)
+    samples = [Sample(a, b) for a, b in zip(xs, ys)]
+
+    def build(fsdp):
+        opt = DistriOptimizer(_big_mlp(), array(samples),
+                              nn.ClassNLLCriterion(), batch_size=16,
+                              mesh=_data_mesh(4))
+        opt.set_optim_method(Adam(1e-3))
+        opt.set_fsdp(fsdp)
+        return opt
+
+    whole = build(read)
+    whole.set_end_when(max_iteration(5))
+    whole.optimize()
+
+    first = build(written)
+    first.set_end_when(max_iteration(3))
+    first.set_checkpoint(str(tmp_path / "ckpt"), several_iteration(1),
+                         format=fmt)
+    first.optimize()
+
+    second = build(read)
+    second.set_checkpoint(str(tmp_path / "ckpt"), several_iteration(1),
+                          format=fmt)
+    assert second.resume_from_checkpoint() is True
+    second.set_end_when(max_iteration(5))
+    second.optimize()
+    assert second.optim_method.state["neval"] - 1 == 5
+    _assert_trees((second.model.param_tree(), second.optim_method._slots),
+                  (whole.model.param_tree(), whole.optim_method._slots),
+                  rtol=1e-5, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# the threshold's one default, and what happens where it cannot apply
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("value,want", [
+    (None, 1 << 20), ("", None), ("0", None), ("4096", 4096)])
+def test_fsdp_threshold_property_and_default(monkeypatch, value, want):
+    """``bigdl.fsdp.minBytes``: unset is the 1 MiB default, empty or 0
+    replicates, a number is the threshold — and ``set_fsdp()``,
+    ``derive_plan`` and ``compile_step_with_plan`` default to the same
+    constant."""
+    import inspect
+
+    from bigdl_tpu.parallel.plan import FSDP_MIN_BYTES
+
+    assert FSDP_MIN_BYTES == 1 << 20
+    for fn, arg in ((DistriOptimizer.set_fsdp, "min_bytes"),
+                    (derive_plan, "fsdp_min_bytes"),
+                    (compile_step_with_plan, "fsdp_min_bytes")):
+        assert inspect.signature(fn).parameters[arg].default \
+            is FSDP_MIN_BYTES
+    monkeypatch.delenv("BIGDL_FSDP_MINBYTES", raising=False)
+    if value is not None:
+        monkeypatch.setenv("BIGDL_FSDP_MINBYTES", value)
+    opt = DistriOptimizer(nn.Linear(4, 2), array([Sample(
+        np.zeros(4, np.float32), np.float32(1))]), nn.MSECriterion(),
+        batch_size=1)
+    assert opt.fsdp_min_bytes == want
+    assert opt.set_fsdp().fsdp_min_bytes == FSDP_MIN_BYTES
+
+
+def test_pipeline_layout_says_that_it_keeps_the_replicated_update(caplog):
+    """A threshold on a data x pipe mesh is not applied (the packed
+    stack is stage-sharded, never gathered on use): the derived plan
+    says so in the log instead of dropping it silently."""
+    from bigdl_tpu.models.transformer import TransformerLM
+
+    RNG().set_seed(3)
+    model = TransformerLM(17, embed_dim=8, num_heads=2, num_layers=2,
+                          max_len=8)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "pipe"))
+    with caplog.at_level(logging.INFO, logger="bigdl_tpu"):
+        plan = derive_plan(model, mesh, pipe_axis="pipe", n_pipe=2,
+                           fsdp_min_bytes=64)
+    assert plan.fsdp_min_bytes is None
+    assert "pipeline layout keeps the replicated update" in caplog.text
+    assert "64 bytes" in caplog.text
+
+
+def test_armed_rule_on_one_device_reads_no_sharded_update():
+    """``Rule(fsdp=True)`` on a one-device data axis compiles the
+    unsharded program, and the engagement gauge reads what was
+    compiled: 0."""
+    RNG().set_seed(5)
+    model = nn.Sequential(nn.Linear(64, 64), nn.Tanh(), nn.Linear(64, 2))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    plan = Plan([Rule(r".*weight$", P("data", None), fsdp=True),
+                 Rule(".*", P())])
+    eng = compile_step_with_plan(model, nn.MSECriterion(),
+                                 SGD(learning_rate=0.1), mesh, plan=plan)
+    assert not eng.has_fsdp
+    assert eng.update_sharded_bytes == 0.0

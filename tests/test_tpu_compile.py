@@ -23,13 +23,17 @@ from bigdl_tpu.ops.latent_attend import BLOCK_POSITIONS, _latent_attend_kernel
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or it is held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -300,3 +304,78 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     # the prompt's 32 768 rows go in pieces of ``ragged_dot``: no Mosaic
     # call under the scope outside the decode step
     assert len(calls) == len(step)
+
+
+def test_dp4_step_gathers_behind_the_forward_and_reduce_scatters(
+        topo, monkeypatch):
+    """The data-parallel training step of ``parallel/plan.py`` compiled
+    for the described v5e:2x2's four chips (PERF.md §6 "PR 44"): two
+    layers at gpt2-medium's widths, Adam, bf16 compute, the step the
+    ``gpt2m_train_dp4`` cell times at toy depth.  Each of
+    the 15 leaves over 1 MiB lives on its data shard: its bf16 copy is
+    gathered ASYNCHRONOUSLY (an ``async-collective-start`` / ``-done``
+    fusion pair with the forward's products between them, or an
+    all-gather the compiler marked ``async_collective_name``), its f32
+    cotangent goes through ONE reduce-scatter fusion, and no all-reduce
+    over a leaf of 1 MiB or more is left — the parent's twelve combined
+    synchronous ``psum``s of 1.6 GB are gone from the program."""
+    import json
+    import os
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu import nn
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.optim import Adam
+    from bigdl_tpu.parallel.plan import compile_step_with_plan
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "gpt2-medium.json")) as f:
+        kw = json.load(f)["program"]["kwargs"]
+    # the real vocabulary: 50257 divides by nothing, so the two tables
+    # shard their MINOR dimension — and a smaller table's minor-dimension
+    # scatter the compiler turns back into an all-reduce and a slice
+    model = TransformerLM(**{**kw, "num_layers": 2})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    crit = nn.TimeDistributedCriterion(nn.CrossEntropyCriterion(), True)
+    eng = compile_step_with_plan(model, crit, Adam(3e-4), mesh,
+                                 compute_dtype=jnp.bfloat16, donate=True)
+    host = model.param_tree()
+    sharded = [name for name, row in eng.plan.table(host).items()
+               if "[fsdp]" in row]
+    assert len(sharded) == 2 * 6 + 3    # the block matrices, wte, wpe, head
+
+    def S(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    tree = jax.tree_util.tree_map
+    x = S(jax.ShapeDtypeStruct((32, 1024), jnp.float32), P("data"))
+    text = eng.jitted_for(x, x, False).lower(
+        tree(S, host, eng.param_specs),
+        tree(S, jax.eval_shape(eng.optim.init_state, host), eng.slot_specs),
+        tree(S, model.buffer_tree(), eng.buffer_specs),
+        S(jax.ShapeDtypeStruct((), jnp.float32), P()),
+        S(jax.ShapeDtypeStruct((2,), jnp.uint32), P()), x, x,
+    ).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    starts = re.findall(r"%(async-collective-start[.\d]*) = ", entry)
+    dones = re.findall(r"%(async-collective-done[.\d]*) = ", entry)
+    marked = [ln for ln in entry.splitlines()
+              if " all-gather(" in ln and "async_collective_name" in ln]
+    plain = [ln for ln in entry.splitlines()
+             if " all-gather(" in ln and "async_collective_name" not in ln]
+    assert len(starts) == len(dones) and not plain
+    assert len(starts) + len(marked) == len(sharded)
+    assert len(starts) >= 2 * 6         # every block matrix's gather
+    scatters = [ln for ln in entry.splitlines()
+                if "AllReduceScatterFusion" in ln]
+    assert len(scatters) == len(sharded)
+    assert all(re.search(r"= f32\[", ln) for ln in scatters)
+    # (a scatter's own fused computation holds an all-reduce: the entry's)
+    for shape in re.findall(r"= \(?(\w+\[[\d,]*\])[^=]* all-reduce\(", entry):
+        dims = [int(d) for d in re.findall(r"\d+", shape.split("[")[1])]
+        assert int(np.prod(dims or [1])) * 4 < 1 << 20, shape
