@@ -1,109 +1,45 @@
-"""Autoregressive generation for TransformerLM, HybridMambaLM,
-ParallelMoELM and SequentialMoELM — KV-cache decode, with a recurrent
-state beside the K/V where a block has one, a cache of each layer's own
-length where layers differ in what they see, a LATENT cache (no K or V
-by head) where a block's attention is latent, and NO cache but a
-two-position tail where a block's operator is a short convolution.
+"""Autoregressive generation for the causal LMs of this package
+(:class:`CausalLM`: ``TransformerLM``, ``HybridMambaLM``,
+``ParallelMoELM``, ``SequentialMoELM`` and its families) — cached decode
+over whatever state each layer keeps between tokens.
 
 The reference predates autoregressive LMs entirely (its sequence story
 is Recurrent/TimeDistributed, SURVEY §5.7), so this is a TPU-native
 extension: one jitted program containing a **batched prefill** (the
-whole prompt in one causal pass that fills the per-layer KV caches —
+whole prompt in one causal pass that fills the per-layer caches —
 MXU-sized matmuls, not a token loop) followed by a ``lax.scan`` over
-decode steps at static shapes, with the caches (``[B, Hkv, T_cache,
-Dh]`` — the KV head count, smaller than the query's under GQA)
-updated in place via ``lax.dynamic_update_slice``.  No Python-level
-loop over tokens, no recompilation per length.  ``T_cache`` is what
-THIS program can use — prompt + ``max_new``, both static, rounded up
-to a multiple of 128 and never past ``max_len`` (:func:`_cache_len`):
-a decode step of the plain form reads its whole cache, so a cache as
-long as the model's positional table would make every step pay for
-positions no call of this program can ever write.
+decode steps at static shapes, with the caches updated in place.  No
+Python-level loop over tokens, no recompilation per length.
 
-TWO decode attends are chosen by shapes alone, each by the
-``attend_plan`` of its op, with no argument, flag or model name: the
-attend on per-head K/V (``ops/gqa_attend.py``) and the absorbed attend
-on a latent cache (``ops/latent_attend.py``, below).  Where a layer's
-cache is large, on a TPU, ONE Pallas kernel walks it in blocks of 128
-positions up to the block the step's position falls in — each block
-read once for scores, softmax and the weighted sum, nothing beyond it
-fetched.  Everywhere else — small buckets, every other backend,
-``Tq > 1``, and for per-head K/V a ring or int8 storage — the plain
-einsums (:func:`_gqa_attend`, the one plain form of the per-head attend
-and the kernel's reference) read the whole static cache twice.
+**This file knows what is the same for every model, and asks the layers
+for the rest.**  Here: how long the cache of one program is
+(:func:`_cache_len` — prompt + ``max_new``, both static, rounded up to a
+multiple of 128 and never past ``max_len``: a decode step of the plain
+form reads its whole cache, so a cache as long as the model's positional
+table would make every step pay for positions no call of this program
+can ever write), the embedding, the loop over blocks, the head, sampling,
+the scan, beam search, the paged store and the compile ladder.  With the
+layer (the decode-state protocol, ``nn/attention.py``): what a block
+keeps between tokens (``block.state_init``) and how Tq tokens at ``pos``
+advance it (``block.advance``) — per-head K/V, plain, a ring of a window
+or int8 (``nn.MultiHeadAttention``); a latent cache with an absorbed
+step (``nn.LatentAttention``); a recurrent state beside the K/V
+(``nn.HybridMambaBlock``); a convolution tail (``nn.GatedShortConv``);
+expert counts (``models/parallel_moe.py``); ``n`` residual streams a
+token with a counter (``models/latent_moe.py``).  A new operator brings
+a module under ``nn/`` and no line of this file (``docs/serving.md``,
+"what a new architecture brings to be served").  ONE machinery
+(``_decode_machinery``) backs both the sampling decoder and beam search,
+and greedy decode is pinned against the full dense forward by a
+teacher-forcing oracle in tests/test_generate.py, which keeps the
+implementations from drifting.
 
-A hybrid block (``nn.HybridMambaBlock``) keeps, beside its K/V, the
-Mamba-2 mixer's SSM state ``[B, heads, head, N]`` (float32) and conv
-tail ``[B, d_conv - 1, channels]`` in the same per-layer cache dict:
-prefill runs the chunked scan and hands the state after the last
-prompt token to the decode scan, which advances it one token a step.
-The paged path keeps K/V pages only and refuses such a block.
-
-A block may see a sliding WINDOW (``MultiHeadAttention.window``): its
-K/V cache is then ``min(T_cache, window)`` positions long, written at
-``pos mod`` that length once it is full, and read with each slot's
-absolute position and the lower bound ``k_pos > q_pos - window``; a
-block without one keeps ``T_cache`` positions.  Rotation (rotate-half,
-interleaved, or none) and window are read per block.  A parallel block
-(``models/parallel_moe.py``) runs attention and its expert layer on ONE
-normed input; its cache also carries ``moe_counts`` ``[B, held]``, the
-assignments each held expert took from each row, which a generate call
-returns beside the tokens on request (``return_stats=True``).
-
-A block whose attention is LATENT (``nn.LatentAttention``;
-``models/latent_moe.py``) keeps ``ckv`` ``[B, T_cache, kv_rank]`` and
-``kr`` ``[B, rope, T_cache]`` — the normed latent and the one rotated
-key all heads share, the key with positions minor (``rope`` is half a
-lane tile) — and has TWO attention paths: prefill expands the prompt's
-latent to per-head K and V once and runs causal (flash) attention; a
-decode step absorbs ``wkv_b`` into the query and the output and attends
-on the latent itself, so that nothing with both a head and a
-cached-position axis exists but the scores.  That attend has two arms,
-chosen by shapes alone (``ops/latent_attend.py``, ``attend_plan``):
-where a layer's cache is large, on a TPU, ONE Pallas kernel walks the
-cache in blocks up to the block the step's position falls in — each
-block read once for scores, softmax and ``P c_kv``, nothing beyond it
-fetched; everywhere else the plain einsums read the whole static cache
-twice.
-
-A block whose operator is a gated SHORT CONVOLUTION
-(``nn.GatedShortConv``; ``models/latent_moe.py``'s ``ShortConvMoELM``)
-has no attention at all: its whole state is ``conv`` ``[B, kernel - 1,
-D]``, the last values of its gated input, whatever the context — no
-``k``, no ``v``, no position.  Prefill keeps the tail of the prompt, a
-decode step reads it, writes one output from ``kernel`` values and
-shifts.  The head geometry of such a model is its first attention
-layer's.  What a block is made of is decided in one place
-(:func:`_block_kind`).
-
-A block whose RESIDUAL is a hyper-connection (``nn.HyperConnection``;
-``models/latent_moe.py``'s ``HyperLatentMoELM``) carries ``n`` streams a
-token: ``prefill`` and ``decode_token`` hand ``[B, Tq, n, D]`` from
-layer to layer, each sublayer reading the mixture and writing the
-result its maps give (``models.latent_moe.sublayer_input`` /
-``sublayer_result``: the sequential arm asks them, for the plain
-residual too), and ``logits_last`` sums the streams.  The caches are
-what the operator's are; beside ``moe_counts`` such a layer's cache
-carries ``mhc_err``, the call's largest distance of a residual map from
-doubly stochastic.  Beam search and the paged decoder refuse the block
-by name.
-
-Built from the model's OWN parameter tree and modules (the
-parallel/pipeline.py pattern): LN/MLP sublayers run through their
-module ``apply_fn``; attention re-derives the q/k/v/o projections from
-the MultiHeadAttention parameter names (wq/wk/wv/wo + biases) because
-cached decode attention is a different computation from the module's
-full-sequence forward.  ONE machinery (``_decode_machinery``) backs
-both the sampling decoder and beam search, and greedy decode is pinned
-against the full dense forward by a teacher-forcing oracle in
-tests/test_generate.py, which keeps the implementations from drifting.
-
-MoE models decode through a capacity-FREE gather dispatch (each token
-simply uses its argmax expert): at inference nothing should be
-dropped — training-time capacity drops are a static-shape batching
-artifact, not part of the learned function.  The teacher-forcing
-equivalence with the training forward therefore holds whenever the
-training forward's capacity does not bind.
+Who cannot hold a layer's state says so once, from the protocol: beam
+search gathers every leaf along the beam axis and refuses a state with a
+leaf that has none; ``kv_dtype="int8"`` asks each operator to hold its
+state so (K/V can; a latent cache refuses; a state that is no K/V stays
+as it is); the paged decoder keeps K/V pages of one length and refuses a
+block whose state is anything else.
 
 Sampling: ``temperature=0`` → greedy argmax; ``temperature>0`` →
 categorical over ``logits/temperature`` (optionally within ``top_k``
@@ -124,7 +60,6 @@ from __future__ import annotations
 import threading
 import weakref
 from concurrent.futures import CancelledError
-from contextlib import nullcontext
 from functools import partial
 from typing import Optional
 
@@ -133,7 +68,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..nn.mamba import scaled as _scaled
-from .latent_moe import sublayer_input, sublayer_result
 
 # compiled generators per model instance (weak: dies with the model),
 # keyed by build config.  NOT stored on the module itself — a jitted
@@ -144,18 +78,48 @@ _GEN_CACHE = weakref.WeakKeyDictionary()
 _LOWER_LOCK = threading.Lock()
 
 
-def _check_model(model):
-    from .hybrid_mamba import HybridMambaLM
-    from .latent_moe import SequentialMoELM
-    from .parallel_moe import ParallelMoELM
-    from .transformer import TransformerLM
+class CausalLM:
+    """What generation serves, mixed into a ``Container`` of
+    ``TransformerLM``'s child layout: ``0`` the embedding, ``1..L``
+    blocks that answer the decode-state protocol (``nn/attention.py``),
+    ``L+1`` the final norm, ``L+2`` the head.  What the two ends apply
+    beside their modules is read here, each at its neutral default: a
+    table of learned positions unless ``use_rope``, a head that is handed
+    the embedding's leaf where ``tied_head``, and three constant
+    scales."""
 
-    if not isinstance(model, (TransformerLM, HybridMambaLM, ParallelMoELM,
-                              SequentialMoELM)):
+    use_rope = False
+    tied_head = False
+    embedding_multiplier = 1.0
+    lm_head_multiplier = 1.0
+    logit_scale = 1.0
+
+    def generate(self, prompt_ids, max_new: int, rng=None,
+                 temperature: float = 0.0, top_k: int = 0,
+                 top_p: float = 1.0, compute_dtype=None,
+                 eos_id=None, pad_id=None):
+        """Autoregressive decode through each layer's own cache:
+        prefill + ``lax.scan`` decode at static shapes.
+        ``temperature=0`` is greedy (pinned against the dense forward by
+        teacher forcing); ``>0`` samples, optionally within ``top_k``
+        and/or the ``top_p`` nucleus; the compiled program holds only
+        the sampler the call asked for, and a new ``temperature`` or
+        ``top_p`` value compiles nothing.  ``eos_id`` stops a row early
+        (it keeps emitting ``pad_id``, default the eos itself —
+        hf.generate's convention, at static shapes).  The compiled
+        generator is cached per (max_len, compute_dtype)."""
+        return cached_generate(self, compute_dtype)(
+            self.param_tree(), prompt_ids, max_new, rng=rng,
+            temperature=temperature, top_k=top_k, top_p=top_p,
+            eos_id=eos_id, pad_id=pad_id)
+
+
+def _check_model(model):
+    if not isinstance(model, CausalLM):
         raise TypeError(
-            f"generation supports TransformerLM, HybridMambaLM, "
-            f"ParallelMoELM and SequentialMoELM (LatentMoELM, "
-            f"ShortConvMoELM, HyperLatentMoELM; got {type(model).__name__})")
+            f"generation supports the CausalLM containers (TransformerLM, "
+            f"HybridMambaLM, ParallelMoELM, SequentialMoELM and its "
+            f"families; got {type(model).__name__})")
     # seq_strategy (dense/flash/ring/ulysses) changes only HOW training
     # attention is computed — the parameter tree is strategy-independent,
     # so a ring/Ulysses-trained model decodes through the same cached
@@ -164,125 +128,13 @@ def _check_model(model):
     return 1, len(model.modules) - 3
 
 
-def _block_kind(block) -> tuple:
-    """What a block is made of — decided HERE and nowhere else:
-    ``(form, operator, experts)``.
-
-    * ``form``: ``"hybrid"`` (``nn.HybridMambaBlock``: a recurrent state
-      beside its attention), ``"parallel"``
-      (``models.parallel_moe.ParallelMoEBlock``: attention and the
-      expert layer read one normed input) or ``"sequential"`` (the
-      operator, then the FFN on a second norm);
-    * ``operator``: ``"latent"`` (``nn.LatentAttention``: the cache
-      holds the latent and the shared rotated key), ``"conv"``
-      (``nn.GatedShortConv``: no attention; the cache holds the
-      convolution's tail and nothing else) or ``"kv"`` (per-head K and
-      V);
-    * ``experts``: the block's ``DroplessMoE`` (its cache carries
-      ``moe_counts``), or None."""
-    form = {"hybrid_mamba": "hybrid", "parallel_moe": "parallel"}.get(
-        getattr(block, "kind", None), "sequential")
-    operator = {"latent": "latent", "short_conv": "conv"}.get(
-        getattr(block.modules[1], "kind", None), "kv")
-    experts = (block.moe if form == "parallel"
-               or getattr(block, "ffn_kind", None) == "moe" else None)
-    return form, operator, experts
-
-
-def _is_hybrid(block) -> bool:
-    return _block_kind(block)[0] == "hybrid"
-
-
-def _is_parallel(block) -> bool:
-    return _block_kind(block)[0] == "parallel"
-
-
-def _is_latent(block) -> bool:
-    return _block_kind(block)[1] == "latent"
-
-
-def _is_conv(block) -> bool:
-    return _block_kind(block)[1] == "conv"
-
-
-def _head_geometry(blocks) -> tuple:
-    """``(heads, K/V heads, head size)`` of the model's per-head K/V,
-    from the first block that keeps one: a block without attention has
-    no heads to ask for, and a latent block keeps nothing by head (its
-    head count is returned for a model of latent blocks alone, with no
-    head size — such a model uses none)."""
-    by_kind = {}
-    for b in blocks:
-        by_kind.setdefault(_block_kind(b)[1], b.modules[1])
-    mha = by_kind.get("kv", by_kind.get("latent"))
-    H = getattr(mha, "num_heads", None)
-    return H, getattr(mha, "num_kv_heads", H), getattr(mha, "head_dim", None)
-
-
-def _window_of(block):
-    """The block's sliding window in positions, or None."""
-    return getattr(block.modules[1], "window", None)
-
-
-def _refuse_recurrent(model, first, count, what: str):
-    """The paged path keeps K/V pages only: a block with a recurrent
-    state has nowhere to put it there, so it is refused, not decoded
-    without its state.  Its pages are all of one length and its block
-    step is the sequential one, so a block with a window or a parallel
-    expert layer is refused too, not decoded as another model."""
-    blocks = model.modules[first:first + count]
-    _refuse_streams(blocks, what,
-                    "carries one residual vector [B, 1, D] a token")
-    _refuse_latent(blocks, what, "K/V pages [Hkv, page, Dh]")
-    conv = [b.modules[1] for b in blocks if _is_conv(b)]
-    if conv:
-        raise TypeError(
-            f"{what} pages K/V only and {type(conv[0]).__name__} keeps no "
-            f"K or V at all — its whole state is a convolution tail of "
-            f"{conv[0].kernel - 1} positions a row: decode this model "
-            f"through generate() / submit_generate(), whose static cache "
-            f"holds a tail where a layer has one")
-    if any(_is_parallel(b) or _window_of(b) for b in blocks):
-        raise TypeError(
-            f"{what} keeps pages of ONE length for every layer and runs "
-            f"the sequential block: {type(model).__name__}'s layers "
-            f"differ in what they see (a window beside full attention) "
-            f"and run attention and experts on one norm.  Decode this "
-            f"model through generate() / submit_generate(), whose "
-            f"static cache is sized per layer")
-    if any(_is_hybrid(b) for b in model.modules[first:first + count]):
-        raise TypeError(
-            f"{what} pages K/V only and {type(model).__name__}'s blocks "
-            f"carry a recurrent state (SSM state and conv tail) beside "
-            f"it: decode this model through generate() / "
-            f"submit_generate(), whose static cache holds both")
-
-
-def _refuse_streams(blocks, what: str, why: str):
-    """A hyper-connected block's state between layers is ``n`` streams a
-    token and its cache carries a counter with no batch axis: a decoder
-    built around one residual vector and batch-major cache leaves
-    refuses it, by name."""
-    hyper = [b for b in blocks if getattr(b, "streams", 0)]
-    if hyper:
-        raise TypeError(
-            f"{what} {why} and {type(hyper[0].hyper[0]).__name__} makes "
-            f"the residual of {type(hyper[0]).__name__} "
-            f"{hyper[0].streams} streams a token: decode this model "
-            f"through generate() / submit_generate()")
-
-
-def _refuse_latent(blocks, what: str, holds: str):
-    """A latent block's cache is the latent and ONE rotated key a
-    position, no K or V by head: a store made for those has no place
-    for it."""
-    latent = [b.modules[1] for b in blocks if _is_latent(b)]
-    if latent:
-        raise TypeError(
-            f"{what} holds {holds} and {type(latent[0]).__name__} keeps "
-            f"no K or V by head — its cache is the latent and one rotated "
-            f"key a position: decode this model through generate() / "
-            f"submit_generate() with the default cache")
+def _refusal(what: str, block):
+    """The one way a decoder says it cannot hold a block's state: by the
+    block's class and in the block's own words (``state_doc``)."""
+    return TypeError(
+        f"{what} and cannot hold {type(block).__name__}'s state — "
+        f"{getattr(block, 'state_doc', 'it keeps one of its own')}: decode "
+        f"this model through generate() / submit_generate()")
 
 
 def _check_len(model, max_len):
@@ -327,135 +179,6 @@ def _cast_params(p, compute_dtype):
     return hold_floats(p, compute_dtype, keep=FLOAT32_LEAVES)
 
 
-def _proj(x, params, w, b, with_bias):
-    y = jnp.dot(x, params[w].T)
-    return y + params[b] if with_bias else y
-
-
-# capacity-bind capture: while a list is installed on this thread,
-# every _moe_ffn_nodrop call appends the fraction of its tokens that
-# the TRAINING dispatch's static capacity would have dropped (trace-
-# time side channel for capacity_bind_report; absent during normal
-# decode).  Thread-LOCAL so a concurrent trace of another model's
-# generator cannot interleave its fractions into this report.
-
-_BIND_TLS = threading.local()
-
-
-def _moe_ffn_nodrop(moe, params, x):
-    """Capacity-free top-k advance of a ``MoEFFN`` for decode, through
-    the dropless dispatch of ``parallel/moe.py``: assignments sorted by
-    expert, one grouped product per projection (each expert's weights
-    read at most once a call, none gathered per token), mixed by the
-    (top-1 raw / top-k renormalized) gates.  [B, Tq, D] -> [B, Tq, D]."""
-    from ..parallel.moe import (dropless_apply, grouped_matmul,
-                                route_top_k, row_experts)
-
-    B, Tq, D = x.shape
-    K = getattr(moe, "top_k", 1)
-    x2 = x.reshape(B * Tq, D)
-    gk, idxk = route_top_k(x2, params["router_w"], params["router_b"], K,
-                           "softmax", renormalize=K > 1)
-    if getattr(_BIND_TLS, "capture", None) is not None:
-        # the training dispatch's keep rule, via the module's own
-        # shared helper so the two can never drift (capacity from THIS
-        # batch's token count; choice-ordered stream like _route) —
-        # the fraction is over all N·K routing assignments
-        kept, counts = 0.0, None
-        for c in range(K):
-            oh = jax.nn.one_hot(idxk[:, c], moe.n_experts,
-                                dtype=jnp.float32)
-            _, keep, counts = moe.keep_mask(oh, counts)
-            kept = kept + jnp.sum(keep.astype(jnp.float32))
-        _BIND_TLS.capture.append(1.0 - kept / (B * Tq * K))
-
-    def gelu_experts(xs, sizes):
-        e = row_experts(sizes, xs.shape[0])
-        h = jax.nn.gelu(grouped_matmul(xs, params["wi"], sizes)
-                        + params["bi"][e].astype(xs.dtype))
-        return (grouped_matmul(h, params["wo"], sizes)
-                + params["bo"][e].astype(xs.dtype))
-
-    y, _ = dropless_apply(x2, idxk, gk, (0, moe.n_experts), gelu_experts)
-    return y.reshape(B, Tq, D)
-
-
-def _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None,
-                window=None):
-    """Causal attention of Tq queries (absolute positions
-    pos..pos+Tq-1) against a dense ``[B, Hkv, Tm, Dh]`` cache view.
-    GQA contracts the query groups against the UN-repeated cache — a
-    repeat here would materialize H/Hkv copies of the whole cache
-    every decode step, exactly the bandwidth GQA exists to save.
-    Shared by the dense-cache machinery and the paged decode path (the
-    paged path passes a page-gathered view), so the two can never
-    drift numerically.  ``k_pos`` [Tm] gives each cache slot's
-    ABSOLUTE position when the view is not contiguous from 0 — the
-    page-window path gathers only the live pages, so slot index and
-    position diverge.  ``window`` adds the sliding window's far edge,
-    ``k_pos > q_pos - window``, and masks a slot that holds no position
-    yet (``k_pos < 0``: a ring that is not full)."""
-    Tq, Tm = q.shape[2], k_cache.shape[2]
-    scale = 1.0 / jnp.sqrt(jnp.float32(Dh)).astype(q.dtype)
-    qpos = pos + jnp.arange(Tq)
-    if k_pos is None:
-        k_pos = jnp.arange(Tm)
-    mask = k_pos[None, :] <= qpos[:, None]            # [Tq, Tm]
-    if window is not None:
-        mask = (mask & (k_pos[None, :] > qpos[:, None] - window)
-                & (k_pos[None, :] >= 0))
-    if Hkv == H:
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache) * scale
-        scores = jnp.where(mask[None, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype),
-                          v_cache)
-    B = q.shape[0]
-    qg = q.reshape(B, Hkv, H // Hkv, Tq, Dh)
-    scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache) * scale
-    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(q.dtype),
-                   v_cache)
-    return o.reshape(B, H, Tq, Dh)
-
-
-def _ffn(block, bp, ln2):
-    """The block's MLP (gelu / swiglu / one gated module / capacity-free
-    MoE) on its normed input."""
-    kind = getattr(block, "mlp_kind",
-                   "moe" if block.is_moe else "gelu")
-    if kind == "moe":
-        ffn = _moe_ffn_nodrop(block.modules[3], bp["3"], ln2)
-    elif kind == "gated":       # the whole SwiGLU is child 3 (GatedFFN)
-        ffn, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False, None)
-    elif kind == "swiglu":
-        # a hybrid block's two muP constants; (1, 1) multiplies nothing
-        gm, dm = getattr(block, "mlp_multipliers", (1.0, 1.0))
-        g, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
-                                         None)
-        u, _ = block.modules[4].apply_fn(bp["4"], {}, ln2, False,
-                                         None)
-        ffn, _ = block.modules[5].apply_fn(
-            bp["5"], {}, jax.nn.silu(_scaled(g, gm)) * u, False, None)
-        ffn = _scaled(ffn, dm)
-    else:
-        mid, _ = block.modules[3].apply_fn(bp["3"], {}, ln2, False,
-                                           None)
-        out, _ = block.modules[4].apply_fn(bp["4"], {},
-                                           jax.nn.gelu(mid), False,
-                                           None)
-        ffn = out
-    return ffn
-
-
-def _ffn_sublayer(block, bp, h):
-    """ln2 + :func:`_ffn` with the residual add — the post-attention
-    half of a block with the plain residual, shared by the dense-cache
-    and paged machineries."""
-    ln2, _ = block.modules[2].apply_fn(bp["2"], {}, h, False, None)
-    return h + _ffn(block, bp, ln2)
-
 
 def _cache_len(T_max, T0, max_new):
     """Positions the static K/V cache of one generate program holds:
@@ -470,51 +193,6 @@ def _cache_len(T_max, T0, max_new):
     return min(T_max, -(-(T0 + max_new) // 128) * 128)
 
 
-def _cache_init(block, B, T_cache, dt, kv_int8=False):
-    """One layer's state for ``B`` rows, a dict: K and V ``[B, Hkv,
-    T_cache, Dh]`` (int8 with ``k_scale`` / ``v_scale`` beside them
-    under ``kv_int8``) and, for a hybrid block, the mixer's ``ssm``
-    state and ``conv`` tail beside those.  ``T_cache`` is the calling
-    program's :func:`_cache_len`, not the model's ``max_len``: every
-    reader of the cache takes its length from its shape.  A block with
-    a sliding window keeps ``min(T_cache, window)`` positions (a ring);
-    a block with an expert layer adds ``moe_counts`` ``[B, held]``
-    int32.  A LATENT block keeps no K or V: ``ckv`` ``[B, T_cache,
-    kv_rank]`` (the normed latent) and ``kr`` ``[B, rope, T_cache]``
-    (the rotated key all heads share; positions minor, so that no
-    position's ``rope`` numbers are padded to a lane tile and the
-    attend's kernel reads what the leaf holds) — no leaf has a head
-    axis, and a position holds ``kv_rank + rope`` numbers.  A block
-    whose operator is a SHORT CONVOLUTION keeps ``conv`` ``[B, kernel -
-    1, D]`` and nothing else: no ``k``, no ``v``, under ``kv_int8``
-    too (the tail is not K/V and stays in ``dt``)."""
-    form, operator, experts = _block_kind(block)
-    mha = block.modules[1]
-    if operator == "conv":
-        cache = mha.state_init(B, dt)
-    elif operator == "latent":
-        cache = {"ckv": jnp.zeros((B, T_cache, mha.kv_rank), dt),
-                 "kr": jnp.zeros((B, mha.rope_dim, T_cache), dt)}
-    else:
-        Hkv = getattr(mha, "num_kv_heads", mha.num_heads)
-        kv = (B, Hkv, min(T_cache, _window_of(block) or T_cache),
-              mha.head_dim)
-        if kv_int8:
-            cache = {"k": jnp.zeros(kv, jnp.int8),
-                     "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
-                     "v": jnp.zeros(kv, jnp.int8),
-                     "v_scale": jnp.zeros(kv[:3] + (1,), jnp.float32)}
-        else:
-            cache = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
-    if form == "hybrid":
-        cache.update(block.mixer.state_init(B, dt))
-    if experts is not None:
-        cache["moe_counts"] = jnp.zeros((B, experts.held[1]), jnp.int32)
-    if getattr(block, "streams", 0):   # a counter too: one number a layer
-        cache["mhc_err"] = jnp.zeros((), jnp.float32)
-    return cache
-
-
 def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                     compute_dtype=None, max_len: Optional[int] = None,
                     kv_dtype: Optional[str] = None) -> dict:
@@ -522,30 +200,23 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     tokens and ``max_new`` answer tokens holds on the device, from
     shapes alone: ``kv_cache_positions`` (how long that program's
     static cache is, :func:`_cache_len`, against the model's
-    ``max_len``), ``kv_cache_bytes`` (the K/V of every layer THAT KEEPS
-    ONE at that length: what is allocated, and what every decode step
-    reads) and ``recurrent_state_bytes`` (SSM state and conv tail — a
+    ``max_len``) and what each layer says it keeps at that length
+    (``block.footprint``), summed by kind: ``kv_cache_bytes`` (the K/V
+    of every layer THAT KEEPS ONE: what is allocated, and what every
+    decode step reads; also by kind of layer, ``kv_cache_bytes_window``
+    and ``kv_cache_bytes_full``, where layers differ in what they see),
+    ``latent_cache_bytes`` (a latent layer's; its ``kv_cache_bytes`` is
+    0) and ``recurrent_state_bytes`` (SSM state and conv tail — a
     short-convolution layer's whole state; zero for a model without
-    them).  Where some layer has a sliding window, K/V
-    is also given by KIND of layer: ``kv_cache_bytes_window`` (layers
-    that keep ``min(positions, window)``) and ``kv_cache_bytes_full``.
-    A model with latent attention also gives ``latent_cache_bytes``
-    (the latent and the shared rotated key of every position, as
-    allocated; its ``kv_cache_bytes`` is 0) and which arm of the
-    absorbed attend this program's decode step compiled:
-    ``latent_attend`` (``"kernel"`` or ``"einsum"``) and
-    ``latent_attend_block`` (positions a block of the kernel's walk; 0
-    for the einsums) — ``ops.latent_attend.attend_plan``, the rule the
-    step itself reads.  A model with per-head K/V gives the same of its
-    decode attend: ``kv_attend`` and ``kv_attend_block``, from
-    ``ops.gqa_attend.attend_plan`` (the layers that are no ring; a ring
-    keeps the einsums).  A model with experts gives the arm and the
-    tile plan of the grouped products in its decode step (a buffer of
-    ``batch * min(top_k, held)`` rows): ``grouped`` (``"ragged"``,
-    ``"grouped_decode"`` or ``"gmm"``), ``grouped_tiles`` (``"<rows a
-    product>x<tk>x<tn>"`` of the gate and up products; empty for
-    ``ragged``) and ``grouped_tiles_down`` — ``parallel.moe.
-    grouped_plan``, the rule ``grouped_matmul`` itself reads."""
+    them).  Beside the bytes, which arm this program's decode step
+    compiled: ``kv_attend`` / ``latent_attend`` (``"kernel"`` or
+    ``"einsum"``) with ``kv_attend_block`` / ``latent_attend_block``
+    (positions a block of the kernel's walk; 0 for the einsums) — of
+    the layers that take the kernel, where some do (a ring keeps the
+    einsums) — and, for a model with experts, ``grouped``,
+    ``grouped_tiles`` and ``grouped_tiles_down``
+    (``DroplessMoE.decode_plan``).  Each is the rule the step itself
+    reads."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
@@ -553,409 +224,88 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
         model.param_tree())[0].dtype)
     out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
            "kv_cache_positions": T_cache}
-    blocks = model.modules[first:first + count]
-    by_kind = {"kv_cache_bytes_window": 0, "kv_cache_bytes_full": 0}
-    if any(_is_latent(b) for b in blocks):
-        out["latent_cache_bytes"] = 0
-    plain = None    # what a K/V layer that is no ring stores
-    for block in blocks:
-        shapes = jax.eval_shape(partial(_cache_init, block, int(batch),
-                                        T_cache, dt, _kv_int8(kv_dtype)))
-        for name, a in shapes.items():
-            nbytes = a.size * a.dtype.itemsize
-            if name == "k" and a.shape[2] != _window_of(block):
-                plain = a.dtype
-            if name in ("k", "v", "k_scale", "v_scale"):
-                out["kv_cache_bytes"] += nbytes
-                by_kind["kv_cache_bytes_window" if _window_of(block)
-                        else "kv_cache_bytes_full"] += nbytes
-            elif name in ("ckv", "kr"):
-                out["latent_cache_bytes"] += nbytes
-            elif name not in ("moe_counts", "mhc_err"):   # counters
-                out["recurrent_state_bytes"] += nbytes
-    if any(_window_of(b) for b in blocks):
-        out.update(by_kind)
-    if "latent_cache_bytes" in out:
-        from ..ops.latent_attend import attend_plan
-
-        mla = next(b.modules[1] for b in blocks if _is_latent(b))
-        block = attend_plan(int(batch), T_cache, mla.kv_rank,
-                            mla.rope_dim, dt)
-        out.update(latent_attend="kernel" if block else "einsum",
-                   latent_attend_block=block)
-    if out["kv_cache_bytes"]:
-        from ..ops.gqa_attend import attend_plan
-
-        # every layer that is not a ring keeps T_cache positions and
-        # attends alike; a ring keeps the einsums whatever its size
-        _, Hkv, Dh = _head_geometry(blocks)
-        plan = 0 if plain is None else attend_plan(
-            int(batch), Hkv, T_cache, Dh, plain)
-        out.update(kv_attend="kernel" if plan else "einsum",
-                   kv_attend_block=plan)
-    experts = next((e for e in (_block_kind(b)[2] for b in blocks)
-                    if e is not None), None)
-    if experts is not None:
-        from ..parallel.moe import grouped_plan
-
-        # a decode step's buffer: one token a row, its choices among
-        # the held experts
-        rows = int(batch) * min(experts.top_k, experts.held[1])
-        D, F = experts.embed_dim, experts.hidden_dim
-        impl, up = grouped_plan(rows, D, F, dt)
-        down = grouped_plan(rows, F, D, dt)[1]
-        # as text: these ride on ``serve.dispatch`` into a profiler
-        # session, whose event metadata is split at commas
-        out.update(grouped=impl,
-                   grouped_tiles="x".join(map(str, up or ())),
-                   grouped_tiles_down="x".join(map(str, down or ())))
+    for block in model.modules[first:first + count]:
+        for name, v in block.footprint(int(batch), dt, T_cache,
+                                       _kv_int8(kv_dtype)).items():
+            if isinstance(v, int):                  # bytes of a kind
+                out[name] = out.get(name, 0) + v
+            elif not isinstance(v, tuple):          # a plan, in words
+                out.setdefault(name, v)
+            elif v[1] or name not in out:           # (arm, its block)
+                out[name], out[name + "_block"] = v
     return out
 
 
-def _decode_machinery(model, first, count, kv_int8=False):
-    """The cached-attention forward shared by the sampling decoder and
-    beam search — built once per generator from the model structure.
-    Every function takes the (already cast) param tree ``pc``
-    explicitly; ``prefill`` is told how long a cache to allocate, and
-    everything after it takes that length from the cache's shape.
-
-    ``kv_int8`` stores the caches as int8 with a float32 scale per
-    (batch, head, position) — absmax rounding over the head dim.
-    Decode is cache-bandwidth-bound, so halving (vs bf16) the bytes
-    read per step buys throughput; the prompt's own prefill attention
-    stays full-precision (only post-prefill decode steps read the
-    quantized cache).  Lossy by construction — an approximation knob,
-    off by default.  It quantises K and V and nothing else: a layer
-    without attention keeps its convolution tail as it is."""
+def _ends(model, first, count):
+    """The two ends every decoder shares: ``embed_at(pc, tok, pos, Tq)``
+    (the state the first block takes) and ``logits_last(pc, h)``."""
     blocks = model.modules[first:first + count]
-    ln_f = model.modules[first + count]
-    head = model.modules[first + count + 1]
-    embed = model.modules[0]
-    if kv_int8:
-        _refuse_latent(blocks, 'kv_dtype="int8"',
-                       "K and V by head as int8 with a scale a head")
-    # per-head K/V geometry, GQA's smaller K/V head count included (a
-    # latent block has none and uses none; a block without attention
-    # is passed over)
-    H, Hkv, Dh = _head_geometry(blocks)
-    use_rope = getattr(model, "use_rope", False)
-    tied = getattr(model, "tied_head", False)
-    # streams of the state between layers: all blocks alike (0: plain)
-    n_streams = getattr(blocks[0], "streams", 0)
+    embed, ln_f, head = (model.modules[i]
+                         for i in (0, first + count, first + count + 1))
+    # a block whose state between layers is not the one residual vector
+    # says how the embedding becomes it and what the final norm takes
+    spread = getattr(blocks[0], "replicate", lambda h: h)
+    gather = getattr(blocks[0], "reduce", lambda h: h)
 
-    def _rope_of(mha):
-        """(kind, theta) of ONE block's rotation — "half",
-        "interleaved", or None for a layer without positions; a model
-        that rotates (``use_rope``) and whose layers do not say how
-        rotates by halves."""
-        kind = getattr(mha, "rope_kind", "half") if use_rope else None
-        return kind, getattr(mha, "rope_theta", 10000.0)
-
-    def _split(x, B, h=H):
-        return x.reshape(B, -1, h, Dh).transpose(0, 2, 1, 3)
-
-    def _rep(kv):
-        """Broadcast the Hkv kv heads to the H query heads (GQA) — only
-        used on the prompt-length prefill tensors; the decode hot loop
-        keeps the cache un-repeated via the grouped einsum below."""
-        if Hkv == H:
-            return kv
-        return jnp.repeat(kv, H // Hkv, axis=1)
-
-    def _attend(q, k_cache, v_cache, pos, window=None):
-        from ..ops.gqa_attend import _gqa_attend_kernel, attend_plan
-
-        # one kernel pass over the written part of the cache where the
-        # shapes say it wins, ``_gqa_attend`` over the whole of it
-        # otherwise (a ring, int8 storage, ``Tq > 1``, a small cache,
-        # every backend but a TPU)
-        block = attend_plan(q.shape[0], Hkv, k_cache.shape[2], Dh,
-                            jnp.int8 if kv_int8 else k_cache.dtype,
-                            q.shape[2], window)
-        with jax.named_scope("attention.decode_attend"):
-            if block:
-                return _gqa_attend_kernel(q[:, :, 0], k_cache, v_cache, pos,
-                                          block, False)[:, :, None]
-            if window is None:
-                return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh)
-            # a ring: slot s holds the latest position <= pos that is s
-            # mod the ring's length (negative: none yet)
-            ring = k_cache.shape[2]
-            k_pos = pos - (pos - jnp.arange(ring)) % ring
-            return _gqa_attend(q, k_cache, v_cache, pos, H, Hkv, Dh,
-                               k_pos=k_pos, window=window)
-
-    def _quant(x):
-        """absmax int8 over the head dim: x ≈ q * s, q int8,
-        s [β..., 1] float32."""
-        s_ = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
-                     keepdims=True) / 127.0 + 1e-12
-        q_ = jnp.round(x.astype(jnp.float32) / s_).astype(jnp.int8)
-        return q_, s_
-
-    def _ring_put(arr, x, pos):
-        """``x`` [B, Hkv, Tq, ·] at positions pos.. into ``arr``, whose
-        time axis may be SHORTER than the positions the program spans
-        (a sliding layer's ring): a token goes to slot ``pos mod`` the
-        ring's length; of a prompt longer than the ring the last ring's
-        worth is kept, each position at its slot."""
-        ring, Tq = arr.shape[2], x.shape[2]
-        if isinstance(pos, int):                # prefill, from 0
-            if Tq <= ring:
-                return lax.dynamic_update_slice(arr, x, (0, 0, pos, 0))
-            return jnp.roll(x[:, :, Tq - ring:], (Tq - ring) % ring, axis=2)
-        return lax.dynamic_update_slice(arr, x, (0, 0, pos % ring, 0))
-
-    def _cache_write(cache, k, v, pos, ringed=False):
-        put = _ring_put if ringed else (
-            lambda arr, x, pos: lax.dynamic_update_slice(arr, x,
-                                                         (0, 0, pos, 0)))
-        new = dict(cache)
-        for name, x in (("k", k), ("v", v)):
-            if kv_int8:
-                x, scale = _quant(x)
-                new[name + "_scale"] = put(cache[name + "_scale"], scale,
-                                           pos)
-            new[name] = put(cache[name], x, pos)
-        return new
-
-    def _cache_kv(cache, dt):
-        """(k, v) dense views of the cache — for int8 the convert+
-        scale is elementwise and fuses into the attention dot's
-        operand read (the int8 bytes are what HBM streams)."""
-        if kv_int8:
-            return (cache["k"].astype(dt) * cache["k_scale"].astype(dt),
-                    cache["v"].astype(dt) * cache["v_scale"].astype(dt))
-        return cache["k"], cache["v"]
-
-    def _latent_attention(mla, ap, ln1, cache, pos):
-        """Latent attention of Tq tokens at ``pos`` against the cache of
-        ``ckv`` / ``kr``.  Prefill EXPANDS the prompt's latent to
-        per-head K and V once and runs causal (flash) attention at the
-        full head size.  A decode step never expands the cache: the
-        key half of ``wkv_b`` is absorbed into the query (``q_lat =
-        q_nope W_uk``), scores and the weighted sum are taken on the
-        latent itself, and the value half is applied to the ONE
-        resulting latent a head (``o = o_lat W_uv``) — algebraically the
-        same, ``kv_rank + rope`` numbers a cached position read instead
-        of ``heads * (qk + v)`` made.  The only array with both a head
-        and a cached-position axis is the scores."""
-        Tq = ln1.shape[1]
-        qpos = pos + jnp.arange(Tq)
-        with jax.named_scope("mla.q_proj"):
-            q_nope, q_rope = mla.queries(ap, ln1, qpos)
-        with jax.named_scope("mla.kv_latent"):
-            ckv, kr = mla.latent(ap, ln1, qpos)
-            cache = {**cache,
-                     "ckv": lax.dynamic_update_slice(
-                         cache["ckv"], ckv.astype(cache["ckv"].dtype),
-                         (0, pos, 0)),
-                     "kr": lax.dynamic_update_slice(
-                         cache["kr"],
-                         kr.astype(cache["kr"].dtype).transpose(0, 2, 1),
-                         (0, 0, pos))}
-        if isinstance(pos, int) and pos == 0:
-            with jax.named_scope("mla.expand"):
-                k, v = mla.expand(ap, ckv, kr)
-            # the flash kernels take one head size; a narrower value
-            # head goes the plain way, whole scores
-            o = mla.attend_full(q_nope, q_rope, k, v,
-                                flash=v.shape[-1] == mla.qk_dim)
-        else:
-            w_uk, w_uv = mla.up_weights(ap)
-            dt = q_nope.dtype
-            with jax.named_scope("mla.absorb"):
-                q_lat = jnp.einsum("bhqn,hnc->bhqc", q_nope,
-                                   w_uk.astype(dt))
-            with jax.named_scope("mla.attend"):
-                # one pass over the written part of the cache where the
-                # shapes say the kernel wins, the plain einsums over the
-                # whole of it otherwise (``ops.latent_attend.attend_plan``)
-                from ..ops.latent_attend import latent_attend
-
-                o_lat = latent_attend(q_lat, q_rope, cache["ckv"],
-                                      cache["kr"], pos, mla.qk_dim,
-                                      scale_mult=mla.softmax_mult)
-            with jax.named_scope("mla.absorb"):
-                o = jnp.einsum("bhqc,hvc->bhqv", o_lat, w_uv.astype(dt))
-        with jax.named_scope("mla.out_proj"):
-            return mla.out_proj(ap, o), cache
-
-    def _attention(block, ap, ln1, cache, pos):
-        """Cached attention of one block on Tq tokens at ``pos``;
-        returns (the output projection's result, cache)."""
-        mha = block.modules[1]
-        if _is_latent(block):
-            return _latent_attention(mha, ap, ln1, cache, pos)
-        B = ln1.shape[0]
-        q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
-        k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
-        v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
-        k = _scaled(k, getattr(mha, "key_multiplier", 1.0))
-        # per-head QK-norm where the module has it, BEFORE the rotation:
-        # the cache holds normed, rotated keys
-        q, k = mha.normed_heads(ap, q, k)
-        rope, rope_theta = _rope_of(mha)
-        if rope:
-            # rotate at ABSOLUTE positions; the cache stores rotated
-            # keys (the standard KV-cache convention for RoPE)
-            from ..nn.attention import rope_rotate
-
-            qpos = pos + jnp.arange(q.shape[2])
-            il = rope == "interleaved"
-            q = rope_rotate(q, qpos, rope_theta, interleaved=il)
-            k = rope_rotate(k, qpos, rope_theta, interleaved=il)
-        window = _window_of(block)
-        # a sliding layer's cache as long as its window is a ring; a
-        # shorter one holds every position of this program, all of them
-        # inside the window: a plain cache
-        ringed = bool(window) and cache["k"].shape[2] == window
-        cache = _cache_write(cache, k, v, pos, ringed)
-        if isinstance(pos, int) and pos == 0:
-            # the whole prefill (ANY prompt length — a 1-token prompt
-            # rides flash_attention's dense fallback) attends the
-            # full-precision k/v, so the first generated token is
-            # bit-exact even under kv_int8
-            # prefill: causal attention over the PROMPT only — cache
-            # slots past the prompt are outside the causal horizon
-            # anyway, so scoring the whole [T_cache] cache (the _attend
-            # path) wastes T_cache/T0 of the work and materializes the
-            # full score tile.  The flash kernels make this
-            # O(T0·block) memory on TPU; off-TPU (and at non-blockable
-            # T0) flash_attention falls back to the same dense causal
-            # attention, so numerics stay pinned by the greedy
-            # teacher-forcing oracle either way.
-            from ..ops.flash_attention import flash_attention
-
-            o = flash_attention(q, _rep(k), _rep(v), causal=True,
-                                window=window)
-        else:
-            o = _attend(q, *_cache_kv(cache, q.dtype), pos,
-                        window if ringed else None)
-        o = o.transpose(0, 2, 1, 3).reshape(B, o.shape[2], H * Dh)
-        return _proj(o, ap, "wo", "bo", mha.with_bias), cache
-
-    def _operator(block, operator, ap, ln1, cache, pos):
-        """The token-mixing operator of one sequential block on Tq
-        tokens at ``pos``: cached attention, or the short convolution
-        over its tail (prefill keeps the prompt's last values, a step
-        shifts one in)."""
-        if operator != "conv":
-            return _attention(block, ap, ln1, cache, pos)
-        conv = block.modules[1]
-        if isinstance(pos, int) and pos == 0:
-            a, state = conv.sequence(ap, ln1)
-        else:
-            a, state = conv.step(ap, ln1, cache)
-        return a, {**cache, **state}
-
-    def _block_step(block, bp, h, cache, pos):
-        """One block on Tq tokens (prefill: Tq=T0 at pos 0; decode:
-        Tq=1) against its cache; returns (h, cache).  A hybrid block's
-        mixer reads the same normed input as its attention: prefill
-        runs the chunked scan from an empty state and keeps the state
-        after the last prompt token, a decode step advances it."""
-        form, operator, experts = _block_kind(block)
-        if form == "sequential":
-            # ONE arm for every operator (per-head K/V, latent, short
-            # convolution), every FFN and both residuals: the block says
-            # what a sublayer reads of the state and how its result goes
-            # back (``h + y``, or a hyper-connection's maps over the
-            # streams ``[B, Tq, n, D]``); a block that names its
-            # operator's device scope gets it
-            ln1, co = sublayer_input(block, bp, 0, h)
-            with getattr(block, "operator_scope", nullcontext)():
-                a, cache = _operator(block, operator, bp["1"], ln1, cache,
-                                     pos)
-            h = sublayer_result(block, 0, h, a, co)
-            ln2, co2 = sublayer_input(block, bp, 1, h)
-            if experts is None:
-                h = sublayer_result(block, 1, h, _ffn(block, bp, ln2), co2)
-            else:
-                B, Tq, D = ln2.shape
-                m, counts = experts.routed(bp["3"], ln2.reshape(B * Tq, D),
-                                           batch=B)
-                h = sublayer_result(block, 1, h, m.reshape(B, Tq, D), co2)
-                cache = {**cache,
-                         "moe_counts": cache["moe_counts"] + counts}
-            if co is not None:
-                # the counter of the call: how far from doubly stochastic
-                # the worst residual map of either sublayer was
-                cache = {**cache, "mhc_err": jnp.maximum(
-                    cache["mhc_err"], jnp.maximum(co.err, co2.err))}
-            return h, cache
-        ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False, None)
-        if form == "parallel":
-            # attention and the expert layer read the SAME normed input
-            with jax.named_scope("block.attention"):
-                a, cache = _attention(block, bp["1"], ln1, cache, pos)
-            B, Tq, D = ln1.shape
-            m, counts = block.moe.routed(bp["2"], ln1.reshape(B * Tq, D),
-                                         batch=B)
-            return (h + a + m.reshape(B, Tq, D),
-                    {**cache, "moe_counts": cache["moe_counts"] + counts})
-        with jax.named_scope("mixer.attention"):
-            a, cache = _attention(
-                block, bp["1"],
-                _scaled(ln1, block.attention_in_multiplier), cache, pos)
-        mixer = block.mixer
-        if isinstance(pos, int) and pos == 0:
-            m, state = mixer.sequence(bp["6"], ln1)
-        else:
-            m, state = mixer.step(bp["6"], ln1, cache)
-        return (_ffn_sublayer(block, bp, block.mix(h, a, m)),
-                {**cache, **state})
-
-    def _embed_at(pc, tok, pos, Tq):
+    def embed_at(pc, tok, pos, Tq):
         h, _ = embed.apply_fn(pc["0"], {}, tok, False, None)
-        h = _scaled(h, getattr(model, "embedding_multiplier", 1.0))
-        if use_rope:  # positions live in the per-layer q/k rotation
-            return h
-        return h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq)
+        h = _scaled(h, model.embedding_multiplier)
+        if model.use_rope:  # positions live in the per-layer q/k rotation
+            return spread(h)
+        return spread(h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq))
 
-    def _state_of(h):
-        """The state the first layer takes: the embedding, or where the
-        blocks carry streams every stream the embedding."""
-        return blocks[0].hyper[0].replicate(h) if n_streams else h
+    def logits_last(pc, h):
+        """Head on the LAST position of h only -> [B, V] f32."""
+        h = gather(h[:, -1:])
+        h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
+        # a tied head owns no leaf: it is handed the embedding's
+        h, _ = head.apply_fn(
+            pc["0" if model.tied_head else str(first + count + 1)], {}, h,
+            False, None)
+        h = _scaled(h, model.lm_head_multiplier)
+        h = _scaled(h, model.logit_scale)
+        return h[:, 0, :].astype(jnp.float32)
+
+    return embed_at, logits_last
+
+
+def _decode_machinery(model, first, count, kv_int8=False):
+    """The cached forward shared by the sampling decoder and beam search
+    — built once per generator from the model structure.  Every
+    function takes the (already cast) param tree ``pc`` explicitly;
+    ``prefill`` is told how long a cache to allocate, and everything
+    after it takes that length from the cache's shape.  ``kv_int8``
+    asks every layer to hold its state as int8 (an approximation knob,
+    off by default): whoever cannot says so here, at the build."""
+    blocks = model.modules[first:first + count]
+    if kv_int8:
+        jax.eval_shape(lambda: [b.state_init(1, jnp.float32, 128, True)
+                                for b in blocks])
+    embed_at, logits_last = _ends(model, first, count)
 
     def prefill(pc, prompt, dt, T_cache):
         """The whole prompt in one causal pass; returns (h [B,T0,D] —
-        [B,T0,n,D] where the blocks carry ``n`` streams — and caches) of
+        or whatever state the blocks hand each other — and caches) of
         ``T_cache`` positions with [0, T0) filled."""
         B, T0 = prompt.shape
-        h = _state_of(_embed_at(pc, prompt, 0, T0))
+        h = embed_at(pc, prompt, 0, T0)
         caches = []
         for bi, block in enumerate(blocks):
-            cache = _cache_init(block, B, T_cache, dt, kv_int8)
-            h, cache = _block_step(block, pc[str(first + bi)], h,
-                                   cache, 0)
+            cache = block.state_init(B, dt, T_cache, kv_int8)
+            h, cache = block.advance(pc[str(first + bi)], h, cache, 0)
             caches.append(cache)
         return h, caches
 
     def decode_token(pc, tok, caches, pos):
         """One token [B, 1] at absolute position ``pos``; returns
         (h [B,1,D], new_caches)."""
-        h = _state_of(_embed_at(pc, tok, pos, 1))
+        h = embed_at(pc, tok, pos, 1)
         new_caches = []
         for bi, block in enumerate(blocks):
-            h, cache = _block_step(block, pc[str(first + bi)], h,
-                                   caches[bi], pos)
+            h, cache = block.advance(pc[str(first + bi)], h, caches[bi],
+                                     pos)
             new_caches.append(cache)
         return h, new_caches
-
-    def logits_last(pc, h):
-        """Head on the LAST position of h only -> [B, V] f32; the
-        streams of a hyper-connected state are summed first."""
-        h = h[:, -1:]
-        if n_streams:
-            h = blocks[0].hyper[0].reduce(h)
-        h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
-        # a tied head owns no leaf: it is handed the embedding's
-        h, _ = head.apply_fn(pc["0" if tied else str(first + count + 1)],
-                             {}, h, False, None)
-        h = _scaled(h, getattr(model, "lm_head_multiplier", 1.0))
-        h = _scaled(h, getattr(model, "logit_scale", 1.0))
-        return h[:, 0, :].astype(jnp.float32)
 
     return prefill, decode_token, logits_last
 
@@ -982,13 +332,14 @@ def make_generate(model, max_len: Optional[int] = None,
     the host from the call's own numbers (a greedy call ignores
     ``top_k`` / ``top_p``: one program); the decode loop itself is a
     scan — no per-token dispatch.  ``return_stats=True`` returns
-    ``(ids, stats)``: for a model with dropless expert layers ``stats``
-    holds ``moe_counts`` ``[expert layers, held]`` int32, the
-    assignments each held expert took in the call (fetched with the
-    tokens; a dense layer among them has no row); for a model whose
-    residual is a hyper-connection ``mhc_sinkhorn_err``, the largest
-    ``|rowsum - 1|`` or ``|colsum - 1|`` of a residual map the call
-    computed (a float32 scalar); for any other model it is empty.
+    ``(ids, stats)``, the counters the blocks keep in their state
+    (``block.counters``): for a model with dropless expert layers
+    ``moe_counts`` ``[expert layers, held]`` int32, the assignments each
+    held expert took in the call (fetched with the tokens; a dense layer
+    among them has no row); for a model whose residual is a
+    hyper-connection ``mhc_sinkhorn_err``, the largest ``|rowsum - 1|``
+    or ``|colsum - 1|`` of a residual map the call computed (a float32
+    scalar); for any other model it is empty.
 
     ``generate.compile_ahead(params, batch, prompt_len, max_new,
     executor)`` starts the compile of the GREEDY program of that shape
@@ -1003,8 +354,8 @@ def make_generate(model, max_len: Optional[int] = None,
     T_max = _check_len(model, max_len)
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
-    counted = any(_block_kind(b)[2] is not None or getattr(b, "streams", 0)
-                  for b in model.modules[first:first + count])
+    blocks = model.modules[first:first + count]
+    counted = any(getattr(b, "counters", None) for b in blocks)
 
     # device scopes (``jax.named_scope``): metadata on the HLO
     # operations only — ``generate.cast_params`` / ``.prefill`` /
@@ -1090,17 +441,20 @@ def make_generate(model, max_len: Optional[int] = None,
                 length=max_new - 1)
         if not counted:
             return ids
-        stats = {}
-        counts = [jnp.sum(c["moe_counts"], axis=0)
-                  for c in caches if "moe_counts" in c]
-        if counts:
-            # [layers, held]: the assignments each held expert took in
-            # this call, prefill and every decode step, all rows
-            stats["moe_counts"] = jnp.stack(counts)
-        errs = [c["mhc_err"] for c in caches if "mhc_err" in c]
-        if errs:
-            stats["mhc_sinkhorn_err"] = jnp.max(jnp.stack(errs))
-        return ids, stats
+        # what the layers counted in this call, prefill and every decode
+        # step: a counter with a batch axis is summed over the rows and
+        # stacked [layers, ...]; a scalar a layer becomes the call's
+        # largest
+        found = {}
+        for block, cache in zip(blocks, caches):
+            for leaf, name in getattr(block, "counters", {}).items():
+                c = cache[leaf]
+                found.setdefault(name, []).append(
+                    jnp.sum(c, axis=0) if c.ndim else c)
+        return ids, {
+            name: jnp.stack(cs) if cs[0].ndim else jnp.max(jnp.stack(cs))
+            for name, cs in sorted(found.items(),
+                                   key=lambda kv: -kv[1][0].ndim)}
 
     # greedy programs compiled ahead of their first call:
     # (batch, prompt_len, max_new) -> Future of the executable
@@ -1200,11 +554,17 @@ def make_beam_search(model, max_len: Optional[int] = None,
     depths, so ``num_beams=1`` reduces to greedy and with enough beams
     to hold every prefix it IS exhaustive search (the oracle test pins
     that, with and without eos).  Shares :func:`_decode_machinery` with
-    the sampling decoder."""
+    the sampling decoder; it follows a beam by gathering every leaf of
+    the caches along the beam axis, so a block whose state has a leaf
+    without a batch axis is refused."""
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
-    _refuse_streams(model.modules[first:first + count], "beam search",
-                    "gathers every cache leaf along the beam axis")
+    for block in model.modules[first:first + count]:
+        one, two = (jax.eval_shape(lambda b=b: block.state_init(
+            b, jnp.float32, 128)) for b in (1, 2))
+        if any(one[leaf].shape == two[leaf].shape for leaf in one):
+            raise _refusal("beam search gathers every cache leaf along the "
+                           "beam axis", block)
     prefill, decode_token, logits_last = _decode_machinery(
         model, first, count, kv_int8=_kv_int8(kv_dtype))
 
@@ -1297,14 +657,17 @@ def make_beam_search(model, max_len: Optional[int] = None,
 
 def _paged_machinery(model, first, count, page_size, page_window=None,
                      page_globals: int = 1):
-    """The paged twin of :func:`_decode_machinery`: K/V live in a
-    shared ``[num_pages, layers, Hkv, page_size, Dh]`` arena and each
-    request addresses its positions through a page table ``pt`` (page
-    ids, bucket-padded).  Attention gathers the request's pages into a
-    dense view and runs the SAME :func:`_gqa_attend` the unpaged path
-    runs — masked positions contribute exactly zero, so the paged
-    token stream is the unpaged stream (pinned in
-    tests/test_kvpool.py).
+    """The paged twin of :func:`_decode_machinery`: the SAME embedding,
+    head and blocks on another store.  K/V live in a shared
+    ``[num_pages, layers, Hkv, page_size, Dh]`` arena and each request
+    addresses its positions through a page table ``pt`` (page ids,
+    bucket-padded): a block makes its heads and runs its FFN as in the
+    static path (``block.attention_sublayer`` / ``ffn_sublayer``) and is
+    handed the attention itself — the arena write, the gather of the
+    request's pages into a dense view and the SAME
+    ``gqa_attend_reference`` the unpaged path runs — masked positions
+    contribute exactly zero, so the paged token stream is the unpaged
+    stream (pinned in tests/test_kvpool.py).
 
     ``page_window`` turns on the page-granular block mask (the BLaST
     sparsity story on the serving path): each decode step gathers and
@@ -1320,50 +683,14 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
     ``pt`` are traced values, so page-table REUSE never recompiles —
     one decode program per page-count bucket, ever.
     """
+    from ..ops.gqa_attend import gqa_attend_reference
+
     blocks = model.modules[first:first + count]
-    ln_f = model.modules[first + count]
-    head = model.modules[first + count + 1]
-    embed = model.modules[0]
-    mha0 = blocks[0].modules[1]
-    H, Dh = mha0.num_heads, mha0.head_dim
-    Hkv = getattr(mha0, "num_kv_heads", H)
-    use_rope = getattr(model, "use_rope", False)
-    rope_theta = getattr(mha0, "rope_theta", 10000.0)
+    embed_at, logits_last = _ends(model, first, count)
 
-    def _split(x, B, h=H):
-        return x.reshape(B, -1, h, Dh).transpose(0, 2, 1, 3)
-
-    def _rep(kv):
-        if Hkv == H:
-            return kv
-        return jnp.repeat(kv, H // Hkv, axis=1)
-
-    def _embed_at(pc, tok, pos, Tq):
-        h, _ = embed.apply_fn(pc["0"], {}, tok, False, None)
-        if use_rope:
-            return h
-        return h + lax.dynamic_slice_in_dim(pc["pos"], pos, Tq)
-
-    def _qkv(block, ap, ln1, pos_ids):
-        mha = block.modules[1]
-        B = ln1.shape[0]
-        q = _split(_proj(ln1, ap, "wq", "bq", mha.with_bias), B)
-        k = _split(_proj(ln1, ap, "wk", "bk", mha.with_bias), B, Hkv)
-        v = _split(_proj(ln1, ap, "wv", "bv", mha.with_bias), B, Hkv)
-        q, k = mha.normed_heads(ap, q, k)
-        if use_rope:
-            from ..nn.attention import rope_rotate
-
-            q = rope_rotate(q, pos_ids, rope_theta)
-            k = rope_rotate(k, pos_ids, rope_theta)
-        return q, k, v
-
-    def logits_last(pc, h):
-        h = h[:, -1:, :]
-        h, _ = ln_f.apply_fn(pc[str(first + count)], {}, h, False, None)
-        h, _ = head.apply_fn(pc[str(first + count + 1)], {}, h, False,
-                             None)
-        return h[:, 0, :].astype(jnp.float32)
+    def _rep(kv, H):
+        Hkv = kv.shape[1]
+        return kv if Hkv == H else jnp.repeat(kv, H // Hkv, axis=1)
 
     def _prefill_attend(q, k, v, T0):
         """Prompt self-attention: full causal flash, or the page-window
@@ -1372,56 +699,60 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
         holds)."""
         from ..ops.flash_attention import flash_attention
 
-        n_pages = -(-T0 // page_size)
+        n_pages, H = -(-T0 // page_size), q.shape[1]
         if page_window is None or n_pages <= page_window + page_globals \
                 or T0 % page_size:
             # non-page-multiple prompts keep the dense causal pass: the
             # ragged tail page cannot be expressed at block granularity
-            return flash_attention(q, _rep(k), _rep(v), causal=True)
+            return flash_attention(q, _rep(k, H), _rep(v, H), causal=True)
         from ..ops.block_sparse import (block_sparse_attention,
                                         sliding_window_mask)
 
         mask = sliding_window_mask(n_pages, n_pages, page_window,
                                    n_global=page_globals, causal=True,
                                    block_q=page_size, block_k=page_size)
-        return block_sparse_attention(q, _rep(k), _rep(v), mask,
+        return block_sparse_attention(q, _rep(k, H), _rep(v, H), mask,
                                       causal=True)
+
+    def _through(pc, h, pos, attend_of):
+        """``h`` through every block, layer ``bi``'s attention being
+        ``attend_of(bi)``."""
+        for bi, block in enumerate(blocks):
+            bp = pc[str(first + bi)]
+            h = block.ffn_sublayer(
+                bp, block.attention_sublayer(bp, h, pos, attend_of(bi)))
+        return logits_last(pc, h)
 
     def prefill(pc, prompt, pt, arena_k, arena_v):
         """The whole prompt in one causal pass (the flash path the
         dense machinery uses — first-token numerics identical), K/V
         scattered into the request's pages.  ``prompt`` is [1, T0]."""
-        B, T0 = prompt.shape
+        T0 = prompt.shape[1]
         n_pages = -(-T0 // page_size)          # static: T0 is static
-        h = _embed_at(pc, prompt, 0, T0)
-        for bi, block in enumerate(blocks):
-            bp = pc[str(first + bi)]
-            ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False,
-                                               None)
-            q, k, v = _qkv(block, bp["1"], ln1, jnp.arange(T0))
+        arena = [arena_k, arena_v]
 
-            def paged_view(x):  # [1, Hkv, T0, Dh] -> [n, Hkv, ps, Dh]
-                xp = jnp.pad(
-                    x[0], ((0, 0), (0, n_pages * page_size - T0),
-                           (0, 0)))
-                return xp.reshape(Hkv, n_pages, page_size,
-                                  Dh).transpose(1, 0, 2, 3)
+        def paged_view(x):  # [1, Hkv, T0, Dh] -> [n, Hkv, ps, Dh]
+            Hkv, Dh = x.shape[1], x.shape[3]
+            xp = jnp.pad(x[0], ((0, 0), (0, n_pages * page_size - T0),
+                                (0, 0)))
+            return xp.reshape(Hkv, n_pages, page_size,
+                              Dh).transpose(1, 0, 2, 3)
 
-            arena_k = arena_k.at[pt[:n_pages], bi].set(
-                paged_view(k).astype(arena_k.dtype))
-            arena_v = arena_v.at[pt[:n_pages], bi].set(
-                paged_view(v).astype(arena_v.dtype))
-            o = _prefill_attend(q, k, v, T0)
-            o = o.transpose(0, 2, 1, 3).reshape(B, T0, H * Dh)
-            h = h + _proj(o, bp["1"], "wo", "bo",
-                          block.modules[1].with_bias)
-            h = _ffn_sublayer(block, bp, h)
-        return logits_last(pc, h), arena_k, arena_v
+        def attend_of(bi):
+            def attend(q, k, v):
+                for i, x in enumerate((k, v)):
+                    arena[i] = arena[i].at[pt[:n_pages], bi].set(
+                        paged_view(x).astype(arena[i].dtype))
+                return _prefill_attend(q, k, v, T0)
+            return attend
+
+        logits = _through(pc, embed_at(pc, prompt, 0, T0), None, attend_of)
+        return (logits, *arena)
 
     def _page_view(arena, pages, bi, dt):
         """Gather ``pages`` (page-id vector) of layer ``bi`` into a
         dense [1, Hkv, len*page_size, Dh] cache view."""
-        n = pages.shape[0]
+        n, Hkv, Dh = pages.shape[0], arena.shape[2], arena.shape[4]
         return arena[pages, bi].transpose(1, 0, 2, 3).reshape(
             Hkv, n * page_size, Dh)[None].astype(dt)
 
@@ -1435,19 +766,24 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
         P = pt.shape[0]
         windowed = page_window is not None \
             and P > page_window + page_globals
-        h = _embed_at(pc, tok, pos, 1)
-        for bi, block in enumerate(blocks):
-            bp = pc[str(first + bi)]
-            ln1, _ = block.modules[0].apply_fn(bp["0"], {}, h, False,
-                                               None)
-            q, k, v = _qkv(block, bp["1"], ln1, pos + jnp.arange(1))
-            page = pt[pos // page_size]
-            slot = pos % page_size
-            arena_k = arena_k.at[page, bi, :, slot, :].set(
-                k[0, :, 0, :].astype(arena_k.dtype))
-            arena_v = arena_v.at[page, bi, :, slot, :].set(
-                v[0, :, 0, :].astype(arena_v.dtype))
-            if windowed:
+        arena = [arena_k, arena_v]
+
+        def attend_of(bi):
+            def attend(q, k, v):
+                H, Hkv, Dh = q.shape[1], k.shape[1], q.shape[3]
+                page = pt[pos // page_size]
+                slot = pos % page_size
+                for i, x in enumerate((k, v)):
+                    arena[i] = arena[i].at[page, bi, :, slot, :].set(
+                        x[0, :, 0, :].astype(arena[i].dtype))
+                if not windowed:
+                    # gather THIS request's pages into a dense
+                    # [1, Hkv, T, Dh] view (T = bucket * page_size);
+                    # positions past ``pos`` (padding pages, other
+                    # requests' bytes) are causally masked to exactly
+                    # zero weight inside the attend
+                    kc, vc = (_page_view(a, pt, bi, q.dtype) for a in arena)
+                    return gqa_attend_reference(q, kc, vc, pos, H, Hkv, Dh)
                 # sparse page mask: gather the G anchor pages + the W
                 # pages ending at the current one.  ``start`` clamps to
                 # G so anchors never duplicate; not-yet-written window
@@ -1461,24 +797,13 @@ def _paged_machinery(model, first, count, page_size, page_window=None,
                     [jnp.arange(G), start + jnp.arange(W)])
                 k_pos = (page_ids[:, None] * page_size
                          + jnp.arange(page_size)[None, :]).reshape(-1)
-                kc = _page_view(arena_k, live, bi, q.dtype)
-                vc = _page_view(arena_v, live, bi, q.dtype)
-                o = _gqa_attend(q, kc, vc, pos, H, Hkv, Dh,
-                                k_pos=k_pos)
-            else:
-                # gather THIS request's pages into a dense
-                # [1, Hkv, T, Dh] view (T = bucket * page_size);
-                # positions past ``pos`` (padding pages, other
-                # requests' bytes) are causally masked to exactly zero
-                # weight inside _gqa_attend
-                kc = _page_view(arena_k, pt, bi, q.dtype)
-                vc = _page_view(arena_v, pt, bi, q.dtype)
-                o = _gqa_attend(q, kc, vc, pos, H, Hkv, Dh)
-            o = o.transpose(0, 2, 1, 3).reshape(1, 1, H * Dh)
-            h = h + _proj(o, bp["1"], "wo", "bo",
-                          block.modules[1].with_bias)
-            h = _ffn_sublayer(block, bp, h)
-        return logits_last(pc, h), arena_k, arena_v
+                kc, vc = (_page_view(a, live, bi, q.dtype) for a in arena)
+                return gqa_attend_reference(q, kc, vc, pos, H, Hkv, Dh,
+                                            k_pos=k_pos)
+            return attend
+
+        logits = _through(pc, embed_at(pc, tok, pos, 1), pos, attend_of)
+        return (logits, *arena)
 
     return prefill, decode
 
@@ -1564,16 +889,26 @@ class PagedDecoder:
             raise ValueError(f"page_window must be >= 1 pages, got "
                              f"{page_window}")
         first, count = _check_model(model)
-        _refuse_recurrent(model, first, count, "PagedDecoder (KVPagePool)")
-        mha0 = model.modules[first].modules[1]
-        Hkv = getattr(mha0, "num_kv_heads", mha0.num_heads)
+        for block in model.modules[first:first + count]:
+            # pages hold K and V of every position and nothing else: a
+            # block is served if that is ALL its state and it runs its
+            # attention on a store handed in
+            kv = jax.eval_shape(lambda: block.state_init(
+                1, jnp.float32, model.max_len))
+            if (set(kv) != {"k", "v"} or kv["k"].shape[2] != model.max_len
+                    or not hasattr(block, "attention_sublayer")):
+                raise _refusal(
+                    "PagedDecoder (KVPagePool) keeps K and V in pages of "
+                    "ONE length for every layer, under the plain residual,",
+                    block)
+        _, Hkv, _, Dh = kv["k"].shape
         if (pool.layers, pool.num_kv_heads, pool.head_dim) != \
-                (count, Hkv, mha0.head_dim):
+                (count, Hkv, Dh):
             raise ValueError(
                 f"pool geometry (layers={pool.layers}, "
                 f"Hkv={pool.num_kv_heads}, Dh={pool.head_dim}) does "
                 f"not match the model (layers={count}, Hkv={Hkv}, "
-                f"Dh={mha0.head_dim})")
+                f"Dh={Dh})")
         self.model = model
         self.pool = pool
         #: decode window cap: the positional table AND the arena both
@@ -1716,6 +1051,8 @@ def capacity_bind_report(model, params, ids):
 
     Returns ``{block_index: fraction}`` over the model's MoE blocks plus
     ``"overall"`` (their mean); ``{}`` for a dense model."""
+    from ..parallel.moe import BIND_TLS
+
     first, count = _check_model(model)
     blocks = model.modules[first:first + count]
     moe_idx = [first + bi for bi, b in enumerate(blocks) if b.is_moe]
@@ -1733,13 +1070,13 @@ def capacity_bind_report(model, params, ids):
 
         @jax.jit
         def _replay(p, toks):
-            _BIND_TLS.capture = []
+            BIND_TLS.capture = []
             try:
                 dt = jax.tree_util.tree_leaves(p)[0].dtype
                 prefill(p, toks, dt, T)
-                fracs = list(_BIND_TLS.capture)
+                fracs = list(BIND_TLS.capture)
             finally:
-                _BIND_TLS.capture = None
+                BIND_TLS.capture = None
             return jnp.stack(fracs)
 
         slot[T] = _replay

@@ -9,7 +9,8 @@ untied head — so the generation builder, the server and the optimizers
 take it as they take the dense model: ``apply_fn`` is plain
 differentiable jax (no hand-written backward), ``generate`` decodes
 through a K/V cache AND a recurrent state per layer
-(``models/generate.py``).  Rotary positions only: no position table.
+(``nn.HybridMambaBlock.advance``).  Rotary positions only: no position
+table.
 
 ``param_dtype`` is the dtype the model HOLDS its floating parameters in
 (a served bfloat16 model costs 2 bytes a parameter): the constructor
@@ -27,9 +28,10 @@ from .. import nn
 from ..nn.initialization import device_draw
 from ..nn.mamba import scaled
 from ..nn.module import Container, hold_floats
+from .generate import CausalLM
 
 
-class HybridMambaLM(Container):
+class HybridMambaLM(CausalLM, Container):
     """Decoder-only causal LM over 1-based token ids [batch, seq]."""
 
     def __init__(self, vocab_size: int, embed_dim: int, num_heads: int,
@@ -99,20 +101,6 @@ class HybridMambaLM(Container):
             super().reset()
         self.set_param_tree(self.param_tree())
         return self
-
-    def generate(self, prompt_ids, max_new: int, rng=None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, compute_dtype=None,
-                 eos_id=None, pad_id=None):
-        """Autoregressive decode (``TransformerLM.generate``'s
-        contract): prefill runs the chunked scan and hands each layer's
-        final state, beside its K/V, to the decode scan."""
-        from .generate import cached_generate
-
-        return cached_generate(self, compute_dtype)(
-            self.param_tree(), prompt_ids, max_new, rng=rng,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            eos_id=eos_id, pad_id=pad_id)
 
     def apply_fn(self, params, buffers, x, training, rng):
         h, _ = self.modules[0].apply_fn(params["0"], buffers["0"], x,

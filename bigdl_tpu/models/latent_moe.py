@@ -53,11 +53,18 @@ operator, ``2`` RMSNorm, ``3`` the FFN and, where the residual is a
 hyper-connection, ``4`` the operator's and ``5`` the FFN's), ``L+1``
 the final RMSNorm, ``L+2`` the head — so the generation builder, the
 server and the optimizers take it as they take the dense model.  What ``generate``
-keeps a layer depends on its operator (``models/generate.py``): the
+keeps a layer is what its operator keeps (``state_init`` / ``sequence``
+/ ``step``: the decode-state protocol of ``nn/attention.py``): the
 latent ``c_kv`` and the rotated shared key of every position and nothing
 by head; per-head K and V; or, for a short convolution, the last
 ``kernel - 1`` values of its gated input and NOTHING that grows with the
-context.  ``param_dtype`` and the device draw as in ``HybridMambaLM``;
+context — and ANY operator that answers the three calls decodes, with
+no edit to the generation builder.  A layer with experts adds
+``moe_counts`` ``[B, held]``, a hyper-connected one ``mhc_err``, the
+call's largest distance of a residual map from doubly stochastic
+(``mhc_sinkhorn_err`` of ``return_stats=True``); its state between
+layers is ``[B, Tq, n, D]``, and beam search and the paged decoder
+refuse it.  ``param_dtype`` and the device draw as in ``HybridMambaLM``;
 the selection bias stays float32.
 """
 from __future__ import annotations
@@ -69,12 +76,14 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..nn.attention import advance, footprint, fresh_state
 from ..nn.hyper_connection import HyperConnection
 from ..nn.initialization import (IN_OUT, RandomNormal, device_draw,
                                  no_draw)
 from ..nn.module import (FLOAT32_LEAVES, Container, TensorModule,
                          hold_floats)
 from ..parallel.moe import DroplessMoE
+from .generate import CausalLM
 from .parallel_moe import TiedHeadTrees
 
 
@@ -183,22 +192,21 @@ def sublayer_result(block, i: int, state, y, co):
 
 
 class SequentialMoEBlock(Container):
-    """``h = x + Op(norm_1 x); y = h + FFN(norm_2 h)``.  Children, in
-    the order the generation builder relies on: ``0`` RMSNorm, ``1`` the
-    operator (latent or grouped-query attention, or a gated short
-    convolution), ``2`` RMSNorm, ``3`` the FFN (``ffn_kind``:
-    ``"dense"`` a :class:`GatedFFN`, ``"moe"`` a ``DroplessMoE``).
+    """``h = x + Op(norm_1 x); y = h + FFN(norm_2 h)``.  Children:
+    ``0`` RMSNorm, ``1`` the operator (latent or grouped-query
+    attention, a gated short convolution, or whatever answers
+    ``state_init`` / ``sequence`` / ``step``), ``2`` RMSNorm, ``3`` the
+    FFN (``ffn_kind``: ``"dense"`` a :class:`GatedFFN`, ``"moe"`` a
+    ``DroplessMoE``).
 
     ``hyper`` (a zero-argument factory of a ``HyperConnection``) makes the
     residual a hyper-connection: children ``4`` (the operator's) and
     ``5`` (the FFN's) follow, ``x`` and ``y`` are ``[B, T, n, embed]``,
     and each sublayer reads and writes the streams through its module's
     maps (:func:`sublayer_input` / :func:`sublayer_result`, which
-    ``apply_fn`` and the generation builder both call, for the plain
-    residual too).  Without it the block is what it was, program and
-    parameter tree."""
-
-    kind = "sequential_moe"
+    ``apply_fn`` and ``advance`` both call, for the plain residual
+    too).  Without it the block is what it was, program and parameter
+    tree."""
 
     def __init__(self, operator, ffn, embed_dim: int, norm_eps: float,
                  param_dtype: Optional[str] = None,
@@ -212,8 +220,6 @@ class SequentialMoEBlock(Container):
         self.hyper = tuple(self.modules[4:6]) if hyper is not None else None
         self.ffn_kind = "moe" if isinstance(ffn, DroplessMoE) else "dense"
         self.is_moe = self.ffn_kind == "moe"
-        if not self.is_moe:
-            self.mlp_kind = "gated"     # generate._ffn_sublayer's arm
 
     @property
     def moe(self) -> DroplessMoE:
@@ -243,12 +249,77 @@ class SequentialMoEBlock(Container):
             x = sublayer_result(self, i, x, y, co)
         return x, buffers
 
+    # -- decode: the state between tokens, and Tq tokens against it ------
+    @property
+    def counters(self) -> dict:
+        """Leaf of the decode state -> the statistic a call returns."""
+        return {**({"moe_counts": "moe_counts"} if self.is_moe else {}),
+                **({"mhc_err": "mhc_sinkhorn_err"} if self.hyper else {})}
+
+    @property
+    def state_doc(self) -> str:
+        if self.hyper:
+            return (f"{type(self.hyper[0]).__name__} makes the residual of "
+                    f"{type(self).__name__} {self.streams} streams a token, "
+                    "with a counter a layer that has no batch axis")
+        op = self.modules[1]
+        return (f"{type(op).__name__} "
+                + getattr(op, "state_doc", "keeps a state of its own"))
+
+    def replicate(self, h):
+        """The state the first layer takes of the embedding ``h``."""
+        return self.hyper[0].replicate(h) if self.hyper else h
+
+    def reduce(self, h):
+        """What the final norm takes of the last layer's state."""
+        return self.hyper[0].reduce(h) if self.hyper else h
+
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        state = fresh_state(self.modules[1], batch, dtype, length, int8)
+        if self.is_moe:
+            state["moe_counts"] = jnp.zeros((batch, self.moe.held[1]),
+                                            jnp.int32)
+        if self.hyper:          # a counter too: one number a layer
+            state["mhc_err"] = jnp.zeros((), jnp.float32)
+        return state
+
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        return {**footprint(self.modules[1], batch, dtype, length, int8),
+                **(self.moe.decode_plan(batch, dtype) if self.is_moe
+                   else {})}
+
+    def advance(self, params, h, state, pos):
+        """ONE form for every operator, every FFN and both residuals:
+        what a sublayer reads of ``h`` and how its result goes back are
+        :func:`sublayer_input` / :func:`sublayer_result`'s."""
+        x, co = sublayer_input(self, params, 0, h)
+        with self.operator_scope():
+            a, wrote = advance(self.modules[1], params["1"], x, state, pos)
+        state = {**state, **wrote}
+        h = sublayer_result(self, 0, h, a, co)
+        x, co2 = sublayer_input(self, params, 1, h)
+        if self.is_moe:
+            B, Tq, D = x.shape
+            y, counts = self.moe.routed(params["3"], x.reshape(B * Tq, D),
+                                        batch=B)
+            h = sublayer_result(self, 1, h, y.reshape(B, Tq, D), co2)
+            state["moe_counts"] = state["moe_counts"] + counts
+        else:
+            y, _ = self.modules[3].apply_fn(params["3"], {}, x, False, None)
+            h = sublayer_result(self, 1, h, y, co2)
+        if co is not None:
+            # the counter of the call: how far from doubly stochastic
+            # the worst residual map of either sublayer was
+            state["mhc_err"] = jnp.maximum(
+                state["mhc_err"], jnp.maximum(co.err, co2.err))
+        return h, state
+
 
 #: the name the block had while latent attention was its only operator
 LatentMoEBlock = SequentialMoEBlock
 
 
-class SequentialMoELM(TiedHeadTrees, Container):
+class SequentialMoELM(TiedHeadTrees, CausalLM, Container):
     """Decoder-only causal LM over 1-based token ids [batch, seq].
 
     ``operators`` and ``ffns`` are one zero-argument FACTORY a layer
@@ -309,21 +380,6 @@ class SequentialMoELM(TiedHeadTrees, Container):
             super().reset()
         self.set_param_tree(self.param_tree())
         return self
-
-    def generate(self, prompt_ids, max_new: int, rng=None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, compute_dtype=None,
-                 eos_id=None, pad_id=None):
-        """Autoregressive decode (``TransformerLM.generate``'s
-        contract) through each layer's own cache: a latent layer's
-        prefill expands it to per-head K and V once and a decode step
-        never does; a short convolution carries its tail."""
-        from .generate import cached_generate
-
-        return cached_generate(self, compute_dtype)(
-            self.param_tree(), prompt_ids, max_new, rng=rng,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            eos_id=eos_id, pad_id=pad_id)
 
     def apply_fn(self, params, buffers, x, training, rng):
         h, last = x, len(self.modules) - 1

@@ -22,9 +22,12 @@ embedding, ``1..L`` the blocks (children ``0`` norm, ``1`` attention,
 that owns no leaf and READS the embedding's — so the generation
 builder, the server and the optimizers take it as they take the dense
 model.  ``generate`` keeps a K/V cache per layer whose length depends on
-the layer's kind (``models/generate.py``): ``min(T_cache, window)``
-positions, written round-robin, for a sliding layer.  ``param_dtype``
-and the device draw as in ``HybridMambaLM``.
+the layer's kind (``nn.MultiHeadAttention.state_init``): ``min(T_cache,
+window)`` positions, written round-robin, for a sliding layer; beside
+it ``moe_counts`` ``[B, held]``, the assignments each held expert took
+from each row, which a generate call returns beside the tokens on
+request (``return_stats=True``).  ``param_dtype`` and the device draw
+as in ``HybridMambaLM``.
 """
 from __future__ import annotations
 
@@ -34,9 +37,11 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..nn.attention import advance
 from ..nn.initialization import RandomNormal, device_draw
 from ..nn.module import Container, TensorModule, hold_floats
 from ..parallel.moe import DroplessMoE
+from .generate import CausalLM
 
 LAYER_KINDS = ("sliding", "full")
 
@@ -106,12 +111,15 @@ class TiedHeadTrees:
 
 
 class ParallelMoEBlock(Container):
-    """``x + Attn(LN(x)) + MoE(LN(x))``.  Children, in the order the
-    generation builder relies on: ``0`` the LayerNorm (no bias), ``1``
-    attention, ``2`` the expert layer."""
+    """``x + Attn(LN(x)) + MoE(LN(x))``.  Children: ``0`` the LayerNorm
+    (no bias), ``1`` attention, ``2`` the expert layer."""
 
-    kind = "parallel_moe"
     is_moe = True
+    #: leaf of the decode state -> the statistic a call returns of it
+    counters = {"moe_counts": "moe_counts"}
+    state_doc = ("its layers differ in what they see (a window beside full "
+                 "attention) and run attention and experts on one norm, "
+                 "whose counts it keeps beside the K/V")
 
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, expert_dim: int, n_experts: int, top_k: int,
@@ -159,8 +167,35 @@ class ParallelMoEBlock(Container):
             a = run(1, n)
         return x + a + run(2, n), buffers
 
+    # -- decode: the state between tokens, and Tq tokens against it ------
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        return {**self.modules[1].state_init(batch, dtype, length, int8),
+                "moe_counts": jnp.zeros((batch, self.moe.held[1]),
+                                        jnp.int32)}
 
-class ParallelMoELM(TiedHeadTrees, Container):
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        """The attention's, its K/V also by KIND of layer (a sliding
+        layer keeps ``min(positions, window)``), and the experts'."""
+        kv = self.modules[1].footprint(batch, dtype, length, int8)
+        sliding = self.attention == "sliding"
+        return {**kv,
+                "kv_cache_bytes_window": kv["kv_cache_bytes"] * sliding,
+                "kv_cache_bytes_full": kv["kv_cache_bytes"] * (not sliding),
+                **self.moe.decode_plan(batch, dtype)}
+
+    def advance(self, params, h, state, pos):
+        """Attention and the expert layer read the SAME normed input."""
+        n, _ = self.modules[0].apply_fn(params["0"], {}, h, False, None)
+        with jax.named_scope("block.attention"):
+            a, state = advance(self.modules[1], params["1"], n, state, pos)
+        B, Tq, D = n.shape
+        m, counts = self.moe.routed(params["2"], n.reshape(B * Tq, D),
+                                    batch=B)
+        return (h + a + m.reshape(B, Tq, D),
+                {**state, "moe_counts": state["moe_counts"] + counts})
+
+
+class ParallelMoELM(TiedHeadTrees, CausalLM, Container):
     """Decoder-only causal LM over 1-based token ids [batch, seq]."""
 
     def __init__(self, vocab_size: int, embed_dim: int, num_heads: int,
@@ -216,19 +251,6 @@ class ParallelMoELM(TiedHeadTrees, Container):
             super().reset()
         self.set_param_tree(self.param_tree())
         return self
-
-    def generate(self, prompt_ids, max_new: int, rng=None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, compute_dtype=None,
-                 eos_id=None, pad_id=None):
-        """Autoregressive decode (``TransformerLM.generate``'s
-        contract) through caches of each layer's own length."""
-        from .generate import cached_generate
-
-        return cached_generate(self, compute_dtype)(
-            self.param_tree(), prompt_ids, max_new, rng=rng,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            eos_id=eos_id, pad_id=pad_id)
 
     def apply_fn(self, params, buffers, x, training, rng):
         n = len(self.modules)

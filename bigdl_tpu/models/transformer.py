@@ -22,9 +22,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import nn
+from ..nn.attention import advance
 from ..nn.module import Container
 from ..parallel.tensor_parallel import ColumnParallelLinear, RowParallelLinear
 from ..utils.rng import next_jax_key
+from .generate import CausalLM
 
 
 def _norm_factory(norm: str, norm_eps):
@@ -43,7 +45,14 @@ class TransformerBlock(Container):
     ``moe_experts > 0`` swaps the dense MLP for a Switch-style
     mixture-of-experts FFN (parallel/moe.py) — expert-parallel over
     ``moe_axis`` when set (the token-sharding mesh axis), dense
-    otherwise.  Dropped-over-capacity tokens ride the residual."""
+    otherwise.  Dropped-over-capacity tokens ride the residual.
+
+    A decoder keeps the attention's K/V and nothing else (``state_init``
+    / ``advance``: the decode-state protocol of ``nn/attention.py``),
+    LN/MLP sublayers run through their module ``apply_fn``, and an MoE
+    FFN decodes capacity-FREE (``MoEFFN.nodrop``: at inference nothing
+    should be dropped — training-time capacity drops are a static-shape
+    batching artifact, not part of the learned function)."""
 
     def __init__(self, embed_dim: int, num_heads: int, mlp_dim: int,
                  causal: bool = True, seq_strategy: str = "dense",
@@ -143,6 +152,44 @@ class TransformerBlock(Container):
         mask = jax.random.bernoulli(key, keep, v.shape)
         return jnp.where(mask, v / keep, 0).astype(v.dtype)
 
+    # -- decode: the state between tokens, and Tq tokens against it ------
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        return self.modules[1].state_init(batch, dtype, length, int8)
+
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        return self.modules[1].footprint(batch, dtype, length, int8)
+
+    def _run(self, params, i, v):
+        return self.modules[i].apply_fn(params[str(i)], {}, v, False,
+                                        None)[0]
+
+    def ffn_sublayer(self, params, h):
+        """``h + MLP(norm_2 h)``: gelu, swiglu, or the capacity-free
+        mixture of experts."""
+        x = self._run(params, 2, h)
+        if self.is_moe:
+            return h + self.modules[3].nodrop(params["3"], x)
+        if getattr(self, "mlp_kind", None) == "swiglu":
+            g, u = self._run(params, 3, x), self._run(params, 4, x)
+            return h + self._run(params, 5, jax.nn.silu(g) * u)
+        return h + self._run(params, 4,
+                             jax.nn.gelu(self._run(params, 3, x)))
+
+    def attention_sublayer(self, params, h, pos, attend):
+        """``h + Attn(norm_1 h)`` with the attention ITSELF handed in —
+        ``attend(q, k, v) -> o [B, H, Tq, Dh]`` on the heads this layer
+        makes at ``pos`` (None: from 0), wherever it keeps them: what a
+        decoder with a store of its own (the paged one) runs."""
+        mha, x = self.modules[1], self._run(params, 0, h)
+        at = jnp.arange(x.shape[1])
+        q, k, v = mha.heads(params["1"], x, at if pos is None else pos + at)
+        return h + mha.merged(params["1"], attend(q, k, v))
+
+    def advance(self, params, h, state, pos):
+        a, state = advance(self.modules[1], params["1"],
+                           self._run(params, 0, h), state, pos)
+        return self.ffn_sublayer(params, h + a), state
+
     def apply_fn(self, params, buffers, x, training, rng):
         def sub(i):
             return jax.random.fold_in(rng, i) if rng is not None else None
@@ -181,7 +228,7 @@ class TransformerBlock(Container):
             return x + self._drop(h, sub(11), training), nb
 
 
-class TransformerLM(Container):
+class TransformerLM(CausalLM, Container):
     """Decoder-only causal LM over 1-based token ids [batch, seq].
 
     Output is log-probs [batch, seq, vocab] — feed
@@ -292,27 +339,6 @@ class TransformerLM(Container):
         if not getattr(self, 'use_rope', False):
             tree["pos"] = self.scale_w
         return tree
-
-    def generate(self, prompt_ids, max_new: int, rng=None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, compute_dtype=None,
-                 eos_id=None, pad_id=None):
-        """Autoregressive decode with a KV cache (models/generate.py):
-        prefill + ``lax.scan`` decode at static shapes.  ``temperature=0``
-        is greedy (pinned against the dense forward by teacher forcing);
-        ``>0`` samples, optionally within ``top_k`` and/or the ``top_p``
-        nucleus; the compiled program holds only the sampler the call
-        asked for, and a new ``temperature`` or ``top_p`` value compiles
-        nothing.  ``eos_id`` stops a row early (it keeps emitting
-        ``pad_id``, default the eos itself — hf.generate's convention,
-        at static shapes).  The compiled generator is cached per
-        (max_len, compute_dtype)."""
-        from .generate import cached_generate
-
-        return cached_generate(self, compute_dtype)(
-            self.param_tree(), prompt_ids, max_new, rng=rng,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            eos_id=eos_id, pad_id=pad_id)
 
     def _positions(self, pos_table, T):
         if self.seq_strategy in ("ring", "ulysses"):

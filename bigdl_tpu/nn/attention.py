@@ -5,6 +5,35 @@ so these layers have no reference counterpart to cite — they exist
 because the TPU framework makes long-context and sequence parallelism
 first-class.  The compute lives in ``parallel/ring_attention.py``; these
 modules wrap it in the standard layer protocol.
+
+**What a layer keeps between tokens lives with the layer.**  A
+token-mixing operator a decoder can carry (``models/generate.py``)
+answers three calls, as ``nn.Mamba2Mixer`` and ``nn.GatedShortConv`` do:
+
+* ``state_init(batch, dtype)`` — its state before any token, a dict of
+  arrays;
+* ``sequence(params, x, state=None)`` — a sequence after ``state``
+  (None: from its start) -> ``(out, the leaves it wrote)``;
+* ``step(params, x, state)`` — one token -> ``(out, the leaves it
+  wrote)``.
+
+An operator whose state has a POSITION axis (``keeps_positions``: the
+two attentions here) is told more: ``state_init(batch, dtype, length,
+int8=False)`` leaves room for the calling program's ``length`` positions
+— as int8 where it can be held so; one that cannot raises ``TypeError``
+— ``sequence(params, x, state)`` writes the prompt into that fresh
+state, and ``step(params, x, state, pos)`` is told the token's absolute
+position.  An operator MAY also say ``footprint(batch, dtype, length,
+int8)`` (its bytes by kind and the arm its step compiles, for
+``cache_footprint``; unsaid, all it keeps is ``recurrent_state_bytes``)
+and ``state_doc`` (what it keeps, in words, for whoever cannot hold
+it).  A block around it answers
+``state_init`` likewise and ``advance(params, h, state, pos)`` — Tq
+tokens at ``pos`` (the Python ``0``: the whole prompt) against its state
+— with ``footprint``, ``counters`` (leaf -> the statistic a call
+returns of it) and ``state_doc`` where it has any: ``nn.mamba``,
+``models/transformer.py``, ``models/parallel_moe.py``,
+``models/latent_moe.py``.
 """
 from __future__ import annotations
 
@@ -27,6 +56,97 @@ SPARSE_PATTERNS = ("sliding", "strided")
 
 
 ROPE_KINDS = ("half", "interleaved")
+
+
+# -- the decode-state protocol: what the blocks ask of an operator ---------
+def from_start(pos) -> bool:
+    """``pos`` is the Python ``0``: the whole prompt, not a step."""
+    return isinstance(pos, int) and pos == 0
+
+
+def fresh_state(op, batch: int, dtype, length: int, int8: bool = False):
+    """``op``'s state before any token, for a program of ``length``
+    positions; a state without positions is no K/V and stays in
+    ``dtype`` whatever ``int8`` says."""
+    if getattr(op, "keeps_positions", False):
+        return op.state_init(batch, dtype, length, int8)
+    return op.state_init(batch, dtype)
+
+
+def advance(op, params, x, state, pos):
+    """``op`` over Tq tokens at ``pos`` against ``state``: ``sequence``
+    for the whole prompt (into the fresh state, where it has room to
+    fill), ``step`` after it."""
+    if getattr(op, "keeps_positions", False):
+        return (op.sequence(params, x, state) if from_start(pos)
+                else op.step(params, x, state, pos))
+    return (op.sequence(params, x) if from_start(pos)
+            else op.step(params, x, state))
+
+
+def state_bytes(shapes) -> int:
+    return sum(a.size * a.dtype.itemsize for a in shapes.values())
+
+
+def footprint(op, batch: int, dtype, length: int, int8: bool = False):
+    """``op.footprint(...)``, or for an operator that says nothing all
+    of its state as ``recurrent_state_bytes``."""
+    if hasattr(op, "footprint"):
+        return op.footprint(batch, dtype, length, int8)
+    return {"recurrent_state_bytes": state_bytes(jax.eval_shape(
+        lambda: fresh_state(op, batch, dtype, length, int8)))}
+
+
+def _proj(x, params, w, b, with_bias):
+    y = jnp.dot(x, params[w].T)
+    return y + params[b] if with_bias else y
+
+
+def _quant(x):
+    """absmax int8 over the head dim: x ≈ q * s, q int8,
+    s [β..., 1] float32."""
+    s_ = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
+                 keepdims=True) / 127.0 + 1e-12
+    q_ = jnp.round(x.astype(jnp.float32) / s_).astype(jnp.int8)
+    return q_, s_
+
+
+def _ring_put(arr, x, pos):
+    """``x`` [B, Hkv, Tq, ·] at positions pos.. into ``arr``, whose
+    time axis may be SHORTER than the positions the program spans
+    (a sliding layer's ring): a token goes to slot ``pos mod`` the
+    ring's length; of a prompt longer than the ring the last ring's
+    worth is kept, each position at its slot."""
+    ring, Tq = arr.shape[2], x.shape[2]
+    if isinstance(pos, int):                # prefill, from 0
+        if Tq <= ring:
+            return lax.dynamic_update_slice(arr, x, (0, 0, pos, 0))
+        return jnp.roll(x[:, :, Tq - ring:], (Tq - ring) % ring, axis=2)
+    return lax.dynamic_update_slice(arr, x, (0, 0, pos % ring, 0))
+
+
+def _cache_write(cache, k, v, pos, ringed, int8):
+    put = _ring_put if ringed else (
+        lambda arr, x, pos: lax.dynamic_update_slice(arr, x,
+                                                     (0, 0, pos, 0)))
+    new = dict(cache)
+    for name, x in (("k", k), ("v", v)):
+        if int8:
+            x, scale = _quant(x)
+            new[name + "_scale"] = put(cache[name + "_scale"], scale,
+                                       pos)
+        new[name] = put(cache[name], x, pos)
+    return new
+
+
+def _cache_kv(cache, dt, int8):
+    """(k, v) dense views of the cache — for int8 the convert+
+    scale is elementwise and fuses into the attention dot's
+    operand read (the int8 bytes are what HBM streams)."""
+    if int8:
+        return (cache["k"].astype(dt) * cache["k_scale"].astype(dt),
+                cache["v"].astype(dt) * cache["v_scale"].astype(dt))
+    return cache["k"], cache["v"]
 
 
 def rope_kind(rope):
@@ -141,6 +261,27 @@ class MultiHeadAttention(TensorModule):
         densely with the mask applied elementwise.  On a TPU the block
         must be a multiple of 128 or span the whole sequence — a
         smaller block raises (ops/block_sparse.py ``_kernel_path``).
+
+    What a DECODER keeps of the layer (``state_init`` / ``sequence`` /
+    ``step``) is its K and V by K/V head, ``[B, Hkv, T, Dh]`` — the KV
+    head count, smaller than the query's under GQA — normed and rotated
+    as they are attended, because cached decode attention is another
+    computation than the full-sequence forward.  ``T`` is the calling
+    program's length; a layer with a sliding ``window`` keeps ``min(T,
+    window)`` positions, written at ``pos mod`` that length once it is
+    full (a ring) and read with each slot's absolute position and the
+    lower bound ``k_pos > q_pos - window``.  Under ``int8`` K and V are
+    int8 with a float32 scale per (batch, head, position) — absmax
+    rounding over the head dim: decode is cache-bandwidth-bound, so
+    halving (vs bf16) the bytes read per step buys throughput; lossy by
+    construction, and the prompt's own attention stays full-precision.
+    A step's attend is chosen by shapes alone
+    (``ops.gqa_attend.attend_plan``): where the cache is large, on a
+    TPU, ONE Pallas kernel walks it in blocks of 128 positions up to the
+    block the step's position falls in; everywhere else — small
+    buckets, every other backend, a ring, int8 storage — the plain
+    einsums (``ops.gqa_attend.gqa_attend_reference``) read the whole
+    static cache twice.
     """
 
     def __init__(self, embed_dim: int, num_heads: int,
@@ -247,6 +388,134 @@ class MultiHeadAttention(TensorModule):
         h = heads or self.num_heads
         return x.reshape(B, T, h, self.head_dim).transpose(0, 2, 1, 3)
 
+    def _rep(self, kv):
+        """The Hkv K/V heads repeated to the H query heads (GQA) — on
+        whole-sequence tensors only; a decode step keeps the cache
+        un-repeated (the grouped einsums, the kernel)."""
+        if self.num_kv_heads == self.num_heads:
+            return kv
+        return jnp.repeat(kv, self.num_heads // self.num_kv_heads, axis=1)
+
+    def heads(self, params, x, pos=None):
+        """(q [B, H, T, Dh], k, v [B, Hkv, T, Dh]) of ``x`` [B, T, E]:
+        the projections, the key multiplier, the per-head norms, then
+        the rotation at ABSOLUTE positions — ``pos .. pos + T - 1``
+        (None: from 0), or ``pos`` [T] itself — what a cache holds is
+        normed, rotated keys."""
+        q = self._split(_proj(x, params, "wq", "bq", self.with_bias))
+        k = self._split(_proj(x, params, "wk", "bk", self.with_bias),
+                        self.num_kv_heads)
+        v = self._split(_proj(x, params, "wv", "bv", self.with_bias),
+                        self.num_kv_heads)
+        if getattr(self, "key_multiplier", 1.0) != 1.0:
+            k = k * self.key_multiplier
+        q, k = self.normed_heads(params, q, k)
+        if self.rope:
+            at = pos
+            if pos is None or jnp.ndim(pos) != 1:
+                at = jnp.arange(q.shape[2])
+                at = at if pos is None else pos + at
+            il = getattr(self, "rope_kind", "half") == "interleaved"
+            q = rope_rotate(q, at, self.rope_theta, interleaved=il)
+            k = rope_rotate(k, at, self.rope_theta, interleaved=il)
+        return q, k, v
+
+    def merged(self, params, o):
+        """``o`` [B, H, T, Dh] through the output projection."""
+        B, H, T, D = o.shape
+        return _proj(o.transpose(0, 2, 1, 3).reshape(B, T, H * D), params,
+                     "wo", "bo", self.with_bias)
+
+    # -- what a decoder keeps of the layer, and a token against it ------
+    keeps_positions = True
+
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        """K and V ``[batch, Hkv, min(length, window), Dh]`` in
+        ``dtype``, or int8 with ``k_scale`` / ``v_scale`` beside them."""
+        kv = (batch, self.num_kv_heads,
+              min(length, getattr(self, "window", None) or length),
+              self.head_dim)
+        if int8:
+            return {"k": jnp.zeros(kv, jnp.int8),
+                    "k_scale": jnp.zeros(kv[:3] + (1,), jnp.float32),
+                    "v": jnp.zeros(kv, jnp.int8),
+                    "v_scale": jnp.zeros(kv[:3] + (1,), jnp.float32)}
+        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        """``kv_cache_bytes`` as allocated, and the arm a step of this
+        layer compiles: ``kv_attend`` ``("kernel" | "einsum", positions
+        a block of the kernel's walk)`` — the rule the step reads."""
+        from ..ops.gqa_attend import attend_plan
+
+        shapes = jax.eval_shape(
+            lambda: self.state_init(batch, dtype, length, int8))
+        k = shapes["k"]
+        block = attend_plan(batch, k.shape[1], k.shape[2], k.shape[3],
+                            k.dtype, 1, self._ring(k))
+        return {"kv_cache_bytes": state_bytes(shapes),
+                "kv_attend": ("kernel" if block else "einsum", block)}
+
+    def _ring(self, k):
+        """The layer's window where its cache ``k`` is a ring: a sliding
+        layer's cache as long as its window; a shorter one holds every
+        position of its program, all inside the window: a plain cache."""
+        window = getattr(self, "window", None)
+        return window if window and k.shape[2] == window else None
+
+    def _decode_attend(self, q, k_cache, v_cache, pos, window, int8):
+        from ..ops.gqa_attend import (_gqa_attend_kernel, attend_plan,
+                                      gqa_attend_reference)
+
+        # one kernel pass over the written part of the cache where the
+        # shapes say it wins, the plain einsums over the whole of it
+        # otherwise (a ring, int8 storage, ``Tq > 1``, a small cache,
+        # every backend but a TPU)
+        H, Hkv, Dh = self.num_heads, self.num_kv_heads, self.head_dim
+        block = attend_plan(q.shape[0], Hkv, k_cache.shape[2], Dh,
+                            jnp.int8 if int8 else k_cache.dtype,
+                            q.shape[2], window)
+        with jax.named_scope("attention.decode_attend"):
+            if block:
+                return _gqa_attend_kernel(q[:, :, 0], k_cache, v_cache, pos,
+                                          block, False)[:, :, None]
+            if window is None:
+                return gqa_attend_reference(q, k_cache, v_cache, pos, H,
+                                            Hkv, Dh)
+            # a ring: slot s holds the latest position <= pos that is s
+            # mod the ring's length (negative: none yet)
+            ring = k_cache.shape[2]
+            k_pos = pos - (pos - jnp.arange(ring)) % ring
+            return gqa_attend_reference(q, k_cache, v_cache, pos, H, Hkv,
+                                        Dh, k_pos=k_pos, window=window)
+
+    def sequence(self, params, x, state):
+        """The whole prompt ``x`` [B, T0, E] in one causal pass that
+        fills ``state`` (``state_init``'s) at ``[0, T0)``: the prompt
+        attends its own full-precision k/v by the flash kernels
+        (O(T0·block) memory on a TPU, the same dense causal attention
+        elsewhere), never the ``[T]`` cache — slots past the prompt are
+        outside the causal horizon anyway — so the first generated
+        token is bit-exact under int8 too."""
+        return self.step(params, x, state, 0)
+
+    def step(self, params, x, state, pos):
+        """Tq tokens at ``pos`` against ``state``: written, then
+        attended; -> (the output projection's result, the state)."""
+        int8 = "k_scale" in state
+        q, k, v = self.heads(params, x, pos)
+        window = self._ring(state["k"])
+        state = _cache_write(state, k, v, pos, bool(window), int8)
+        if from_start(pos):
+            from ..ops.flash_attention import flash_attention
+
+            o = flash_attention(q, self._rep(k), self._rep(v), causal=True,
+                                window=getattr(self, "window", None))
+        else:
+            o = self._decode_attend(q, *_cache_kv(state, q.dtype, int8),
+                                    pos, window, int8)
+        return self.merged(params, o), state
+
     def block_mask(self, T, S):
         """The layer's static :class:`~bigdl_tpu.ops.BlockMask` for a
         (T, S) attention — built once per shape and cached (hashable,
@@ -304,33 +573,14 @@ class MultiHeadAttention(TensorModule):
         return attention(q, k, v, causal=self.causal)
 
     def _apply(self, params, buffers, x, training, rng):
-        def proj(x, w, b):
-            y = jnp.dot(x, w.T)
-            return y + params[b] if self.with_bias else y
-
-        q = self._split(proj(x, params["wq"], "bq"))
-        k = self._split(proj(x, params["wk"], "bk"), self.num_kv_heads)
-        v = self._split(proj(x, params["wv"], "bv"), self.num_kv_heads)
-        if getattr(self, "key_multiplier", 1.0) != 1.0:
-            k = k * self.key_multiplier
-        q, k = self.normed_heads(params, q, k)
-        if self.rope:
-            pos = jnp.arange(q.shape[2])
-            il = getattr(self, "rope_kind", "half") == "interleaved"
-            q = rope_rotate(q, pos, self.rope_theta, interleaved=il)
-            k = rope_rotate(k, pos, self.rope_theta, interleaved=il)
-        if self.num_kv_heads != self.num_heads:
-            group = self.num_heads // self.num_kv_heads
-            k = jnp.repeat(k, group, axis=1)
-            v = jnp.repeat(v, group, axis=1)
+        q, k, v = self.heads(params, x)
+        k, v = self._rep(k), self._rep(v)
         # device scope (``telemetry.tracer.DEVICE_SCOPES``): the
         # attention itself, whichever arm; projections and rotation
         # stay outside it
         with jax.named_scope("attention.core"):
             o = self._attend(q, k, v)
-        B, H, T, D = o.shape
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
-        return proj(o, params["wo"], "bo"), buffers
+        return self.merged(params, o), buffers
 
 
 class LatentAttention(TensorModule):
@@ -353,12 +603,30 @@ class LatentAttention(TensorModule):
 
     ``apply_fn`` is the EXPANDED full-sequence form (per-head K and V
     made from the latent, then causal flash or dense attention) — the
-    training and prefill form, differentiable by autodiff.  What a
-    decode step keeps and reads is ``c_kv`` and the rotated ``k_rope``
-    (``kv_rank + rope`` numbers a position), with ``wkv_b`` absorbed
-    into the query and the output: ``models/generate.py``."""
+    training and prefill form, differentiable by autodiff.
 
-    kind = "latent"
+    What a DECODER keeps (``state_init`` / ``sequence`` / ``step``) is
+    ``ckv`` ``[B, T, kv_rank]`` (the normed latent) and ``kr`` ``[B,
+    rope, T]`` (the rotated key all heads share; positions minor, so
+    that no position's ``rope`` numbers are padded to a lane tile and
+    the attend's kernel reads what the leaf holds) — no leaf has a head
+    axis, and a position holds ``kv_rank + rope`` numbers.  The prompt
+    EXPANDS its latent to per-head K and V once and runs causal (flash)
+    attention at the full head size.  A decode step never expands the
+    cache: the key half of ``wkv_b`` is absorbed into the query (``q_lat
+    = q_nope W_uk``), scores and the weighted sum are taken on the
+    latent itself, and the value half is applied to the ONE resulting
+    latent a head (``o = o_lat W_uv``) — algebraically the same,
+    ``kv_rank + rope`` numbers a cached position read instead of ``heads
+    * (qk + v)`` made; the only array with both a head and a
+    cached-position axis is the scores.  That attend has two arms,
+    chosen by shapes alone (``ops.latent_attend.attend_plan``): where
+    the cache is large, on a TPU, ONE Pallas kernel walks it in blocks
+    up to the block the step's position falls in; everywhere else the
+    plain einsums read the whole static cache twice."""
+
+    state_doc = ("keeps no K or V by head — its cache is the latent and "
+                 "one rotated key a position")
 
     def __init__(self, embed_dim: int, num_heads: int, q_rank: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
@@ -485,6 +753,75 @@ class LatentAttention(TensorModule):
         B, H, T, D = o.shape
         return jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, H * D),
                        params["wo"].T)
+
+    # -- what a decoder keeps of the layer, and a token against it ------
+    keeps_positions = True
+
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        if int8:
+            raise TypeError(
+                'kv_dtype="int8" holds K and V by head as int8 with a '
+                f"scale a head and {type(self).__name__} {self.state_doc}: "
+                "decode this model through generate() / submit_generate() "
+                "with the default cache")
+        return {"ckv": jnp.zeros((batch, length, self.kv_rank), dtype),
+                "kr": jnp.zeros((batch, self.rope_dim, length), dtype)}
+
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        """``latent_cache_bytes`` as allocated, and the arm of the
+        absorbed attend a step compiles (``latent_attend``: ``("kernel"
+        | "einsum", positions a block)``) — the rule the step reads."""
+        from ..ops.latent_attend import attend_plan
+
+        block = attend_plan(batch, length, self.kv_rank, self.rope_dim,
+                            dtype)
+        return {"latent_cache_bytes": state_bytes(jax.eval_shape(
+                    lambda: self.state_init(batch, dtype, length, int8))),
+                "latent_attend": ("kernel" if block else "einsum", block)}
+
+    def sequence(self, params, x, state):
+        return self.step(params, x, state, 0)
+
+    def step(self, params, x, state, pos):
+        """Tq tokens at ``pos`` against ``state``: the whole prompt
+        (``pos`` the Python 0) expands and attends itself, a decode step
+        absorbs and attends the latent."""
+        qpos = pos + jnp.arange(x.shape[1])
+        with jax.named_scope("mla.q_proj"):
+            q_nope, q_rope = self.queries(params, x, qpos)
+        with jax.named_scope("mla.kv_latent"):
+            ckv, kr = self.latent(params, x, qpos)
+            state = {**state,
+                     "ckv": lax.dynamic_update_slice(
+                         state["ckv"], ckv.astype(state["ckv"].dtype),
+                         (0, pos, 0)),
+                     "kr": lax.dynamic_update_slice(
+                         state["kr"],
+                         kr.astype(state["kr"].dtype).transpose(0, 2, 1),
+                         (0, 0, pos))}
+        if from_start(pos):
+            with jax.named_scope("mla.expand"):
+                k, v = self.expand(params, ckv, kr)
+            # the flash kernels take one head size; a narrower value
+            # head goes the plain way, whole scores
+            o = self.attend_full(q_nope, q_rope, k, v,
+                                 flash=v.shape[-1] == self.qk_dim)
+        else:
+            w_uk, w_uv = self.up_weights(params)
+            dt = q_nope.dtype
+            with jax.named_scope("mla.absorb"):
+                q_lat = jnp.einsum("bhqn,hnc->bhqc", q_nope,
+                                   w_uk.astype(dt))
+            with jax.named_scope("mla.attend"):
+                from ..ops.latent_attend import latent_attend
+
+                o_lat = latent_attend(q_lat, q_rope, state["ckv"],
+                                      state["kr"], pos, self.qk_dim,
+                                      scale_mult=self.softmax_mult)
+            with jax.named_scope("mla.absorb"):
+                o = jnp.einsum("bhqc,hvc->bhqv", o_lat, w_uv.astype(dt))
+        with jax.named_scope("mla.out_proj"):
+            return self.out_proj(params, o), state
 
     def _apply(self, params, buffers, x, training, rng):
         pos = jnp.arange(x.shape[1])

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..utils.rng import next_jax_key
-from .attention import MultiHeadAttention
+from .attention import MultiHeadAttention, advance, footprint
 from .initialization import RandomNormal, device_draw
 from .linear import Linear
 from .module import Container, TensorModule
@@ -330,14 +330,22 @@ class HybridMambaBlock(Container):
         u = rms(x);  x = x + mixer(u) * ssm_out + attn(u * attn_in) * attn_out
         f = rms(x);  x = x + down(up(f) * silu(gate(f) * mlp_gate)) * mlp_down
 
-    Children, in the order the generation builder relies on (the first
-    six are a llama-dialect ``TransformerBlock``'s): ``0`` input norm,
-    ``1`` attention, ``2`` pre-MLP norm, ``3`` gate, ``4`` up, ``5``
-    down, ``6`` the mixer.  Every multiplier defaults to 1.
+    Children (the first six are a llama-dialect ``TransformerBlock``'s):
+    ``0`` input norm, ``1`` attention, ``2`` pre-MLP norm, ``3`` gate,
+    ``4`` up, ``5`` down, ``6`` the mixer.  Every multiplier defaults
+    to 1.
+
+    A decoder keeps, beside the attention's K/V, the mixer's SSM state
+    ``[B, heads, head, N]`` (float32) and conv tail ``[B, d_conv - 1,
+    channels]`` in ONE dict a layer (``state_init`` / ``advance``, the
+    decode-state protocol of ``nn/attention.py``): the prompt runs the
+    chunked scan from an empty state and hands the state after its last
+    token to the decode steps, which advance it one token each.
     """
 
-    kind = "hybrid_mamba"
     is_moe = False
+    state_doc = ("it carries a recurrent state (SSM state and conv tail) "
+                 "beside its K/V")
     mlp_kind = "swiglu"
 
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
@@ -397,6 +405,36 @@ class HybridMambaBlock(Container):
         gm, dm = self.mlp_multipliers
         g = jax.nn.silu(scaled(run(3, f), gm)) * run(4, f)
         return x + scaled(run(5, g), dm), buffers
+
+    # -- decode: the state between tokens, and Tq tokens against it ------
+    def state_init(self, batch: int, dtype, length: int, int8: bool = False):
+        return {**self.modules[1].state_init(batch, dtype, length, int8),
+                **self.mixer.state_init(batch, dtype)}
+
+    def footprint(self, batch: int, dtype, length: int, int8: bool = False):
+        return {**self.modules[1].footprint(batch, dtype, length, int8),
+                **footprint(self.mixer, batch, dtype, length, int8)}
+
+    def advance(self, params, h, state, pos):
+        """The mixer reads the same normed input as the attention: the
+        prompt runs the chunked scan from an empty state and keeps the
+        state after its last token, a decode step advances it."""
+        def run(i, v):
+            return self.modules[i].apply_fn(params[str(i)], {}, v, False,
+                                            None)[0]
+
+        u = run(0, h)
+        with jax.named_scope("mixer.attention"):
+            a, state = advance(
+                self.modules[1], params["1"],
+                scaled(u, self.attention_in_multiplier), state, pos)
+        m, carried = advance(self.mixer, params["6"], u, state, pos)
+        h = self.mix(h, a, m)
+        f = run(2, h)
+        gm, dm = self.mlp_multipliers
+        g, up = run(3, f), run(4, f)
+        f = run(5, jax.nn.silu(scaled(g, gm)) * up)
+        return h + scaled(f, dm), {**state, **carried}
 
 
 def scaled(v, m: float):

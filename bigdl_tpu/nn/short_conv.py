@@ -32,10 +32,14 @@ class GatedShortConv(TensorModule):
     unless an init method is set.
 
     ``apply_fn`` is the whole-sequence form (plain jax: differentiable
-    by autodiff).  ``sequence`` / ``step`` / ``state_init`` are
-    ``Mamba2Mixer``'s contract, so the generation builder carries the
-    tail as it carries a mixer's: ``{"conv": [batch, kernel - 1,
-    embed]}`` in the cache's dtype."""
+    by autodiff).  ``state_init`` / ``sequence`` / ``step`` are
+    ``Mamba2Mixer``'s contract, the decode-state protocol of an
+    operator without positions (``nn/attention.py``): the layer's WHOLE
+    state is its tail, ``{"conv": [batch, kernel - 1, embed]}`` in the
+    decoder's dtype (not K/V: under an int8 cache too), whatever the
+    context — no ``k``, no ``v``, no position.  The prompt keeps its
+    last values, a decode step reads them, writes one output from
+    ``kernel`` values and shifts."""
 
     kind = "short_conv"
 
@@ -57,6 +61,11 @@ class GatedShortConv(TensorModule):
         self._register_param("conv", init.init((K, D), IN_OUT))
         self._register_param("w_out", init.init((D, D), IN_OUT))
         return self
+
+    @property
+    def state_doc(self) -> str:
+        return ("keeps no K or V at all — its whole state is a convolution "
+                f"tail of {self.kernel - 1} positions a row")
 
     def sequence(self, params, x, state=None):
         """[b, T, D] and the state before it (None: zeros, the start of

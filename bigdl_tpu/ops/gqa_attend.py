@@ -3,11 +3,11 @@ of ONE query token a row against the un-repeated K and V leaves — as one
 Pallas TPU kernel that reads the cache once and only as far as it is
 written.
 
-What a decode step holds (``models/generate.py``, ``_attention``): the
+What a decode step holds (``nn.MultiHeadAttention.step``): the
 rotated query ``q [B, H, 1, Dh]``, the layer's cache ``k``, ``v`` ``[B,
 Hkv, T, Dh]`` (``Hkv`` K/V heads, each shared by ``G = H / Hkv`` query
 heads) and ONE position ``pos`` for the whole batch.  The plain form
-(``generate._gqa_attend``, which stays the reference) is two grouped
+(:func:`gqa_attend_reference`, which stays the reference) is two grouped
 einsums over the whole cache with a float32 softmax between them: each
 leaf streams through the MXU ``G`` query rows at a time, all ``T``
 positions of it whatever ``pos``.
@@ -102,6 +102,46 @@ KERNEL_MIN_CACHE_BYTES = 64 << 20
 KERNEL_MIN_CACHE_BYTES_HEAD_64 = 6 << 20
 
 
+def gqa_attend_reference(q, k_cache, v_cache, pos, H, Hkv, Dh, k_pos=None,
+                         window=None):
+    """The plain form, any ``Tq``, and the kernel's reference: causal
+    attention of Tq queries (absolute positions pos..pos+Tq-1) against
+    a dense ``[B, Hkv, Tm, Dh]`` cache view.  GQA contracts the query
+    groups against the UN-repeated cache — a repeat here would
+    materialize H/Hkv copies of the whole cache every decode step,
+    exactly the bandwidth GQA exists to save.  Shared by the static
+    cache and the paged decode path (which passes a page-gathered
+    view), so the two can never drift numerically.  ``k_pos`` [Tm] gives
+    each cache slot's ABSOLUTE position when the view is not contiguous
+    from 0 — the page-window path gathers only the live pages, so slot
+    index and position diverge.  ``window`` adds the sliding window's
+    far edge, ``k_pos > q_pos - window``, and masks a slot that holds no
+    position yet (``k_pos < 0``: a ring that is not full)."""
+    Tq, Tm = q.shape[2], k_cache.shape[2]
+    scale = 1.0 / jnp.sqrt(jnp.float32(Dh)).astype(q.dtype)
+    qpos = pos + jnp.arange(Tq)
+    if k_pos is None:
+        k_pos = jnp.arange(Tm)
+    mask = k_pos[None, :] <= qpos[:, None]            # [Tq, Tm]
+    if window is not None:
+        mask = (mask & (k_pos[None, :] > qpos[:, None] - window)
+                & (k_pos[None, :] >= 0))
+    if Hkv == H:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache) * scale
+        scores = jnp.where(mask[None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype),
+                          v_cache)
+    B = q.shape[0]
+    qg = q.reshape(B, Hkv, H // Hkv, Tq, Dh)
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache) * scale
+    scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    o = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(q.dtype),
+                   v_cache)
+    return o.reshape(B, H, Tq, Dh)
+
+
 def _lane_width(Dh: int) -> int:
     """Lanes a position's ``Dh`` numbers fill in VMEM: whole tiles."""
     return -(-Dh // _LANES) * _LANES
@@ -124,7 +164,7 @@ def attend_plan(B: int, Hkv: int, T: int, Dh: int, dtype, Tq: int = 1,
     """Positions a block of the kernel arm for a decode step of ``B``
     rows and ``Hkv`` K/V heads against ``T`` cached positions of ``Dh``
     numbers stored in ``dtype`` — or 0: the einsum arm.  The ONE rule
-    both the decode step (``generate._decode_machinery``) and
+    both the decode step (``nn.MultiHeadAttention.step``) and
     ``cache_footprint`` read.  The kernel takes one query a row against
     a cache that is contiguous from position 0 (no ``window``: a ring's
     slots are not positions) and held in a floating dtype (int8 K/V is

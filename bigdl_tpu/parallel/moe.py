@@ -210,6 +210,42 @@ class MoEFFN(TensorModule):
             counts if counts is not None else 0.0)
         return pos, (pos <= C) & (onehot > 0), new_counts     # [N, E]
 
+    def nodrop(self, params, x):
+        """The capacity-FREE top-k advance a decoder runs (each token
+        simply uses its chosen experts: at inference nothing should be
+        dropped), through the dropless dispatch below: assignments
+        sorted by expert, one grouped product per projection (each
+        expert's weights read at most once a call, none gathered per
+        token), mixed by the (top-1 raw / top-k renormalized) gates.
+        [B, Tq, D] -> [B, Tq, D]."""
+        B, Tq, D = x.shape
+        K = getattr(self, "top_k", 1)
+        x2 = x.reshape(B * Tq, D)
+        gk, idxk = route_top_k(x2, params["router_w"], params["router_b"], K,
+                               "softmax", renormalize=K > 1)
+        if getattr(BIND_TLS, "capture", None) is not None:
+            # the training dispatch's keep rule (capacity from THIS
+            # batch's token count; choice-ordered stream like _route) —
+            # the fraction is over all N·K routing assignments
+            kept, counts = 0.0, None
+            for c in range(K):
+                oh = jax.nn.one_hot(idxk[:, c], self.n_experts,
+                                    dtype=jnp.float32)
+                _, keep, counts = self.keep_mask(oh, counts)
+                kept = kept + jnp.sum(keep.astype(jnp.float32))
+            BIND_TLS.capture.append(1.0 - kept / (B * Tq * K))
+
+        def gelu_experts(xs, sizes):
+            e = row_experts(sizes, xs.shape[0])
+            h = jax.nn.gelu(grouped_matmul(xs, params["wi"], sizes)
+                            + params["bi"][e].astype(xs.dtype))
+            return (grouped_matmul(h, params["wo"], sizes)
+                    + params["bo"][e].astype(xs.dtype))
+
+        y, _ = dropless_apply(x2, idxk, gk, (0, self.n_experts),
+                              gelu_experts)
+        return y.reshape(B, Tq, D)
+
     def _expert_mlp(self, inp, params):
         """inp [e, c, D] through the (possibly expert-sharded) stacked
         weights — the leading dims of ``inp`` and ``params['wi']``
@@ -256,8 +292,16 @@ class MoEFFN(TensorModule):
 # experts' assignments sorted by expert, one grouped matrix product per
 # projection, a weighted gather back.  Shared by ``DroplessMoE`` (gated
 # experts, a share of the experts) and by decode's capacity-free advance
-# of a ``MoEFFN`` (``models/generate.py::_moe_ffn_nodrop``).
+# of a ``MoEFFN`` (``MoEFFN.nodrop``).
 # --------------------------------------------------------------------------
+
+#: capacity-bind capture: while a list is installed on this thread
+#: (``capture``), every ``MoEFFN.nodrop`` call appends the fraction of
+#: its assignments the TRAINING dispatch's static capacity would have
+#: dropped (trace-time side channel of ``capacity_bind_report``; absent
+#: in a normal decode).  Thread-LOCAL, so a concurrent trace of another
+#: model's generator cannot interleave its fractions into a report.
+BIND_TLS = threading.local()
 
 SCORINGS = ("softmax", "sigmoid")
 
@@ -656,6 +700,24 @@ class DroplessMoE(TensorModule):
                 held_key(idx, self.held).reshape(batch, -1), count + 1,
                 dtype=jnp.int32), axis=1)[:, :count]
         return y, sizes
+
+    def decode_plan(self, batch: int, dtype) -> dict:
+        """The arm and the tile plan of the grouped products in a decode
+        step of ``batch`` rows (one token a row, its choices among the
+        held experts): ``grouped`` (``"ragged"``, ``"grouped_decode"``
+        or ``"gmm"``), ``grouped_tiles`` (``"<rows a product>x<tk>x<tn>"``
+        of the gate and up products; empty for ``ragged``) and
+        ``grouped_tiles_down`` — :func:`grouped_plan`, the rule
+        ``grouped_matmul`` itself reads.  As text: these ride on
+        ``serve.dispatch`` into a profiler session, whose event metadata
+        is split at commas."""
+        rows = batch * min(self.top_k, self.held[1])
+        D, F = self.embed_dim, self.hidden_dim
+        impl, up = grouped_plan(rows, D, F, dtype)
+        down = grouped_plan(rows, F, D, dtype)[1]
+        return {"grouped": impl,
+                "grouped_tiles": "x".join(map(str, up or ())),
+                "grouped_tiles_down": "x".join(map(str, down or ()))}
 
     def _apply(self, params, buffers, x, training, rng):
         B, T, D = x.shape

@@ -163,14 +163,17 @@ class KVPagePool:
     @classmethod
     def for_model(cls, model, num_pages: int, page_size: int = 16,
                   dtype=None) -> "KVPagePool":
-        """Size a pool from a ``TransformerLM``'s own geometry."""
+        """Size a pool from a ``TransformerLM``'s own geometry: the
+        K/V heads and head size its first block says it keeps."""
+        import jax
+
         from ..models.generate import _check_model
 
         first, count = _check_model(model)
-        mha = model.modules[first].modules[1]
-        return cls(num_pages, count,
-                   getattr(mha, "num_kv_heads", mha.num_heads),
-                   page_size, mha.head_dim, dtype=dtype)
+        k = jax.eval_shape(lambda: model.modules[first].state_init(
+            1, "float32", page_size))["k"]
+        return cls(num_pages, count, k.shape[1], page_size, k.shape[3],
+                   dtype=dtype)
 
     def arena_bytes(self) -> int:
         """Bytes the full K+V arena occupies (itemsize from dtype;

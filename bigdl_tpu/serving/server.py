@@ -1273,11 +1273,12 @@ class InferenceServer:
                 fetch.set(**moe_counters(
                     np.asarray(stats["moe_counts"]),
                     bucket * (prompts.shape[1] + max_new - 1)))
-            if "mhc_sinkhorn_err" in stats:
-                # how far from doubly stochastic the call's worst
-                # residual map was (a hyper-connected model)
-                fetch.set(mhc_sinkhorn_err=float(
-                    stats["mhc_sinkhorn_err"]))
+            # whatever else the call's layers counted, one number each
+            # (a hyper-connected model: how far from doubly stochastic
+            # its worst residual map was), by its own name
+            for name, v in stats.items():
+                if not np.ndim(v):
+                    fetch.set(**{name: float(v)})
         if new_sig:
             self._settle_heap()
         return out, bucket

@@ -464,7 +464,7 @@ def test_the_server_reports_the_counters_and_the_cache_by_kind():
 
 # -- the paths it shares -------------------------------------------------
 def test_a_moe_ffn_decodes_through_the_dispatch_without_gathered_weights():
-    """``_moe_ffn_nodrop`` is a call of the dropless dispatch: no
+    """``MoEFFN.nodrop`` is a call of the dropless dispatch: no
     ``[N, D, H]`` of weights gathered per token, and the capacity-free
     mixture it always computed."""
     from bigdl_tpu.parallel.moe import MoEFFN
@@ -472,7 +472,7 @@ def test_a_moe_ffn_decodes_through_the_dispatch_without_gathered_weights():
     moe = MoEFFN(16, 24, 4, top_k=2)
     p = moe.param_tree()
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 16))
-    got = G._moe_ffn_nodrop(moe, p, x)
+    got = moe.nodrop(p, x)
     x2 = x.reshape(10, 16)
     probs = jax.nn.softmax(x2 @ p["router_w"].T + p["router_b"], -1)
     g, idx = jax.lax.top_k(probs, 2)
@@ -485,7 +485,7 @@ def test_a_moe_ffn_decodes_through_the_dispatch_without_gathered_weights():
         want = want + g[:, c, None] * (jnp.einsum("nh,nhd->nd", h, wo)
                                        + p["bo"][idx[:, c]])
     _close(got.reshape(10, 16), want)
-    jaxpr = str(jax.make_jaxpr(lambda a: G._moe_ffn_nodrop(moe, p, a))(x))
+    jaxpr = str(jax.make_jaxpr(lambda a: moe.nodrop(p, a))(x))
     assert "f32[10,16,24]" not in jaxpr and "f32[10,24,16]" not in jaxpr
 
 

@@ -430,8 +430,7 @@ def test_capacity_bind_report_matches_brute_force():
     the module apply_fns (full-sequence causal attention, capacity-free
     MoE — the decode path's semantics), with the training dispatch's
     over-capacity count recomputed in numpy at every MoE router."""
-    from bigdl_tpu.models.generate import (_moe_ffn_nodrop,
-                                           capacity_bind_report)
+    from bigdl_tpu.models.generate import capacity_bind_report
 
     model = _model(moe_experts=2, moe_capacity_factor=0.51)
     params = model.param_tree()
@@ -464,7 +463,7 @@ def test_capacity_bind_report_matches_brute_force():
             seen[int(e)] = seen.get(int(e), 0) + 1
             dropped += seen[int(e)] > C
         want[1 + bi] = dropped / N
-        h = h + _moe_ffn_nodrop(moe, bp["3"], ln2)
+        h = h + moe.nodrop(bp["3"], ln2)
     for k, v in want.items():
         np.testing.assert_allclose(rep[k], v, atol=1e-6)
     assert rep["overall"] > 0.0  # capacity 0.51 must bind somewhere
@@ -630,3 +629,65 @@ def test_cache_footprint_counts_the_allocated_cache(t0, max_new, max_len,
     assert q8["kv_cache_bytes"] == LAYERS * 2 * 3 * 2 * want * (4 + 4)
     with pytest.raises(ValueError, match="exceeds max_len"):
         cache_footprint(model, 3, t0, max_len - t0 + 1)
+
+
+# -- the program-side twin of benchmark's test_new_architecture_is_found --
+def test_a_new_operator_is_served_with_no_edit_to_the_package():
+    """An operator DEFINED HERE — a gated mean over the last two
+    positions, answering ``state_init`` / ``sequence`` / ``step`` —
+    inside a ``SequentialMoEBlock`` of a ``SequentialMoELM`` decodes
+    greedily to what teacher forcing through ``apply_fn`` gives, reports
+    its bytes, and is refused by the paged decoder with the one message:
+    ``models/generate.py`` asks the layer and never looks."""
+    from bigdl_tpu.models.generate import PagedDecoder, cache_footprint
+    from bigdl_tpu.models.latent_moe import GatedFFN, SequentialMoELM
+    from bigdl_tpu.nn.initialization import IN_OUT, RandomNormal
+    from bigdl_tpu.nn.module import TensorModule
+    from bigdl_tpu.serving.kvpool import KVPagePool
+
+    class GatedPairMean(TensorModule):
+        """``sigmoid(x_t W) * (x_t + x_{t-1}) / 2``; carries ``x_{t-1}``."""
+
+        def __init__(self, embed_dim):
+            super().__init__()
+            self.embed_dim = embed_dim
+            self._register_param("w", RandomNormal(0.0, 0.5).init(
+                (embed_dim, embed_dim), IN_OUT))
+
+        def state_init(self, batch, dtype):
+            return {"last": jnp.zeros((batch, 1, self.embed_dim), dtype)}
+
+        def sequence(self, params, x, state=None):
+            last = (self.state_init(x.shape[0], x.dtype) if state is None
+                    else state)["last"]
+            before = jnp.concatenate([last, x[:, :-1]], axis=1)
+            out = jax.nn.sigmoid(x @ params["w"].T) * (x + before) / 2
+            return out, {"last": x[:, -1:]}
+
+        def step(self, params, x, state):
+            return self.sequence(params, x, state)
+
+        def _apply(self, params, buffers, x, training, rng):
+            return self.sequence(params, x)[0], buffers
+
+    RNG().set_seed(9)
+    model = SequentialMoELM(
+        VOCAB, EMBED, [lambda: GatedPairMean(EMBED)] * LAYERS,
+        [lambda: GatedFFN(EMBED, MLP, 0.5)] * LAYERS, max_len=TMAX,
+        init_std=0.5)
+    prompt = np.random.RandomState(5).randint(
+        1, VOCAB + 1, (3, 5)).astype(np.int32)
+    ids = make_generate(model)(model.param_tree(), prompt, max_new=9)
+    assert len({tuple(r) for r in np.asarray(ids)[:, 5:]}) > 1
+    _teacher_force_check(model, ids, prompt_len=5)
+    # all it keeps is a state that does not grow with the context
+    foot = cache_footprint(model, 3, 5, 9)
+    assert foot == {"kv_cache_positions": TMAX, "kv_cache_bytes": 0,
+                    "recurrent_state_bytes": LAYERS * 3 * EMBED * 4}
+    pool = KVPagePool(num_pages=8, page_size=4, layers=LAYERS,
+                      num_kv_heads=2, head_dim=8)
+    with pytest.raises(TypeError, match="pages of ONE length .* cannot hold "
+                       "SequentialMoEBlock's state — GatedPairMean keeps a "
+                       "state of its own: decode this model through "
+                       r"generate\(\) / submit_generate\(\)"):
+        PagedDecoder(model, pool)

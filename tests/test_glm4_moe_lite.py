@@ -38,7 +38,7 @@ from benchmark.reference import glm4_moe_lite as ref  # noqa: E402
 from bigdl_tpu import nn  # noqa: E402
 from bigdl_tpu.models import generate as G  # noqa: E402
 from bigdl_tpu.models.latent_moe import (GatedFFN, LatentMoELM,  # noqa: E402
-                                         LogitHead)
+                                         LogitHead, SequentialMoEBlock)
 from bigdl_tpu.parallel import moe as M  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark/tests/glm47flash/benchmark/"
@@ -207,7 +207,7 @@ def test_a_decode_step_makes_nothing_by_head_and_position_but_the_scores():
     first, count = G._check_model(model)
     _, decode_token, _ = G._decode_machinery(model, first, count)
     B, H, T = 3, CFG["num_attention_heads"], 64
-    caches = [G._cache_init(b, B, T, jnp.float32)
+    caches = [b.state_init(B, jnp.float32, T)
               for b in model.modules[first:first + count]]
     text = str(jax.make_jaxpr(
         lambda pc, tok, caches: decode_token(pc, tok, caches, jnp.int32(20))
@@ -298,10 +298,10 @@ def test_dense_first_then_experts_an_untied_head_and_held_dtypes():
     blocks = model.modules[1:1 + LAYERS]
     assert isinstance(blocks[0].modules[3], GatedFFN)
     assert all(isinstance(b.modules[3], M.DroplessMoE) for b in blocks[1:])
-    assert [G._block_kind(b)[:2] for b in blocks] == [
-        ("sequential", "latent")] * LAYERS
-    assert G._block_kind(blocks[0])[2] is None
-    assert G._block_kind(blocks[1])[2] is blocks[1].modules[3]
+    assert [(type(b), type(b.modules[1])) for b in blocks] == [
+        (SequentialMoEBlock, nn.LatentAttention)] * LAYERS
+    assert not blocks[0].is_moe and not blocks[0].counters
+    assert blocks[1].is_moe and blocks[1].moe is blocks[1].modules[3]
     head = model.modules[-1]
     assert isinstance(head, LogitHead)
     tree = model.param_tree()

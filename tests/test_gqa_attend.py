@@ -1,6 +1,6 @@
 """The attend of a per-head K/V decode step (``ops/gqa_attend.py``,
 PR 41): the Pallas kernel, interpreted on the CPU, against the plain
-grouped-query einsums of ``generate._gqa_attend`` it replaces where a
+grouped-query einsums of ``gqa_attend_reference`` it replaces where a
 layer's K and V are large — one pass over the cache, and only as far as
 it is written.
 
@@ -41,7 +41,7 @@ def _operands(G_, Dh, dt, B=B, Hkv=HKV, T=T, seed=0):
 
 
 def _kernel_arm(q, k, v, pos):
-    """The kernel arm, interpreted, on the operands of ``_gqa_attend``."""
+    """The kernel arm, interpreted, on the reference's operands."""
     block = A.attend_plan(q.shape[0], k.shape[1], k.shape[2], q.shape[3],
                           k.dtype, interpret=True)
     return A._gqa_attend_kernel(q[:, :, 0], k, v, pos, block,
@@ -69,7 +69,8 @@ def test_kernel_equals_the_einsums_and_reads_nothing_beyond_pos(G_, Dh, dt,
     falls in the scores AND the V rows beyond it are masked, so not even
     ``0 * inf`` reaches the result."""
     q, k, v = _operands(G_, Dh, dt)
-    want = G._gqa_attend(q, k, v, jnp.int32(pos), HKV * G_, HKV, Dh)
+    want = A.gqa_attend_reference(q, k, v, jnp.int32(pos), HKV * G_, HKV,
+                                  Dh)
     dead = (jnp.arange(T) > pos)[None, None, :, None]
     got = _kernel_arm(q, jnp.where(dead, jnp.nan, k),
                       jnp.where(dead, jnp.inf, v), jnp.int32(pos))
@@ -82,7 +83,8 @@ def test_a_cache_no_block_divides_is_one_block():
     q, k, v = _operands(4, 8, jnp.float32, T=48, seed=1)
     assert A.attend_plan(B, HKV, 48, 8, jnp.float32, interpret=True) == 48
     for pos in (0, 17, 47):
-        want = G._gqa_attend(q, k, v, jnp.int32(pos), HKV * 4, HKV, 8)
+        want = A.gqa_attend_reference(q, k, v, jnp.int32(pos), HKV * 4,
+                                      HKV, 8)
         _close(_kernel_arm(q, k, v, jnp.int32(pos)), want, jnp.float32)
 
 
@@ -201,7 +203,7 @@ def _bf16_jaxpr(model):
 
 @pytest.mark.parametrize("name", list(TOYS))
 def test_the_einsum_arm_is_the_program_it_was(monkeypatch, name):
-    """Where the rule says 0 the decode step calls ``_gqa_attend`` as it
+    """Where the rule says 0 the decode step calls the reference as it
     did before there was a rule (``_decode_machinery._attend``: the
     parent's two calls, verbatim, after the kernel's branch): no kernel
     in the program, and the SAME program whether the rule said 0 for
@@ -235,8 +237,9 @@ def test_greedy_tokens_of_the_kernel_arm_are_the_einsum_arms(monkeypatch,
                                                              name):
     with jax.default_matmul_precision("highest"):
         cfg, model = _toy(name, max_len=64)
-        layers = sum(G._block_kind(b)[1] == "kv" for b in
-                     model.modules[1:1 + G._check_model(model)[1]])
+        layers = sum(
+            "k" in jax.eval_shape(lambda b=b: b.state_init(1, jnp.float32, 8))
+            for b in model.modules[1:1 + G._check_model(model)[1]])
         # 25 + 11 tokens: the steps' positions cross from the walk's
         # first block of 32 into its second
         prompts = np.random.RandomState(2).randint(

@@ -41,6 +41,7 @@ from benchmark.reference import lfm2_moe as ref  # noqa: E402
 from bigdl_tpu import nn  # noqa: E402
 from bigdl_tpu.models import generate as G  # noqa: E402
 from bigdl_tpu.models.latent_moe import (GatedFFN, LogitHead,  # noqa: E402
+                                         SequentialMoEBlock,
                                          SequentialMoELM, ShortConvMoELM)
 from bigdl_tpu.parallel import moe as M  # noqa: E402
 
@@ -276,8 +277,11 @@ def test_a_conv_layer_keeps_its_tail_and_nothing_else():
         if i >= CFG["num_dense_layers"]:
             want["moe_counts"] = (2, held)
         assert {k: v.shape for k, v in cache.items()} == want, (i, kind)
-    assert [G._block_kind(b)[1] for b in model.modules[1:1 + LAYERS]] == [
-        "conv", "kv", "conv", "conv", "conv"]
+    # what a layer keeps is what its operator says it keeps
+    assert [sorted(set(jax.eval_shape(
+        lambda b=b: b.state_init(1, jnp.float32, 8))) - set(b.counters))
+            for b in model.modules[1:1 + LAYERS]] == [
+        ["conv"], ["k", "v"], ["conv"], ["conv"], ["conv"]]
     foot = G.cache_footprint(model, 2, 19, 5)
     assert foot["kv_cache_positions"] == T_cache
     # K and V of the ONE attention layer; per-head K/V in all five layers
@@ -292,12 +296,18 @@ def test_a_conv_layer_keeps_its_tail_and_nothing_else():
     assert long["recurrent_state_bytes"] == foot["recurrent_state_bytes"]
 
 
-def test_the_head_geometry_is_the_first_attention_layers():
+def test_the_head_geometry_is_each_attention_layers_own():
+    """No model-wide head geometry: the attention layer's state has its
+    K/V heads and head size, a layer without attention has no heads to
+    ask for and is never asked."""
     model = _model()
     blocks = model.modules[1:1 + LAYERS]
     assert not hasattr(blocks[0].modules[1], "num_heads")
-    assert G._head_geometry(blocks) == (4, HKV, DH)
-    assert G._head_geometry(blocks[2:]) == (None, None, None)
+    states = [jax.eval_shape(lambda b=b: b.state_init(3, jnp.float32, 64))
+              for b in blocks]
+    assert states[1]["k"].shape == states[1]["v"].shape == (3, HKV, 64, DH)
+    assert blocks[1].modules[1].num_heads == 4
+    assert all("k" not in s and "v" not in s for s in states[2:])
 
 
 # -- (d) the router ------------------------------------------------------
@@ -390,9 +400,9 @@ def test_operators_by_layer_a_tied_head_and_held_dtypes():
         assert isinstance(block.modules[1], want)
     assert isinstance(blocks[0].modules[3], GatedFFN)
     assert all(isinstance(b.modules[3], M.DroplessMoE) for b in blocks[1:])
-    assert [G._block_kind(b)[0] for b in blocks] == ["sequential"] * LAYERS
-    assert G._block_kind(blocks[0])[2] is None
-    assert G._block_kind(blocks[1])[2] is blocks[1].modules[3]
+    assert [type(b) for b in blocks] == [SequentialMoEBlock] * LAYERS
+    assert not blocks[0].is_moe and not blocks[0].counters
+    assert blocks[1].is_moe and blocks[1].moe is blocks[1].modules[3]
     # the head owns no leaf and the tree does not name it
     head = model.modules[-1]
     assert isinstance(head, LogitHead) and head.tied and model.tied_head
@@ -497,7 +507,7 @@ def test_an_int8_cache_quantises_the_attention_layers_kv_only():
     first, count = G._check_model(model)
     blocks = model.modules[first:first + count]
     for kind, block in zip(TYPES, blocks):
-        cache = G._cache_init(block, 2, 64, jnp.float32, kv_int8=True)
+        cache = block.state_init(2, jnp.float32, 64, True)
         cache.pop("moe_counts", None)
         if kind == "conv":
             assert {k: v.dtype for k, v in cache.items()} == {

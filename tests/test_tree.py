@@ -61,3 +61,37 @@ def test_one_v5e_peak():
     assert (spec.peak_flops_per_sec, spec.hbm_bytes,
             spec.hbm_bytes_per_sec) == (
         row["bf16_flops_per_s"], row["hbm_bytes"], row["hbm_bytes_per_s"])
+
+
+#: what ``models/generate.py`` and ``serving/server.py`` may not name: a
+#: block's or an operator's kind, a cache leaf of ONE architecture, a
+#: block's private child index — a layer's decode state lives with the
+#: layer (``nn/attention.py``, the decode-state protocol), and a new
+#: architecture edits neither file
+_KINDS = {"hybrid_mamba", "parallel_moe", "sequential_moe", "latent",
+          "short_conv", "hybrid", "parallel", "sequential", "gated",
+          "swiglu", "gelu"}
+_LEAVES = {"ckv", "kr", "ssm", "conv", "mhc_err", "mhc_sinkhorn_err"}
+
+
+@pytest.mark.parametrize("path", ["bigdl_tpu/models/generate.py",
+                                  "bigdl_tpu/serving/server.py"])
+def test_the_decoders_ask_the_layers_and_never_look(path):
+    import ast
+
+    with open(os.path.join(REPO, path)) as f:
+        src = f.read()
+    tree = ast.parse(src)
+    named = {n.value for n in ast.walk(tree)
+             if isinstance(n, ast.Constant) and isinstance(n.value, str)}
+    assert not named & (_KINDS | _LEAVES), sorted(named & (_KINDS | _LEAVES))
+    assert not {n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)
+                } & {"mlp_kind", "ffn_kind", "is_hybrid", "hyper", "mixer"}
+    assert not re.findall(r'\bbp\["\d"\]|\.modules\[\d', src)
+    if path.endswith("generate.py"):
+        # of models/ it imports nothing but the base the containers share
+        local = {a.name for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom) and (
+                     n.level == 1 or (n.module or "").startswith("models"))
+                 for a in n.names}
+        assert local <= {"CausalLM"}, local

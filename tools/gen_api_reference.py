@@ -98,6 +98,17 @@ def main():
             "  make the zeros they need, so a model that is only served, or",
             "  trained through an optimizer's compiled step, never pays",
             "  for them.",
+            "- **The decode-state protocol** (PR 45; `nn/attention.py`): what",
+            "  a layer keeps between tokens lives with the layer.  An",
+            "  operator answers `state_init` / `sequence` / `step`",
+            "  (`MultiHeadAttention`, `LatentAttention`, `Mamba2Mixer`,",
+            "  `GatedShortConv`), a block `state_init` / `advance`",
+            "  (`TransformerBlock`, `HybridMambaBlock`, `ParallelMoEBlock`,",
+            "  `SequentialMoEBlock`), and the four LMs share ONE",
+            "  `generate()` from `models.generate.CausalLM`;",
+            "  `models/generate.py` asks them and names no architecture",
+            "  (docs/serving.md, \"What a new architecture brings to be",
+            "  served\").",
             "- **`param_dtype`** (`models.hybrid_mamba.HybridMambaLM`): the",
             "  dtype the model HOLDS its floating parameters in.  The",
             "  constructor draws in it, `set_param_tree` casts each",
@@ -191,14 +202,14 @@ def main():
             "  window=None)`** (PR 41): the arm of a decode step's attend",
             "  on per-head K/V — one query a row against the un-repeated",
             "  `[B, Hkv, T, Dh]` leaves, every model above but the latent",
-            "  one; the decode step (`generate._decode_machinery`) and",
+            "  one; the decode step (`nn.MultiHeadAttention.step`) and",
             "  `cache_footprint` read the one rule.  Where `attend_plan`",
             "  says so (a TPU, `Tq == 1`, a cache contiguous from position",
             "  0 and no ring, K/V stored floating, `T` a whole number of",
             "  128-position blocks, a head of 64 or of whole lane tiles,",
             "  and a layer's K + V of `KERNEL_MIN_CACHE_BYTES` or more) ONE",
             "  Pallas kernel walks the cache up to the block `pos` falls",
-            "  in; elsewhere `generate._gqa_attend`, the plain einsums and",
+            "  in; elsewhere `ops.gqa_attend.gqa_attend_reference`, the plain einsums and",
             "  the kernel's reference.  `cache_footprint` / `serve.dispatch`",
             "  say which: `kv_attend`, `kv_attend_block`.",
             "- **`parallel.moe.route_top_k(..., select_bias=None,",

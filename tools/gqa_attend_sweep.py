@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """The attend of a per-head K/V decode step, alone, on the chip: the
-plain grouped-query einsums of ``models/generate.py::_gqa_attend`` (two
+plain grouped-query einsums of ``ops.gqa_attend.gqa_attend_reference`` (two
 reads of the whole cache) against the Pallas kernel of
 ``bigdl_tpu/ops/gqa_attend.py`` (one read of the written part), at the
 shapes the serving cells run — ONE query token a row against ``[B, Hkv,
@@ -66,7 +66,7 @@ def main() -> int:
     from jax.experimental.layout import Layout, with_layout_constraint
 
     from benchmark import counts
-    from bigdl_tpu.models.generate import _gqa_attend
+    from bigdl_tpu.ops.gqa_attend import gqa_attend_reference as _gqa_attend
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
