@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..nn.mamba import scaled as _scaled
+from ..telemetry.registry import default_registry
 
 # compiled generators per model instance (weak: dies with the model),
 # keyed by build config.  NOT stored on the module itself — a jitted
@@ -180,6 +181,27 @@ def _cast_params(p, compute_dtype):
 
 
 
+#: tokens one pass of the prompt may hold: a bucket's prompt of more
+#: goes through the blocks in groups of rows (``prefill_groups``)
+PREFILL_TOKENS = 65536
+
+
+def prefill_groups(batch: int, prompt_len: int) -> int:
+    """Groups of rows a prompt of ``batch`` x ``prompt_len`` tokens goes
+    through the blocks in: 1 within ``PREFILL_TOKENS``, else ``batch`` /
+    the largest power of two of rows that divides the batch and holds
+    at most that many tokens (one row a group where a single row is
+    longer: what grows with ONE row's length is not cut here).  From
+    shapes alone — the rule the program reads, and ``cache_footprint``."""
+    if batch * prompt_len <= PREFILL_TOKENS:
+        return 1
+    rows = 1
+    while (batch % (2 * rows) == 0
+           and 2 * rows * prompt_len <= PREFILL_TOKENS):
+        rows *= 2
+    return batch // rows
+
+
 def _cache_len(T_max, T0, max_new):
     """Positions the static K/V cache of one generate program holds:
     ``T0 + max_new`` rounded up to a multiple of 128 (the cache's
@@ -204,7 +226,8 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     (``block.footprint``), summed by kind: ``kv_cache_bytes`` (the K/V
     of every layer THAT KEEPS ONE: what is allocated, and what every
     decode step reads; also by kind of layer, ``kv_cache_bytes_window``
-    and ``kv_cache_bytes_full``, where layers differ in what they see),
+    and ``kv_cache_bytes_full`` — the attention operator's own split,
+    ``MultiHeadAttention.footprint``: it has a window or it has not),
     ``latent_cache_bytes`` (a latent layer's; its ``kv_cache_bytes`` is
     0) and ``recurrent_state_bytes`` (SSM state and conv tail — a
     short-convolution layer's whole state; zero for a model without
@@ -215,15 +238,17 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     the layers that take the kernel, where some do (a ring keeps the
     einsums) — and, for a model with experts, ``grouped``,
     ``grouped_tiles`` and ``grouped_tiles_down``
-    (``DroplessMoE.decode_plan``).  Each is the rule the step itself
-    reads."""
+    (``DroplessMoE.decode_plan``).  ``prefill_groups``: the groups of
+    rows the prompt pass goes in (:func:`prefill_groups`; 1: whole).
+    Each is the rule the program itself reads."""
     first, count = _check_model(model)
     T_cache = _cache_len(_check_len(model, max_len), int(prompt_len),
                          int(max_new))
     dt = jnp.dtype(compute_dtype or jax.tree_util.tree_leaves(
         model.param_tree())[0].dtype)
     out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
-           "kv_cache_positions": T_cache}
+           "kv_cache_positions": T_cache,
+           "prefill_groups": prefill_groups(int(batch), int(prompt_len))}
     for block in model.modules[first:first + count]:
         for name, v in block.footprint(int(batch), dt, T_cache,
                                        _kv_int8(kv_dtype)).items():
@@ -269,6 +294,15 @@ def _ends(model, first, count):
     return embed_at, logits_last
 
 
+def _leaves_without_rows(block, dtype, length, int8=False) -> set:
+    """The leaves of ``block``'s decode state that have no batch axis (a
+    counter a layer): those whose shape two batch sizes do not tell
+    apart."""
+    one, two = (jax.eval_shape(lambda b=b: block.state_init(
+        b, dtype, length, int8)) for b in (1, 2))
+    return {leaf for leaf in one if one[leaf].shape == two[leaf].shape}
+
+
 def _decode_machinery(model, first, count, kv_int8=False):
     """The cached forward shared by the sampling decoder and beam search
     — built once per generator from the model structure.  Every
@@ -283,18 +317,55 @@ def _decode_machinery(model, first, count, kv_int8=False):
                                 for b in blocks])
     embed_at, logits_last = _ends(model, first, count)
 
-    def prefill(pc, prompt, dt, T_cache):
-        """The whole prompt in one causal pass; returns (h [B,T0,D] —
-        or whatever state the blocks hand each other — and caches) of
-        ``T_cache`` positions with [0, T0) filled."""
+    def prefill_rows(pc, prompt, dt, T_cache, carried=None):
+        """``prompt``'s rows in one causal pass; ``carried``: a layer's
+        leaves that have no batch axis, as the rows before left them."""
         B, T0 = prompt.shape
         h = embed_at(pc, prompt, 0, T0)
         caches = []
         for bi, block in enumerate(blocks):
             cache = block.state_init(B, dt, T_cache, kv_int8)
+            if carried:
+                cache.update(carried[bi])
             h, cache = block.advance(pc[str(first + bi)], h, cache, 0)
             caches.append(cache)
         return h, caches
+
+    def prefill(pc, prompt, dt, T_cache, whole=False):
+        """The whole prompt in causal passes; returns (h [B,T0,D] — or
+        whatever state the blocks hand each other; of the LAST position
+        alone where the rows went in groups — and caches) of
+        ``T_cache`` positions with [0, T0) filled.  A prompt of more
+        than ``PREFILL_TOKENS`` tokens goes through the blocks in
+        groups of rows (:func:`prefill_groups`; a Python loop, so that
+        a generate program keeps ONE ``while``, its decode scan): what
+        a pass holds beside the weights — q, the K and V repeated to
+        the query heads, the dispatch buffers — grows with its tokens.
+        Each group's caches land in the batch's by rows; a leaf without
+        a batch axis (a counter a layer) is carried from group to
+        group as it is from step to step.  ``whole``: one pass whatever
+        its size (``capacity_bind_report`` asks what a capacity sized
+        for ALL the batch's tokens would drop)."""
+        B, T0 = prompt.shape
+        groups = 1 if whole else prefill_groups(B, T0)
+        if groups == 1:
+            return prefill_rows(pc, prompt, dt, T_cache)
+        g = B // groups
+        shared = [_leaves_without_rows(block, dt, T_cache, kv_int8)
+                  for block in blocks]
+        parts, last, carried = [], [], None
+        for lo in range(0, B, g):
+            with jax.named_scope("generate.prefill_group"):
+                h, caches = prefill_rows(pc, prompt[lo:lo + g], dt, T_cache,
+                                         carried)
+            last.append(h[:, -1:])
+            parts.append(caches)
+            carried = [{n: c[n] for n in names}
+                       for c, names in zip(caches, shared)]
+        caches = [{n: (parts[-1][bi][n] if n in shared[bi] else
+                       jnp.concatenate([p[bi][n] for p in parts]))
+                   for n in parts[0][bi]} for bi in range(len(blocks))]
+        return jnp.concatenate(last), caches
 
     def decode_token(pc, tok, caches, pos):
         """One token [B, 1] at absolute position ``pos``; returns
@@ -506,6 +577,11 @@ def make_generate(model, max_len: Optional[int] = None,
         nucleus = bool(not greedy and 0 < top_p < 1)
         prompt = jnp.asarray(prompt_ids, jnp.int32)
         shape = prompt.shape + (int(max_new),)
+        default_registry().counter(
+            "bigdl_generate_prefill_groups_total",
+            "groups of rows that generate calls' prompt passes went "
+            "through the blocks in (1 a call whose prompt went whole)"
+        ).inc(prefill_groups(*prompt.shape))
         program = (_compiled_ahead(shape)
                    if greedy and rng is None else None)
         if program is not None:
@@ -560,9 +636,7 @@ def make_beam_search(model, max_len: Optional[int] = None,
     first, count = _check_model(model)
     T_max = _check_len(model, max_len)
     for block in model.modules[first:first + count]:
-        one, two = (jax.eval_shape(lambda b=b: block.state_init(
-            b, jnp.float32, 128)) for b in (1, 2))
-        if any(one[leaf].shape == two[leaf].shape for leaf in one):
+        if _leaves_without_rows(block, jnp.float32, 128):
             raise _refusal("beam search gathers every cache leaf along the "
                            "beam axis", block)
     prefill, decode_token, logits_last = _decode_machinery(
@@ -1073,7 +1147,7 @@ def capacity_bind_report(model, params, ids):
             BIND_TLS.capture = []
             try:
                 dt = jax.tree_util.tree_leaves(p)[0].dtype
-                prefill(p, toks, dt, T)
+                prefill(p, toks, dt, T, whole=True)
                 fracs = list(BIND_TLS.capture)
             finally:
                 BIND_TLS.capture = None

@@ -46,6 +46,13 @@ operator and FFN lists from its configuration's own numbers:
   here with YaRN and a value head narrower than the query's, the same
   router — around a residual of ``hc_mult`` streams mixed per token by
   manifold-constrained hyper-connections, an untied head.
+* :class:`PreRoutedMoELM` — PowerInfer's ``smallthinker``
+  (SmallThinker-21BA3B-Instruct): grouped-query attention that is
+  position-free and global in one layer of a published layout and
+  rotated under a sliding window in the others, an expert layer in
+  EVERY block whose router reads the block's INPUT — before the first
+  norm, before attention (``pre_routed``) — a softmax over the chosen
+  logits, ReLU-gated experts, an untied head.
 
 A ``Container`` with ``TransformerLM``'s child layout — ``0`` the
 embedding, ``1..L`` the blocks (children ``0`` RMSNorm, ``1`` the
@@ -206,11 +213,18 @@ class SequentialMoEBlock(Container):
     maps (:func:`sublayer_input` / :func:`sublayer_result`, which
     ``apply_fn`` and ``advance`` both call, for the plain residual
     too).  Without it the block is what it was, program and parameter
-    tree."""
+    tree.
+
+    ``pre_routed``: the expert layer's ROUTER multiplies the block's
+    input ``x`` — un-normed, before the operator — while its experts
+    read ``norm_2 h`` as ever: ``x`` rides across the operator sublayer
+    to ``DroplessMoE.routed(..., scores_from=x)``, in ``apply_fn`` and
+    ``advance`` alike."""
 
     def __init__(self, operator, ffn, embed_dim: int, norm_eps: float,
                  param_dtype: Optional[str] = None,
-                 hyper: Optional[Callable] = None):
+                 hyper: Optional[Callable] = None,
+                 pre_routed: bool = False):
         children = [nn.RMSNorm(embed_dim, eps=norm_eps), operator,
                     nn.RMSNorm(embed_dim, eps=norm_eps), ffn]
         if hyper is not None:
@@ -220,6 +234,14 @@ class SequentialMoEBlock(Container):
         self.hyper = tuple(self.modules[4:6]) if hyper is not None else None
         self.ffn_kind = "moe" if isinstance(ffn, DroplessMoE) else "dense"
         self.is_moe = self.ffn_kind == "moe"
+        #: the router reads the block's INPUT, not the experts'
+        self.pre_routed = bool(pre_routed)
+        if self.pre_routed and (self.hyper or not self.is_moe):
+            raise ValueError(
+                "pre_routed: the router of an expert layer reads the "
+                "block's input under the plain residual (no published "
+                "model routes ahead of a hyper-connection, and a dense "
+                "FFN has no router)")
 
     @property
     def moe(self) -> DroplessMoE:
@@ -240,12 +262,20 @@ class SequentialMoEBlock(Container):
         return self.hyper[0].n_streams if self.hyper else 0
 
     def apply_fn(self, params, buffers, x, training, rng):
+        block_input = x
         for i in (0, 1):        # the operator, then the FFN
             n, co = sublayer_input(self, params, i, x)
-            with self.operator_scope() if i == 0 else nullcontext():
-                y, _ = self.modules[2 * i + 1].apply_fn(
-                    params[str(2 * i + 1)], buffers[str(2 * i + 1)], n,
-                    training, None)
+            if i == 1 and getattr(self, "pre_routed", False):
+                D = n.shape[-1]
+                y, _ = self.moe.routed(
+                    params["3"], n.reshape(-1, D),
+                    scores_from=block_input.reshape(-1, D))
+                y = y.reshape(n.shape)
+            else:
+                with self.operator_scope() if i == 0 else nullcontext():
+                    y, _ = self.modules[2 * i + 1].apply_fn(
+                        params[str(2 * i + 1)], buffers[str(2 * i + 1)], n,
+                        training, None)
             x = sublayer_result(self, i, x, y, co)
         return x, buffers
 
@@ -262,6 +292,11 @@ class SequentialMoEBlock(Container):
             return (f"{type(self.hyper[0]).__name__} makes the residual of "
                     f"{type(self).__name__} {self.streams} streams a token, "
                     "with a counter a layer that has no batch axis")
+        if getattr(self, "pre_routed", False):
+            return ("its layers differ in what they see (a window's ring "
+                    "beside a position-free full layer) and its router "
+                    "reads the block's input, whose counts it keeps beside "
+                    "the K/V")
         op = self.modules[1]
         return (f"{type(op).__name__} "
                 + getattr(op, "state_doc", "keeps a state of its own"))
@@ -292,6 +327,7 @@ class SequentialMoEBlock(Container):
         """ONE form for every operator, every FFN and both residuals:
         what a sublayer reads of ``h`` and how its result goes back are
         :func:`sublayer_input` / :func:`sublayer_result`'s."""
+        block_input = h
         x, co = sublayer_input(self, params, 0, h)
         with self.operator_scope():
             a, wrote = advance(self.modules[1], params["1"], x, state, pos)
@@ -300,8 +336,11 @@ class SequentialMoEBlock(Container):
         x, co2 = sublayer_input(self, params, 1, h)
         if self.is_moe:
             B, Tq, D = x.shape
-            y, counts = self.moe.routed(params["3"], x.reshape(B * Tq, D),
-                                        batch=B)
+            y, counts = self.moe.routed(
+                params["3"], x.reshape(B * Tq, D), batch=B,
+                scores_from=(block_input.reshape(B * Tq, D)
+                             if getattr(self, "pre_routed", False)
+                             else None))
             h = sublayer_result(self, 1, h, y.reshape(B, Tq, D), co2)
             state["moe_counts"] = state["moe_counts"] + counts
         else:
@@ -325,8 +364,10 @@ class SequentialMoELM(TiedHeadTrees, CausalLM, Container):
     ``operators`` and ``ffns`` are one zero-argument FACTORY a layer
     each: a layer's modules are made inside the device draw and cast to
     ``param_dtype`` child by child, so neither a block nor the model is
-    ever whole in float32.  ``hyper`` (a factory of a
-    ``HyperConnection``) gives EVERY block a hyper-connected residual: the embedding is
+    ever whole in float32.  ``pre_routed``: every block's router reads
+    the block's input (:class:`SequentialMoEBlock`).  ``hyper`` (a
+    factory of a ``HyperConnection``) gives EVERY block a
+    hyper-connected residual: the embedding is
     repeated into the streams and they are summed before the final
     norm.  ``draw_weights=False`` builds the model WITHOUT drawing: every
     matrix zeros, for a caller that sets the weights next (a checkpoint,
@@ -338,7 +379,7 @@ class SequentialMoELM(TiedHeadTrees, CausalLM, Container):
                  norm_eps: float = 1e-5, output: str = "log_probs",
                  init_std: float = 0.02, param_dtype: Optional[str] = None,
                  hyper: Optional[Callable] = None,
-                 draw_weights: bool = True):
+                 draw_weights: bool = True, pre_routed: bool = False):
         if output not in ("log_probs", "logits"):
             raise ValueError(f"output {output!r} not in (log_probs, logits)")
         if len(operators) != len(ffns):
@@ -361,7 +402,8 @@ class SequentialMoELM(TiedHeadTrees, CausalLM, Container):
             for make_operator, make_ffn in zip(operators, ffns):
                 self.add(SequentialMoEBlock(make_operator(), make_ffn(),
                                             embed_dim, norm_eps,
-                                            self.param_dtype, hyper))
+                                            self.param_dtype, hyper,
+                                            pre_routed))
             self.add(_held_in(nn.RMSNorm(embed_dim, eps=norm_eps),
                               self.param_dtype))
             self.add(_held_in(LogitHead(embed_dim, vocab_size, init_std,
@@ -531,3 +573,58 @@ class ShortConvMoELM(SequentialMoELM):
             tied_head=True, max_len=max_len, norm_eps=norm_eps,
             output=output, init_std=init_std, param_dtype=param_dtype)
         self.layer_types = tuple(layer_types)
+
+
+class PreRoutedMoELM(SequentialMoELM):
+    """``smallthinker``: layer ``i``'s operator is grouped-query
+    attention without biases or QK-norm, rotated by halves where
+    ``rope_layout[i]`` and seeing the last ``window`` keys where
+    ``window_layout[i]`` (a layer with neither is global and has NO
+    positions at all); EVERY block's FFN is an expert layer whose
+    router reads the block's input (``pre_routed``) — a softmax over
+    the ``top_k`` chosen logits, which ``scoring="softmax"`` with
+    ``renormalize`` is term for term — over ReLU-gated experts, no
+    shared expert, no selection bias; an untied head."""
+
+    def __init__(self, vocab_size: int, embed_dim: int, num_heads: int,
+                 num_kv_heads: int, head_dim: int, expert_dim: int,
+                 rope_layout: Sequence[int], window_layout: Sequence[int],
+                 window: int, n_experts: int, top_k: int,
+                 held: Optional[Sequence[int]] = None, max_len: int = 2048,
+                 rope_theta: float = 1500000.0, norm_eps: float = 1e-6,
+                 seq_strategy: str = "dense", output: str = "log_probs",
+                 init_std: float = 0.02, param_dtype: Optional[str] = None,
+                 draw_weights: bool = True):
+        if len(rope_layout) != len(window_layout):
+            raise ValueError(f"rope_layout names {len(rope_layout)} layers, "
+                             f"window_layout {len(window_layout)}")
+
+        def attention(rotated, windowed):
+            def make():
+                mha = nn.MultiHeadAttention(
+                    embed_dim, num_heads, causal=True, with_bias=False,
+                    seq_strategy=seq_strategy, num_kv_heads=num_kv_heads,
+                    head_dim=head_dim, rope=True if rotated else None,
+                    rope_theta=rope_theta,
+                    window=window if windowed else None)
+                mha.set_init_method(RandomNormal(0.0, init_std))
+                return mha.reset()
+            return make
+
+        def experts():
+            return DroplessMoE(
+                embed_dim, expert_dim, n_experts, top_k=top_k,
+                scoring="softmax", renormalize=True,
+                held=tuple(held) if held is not None else None,
+                init_std=init_std, activation="relu")
+
+        super().__init__(
+            vocab_size, embed_dim,
+            [attention(r, w) for r, w in zip(rope_layout, window_layout)],
+            [experts] * len(rope_layout), max_len=max_len,
+            norm_eps=norm_eps, output=output, init_std=init_std,
+            param_dtype=param_dtype, draw_weights=draw_weights,
+            pre_routed=True)
+        self.rope_layout = tuple(int(r) for r in rope_layout)
+        self.window_layout = tuple(int(w) for w in window_layout)
+        self.window = int(window)

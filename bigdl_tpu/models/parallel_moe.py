@@ -174,13 +174,11 @@ class ParallelMoEBlock(Container):
                                         jnp.int32)}
 
     def footprint(self, batch: int, dtype, length: int, int8: bool = False):
-        """The attention's, its K/V also by KIND of layer (a sliding
-        layer keeps ``min(positions, window)``), and the experts'."""
-        kv = self.modules[1].footprint(batch, dtype, length, int8)
-        sliding = self.attention == "sliding"
-        return {**kv,
-                "kv_cache_bytes_window": kv["kv_cache_bytes"] * sliding,
-                "kv_cache_bytes_full": kv["kv_cache_bytes"] * (not sliding),
+        """The attention's — its K/V also by KIND of layer, which the
+        operator itself says (``MultiHeadAttention.footprint``: a
+        sliding layer keeps ``min(positions, window)``) — and the
+        experts'."""
+        return {**self.modules[1].footprint(batch, dtype, length, int8),
                 **self.moe.decode_plan(batch, dtype)}
 
     def advance(self, params, h, state, pos):

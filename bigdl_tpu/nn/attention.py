@@ -443,9 +443,12 @@ class MultiHeadAttention(TensorModule):
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
     def footprint(self, batch: int, dtype, length: int, int8: bool = False):
-        """``kv_cache_bytes`` as allocated, and the arm a step of this
-        layer compiles: ``kv_attend`` ``("kernel" | "einsum", positions
-        a block of the kernel's walk)`` — the rule the step reads."""
+        """``kv_cache_bytes`` as allocated — again by the layer's KIND,
+        ``kv_cache_bytes_window`` (it has a ``window``, whether or not
+        its cache is a ring at this length) or ``kv_cache_bytes_full``
+        — and the arm a step of this layer compiles: ``kv_attend``
+        ``("kernel" | "einsum", positions a block of the kernel's
+        walk)`` — the rule the step reads."""
         from ..ops.gqa_attend import attend_plan
 
         shapes = jax.eval_shape(
@@ -453,7 +456,11 @@ class MultiHeadAttention(TensorModule):
         k = shapes["k"]
         block = attend_plan(batch, k.shape[1], k.shape[2], k.shape[3],
                             k.dtype, 1, self._ring(k))
-        return {"kv_cache_bytes": state_bytes(shapes),
+        held = state_bytes(shapes)
+        sliding = bool(getattr(self, "window", None))
+        return {"kv_cache_bytes": held,
+                "kv_cache_bytes_window": held * sliding,
+                "kv_cache_bytes_full": held * (not sliding),
                 "kv_attend": ("kernel" if block else "einsum", block)}
 
     def _ring(self, k):

@@ -562,14 +562,21 @@ def row_experts(sizes, rows: int):
                                         side="right"), sizes.shape[0] - 1)
 
 
-def swiglu_experts(w_gate, w_up, w_down):
-    """``expert_fn`` of gated experts: ``down(silu(gate(x)) * up(x))``
+#: the gate's non-linearity in a gated expert: SwiGLU's, or ReGLU's
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def swiglu_experts(w_gate, w_up, w_down, activation: str = "silu"):
+    """``expert_fn`` of gated experts: ``down(act(gate(x)) * up(x))``
     with ``w_gate`` / ``w_up`` [E, D, F] and ``w_down`` [E, F, D] — three
-    grouped products and the gate."""
+    grouped products and the gate; ``activation`` one of
+    :data:`GATE_ACTIVATIONS` (``"silu"``: SwiGLU)."""
+    act = GATE_ACTIVATIONS[activation]
+
     def fn(xs, sizes):
         g = grouped_matmul(xs, w_gate, sizes)
         u = grouped_matmul(xs, w_up, sizes)
-        return grouped_matmul(jax.nn.silu(g) * u, w_down, sizes)
+        return grouped_matmul(act(g) * u, w_down, sizes)
 
     return fn
 
@@ -585,7 +592,8 @@ class DroplessMoE(TensorModule):
     all): the layer computes the part of the mixture its own experts
     give, and what absent experts would add is left out — the layer
     expert parallelism needs, run without its exchange.  Experts are
-    SwiGLU MLPs ``embed -> hidden -> embed`` without biases, stored
+    gated MLPs ``embed -> hidden -> embed`` without biases
+    (``activation``: the gate's ``"silu"``, SwiGLU, or ``"relu"``), stored
     ``w_gate`` / ``w_up`` [count, embed, hidden] and ``w_down`` [count,
     hidden, embed], drawn ``normal(0, init_std)`` unless an init method
     is set.  ``n_shared`` shared experts of the same shape see
@@ -604,15 +612,22 @@ class DroplessMoE(TensorModule):
     ``apply_fn`` is plain differentiable jax off the TPU.
 
     ``routed(params, x2)`` returns the per-expert assignment counts
-    beside the result, for the decode scan's counters."""
+    beside the result, for the decode scan's counters; its
+    ``scores_from`` is what the ROUTER multiplies where that is another
+    tensor than the experts' input (a router placed before the block's
+    attention reads the block's input)."""
 
     def __init__(self, embed_dim: int, hidden_dim: int, n_experts: int,
                  top_k: int = 2, scoring: str = "softmax",
                  renormalize: bool = True, n_shared: int = 0,
                  held: Optional[tuple] = None, init_std: float = 0.02,
                  score_bias: bool = False, routed_scale: float = 1.0,
-                 renorm_eps: float = 1e-20):
+                 renorm_eps: float = 1e-20, activation: str = "silu"):
         super().__init__()
+        if activation not in GATE_ACTIVATIONS:
+            raise ValueError(f"activation {activation!r} not in "
+                             f"{tuple(GATE_ACTIVATIONS)}")
+        self.activation = activation
         self.renorm_eps = float(renorm_eps)
         self.init_std = float(init_std)
         self.score_bias = bool(score_bias)
@@ -666,23 +681,28 @@ class DroplessMoE(TensorModule):
         with jax.named_scope("moe.shared"):
             dt = x2.dtype
             ct = jnp.promote_types(dt, jnp.float32)
+            act = GATE_ACTIVATIONS[getattr(self, "activation", "silu")]
             y = 0.0
             for s in range(self.n_shared):
                 g = jnp.dot(x2, params["shared_gate"][s].astype(dt))
                 u = jnp.dot(x2, params["shared_up"][s].astype(dt))
-                y = y + jnp.dot(jax.nn.silu(g) * u,
+                y = y + jnp.dot(act(g) * u,
                                 params["shared_down"][s].astype(dt),
                                 preferred_element_type=ct)
             return (y / self.n_shared).astype(dt)
 
-    def routed(self, params, x2, batch: Optional[int] = None):
+    def routed(self, params, x2, batch: Optional[int] = None,
+               scores_from=None):
         """(the held experts' part plus the shared mean [N, D], the
         assignments each held expert took: [count] int32, or [batch,
-        count] — by leading row of the ``batch`` the tokens came in)."""
+        count] — by leading row of the ``batch`` the tokens came in).
+        ``scores_from`` [N, D]: what the router multiplies (None: ``x2``,
+        the experts' own input)."""
         # a layer names ``renorm_eps`` only where it departs from the
         # default: every other layer's call is the one it always made
         eps = getattr(self, "renorm_eps", 1e-20)
-        gates, idx = route_top_k(x2, params["router_w"], None, self.top_k,
+        gates, idx = route_top_k(x2 if scores_from is None else scores_from,
+                                 params["router_w"], None, self.top_k,
                                  self.scoring, self.renormalize,
                                  params.get("score_bias"),
                                  self.routed_scale,
@@ -691,7 +711,8 @@ class DroplessMoE(TensorModule):
         y, sizes = dropless_apply(
             x2, idx, gates, self.held,
             swiglu_experts(params["w_gate"], params["w_up"],
-                           params["w_down"]))
+                           params["w_down"],
+                           getattr(self, "activation", "silu")))
         if self.n_shared:
             y = y + self.shared(params, x2)
         if batch is not None:

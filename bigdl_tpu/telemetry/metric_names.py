@@ -58,6 +58,12 @@ INFEED_BUFFER_MISSES_TOTAL = "bigdl_infeed_buffer_misses_total"
 #: booked where the work happens
 INIT_DRAW_SECONDS_TOTAL = "bigdl_init_draw_seconds_total"
 
+# --- generation (models/generate.py) --------------------------------------
+#: groups of rows that generate calls' prompt passes went through the
+#: blocks in (``generate.prefill_groups``: 1 a call whose prompt went
+#: whole, more where a bucket's prompt passes ``PREFILL_TOKENS``)
+GENERATE_PREFILL_GROUPS_TOTAL = "bigdl_generate_prefill_groups_total"
+
 # --- performance accounting (telemetry/perf.py, parallel/plan.py) --------
 PERF_FLOPS_PER_STEP = "bigdl_perf_flops_per_step"
 PERF_BYTES_PER_STEP = "bigdl_perf_bytes_per_step"

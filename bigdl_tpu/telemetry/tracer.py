@@ -183,8 +183,8 @@ PROGRAM_SPANS = {
 #: whichever arm ``seq_strategy`` picks; projections and rotation are
 #: outside it.
 DEVICE_SCOPES = (
-    "generate.cast_params", "generate.prefill", "generate.decode_step",
-    "generate.sample",
+    "generate.cast_params", "generate.prefill", "generate.prefill_group",
+    "generate.decode_step", "generate.sample",
     "mixer.in_proj", "mixer.conv", "mixer.ssd_scan", "mixer.ssm_step",
     "mixer.gate_norm", "mixer.out_proj", "mixer.attention",
     "moe.route", "moe.dispatch", "moe.expert_matmul", "moe.combine",

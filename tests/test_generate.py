@@ -624,6 +624,10 @@ def test_cache_footprint_counts_the_allocated_cache(t0, max_new, max_len,
     assert got == {
         "kv_cache_positions": want, "recurrent_state_bytes": 0,
         "kv_cache_bytes": LAYERS * 2 * 3 * 2 * want * (EMBED // 4) * 4,
+        # by kind of layer (PR 46): no layer has a window
+        "kv_cache_bytes_window": 0,
+        "kv_cache_bytes_full": LAYERS * 2 * 3 * 2 * want * (EMBED // 4) * 4,
+        "prefill_groups": 1,                            # the prompt whole
         "kv_attend": "einsum", "kv_attend_block": 0}    # the CPU's arm
     q8 = cache_footprint(model, 3, t0, max_new, kv_dtype="int8")
     assert q8["kv_cache_bytes"] == LAYERS * 2 * 3 * 2 * want * (4 + 4)
@@ -683,6 +687,7 @@ def test_a_new_operator_is_served_with_no_edit_to_the_package():
     # all it keeps is a state that does not grow with the context
     foot = cache_footprint(model, 3, 5, 9)
     assert foot == {"kv_cache_positions": TMAX, "kv_cache_bytes": 0,
+                    "prefill_groups": 1,
                     "recurrent_state_bytes": LAYERS * 3 * EMBED * 4}
     pool = KVPagePool(num_pages=8, page_size=4, layers=LAYERS,
                       num_kv_heads=2, head_dim=8)

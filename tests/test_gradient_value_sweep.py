@@ -347,6 +347,16 @@ MODULE_CASES = {
         DroplessMoE(8, 12, 6, top_k=2, scoring="sigmoid", init_std=0.3,
                     score_bias=True, renorm_eps=1e-6), 8, 1e-5),
         lambda: X8, {}),
+    # the pre-routed block: the router reads the block's INPUT, a
+    # softmax over the chosen logits, a ReLU gate, attention under a
+    # sliding window of 3
+    "PreRoutedMoEBlock": (lambda: LatentMoEBlock(
+        nn.MultiHeadAttention(8, 2, causal=True, with_bias=False,
+                              num_kv_heads=1, head_dim=4, rope=True,
+                              window=3),
+        DroplessMoE(8, 12, 6, top_k=2, scoring="softmax", init_std=0.3,
+                    activation="relu"), 8, 1e-6, pre_routed=True),
+        lambda: X8, {}),
     # a hyper-connected residual (three streams a token) around latent
     # attention with YaRN and the expert layer: autodiff through the
     # sigmoids, ``exp`` and the unrolled Sinkhorn sweeps of both

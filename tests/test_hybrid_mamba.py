@@ -403,8 +403,9 @@ def test_device_scopes_name_the_lowered_operations():
     for scope in DEVICE_SCOPES:
         # this model's: ``moe.*`` / ``block.*`` are the parallel expert
         # block's (tests/test_command_a_plus.py)
-        if scope.startswith(("generate.", "mixer.")) \
-                and scope != "generate.cast_params":    # f32 held: no cast
+        if scope.startswith(("generate.", "mixer.")) and scope not in (
+                "generate.cast_params",         # f32 held: no cast
+                "generate.prefill_group"):      # the prompt went whole
             assert scope + "/" in text or scope + '"' in text, scope
     # the chunked scan runs in the prefill, the one-token step in the loop
     assert "generate.prefill/mixer.ssd_scan" in text
@@ -429,6 +430,9 @@ def test_dispatch_span_says_what_the_bucket_holds():
     got = cache_footprint(model, 4, 11, 3, compute_dtype=jnp.float32)
     assert got == {"kv_cache_bytes": kv(48), "recurrent_state_bytes": rec,
                    "kv_cache_positions": 48,
+                   # by kind of layer and the prompt pass's groups (PR 46)
+                   "kv_cache_bytes_window": 0, "kv_cache_bytes_full": kv(48),
+                   "prefill_groups": 1,
                    "kv_attend": "einsum", "kv_attend_block": 0}
     # the same call on a long table holds 128 positions, not 640, and
     # the recurrent state does not care
@@ -436,6 +440,8 @@ def test_dispatch_span_says_what_the_bucket_holds():
     got = cache_footprint(long, 4, 11, 3, compute_dtype=jnp.float32)
     assert got == {"kv_cache_bytes": kv(128), "recurrent_state_bytes": rec,
                    "kv_cache_positions": 128,
+                   "kv_cache_bytes_window": 0, "kv_cache_bytes_full": kv(128),
+                   "prefill_groups": 1,
                    "kv_attend": "einsum", "kv_attend_block": 0}
     dense = TransformerLM(23, embed_dim=16, num_heads=2, mlp_dim=32,
                           num_layers=2, max_len=24)

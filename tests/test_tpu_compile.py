@@ -247,6 +247,57 @@ def test_xing4_decode_step_names_its_hyper_connections(one_chip, monkeypatch):
                    for n in names)
 
 
+def test_smallthinker_32_row_bucket_fits_a_v5e_with_its_prompt_in_groups(
+        one_chip, monkeypatch):
+    """The SmallThinker cell's largest program — 32 rows, a prompt of
+    4608 and 256 new tokens, every width, head count, the window and the
+    whole vocabulary as published — compiled for the described chip with
+    the TPU's branches taken: the prompt pass goes in 4 groups of 8 rows
+    (147 456 tokens against ``PREFILL_TOKENS``), the program keeps ONE
+    ``while`` and its arguments and temporaries stay under 13 GB.  To
+    spare this host 4.2 GB of zeros, 8 of the 64 experts a layer are
+    held here (the router keeps its 64 outputs and 6 a token, so every
+    buffer of the program has the cell's shape) and the 56 left out are
+    added to the arguments by arithmetic."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.models import generate as G
+    from bigdl_tpu.models.latent_moe import PreRoutedMoELM
+
+    B, T0, new, held = 32, 4608, 256, 8
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "smallthinker-21b-a3b-l4.json")) as f:
+        kw = json.load(f)["program"]["kwargs"]
+    model = PreRoutedMoELM(**{**kw, "held": [0, held],
+                              "draw_weights": False})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fp = G.cache_footprint(model, B, T0, new, compute_dtype=jnp.bfloat16)
+    assert (fp["kv_cache_bytes_window"], fp["kv_cache_bytes_full"],
+            fp["kv_cache_positions"], fp["prefill_groups"]) == (
+        805_306_368, 318_767_104, 4864, 4)
+
+    def S(shape=(), dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    gen = G.make_generate(model, compute_dtype=jnp.bfloat16)
+    run = [c.cell_contents for c in gen.__closure__
+           if hasattr(c.cell_contents, "lower")][0]
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
+                                    model.param_tree())
+    compiled = run.lower(params, S((B, T0)), new, S((2,), jnp.uint32),
+                         S(dt=jnp.float32), 0, S(dt=jnp.float32), S(), S(),
+                         True, False).compile()
+    mem = compiled.memory_analysis()
+    left_out = (kw["n_experts"] - held) * len(kw["rope_layout"]) \
+        * 3 * kw["embed_dim"] * kw["expert_dim"] * 2
+    assert left_out == 2_642_411_520
+    assert (mem.argument_size_in_bytes + left_out
+            + mem.temp_size_in_bytes) < 13e9
+    assert len(re.findall(r" while\(", compiled.as_text())) == 1
+
+
 @pytest.mark.parametrize("config,cls,up,down", [
     ("lfm2-24b-a2b-l5", "ShortConvMoELM", "128x2048x1536",
      "128x1536x2048"),

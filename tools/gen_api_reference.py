@@ -184,6 +184,25 @@ def main():
             "  keep K/V, the tails are `recurrent_state_bytes`);",
             "  `kv_dtype=\"int8\"` quantises those K/V and leaves the tails;",
             "  `PagedDecoder` refuses the block by name.",
+            "- **`models.latent_moe.PreRoutedMoELM`** (PR 46;",
+            "  `smallthinker`, SmallThinker-21BA3B-Instruct): grouped-query",
+            "  attention without biases or QK-norm, rotated by halves where",
+            "  `rope_layout[i]` and under a sliding `window` where",
+            "  `window_layout[i]` (a layer with neither is global and has no",
+            "  positions), an expert layer in EVERY block whose router",
+            "  reads the block's INPUT (`SequentialMoEBlock(pre_routed=True)`",
+            "  -> `DroplessMoE.routed(..., scores_from=x)`), a softmax over",
+            "  the chosen logits (`scoring=\"softmax\"`, `renormalize`),",
+            "  ReLU-gated experts (`DroplessMoE(activation=\"relu\")`), no",
+            "  shared expert, an untied head.  `generate()` /",
+            "  `submit_generate` keep a ring of `window` positions for a",
+            "  window layer and every position for a global one",
+            "  (`cache_footprint`: `kv_cache_bytes_window` /",
+            "  `kv_cache_bytes_full`, from `MultiHeadAttention.footprint`);",
+            "  a bucket's prompt of more than 65 536 tokens goes through the",
+            "  blocks in groups of rows (`generate.prefill_groups`);",
+            "  `kv_dtype=\"int8\"` and beam search serve it, `PagedDecoder`",
+            "  refuses the block by name.",
             "- **`models.latent_moe.LatentMoELM`** (PR 36; the same",
             "  container since PR 40, its tree and its programs unchanged):",
             "  `LatentAttention` in every layer, a dense SwiGLU (`GatedFFN`)",
