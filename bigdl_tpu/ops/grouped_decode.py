@@ -1,7 +1,7 @@
 """The grouped matrix product of a decode step's expert layer — row
 ``r`` of group ``g`` times ``w[g]`` — as one Pallas TPU kernel in which
 every hit expert's matrix crosses HBM ONCE, as one contiguous copy of
-6-8 MB.
+3.9-8 MB.
 
 What a decode step holds (``parallel/moe.py``, ``_dropless_piece``): the
 sorted buffer ``xs [R, k]`` of at most 2048 rows, ``group_sizes [G]``
@@ -27,12 +27,14 @@ The kernel:
   bytes-once in two calls to the chip and 88.6 in a third.  PERF.md §6
   "PR 43".);
 - the row buffer is in VMEM whole, and a group's rows are walked in
-  chunks of ``CHUNK_ROWS`` from a prefetched offset rounded DOWN to a
-  sublane tile of the operand (16 rows of bfloat16): the MXU takes as
-  long over 16 rows as over 128 — the matrix passes through it either
-  way — so ONE product covers any group of up to ``CHUNK_ROWS - 15``
-  rows wherever it lies, and no group is visited twice for straddling a
-  row tile, as the 128-row tiles of megablox's ``gmm`` make it;
+  chunks of ``chunk`` rows — :func:`chunk_rows` of the buffer: 128
+  where that divides its rows, else 64 (SmallThinker's 192) — from a
+  prefetched offset rounded DOWN to a sublane tile of the operand (16
+  rows of bfloat16): the MXU takes as long over 16 rows as over 128 —
+  the matrix passes through it either way — so ONE product covers any
+  group of up to ``chunk - 15`` rows wherever it lies, and no group is
+  visited twice for straddling a row tile, as the 128-row tiles of
+  megablox's ``gmm`` make it;
 - the product's rows of OTHER groups (before the group's first row,
   past its last) are masked at the write, into the ``[R, n]`` output
   that stays in VMEM until the last group; rows past
@@ -42,9 +44,9 @@ The kernel:
   the whole depth, one cast at the write — on the chip, bit for bit
   what ``jax.lax.ragged_dot`` gives.
 
-``tools/moe_grouped_sweep.py`` times it against ``gmm`` at the four
-serving cells' shapes with uneven group sizes; PERF.md §6 "PR 43" has
-the table.
+``tools/moe_grouped_sweep.py`` times it against ``gmm`` and
+``ragged_dot`` at the five serving cells' shapes with uneven group
+sizes; PERF.md §6 "PR 43" and "PR 47" have the tables.
 """
 from __future__ import annotations
 
@@ -56,11 +58,14 @@ from jax import lax
 
 from ._support import pl, pltpu
 
-# rows of one product: a group of up to CHUNK_ROWS - 15 rows is one
-CHUNK_ROWS = 128
+# rows of one product, the first that divides the buffer's rows: a
+# group of up to chunk - 15 rows is one product.  The chip measured
+# chunks of 32 / 64 / 128 level while a matrix's copy hides the product
+# (PERF.md §6 "PR 43"); 128 makes the fewest
+CHUNKS = (128, 64)
 # the largest [k, n] matrix the kernel takes as one tile (the pipeline
-# holds two): LFM2's and GLM's 6 MB and Xing4.0's 7 MB are; Command A+'s
-# 32 MB are not, and keep ``gmm``
+# holds two): SmallThinker's 3.9 MB, LFM2's and GLM's 6 MB and Xing4.0's
+# 7 MB are; Command A+'s 32 MB are not, and keep ``gmm``
 WHOLE_BYTES = 8 << 20
 # what the kernel may hold in VMEM: half a v5e's 128 MiB, the rest is
 # the compiler's for what it keeps there between operations (its own
@@ -68,21 +73,28 @@ WHOLE_BYTES = 8 << 20
 VMEM_BYTES = 64 << 20
 
 
-def vmem_bytes(R: int, k: int, n: int, itemsize: int) -> int:
+def chunk_rows(R: int) -> int:
+    """Rows a product of a buffer of ``R`` rows: the first of ``CHUNKS``
+    that divides ``R``; 0 where none does (no plan for such a buffer)."""
+    return next((c for c in CHUNKS if R % c == 0), 0)
+
+
+def vmem_bytes(R: int, k: int, n: int, itemsize: int, chunk: int) -> int:
     """What a call holds in VMEM: the matrix twice, the row buffer and
     the output (the pipeline allots two of each, copies one) and a
     chunk's float32 product."""
     return (2 * k * n * itemsize + 2 * R * k * itemsize
-            + 2 * R * n * itemsize + CHUNK_ROWS * n * 4)
+            + 2 * R * n * itemsize + chunk * n * 4)
 
 
-def fits(R: int, k: int, n: int, itemsize: int) -> bool:
-    """Whether the kernel takes ``[R, k] x [G, k, n]``: whole chunks of
-    rows, whole lane tiles of columns, the matrix one tile and the call
-    within the kernel's VMEM."""
-    return (R % CHUNK_ROWS == 0 and n % 128 == 0
+def fits(R: int, k: int, n: int, itemsize: int, chunk: int) -> bool:
+    """Whether the kernel takes ``[R, k] x [G, k, n]`` in products of
+    ``chunk`` rows: whole chunks of rows, whole lane tiles of depth and
+    of columns, the matrix one tile and the call within the kernel's
+    VMEM."""
+    return (chunk > 0 and R % chunk == 0 and k % 128 == 0 and n % 128 == 0
             and k * n * itemsize <= WHOLE_BYTES
-            and vmem_bytes(R, k, n, itemsize) <= VMEM_BYTES)
+            and vmem_bytes(R, k, n, itemsize, chunk) <= VMEM_BYTES)
 
 
 def _kernel(offs_ref, hit_ref, xs_ref, w_ref, o_ref, *, chunk: int,
@@ -108,17 +120,20 @@ def _kernel(offs_ref, hit_ref, xs_ref, w_ref, o_ref, *, chunk: int,
     lax.fori_loop(0, pl.cdiv(end - base, chunk), one, None)
 
 
-def grouped_decode(xs, w, group_sizes, *, chunk: int = CHUNK_ROWS,
+def grouped_decode(xs, w, group_sizes, *, chunk: int | None = None,
                    interpret: bool = False):
     """``xs [R, k]`` rows sorted by group, ``w [G, k, n]``,
     ``group_sizes [G]`` int32 -> ``[R, n]`` in ``xs``'s dtype: row ``r``
     of group ``g`` times ``w[g]``; rows past ``sum(group_sizes)``
-    undefined.  ``R`` a multiple of ``chunk`` (the sweep's lever), which
-    is a multiple of the operand's sublane tile."""
+    undefined.  ``R`` a multiple of ``chunk`` (None: :func:`chunk_rows`
+    of ``R``; the sweep's lever), which is a multiple of the operand's
+    sublane tile."""
     R, k = xs.shape
     G, _, n = w.shape
     align = max(8, 32 // xs.dtype.itemsize)
-    if R % chunk or chunk % align:
+    if chunk is None:
+        chunk = chunk_rows(R)
+    if not chunk or R % chunk or chunk % align:
         raise ValueError(f"grouped_decode: {R} rows are no whole chunks of "
                          f"{chunk} rows in tiles of {align}")
     sizes = group_sizes.astype(jnp.int32)

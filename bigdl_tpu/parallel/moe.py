@@ -361,13 +361,14 @@ GROUPED_IMPLS = ("ragged", "gmm", "grouped_decode")
 _TRACING = threading.local()
 
 
-def _tiles_of(impl: str, k: int, n: int):
-    """``(rows, tk, tn)`` of one grid step of ``impl`` at depth ``k`` and
-    width ``n``; None for ``ragged`` (the compiler's own)."""
+def _tiles_of(impl: str, R: int, k: int, n: int):
+    """``(rows, tk, tn)`` of one grid step of ``impl`` over a buffer of
+    ``R`` rows at depth ``k`` and width ``n``; None for ``ragged`` (the
+    compiler's own)."""
     if impl == "grouped_decode":        # the whole matrix
-        from ..ops.grouped_decode import CHUNK_ROWS
+        from ..ops.grouped_decode import chunk_rows
 
-        return (CHUNK_ROWS, k, n)
+        return (chunk_rows(R), k, n)
     if impl == "gmm":       # PR 32's: 1024 where that divides, else 512
         return (128, 1024 if k % 1024 == 0 else 512,
                 1024 if n % 1024 == 0 else 512)
@@ -378,26 +379,34 @@ def grouped_plan(R: int, k: int, n: int, dtype) -> tuple:
     """``(impl, tiles)`` of the grouped product ``[R, k] x [G, k, n]``
     held in ``dtype`` — the ONE rule :func:`grouped_matmul` and
     ``generate.cache_footprint`` read, from shapes and the backend
-    alone (``PERF.md`` §6 "PR 32" and "PR 43" have the sweeps):
+    alone (``PERF.md`` §6 "PR 32", "PR 43" and "PR 47" have the sweeps):
 
-    * off a TPU, for a buffer of more than 2048 rows (a prefill piece)
-      or one that 128 rows, 512 deep and 512 wide do not divide:
-      ``("ragged", None)``;
+    * off a TPU, or for a buffer of more than 2048 rows (a prefill
+      piece): ``("ragged", None)``;
     * else ``("grouped_decode", (rows a product, k, n))`` — the kernel
       of ``ops/grouped_decode.py``, an expert's whole matrix one tile —
-      where the matrix is within ``grouped_decode.WHOLE_BYTES`` (8 MB)
-      and the call within the kernel's VMEM;
-    * else ``("gmm", (128, tk, tn))``, megablox under PR 32's tiles: an
+      where the kernel itself takes the product
+      (``grouped_decode.fits``): the buffer is whole products of 128
+      rows, or of 64 where 128 does not divide it (SmallThinker's 32
+      rows x 6 choices = 192), depth and width are whole lane tiles,
+      the matrix is within ``grouped_decode.WHOLE_BYTES`` (8 MB:
+      SmallThinker's 3.9, LFM2's 6, Xing4.0's 7) and the call within the
+      kernel's VMEM;
+    * else, where 128 rows, 512 deep and 512 wide divide the product,
+      ``("gmm", (128, tk, tn))``, megablox under PR 32's tiles: an
       expert of 32 MB in 2 MB tiles reads 92 % of its bytes' time there,
-      and no way of cutting it read more."""
-    if not (jax.default_backend() == "tpu" and R <= 2048 and R % 128 == 0
-            and k % 512 == 0 and n % 512 == 0):
+      and no way of cutting it read more;
+    * else (a buffer of 32, 48 or 96 rows; a width that is no whole
+      lane tiles) ``("ragged", None)``."""
+    if jax.default_backend() != "tpu" or R > 2048:
         return "ragged", None
-    from ..ops.grouped_decode import fits
+    from ..ops.grouped_decode import chunk_rows, fits
 
-    impl = ("grouped_decode" if fits(R, k, n, jnp.dtype(dtype).itemsize)
-            else "gmm")
-    return impl, _tiles_of(impl, k, n)
+    impl = ("grouped_decode"
+            if fits(R, k, n, jnp.dtype(dtype).itemsize, chunk_rows(R))
+            else "gmm" if R % 128 == 0 and k % 512 == 0 and n % 512 == 0
+            else "ragged")
+    return impl, _tiles_of(impl, R, k, n)
 
 
 def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
@@ -410,9 +419,10 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
       tiles of 512 on it;
     * ``"grouped_decode"`` — the repo's own Pallas kernel
       (``ops/grouped_decode.py``) for a decode step's buffer: one grid
-      step a group, its whole matrix one contiguous tile of up to 8 MB,
+      step a group, its whole matrix one contiguous tile of 3.9-8 MB,
       so every hit expert's weights cross HBM ONCE however its handful
-      of rows lie in the buffer.  TPU only;
+      of rows lie in the buffer, which it walks in the products of 128
+      or 64 rows the plan's tiles name.  TPU only;
     * ``"gmm"`` — the Pallas grouped matmul that ships with jax
       (megablox), rows in tiles of 128: a group that straddles a row
       tile is visited twice, and with more than one k tile its weights
@@ -425,7 +435,7 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
     if impl is None:
         impl, tiles = grouped_plan(R, k, n, xs.dtype)
     elif impl in GROUPED_IMPLS:
-        tiles = _tiles_of(impl, k, n)
+        tiles = _tiles_of(impl, R, k, n)
     else:
         raise ValueError(f"grouped matmul {impl!r} not in {GROUPED_IMPLS}")
     plans = getattr(_TRACING, "plans", None)
@@ -436,7 +446,7 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
     if impl == "grouped_decode":
         from ..ops.grouped_decode import grouped_decode
 
-        return grouped_decode(xs, w, group_sizes)
+        return grouped_decode(xs, w, group_sizes, chunk=tiles[0])
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     return gmm(xs, w.astype(xs.dtype), group_sizes,

@@ -1,8 +1,9 @@
 """The grouped product of a decode step's expert layer
 (``ops/grouped_decode.py``, PR 43): the Pallas kernel, interpreted on
 the CPU, against ``jax.lax.ragged_dot`` — the arm it replaces megablox's
-``gmm`` with where ``parallel.moe.grouped_plan`` says so — on UNEVEN
-group sizes; and the plan itself at the four serving cells' shapes.
+``gmm`` and the compiler's ``ragged-dot`` with where
+``parallel.moe.grouped_plan`` says so — on UNEVEN group sizes; and the
+plan itself at the five serving cells' shapes.
 
 Tolerances.  The kernel and ``ragged_dot`` both accumulate in float32
 and round once; they differ by summation order: 1e-5 of the largest
@@ -19,7 +20,8 @@ from bigdl_tpu.parallel import moe
 
 # cell -> (embed D, expert F, rows of a full bucket's decode buffer)
 CELLS = {"lfm2": (2048, 1536, 1024), "xing4": (3584, 1024, 1024),
-         "glm": (2048, 1536, 1024), "commandaplus": (4096, 4096, 1024)}
+         "glm": (2048, 1536, 1024), "commandaplus": (4096, 4096, 1024),
+         "smallthinker": (2560, 768, 192)}
 
 
 @pytest.fixture
@@ -29,19 +31,22 @@ def on_tpu(monkeypatch):
 
 
 @pytest.mark.parametrize("product", ["up", "down"])
-@pytest.mark.parametrize("cell", ["lfm2", "xing4", "glm"])
+@pytest.mark.parametrize("cell", ["lfm2", "xing4", "glm", "smallthinker"])
 def test_plan_at_the_serving_cells(on_tpu, cell, product):
     """An expert's WHOLE matrix is the tile — one k tile, so a hit
     expert's weights are one copy and the float32 sum is written once;
     all ``n`` columns, so the copy is contiguous — within 8 MB and
-    inside the kernel's VMEM, at every bucket of the ladder."""
+    inside the kernel's VMEM, at every bucket of the ladder that is
+    whole products: of 128 rows, or of 64 where 128 does not divide the
+    buffer (a 16-row bucket's 64; SmallThinker's 32 rows x 6 = 192)."""
     D, F, R = CELLS[cell]
     k, n = (D, F) if product == "up" else (F, D)
-    for rows in (128, 256, 512, R, 2048):
+    for rows in (64, 128, 192, 256, 512, R, 2048):
+        chunk = 64 if rows % 128 else 128
         impl, tiles = moe.grouped_plan(rows, k, n, jnp.bfloat16)
-        assert (impl, tiles) == ("grouped_decode", (GD.CHUNK_ROWS, k, n))
+        assert (impl, tiles) == ("grouped_decode", (chunk, k, n))
         assert k * n * 2 <= GD.WHOLE_BYTES
-        assert GD.vmem_bytes(rows, k, n, 2) <= GD.VMEM_BYTES
+        assert GD.vmem_bytes(rows, k, n, 2, chunk) <= GD.VMEM_BYTES
 
 
 @pytest.mark.parametrize("R,k,n,dt,why", [
@@ -62,7 +67,9 @@ def test_shape_without_a_plan_keeps_todays_gmm_tiles(on_tpu, R, k, n, dt,
 @pytest.mark.parametrize("R,k,n", [
     (32768, 2048, 1536),      # a prefill piece
     (1000, 2048, 1536),       # no whole row tiles
-    (1024, 2048, 1280),       # a width 512 does not divide
+    (1024, 2048, 1300),       # a width that is no whole lane tiles
+    (96, 2560, 768),          # SmallThinker's 16-row bucket: no whole
+    (32, 2048, 1536),         # products of 64 rows; LFM2's 8-row one
 ])
 def test_other_buffers_keep_ragged_dot(on_tpu, R, k, n):
     assert moe.grouped_plan(R, k, n, jnp.bfloat16) == ("ragged", None)
@@ -86,6 +93,10 @@ SIZES = {
     "sixty-four groups of a few rows": list(np.random.default_rng(0)
                                             .multinomial(250, [1 / 64] * 64)),
 }
+# SmallThinker's decode buffer, 32 rows x 6 choices over 64 experts as
+# its router deals them: the busiest at 4 x the mean of 3, some empty
+SKEWED_192 = list(np.random.default_rng(47).permutation(
+    [12, 9, 7] + [5] * 4 + [4] * 10 + [3] * 22 + [2] * 19 + [0] * 6))
 
 
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
@@ -111,6 +122,36 @@ def test_kernel_equals_ragged_dot(case, chunk, dt):
     tol = 1e-5 if dt == jnp.float32 else 2.0 ** -7
     assert np.abs(got - want).max(initial=0.0) <= tol * max(
         np.abs(want).max(initial=0.0), 1.0)
+
+
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kernel_equals_ragged_dot_at_192_rows_in_chunks_of_64(dt):
+    """The SmallThinker step's buffer: 192 rows are no whole 128-row
+    chunks, so the plan walks it in products of 64 — 64 groups, the
+    busiest 12 rows (4 x the mean), six of them empty, the last chunk's
+    rows shared by many groups."""
+    sizes = np.asarray(SKEWED_192, np.int32)
+    assert (len(sizes), sizes.sum(), sizes.max(), (sizes == 0).sum()) == (
+        64, 192, 12, 6)
+    R, k, n = 192, 256, 384
+    assert GD.chunk_rows(R) == 64
+    ks = jax.random.split(jax.random.PRNGKey(47), 2)
+    xs = jax.random.normal(ks[0], (R, k), dt)
+    w = jax.random.normal(ks[1], (len(sizes), k, n), dt) / k ** 0.5
+    got = GD.grouped_decode(xs, w, jnp.asarray(sizes), chunk=64,
+                            interpret=True)
+    want = lax.ragged_dot(xs, w, jnp.asarray(sizes))
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    tol = 1e-5 if dt == jnp.float32 else 2.0 ** -7
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1.0)
+
+
+@pytest.mark.parametrize("R,chunk", [(2048, 128), (1024, 128), (192, 64),
+                                     (64, 64), (96, 0), (32, 0), (1000, 0)])
+def test_rows_a_product_from_the_buffers_rows(R, chunk):
+    assert GD.chunk_rows(R) == chunk
+    assert GD.fits(R, 2560, 768, 2, chunk) == bool(chunk)
 
 
 def test_kernel_refuses_a_buffer_without_whole_chunks():

@@ -298,22 +298,32 @@ def test_smallthinker_32_row_bucket_fits_a_v5e_with_its_prompt_in_groups(
     assert len(re.findall(r" while\(", compiled.as_text())) == 1
 
 
-@pytest.mark.parametrize("config,cls,up,down", [
-    ("lfm2-24b-a2b-l5", "ShortConvMoELM", "128x2048x1536",
-     "128x1536x2048"),
-    ("xing4.0-29b-a4b-l5e32v2", "HyperLatentMoELM", "128x3584x1024",
-     "128x1024x3584"),
+_TOY_4_OF_4 = {"vocab_size": 256, "mlp_dim": 256, "n_experts": 4,
+               "held": [0, 4], "top_k": 4}
+
+
+@pytest.mark.parametrize("config,cls,B,toy,up,down", [
+    ("lfm2-24b-a2b-l5", "ShortConvMoELM", 256, _TOY_4_OF_4,
+     "128x2048x1536", "128x1536x2048"),
+    ("xing4.0-29b-a4b-l5e32v2", "HyperLatentMoELM", 256, _TOY_4_OF_4,
+     "128x3584x1024", "128x1024x3584"),
+    ("smallthinker-21b-a3b-l4", "PreRoutedMoELM", 32,
+     {"vocab_size": 256, "n_experts": 8, "held": [0, 8]},
+     "64x2560x768", "64x768x2560"),
 ])
 def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
-        one_chip, monkeypatch, config, cls, up, down):
+        one_chip, monkeypatch, config, cls, B, toy, up, down):
     """The largest bucket of the LFM2 and Xing4.0 cells (256 rows, four
     choices a token: a decode buffer of 1024 rows at the published
     embed and expert widths; four experts, toy vocabulary and dense
-    FFN) compiles under the plan of ``parallel.moe.grouped_plan`` —
-    each expert's matrix ONE whole-depth tile of 6-7 MB, 22-31 MiB of
-    VMEM a call — and a decode step holds twelve Mosaic calls under
-    ``moe.expert_matmul`` (three a layer, four expert layers): what the
-    ``*_expert_matmul_roofline`` readers count a step's products by."""
+    FFN) and of the SmallThinker cell (32 rows, six choices: 192 rows,
+    no whole 128-row chunks; eight experts) compiles under the plan of
+    ``parallel.moe.grouped_plan`` — each expert's matrix ONE whole-depth
+    tile of 3.9-7 MB, 11-31 MiB of VMEM a call, walked in products of
+    128 rows or of 64 — and a decode step holds twelve Mosaic calls
+    under ``moe.expert_matmul`` (three a layer, four expert layers):
+    what the ``*_expert_matmul_roofline`` readers count a step's
+    products by."""
     import json
     import os
     import re
@@ -321,13 +331,13 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     from bigdl_tpu.models import generate as G
     from bigdl_tpu.models import latent_moe
 
-    B, T0, new = 256, 128, 256
+    T0, new = 128, 256
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
                            "configs", config + ".json")) as f:
-        kw = json.load(f)["program"]["kwargs"]
-    model = getattr(latent_moe, cls)(**{
-        **kw, "vocab_size": 256, "mlp_dim": 256, "n_experts": 4,
-        "held": [0, 4], "top_k": 4, "max_len": T0 + new})
+        kw = {**json.load(f)["program"]["kwargs"], **toy,
+              "max_len": T0 + new}
+    model = getattr(latent_moe, cls)(**kw)
+    rows = B * kw["top_k"]                 # a token's choices, all held
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     foot = G.cache_footprint(model, B, T0, new, compute_dtype=jnp.bfloat16)
     assert (foot["grouped"], foot["grouped_tiles"],
@@ -350,10 +360,10 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     step = [c for c in calls if "generate.decode_step" in c]
     assert len(step) == 12
     assert {re.search(r"= bf16\[(\d+),(\d+)\]", c).groups()
-            for c in step} == {(str(4 * B), up.split("x")[2]),
-                               (str(4 * B), down.split("x")[2])}
-    # the prompt's 32 768 rows go in pieces of ``ragged_dot``: no Mosaic
-    # call under the scope outside the decode step
+            for c in step} == {(str(rows), up.split("x")[2]),
+                               (str(rows), down.split("x")[2])}
+    # the prompt's 32 768 (24 576) rows go in pieces of ``ragged_dot``:
+    # no Mosaic call under the scope outside the decode step
     assert len(calls) == len(step)
 
 

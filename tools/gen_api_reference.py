@@ -252,8 +252,10 @@ def main():
             "  decode step's buffer of at most 2048 rows on a TPU the",
             "  repo's own kernel, `ops.grouped_decode.grouped_decode` —",
             "  one grid step an expert, its whole matrix ONE contiguous",
-            "  tile of up to 8 MB, so a hit expert's weights cross HBM",
-            "  once wherever its rows lie — and for a larger matrix the",
+            "  tile of 3.9-8 MB, so a hit expert's weights cross HBM",
+            "  once wherever its rows lie, the buffer walked in products",
+            "  of 128 rows or of 64 where 128 does not divide it",
+            "  (SmallThinker's 192) — and for a larger matrix the",
             "  Pallas grouped matmul that ships with jax; `grouped_plan`",
             "  is the one rule, from shapes alone, and `cache_footprint` /",
             "  `serve.dispatch` say which: `grouped`, `grouped_tiles`,",

@@ -3,14 +3,15 @@
 the chip, as a decode step issues them: ``--calls`` gated experts
 (``gate`` and ``up`` ``[R, d] x [G, d, f]``, ``down`` ``[R, f] x [G, f,
 d]``) back to back inside ONE jitted loop, each fed the one before it,
-at the shapes the four serving cells with experts dispatch — and with
+at the shapes the five serving cells with experts dispatch — and with
 group sizes drawn as those cells route: UNEVEN (a multinomial over
 popularities from a Dirichlet, the busiest group at 2-3 x the mean, as
 ``moe_load_max_over_mean`` reads), so that groups straddle the 128-row
 tiles of megablox's ``gmm``.  (PR 32's sweep gave every group ``real //
 held`` rows: every group started on a tile boundary, none straddled.)
 
-    python tools/moe_grouped_sweep.py [--shapes lfm2,xing4,glm,commandaplus]
+    python tools/moe_grouped_sweep.py
+        [--shapes lfm2,xing4,glm,commandaplus,smallthinker,smallthinker_prefill]
         [--arms gmm,decode,ragged] [--chunk 128,64]
         [--tiling today plan 128,k,today 64,k,plan]
         [--d .. --f .. --held .. --rows .. --real ..]
@@ -24,15 +25,19 @@ number or ``k`` for the whole depth, ``tn`` a number, ``today`` or
 ``plan``); ``decode`` (the repo's own ``ops/grouped_decode.py``: one
 grid step a group, its whole matrix one tile) under each ``--chunk``
 (rows a product); ``ragged`` (``jax.lax.ragged_dot``).  An arm is
-skipped at a shape its tiles do not divide, ``decode`` where the matrix
-is over its 8 MB.  ``--d`` ... give one shape of your own in place of
+skipped at a shape its tiles do not divide, ``decode`` where the kernel
+does not take the product (``grouped_decode.fits``: a matrix over its
+8 MB, rows that are no whole chunks, a buffer over its VMEM — a prompt
+piece).  ``--d`` ... give one shape of your own in place of
 ``--shapes``.
 
 Chip only.  A row of the table is one product (``gate``: the gate and
 up calls; ``down``) of one arm at one shape:
 
 * ``ms_per_call`` (``ms_min``, ``ms_max``): from the DEVICE TRACE of the
-  loop, the custom call's own events in the order a layer runs them;
+  loop, the custom call's own events in the order a layer runs them
+  (left out, ``calls_timed`` 0, where the trace holds another number of
+  them than three a layer: ``calls_seen``);
 * the kernel's grid, counted on the host from the sizes: ``visits``
   ((row tile, group) pairs of ``gmm``; groups hit), ``grid_steps``,
   ``products`` (MXU passes of a weight tile) and, for ``gmm``,
@@ -41,7 +46,8 @@ up calls; ``down``) of one arm at one shape:
   re-fetches unless the block is the one held;
 * ``bytes_once_pct``: the hit experts' weights and the real rows in and
   out ONCE at the chip's bandwidth (``benchmark/peaks.json``), over
-  ``ms_per_call``;
+  ``ms_per_call``; ``peak_flops_pct``: the real rows' operations at the
+  chip's bf16 peak over the same (what bounds a prompt piece);
 * ``loop_ms_per_layer``: the host clock around the whole loop over its
   layers — the three products with the sizes' bookkeeping and the gate
   between them, as the program pays them — and
@@ -52,7 +58,8 @@ up calls; ``down``) of one arm at one shape:
   ``ragged_dot`` on the defined rows of one gate product (values of
   order 1).
 
-PERF.md §6 "PR 43" has the readings (§6 "PR 32" the first sweep's).
+PERF.md §6 "PR 43" and "PR 47" have the readings (§6 "PR 32" the first
+sweep's).
 """
 import argparse
 import json
@@ -73,6 +80,11 @@ SHAPES = {
     "xing4": (3584, 1024, 32, 1024, 512),
     "glm": (2048, 1536, 16, 1024, 256),
     "commandaplus": (4096, 4096, 16, 1024, 128),
+    # 32 rows x 6 choices: no whole 128-row chunks, products of 64
+    "smallthinker": (2560, 768, 64, 192, 192),
+    # one piece of that cell's prompt pass (4608 rows x 6 choices): over
+    # the plan's 2048 rows, ``ragged`` in the program; ``decode`` skips it
+    "smallthinker_prefill": (2560, 768, 64, 27648, 27648),
 }
 
 
@@ -124,7 +136,11 @@ def call_seconds(trace_dir, trace_reduce):
     calls, other = [], 0.0
     for ev, self_ns, in_loop in trace_reduce.self_times(
             trace_reduce._line(chip, "XLA Ops")):
-        if "tpu_custom_call" in ev[0] or " ragged-dot(" in ev[0]:
+        # on the v5e a ``ragged_dot`` is a custom call the compiler names
+        # ``ragged-dot-none.N`` (beside a ``ragged-dot-metadata`` of 3 us)
+        head = ev[0].split(" = ")[0]
+        if "tpu_custom_call" in ev[0] or (
+                "ragged-dot" in head and "metadata" not in head):
             calls.append((ev[1], ev[2] / 1e9))
         elif in_loop:
             other += self_ns / 1e9
@@ -133,7 +149,8 @@ def call_seconds(trace_dir, trace_reduce):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default=",".join(
+        s for s in SHAPES if s != "smallthinker_prefill"))
     for dim in ("d", "f", "held", "rows", "real"):
         ap.add_argument("--" + dim, type=int, default=0)
     ap.add_argument("--arms", default="gmm,decode")
@@ -175,7 +192,7 @@ def main() -> int:
             if arm == "ragged":
                 out.append(("ragged", None, lax.ragged_dot, None))
             for t in args.tiling if arm == "gmm" else ():
-                today = _tiles_of("gmm", k, n)
+                today = _tiles_of("gmm", rows, k, n)
                 named = {"k": k, "today": today[2], "plan": tn_plan}
                 tiles = (today if t == "today" else
                          (today[0], k, tn_plan) if t == "plan" else
@@ -190,7 +207,7 @@ def main() -> int:
                       if arm == "decode"):
                 out.append((
                     f"decode chunk {c}", (c, k, n),
-                    GD.fits(rows, k, n, 2) and (
+                    GD.fits(rows, k, n, 2, c) and (
                         lambda x, w, s, c=c: GD.grouped_decode(
                             x, w, s, chunk=c)),
                     lambda sizes, c=c: decode_grid(sizes, c)))
@@ -262,14 +279,17 @@ def main() -> int:
                 row = dict(head, product=product, k=k, n=n, tiles=tiles,
                            loop_ms_per_layer=loop_ms,
                            other_ops_ms_per_layer=1e3 * other / args.calls,
-                           calls_timed=len(calls) if whole else 0)
+                           calls_timed=len(calls) if whole else 0,
+                           calls_seen=len(secs))
                 if whole:
                     ms = 1e3 * statistics.median(calls)
                     once = 2.0 * (hit * k * n + real * (k + n))
                     row.update(
                         ms_per_call=ms, ms_min=1e3 * min(calls),
                         ms_max=1e3 * max(calls), bytes_once_pct=100.0 * once
-                        / peaks["hbm_bytes_per_s"] / (ms / 1e3))
+                        / peaks["hbm_bytes_per_s"] / (ms / 1e3),
+                        peak_flops_pct=100.0 * 2.0 * real * k * n
+                        / peaks["bf16_flops_per_s"] / (ms / 1e3))
                 if grid:
                     row.update(grid(sizes_np))
                     if whole:
