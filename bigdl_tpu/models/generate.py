@@ -238,7 +238,9 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
     the layers that take the kernel, where some do (a ring keeps the
     einsums) — and, for a model with experts, ``grouped``,
     ``grouped_tiles`` and ``grouped_tiles_down``
-    (``DroplessMoE.decode_plan``).  ``prefill_groups``: the groups of
+    (``DroplessMoE.decode_plan``) and the same three for a piece of the
+    prompt pass, ``grouped_prefill*`` (a block's ``prefill_plan`` of the
+    tokens one group of rows brings).  ``prefill_groups``: the groups of
     rows the prompt pass goes in (:func:`prefill_groups`; 1: whole).
     Each is the rule the program itself reads."""
     first, count = _check_model(model)
@@ -246,10 +248,14 @@ def cache_footprint(model, batch: int, prompt_len: int, max_new: int,
                          int(max_new))
     dt = jnp.dtype(compute_dtype or jax.tree_util.tree_leaves(
         model.param_tree())[0].dtype)
+    groups = prefill_groups(int(batch), int(prompt_len))
     out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
-           "kv_cache_positions": T_cache,
-           "prefill_groups": prefill_groups(int(batch), int(prompt_len))}
+           "kv_cache_positions": T_cache, "prefill_groups": groups}
     for block in model.modules[first:first + count]:
+        if hasattr(block, "prefill_plan"):
+            for name, v in block.prefill_plan(
+                    int(batch) // groups * int(prompt_len), dt).items():
+                out.setdefault(name, v)
         for name, v in block.footprint(int(batch), dt, T_cache,
                                        _kv_int8(kv_dtype)).items():
             if isinstance(v, int):                  # bytes of a kind

@@ -323,6 +323,10 @@ class SequentialMoEBlock(Container):
                 **(self.moe.decode_plan(batch, dtype) if self.is_moe
                    else {})}
 
+    def prefill_plan(self, tokens: int, dtype):
+        """The experts' arm over a prompt pass of ``tokens`` tokens."""
+        return self.moe.prefill_plan(tokens, dtype) if self.is_moe else {}
+
     def advance(self, params, h, state, pos):
         """ONE form for every operator, every FFN and both residuals:
         what a sublayer reads of ``h`` and how its result goes back are

@@ -181,6 +181,10 @@ class ParallelMoEBlock(Container):
         return {**self.modules[1].footprint(batch, dtype, length, int8),
                 **self.moe.decode_plan(batch, dtype)}
 
+    def prefill_plan(self, tokens: int, dtype):
+        """The experts' arm over a prompt pass of ``tokens`` tokens."""
+        return self.moe.prefill_plan(tokens, dtype)
+
     def advance(self, params, h, state, pos):
         """Attention and the expert layer read the SAME normed input."""
         n, _ = self.modules[0].apply_fn(params["0"], {}, h, False, None)

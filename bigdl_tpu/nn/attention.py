@@ -30,8 +30,9 @@ and ``state_doc`` (what it keeps, in words, for whoever cannot hold
 it).  A block around it answers
 ``state_init`` likewise and ``advance(params, h, state, pos)`` — Tq
 tokens at ``pos`` (the Python ``0``: the whole prompt) against its state
-— with ``footprint``, ``counters`` (leaf -> the statistic a call
-returns of it) and ``state_doc`` where it has any: ``nn.mamba``,
+— with ``footprint``, ``prefill_plan(tokens, dtype)`` (the arm its
+prompt pass compiles, in words), ``counters`` (leaf -> the statistic a
+call returns of it) and ``state_doc`` where it has any: ``nn.mamba``,
 ``models/transformer.py``, ``models/parallel_moe.py``,
 ``models/latent_moe.py``.
 """

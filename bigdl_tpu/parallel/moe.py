@@ -355,10 +355,15 @@ def route_top_k(x2, router_w, router_b, top_k: int,
     return gates, idx
 
 
-GROUPED_IMPLS = ("ragged", "gmm", "grouped_decode")
+GROUPED_IMPLS = ("ragged", "gmm", "grouped_decode", "grouped_prefill")
 # the plans :func:`grouped_matmul` chose while this thread traces an
 # expert function, for the ``moe.schedule`` event of the dispatch
 _TRACING = threading.local()
+
+
+#: rows of the largest buffer a decode step dispatches: a buffer of
+#: more rows is a piece of a prompt pass
+DECODE_ROWS = 2048
 
 
 def _tiles_of(impl: str, R: int, k: int, n: int):
@@ -369,6 +374,10 @@ def _tiles_of(impl: str, R: int, k: int, n: int):
         from ..ops.grouped_decode import chunk_rows
 
         return (chunk_rows(R), k, n)
+    if impl == "grouped_prefill":       # the whole matrix, a row tile
+        from ..ops.grouped_prefill import ROW_TILE
+
+        return (ROW_TILE, k, n)
     if impl == "gmm":       # PR 32's: 1024 where that divides, else 512
         return (128, 1024 if k % 1024 == 0 else 512,
                 1024 if n % 1024 == 0 else 512)
@@ -379,10 +388,21 @@ def grouped_plan(R: int, k: int, n: int, dtype) -> tuple:
     """``(impl, tiles)`` of the grouped product ``[R, k] x [G, k, n]``
     held in ``dtype`` — the ONE rule :func:`grouped_matmul` and
     ``generate.cache_footprint`` read, from shapes and the backend
-    alone (``PERF.md`` §6 "PR 32", "PR 43" and "PR 47" have the sweeps):
+    alone (``PERF.md`` §6 "PR 32", "PR 43", "PR 47" and "PR 48" have the
+    sweeps):
 
-    * off a TPU, or for a buffer of more than 2048 rows (a prefill
-      piece): ``("ragged", None)``;
+    * off a TPU: ``("ragged", None)``;
+    * a buffer of more than ``DECODE_ROWS`` = 2048 rows (a piece of a
+      prompt pass, bound by its operations):
+      ``("grouped_prefill", (128, k, n))`` — the kernel of
+      ``ops/grouped_prefill.py``, ONE k tile and the whole width, a
+      group's matrix fetched once however many row tiles it spans —
+      where the kernel itself takes the product
+      (``grouped_prefill.fits``: whole row tiles, whole lane tiles, the
+      matrix one tile within the kernel's 16 MiB of VMEM: SmallThinker's
+      ``[2560, 768]`` asks for 9.9 MiB, LFM2's and GLM's
+      ``[2048, 1536]`` for 15.8); else (Xing4.0's ``[3584, 1024]`` asks
+      for 19.8, Command A+'s matrix is 32 MB) ``("ragged", None)``;
     * else ``("grouped_decode", (rows a product, k, n))`` — the kernel
       of ``ops/grouped_decode.py``, an expert's whole matrix one tile —
       where the kernel itself takes the product
@@ -398,12 +418,18 @@ def grouped_plan(R: int, k: int, n: int, dtype) -> tuple:
       and no way of cutting it read more;
     * else (a buffer of 32, 48 or 96 rows; a width that is no whole
       lane tiles) ``("ragged", None)``."""
-    if jax.default_backend() != "tpu" or R > 2048:
+    if jax.default_backend() != "tpu":
         return "ragged", None
+    itemsize = jnp.dtype(dtype).itemsize
+    if R > DECODE_ROWS:
+        from ..ops import grouped_prefill
+
+        impl = ("grouped_prefill" if grouped_prefill.fits(R, k, n, itemsize)
+                else "ragged")
+        return impl, _tiles_of(impl, R, k, n)
     from ..ops.grouped_decode import chunk_rows, fits
 
-    impl = ("grouped_decode"
-            if fits(R, k, n, jnp.dtype(dtype).itemsize, chunk_rows(R))
+    impl = ("grouped_decode" if fits(R, k, n, itemsize, chunk_rows(R))
             else "gmm" if R % 128 == 0 and k % 512 == 0 and n % 512 == 0
             else "ragged")
     return impl, _tiles_of(impl, R, k, n)
@@ -426,8 +452,14 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
     * ``"gmm"`` — the Pallas grouped matmul that ships with jax
       (megablox), rows in tiles of 128: a group that straddles a row
       tile is visited twice, and with more than one k tile its weights
-      are fetched twice.  What a shape without a ``grouped_decode`` plan
-      keeps.  TPU only.
+      are fetched twice.  What a decode buffer without a
+      ``grouped_decode`` plan keeps.  TPU only;
+    * ``"grouped_prefill"`` — the repo's own Pallas kernel
+      (``ops/grouped_prefill.py``) for a piece of a prompt pass: that
+      grid of (row tile, group) visits with ONE k tile, an expert's
+      whole matrix the weight tile — fetched once a group, the float32
+      sum complete at the one write — and its visit lists made without
+      a loop on the device.  TPU only.
 
     None picks by :func:`grouped_plan`."""
     R, k = xs.shape
@@ -447,6 +479,10 @@ def grouped_matmul(xs, w, group_sizes, impl: Optional[str] = None):
         from ..ops.grouped_decode import grouped_decode
 
         return grouped_decode(xs, w, group_sizes, chunk=tiles[0])
+    if impl == "grouped_prefill":
+        from ..ops.grouped_prefill import grouped_prefill
+
+        return grouped_prefill(xs, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     return gmm(xs, w.astype(xs.dtype), group_sizes,
@@ -490,6 +526,20 @@ def dispatch_plan(idx, gates, held, rows: int):
     return tok, valid, pos, weight, sizes
 
 
+def dispatch_pieces(N: int, K: int, count: int) -> tuple:
+    """``(pieces, tokens a piece)`` of a dispatch of ``N`` tokens with
+    ``K`` choices each among ``count`` held experts: one piece while the
+    sorted buffer (``N * min(K, count)`` rows) stays within
+    ``MAX_DISPATCH_ROWS``, else the fewest equal pieces that do."""
+    per_tok = min(K, count)
+    if N * per_tok <= MAX_DISPATCH_ROWS or N == 1:
+        return 1, N
+    pieces = -(-N * per_tok // MAX_DISPATCH_ROWS)
+    while N % pieces:
+        pieces += 1
+    return pieces, N // pieces
+
+
 def dropless_apply(x2, idx, gates, held, expert_fn):
     """The held experts' part of the mixture for ``x2`` [N, D]:
     ``sum_k weight[n, k] * expert_{idx[n, k]}(x2[n])`` over the choices
@@ -501,13 +551,9 @@ def dropless_apply(x2, idx, gates, held, expert_fn):
     shapes for the worst case, nothing dropped; a token list whose
     buffer would pass ``MAX_DISPATCH_ROWS`` goes in equal pieces."""
     N, K = idx.shape
-    per_tok = min(K, held[1])
-    if N * per_tok <= MAX_DISPATCH_ROWS or N == 1:
+    pieces, n = dispatch_pieces(N, K, held[1])
+    if pieces == 1:
         return _dropless_piece(x2, idx, gates, held, expert_fn)
-    pieces = -(-N * per_tok // MAX_DISPATCH_ROWS)
-    while N % pieces:
-        pieces += 1
-    n = N // pieces
     if pieces <= MAX_UNROLLED_PIECES:
         # unrolled, so that a generate program's device trace holds one
         # ``while`` — its decode scan (as the chunked SSD scan does)
@@ -742,13 +788,24 @@ class DroplessMoE(TensorModule):
         ``grouped_matmul`` itself reads.  As text: these ride on
         ``serve.dispatch`` into a profiler session, whose event metadata
         is split at commas."""
-        rows = batch * min(self.top_k, self.held[1])
+        return self._plan_words("grouped", batch, dtype)
+
+    def prefill_plan(self, tokens: int, dtype) -> dict:
+        """The same three words for ONE PIECE of a prompt pass of
+        ``tokens`` tokens (:func:`dispatch_pieces`, the arithmetic
+        :func:`dropless_apply` cuts it by): ``grouped_prefill``,
+        ``grouped_prefill_tiles``, ``grouped_prefill_tiles_down``."""
+        n = dispatch_pieces(tokens, self.top_k, self.held[1])[1]
+        return self._plan_words("grouped_prefill", n, dtype)
+
+    def _plan_words(self, word: str, tokens: int, dtype) -> dict:
+        rows = tokens * min(self.top_k, self.held[1])
         D, F = self.embed_dim, self.hidden_dim
         impl, up = grouped_plan(rows, D, F, dtype)
         down = grouped_plan(rows, F, D, dtype)[1]
-        return {"grouped": impl,
-                "grouped_tiles": "x".join(map(str, up or ())),
-                "grouped_tiles_down": "x".join(map(str, down or ()))}
+        return {word: impl,
+                word + "_tiles": "x".join(map(str, up or ())),
+                word + "_tiles_down": "x".join(map(str, down or ()))}
 
     def _apply(self, params, buffers, x, training, rng):
         B, T, D = x.shape

@@ -1207,9 +1207,21 @@ class InferenceServer:
         the others, and each new one would stop the worker for a whole
         compile.  All of them start compiling now, beside each other
         on the compile pool — this batch's first, which ``gen`` then
-        waits for — so a ladder costs about its slowest program, not
-        their sum (cold start of an autoscaled replica; the warm-up of
-        a benchmark)."""
+        waits for; then those whose prompt pass goes in groups of rows
+        (``generate.prefill_groups``), most groups first: a program is
+        unrolled once a group, so these are the slowest to build or to
+        load from the compile cache, and tracing and lowering are
+        Python under one interpreter lock — what is asked for first is
+        lowered first, and the slowest load has to start while the
+        others are still lowered (SmallThinker's 32-row program of 4
+        groups loads in 15-24 s: the warm ladder took 35.0 s smallest
+        first and 28.3 s in this order before ``ops/grouped_prefill.py``,
+        43.3 and 36.8-38.4 s with it; ``PERF.md`` §6 "PR 48"); then the
+        rest, smallest first — so a ladder costs about its slowest
+        program, not their sum (cold start of an autoscaled replica;
+        the warm-up of a benchmark)."""
+        from ..models.generate import prefill_groups
+
         _, bucket, prompt_len, max_new = sig
         ladder = self.batcher.ladder
         if self._compile_pool is None:
@@ -1217,7 +1229,9 @@ class InferenceServer:
                 max_workers=max(1, min(len(ladder),
                                        (os.cpu_count() or 2) - 1)),
                 thread_name_prefix="bigdl-serving-compile")
-        for b in [bucket] + [b for b in ladder if b != bucket]:
+        for b in [bucket] + sorted(
+                (b for b in ladder if b != bucket),
+                key=lambda b: -prefill_groups(b, prompt_len)):
             gen.compile_ahead(params, b, prompt_len, max_new,
                               self._compile_pool)
 

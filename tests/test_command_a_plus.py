@@ -372,6 +372,9 @@ def test_cache_footprint_by_kind_of_layer():
     # the arm and plan of the step's grouped products (PR 43): the CPU's
     assert (fp["grouped"], fp["grouped_tiles"],
             fp["grouped_tiles_down"]) == ("ragged", "", "")
+    # and of a piece of its prompt pass (PR 48)
+    assert (fp["grouped_prefill"], fp["grouped_prefill_tiles"],
+            fp["grouped_prefill_tiles_down"]) == ("ragged", "", "")
     # a context shorter than the window: every layer keeps all of it
     short = G.cache_footprint(_model(window=128), 3, 19, 11)
     assert short["kv_cache_bytes_window"] == 3 * per_pos * T
@@ -460,6 +463,11 @@ def test_the_server_reports_the_counters_and_the_cache_by_kind():
     # every generate batch says which grouped product it compiled
     assert (dispatch.args["grouped"], dispatch.args["grouped_tiles"],
             dispatch.args["grouped_tiles_down"]) == ("ragged", "", "")
+    # ... in its steps, and in the pieces of its prompt pass (PR 48)
+    assert (dispatch.args["grouped_prefill"],
+            dispatch.args["grouped_prefill_tiles"],
+            dispatch.args["grouped_prefill_tiles_down"]) == ("ragged", "",
+                                                             "")
 
 
 # -- the paths it shares -------------------------------------------------

@@ -482,6 +482,88 @@ def test_compile_ahead_is_the_program_the_call_would_build():
     assert not gen2.ahead
 
 
+def test_a_ladder_is_asked_for_this_batch_first_then_most_groups_first(
+        monkeypatch):
+    """``_compile_ladder``: programs are lowered one at a time in the
+    order they are asked for, and one whose prompt pass goes in groups
+    of rows takes longest to build or load — after the batch's own, the
+    programs with the most groups are asked for first, so that they
+    build while the others are still traced; the rest smallest first,
+    as ever (PR 48)."""
+    from bigdl_tpu.models import generate as G
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.serving import InferenceServer
+
+    class Gen:
+        asked = []
+
+        def compile_ahead(self, params, b, prompt_len, max_new, pool):
+            self.asked.append((b, prompt_len, max_new))
+
+    lm = TransformerLM(61, embed_dim=16, num_heads=2, num_layers=1,
+                       max_len=32, output="logits")
+    srv = InferenceServer(lm, max_batch=16)
+    assert list(srv.batcher.ladder) == [1, 2, 4, 8, 16]
+
+    def asked(first):
+        Gen.asked = []
+        srv._compile_ladder(Gen(), None, ("gen", first, 5, 4))
+        assert all(shape[1:] == (5, 4) for shape in Gen.asked)
+        return [shape[0] for shape in Gen.asked]
+
+    # every prompt goes whole: this batch's, then smallest first
+    assert asked(1) == [1, 2, 4, 8, 16] and asked(4) == [4, 1, 2, 8, 16]
+    # 20 tokens a pass: 16 rows x 5 go in 4 groups, 8 rows in 2
+    monkeypatch.setattr(G, "PREFILL_TOKENS", 20)
+    assert [G.prefill_groups(b, 5) for b in (4, 8, 16)] == [1, 2, 4]
+    assert asked(1) == [1, 16, 8, 2, 4] and asked(8) == [8, 16, 1, 2, 4]
+    srv._compile_pool.shutdown()
+
+
+@pytest.mark.parametrize("traffic,grouped", [
+    ("closed128_p4608_n256_b32", [32, 16]),     # SmallThinker: 4 and 2
+    ("closed256_p256_n128_b64", []),
+    ("closed32_p2048_n8", []),
+    ("closed512_p128_n128_b128", []),
+    ("closed512_p128_n256_b256", []),
+    ("closed512_p128_n256_b256_mhc", []),
+    ("closed512_p128_n512_b256", []),
+    ("closed64_p128_n96", []),
+    ("open_p128_n96", []),
+])
+def test_the_ladder_of_each_served_cell(traffic, grouped):
+    """The order each benchmark cell's ladder is asked for in, from the
+    cell's own ``max_batch`` and prompt: only a prompt pass that goes in
+    groups of rows moves a program ahead — SmallThinker's 32- and 16-row
+    programs — and every other cell's ladder is asked for as before
+    PR 48, this batch's bucket and then smallest first."""
+    import json
+    import os
+
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.serving import InferenceServer
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "traffic", traffic + ".json")) as fh:
+        cell = json.load(fh)
+    asked = []
+
+    class Gen:
+        def compile_ahead(self, params, b, prompt_len, max_new, pool):
+            asked.append(b)
+
+    lm = TransformerLM(61, embed_dim=16, num_heads=2, num_layers=1,
+                       max_len=32, output="logits")
+    srv = InferenceServer(lm, max_batch=cell["max_batch"])
+    ladder = list(srv.batcher.ladder)
+    assert ladder[-1] == cell["max_batch"]
+    srv._compile_ladder(Gen(), None, ("gen", 1, cell["prompt_len"],
+                                      cell["max_new"]))
+    assert asked == [1] + grouped + [b for b in ladder[1:]
+                                     if b not in grouped]
+    srv._compile_pool.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # metrics export
 # ---------------------------------------------------------------------------

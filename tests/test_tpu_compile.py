@@ -254,7 +254,12 @@ def test_smallthinker_32_row_bucket_fits_a_v5e_with_its_prompt_in_groups(
     whole vocabulary as published — compiled for the described chip with
     the TPU's branches taken: the prompt pass goes in 4 groups of 8 rows
     (147 456 tokens against ``PREFILL_TOKENS``), the program keeps ONE
-    ``while`` and its arguments and temporaries stay under 13 GB.  To
+    ``while`` and its arguments and temporaries stay under 13 GB.  Its
+    grouped products are all Mosaic calls under ``moe.expert_matmul``
+    (PR 48): twelve a decode step under ``generate.decode_step``
+    (``ops/grouped_decode.py``), and OUTSIDE it the prompt pass's 4
+    groups x 8 pieces of 27 648 rows x 4 layers x 3 products
+    (``ops/grouped_prefill.py``) — no ``ragged-dot`` is left.  To
     spare this host 4.2 GB of zeros, 8 of the 64 experts a layer are
     held here (the router keeps its 64 outputs and 6 a token, so every
     buffer of the program has the cell's shape) and the 56 left out are
@@ -277,6 +282,9 @@ def test_smallthinker_32_row_bucket_fits_a_v5e_with_its_prompt_in_groups(
     assert (fp["kv_cache_bytes_window"], fp["kv_cache_bytes_full"],
             fp["kv_cache_positions"], fp["prefill_groups"]) == (
         805_306_368, 318_767_104, 4864, 4)
+    assert (fp["grouped_prefill"], fp["grouped_prefill_tiles"],
+            fp["grouped_prefill_tiles_down"]) == (
+        "grouped_prefill", "128x2560x768", "128x768x2560")
 
     def S(shape=(), dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -295,24 +303,71 @@ def test_smallthinker_32_row_bucket_fits_a_v5e_with_its_prompt_in_groups(
     assert left_out == 2_642_411_520
     assert (mem.argument_size_in_bytes + left_out
             + mem.temp_size_in_bytes) < 13e9
-    assert len(re.findall(r" while\(", compiled.as_text())) == 1
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "moe.expert_matmul" in line]
+    step = [c for c in calls if "generate.decode_step" in c]
+    assert (len(step), len(calls) - len(step)) == (12, 4 * 8 * 4 * 3)
+    assert {re.search(r"= bf16\[(\d+),(\d+)\]", c).groups()
+            for c in calls if c not in step} == {("27648", "768"),
+                                                 ("27648", "2560")}
+    assert "ragged-dot" not in text
+
+
+def test_the_products_of_a_prompt_piece_share_one_set_of_visit_lists(
+        one_chip):
+    """``grouped_prefill`` makes its visit lists from the sizes inside
+    the call, and a piece's gate, up and down products get the SAME
+    sizes: compiled for the described v5e, the three calls of a gated
+    expert leave ONE set of the lists' fusions in the program (the
+    compiler merges equal operations of equal operands), so a prompt
+    pass holds them once a piece, not once a product."""
+    import re
+
+    from bigdl_tpu.ops.grouped_prefill import grouped_prefill
+
+    R, k, n, G = 2048, 256, 384, 8
+
+    def one(x, wg, wu, wd, s):
+        return grouped_prefill(x, wg, s)
+
+    def three(x, wg, wu, wd, s):
+        g, u = grouped_prefill(x, wg, s), grouped_prefill(x, wu, s)
+        return grouped_prefill(jax.nn.relu(g) * u, wd, s)
+
+    def S(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def entry(fn):
+        text = jax.jit(fn).lower(
+            S((R, k)), S((G, k, n)), S((G, k, n)), S((G, n, k)),
+            S((G,), jnp.int32)).compile().as_text()
+        body = text[text.index("ENTRY"):]
+        return (body.count('custom_call_target="tpu_custom_call"'),
+                len(re.findall(r" fusion\(", body)))
+
+    (calls1, fusions1), (calls3, fusions3) = entry(one), entry(three)
+    assert (calls1, calls3) == (1, 3)
+    assert fusions3 == fusions1 + 1         # the gate's product alone
 
 
 _TOY_4_OF_4 = {"vocab_size": 256, "mlp_dim": 256, "n_experts": 4,
                "held": [0, 4], "top_k": 4}
 
 
-@pytest.mark.parametrize("config,cls,B,toy,up,down", [
+@pytest.mark.parametrize("config,cls,B,toy,up,down,pieces", [
     ("lfm2-24b-a2b-l5", "ShortConvMoELM", 256, _TOY_4_OF_4,
-     "128x2048x1536", "128x1536x2048"),
+     "128x2048x1536", "128x1536x2048", 4),
     ("xing4.0-29b-a4b-l5e32v2", "HyperLatentMoELM", 256, _TOY_4_OF_4,
-     "128x3584x1024", "128x1024x3584"),
+     "128x3584x1024", "128x1024x3584", 0),
     ("smallthinker-21b-a3b-l4", "PreRoutedMoELM", 32,
      {"vocab_size": 256, "n_experts": 8, "held": [0, 8]},
-     "64x2560x768", "64x768x2560"),
+     "64x2560x768", "64x768x2560", 1),
 ])
 def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
-        one_chip, monkeypatch, config, cls, B, toy, up, down):
+        one_chip, monkeypatch, config, cls, B, toy, up, down, pieces):
     """The largest bucket of the LFM2 and Xing4.0 cells (256 rows, four
     choices a token: a decode buffer of 1024 rows at the published
     embed and expert widths; four experts, toy vocabulary and dense
@@ -323,7 +378,14 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     128 rows or of 64 — and a decode step holds twelve Mosaic calls
     under ``moe.expert_matmul`` (three a layer, four expert layers):
     what the ``*_expert_matmul_roofline`` readers count a step's
-    products by."""
+    products by.  The prompt's 32 768 (24 576) tokens go in ``pieces``
+    pieces of ``ops/grouped_prefill.py``'s calls where an expert's
+    matrix is one tile of it (SmallThinker's 3.9 MB, LFM2's 6.3:
+    ``128x<k>x<n>``, 10-16 MiB of VMEM a call), OUTSIDE
+    ``generate.decode_step``, and no ``ragged-dot`` is left in the
+    program; ``pieces`` 0 — Xing4.0's 7.3 MB, 19.8 MiB a call — says
+    the prompt pass keeps ``ragged_dot``.  ``cache_footprint`` says
+    which in ``grouped_prefill*``."""
     import json
     import os
     import re
@@ -342,6 +404,10 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     foot = G.cache_footprint(model, B, T0, new, compute_dtype=jnp.bfloat16)
     assert (foot["grouped"], foot["grouped_tiles"],
             foot["grouped_tiles_down"]) == ("grouped_decode", up, down)
+    assert (foot["grouped_prefill"], foot["grouped_prefill_tiles"],
+            foot["grouped_prefill_tiles_down"]) == ((
+        "grouped_prefill", "128x" + up.split("x", 1)[1],
+        "128x" + down.split("x", 1)[1]) if pieces else ("ragged", "", ""))
 
     def S(shape=(), dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -362,9 +428,10 @@ def test_grouped_products_of_a_256_row_bucket_compile_for_v5e(
     assert {re.search(r"= bf16\[(\d+),(\d+)\]", c).groups()
             for c in step} == {(str(rows), up.split("x")[2]),
                                (str(rows), down.split("x")[2])}
-    # the prompt's 32 768 (24 576) rows go in pieces of ``ragged_dot``:
-    # no Mosaic call under the scope outside the decode step
-    assert len(calls) == len(step)
+    # the prompt pass's calls lie outside the decode step, three a
+    # piece and expert layer, or it is ``ragged_dot``'s
+    assert len(calls) - len(step) == pieces * 12
+    assert ("ragged-dot" in text) == (not pieces)
 
 
 def test_dp4_step_gathers_behind_the_forward_and_reduce_scatters(

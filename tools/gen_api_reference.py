@@ -255,11 +255,18 @@ def main():
             "  tile of 3.9-8 MB, so a hit expert's weights cross HBM",
             "  once wherever its rows lie, the buffer walked in products",
             "  of 128 rows or of 64 where 128 does not divide it",
-            "  (SmallThinker's 192) — and for a larger matrix the",
-            "  Pallas grouped matmul that ships with jax; `grouped_plan`",
-            "  is the one rule, from shapes alone, and `cache_footprint` /",
-            "  `serve.dispatch` say which: `grouped`, `grouped_tiles`,",
-            "  `grouped_tiles_down`).  `MoEFFN` keeps its capacity",
+            "  (SmallThinker's 192) — for a larger matrix the Pallas",
+            "  grouped matmul that ships with jax, and for a piece of a",
+            "  prompt pass (more than 2048 rows) whose expert matrix is",
+            "  one tile `ops.grouped_prefill.grouped_prefill`: a (row",
+            "  tile, group) visit a grid step with ONE k tile, a group's",
+            "  matrix fetched once and the next group's behind it;",
+            "  `grouped_plan` is the one rule, from shapes alone, and",
+            "  `cache_footprint` / `serve.dispatch` say which: `grouped`,",
+            "  `grouped_tiles`, `grouped_tiles_down` for the decode step,",
+            "  `grouped_prefill`, `grouped_prefill_tiles`,",
+            "  `grouped_prefill_tiles_down` for a piece of the prompt",
+            "  pass).  `MoEFFN` keeps its capacity",
             "  dispatch for training; decode advances it through the same",
             "  dropless dispatch.",
             "- **`ops.flash_attention(..., window=W)`**: the FORWARD kernel",

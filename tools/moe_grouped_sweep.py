@@ -11,8 +11,10 @@ tiles of megablox's ``gmm``.  (PR 32's sweep gave every group ``real //
 held`` rows: every group started on a tile boundary, none straddled.)
 
     python tools/moe_grouped_sweep.py
-        [--shapes lfm2,xing4,glm,commandaplus,smallthinker,smallthinker_prefill]
-        [--arms gmm,decode,ragged] [--chunk 128,64]
+        [--shapes lfm2,xing4,glm,commandaplus,smallthinker,
+                  smallthinker_prefill,lfm2_prefill,glm_prefill,
+                  xing4_prefill,commandaplus_prefill]
+        [--arms gmm,decode,prefill,ragged] [--chunk 128,64]
         [--tiling today plan 128,k,today 64,k,plan]
         [--d .. --f .. --held .. --rows .. --real ..]
 
@@ -21,15 +23,21 @@ Arms: ``gmm`` (the Pallas grouped matmul that ships with jax) under each
 ``tn`` 1024 where that divides, else 512), ``plan`` ISSUE 43's Arm A
 (one k tile and the widest column tile within 4 MB that divides ``n``:
 ``[2048, 768]``, ``[3584, 512]``, ...), ``tm,tk,tn`` explicit (``tk`` a
-number or ``k`` for the whole depth, ``tn`` a number, ``today`` or
-``plan``); ``decode`` (the repo's own ``ops/grouped_decode.py``: one
-grid step a group, its whole matrix one tile) under each ``--chunk``
-(rows a product); ``ragged`` (``jax.lax.ragged_dot``).  An arm is
-skipped at a shape its tiles do not divide, ``decode`` where the kernel
-does not take the product (``grouped_decode.fits``: a matrix over its
-8 MB, rows that are no whole chunks, a buffer over its VMEM — a prompt
-piece).  ``--d`` ... give one shape of your own in place of
-``--shapes``.
+number or ``k`` for the whole depth, ``tn`` a number, ``n`` for the
+whole width, ``today`` or ``plan``); ``decode`` (the repo's own
+``ops/grouped_decode.py``: one grid step a group, its whole matrix one
+tile) under each ``--chunk`` (rows a product); ``prefill`` (the repo's
+own ``ops/grouped_prefill.py``: ``gmm``'s grid with ONE k tile, for a
+piece of a prompt pass; its row tile is the module's, 128: 256 read
+slower at every shape, PERF.md §6 "PR 48");
+``ragged`` (``jax.lax.ragged_dot``).  An arm is skipped at a shape its
+tiles do not divide, ``decode`` and ``prefill`` where the kernel does
+not take the product (``grouped_decode.fits``: a matrix over its 8 MB,
+rows that are no whole chunks, a buffer over its VMEM — a prompt piece;
+``grouped_prefill.fits``: a matrix that is no one tile within its VMEM).
+``--d`` ... give one shape of your own in place of ``--shapes``;
+``--even`` deals every group ``real // held`` rows in place of a draw
+(with whole row tiles a group, no group straddles a tile).
 
 Chip only.  A row of the table is one product (``gate``: the gate and
 up calls; ``down``) of one arm at one shape:
@@ -58,10 +66,11 @@ up calls; ``down``) of one arm at one shape:
   ``ragged_dot`` on the defined rows of one gate product (values of
   order 1).
 
-PERF.md §6 "PR 43" and "PR 47" have the readings (§6 "PR 32" the first
-sweep's).
+PERF.md §6 "PR 43", "PR 47" and "PR 48" have the readings (§6 "PR 32"
+the first sweep's).
 """
 import argparse
+import collections
 import json
 import os
 import shutil
@@ -82,18 +91,27 @@ SHAPES = {
     "commandaplus": (4096, 4096, 16, 1024, 128),
     # 32 rows x 6 choices: no whole 128-row chunks, products of 64
     "smallthinker": (2560, 768, 64, 192, 192),
-    # one piece of that cell's prompt pass (4608 rows x 6 choices): over
-    # the plan's 2048 rows, ``ragged`` in the program; ``decode`` skips it
+    # one piece of a cell's prompt pass (SmallThinker's: 4608 tokens x 6
+    # choices; the others': 8192 tokens x 4, Command A+'s 4096 x 8), the
+    # real rows the share of the choices that name a held expert: over
+    # the plan's 2048 rows, so ``decode`` skips them
     "smallthinker_prefill": (2560, 768, 64, 27648, 27648),
+    "lfm2_prefill": (2048, 1536, 64, 32768, 32768),
+    "glm_prefill": (2048, 1536, 16, 32768, 8192),
+    "xing4_prefill": (3584, 1024, 32, 32768, 16384),
+    "commandaplus_prefill": (4096, 4096, 16, 32768, 4096),
 }
 
 
-def draw_sizes(held: int, real: int, seed: int):
+def draw_sizes(held: int, real: int, seed: int, even: bool = False):
     """Rows a group as a router deals them: popularities from a
     Dirichlet(4) (a coefficient of variation of one half), ``real``
-    assignments drawn over them."""
+    assignments drawn over them; ``even``: ``real // held`` each (with
+    whole row tiles a group no group straddles one)."""
     import numpy as np
 
+    if even:
+        return np.full((held,), real // held, "int32")
     rng = np.random.default_rng(seed)
     return rng.multinomial(real, rng.dirichlet([4.0] * held)).astype("int32")
 
@@ -130,27 +148,31 @@ def decode_grid(sizes, chunk: int, align: int = 16):
 
 def call_seconds(trace_dir, trace_reduce):
     """([seconds of each Mosaic / ragged call, in the order they ran],
-    self seconds of every OTHER operation inside the loop) of chip 0."""
+    self seconds of every OTHER operation inside the loop, {name of such
+    an operation: its self seconds}) of chip 0."""
     planes = trace_reduce.load(trace_dir)
     chip = next(p for p in planes if trace_reduce._is_chip(p["name"]))
-    calls, other = [], 0.0
+    calls, other, names = [], 0.0, collections.Counter()
     for ev, self_ns, in_loop in trace_reduce.self_times(
             trace_reduce._line(chip, "XLA Ops")):
         # on the v5e a ``ragged_dot`` is a custom call the compiler names
         # ``ragged-dot-none.N`` (beside a ``ragged-dot-metadata`` of 3 us)
+        # (the metadata call is a Mosaic custom call too: PR 48's first
+        # sweep counted four calls a layer)
         head = ev[0].split(" = ")[0]
-        if "tpu_custom_call" in ev[0] or (
-                "ragged-dot" in head and "metadata" not in head):
+        if "metadata" not in head and ("tpu_custom_call" in ev[0]
+                                       or "ragged-dot" in head):
             calls.append((ev[1], ev[2] / 1e9))
         elif in_loop:
             other += self_ns / 1e9
-    return [sec for _, sec in sorted(calls)], other
+            names[head.lstrip("%").rstrip(".0123456789")] += self_ns / 1e9
+    return [sec for _, sec in sorted(calls)], other, names
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shapes", default=",".join(
-        s for s in SHAPES if s != "smallthinker_prefill"))
+        s for s in SHAPES if not s.endswith("_prefill")))
     for dim in ("d", "f", "held", "rows", "real"):
         ap.add_argument("--" + dim, type=int, default=0)
     ap.add_argument("--arms", default="gmm,decode")
@@ -161,6 +183,8 @@ def main() -> int:
     ap.add_argument("--calls", type=int, default=24,
                     help="expert layers in one timed loop")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--even", action="store_true",
+                    help="real // held rows a group in place of a draw")
     ap.add_argument("--out", default="chiprun_out/moe_grouped_sweep.json")
     args = ap.parse_args()
     import jax
@@ -170,6 +194,7 @@ def main() -> int:
 
     from benchmark import counts, trace_reduce
     from bigdl_tpu.ops import grouped_decode as GD
+    from bigdl_tpu.ops import grouped_prefill as GP
     from bigdl_tpu.parallel.moe import _tiles_of
 
     dev = jax.devices()[0]
@@ -193,7 +218,7 @@ def main() -> int:
                 out.append(("ragged", None, lax.ragged_dot, None))
             for t in args.tiling if arm == "gmm" else ():
                 today = _tiles_of("gmm", rows, k, n)
-                named = {"k": k, "today": today[2], "plan": tn_plan}
+                named = {"k": k, "n": n, "today": today[2], "plan": tn_plan}
                 tiles = (today if t == "today" else
                          (today[0], k, tn_plan) if t == "plan" else
                          tuple(named.get(w) or int(w) for w in t.split(",")))
@@ -202,6 +227,12 @@ def main() -> int:
                     f"gmm {t}", tiles,
                     ok and (lambda x, w, s, tiles=tiles: gmm(
                         x, w, s, preferred_element_type=dt, tiling=tiles)),
+                    lambda sizes, tiles=tiles: gmm_grid(sizes, tiles, k, n)))
+            if arm == "prefill":
+                tiles = (GP.ROW_TILE, k, n)
+                out.append((
+                    "prefill", tiles,
+                    GP.fits(rows, k, n, 2) and GP.grouped_prefill,
                     lambda sizes, tiles=tiles: gmm_grid(sizes, tiles, k, n)))
             for c in (int(c) for c in args.chunk.split(",")
                       if arm == "decode"):
@@ -215,7 +246,7 @@ def main() -> int:
 
     rows_out = []
     for name, (d, f, held, rows, real) in shapes.items():
-        sizes_np = draw_sizes(held, real, args.seed)
+        sizes_np = draw_sizes(held, real, args.seed, args.even)
         sizes = jnp.asarray(sizes_np)
         hit = int((sizes_np > 0).sum())
         ks = jax.random.split(jax.random.PRNGKey(args.seed), 4)
@@ -261,7 +292,7 @@ def main() -> int:
                     walls.append(time.perf_counter() - t0)
                 with jax.profiler.trace(trace_dir):
                     jax.block_until_ready(loop(x, wg, wu, wd, sizes))
-                secs, other = call_seconds(trace_dir, trace_reduce)
+                secs, other, names = call_seconds(trace_dir, trace_reduce)
             except Exception as e:  # noqa: BLE001 — a sweep reports
                 rows_out.append(dict(
                     head, error=f"{type(e).__name__}: {str(e)[:300]}"))
@@ -281,6 +312,9 @@ def main() -> int:
                            other_ops_ms_per_layer=1e3 * other / args.calls,
                            calls_timed=len(calls) if whole else 0,
                            calls_seen=len(secs))
+                if not whole:   # what the loop held instead
+                    row["loop_ops_ms"] = {
+                        nm: 1e3 * sec for nm, sec in names.most_common(6)}
                 if whole:
                     ms = 1e3 * statistics.median(calls)
                     once = 2.0 * (hit * k * n + real * (k + n))
